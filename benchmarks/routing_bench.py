@@ -205,9 +205,6 @@ def main(argv=None) -> None:
     )
     args = p.parse_args(argv)
 
-    from dynamo_tpu.platform import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
     print(json.dumps(asyncio.run(bench(args)), indent=1))
 
 

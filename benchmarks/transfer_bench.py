@@ -127,9 +127,6 @@ def main(argv=None) -> None:
     p.add_argument("--iters", type=int, default=5)
     args = p.parse_args(argv)
 
-    from dynamo_tpu.platform import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
     import os
 
     # Sender and receiver share this process, so the device plane is safe

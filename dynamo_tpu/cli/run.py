@@ -162,7 +162,11 @@ async def _stop_engine(runner) -> None:
         await res
 
 
-async def _run_http(args) -> None:
+async def _start_http(args):
+    """Build and start the `run in=http` server: (HttpService, engine
+    runner or None, model watcher or None — the caller keeps it alive).
+    Split from _run_http so chip_smoke.py serves through the same
+    construction the CLI uses."""
     from dynamo_tpu.frontend import HttpService, ModelManager
     from dynamo_tpu.frontend.service import ModelWatcher
 
@@ -189,6 +193,11 @@ async def _run_http(args) -> None:
         request_timeout_s=getattr(args, "request_timeout", None),
     )
     await svc.start()
+    return svc, runner, watcher
+
+
+async def _run_http(args) -> None:
+    svc, runner, _watcher = await _start_http(args)
     print(f"listening on http://{args.host}:{svc.port}/v1", flush=True)
     try:
         await asyncio.Event().wait()
@@ -282,8 +291,8 @@ async def _run_worker(args) -> None:
 
     rt = await DistributedRuntime.create(args.fabric)
     # progress line BEFORE engine construction: lets a supervisor
-    # distinguish "loading/compiling" (slow but alive) from a wedged
-    # device tunnel (this line never appears)
+    # distinguish "loading/compiling" (slow but alive) from a process
+    # that never got going (this line never appears)
     print(f"worker booting (model={args.model}, role={args.role})",
           flush=True)
     if args.role == "prefill":
@@ -863,9 +872,8 @@ def build_parser() -> argparse.ArgumentParser:
     runp.add_argument("--page-size", type=int, default=64, dest="page_size")
     runp.add_argument(
         "--decode-steps", type=int, default=None, dest="decode_steps",
-        help="decode steps fused per dispatch (host sync per K tokens/seq;"
-             " raise to ~64 on a remote/tunneled TPU where the sync RTT"
-             " dominates a step). Default: engine default (8)",
+        help="decode steps fused per dispatch (host sync per K tokens/seq)."
+             " Default: engine default (8)",
     )
     runp.add_argument(
         "--decode-kstep", type=int, default=1, dest="decode_kstep",
@@ -1392,10 +1400,6 @@ def main(argv: Optional[list[str]] = None) -> None:
         from dynamo_tpu import telemetry
 
         telemetry.configure(enabled=True)
-
-    from dynamo_tpu.platform import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
 
     # Manifest/introspection commands don't touch the native hot path —
     # dispatch them before the (possibly minutes-long) native compile.

@@ -46,10 +46,8 @@ class EngineConfig:
     #: max sequences resident (decode slots)
     max_seqs: int = 64
     #: decode steps fused per dispatch (lax.scan with on-device token
-    #: feedback): one host⇄device sync per `decode_steps` tokens/seq. With
-    #: a remote/tunneled TPU the sync round-trip dominates a decode step,
-    #: so K steps per sync multiplies decode throughput by ~K. Finish
-    #: conditions are applied on the host afterwards — up to K-1 speculative
+    #: feedback): one host⇄device sync per `decode_steps` tokens/seq.
+    #: Finish conditions are applied on the host afterwards — up to K-1 speculative
     #: tokens past a stop are computed and dropped. 1 = classic stepping.
     decode_steps: int = 8
     #: on-device K-step decode windows (ROADMAP item 2a, the host-loop
@@ -160,12 +158,11 @@ class EngineConfig:
     #: halves the HBM weight traffic decode is bound by)
     quantize: Optional[str] = None
     #: KV-cache page quantization: None | "int8" | "fp8". Pages store the
-    #: narrow dtype with per-(page, slot, kv-head) f32 scale planes;
+    #: narrow dtype with per-(page, kv-head, slot) f32 scale planes;
     #: dequant is folded into the Pallas page-walk kernels (and the XLA
     #: gather fallback), halving KV HBM traffic in the history-dominated
-    #: decode regime and ~doubling effective cache capacity. "fp8" needs
-    #: a jax with float8_e4m3fn. Not supported for MLA (shared-latent
-    #: cache) models.
+    #: decode regime and ~doubling effective cache capacity. Not
+    #: supported for MLA (shared-latent cache) models.
     kv_quantize: Optional[str] = None
     #: decode attention: "auto" (pallas on TPU single-chip, else xla),
     #: "xla", "pallas", or "hybrid" (pallas kernels with decode falling

@@ -168,6 +168,17 @@ class LlamaConfig:
             return -(-self.head_dim // 128) * 128
         return self.head_dim
 
+    def kv_scale_slots(self, page_size: int) -> int:
+        """Slots per page in a quantized pool's scale planes
+        ([L, P, Hkv, slots]). The Pallas readers DMA one page's whole
+        [Hkv, slots] plane, and Mosaic requires the minor dim of a DMA
+        slice aligned to the 128-lane tile — so with the kernels active
+        the planes keep `page_size` padded up to a 128 multiple (the pad
+        slots are never read)."""
+        if self.attention_impl in ("pallas", "hybrid"):
+            return -(-page_size // 128) * 128
+        return page_size
+
     # -- canned configs ----------------------------------------------------
 
     @staticmethod
@@ -552,9 +563,12 @@ class KVPages(NamedTuple):
     page table ever references it.
 
     Quantized pages (`kv_quantize="int8"|"fp8"`): k/v hold the narrow
-    dtype and k_scale/v_scale carry per-(page, slot, kv-head) f32 scale
-    planes of shape [L, P, S, Hkv] — each page travels with its own
-    [S, Hkv] scale plane. A token's row [D] quantizes symmetrically
+    dtype and k_scale/v_scale carry per-(page, kv-head, slot) f32 scale
+    planes of shape [L, P, Hkv, S'] — each page travels with its own
+    [Hkv, S'] scale plane, slot-minor so a page's plane is one
+    lane-aligned DMA (S' = LlamaConfig.kv_scale_slots(S): S, or S padded
+    to a 128 multiple when the kernels are active). A token's row [D]
+    quantizes symmetrically
     against its own amax on write, so pages filling incrementally never
     need re-scaling, and readers dequantize in VMEM right after the page
     DMA lands — no fp copy of the cache ever exists in HBM.
@@ -562,7 +576,7 @@ class KVPages(NamedTuple):
 
     k: jax.Array
     v: jax.Array
-    k_scale: Optional[jax.Array] = None  # [L, P, S, Hkv] f32, quantized only
+    k_scale: Optional[jax.Array] = None  # [L, P, Hkv, S'] f32, quantized only
     v_scale: Optional[jax.Array] = None
 
     @property
@@ -583,13 +597,7 @@ def kv_quant_spec(mode: str):
     if mode == "int8":
         return jnp.int8, 127.0
     if mode == "fp8":
-        fp8 = getattr(jnp, "float8_e4m3fn", None)
-        if fp8 is None:
-            raise ValueError(
-                "kv_quantize='fp8' needs jnp.float8_e4m3fn (newer jax); "
-                "use 'int8'"
-            )
-        return fp8, 448.0
+        return jnp.float8_e4m3fn, 448.0
     raise ValueError(f"unknown kv_quantize mode {mode!r}; use int8|fp8")
 
 
@@ -625,7 +633,10 @@ def init_kv_pages(
     )
     if kv_quantize:
         qdtype, _ = kv_quant_spec(kv_quantize)
-        scale_shape = shape[:-1]
+        scale_shape = (
+            cfg.num_layers, num_pages, cfg.num_kv_heads,
+            cfg.kv_scale_slots(page_size),
+        )
         return KVPages(
             k=jnp.zeros(shape, qdtype),
             v=jnp.zeros(shape, qdtype),
@@ -645,16 +656,20 @@ def kv_page_bytes(
     kv_pool_bytes_dense_equiv, are computed from the actual pool arrays
     at engine init instead — that also covers MLA's asymmetric caches).
     `cfg` is a LlamaConfig (MoE passes cfg.base); quantized pages pay
-    1 byte/elem + 4-byte f32 row scales, i.e. ~(1 + 4/D)/itemsize of
+    1 byte/elem + 4-byte f32 row scales (plus the scale planes' lane
+    padding when the kernels are active), i.e. ~(1 + 4/D)/itemsize of
     the dense cost. Pinned by tests/test_kv_quant.py."""
     d = cfg.kv_head_dim
-    elems = cfg.num_layers * page_size * cfg.num_kv_heads
+    heads = cfg.num_layers * cfg.num_kv_heads
     if kv_quantize:
         qdtype, _ = kv_quant_spec(kv_quantize)
-        per = d * jnp.dtype(qdtype).itemsize + 4  # row + f32 scale
+        per_head = (
+            page_size * d * jnp.dtype(qdtype).itemsize  # rows
+            + cfg.kv_scale_slots(page_size) * 4  # f32 scale plane
+        )
     else:
-        per = d * jnp.dtype(dtype or cfg.dtype).itemsize
-    return 2 * elems * per  # k and v
+        per_head = page_size * d * jnp.dtype(dtype or cfg.dtype).itemsize
+    return 2 * heads * per_head  # k and v
 
 
 # ---------------------------------------------------------------------------
@@ -1205,16 +1220,15 @@ def apply_rope(
 def paged_scatter(
     cache: jax.Array,  # [L, P, S, ...] — the FULL stacked cache
     layer: jax.Array,  # scalar int32 layer index
-    new: jax.Array,  # [B, T, ...] (KV rows [B,T,Hkv,D] or scale [B,T,Hkv])
+    new: jax.Array,  # [B, T, ...] (KV rows [B,T,Hkv,D])
     page_tables: jax.Array,  # [B, MP] int32
     positions: jax.Array,  # [B, T] int32
     valid: jax.Array,  # [B, T] bool
 ) -> jax.Array:
     """Write new KV for absolute `positions` into cache[layer]'s pages
     (the XLA fallback path; the Pallas impl stages writes and lands them
-    with one DMA kernel per step instead — ops/kv_update.py). Trailing
-    dims are generic: the same scatter lands KV rows and their quantized
-    scale planes.
+    with one DMA kernel per step instead — ops/kv_update.py). Quantized
+    pools land their scale planes with _scale_scatter.
 
     Invalid (padding) slots are redirected to the null page 0 slot 0.
 
@@ -1273,16 +1287,51 @@ def paged_scatter_kv(
     return KVPages(
         k=paged_scatter(kv.k, layer, kq, *args),
         v=paged_scatter(kv.v, layer, vq, *args),
-        k_scale=paged_scatter(kv.k_scale, layer, ks, *args),
-        v_scale=paged_scatter(kv.v_scale, layer, vs, *args),
+        k_scale=_scale_scatter(kv.k_scale, layer, ks, kv.page_size, *args),
+        v_scale=_scale_scatter(kv.v_scale, layer, vs, kv.page_size, *args),
     )
+
+
+def _scale_scatter(
+    planes: jax.Array,  # [L, P, Hkv, S'] — the FULL stacked scale planes
+    layer: jax.Array,
+    new: jax.Array,  # [B, T, Hkv] f32 row scales
+    page_size: int,
+    page_tables: jax.Array,
+    positions: jax.Array,
+    valid: jax.Array,
+) -> jax.Array:
+    """paged_scatter for the slot-minor scale planes (same null-page
+    redirect, same slice-layer -> scatter -> dynamic_update structure)."""
+    page_ids = jnp.take_along_axis(
+        page_tables, positions // page_size, axis=1
+    )
+    flat_pages = jnp.where(valid, page_ids, 0).reshape(-1)
+    flat_slots = jnp.where(valid, positions % page_size, 0).reshape(-1)
+    plane = lax.dynamic_index_in_dim(planes, layer, 0, keepdims=False)
+    plane = plane.at[flat_pages, :, flat_slots].set(
+        new.reshape(-1, new.shape[-1]), mode="drop"
+    )
+    return lax.dynamic_update_index_in_dim(planes, plane, layer, 0)
+
+
+def _scale_gather(
+    planes: jax.Array, layer: jax.Array, page_tables: jax.Array,
+    page_size: int,
+) -> jax.Array:
+    """[L, P, Hkv, S'] × [B, MP] -> [B, MP*S, Hkv], position-ordered row
+    scales (pad slots stripped)."""
+    g = lax.dynamic_index_in_dim(planes, layer, 0, keepdims=False)[
+        page_tables
+    ][..., :page_size]  # [B, MP, Hkv, S]
+    b, mp, hkv, s = g.shape
+    return g.transpose(0, 1, 3, 2).reshape(b, mp * s, hkv)
 
 
 def paged_gather(
     cache: jax.Array, layer: jax.Array, page_tables: jax.Array
 ) -> jax.Array:
-    """[L, P, S, ...] × [B, MP] -> [B, MP*S, ...], position-ordered.
-    Trailing dims are generic (KV rows or their scale planes)."""
+    """[L, P, S, ...] × [B, MP] -> [B, MP*S, ...], position-ordered."""
     g = jax.lax.dynamic_index_in_dim(
         cache, layer, axis=0, keepdims=False
     )[page_tables]  # [B, MP, S, ...]
@@ -1300,8 +1349,8 @@ def paged_gather_kv(
     k = paged_gather(kv.k, layer, page_tables)
     v = paged_gather(kv.v, layer, page_tables)
     if kv.quantized:
-        ks = paged_gather(kv.k_scale, layer, page_tables)  # [B, K, Hkv]
-        vs = paged_gather(kv.v_scale, layer, page_tables)
+        ks = _scale_gather(kv.k_scale, layer, page_tables, kv.page_size)
+        vs = _scale_gather(kv.v_scale, layer, page_tables, kv.page_size)
         return (
             dequantize_kv_rows(k, ks, dtype),
             dequantize_kv_rows(v, vs, dtype),

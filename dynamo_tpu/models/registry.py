@@ -67,8 +67,8 @@ def _kv_pages_spec(kv_quantize=None, shard_heads: bool = True):
 
     scale = (
         resolve(L(
-            "layers", "kv_pages", "kv_seq",
-            "kv_heads" if shard_heads else None,
+            "layers", "kv_pages",
+            "kv_heads" if shard_heads else None, "kv_seq",
         ))
         if kv_quantize
         else None

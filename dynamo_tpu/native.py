@@ -11,6 +11,10 @@ Build discipline:
   or None while a background build (started on first miss) is running.
   Callers must keep a Python fallback path (tokens/blocks.py,
   kv_router/indexer.py do).
+- The library is keyed on a hash of the tracked sources (native/*.cpp,
+  xxh3.h, Makefile): it lives at native/build/libdynamo_native-<hash>.so,
+  so a library built from other sources is never loaded — file times say
+  nothing once a tree has been copied.
 - Builds are cross-process safe: compiled under an flock to a temp name in
   native/build/, then os.replace'd into place so a concurrent loader never
   dlopens a half-written ELF.
@@ -19,6 +23,7 @@ Build discipline:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -29,15 +34,20 @@ from typing import Optional
 logger = logging.getLogger(__name__)
 
 _NATIVE_DIR = Path(__file__).resolve().parent.parent / "native"
-_LIB_PATH = _NATIVE_DIR / "build" / "libdynamo_native.so"
-_SOURCES = [
-    _NATIVE_DIR / "dynamo_native.cpp",
-    _NATIVE_DIR / "pool.cpp",
-    _NATIVE_DIR / "host_tier.cpp",
-    _NATIVE_DIR / "codec.cpp",
-    _NATIVE_DIR / "kv_events.cpp",
-    _NATIVE_DIR / "xxh3.h",
-]
+
+
+def lib_path() -> Path:
+    """native/build/libdynamo_native-<hash of the tracked sources>.so"""
+    h = hashlib.sha256()
+    for src in (
+        *sorted(_NATIVE_DIR.glob("*.cpp")),
+        _NATIVE_DIR / "xxh3.h",
+        _NATIVE_DIR / "Makefile",
+    ):
+        h.update(src.name.encode() + b"\0")
+        h.update(src.read_bytes())
+    return _NATIVE_DIR / "build" / f"libdynamo_native-{h.hexdigest()[:16]}.so"
+
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -139,18 +149,12 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
     return lib
 
 
-def _stale() -> bool:
-    if not _LIB_PATH.exists():
-        return True
-    mtime = _LIB_PATH.stat().st_mtime
-    return any(s.exists() and s.stat().st_mtime > mtime for s in _SOURCES)
-
-
 def _build() -> bool:
     """Compile under an inter-process lock; atomic rename into place."""
     build_dir = _NATIVE_DIR / "build"
     tmp = build_dir / f".tmp.{os.getpid()}.so"
     try:
+        target = lib_path()
         build_dir.mkdir(parents=True, exist_ok=True)
         lock_path = build_dir / ".build.lock"
         with open(lock_path, "w") as lock_f:
@@ -158,7 +162,7 @@ def _build() -> bool:
 
             fcntl.flock(lock_f, fcntl.LOCK_EX)
             try:
-                if not _stale():  # another process built it while we waited
+                if target.exists():  # another process built it meanwhile
                     return True
                 proc = subprocess.run(
                     ["make", "-s", "-C", str(_NATIVE_DIR),
@@ -168,7 +172,11 @@ def _build() -> bool:
                 if proc.returncode != 0:
                     logger.warning("native build failed:\n%s", proc.stderr[-2000:])
                     return False
-                os.replace(tmp, _LIB_PATH)
+                os.replace(tmp, target)
+                # libraries of other source revisions are dead weight
+                for old in build_dir.glob("libdynamo_native*.so"):
+                    if old != target:
+                        old.unlink(missing_ok=True)
                 return True
             finally:
                 tmp.unlink(missing_ok=True)
@@ -183,14 +191,11 @@ def _load() -> Optional[ctypes.CDLL]:
     """Must be called with _lock held. Latches failure: a present-but-
     unloadable .so (corrupt/ABI mismatch) must not be retried per request."""
     global _lib, _build_failed
+    path = lib_path()
     try:
-        _lib = _configure(ctypes.CDLL(str(_LIB_PATH)))
-    except (OSError, AttributeError) as e:
-        # AttributeError: a .so from an older source revision is missing
-        # newly-declared symbols (git checkouts can leave mtimes that
-        # defeat _stale's strict >) — same contract as unloadable: return
-        # None, pure-Python fallbacks cover the gap
-        logger.warning("could not load %s: %s", _LIB_PATH, e)
+        _lib = _configure(ctypes.CDLL(str(path)))
+    except OSError as e:
+        logger.warning("could not load %s: %s", path, e)
         _lib = None
         _build_failed = True
     return _lib
@@ -212,7 +217,7 @@ def ensure_built(timeout_s: float = 180.0) -> Optional[ctypes.CDLL]:
     # Compile OUTSIDE _lock: concurrent lib() callers must stay non-blocking
     # (they fall back to Python while this thread builds). _build() itself is
     # flock-serialized, so parallel ensure_built calls don't race the .so.
-    built = (not _stale()) or _build()
+    built = lib_path().exists() or _build()
     with _lock:
         if _lib is not None or _build_failed:
             return _lib
@@ -234,7 +239,7 @@ def lib() -> Optional[ctypes.CDLL]:
             return _lib
         if _build_failed:
             return None
-        if not _stale():
+        if lib_path().exists():
             return _load()
         if _build_thread is None or not _build_thread.is_alive():
 

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dynamo_tpu.platform import tpu_compiler_params
-
 #: q/k tile rows; T is padded to a multiple (masked out)
 BLOCK = 128
 
@@ -108,13 +106,13 @@ def _hist_kernel(
     #   vcur_ref,  # [1, T, Hkv, D] VMEM
     #   k_hbm,  # [L, P, S, Hkv, D] ANY (narrow dtype when quantized)
     #   v_hbm,
-    #   [ks_hbm, vs_hbm]  # [L, P, S, Hkv] f32 scale planes (quantized)
+    #   [ks_hbm, vs_hbm]  # [L, P, Hkv, S'] f32 scale planes (quantized)
     # output:
     #   o_ref,  # [1, BQ, HQ, D]
     # scratch:
     #   k_scr,  # [2, S, Hkv, D] VMEM
     #   v_scr,
-    #   [ks_scr, vs_scr]  # [2, S, Hkv] f32 VMEM (quantized)
+    #   [ks_scr, vs_scr]  # [2, Hkv, S'] f32 VMEM (quantized)
     #   sem,  # [2 or 4, 2] DMA semaphores
     *refs,
     page_size: int,
@@ -183,10 +181,12 @@ def _hist_kernel(
         kp = k_scr[slot].astype(jnp.float32)  # [S, Hkv, D]
         vp = v_scr[slot].astype(jnp.float32)
         if quantized:
-            # dequant in VMEM right after the page lands (scale folds
-            # into this page's slice of the online softmax)
-            kp = kp * ks_scr[slot][..., None]
-            vp = vp * vs_scr[slot][..., None]
+            # dequant in VMEM right after the page lands, folded into
+            # this page's slice of the online softmax: key scales
+            # multiply score columns, value scales weight columns (the
+            # slot-minor planes are lane-oriented like both)
+            ksc = ks_scr[slot][:, :s]  # [Hkv, S]
+            vsc = vs_scr[slot][:, :s]
         key_pos = i * s + jax.lax.broadcasted_iota(jnp.int32, (1, s), 1)
         key_mask = key_pos < hist  # [1, S] — the last page may be partial
 
@@ -196,6 +196,8 @@ def _hist_kernel(
                 qh_tile(h), kp[:, h], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # [G·BQ, S]
+            if quantized:
+                scores = scores * ksc[h : h + 1]
             scores = jnp.where(key_mask, scores, -1e30)
             m_new = jnp.maximum(
                 ms[h], jnp.max(scores, axis=1, keepdims=True)
@@ -203,8 +205,9 @@ def _hist_kernel(
             p = jnp.exp(scores - m_new)
             corr = jnp.exp(ms[h] - m_new)
             l_new = ls[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            pv = p * vsc[h : h + 1] if quantized else p
             a_new = accs[h] * corr + jax.lax.dot_general(
-                p, vp[:, h], (((1,), (0,)), ((), ())),
+                pv, vp[:, h], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             m_out.append(m_new)
@@ -286,7 +289,7 @@ def paged_prefill_attention(
     scale_dim: int | None = None,
     interpret: bool | None = None,
     mesh=None,
-    k_scale: jax.Array | None = None,  # [L, P, S, Hkv] f32 (quantized pools)
+    k_scale: jax.Array | None = None,  # [L, P, Hkv, S'] f32 (quantized pools)
     v_scale: jax.Array | None = None,
 ) -> jax.Array:
     """History-chunk prefill attention: paged history walked with
@@ -305,9 +308,6 @@ def paged_prefill_attention(
     if mesh is not None and mesh.shape.get("tp", 1) > 1:
         from functools import partial
 
-        from dynamo_tpu.platform import get_shard_map
-
-        shard_map = get_shard_map()
         from jax.sharding import PartitionSpec as P
 
         def sharded(q_, kc_, vc_, k_, v_, layer_, pt_, hl_, cl_, *scales):
@@ -329,9 +329,9 @@ def paged_prefill_attention(
         args = [q, k_cur, v_cur, k_cache, v_cache, layer, page_tables,
                 hist_lens, cur_lens]
         if quantized:
-            in_specs += [P(None, None, None, "tp"), P(None, None, None, "tp")]
+            in_specs += [P(None, None, "tp", None), P(None, None, "tp", None)]
             args += [k_scale, v_scale]
-        fn = shard_map(
+        fn = jax.shard_map(
             sharded,
             mesh=mesh,
             in_specs=tuple(in_specs),
@@ -377,8 +377,8 @@ def paged_prefill_attention(
             pl.BlockSpec(memory_space=pl.ANY),
         ]
         scratch_shapes += [
-            pltpu.VMEM((2, s, hkv), jnp.float32),
-            pltpu.VMEM((2, s, hkv), jnp.float32),
+            pltpu.VMEM((2, *k_scale.shape[2:]), jnp.float32),
+            pltpu.VMEM((2, *v_scale.shape[2:]), jnp.float32),
         ]
         operands += [k_scale, v_scale]
     scratch_shapes.append(
@@ -409,7 +409,7 @@ def paged_prefill_attention(
         # the static kv-head unroll holds per-head f32 accumulators; at
         # llama3 shapes (Hkv=8, G=4, BQ=128, D=128) that is ~19MB of
         # scoped VMEM — above Mosaic's 16MB default, well under v5e's 128MB
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024
         ),
     )(
@@ -443,12 +443,9 @@ def flash_prefill_attention(
     if mesh is not None and mesh.shape.get("tp", 1) > 1:
         from functools import partial
 
-        from dynamo_tpu.platform import get_shard_map
-
-        shard_map = get_shard_map()
         from jax.sharding import PartitionSpec as P
 
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(
                 flash_prefill_attention,
                 scale_dim=scale_dim, interpret=interpret, mesh=None,

@@ -18,17 +18,15 @@ null page 0.
 
 Quantized pools (kv_quantize, models/llama.py): the staged model-dtype
 rows are quantized HERE — per-token, per-kv-head symmetric amax scales —
-and the kernel DMAs the narrow pages plus their [run, Hkv] f32 scale
-planes in the same launch, so no fp copy of the cache ever exists in HBM
-(the staged arrays are transient step-sized temporaries either way).
+and the kernel DMAs the narrow pages, so no fp copy of the cache ever
+exists in HBM (the staged arrays are transient step-sized temporaries
+either way). The f32 row scales land in their slot-minor planes
+([L, P, Hkv, S']) through one XLA scatter per plane: a token's scales are
+a strided column of single lanes there, which Mosaic's DMA (minor-dim
+slices aligned to the 128-lane tile) cannot express.
 
 input_output_aliasing keeps both caches in place. D must be a 128 multiple
-on TPU (LlamaConfig.kv_head_dim) — Mosaic DMA minor-dim alignment. The
-scale-plane copies have a SUB-128 minor dim (Hkv) — interpret mode can't
-prove Mosaic accepts that, so the queued on-chip stages
-(scripts/tpu_pallas_check.py paged_write_int8 / paged_decode_int8) are
-the lowering proof; if Mosaic rejects it, store the planes lane-padded
-(or packed into spare page lanes) — the semantics here don't change.
+on TPU (LlamaConfig.kv_head_dim) — Mosaic DMA minor-dim alignment.
 
 Parity: the engine-side KV write the reference delegates to vLLM's
 reshape_and_cache CUDA kernel (SURVEY.md §2.9); TPU-native equivalent as a
@@ -48,42 +46,19 @@ from jax.experimental.pallas import tpu as pltpu
 def _write_kernel(
     pages_ref,  # [NR] int32 target page per run (scalar prefetch)
     slots_ref,  # [NR] int32 first slot per run (scalar prefetch)
-    *refs,  # srcs, aliased-ins, outs, sem — layout depends on `quantized`
+    k_src_ref,  # [L, NR, R, Hkv, D] ANY — staged K rows, run-major
+    v_src_ref,
+    k_in_ref,  # aliased: writes land in place
+    v_in_ref,
+    k_out_ref,  # [L, P, S, Hkv, D] ANY
+    v_out_ref,
+    sem,
+    *,
     num_runs: int,
     run: int,
-    quantized: bool,
 ):
-    if quantized:
-        (
-            k_src_ref,  # [L, NR, R, Hkv, D] ANY — quantized staged rows
-            v_src_ref,
-            ks_src_ref,  # [L, NR, R, Hkv] ANY — f32 row scales
-            vs_src_ref,
-            k_in_ref, v_in_ref, ks_in_ref, vs_in_ref,  # aliased
-            k_out_ref,  # [L, P, S, Hkv, D] ANY
-            v_out_ref,
-            ks_out_ref,  # [L, P, S, Hkv] ANY
-            vs_out_ref,
-            sem,
-        ) = refs
-        del k_in_ref, v_in_ref, ks_in_ref, vs_in_ref
-        pairs = (
-            (k_src_ref, k_out_ref),
-            (v_src_ref, v_out_ref),
-            (ks_src_ref, ks_out_ref),
-            (vs_src_ref, vs_out_ref),
-        )
-    else:
-        (
-            k_src_ref,  # [L, NR, R, Hkv, D] ANY — staged K rows, run-major
-            v_src_ref,
-            k_in_ref, v_in_ref,  # aliased: writes land in place
-            k_out_ref,  # [L, P, S, Hkv, D] ANY
-            v_out_ref,
-            sem,
-        ) = refs
-        del k_in_ref, v_in_ref
-        pairs = ((k_src_ref, k_out_ref), (v_src_ref, v_out_ref))
+    del k_in_ref, v_in_ref
+    pairs = ((k_src_ref, k_out_ref), (v_src_ref, v_out_ref))
 
     def copies(i):
         return tuple(
@@ -122,7 +97,7 @@ def paged_write(
     *,
     use_kernel: bool | None = None,
     mesh=None,
-    k_scale: jax.Array | None = None,  # [L, P, S, Hkv] f32 (quantized pools)
+    k_scale: jax.Array | None = None,  # [L, P, Hkv, S'] f32 (quantized pools)
     v_scale: jax.Array | None = None,
 ):
     """Write one step's staged KV for all layers into the caches in place.
@@ -149,13 +124,10 @@ def paged_write(
     if use_kernel and mesh is not None and mesh.shape.get("tp", 1) > 1:
         from functools import partial
 
-        from dynamo_tpu.platform import get_shard_map
-
-        shard_map = get_shard_map()
         from jax.sharding import PartitionSpec as P
 
         kv_spec = P(None, None, None, "tp", None)
-        scale_spec = P(None, None, None, "tp")
+        scale_spec = P(None, None, "tp", None)
         in_specs = [
             kv_spec, kv_spec, kv_spec, kv_spec,
             P(None, None), P(None, None), P(None, None),
@@ -173,7 +145,7 @@ def paged_write(
                 v_scale=scales[1] if scales else None,
             )
 
-        fn = shard_map(
+        fn = jax.shard_map(
             sharded,
             mesh=mesh,
             in_specs=tuple(in_specs),
@@ -188,24 +160,33 @@ def paged_write(
     L, b, t = k_stage.shape[0], k_stage.shape[1], k_stage.shape[2]
     s = k_cache.shape[2]
 
+    # token-granular targets (invalid -> null page 0, slot 0): the XLA
+    # scatter's, and the scale planes' on either path
+    page_ids = jnp.take_along_axis(page_tables, positions // s, axis=1)
+    page_ids = jnp.where(valid, page_ids, 0).reshape(-1)
+    slot_of = jnp.where(valid, positions % s, 0).reshape(-1)
+
     if quantized:
         from dynamo_tpu.models.llama import quantize_kv_rows
 
         mode = "int8" if k_cache.dtype == jnp.int8 else "fp8"
         k_q, k_s = quantize_kv_rows(k_stage, mode)  # [L,B,T,Hkv,D], [L,B,T,Hkv]
         v_q, v_s = quantize_kv_rows(v_stage, mode)
+        # advanced indices on the page and slot axes: updates are
+        # [B*T, L, Hkv]
+        k_scale = k_scale.at[:, page_ids, :, slot_of].set(
+            k_s.reshape(L, b * t, -1).transpose(1, 0, 2), mode="drop"
+        )
+        v_scale = v_scale.at[:, page_ids, :, slot_of].set(
+            v_s.reshape(L, b * t, -1).transpose(1, 0, 2), mode="drop"
+        )
+        scales = (k_scale, v_scale)
     else:
-        k_q, v_q, k_s, v_s = k_stage, v_stage, None, None
+        k_q, v_q, scales = k_stage, v_stage, ()
 
     if not use_kernel:
         # XLA scatter fallback (CPU, meshes): token-granular, one 5D
-        # advanced-index scatter per cache (+ the scale planes when
-        # quantized).
-        page_of = positions // s
-        slot_of = positions % s
-        page_ids = jnp.take_along_axis(page_tables, page_of, axis=1)
-        page_ids = jnp.where(valid, page_ids, 0).reshape(-1)
-        slot_of = jnp.where(valid, slot_of, 0).reshape(-1)
+        # advanced-index scatter per cache.
         ks = k_q.reshape(L, b * t, *k_q.shape[3:])
         vs = v_q.reshape(L, b * t, *v_q.shape[3:])
         k_cache = k_cache.at[:, page_ids, slot_of].set(
@@ -214,15 +195,7 @@ def paged_write(
         v_cache = v_cache.at[:, page_ids, slot_of].set(
             vs.astype(v_cache.dtype), mode="drop"
         )
-        if not quantized:
-            return k_cache, v_cache
-        k_scale = k_scale.at[:, page_ids, slot_of].set(
-            k_s.reshape(L, b * t, -1), mode="drop"
-        )
-        v_scale = v_scale.at[:, page_ids, slot_of].set(
-            v_s.reshape(L, b * t, -1), mode="drop"
-        )
-        return k_cache, v_cache, k_scale, v_scale
+        return (k_cache, v_cache, *scales)
 
     run = min(t, s)
     assert t % run == 0, f"chunk T={t} must be a multiple of run={run}"
@@ -231,53 +204,38 @@ def paged_write(
     # First token of each run determines its page/slot; invalid -> null.
     first_pos = positions[:, ::run]  # [B, T//R]
     first_valid = valid[:, ::run]
-    page_ids = jnp.take_along_axis(page_tables, first_pos // s, axis=1)
-    page_ids = jnp.where(first_valid, page_ids, 0).reshape(-1)
-    slots = jnp.where(first_valid, first_pos % s, 0).reshape(-1)
+    run_pages = jnp.take_along_axis(page_tables, first_pos // s, axis=1)
+    run_pages = jnp.where(first_valid, run_pages, 0).reshape(-1)
+    run_slots = jnp.where(first_valid, first_pos % s, 0).reshape(-1)
 
     shape_tail = k_stage.shape[3:]
     k_src = k_q.reshape(L, nr, run, *shape_tail).astype(k_cache.dtype)
     v_src = v_q.reshape(L, nr, run, *shape_tail).astype(v_cache.dtype)
-    srcs = [k_src, v_src]
-    out_shape = [
-        jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
-        jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
-    ]
-    caches = [k_cache, v_cache]
-    if quantized:
-        srcs += [
-            k_s.reshape(L, nr, run, *k_s.shape[3:]),
-            v_s.reshape(L, nr, run, *v_s.shape[3:]),
-        ]
-        out_shape += [
-            jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
-            jax.ShapeDtypeStruct(v_scale.shape, v_scale.dtype),
-        ]
-        caches += [k_scale, v_scale]
-    n_src = len(srcs)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (2 * n_src),
-        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n_src,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 4,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
         scratch_shapes=[pltpu.SemaphoreType.DMA],
     )
-    out = pl.pallas_call(
-        functools.partial(
-            _write_kernel, num_runs=nr, run=run, quantized=quantized
-        ),
-        out_shape=out_shape,
+    k_cache, v_cache = pl.pallas_call(
+        functools.partial(_write_kernel, num_runs=nr, run=run),
+        out_shape=[
+            jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
+            jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
+        ],
         grid_spec=grid_spec,
-        # operands: pages, slots, *srcs, *caches — cache i (after the 2
-        # scalar-prefetch operands and n_src staging arrays) aliases
-        # output i, keeping every pool in place
-        input_output_aliases={2 + n_src + i: i for i in range(n_src)},
+        # operands: pages, slots, k_src, v_src, k_cache, v_cache — the
+        # caches alias the outputs, keeping both pools in place
+        input_output_aliases={4: 0, 5: 1},
         interpret=jax.default_backend() != "tpu",
     )(
-        page_ids.astype(jnp.int32),
-        slots.astype(jnp.int32),
-        *srcs,
-        *caches,
+        run_pages.astype(jnp.int32),
+        run_slots.astype(jnp.int32),
+        k_src,
+        v_src,
+        k_cache,
+        v_cache,
     )
-    return tuple(out)
+    return (k_cache, v_cache, *scales)

@@ -63,7 +63,7 @@ def _decode_kernel(
     #   q_ref,  # [B, HQ, D] VMEM (whole batch's queries, unscaled)
     #   k_ref,  # [L, P, S, Hkv, D] in HBM/ANY (narrow dtype when quantized)
     #   v_ref,
-    #   [ks_ref, vs_ref]  # [L, P, S, Hkv] f32 scale planes (quantized)
+    #   [ks_ref, vs_ref]  # [L, P, Hkv, S'] f32 scale planes (quantized)
     # outputs (whole batch resident in VMEM; read-modify-written per page):
     #   acc_ref,  # [B, HQ, D] f32 — UNNORMALIZED flash accumulator
     #   m_ref,  # [B, HQ, 128] f32 — running max (lane-broadcast)
@@ -71,7 +71,7 @@ def _decode_kernel(
     # scratch:
     #   k_scr,  # [DEPTH, S, Hkv, D] VMEM
     #   v_scr,
-    #   [ks_scr, vs_scr]  # [DEPTH, S, Hkv] f32 VMEM (quantized)
+    #   [ks_scr, vs_scr]  # [DEPTH, Hkv, S'] f32 VMEM (quantized)
     #   sem,  # [2 or 4, DEPTH] DMA semaphores: [plane, slot]
     *refs,
     page_size: int,
@@ -140,11 +140,13 @@ def _decode_kernel(
         kp = k_scr[slot].astype(jnp.float32)  # [S, Hkv, D]
         vp = v_scr[slot].astype(jnp.float32)
         if quantized:
-            # dequantize in VMEM right after the DMA lands: the f32 rows
-            # feed the flash merge directly, so the scale folds into the
-            # per-page scores/weights and no fp page ever touches HBM
-            kp = kp * ks_scr[slot][..., None]
-            vp = vp * vs_scr[slot][..., None]
+            # dequantize in VMEM right after the DMA lands, folded into
+            # the flash merge: a key row's scale multiplies its column of
+            # the scores, a value row's its column of the weights — the
+            # slot-minor planes are already lane-oriented like both, and
+            # no fp page ever touches HBM
+            ksc = ks_scr[slot][:, :s]  # [Hkv, S]
+            vsc = vs_scr[slot][:, :s]
         key_pos = (oj % max_pages) * s + jax.lax.broadcasted_iota(
             jnp.int32, (g, s), 1
         )
@@ -167,13 +169,16 @@ def _decode_kernel(
                 qh, kp[:, h], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # [G, S]
+            if quantized:
+                scores = scores * ksc[h : h + 1]
             scores = jnp.where(key_mask, scores, -1e30)
             m_new = jnp.maximum(ms, jnp.max(scores, axis=1, keepdims=True))
             p = jnp.exp(scores - m_new)
             corr = jnp.exp(ms - m_new)
             l_new = ls * corr + jnp.sum(p, axis=1, keepdims=True)
+            pv = p * vsc[h : h + 1] if quantized else p
             a_new = accs * corr + jax.lax.dot_general(
-                p, vp[:, h], (((1,), (0,)), ((), ())),
+                pv, vp[:, h], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             m_out.append(m_new)
@@ -229,7 +234,9 @@ def decode_vmem_bytes(
     scratch (and `itemsize` is the narrow dtype's — the scratch shrinks).
     The caller routes to the XLA gather when this exceeds the budget
     instead of letting Mosaic fail allocation."""
-    scale_scratch = 2 * _DEPTH * s * hkv * 4 if quantized else 0
+    scale_scratch = (
+        2 * _DEPTH * hkv * (-(-s // 128) * 128) * 4 if quantized else 0
+    )
     return (
         b * hq * d * itemsize  # q (itemsize of q ≈ cache dtype or wider)
         + b * hq * d * 4  # acc f32
@@ -252,7 +259,7 @@ def paged_decode_attention(
     interpret: bool | None = None,
     mesh=None,
     work_list=None,  # precomputed decode_work_list (layer-invariant)
-    k_scale: jax.Array | None = None,  # [L, P, S, Hkv] f32 (quantized pools)
+    k_scale: jax.Array | None = None,  # [L, P, Hkv, S'] f32 (quantized pools)
     v_scale: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """History-only flash attention over the paged cache.
@@ -281,9 +288,6 @@ def paged_decode_attention(
         # The (replicated) work list rides along so shards don't re-sort.
         from functools import partial
 
-        from dynamo_tpu.platform import get_shard_map
-
-        shard_map = get_shard_map()
         from jax.sharding import PartitionSpec as P
 
         def sharded(q_, k_, v_, layer_, pt_, hist_, n_, od_, pg_, *scales):
@@ -309,9 +313,9 @@ def paged_decode_attention(
         args = [q, k_cache, v_cache, layer, page_tables, history_lens,
                 *work_list]
         if quantized:
-            in_specs += [P(None, None, None, "tp"), P(None, None, None, "tp")]
+            in_specs += [P(None, None, "tp", None), P(None, None, "tp", None)]
             args += [k_scale, v_scale]
-        fn = shard_map(
+        fn = jax.shard_map(
             sharded,
             mesh=mesh,
             in_specs=tuple(in_specs),
@@ -341,8 +345,8 @@ def paged_decode_attention(
             pl.BlockSpec(memory_space=pl.ANY),
         ]
         scratch_shapes += [
-            pltpu.VMEM((_DEPTH, s, hkv), jnp.float32),
-            pltpu.VMEM((_DEPTH, s, hkv), jnp.float32),
+            pltpu.VMEM((_DEPTH, *k_scale.shape[2:]), jnp.float32),
+            pltpu.VMEM((_DEPTH, *v_scale.shape[2:]), jnp.float32),
         ]
         operands += [k_scale, v_scale]
     scratch_shapes.append(

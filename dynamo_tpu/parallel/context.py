@@ -34,9 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from dynamo_tpu.platform import get_shard_map
-
-shard_map = get_shard_map()
 
 
 def dense_gqa_attention(
@@ -119,14 +116,9 @@ def _ring_shard(q, k, v, *, axis_name: str, causal: bool):
         v_nxt = lax.ppermute(v_cur, axis_name, perm)
         return m, l, acc, k_nxt, v_nxt
 
-    # pcast-to-varying: the carry is device-varying over sp (vma typing).
-    # Pre-vma jax (no lax.pcast) treats every shard_map value as varying
-    # already, so the cast degrades to identity there.
+    # pcast-to-varying: the carry is device-varying over sp (vma typing)
     def _vary(x):
-        pcast = getattr(lax, "pcast", None)
-        if pcast is None:
-            return x
-        return pcast(x, axis_name, to="varying")
+        return lax.pcast(x, axis_name, to="varying")
 
     m0 = _vary(jnp.full((b, hkv, g, tl, 1), -jnp.inf, jnp.float32))
     l0 = _vary(jnp.zeros((b, hkv, g, tl, 1), jnp.float32))
@@ -161,7 +153,7 @@ def ring_attention(
     if q.shape[1] % sp:
         raise ValueError(f"T={q.shape[1]} not divisible by sp={sp}")
     spec = P(batch_axis, axis_name, head_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ring_shard, axis_name=axis_name, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -210,7 +202,7 @@ def ulysses_attention(
             "use ring_attention otherwise"
         )
     spec = P(None, axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ulysses_shard, axis_name=axis_name, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -81,10 +81,7 @@ def init_multihost(
     if os.environ.get("JAX_PLATFORMS") == "cpu":
         # CPU multi-process collectives need an explicit implementation;
         # must be set before the backend initializes.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 — older jax: option absent
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=num_hosts,
@@ -149,17 +146,13 @@ def _hybrid_device_grid(
     num = len(granules)
     if config.dp % num or config.num_devices != len(devices):
         return None
-    try:
-        from jax.experimental import mesh_utils
+    from jax.experimental import mesh_utils
 
-        return mesh_utils.create_hybrid_device_mesh(
-            mesh_shape=(config.dp // num, config.sp, config.ep, config.tp),
-            dcn_mesh_shape=(num, 1, 1, 1),
-            devices=devices,
-        )
-    except Exception:  # noqa: BLE001 — jaxlib without hybrid support /
-        # topology info: the plain reshape still yields a working mesh
-        return None
+    return mesh_utils.create_hybrid_device_mesh(
+        mesh_shape=(config.dp // num, config.sp, config.ep, config.tp),
+        dcn_mesh_shape=(num, 1, 1, 1),
+        devices=devices,
+    )
 
 
 def make_mesh(

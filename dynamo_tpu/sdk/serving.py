@@ -286,10 +286,8 @@ def main(argv: Optional[list[str]] = None) -> None:
     p.add_argument("-f", "--config", default=None)
     args = p.parse_args(argv)
     from dynamo_tpu.logging_config import configure_logging
-    from dynamo_tpu.platform import honor_jax_platforms_env
 
     configure_logging()
-    honor_jax_platforms_env()
     asyncio.run(_amain(args))
 
 

@@ -1,9 +1,9 @@
 """Perf-regression ledger: schema-versioned performance rows on disk.
 
 Every benchmark surface in the repo (bench.py, scripts/
-tpu_decode_profile.py, scripts/tpu_round.sh) appends one row per run to
+tpu_decode_profile.py) appends one row per run to
 ``artifacts/perf_ledger.jsonl`` — an append-only JSONL file that turns
-the scattered BENCH_r*.json / artifacts/tpu/*.json artifacts into one
+scattered driver-round / artifacts/tpu/*.json artifacts into one
 diffable performance history. ``scripts/perf_diff.py`` compares any two
 rounds (or a round vs BASELINE.json) with per-metric tolerance bands
 and exits nonzero on regression; the doctor's perf-regression rule
@@ -392,7 +392,7 @@ def row_from_baseline(doc: dict, round_name: str = "BASELINE") -> dict:
 
 
 def main(argv=None) -> int:
-    """CLI for shell producers (scripts/tpu_round.sh):
+    """CLI for shell producers:
     ``python -m dynamo_tpu.telemetry.perf_ledger --append-bench
     artifacts/tpu/bench_1b.json --round r06`` appends one validated
     row; --append-decode-profile does the same for profile JSON."""

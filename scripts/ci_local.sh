@@ -26,7 +26,7 @@ step() { # name, command...
 # Same step set as .github/workflows/ci.yml (minus pip install — the
 # pod image has the deps baked in; minus the standalone helm template —
 # tests/test_helm_chart.py renders the chart inside the suite).
-step build_native make -C native
+step build_native python -c "from dynamo_tpu import native; assert native.ensure_built() is not None"
 step test_suite python -m pytest tests/ -q
 
 {

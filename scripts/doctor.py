@@ -421,7 +421,7 @@ def diagnose(
                 "recent flight records — the engine loop may be wedged",
                 {"num_running": w.get("num_running")},
                 "check the worker's /v1/debug/stalls and JSONL log; a "
-                "dispatch stuck in the device tunnel shows in the "
+                "dispatch stuck in the device runtime shows in the "
                 "engine thread's stack",
             ))
 

@@ -35,10 +35,6 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from dynamo_tpu.platform import honor_jax_platforms_env  # noqa: E402
-
-honor_jax_platforms_env()
-
 import numpy as np  # noqa: E402
 
 V5E_HBM = 16 * 1024**3  # bytes/chip

@@ -1,9 +1,9 @@
 #!/usr/bin/env bash
 # BASELINE config 1's exact model (DeepSeek-R1-Distill-Llama-8B,
 # architecturally llama3-8b) served end-to-end through the canonical
-# `run in=http out=jax` pipeline. On CPU-fallback this proves the CLI
-# path + preset + model card; the chip bench rides
-# scripts/tpu_dsr1_bench.sh / BENCH_MODEL=deepseek-r1-distill-llama-8b.
+# `run in=http out=jax` pipeline. On the CPU (JAX_PLATFORMS=cpu) this
+# proves the CLI path + preset + model card; on the chip:
+# BENCH_MODEL=deepseek-r1-distill-llama-8b python bench.py.
 set -u
 cd "$(dirname "$0")/.."
 OUT=artifacts/dsr1_distill_cli.json
@@ -29,8 +29,8 @@ import json, sys
 resp = json.loads(sys.argv[1])
 print(json.dumps({
   "what": "DeepSeek-R1-Distill-Llama-8B (BASELINE config 1) served "
-          "end-to-end via `run in=http out=jax` (CPU fallback, random "
-          "weights - 8B bf16 arch proof; chip stage: tpu_dsr1_bench.sh)",
+          "end-to-end via `run in=http out=jax` (CPU, random weights - "
+          "8B bf16 arch proof)",
   "model": resp.get("model"),
   "usage": resp.get("usage"),
   "finish_reason": resp["choices"][0].get("finish_reason"),

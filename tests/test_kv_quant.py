@@ -1,6 +1,6 @@
 """Quantized KV-cache pages (EngineConfig.kv_quantize, ISSUE 2).
 
-Pages store int8 (or fp8) rows with per-(page, slot, kv-head) f32 scale
+Pages store int8 (or fp8) rows with per-(page, kv-head, slot) f32 scale
 planes; the Pallas page writer quantizes on write and both page-walk
 readers (decode + paged-history prefill) dequantize in VMEM, with the
 XLA gather fallback matching. These tests pin:
@@ -90,22 +90,25 @@ def test_paged_write_quantized_kernel_matches_fallback():
             kv.k, kv.v, k_st, v_st, pt, positions, valid,
             use_kernel=use_kernel, k_scale=kv.k_scale, v_scale=kv.v_scale,
         )
-    for a, b in zip(outs[True], outs[False]):
+    for i, (a, b) in enumerate(zip(outs[True], outs[False])):
         # compare READABLE slots only: the kernel's whole-run DMA also
         # lands the prompt-tail garbage row (seq 1 slot 3 — contractually
         # unreadable, overwritten before decode exposes it) which the
         # token-granular scatter drops; page 0 is the null page
         a, b = np.asarray(a), np.asarray(b)
+        if i >= 2:  # scale planes [L, P, Hkv, S] -> slot-major like rows
+            a, b = a.swapaxes(2, 3), b.swapaxes(2, 3)
         assert np.array_equal(a[:, 1], b[:, 1])  # seq 0's full page
         assert np.array_equal(a[:, 3, :3], b[:, 3, :3])  # seq 1 valid rows
 
     # dequantized cache rows ≈ the staged fp values within scale/2
     kq, vq, ks, vs = outs[False]
+    page1_scales = ks[:, 1].swapaxes(1, 2)  # [L, S, Hkv]
     got = np.asarray(
-        dequantize_kv_rows(kq[:, 1], ks[:, 1], jnp.float32)
+        dequantize_kv_rows(kq[:, 1], page1_scales, jnp.float32)
     )  # page 1 = seq 0's tokens
     want = np.asarray(k_st[:, 0])
-    bound = np.asarray(ks[:, 1])[..., None] * 0.5 + 1e-6
+    bound = np.asarray(page1_scales)[..., None] * 0.5 + 1e-6
     assert (np.abs(got - want) <= bound).all()
 
 

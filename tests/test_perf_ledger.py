@@ -1,5 +1,5 @@
 """Perf-regression ledger (ISSUE 19): schema round-trip through
-dynamo_tpu/telemetry/perf_ledger.py, the BENCH_r*.json back-fill
+dynamo_tpu/telemetry/perf_ledger.py, the driver-round back-fill
 (every recorded round must parse into a valid row), and the
 scripts/perf_diff.py CI contract (exit 0 clean / 1 data error / 2
 regression)."""
@@ -176,25 +176,69 @@ def test_compare_rows_tolerance_override():
     assert res["regressions"] == ["tok_s"]
 
 
-# -- producers: BENCH_r*.json back-fill ------------------------------------
+# -- producers: driver-wrapper back-fill ------------------------------------
+
+
+def _bench_doc(n, metric, value, vs, ttft, itl, elapsed) -> dict:
+    """One recorded round in the driver's wrapper shape ({"n", "cmd",
+    "rc", "tail", "parsed"}) around a bench.py payload."""
+    return {
+        "n": n, "cmd": "python bench.py", "rc": 0, "tail": "",
+        "parsed": {
+            "metric": metric, "value": value, "unit": "tok/s",
+            "vs_baseline": vs,
+            "extras": {
+                "platform": "cpu", "model": "tiny", "params": 106816,
+                "num_requests": 16, "isl": 64, "osl": 32,
+                "p50_ttft_s": ttft, "p50_itl_s": itl, "mfu": None,
+                "elapsed_s": elapsed, "generated_tokens": 512,
+            },
+        },
+    }
+
+
+#: the five rounds the driver recorded before this ledger existed (the
+#: retired BENCH_r01..r05.json files, trimmed to what row_from_bench
+#: reads): r01 could not open its backend, r02..r05 are `tiny` on CPU
+BENCH_ROUNDS = {
+    "r01": {
+        "n": 1, "cmd": "python bench.py", "rc": 1, "parsed": None,
+        "tail": (
+            "Traceback (most recent call last):\n"
+            "RuntimeError: Unable to initialize backend 'some_backend': "
+            "UNAVAILABLE: TPU backend setup/compile error (Unavailable).\n"
+        ),
+    },
+    "r02": _bench_doc(
+        2, "output_tok_s_per_chip", 552.57, 1.007, 0.0288, 0.02896, 0.93
+    ),
+    "r03": _bench_doc(
+        3, "output_tok_s_per_chip", 651.55, 1.187, 0.0268, 0.02448, 0.79
+    ),
+    "r04": _bench_doc(
+        4, "output_tok_s_cpu_fallback", 611.51, 1.114, 0.0271, 0.02613, 0.84
+    ),
+    "r05": _bench_doc(
+        5, "output_tok_s_cpu_fallback", 534.45, 0.974, 0.0311, 0.0299, 0.96
+    ),
+}
 
 
 def _backfill(tmp_path) -> str:
-    """Back-fill r01..r05 from the recorded BENCH artifacts into a
-    fresh ledger, returning its path."""
+    """Back-fill r01..r05 from the recorded rounds into a fresh ledger,
+    returning its path."""
     path = str(tmp_path / "ledger.jsonl")
-    for p in sorted(REPO.glob("BENCH_r*.json")):
-        round_name = p.stem.split("_")[-1]
-        with open(p) as f:
-            row = perf_ledger.row_from_bench(json.load(f), round_name)
-        perf_ledger.append_row(row, path)
+    for round_name, doc in BENCH_ROUNDS.items():
+        perf_ledger.append_row(
+            perf_ledger.row_from_bench(doc, round_name), path
+        )
     return path
 
 
 def test_every_recorded_bench_round_parses_into_the_schema(tmp_path):
-    """CI satellite: the repo's BENCH_r*.json history must keep
-    back-filling into valid ledger rows — a schema change that orphans
-    the recorded rounds fails here."""
+    """CI satellite: the recorded driver rounds must keep back-filling
+    into valid ledger rows — a schema change that orphans them fails
+    here."""
     path = _backfill(tmp_path)
     rows, problems = perf_ledger.read_rows(path, strict=True)
     assert problems == []
@@ -247,8 +291,10 @@ def test_row_from_baseline_pseudo_row():
 
 def test_cli_append_bench(tmp_path, capsys):
     path = str(tmp_path / "ledger.jsonl")
+    bench = tmp_path / "BENCH_r03.json"
+    bench.write_text(json.dumps(BENCH_ROUNDS["r03"]))
     rc = perf_ledger.main([
-        "--append-bench", str(REPO / "BENCH_r03.json"),
+        "--append-bench", str(bench),
         "--round", "r03", "--ledger", path,
     ])
     assert rc == 0

@@ -227,7 +227,7 @@ def test_thread_stacks_names_threads():
 class WedgeEngine:
     """AsyncEngineRunner-compatible fake: emits one token per request
     per step, then WEDGES — step() blocks on an event, exactly like a
-    dispatch stuck in a dead device tunnel. `release` unwedges it so
+    dispatch stuck in a dead device runtime. `release` unwedges it so
     the runner thread can exit at teardown."""
 
     def __init__(self, config, wedge_after_steps: int = 1):

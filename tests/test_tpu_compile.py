@@ -1,0 +1,335 @@
+"""Compile-only rehearsal for the chip: the Pallas kernels of the serving
+path, at real widths, through the TPU compiler for a DESCRIBED v5e:2x2
+topology (no chip attached; nothing executes).
+
+Interpret mode (the rest of tier-1) proves kernel semantics, not that
+Mosaic accepts the lowering: DMA slices not aligned to the (8,128) tiling,
+VMEM budgets and shard_map partitioning only fail in the real compiler.
+The caches are built by the adapter's own `init_kv`, so the head-dim
+padding (`LlamaConfig.kv_head_dim`) that keeps the kernels legal for head
+sizes that are not a 128 multiple is what these tests exercise.
+
+`jax.default_backend()`-keyed branches (kernel `interpret` defaults,
+`paged_write`'s kernel switch) are steered here, in the test; the program
+has no option for it. A compile that passes is not a chip run —
+`python chip_smoke.py` through the chip tool is.
+"""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from dynamo_tpu.models.registry import get_model
+from dynamo_tpu.ops.flash_prefill import (
+    flash_prefill_attention,
+    paged_prefill_attention,
+)
+from dynamo_tpu.ops.kv_update import paged_write
+from dynamo_tpu.ops.paged_attention import paged_decode_attention
+
+PAGES, PAGE = 512, 64  # the CLI's default pool
+MAX_PAGES = 4096 // PAGE  # --max-context 4096
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        from jax.experimental import topologies
+
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this image
+        pytest.skip(f"v5e:2x2 topology cannot be described: {e}")
+
+
+@pytest.fixture(autouse=True)
+def tpu_branches_no_persistent_cache(monkeypatch):
+    """Trace the TPU branches, and keep these compiles out of the
+    persistent cache: an entry compiled for a described device is written
+    but cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _adapter(head_dim=None, **cfg_overrides):
+    """llama3-1b (16 layers, hidden 2048, 32/8 heads of 64, bf16) with
+    the Pallas path on; `head_dim` swaps in another head size."""
+    adapter = get_model("llama3-1b", dtype="bfloat16", attention_impl="pallas")
+    if head_dim is None and not cfg_overrides:
+        return adapter
+    from dynamo_tpu.models.registry import _llama_adapter
+
+    cfg = dataclasses.replace(
+        adapter.config, head_dim=head_dim or adapter.config.head_dim,
+        **cfg_overrides,
+    )
+    return _llama_adapter(f"llama3-1b-hd{cfg.head_dim}", cfg)
+
+
+def _on(sharding, tree):
+    """Shapes of `tree` (arrays or ShapeDtypeStructs) placed by `sharding`
+    (one Sharding for all leaves, or a matching tree of them)."""
+    if not isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree, sharding,
+        )
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kv_shapes(adapter, kv_quantize):
+    return jax.eval_shape(
+        lambda: adapter.init_kv(PAGES, PAGE, kv_quantize=kv_quantize)
+    )
+
+
+def _mosaic_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def _compile_decode(adapter, kv, chip, b=8):
+    cfg = adapter.config
+    d = cfg.kv_head_dim
+
+    def fn(q, kv, pt, hist):
+        return paged_decode_attention(
+            q, kv.k, kv.v, jnp.int32(3), pt, hist, scale_dim=cfg.head_dim,
+            k_scale=kv.k_scale, v_scale=kv.v_scale,
+        )
+
+    return jax.jit(fn).lower(
+        _sds((b, cfg.num_heads, d), cfg.dtype, chip), _on(chip, kv),
+        _sds((b, MAX_PAGES), jnp.int32, chip), _sds((b,), jnp.int32, chip),
+    ).compile()
+
+
+def _compile_write(adapter, kv, chip, b, t):
+    cfg = adapter.config
+    stage = _sds(
+        (cfg.num_layers, b, t, cfg.num_kv_heads, cfg.kv_head_dim),
+        cfg.dtype, chip,
+    )
+
+    def fn(kv, ks, vs, pt, pos, valid):
+        return paged_write(
+            kv.k, kv.v, ks, vs, pt, pos, valid,
+            k_scale=kv.k_scale, v_scale=kv.v_scale,
+        )
+
+    return jax.jit(fn).lower(
+        _on(chip, kv), stage, stage, _sds((b, MAX_PAGES), jnp.int32, chip),
+        _sds((b, t), jnp.int32, chip), _sds((b, t), jnp.bool_, chip),
+    ).compile()
+
+
+def _compile_flash_prefill(adapter, chip, b=4, t=128):
+    cfg = adapter.config
+    d = cfg.kv_head_dim
+    return jax.jit(
+        lambda q, k, v, n: flash_prefill_attention(
+            q, k, v, n, scale_dim=cfg.head_dim
+        )
+    ).lower(
+        _sds((b, t, cfg.num_heads, d), cfg.dtype, chip),
+        _sds((b, t, cfg.num_kv_heads, d), cfg.dtype, chip),
+        _sds((b, t, cfg.num_kv_heads, d), cfg.dtype, chip),
+        _sds((b,), jnp.int32, chip),
+    ).compile()
+
+
+def _compile_paged_prefill(adapter, kv, chip, b=4, t=128):
+    cfg = adapter.config
+    d = cfg.kv_head_dim
+    cur = _sds((b, t, cfg.num_kv_heads, d), cfg.dtype, chip)
+
+    def fn(q, kc, vc, kv, pt, hist, cur_lens):
+        return paged_prefill_attention(
+            q, kc, vc, kv.k, kv.v, jnp.int32(3), pt, hist, cur_lens,
+            scale_dim=cfg.head_dim, k_scale=kv.k_scale, v_scale=kv.v_scale,
+        )
+
+    return jax.jit(fn).lower(
+        _sds((b, t, cfg.num_heads, d), cfg.dtype, chip), cur, cur,
+        _on(chip, kv), _sds((b, MAX_PAGES), jnp.int32, chip),
+        _sds((b,), jnp.int32, chip), _sds((b,), jnp.int32, chip),
+    ).compile()
+
+
+#: (head_dim override, extra config overrides): llama3-1b's own 64 lanes
+#: through the padding; 128 (llama3-8b widths); 96 (phi3-mini's 32/32
+#: heads of 96 — ROADMAP R1's candidates)
+WIDTHS = {
+    "hd64": (None, {}),
+    "hd128": (128, {}),
+    "hd96": (96, {"num_kv_heads": 32}),
+}
+
+
+KERNELS = (
+    "decode", "write_decode", "write_prefill", "flash_prefill",
+    "paged_prefill",
+)
+#: every kernel x width x page dtype, minus what adds nothing: flash_prefill
+#: reads no pages, and paged_prefill keeps its int8 case at the model's own
+#: width only — the scale planes do not depend on the head size
+CASES = [
+    (kernel, width, kvq)
+    for kernel in KERNELS
+    for width in WIDTHS
+    for kvq in (None, "int8")
+    if not (kvq and kernel == "flash_prefill")
+    and not (kvq and kernel == "paged_prefill" and width != "hd64")
+]
+
+
+def _case(kernel, width, kvq):
+    # paged_prefill costs ~9 s a compile whatever the shape: tier-1 keeps
+    # it at the model's own width with bf16 pages (the decode kernel's
+    # int8 cases cover the same scale-plane DMA in a second each); its
+    # other cases run with -m slow
+    slow = kernel == "paged_prefill" and (width, kvq) != ("hd64", None)
+    return pytest.param(
+        kernel, width, kvq, id=f"{kernel}-{width}-{kvq or 'bf16'}",
+        marks=[pytest.mark.slow] if slow else [],
+    )
+
+
+@pytest.mark.parametrize(
+    "kernel,width,kv_quantize", [_case(*c) for c in CASES]
+)
+def test_kernel_compiles_for_v5e(topo, kernel, width, kv_quantize):
+    head_dim, overrides = WIDTHS[width]
+    adapter = _adapter(head_dim, **overrides)
+    assert adapter.config.kv_head_dim % 128 == 0
+    chip = SingleDeviceSharding(topo.devices[0])
+    kv = _kv_shapes(adapter, kv_quantize)
+    if kernel == "decode":
+        compiled = _compile_decode(adapter, kv, chip)
+    elif kernel == "write_decode":
+        compiled = _compile_write(adapter, kv, chip, b=8, t=1)
+    elif kernel == "write_prefill":
+        compiled = _compile_write(adapter, kv, chip, b=4, t=128)
+    elif kernel == "flash_prefill":
+        compiled = _compile_flash_prefill(adapter, chip)
+    else:
+        compiled = _compile_paged_prefill(adapter, kv, chip)
+    assert _mosaic_calls(compiled) >= 1
+
+
+@pytest.mark.parametrize(
+    "tp,kv_quantize", [(4, None), (2, "int8")], ids=["tp4-bf16", "tp2-int8"]
+)
+@pytest.mark.parametrize("kernel", ["decode", "write_decode"])
+def test_kernel_compiles_inside_tp_shard_map(topo, kernel, tp, kv_quantize):
+    """The decode and write kernels under their tp shard_map on a Mesh of
+    the described chips (llama3-1b: 32/8 heads divide by 4). Narrow pages
+    need their kv heads per shard in fours (Mosaic packs four 8-bit rows
+    per sublane), so int8 stops at tp=2 here — JaxEngine refuses the rest
+    (test_engine_refuses_narrow_pages_with_too_few_kv_heads_per_shard)."""
+    mesh = Mesh(
+        np.asarray(topo.devices[:tp]).reshape(1, 1, 1, tp),
+        axis_names=("dp", "sp", "ep", "tp"),
+    )
+    adapter = get_model(
+        "llama3-1b", dtype="bfloat16", attention_impl="pallas", mesh=mesh
+    )
+    cfg = adapter.config
+    rep = NamedSharding(mesh, P())
+    kv = _on(
+        jax.tree.map(
+            lambda spec: NamedSharding(mesh, spec),
+            adapter.kv_spec(kv_quantize=kv_quantize),
+        ),
+        _kv_shapes(adapter, kv_quantize),
+    )
+    b, d = 8, cfg.kv_head_dim
+    pt = _sds((b, MAX_PAGES), jnp.int32, rep)
+    if kernel == "decode":
+        def fn(q, kv, pt, hist):
+            return paged_decode_attention(
+                q, kv.k, kv.v, jnp.int32(3), pt, hist,
+                scale_dim=cfg.head_dim, mesh=mesh,
+                k_scale=kv.k_scale, v_scale=kv.v_scale,
+            )
+
+        compiled = jax.jit(fn).lower(
+            _sds((b, cfg.num_heads, d), cfg.dtype,
+                 NamedSharding(mesh, P(None, "tp", None))),
+            kv, pt, _sds((b,), jnp.int32, rep),
+        ).compile()
+    else:
+        stage = _sds(
+            (cfg.num_layers, b, 1, cfg.num_kv_heads, d), cfg.dtype,
+            NamedSharding(mesh, P(None, None, None, "tp", None)),
+        )
+
+        def fn(kv, ks, vs, pt, pos, valid):
+            return paged_write(
+                kv.k, kv.v, ks, vs, pt, pos, valid, mesh=mesh,
+                k_scale=kv.k_scale, v_scale=kv.v_scale,
+            )
+
+        compiled = jax.jit(fn).lower(
+            kv, stage, stage, pt, _sds((b, 1), jnp.int32, rep),
+            _sds((b, 1), jnp.bool_, rep),
+        ).compile()
+    assert _mosaic_calls(compiled) >= 1
+
+
+def test_whole_llama3_1b_decode_step_compiles_with_both_kernels(topo):
+    """One full decode step (16 layers, forward + logits + argmax, B=8)
+    at the published Llama-3.2-1B widths: the page-walk and the DMA
+    writer are both in the compiled program."""
+    adapter = _adapter()
+    chip = SingleDeviceSharding(topo.devices[0])
+    params = _on(
+        chip,
+        jax.eval_shape(lambda: adapter.init_params(jax.random.key(0))),
+    )
+    b = 8
+
+    def step(params, tokens, positions, valid, kv, pt):
+        hidden, kv = adapter.forward_hidden(
+            params, tokens, positions, valid, kv, pt
+        )
+        logits = adapter.compute_logits(params, hidden[:, -1])
+        return jnp.argmax(logits, axis=-1), kv
+
+    compiled = jax.jit(step, donate_argnums=(4,)).lower(
+        params, _sds((b, 1), jnp.int32, chip), _sds((b, 1), jnp.int32, chip),
+        _sds((b, 1), jnp.bool_, chip), _on(chip, _kv_shapes(adapter, None)),
+        _sds((b, MAX_PAGES), jnp.int32, chip),
+    ).compile()
+    assert _mosaic_calls(compiled) == 2
+
+
+def test_engine_refuses_narrow_pages_with_too_few_kv_heads_per_shard():
+    """int8 pages under tp=4 leave llama3-1b 2 kv heads per shard, which
+    Mosaic cannot DMA: on a TPU the engine says so at construction
+    instead of failing inside the first dispatch's compile."""
+    from dynamo_tpu.engine import EngineConfig
+    from dynamo_tpu.engine.engine import JaxEngine
+
+    with pytest.raises(ValueError, match="kv heads per shard"):
+        JaxEngine(EngineConfig(model="llama3-1b", tp=4, kv_quantize="int8"))
