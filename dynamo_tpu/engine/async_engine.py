@@ -17,7 +17,7 @@ import time
 from typing import Any, AsyncIterator, Optional, Protocol
 
 from dynamo_tpu import telemetry
-from dynamo_tpu.engine.engine import JaxEngine
+from dynamo_tpu.engine.engine import JaxEngine, phase
 from dynamo_tpu.engine.request import SamplingParams, StepOutput
 from dynamo_tpu.engine.scheduler import QueueFullError
 from dynamo_tpu.preprocessor.preprocessor import PreprocessedRequest
@@ -221,16 +221,28 @@ class AsyncEngineRunner:
 
     def _emit(self, outputs) -> None:
         wd = self.watchdog
-        for out in outputs:
-            if wd is not None and out.new_token_ids:
-                # engine-side progress mark: a wedged engine thread stops
-                # exactly these, which is what the watchdog detects
-                wd.progress(out.request_id)
-            self._post(out.request_id, output_to_dict(out))
-            if out.finish_reason is not None:
-                if wd is not None:
-                    wd.done(out.request_id)
-                self._post(out.request_id, None)
+        with phase(
+            self.engine.metrics, "engine.emit", "time_emit_ms",
+            posted=len(outputs),
+        ):
+            for out in outputs:
+                if wd is not None and out.new_token_ids:
+                    # engine-side progress mark: a wedged engine thread
+                    # stops exactly these, which is what the watchdog
+                    # detects
+                    wd.progress(out.request_id)
+                self._post(out.request_id, output_to_dict(out))
+                if out.finish_reason is not None:
+                    if wd is not None:
+                        wd.done(out.request_id)
+                    self._post(out.request_id, None)
+
+    def _idle_wait(self) -> None:
+        """Nothing to run: sleep until woken (`engine.wait` in a capture:
+        device idle under it is nobody's fault)."""
+        with phase(None, "engine.wait"):
+            self._wake.wait(timeout=0.05)
+        self._wake.clear()
 
     def _add_pending(self, req, sampling) -> None:
         """Admit one queued request on the engine thread; a full waiting
@@ -309,19 +321,20 @@ class AsyncEngineRunner:
     def _run(self) -> None:
         eng = self.engine
         while not self._stop:
-            pending, aborts, ops = self._drain_inbox()
-            self._run_ops(ops)
-            for req, sampling in pending:
-                self._add_pending(req, sampling)
-            for rid in aborts:
-                eng.abort_request(rid)
-            self._expire_deadlines()
+            with phase(eng.metrics, "engine.intake", "time_intake_ms") as ph:
+                pending, aborts, ops = self._drain_inbox()
+                self._run_ops(ops)
+                for req, sampling in pending:
+                    self._add_pending(req, sampling)
+                for rid in aborts:
+                    eng.abort_request(rid)
+                self._expire_deadlines()
+                ph.note(added=len(pending))
             if not eng.has_work:
                 drain = getattr(eng, "drain_overlap", None)
                 if drain is not None:
                     drain()
-                self._wake.wait(timeout=0.05)
-                self._wake.clear()
+                self._idle_wait()
                 continue
             wd = self.watchdog
             if wd is not None:
@@ -532,31 +545,32 @@ class SpmdEngineRunner(AsyncEngineRunner):
         drv = self.driver
         eng = self.engine
         while not self._stop:
-            pending, aborts, ops = self._drain_inbox()
-            with self._lock:
-                clears, self._clears = self._clears, []
-            self._run_ops(ops)  # read-only by contract
-            submitted: list[str] = []
-            for req, sampling in pending:
-                if req.mm_embeds is not None:
-                    self._post(
-                        req.request_id,
-                        {
-                            "error": "multimodal requests are not "
-                            "supported on a cross-host SPMD group yet"
-                        },
-                    )
-                    self._post(req.request_id, None)
-                    continue
-                drv.submit(req.request_id, list(req.token_ids), sampling)
-                submitted.append(req.request_id)
-            for rid in aborts:
-                drv.abort(rid)
-            if clears:
-                drv.clear_cache()
+            with phase(eng.metrics, "engine.intake", "time_intake_ms") as ph:
+                pending, aborts, ops = self._drain_inbox()
+                with self._lock:
+                    clears, self._clears = self._clears, []
+                self._run_ops(ops)  # read-only by contract
+                submitted: list[str] = []
+                for req, sampling in pending:
+                    if req.mm_embeds is not None:
+                        self._post(
+                            req.request_id,
+                            {
+                                "error": "multimodal requests are not "
+                                "supported on a cross-host SPMD group yet"
+                            },
+                        )
+                        self._post(req.request_id, None)
+                        continue
+                    drv.submit(req.request_id, list(req.token_ids), sampling)
+                    submitted.append(req.request_id)
+                for rid in aborts:
+                    drv.abort(rid)
+                if clears:
+                    drv.clear_cache()
+                ph.note(added=len(submitted))
             if not (drv._pending or eng.has_work):
-                self._wake.wait(timeout=0.05)
-                self._wake.clear()
+                self._idle_wait()
                 continue
             try:
                 outputs = drv.step()

@@ -54,6 +54,8 @@ from dynamo_tpu.models.registry import ModelAdapter, get_model
 from dynamo_tpu.parallel.logical import default_rules
 from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 from dynamo_tpu.parallel.shardings import batch_spec, shardings_for
+from dynamo_tpu import telemetry
+from dynamo_tpu.telemetry import phases
 from dynamo_tpu.tokens import TokenBlockSequence
 
 logger = logging.getLogger(__name__)
@@ -154,15 +156,32 @@ class EngineMetrics:
     #: decode's phase split: dispatch = host array build + program
     #: launch (incl. any speculative next-step launch), sync = blocking
     #: on the sampled ids' device→host copy, host = the stop/finish
-    #: scan + page registration. The columns follow the DECODE ROWS
-    #: wherever they run: pure decode steps (where they sum to
-    #: ~time_decode_ms) and the decode half of mixed steps (whose step
-    #: wall time lands in time_mixed_ms instead). Under overlap_decode
-    #: the sync column collapses (the copy was started a step earlier)
-    #: — the overlap's visibility in bench.py extras.
+    #: scan + page registration. The dispatch column follows the DECODE
+    #: ROWS wherever they run: pure decode steps and the decode half of
+    #: mixed steps (whose step wall time lands in time_mixed_ms
+    #: instead); sync and host are the counters of the `engine.readback`
+    #: and `engine.postprocess` spans and so also take a prefill
+    #: dispatch's readback and first-token postprocess. Under
+    #: overlap_decode the sync column collapses (the copy was started a
+    #: step earlier) — the overlap's visibility in bench.py extras.
     time_decode_dispatch_ms: float = 0.0
     time_decode_sync_ms: float = 0.0
     time_decode_host_ms: float = 0.0
+    #: the rest of the loop, same clock and unit (cumulative host ms):
+    #: stage = numpy array build + host→device transfer of a dispatch's
+    #: inputs (the part of time_decode_dispatch_ms that is not the
+    #: program launch); intake = the runner's inbox drain (admissions,
+    #: aborts, deadlines) and emit = posting a step's outputs to the
+    #: request queues — both outside step(). Each is the counter of the
+    #: `engine.<phase>` span of the same name (`phase`, below).
+    time_stage_ms: float = 0.0
+    time_intake_ms: float = 0.0
+    time_emit_ms: float = 0.0
+    #: admission wait of EVERY admitted request (traced or not), summed
+    #: where the wait ends (scheduler._admit), and how many admissions
+    #: it is summed over: total / admissions = mean queue wait
+    queue_wait_ms_total: float = 0.0
+    admissions: int = 0
     #: program-launch counters. A mixed step normally launches ONE fused
     #: program (mixed_dispatches); its overlap split path launches the
     #: pure prefill program beside the consumed speculation, which also
@@ -247,6 +266,7 @@ class EngineMetrics:
         "time_mixed_ms",
         "time_decode_dispatch_ms", "time_decode_sync_ms",
         "time_decode_host_ms",
+        "time_stage_ms", "time_intake_ms", "time_emit_ms",
         "prefill_dispatches", "decode_dispatches", "mixed_dispatches",
         "overlap_dispatches", "overlap_hits", "overlap_rollbacks",
         "kstep_windows", "kstep_steps", "time_kstep_ms",
@@ -254,6 +274,47 @@ class EngineMetrics:
 
     def to_dict(self) -> dict:
         return dict(self.__dict__)
+
+
+class _Phase:
+    """One phase of the engine loop, measured once and shown twice: a
+    `jax.profiler.TraceAnnotation` span on the profiler's clock (a no-op
+    in C++ unless a capture is running — the engine's own `POST
+    /v1/debug/profile` or anybody's `jax.profiler.start_trace`), and the
+    elapsed host time added to the named cumulative-ms counters."""
+
+    __slots__ = ("_metrics", "_fields", "_span", "_t0")
+
+    def __init__(self, metrics, name: str, fields: tuple, args: dict):
+        self._metrics = metrics
+        self._fields = fields
+        self._span = jax.profiler.TraceAnnotation(name, **args)
+
+    def __enter__(self) -> "_Phase":
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def note(self, **args) -> None:
+        """Span args known only once the phase has run."""
+        self._span.set_metadata(**args)
+
+    def __exit__(self, *exc) -> bool:
+        dt_ms = (time.perf_counter() - self._t0) * 1000.0
+        self._span.__exit__(*exc)
+        m = self._metrics
+        if m is not None:
+            for f in self._fields:
+                setattr(m, f, getattr(m, f, 0.0) + dt_ms)
+        return False
+
+
+def phase(metrics, name: str, *fields: str, **args) -> _Phase:
+    """`with phase(metrics, "engine.stage", "time_stage_ms", kind=...)`:
+    THE way a loop phase is timed (docs/observability.md lists the spans
+    and their counters). `metrics` is an EngineMetrics, or None for an
+    engine double that keeps none."""
+    return _Phase(metrics, name, fields, args)
 
 
 @dataclass
@@ -836,11 +897,30 @@ class JaxEngine:
     def step(self) -> list[StepOutput]:
         if self._profile is not None:
             self._profile_start()  # armed capture opens BEFORE this step
-        t0 = time.perf_counter()
-        batch = self.scheduler.schedule()
-        t1 = time.perf_counter()
-        self.metrics.time_schedule_ms += (t1 - t0) * 1000.0
-        outputs = self._drain_doomed()
+        # the loop's phases (`phase`) are this span's children, so a trace
+        # viewer groups them by step
+        with jax.profiler.StepTraceAnnotation(
+            "engine.step", step_num=self.metrics.steps
+        ):
+            outputs, dispatched = self._step()
+        if self._profile is not None and dispatched:
+            # one dispatched step captured; counted after the step's span
+            # closed, or a capture that stops here would lose it
+            self._profile_count()
+        return outputs
+
+    def _step(self) -> tuple[list[StepOutput], bool]:
+        """One engine step: (outputs, whether a batch was dispatched)."""
+        with phase(
+            self.metrics, "engine.schedule", "time_schedule_ms"
+        ) as ph:
+            batch = self.scheduler.schedule()
+            outputs = self._drain_doomed()
+            ph.note(
+                kind=batch.kind if batch is not None else "none",
+                waiting=self.scheduler.num_waiting(),
+                running=self.scheduler.num_running(),
+            )
         if batch is None or batch.kind not in ("decode", "mixed"):
             # A speculated decode step can only be the next decode step
             # or the decode half of a mixed step; a pure prefill (or a
@@ -854,8 +934,6 @@ class JaxEngine:
             t2 = time.perf_counter()  # after the drain: phase time is
             # dispatch+sync+postprocess only, as the field docs promise
             gen0 = self.metrics.generated_tokens
-            from dynamo_tpu.telemetry import phases
-
             # Dispatch counters increment BEFORE the run so emissions
             # inside it record the post-step mark (the decode-stall
             # histogram compares marks across emissions).
@@ -890,6 +968,8 @@ class JaxEngine:
                 )
                 self._thru_window.append((time.perf_counter(), step_toks))
                 self._thru_tokens += step_toks
+            # taken every step, recorder or not, so the list stays short
+            admit_waits = self.scheduler.take_admit_waits()
             if self.flight is not None:
                 self.flight.record_step(
                     self.metrics,
@@ -916,9 +996,8 @@ class JaxEngine:
                         getattr(self.allocator, "watermark", 0),
                         self.metrics.kv_pages_watermark,
                     ),
+                    admit_wait_ms=admit_waits,
                 )
-        if self._profile is not None and batch is not None:
-            self._profile_count()  # one dispatched step captured
         if not self.scheduler.has_work:
             # the wave ended on a sampled stop the speculation couldn't
             # predict: drop any dangling dispatch so device arrays free
@@ -927,7 +1006,7 @@ class JaxEngine:
             if self._inflight_spec is not None:
                 self._discard_inflight_spec("idle")
         self._refresh_metrics()
-        return outputs
+        return outputs, batch is not None
 
     def _drain_doomed(self) -> list[StepOutput]:
         """Finish requests the scheduler proved can never progress (or
@@ -1005,10 +1084,18 @@ class JaxEngine:
         groups: dict[int, list] = {}
         for piece in batch.prefill:
             groups.setdefault(self._bucket_t(piece.length), []).append(piece)
-        mp = self.config.max_pages_per_seq
         for t_bucket, pieces in sorted(groups.items()):
-            b = len(pieces)
-            b_bucket = self._bucket_b(b)
+            outputs += self._run_prefill_group(t_bucket, pieces, mixed)
+        return outputs
+
+    def _run_prefill_group(
+        self, t_bucket: int, pieces: list, mixed: bool
+    ) -> list[StepOutput]:
+        """One [B, T] prefill dispatch: the pieces of one T bucket."""
+        m = self.metrics
+        mp = self.config.max_pages_per_seq
+        with phase(m, "engine.stage", "time_stage_ms"):
+            b_bucket = self._bucket_b(len(pieces))
             tokens = np.zeros((b_bucket, t_bucket), np.int32)
             positions = np.zeros((b_bucket, t_bucket), np.int32)
             valid = np.zeros((b_bucket, t_bucket), bool)
@@ -1046,7 +1133,7 @@ class JaxEngine:
             # hits — the common case) compiles a history-free program:
             # attention over the in-register chunk only, no page gather.
             first_chunk = all(p.start == 0 for p in pieces)
-            lp_data = None
+            lp = -1
             if any_last:
                 reqs = [p.request for p in pieces]
                 samp, all_greedy = self._sampling_arrays(reqs, pad_to=b_bucket)
@@ -1066,69 +1153,97 @@ class JaxEngine:
                 if bias:
                     host["bias"] = self._bias_arrays(reqs, b_bucket)
                 dev = self._dev_tree(host)
-                args = (self.params, *dev["base"][:3], self.kv,
-                        dev["base"][3])
                 fn = self._get_step_fn(
                     "prefill", b_bucket, t_bucket, greedy=all_greedy,
                     mm=any_mm, first_chunk=first_chunk, lp=lp, pen=pen,
                     bias=bias,
                 )
+                tail = (dev["last"], *dev["samp"], *dev["pen"])
                 # mm/bias ride as keywords: the positional tail of the
-                # shared step_fn signature belongs to the penalty args.
-                mm_kwargs = (
-                    {"mm_embeds": dev["mm"][0], "mm_mask": dev["mm"][1]}
-                    if any_mm
-                    else {}
-                )
-                bias_kwargs = dev.get("bias", {})
-                if lp >= 0:
-                    token_ids, lp_raw, self.kv = fn(
-                        *args, dev["last"], *dev["samp"], *dev["pen"],
-                        **bias_kwargs, **mm_kwargs
+                # shared step signature belongs to the penalty args.
+                kwargs = dict(dev.get("bias", {}))
+                if any_mm:
+                    kwargs.update(
+                        mm_embeds=dev["mm"][0], mm_mask=dev["mm"][1]
                     )
-                    lp_data = tuple(np.asarray(x) for x in lp_raw)
-                else:
-                    token_ids, self.kv = fn(
-                        *args, dev["last"], *dev["samp"], *dev["pen"],
-                        **bias_kwargs, **mm_kwargs
-                    )
-                ids = np.asarray(token_ids)
             else:
                 # No piece finishes its prompt: KV writes only — skip the
                 # vocab-sized logits + sampling entirely.
                 dev = self._dev_tree(host)
-                args = (self.params, *dev["base"][:3], self.kv,
-                        dev["base"][3])
                 fn = self._get_step_fn(
                     "prefill_nosample", b_bucket, t_bucket, mm=any_mm,
                     first_chunk=first_chunk,
                 )
-                self.kv = fn(*args, *dev.get("mm", ()))
-                ids = None
-            for i, piece in enumerate(pieces):
-                req = piece.request
-                req.num_computed_tokens += piece.length
-                self._register_pages(req)
-                if req.prefill_done:
-                    req.state = RequestState.DECODE
-                    lps = tops = None
-                    if lp_data is not None and req.sampling.logprobs >= 0:
-                        lps = (float(lp_data[0][i]),)
-                        nk = req.sampling.logprobs
-                        if nk > 0:
-                            tops = (
-                                tuple(
-                                    (int(lp_data[1][i, j]), float(lp_data[2][i, j]))
-                                    for j in range(min(nk, lp_data[1].shape[-1]))
-                                ),
-                            )
-                    outputs.extend(
-                        self._accept_token(
-                            req, int(ids[i]), first=True, lps=lps,
-                            tops=tops, mixed=mixed,
-                        )
-                    )
+                tail, kwargs = dev.get("mm", ()), {}
+            args = (self.params, *dev["base"][:3], self.kv, dev["base"][3])
+        with phase(
+            m, "engine.launch", kind="prefill", rows=b_bucket, t=t_bucket,
+            speculative=0,
+        ):
+            out = fn(*args, *tail, **kwargs)
+        ids = lp_data = None
+        if not any_last:
+            self.kv = out
+        else:
+            if lp >= 0:
+                token_ids, lp_raw, self.kv = out
+            else:
+                token_ids, self.kv = out
+            with phase(
+                m, "engine.readback", "time_decode_sync_ms", lagged=0
+            ):
+                if lp >= 0:
+                    lp_data = tuple(np.asarray(x) for x in lp_raw)
+                ids = np.asarray(token_ids)
+        outputs: list[StepOutput] = []
+        with phase(m, "engine.postprocess", "time_decode_host_ms") as ph:
+            self._prefill_postprocess(
+                pieces, ids, lp_data, 0, outputs, mixed
+            )
+            ph.note(tokens=len(outputs), finished=self._n_finished(outputs))
         return outputs
+
+    @staticmethod
+    def _n_finished(outputs: list[StepOutput]) -> int:
+        return sum(1 for o in outputs if o.finish_reason is not None)
+
+    def _prefill_postprocess(
+        self, pieces: list, ids, lp_data, row0: int,
+        outputs: list[StepOutput], mixed: bool,
+    ) -> None:
+        """Host half of a prefill dispatch: count the chunk computed,
+        register its filled pages and, where a piece completed its
+        prompt, accept the first token (row `row0 + i` of the sampled
+        ids / logprob arrays, the latter shaped [rows(, N)])."""
+        for i, piece in enumerate(pieces):
+            req = piece.request
+            req.num_computed_tokens += piece.length
+            self._register_pages(req)
+            if req.prefill_done:
+                req.state = RequestState.DECODE
+                lps = tops = None
+                row = row0 + i
+                if lp_data is not None and req.sampling.logprobs >= 0:
+                    lps = (float(lp_data[0][row]),)
+                    nk = req.sampling.logprobs
+                    if nk > 0:
+                        tops = (
+                            tuple(
+                                (
+                                    int(lp_data[1][row, j]),
+                                    float(lp_data[2][row, j]),
+                                )
+                                for j in range(
+                                    min(nk, lp_data[1].shape[-1])
+                                )
+                            ),
+                        )
+                outputs.extend(
+                    self._accept_token(
+                        req, int(ids[row]), first=True, lps=lps,
+                        tops=tops, mixed=mixed,
+                    )
+                )
 
     # -- decode ------------------------------------------------------------
 
@@ -1427,72 +1542,78 @@ class JaxEngine:
         if not self._grow_pages_for(reqs, s):
             return self._run_decode_plain(reqs)
 
-        t0 = time.perf_counter()
-        tokens = np.zeros((b_bucket, t), np.int32)
-        positions = np.zeros((b_bucket, t), np.int32)
-        valid = np.zeros((b_bucket, t), bool)
-        pt = np.zeros((b_bucket, mp), np.int32)
-        drafts = np.zeros((b_bucket, s), np.int32)
-        for i, req in enumerate(reqs):
-            d = self._propose_drafts(req, s)
-            drafts[i] = d
-            tokens[i, 0] = req.all_tokens[-1]
-            tokens[i, 1:] = d
-            positions[i] = np.arange(t, dtype=np.int32) + req.num_tokens - 1
-            valid[i] = True
-            pt[i, : len(req.pages)] = req.pages
-
-        fn = self._get_step_fn("spec_verify", b_bucket, t)
-        d_tokens, d_positions, d_valid, d_pt = self._dev_tree(
-            (tokens, positions, valid, pt)
-        )
-        target_ids, self.kv = fn(
-            self.params, d_tokens, d_positions, d_valid, self.kv, d_pt,
-        )
-        # timing parity with _run_decode_plain (flight-recorder deltas
+        m = self.metrics
+        # the same phases as _run_decode_plain (flight-recorder deltas
         # and the dispatch/sync/host split must not go blind under
         # speculation): array build + launch = dispatch, the blocking
         # device→host read = sync, the accept scan = host
-        self.metrics.time_decode_dispatch_ms += (
-            time.perf_counter() - t0
-        ) * 1000.0
-        t1 = time.perf_counter()
-        target = np.asarray(target_ids)  # [B, t]
-        self.metrics.time_decode_sync_ms += (
-            time.perf_counter() - t1
-        ) * 1000.0
-        t2 = time.perf_counter()
+        with phase(
+            m, "engine.stage", "time_stage_ms", "time_decode_dispatch_ms"
+        ):
+            tokens = np.zeros((b_bucket, t), np.int32)
+            positions = np.zeros((b_bucket, t), np.int32)
+            valid = np.zeros((b_bucket, t), bool)
+            pt = np.zeros((b_bucket, mp), np.int32)
+            drafts = np.zeros((b_bucket, s), np.int32)
+            for i, req in enumerate(reqs):
+                d = self._propose_drafts(req, s)
+                drafts[i] = d
+                tokens[i, 0] = req.all_tokens[-1]
+                tokens[i, 1:] = d
+                positions[i] = (
+                    np.arange(t, dtype=np.int32) + req.num_tokens - 1
+                )
+                valid[i] = True
+                pt[i, : len(req.pages)] = req.pages
+
+            fn = self._get_step_fn("spec_verify", b_bucket, t)
+            d_tokens, d_positions, d_valid, d_pt = self._dev_tree(
+                (tokens, positions, valid, pt)
+            )
+        with phase(
+            m, "engine.launch", "time_decode_dispatch_ms",
+            kind="spec_verify", rows=b_bucket, t=t, speculative=0,
+        ):
+            target_ids, self.kv = fn(
+                self.params, d_tokens, d_positions, d_valid, self.kv, d_pt,
+            )
+        with phase(m, "engine.readback", "time_decode_sync_ms", lagged=0):
+            target = np.asarray(target_ids)  # [B, t]
         outputs: list[StepOutput] = []
         step_drafted = step_accepted = 0
-        for i, req in enumerate(reqs):
-            accepted: list[int] = []
-            finish: Optional[FinishReason] = None
-            for j in range(t):
-                tok = int(target[i, j])
-                accepted.append(tok)
-                finish = self._finish_reason_for(req, tok, len(accepted))
-                if finish is not None:
-                    break
-                if j < s and int(drafts[i, j]) != tok:
-                    break  # draft diverged: the model's token still lands
-            step_drafted += s
-            step_accepted += len(accepted) - 1
-            req.num_computed_tokens += len(accepted)
-            outputs.extend(
-                self._accept_tokens(req, accepted, finish, spec=True)
+        with phase(m, "engine.postprocess", "time_decode_host_ms") as ph:
+            for i, req in enumerate(reqs):
+                accepted: list[int] = []
+                finish: Optional[FinishReason] = None
+                for j in range(t):
+                    tok = int(target[i, j])
+                    accepted.append(tok)
+                    finish = self._finish_reason_for(req, tok, len(accepted))
+                    if finish is not None:
+                        break
+                    if j < s and int(drafts[i, j]) != tok:
+                        break  # draft diverged: the model's token lands
+                step_drafted += s
+                step_accepted += len(accepted) - 1
+                req.num_computed_tokens += len(accepted)
+                outputs.extend(
+                    self._accept_tokens(req, accepted, finish, spec=True)
+                )
+                self._register_pages(req)
+            self._note_spec_step(step_drafted, step_accepted)
+            if (
+                step_drafted
+                and step_accepted / step_drafted
+                < self.config.spec_min_accept_rate
+            ):
+                # Lookup is missing on this workload: revert to fused
+                # multi-step decode for a while, then probe speculation
+                # again.
+                self._spec_cooldown = self.config.spec_cooldown_steps
+            ph.note(
+                tokens=step_accepted + len(reqs),
+                finished=self._n_finished(outputs),
             )
-            self._register_pages(req)
-        self._note_spec_step(step_drafted, step_accepted)
-        if (
-            step_drafted
-            and step_accepted / step_drafted < self.config.spec_min_accept_rate
-        ):
-            # Lookup is missing on this workload: revert to fused multi-
-            # step decode for a while, then probe speculation again.
-            self._spec_cooldown = self.config.spec_cooldown_steps
-        self.metrics.time_decode_host_ms += (
-            time.perf_counter() - t2
-        ) * 1000.0
         return outputs
 
     # -- speculative decode (draft model, fused on-device acceptance) ------
@@ -1608,65 +1729,68 @@ class JaxEngine:
                     inflight.counters_v0, greedy=inflight.greedy,
                     bias=inflight.bias,
                 )
-                t1 = time.perf_counter()
-                out = np.asarray(inflight.out_ids)
-                drafts = np.asarray(inflight.draft_ids)
-                n_acc = np.asarray(inflight.n_acc)
-                self.metrics.time_decode_sync_ms += (
-                    time.perf_counter() - t1
-                ) * 1000.0
+                with phase(
+                    self.metrics, "engine.readback", "time_decode_sync_ms",
+                    lagged=1,
+                ):
+                    out = np.asarray(inflight.out_ids)
+                    drafts = np.asarray(inflight.draft_ids)
+                    n_acc = np.asarray(inflight.n_acc)
                 return self._spec_postprocess(
                     reqs, out, drafts, n_acc, mixed=mixed
                 )
             self._inflight_spec = inflight
             self._discard_inflight_spec("decode batch changed")
-        t0 = time.perf_counter()
-        win_tokens = np.zeros((b_bucket, w), np.int32)
-        win_len = np.zeros(b_bucket, np.int32)
-        pos0 = np.zeros(b_bucket, np.int32)
-        pt = np.zeros((b_bucket, mp), np.int32)
-        for i, req in enumerate(reqs):
-            toks = req.all_tokens[req.spec_draft_pos :]
-            win_tokens[i, : len(toks)] = toks
-            win_len[i] = len(toks)
-            pos0[i] = req.spec_draft_pos
-            pt[i, : len(req.pages)] = req.pages
-        samp, all_greedy = self._sampling_arrays(reqs, pad_to=b_bucket)
-        pen = self._batch_penalty_bucket(reqs)
-        pen_args = self._penalty_arrays(reqs, b_bucket, pen) if pen else ()
-        bias = self._batch_bias(reqs)
-        bias_kwargs = self._bias_arrays(reqs, b_bucket) if bias else {}
-        host = {
-            "base": (win_tokens, win_len, pos0, pt),
-            "samp": samp, "pen": pen_args, "bias": bias_kwargs,
-        }
-        dev = self._dev_tree(host)
-        d_tokens, d_len, d_pos0, d_pt = dev["base"]
-        fn = self._get_step_fn(
-            "spec_fused", b_bucket, w, greedy=all_greedy, pen=pen,
-            bias=bias,
-        )
-        out_ids, draft_ids, n_acc, self.kv, self.draft_kv = fn(
-            self.params, self.draft_params, d_tokens, d_len, d_pos0,
-            self.kv, self.draft_kv, d_pt, *dev["samp"], *dev["pen"],
-            **dev["bias"],
-        )
-        self.metrics.time_decode_dispatch_ms += (
-            time.perf_counter() - t0
-        ) * 1000.0
+        m = self.metrics
+        with phase(
+            m, "engine.stage", "time_stage_ms", "time_decode_dispatch_ms"
+        ):
+            win_tokens = np.zeros((b_bucket, w), np.int32)
+            win_len = np.zeros(b_bucket, np.int32)
+            pos0 = np.zeros(b_bucket, np.int32)
+            pt = np.zeros((b_bucket, mp), np.int32)
+            for i, req in enumerate(reqs):
+                toks = req.all_tokens[req.spec_draft_pos :]
+                win_tokens[i, : len(toks)] = toks
+                win_len[i] = len(toks)
+                pos0[i] = req.spec_draft_pos
+                pt[i, : len(req.pages)] = req.pages
+            samp, all_greedy = self._sampling_arrays(reqs, pad_to=b_bucket)
+            pen = self._batch_penalty_bucket(reqs)
+            pen_args = (
+                self._penalty_arrays(reqs, b_bucket, pen) if pen else ()
+            )
+            bias = self._batch_bias(reqs)
+            bias_kwargs = self._bias_arrays(reqs, b_bucket) if bias else {}
+            host = {
+                "base": (win_tokens, win_len, pos0, pt),
+                "samp": samp, "pen": pen_args, "bias": bias_kwargs,
+            }
+            dev = self._dev_tree(host)
+            d_tokens, d_len, d_pos0, d_pt = dev["base"]
+            fn = self._get_step_fn(
+                "spec_fused", b_bucket, w, greedy=all_greedy, pen=pen,
+                bias=bias,
+            )
+        with phase(
+            m, "engine.launch", "time_decode_dispatch_ms",
+            kind="spec_fused", rows=b_bucket, t=w, speculative=0,
+        ):
+            out_ids, draft_ids, n_acc, self.kv, self.draft_kv = fn(
+                self.params, self.draft_params, d_tokens, d_len, d_pos0,
+                self.kv, self.draft_kv, d_pt, *dev["samp"], *dev["pen"],
+                **dev["bias"],
+            )
         # keep the device busy past this step BEFORE blocking on its
         # result (same discipline as _run_decode_plain)
         self._maybe_chain_spec(
             reqs, b_bucket, out_ids, n_acc, samp[4],
             greedy=all_greedy, bias=bias,
         )
-        t1 = time.perf_counter()
-        out = np.asarray(out_ids)
-        drafts = np.asarray(draft_ids)
-        n_acc_h = np.asarray(n_acc)
-        self.metrics.time_decode_sync_ms += (
-            time.perf_counter() - t1
-        ) * 1000.0
+        with phase(m, "engine.readback", "time_decode_sync_ms", lagged=0):
+            out = np.asarray(out_ids)
+            drafts = np.asarray(draft_ids)
+            n_acc_h = np.asarray(n_acc)
         return self._spec_postprocess(reqs, out, drafts, n_acc_h, mixed=mixed)
 
     def _spec_postprocess(
@@ -1679,60 +1803,68 @@ class JaxEngine:
         the canonical token at each position), plus chain validation: a
         finish/stop truncation the device could not see invalidates the
         chained next dispatch."""
-        t0 = time.perf_counter()
         s = self.config.spec_draft_tokens
         outputs: list[StepOutput] = []
         step_drafted = step_accepted = 0
-        chain = self._inflight_spec  # the dispatch chained for the NEXT step
-        chain_ok = chain is not None
-        for i, req in enumerate(reqs):
-            accepted: list[int] = []
-            finish: Optional[FinishReason] = None
-            for j in range(s + 1):
-                tok = int(out[i, j])
-                accepted.append(tok)
-                finish = self._finish_reason_for(req, tok, len(accepted))
-                if finish is not None:
-                    break
-                if j < s and int(drafts[i, j]) != tok:
-                    break
-            step_drafted += s
-            step_accepted += len(accepted) - 1
-            # catch-up committed through the old last token; the accepted
-            # tokens are the next step's window
-            req.spec_draft_pos = req.num_tokens
-            req.num_computed_tokens += len(accepted)
-            if finish is not None or len(accepted) != int(n_acc[i]):
-                chain_ok = False
-            outputs.extend(
-                self._accept_tokens(
-                    req, accepted, finish, mixed=mixed, spec=True
+        with phase(
+            self.metrics, "engine.postprocess", "time_decode_host_ms"
+        ) as ph:
+            chain = self._inflight_spec  # chained for the NEXT step
+            chain_ok = chain is not None
+            for i, req in enumerate(reqs):
+                accepted: list[int] = []
+                finish: Optional[FinishReason] = None
+                for j in range(s + 1):
+                    tok = int(out[i, j])
+                    accepted.append(tok)
+                    finish = self._finish_reason_for(
+                        req, tok, len(accepted)
+                    )
+                    if finish is not None:
+                        break
+                    if j < s and int(drafts[i, j]) != tok:
+                        break
+                step_drafted += s
+                step_accepted += len(accepted) - 1
+                # catch-up committed through the old last token; the
+                # accepted tokens are the next step's window
+                req.spec_draft_pos = req.num_tokens
+                req.num_computed_tokens += len(accepted)
+                if finish is not None or len(accepted) != int(n_acc[i]):
+                    chain_ok = False
+                outputs.extend(
+                    self._accept_tokens(
+                        req, accepted, finish, mixed=mixed, spec=True
+                    )
                 )
+                self._register_pages(req)
+            self._note_spec_step(step_drafted, step_accepted)
+            if chain is not None:
+                if chain_ok:
+                    chain.expected_num_tokens = tuple(
+                        r.num_tokens for r in reqs
+                    )
+                    chain.expected_out_len = tuple(
+                        len(r.output_tokens) for r in reqs
+                    )
+                else:
+                    self._discard_inflight_spec(
+                        "acceptance diverged or finish"
+                    )
+            if (
+                step_drafted
+                and step_accepted / step_drafted
+                < self.config.spec_min_accept_rate
+            ):
+                # the draft is missing on this workload: fall back to
+                # the plain (overlapped/fused) path for a while, then
+                # probe again
+                self._spec_cooldown = self.config.spec_cooldown_steps
+                self._discard_inflight_spec("acceptance cooldown")
+            ph.note(
+                tokens=step_accepted + len(reqs),
+                finished=self._n_finished(outputs),
             )
-            self._register_pages(req)
-        self._note_spec_step(step_drafted, step_accepted)
-        if chain is not None:
-            if chain_ok:
-                chain.expected_num_tokens = tuple(
-                    r.num_tokens for r in reqs
-                )
-                chain.expected_out_len = tuple(
-                    len(r.output_tokens) for r in reqs
-                )
-            else:
-                self._discard_inflight_spec("acceptance diverged or finish")
-        if (
-            step_drafted
-            and step_accepted / step_drafted
-            < self.config.spec_min_accept_rate
-        ):
-            # the draft is missing on this workload: fall back to the
-            # plain (overlapped/fused) path for a while, then probe again
-            self._spec_cooldown = self.config.spec_cooldown_steps
-            self._discard_inflight_spec("acceptance cooldown")
-        self.metrics.time_decode_host_ms += (
-            time.perf_counter() - t0
-        ) * 1000.0
         return outputs
 
     def _maybe_chain_spec(
@@ -1776,31 +1908,40 @@ class JaxEngine:
                 return
         if not self._grow_pages_for(reqs, 2 * s + 1):
             return
-        t0 = time.perf_counter()
-        mp = self.config.max_pages_per_seq
-        pos0 = np.zeros(b_bucket, np.int32)
-        pt = np.zeros((b_bucket, mp), np.int32)
-        for i, req in enumerate(reqs):
-            pos0[i] = req.num_tokens  # accepted tokens land at n, n+1, …
-            pt[i, : len(req.pages)] = req.pages
-        samp, _ = self._sampling_arrays(reqs, pad_to=b_bucket)
-        bias_kwargs = self._bias_arrays(reqs, b_bucket) if bias else {}
-        host = {"base": (pos0, pt), "samp": samp[:4], "bias": bias_kwargs}
-        dev = self._dev_tree(host)
-        d_pos0, d_pt = dev["base"]
-        # verify-start counters advance by the pending acceptance —
-        # a device add, no host round-trip
-        cv0 = jnp.asarray(counters_v0) + n_acc
-        fn = self._get_step_fn(
-            "spec_fused", b_bucket, w, greedy=greedy, pen=0, bias=bias,
-        )
-        out2, drafts2, nacc2, self.kv, self.draft_kv = fn(
-            self.params, self.draft_params, out_ids, n_acc, d_pos0,
-            self.kv, self.draft_kv, d_pt, *dev["samp"], cv0,
-            **dev["bias"],
-        )
-        for arr in (out2, drafts2, nacc2):
-            arr.copy_to_host_async()
+        m = self.metrics
+        with phase(
+            m, "engine.stage", "time_stage_ms", "time_decode_dispatch_ms"
+        ):
+            mp = self.config.max_pages_per_seq
+            pos0 = np.zeros(b_bucket, np.int32)
+            pt = np.zeros((b_bucket, mp), np.int32)
+            for i, req in enumerate(reqs):
+                pos0[i] = req.num_tokens  # accepted tokens land at n, n+1, …
+                pt[i, : len(req.pages)] = req.pages
+            samp, _ = self._sampling_arrays(reqs, pad_to=b_bucket)
+            bias_kwargs = self._bias_arrays(reqs, b_bucket) if bias else {}
+            host = {
+                "base": (pos0, pt), "samp": samp[:4], "bias": bias_kwargs,
+            }
+            dev = self._dev_tree(host)
+            d_pos0, d_pt = dev["base"]
+            fn = self._get_step_fn(
+                "spec_fused", b_bucket, w, greedy=greedy, pen=0, bias=bias,
+            )
+        with phase(
+            m, "engine.launch", "time_decode_dispatch_ms",
+            kind="spec_fused", rows=b_bucket, t=w, speculative=1,
+        ):
+            # verify-start counters advance by the pending acceptance —
+            # a device add, no host round-trip
+            cv0 = jnp.asarray(counters_v0) + n_acc
+            out2, drafts2, nacc2, self.kv, self.draft_kv = fn(
+                self.params, self.draft_params, out_ids, n_acc, d_pos0,
+                self.kv, self.draft_kv, d_pt, *dev["samp"], cv0,
+                **dev["bias"],
+            )
+            for arr in (out2, drafts2, nacc2):
+                arr.copy_to_host_async()
         self.metrics.overlap_dispatches += 1
         self._inflight_spec = _InflightSpec(
             reqs=tuple(reqs),
@@ -1812,9 +1953,6 @@ class JaxEngine:
             greedy=greedy,
             bias=bias,
         )
-        self.metrics.time_decode_dispatch_ms += (
-            time.perf_counter() - t0
-        ) * 1000.0
 
     def _spec_inflight_matches(
         self, inflight: _InflightSpec, reqs: list[Request]
@@ -1848,7 +1986,7 @@ class JaxEngine:
         inflight, self._inflight_spec = self._inflight_spec, None
         if inflight is None:
             return
-        self.metrics.overlap_rollbacks += 1
+        self._note_rollback(why)
         logger.debug("spec chain rollback: %s", why)
 
     def _run_decode(self, batch: ScheduledBatch) -> list[StepOutput]:
@@ -1872,96 +2010,78 @@ class JaxEngine:
                 return self._consume_inflight(inflight, mixed=mixed)
             self._inflight = inflight  # hand back for the metrics/log
             self._discard_inflight("decode batch changed")
-        t0 = time.perf_counter()
-        b_bucket = self.config.decode_bucket_for(len(reqs))
-        mp = self.config.max_pages_per_seq
-        # On-device K-step window first (config.decode_kstep): finish
-        # conditions evaluate ON DEVICE, so no overshoot compute past a
-        # stop; k_win == 1 falls through to the classic path (which is
-        # then bit-identical to a decode_kstep-free build).
-        k_win = self._pick_kstep(reqs)
-        k_steps = k_win if k_win > 1 else self._pick_decode_steps(reqs)
-        tokens = np.zeros((b_bucket, 1), np.int32)
-        positions = np.zeros((b_bucket, 1), np.int32)
-        valid = np.zeros((b_bucket, 1), bool)
-        pt = np.zeros((b_bucket, mp), np.int32)
-        for i, req in enumerate(reqs):
-            tokens[i, 0] = req.all_tokens[-1]
-            positions[i, 0] = req.num_tokens - 1
-            valid[i, 0] = True
-            pt[i, : len(req.pages)] = req.pages
+        m = self.metrics
+        t0 = time.perf_counter()  # a K-step window's wall: stage to sync
+        with phase(
+            m, "engine.stage", "time_stage_ms", "time_decode_dispatch_ms"
+        ):
+            b_bucket = self.config.decode_bucket_for(len(reqs))
+            mp = self.config.max_pages_per_seq
+            # On-device K-step window first (config.decode_kstep): finish
+            # conditions evaluate ON DEVICE, so no overshoot compute past
+            # a stop; k_win == 1 falls through to the classic path (which
+            # is then bit-identical to a decode_kstep-free build).
+            k_win = self._pick_kstep(reqs)
+            k_steps = k_win if k_win > 1 else self._pick_decode_steps(reqs)
+            tokens = np.zeros((b_bucket, 1), np.int32)
+            positions = np.zeros((b_bucket, 1), np.int32)
+            valid = np.zeros((b_bucket, 1), bool)
+            pt = np.zeros((b_bucket, mp), np.int32)
+            for i, req in enumerate(reqs):
+                tokens[i, 0] = req.all_tokens[-1]
+                positions[i, 0] = req.num_tokens - 1
+                valid[i, 0] = True
+                pt[i, : len(req.pages)] = req.pages
 
-        samp, all_greedy = self._sampling_arrays(reqs, pad_to=b_bucket)
-        lp = self._batch_logprobs(reqs)
-        pen = self._batch_penalty_bucket(reqs)
-        pen_args = (
-            self._penalty_arrays(reqs, b_bucket, pen) if pen else ()
-        )
-        bias = self._batch_bias(reqs)
-        bias_kwargs = self._bias_arrays(reqs, b_bucket) if bias else {}
-        host = {
-            "base": (tokens, positions, valid, pt), "samp": samp,
-            "pen": pen_args, "bias": bias_kwargs,
-        }
-        if k_win > 1:
-            host["stops"], host["budgets"] = self._kstep_arrays(
-                reqs, b_bucket
+            samp, all_greedy = self._sampling_arrays(reqs, pad_to=b_bucket)
+            lp = self._batch_logprobs(reqs)
+            pen = self._batch_penalty_bucket(reqs)
+            pen_args = (
+                self._penalty_arrays(reqs, b_bucket, pen) if pen else ()
             )
-        elif k_steps == 1:
-            host["last"] = np.zeros(b_bucket, np.int32)
-        dev = self._dev_tree(host)
-        samp, pen_args, bias_kwargs = dev["samp"], dev["pen"], dev["bias"]
-        d_tokens, d_positions, d_valid, d_pt = dev["base"]
-        args = (self.params, d_tokens, d_positions, d_valid, self.kv, d_pt)
+            bias = self._batch_bias(reqs)
+            bias_kwargs = self._bias_arrays(reqs, b_bucket) if bias else {}
+            host = {
+                "base": (tokens, positions, valid, pt), "samp": samp,
+                "pen": pen_args, "bias": bias_kwargs,
+            }
+            if k_win > 1:
+                host["stops"], host["budgets"] = self._kstep_arrays(
+                    reqs, b_bucket
+                )
+            elif k_steps == 1:
+                host["last"] = np.zeros(b_bucket, np.int32)
+            dev = self._dev_tree(host)
+            args = (self.params, *dev["base"][:3], self.kv, dev["base"][3])
+            # logprobs rows never reach a K-step window (_pick_kstep
+            # falls back), so that family has no lp variant
+            kind, fn = self._decode_program(
+                b_bucket, k_steps, kstep=k_win > 1, greedy=all_greedy,
+                lp=lp, pen=pen, bias=bias,
+            )
+            if kind == "decode_kstep":
+                head = (dev["stops"], dev["budgets"])
+            else:
+                head = (dev["last"],) if kind == "decode" else ()
         lp_data = None
         n_emit_dev = None
+        with phase(
+            m, "engine.launch", "time_decode_dispatch_ms", kind=kind,
+            rows=b_bucket, k=k_steps, speculative=0,
+        ):
+            out = fn(
+                *args, *head, *dev["samp"], *dev["pen"], **dev["bias"]
+            )
         if k_win > 1:
-            # logprobs rows never reach here (_pick_kstep falls back),
-            # so the family has no lp variant
-            fn = self._get_step_fn(
-                "decode_kstep", b_bucket, k_steps, greedy=all_greedy,
-                lp=-1, pen=pen, bias=bias,
-            )
-            token_ids, n_emit_dev, self.kv = fn(
-                *args, dev["stops"], dev["budgets"], *samp, *pen_args,
-                **bias_kwargs,
-            )
-            m = self.metrics
+            token_ids, n_emit_dev, self.kv = out
             m.kstep_windows += 1
             m.kstep_steps += k_steps
             m.kstep_window_size = k_steps
             self._kstep_live = k_steps
-        elif k_steps == 1:
-            fn = self._get_step_fn(
-                "decode", b_bucket, 1, greedy=all_greedy, lp=lp, pen=pen,
-                bias=bias,
-            )
-            if lp >= 0:
-                token_ids, lp_data, self.kv = fn(
-                    *args, dev["last"], *samp, *pen_args,
-                    **bias_kwargs,
-                )
-            else:
-                token_ids, self.kv = fn(
-                    *args, dev["last"], *samp, *pen_args,
-                    **bias_kwargs,
-                )
+        elif lp >= 0:
+            token_ids, lp_data, self.kv = out
         else:
-            fn = self._get_step_fn(
-                "decode_multi", b_bucket, k_steps, greedy=all_greedy, lp=lp,
-                pen=pen, bias=bias,
-            )
-            if lp >= 0:
-                token_ids, lp_data, self.kv = fn(
-                    *args, *samp, *pen_args, **bias_kwargs
-                )
-            else:
-                token_ids, self.kv = fn(
-                    *args, *samp, *pen_args, **bias_kwargs
-                )  # [K, B]
-        self.metrics.time_decode_dispatch_ms += (
-            time.perf_counter() - t0
-        ) * 1000.0
+            token_ids, self.kv = out  # [B], or [K, B] when fused
         # Keep the device busy past this step BEFORE blocking on its
         # result: the speculated N+1 dispatch computes while the host
         # scans this step's ids for stops below.
@@ -1969,12 +2089,9 @@ class JaxEngine:
             reqs, b_bucket, k_steps, token_ids,
             greedy=all_greedy, lp=lp, bias=bias, kstep=k_win > 1,
         )
-        t1 = time.perf_counter()
-        ids = np.asarray(token_ids).reshape(k_steps, b_bucket)
-        lp_arrays = self._materialize_lp(lp_data, k_steps, b_bucket)
-        self.metrics.time_decode_sync_ms += (
-            time.perf_counter() - t1
-        ) * 1000.0
+        with phase(m, "engine.readback", "time_decode_sync_ms", lagged=0):
+            ids = np.asarray(token_ids).reshape(k_steps, b_bucket)
+            lp_arrays = self._materialize_lp(lp_data, k_steps, b_bucket)
         if k_win > 1:
             # window wall (dispatch+sync) is the measured column for the
             # decode_kstep family's attainment; /k is the per-step time
@@ -2001,6 +2118,21 @@ class JaxEngine:
             reqs, k_steps, ids, lp_arrays, mixed=mixed
         )
 
+    def _decode_program(
+        self, b_bucket: int, k_steps: int, kstep: bool, greedy: bool,
+        lp: int, pen: int, bias: bool,
+    ) -> tuple[str, Callable]:
+        """(kind, program) of a pure decode dispatch of `k_steps` steps:
+        an on-device K-step window, one step, or the fused scan."""
+        if kstep:
+            kind, lp = "decode_kstep", -1
+        else:
+            kind = "decode" if k_steps == 1 else "decode_multi"
+        return kind, self._get_step_fn(
+            kind, b_bucket, k_steps, greedy=greedy, lp=lp, pen=pen,
+            bias=bias,
+        )
+
     @staticmethod
     def _materialize_lp(lp_data, k_steps: int, b_bucket: int):
         """Device logprob outputs -> host (chosen, top_ids, top_lps),
@@ -2021,42 +2153,49 @@ class JaxEngine:
         conditions (dropping overshoot past a stop), append accepted
         tokens, and register newly filled pages. Under overlap_decode
         this runs while the device computes the NEXT step."""
-        t0 = time.perf_counter()
         outputs: list[StepOutput] = []
-        for i, req in enumerate(reqs):
-            accepted: list[int] = []
-            finish: Optional[FinishReason] = None
-            for kk in range(k_steps):
-                accepted.append(int(ids[kk, i]))
-                finish = self._finish_reason_for(req, int(ids[kk, i]),
-                                                 len(accepted))
-                if finish is not None:
-                    break
-            req.num_computed_tokens += len(accepted)
-            lps = tops = None
-            if lp_arrays is not None and req.sampling.logprobs >= 0:
-                chosen_lp, top_ids, top_lps = lp_arrays
-                n = len(accepted)
-                lps = tuple(float(chosen_lp[kk, i]) for kk in range(n))
-                nk = req.sampling.logprobs
-                if nk > 0:
-                    tops = tuple(
-                        tuple(
-                            (int(top_ids[kk, i, j]), float(top_lps[kk, i, j]))
-                            for j in range(min(nk, top_ids.shape[-1]))
-                        )
-                        for kk in range(n)
+        n_tokens = n_finished = 0
+        with phase(
+            self.metrics, "engine.postprocess", "time_decode_host_ms"
+        ) as ph:
+            for i, req in enumerate(reqs):
+                accepted: list[int] = []
+                finish: Optional[FinishReason] = None
+                for kk in range(k_steps):
+                    accepted.append(int(ids[kk, i]))
+                    finish = self._finish_reason_for(
+                        req, int(ids[kk, i]), len(accepted)
                     )
-            outputs.extend(
-                self._accept_tokens(
-                    req, accepted, finish, lps=lps, tops=tops, mixed=mixed,
-                    kstep=kstep,
+                    if finish is not None:
+                        n_finished += 1
+                        break
+                n_tokens += len(accepted)
+                req.num_computed_tokens += len(accepted)
+                lps = tops = None
+                if lp_arrays is not None and req.sampling.logprobs >= 0:
+                    chosen_lp, top_ids, top_lps = lp_arrays
+                    n = len(accepted)
+                    lps = tuple(float(chosen_lp[kk, i]) for kk in range(n))
+                    nk = req.sampling.logprobs
+                    if nk > 0:
+                        tops = tuple(
+                            tuple(
+                                (
+                                    int(top_ids[kk, i, j]),
+                                    float(top_lps[kk, i, j]),
+                                )
+                                for j in range(min(nk, top_ids.shape[-1]))
+                            )
+                            for kk in range(n)
+                        )
+                outputs.extend(
+                    self._accept_tokens(
+                        req, accepted, finish, lps=lps, tops=tops,
+                        mixed=mixed, kstep=kstep,
+                    )
                 )
-            )
-            self._register_pages(req)
-        self.metrics.time_decode_host_ms += (
-            time.perf_counter() - t0
-        ) * 1000.0
+                self._register_pages(req)
+            ph.note(tokens=n_tokens, finished=n_finished)
         return outputs
 
     # -- mixed prefill+decode steps ----------------------------------------
@@ -2139,107 +2278,108 @@ class JaxEngine:
             )
         pieces = fuse_pieces
 
-        t0 = time.perf_counter()
-        b_dec = self.config.decode_bucket_for(len(reqs_d))
-        mp = self.config.max_pages_per_seq
-        # decode half: identical arrays to a k=1 decode step
-        d_tokens = np.zeros((b_dec, 1), np.int32)
-        d_positions = np.zeros((b_dec, 1), np.int32)
-        d_valid = np.zeros((b_dec, 1), bool)
-        d_pt = np.zeros((b_dec, mp), np.int32)
-        for i, req in enumerate(reqs_d):
-            d_tokens[i, 0] = req.all_tokens[-1]
-            d_positions[i, 0] = req.num_tokens - 1
-            d_valid[i, 0] = True
-            d_pt[i, : len(req.pages)] = req.pages
-        # prefill half: one T-bucket group per fused program keeps the
-        # compile family at (b_decode_bucket, t_prefill_bucket,
-        # b_prefill_bucket)
-        b_pre = self._bucket_b(len(pieces))
-        p_tokens = np.zeros((b_pre, t_bucket), np.int32)
-        p_positions = np.zeros((b_pre, t_bucket), np.int32)
-        p_valid = np.zeros((b_pre, t_bucket), bool)
-        p_pt = np.zeros((b_pre, mp), np.int32)
-        last_idx = np.zeros(b_pre, np.int32)
-        any_last = False
-        for i, piece in enumerate(pieces):
-            req = piece.request
-            chunk = req.all_tokens[piece.start : piece.start + piece.length]
-            p_tokens[i, : piece.length] = chunk
-            p_positions[i] = np.arange(t_bucket, dtype=np.int32) + piece.start
-            p_valid[i, : piece.length] = True
-            p_pt[i, : len(req.pages)] = req.pages
-            last_idx[i] = piece.length - 1
-            if piece.start + piece.length >= len(req.prompt_tokens):
-                any_last = True
-        first_chunk = all(p.start == 0 for p in pieces)
-        # sampled row space: decode rows [0, b_dec); when a piece
-        # completes its prompt, prefill rows join at [b_dec, b_dec+b_pre)
-        pre_reqs = [p.request for p in pieces]
-        samp_d, greedy_d = self._sampling_arrays(reqs_d, pad_to=b_dec)
-        if any_last:
-            samp_p, greedy_p = self._sampling_arrays(pre_reqs, pad_to=b_pre)
-            samp = tuple(
-                np.concatenate([a, b]) for a, b in zip(samp_d, samp_p)
-            )
-            all_greedy = greedy_d and greedy_p
-            row_reqs = reqs_d + pre_reqs
-        else:
-            samp, all_greedy, row_reqs = samp_d, greedy_d, reqs_d
-        lp = self._batch_logprobs(row_reqs)
-        pen = self._batch_penalty_bucket(row_reqs)
-        if pen:
-            pen_d = self._penalty_arrays(reqs_d, b_dec, pen)
+        m = self.metrics
+        with phase(
+            m, "engine.stage", "time_stage_ms", "time_decode_dispatch_ms"
+        ):
+            b_dec = self.config.decode_bucket_for(len(reqs_d))
+            mp = self.config.max_pages_per_seq
+            # decode half: identical arrays to a k=1 decode step
+            d_tokens = np.zeros((b_dec, 1), np.int32)
+            d_positions = np.zeros((b_dec, 1), np.int32)
+            d_valid = np.zeros((b_dec, 1), bool)
+            d_pt = np.zeros((b_dec, mp), np.int32)
+            for i, req in enumerate(reqs_d):
+                d_tokens[i, 0] = req.all_tokens[-1]
+                d_positions[i, 0] = req.num_tokens - 1
+                d_valid[i, 0] = True
+                d_pt[i, : len(req.pages)] = req.pages
+            # prefill half: one T-bucket group per fused program keeps the
+            # compile family at (b_decode_bucket, t_prefill_bucket,
+            # b_prefill_bucket)
+            b_pre = self._bucket_b(len(pieces))
+            p_tokens = np.zeros((b_pre, t_bucket), np.int32)
+            p_positions = np.zeros((b_pre, t_bucket), np.int32)
+            p_valid = np.zeros((b_pre, t_bucket), bool)
+            p_pt = np.zeros((b_pre, mp), np.int32)
+            last_idx = np.zeros(b_pre, np.int32)
+            any_last = False
+            for i, piece in enumerate(pieces):
+                req = piece.request
+                chunk = req.all_tokens[piece.start : piece.start + piece.length]
+                p_tokens[i, : piece.length] = chunk
+                p_positions[i] = np.arange(t_bucket, dtype=np.int32) + piece.start
+                p_valid[i, : piece.length] = True
+                p_pt[i, : len(req.pages)] = req.pages
+                last_idx[i] = piece.length - 1
+                if piece.start + piece.length >= len(req.prompt_tokens):
+                    any_last = True
+            first_chunk = all(p.start == 0 for p in pieces)
+            # sampled row space: decode rows [0, b_dec); when a piece
+            # completes its prompt, prefill rows join at [b_dec, b_dec+b_pre)
+            pre_reqs = [p.request for p in pieces]
+            samp_d, greedy_d = self._sampling_arrays(reqs_d, pad_to=b_dec)
             if any_last:
-                pen_p = self._penalty_arrays(pre_reqs, b_pre, pen)
-                pen_args = tuple(
-                    np.concatenate([a, b]) for a, b in zip(pen_d, pen_p)
+                samp_p, greedy_p = self._sampling_arrays(pre_reqs, pad_to=b_pre)
+                samp = tuple(
+                    np.concatenate([a, b]) for a, b in zip(samp_d, samp_p)
                 )
+                all_greedy = greedy_d and greedy_p
+                row_reqs = reqs_d + pre_reqs
             else:
-                pen_args = pen_d
-        else:
-            pen_args = ()
-        bias = self._batch_bias(row_reqs)
-        if bias:
-            bias_d = self._bias_arrays(reqs_d, b_dec)
-            if any_last:
-                bias_p = self._bias_arrays(pre_reqs, b_pre)
-                bias_kwargs = {
-                    k: np.concatenate([bias_d[k], bias_p[k]]) for k in bias_d
-                }
+                samp, all_greedy, row_reqs = samp_d, greedy_d, reqs_d
+            lp = self._batch_logprobs(row_reqs)
+            pen = self._batch_penalty_bucket(row_reqs)
+            if pen:
+                pen_d = self._penalty_arrays(reqs_d, b_dec, pen)
+                if any_last:
+                    pen_p = self._penalty_arrays(pre_reqs, b_pre, pen)
+                    pen_args = tuple(
+                        np.concatenate([a, b]) for a, b in zip(pen_d, pen_p)
+                    )
+                else:
+                    pen_args = pen_d
             else:
-                bias_kwargs = bias_d
-        else:
-            bias_kwargs = {}
+                pen_args = ()
+            bias = self._batch_bias(row_reqs)
+            if bias:
+                bias_d = self._bias_arrays(reqs_d, b_dec)
+                if any_last:
+                    bias_p = self._bias_arrays(pre_reqs, b_pre)
+                    bias_kwargs = {
+                        k: np.concatenate([bias_d[k], bias_p[k]]) for k in bias_d
+                    }
+                else:
+                    bias_kwargs = bias_d
+            else:
+                bias_kwargs = {}
 
-        host = {
-            "based": (d_tokens, d_positions, d_valid, d_pt),
-            "basep": (p_tokens, p_positions, p_valid, p_pt),
-            "last": last_idx, "samp": samp, "pen": pen_args,
-            "bias": bias_kwargs,
-        }
-        dev = self._dev_tree(host)
-        fn = self._get_step_fn(
-            "mixed", b_dec, t_bucket, greedy=all_greedy,
-            first_chunk=first_chunk, lp=lp, pen=pen, bias=bias,
-            b_pre=b_pre, psamp=any_last,
-        )
-        args = (
-            self.params, *dev["based"][:3], self.kv, dev["based"][3],
-            *dev["basep"], dev["last"],
-        )
+            host = {
+                "based": (d_tokens, d_positions, d_valid, d_pt),
+                "basep": (p_tokens, p_positions, p_valid, p_pt),
+                "last": last_idx, "samp": samp, "pen": pen_args,
+                "bias": bias_kwargs,
+            }
+            dev = self._dev_tree(host)
+            fn = self._get_step_fn(
+                "mixed", b_dec, t_bucket, greedy=all_greedy,
+                first_chunk=first_chunk, lp=lp, pen=pen, bias=bias,
+                b_pre=b_pre, psamp=any_last,
+            )
+            args = (
+                self.params, *dev["based"][:3], self.kv, dev["based"][3],
+                *dev["basep"], dev["last"],
+            )
+        with phase(
+            m, "engine.launch", "time_decode_dispatch_ms", kind="mixed",
+            rows=b_dec, t=t_bucket, k=1, speculative=0,
+        ):
+            out = fn(*args, *dev["samp"], *dev["pen"], **dev["bias"])
         lp_data = None
         if lp >= 0:
-            token_ids, lp_data, self.kv = fn(
-                *args, *dev["samp"], *dev["pen"], **dev["bias"]
-            )
+            token_ids, lp_data, self.kv = out
         else:
-            token_ids, self.kv = fn(
-                *args, *dev["samp"], *dev["pen"], **dev["bias"]
-            )
-        self.metrics.time_decode_dispatch_ms += (
-            time.perf_counter() - t0
-        ) * 1000.0
+            token_ids, self.kv = out
         if not any_last:
             # No piece joins decode this step, so the decode rows are
             # stable: keep the pipeline primed — the speculated dispatch
@@ -2248,47 +2388,25 @@ class JaxEngine:
                 reqs_d, b_dec, 1, token_ids,
                 greedy=greedy_d, lp=lp, bias=bias,
             )
-        t1 = time.perf_counter()
-        ids = np.asarray(token_ids)  # [b_dec] or [b_dec + b_pre]
-        lp_arrays = self._materialize_lp(lp_data, 1, ids.shape[0])
-        self.metrics.time_decode_sync_ms += (
-            time.perf_counter() - t1
-        ) * 1000.0
-        d_lp = None
+        with phase(m, "engine.readback", "time_decode_sync_ms", lagged=0):
+            ids = np.asarray(token_ids)  # [b_dec] or [b_dec + b_pre]
+            lp_arrays = self._materialize_lp(lp_data, 1, ids.shape[0])
+        d_lp = p_lp = None
         if lp_arrays is not None:
             d_lp = tuple(a[:, :b_dec] for a in lp_arrays)
+            p_lp = tuple(a[0] for a in lp_arrays)
         outputs = outputs_rest + self._decode_postprocess(
             reqs_d, 1, ids[None, :b_dec], d_lp, mixed=True
         )
-        for i, piece in enumerate(pieces):
-            req = piece.request
-            req.num_computed_tokens += piece.length
-            self._register_pages(req)
-            if req.prefill_done:
-                req.state = RequestState.DECODE
-                lps = tops = None
-                if lp_arrays is not None and req.sampling.logprobs >= 0:
-                    row = b_dec + i
-                    lps = (float(lp_arrays[0][0, row]),)
-                    nk = req.sampling.logprobs
-                    if nk > 0:
-                        tops = (
-                            tuple(
-                                (
-                                    int(lp_arrays[1][0, row, j]),
-                                    float(lp_arrays[2][0, row, j]),
-                                )
-                                for j in range(
-                                    min(nk, lp_arrays[1].shape[-1])
-                                )
-                            ),
-                        )
-                outputs.extend(
-                    self._accept_token(
-                        req, int(ids[b_dec + i]), first=True, lps=lps,
-                        tops=tops, mixed=True,
-                    )
-                )
+        with phase(m, "engine.postprocess", "time_decode_host_ms") as ph:
+            n0 = len(outputs)
+            self._prefill_postprocess(
+                pieces, ids, p_lp, b_dec, outputs, mixed=True
+            )
+            ph.note(
+                tokens=len(outputs) - n0,
+                finished=self._n_finished(outputs[n0:]),
+            )
         return outputs
 
     # -- overlapped decode (one-step-lagged readback) ----------------------
@@ -2342,91 +2460,78 @@ class JaxEngine:
         k_next = self._pow2_floor(k_next)  # reuse the program family
         if not self._grow_pages_for(reqs, k_prev + k_next - 1):
             return
-        t0 = time.perf_counter()
-        mp = self.config.max_pages_per_seq
-        positions = np.zeros((b_bucket, 1), np.int32)
-        valid = np.zeros((b_bucket, 1), bool)
-        pt = np.zeros((b_bucket, mp), np.int32)
-        for i, req in enumerate(reqs):
-            positions[i, 0] = req.num_tokens - 1 + k_prev
-            valid[i, 0] = True
-            pt[i, : len(req.pages)] = req.pages
-        samp, _ = self._sampling_arrays(reqs, pad_to=b_bucket)
-        # the pending step advances every draw counter by its k
-        samp[4][: len(reqs)] += k_prev
-        bias_kwargs = self._bias_arrays(reqs, b_bucket) if bias else {}
-        host = {
-            "base": (positions, valid, pt), "samp": samp,
-            "bias": bias_kwargs,
-        }
-        use_kstep = kstep and k_next > 1
-        if use_kstep:
-            # chain the next K-window through the SAME decode_kstep
-            # family: budgets discount the pending window's k_prev
-            # tokens (the early-outs above already guarantee no row
-            # LENGTH-finishes inside the pending window; a sampled stop
-            # still rolls the chained window back at consume time)
-            host["stops"], host["budgets"] = self._kstep_arrays(
-                reqs, b_bucket, emitted_ahead=k_prev
-            )
-        elif k_next == 1:
-            host["last"] = np.zeros(b_bucket, np.int32)
-        dev = self._dev_tree(host)
-        d_positions, d_valid, d_pt = dev["base"]
-        # on-device token feedback: [B] or [K, B] -> last step [B, 1]
-        d_tokens = (
-            ids_dev if ids_dev.ndim == 2 else ids_dev[None]
-        )[-1][:, None].astype(jnp.int32)
-        args = (
-            self.params, d_tokens, d_positions, d_valid, self.kv, d_pt
-        )
-        lp_data = None
-        if use_kstep:
-            # kstep eligibility pinned lp == -1 at the original
-            # dispatch; the chained window inherits it
-            fn = self._get_step_fn(
-                "decode_kstep", b_bucket, k_next, greedy=greedy,
-                lp=-1, pen=0, bias=bias,
-            )
-            token_ids, _n_emit, self.kv = fn(
-                *args, dev["stops"], dev["budgets"], *dev["samp"],
-                **dev["bias"]
-            )
-            m = self.metrics
-            m.kstep_windows += 1
-            m.kstep_steps += k_next
-            m.kstep_window_size = k_next
-            self._kstep_live = k_next
-        elif k_next == 1:
-            fn = self._get_step_fn(
-                "decode", b_bucket, 1, greedy=greedy, lp=lp, pen=0,
-                bias=bias,
-            )
-            if lp >= 0:
-                token_ids, lp_data, self.kv = fn(
-                    *args, dev["last"], *dev["samp"], **dev["bias"]
+        m = self.metrics
+        with phase(
+            m, "engine.stage", "time_stage_ms", "time_decode_dispatch_ms"
+        ):
+            mp = self.config.max_pages_per_seq
+            positions = np.zeros((b_bucket, 1), np.int32)
+            valid = np.zeros((b_bucket, 1), bool)
+            pt = np.zeros((b_bucket, mp), np.int32)
+            for i, req in enumerate(reqs):
+                positions[i, 0] = req.num_tokens - 1 + k_prev
+                valid[i, 0] = True
+                pt[i, : len(req.pages)] = req.pages
+            samp, _ = self._sampling_arrays(reqs, pad_to=b_bucket)
+            # the pending step advances every draw counter by its k
+            samp[4][: len(reqs)] += k_prev
+            bias_kwargs = self._bias_arrays(reqs, b_bucket) if bias else {}
+            host = {
+                "base": (positions, valid, pt), "samp": samp,
+                "bias": bias_kwargs,
+            }
+            use_kstep = kstep and k_next > 1
+            if use_kstep:
+                # chain the next K-window through the SAME decode_kstep
+                # family: budgets discount the pending window's k_prev
+                # tokens (the early-outs above already guarantee no row
+                # LENGTH-finishes inside the pending window; a sampled
+                # stop still rolls the chained window back at consume
+                # time)
+                host["stops"], host["budgets"] = self._kstep_arrays(
+                    reqs, b_bucket, emitted_ahead=k_prev
                 )
-            else:
-                token_ids, self.kv = fn(
-                    *args, dev["last"], *dev["samp"], **dev["bias"]
-                )
-        else:
-            fn = self._get_step_fn(
-                "decode_multi", b_bucket, k_next, greedy=greedy, lp=lp,
+            elif k_next == 1:
+                host["last"] = np.zeros(b_bucket, np.int32)
+            dev = self._dev_tree(host)
+            d_positions, d_valid, d_pt = dev["base"]
+            # kstep eligibility pinned lp == -1 at the original dispatch;
+            # the chained window inherits it
+            kind, fn = self._decode_program(
+                b_bucket, k_next, kstep=use_kstep, greedy=greedy, lp=lp,
                 pen=0, bias=bias,
             )
-            if lp >= 0:
-                token_ids, lp_data, self.kv = fn(
-                    *args, *dev["samp"], **dev["bias"]
-                )
+            if use_kstep:
+                head = (dev["stops"], dev["budgets"])
             else:
-                token_ids, self.kv = fn(
-                    *args, *dev["samp"], **dev["bias"]
-                )
-        # one-step-lagged readback: start the device→host copy now so the
-        # next step's sync finds the bytes already landed
-        for arr in (token_ids, *(lp_data or ())):
-            arr.copy_to_host_async()
+                head = (dev["last"],) if k_next == 1 else ()
+        lp_data = None
+        with phase(
+            m, "engine.launch", "time_decode_dispatch_ms", kind=kind,
+            rows=b_bucket, k=k_next, speculative=1,
+        ):
+            # on-device token feedback: [B] or [K, B] -> last step [B, 1]
+            d_tokens = (
+                ids_dev if ids_dev.ndim == 2 else ids_dev[None]
+            )[-1][:, None].astype(jnp.int32)
+            out = fn(
+                self.params, d_tokens, d_positions, d_valid, self.kv, d_pt,
+                *head, *dev["samp"], **dev["bias"],
+            )
+            if use_kstep:
+                token_ids, _n_emit, self.kv = out
+                m.kstep_windows += 1
+                m.kstep_steps += k_next
+                m.kstep_window_size = k_next
+                self._kstep_live = k_next
+            elif lp >= 0:
+                token_ids, lp_data, self.kv = out
+            else:
+                token_ids, self.kv = out
+            # one-step-lagged readback: start the device→host copy now so
+            # the next step's sync finds the bytes already landed
+            for arr in (token_ids, *(lp_data or ())):
+                arr.copy_to_host_async()
         self.metrics.overlap_dispatches += 1
         self._inflight = _InflightDecode(
             reqs=tuple(reqs),
@@ -2443,9 +2548,6 @@ class JaxEngine:
             bias=bias,
             kstep=use_kstep,
         )
-        self.metrics.time_decode_dispatch_ms += (
-            time.perf_counter() - t0
-        ) * 1000.0
 
     def _inflight_matches(
         self, inflight: _InflightDecode, reqs: list[Request]
@@ -2483,16 +2585,15 @@ class JaxEngine:
             greedy=inflight.greedy, lp=inflight.lp, bias=inflight.bias,
             kstep=inflight.kstep,
         )
-        t0 = time.perf_counter()
-        ids = np.asarray(inflight.token_ids).reshape(
-            inflight.k_steps, inflight.b_bucket
-        )
-        lp_arrays = self._materialize_lp(
-            inflight.lp_data, inflight.k_steps, inflight.b_bucket
-        )
-        self.metrics.time_decode_sync_ms += (
-            time.perf_counter() - t0
-        ) * 1000.0
+        with phase(
+            self.metrics, "engine.readback", "time_decode_sync_ms", lagged=1
+        ):
+            ids = np.asarray(inflight.token_ids).reshape(
+                inflight.k_steps, inflight.b_bucket
+            )
+            lp_arrays = self._materialize_lp(
+                inflight.lp_data, inflight.k_steps, inflight.b_bucket
+            )
         return self._decode_postprocess(
             reqs, inflight.k_steps, ids, lp_arrays, mixed=mixed,
             kstep=inflight.kstep,
@@ -2509,8 +2610,15 @@ class JaxEngine:
         inflight, self._inflight = self._inflight, None
         if inflight is None:
             return
-        self.metrics.overlap_rollbacks += 1
+        self._note_rollback(why)
         logger.debug("overlap rollback: %s", why)
+
+    def _note_rollback(self, why: str) -> None:
+        """Count a rolled-back speculative dispatch, and mark the moment
+        in a running capture: a zero-length `engine.rollback` span."""
+        self.metrics.overlap_rollbacks += 1
+        with phase(None, "engine.rollback", why=why):
+            pass
 
     def drain_overlap(self) -> None:
         """Public: discard any speculative in-flight decode dispatch
@@ -2775,13 +2883,11 @@ class JaxEngine:
         dispatch path pays nothing."""
 
         def first_call(*args, **kwargs):
-            import time as _time
-
-            from dynamo_tpu import telemetry
-            from dynamo_tpu.telemetry import phases
-
-            t0 = _time.perf_counter()
-            with telemetry.span(
+            ms0 = self.metrics.compile_ms
+            with phase(
+                self.metrics, "engine.compile", "compile_ms",
+                key=str(cache_key),
+            ), telemetry.span(
                 "engine.compile", service="engine",
                 attrs={"kind": kind, "key": str(cache_key)},
             ):
@@ -2789,9 +2895,8 @@ class JaxEngine:
                     jitted, args, kwargs
                 )
                 out = jitted(*args, **kwargs)
-            dt_ms = (_time.perf_counter() - t0) * 1000.0
+            dt_ms = self.metrics.compile_ms - ms0
             self.metrics.compiles += 1
-            self.metrics.compile_ms += dt_ms
             self.compiles_by_kind[kind] = (
                 self.compiles_by_kind.get(kind, 0) + 1
             )
@@ -2853,6 +2958,12 @@ class JaxEngine:
             bias_args = (bias_ids, bias_vals, bias_gated, min_toks); the
             min-token gating reads the CURRENT counters from samp_args,
             so fused-scan steps gate correctly as the count advances."""
+            with jax.named_scope("sample"):
+                return _pick(
+                    logits, samp_args, counts, freq, pres, rep_p, bias_args
+                )
+
+        def _pick(logits, samp_args, counts, freq, pres, rep_p, bias_args):
             eff = logits
             if counts is not None:
                 from dynamo_tpu.engine.sampling import apply_penalties
@@ -2927,10 +3038,12 @@ class JaxEngine:
                         rows = jnp.arange(ids.shape[0])
                         counts = counts.at[rows, ids].add(1.0)
                     out = (ids, maybe_logprobs(logits, ids))
-                    return (
-                        (ids[:, None], positions + 1, kv, counters + 1, counts),
-                        out,
-                    )
+                    with jax.named_scope("feedback"):
+                        carry = (
+                            ids[:, None], positions + 1, kv, counters + 1,
+                            counts,
+                        )
+                    return carry, out
 
                 (_, _, kv, _, _), (all_ids, all_lp) = jax.lax.scan(
                     body, (tokens, positions, kv, counters, counts0), None,
@@ -3020,11 +3133,12 @@ class JaxEngine:
                         & ~stop_mask(ids, stops)
                         & (n_emit < budgets)
                     )
-                    return (
-                        (ids[:, None], positions + emit_i[:, None], kv,
-                         counters + emit_i, counts, alive, n_emit),
-                        ids,
-                    )
+                    with jax.named_scope("feedback"):
+                        carry = (
+                            ids[:, None], positions + emit_i[:, None], kv,
+                            counters + emit_i, counts, alive, n_emit,
+                        )
+                    return carry, ids
 
                 (_, _, kv, _, _, _, n_emit), all_ids = jax.lax.scan(
                     body,
@@ -3342,7 +3456,21 @@ class JaxEngine:
                 return rep(ids), rep(maybe_logprobs(logits, ids)), kv
             return rep(ids), kv
 
-        jitted = jax.jit(step_fn, donate_argnums=(4,))
+        # one body, a name for each kind: a profiler's trace shows the
+        # program as jit_<name>, and prompt processing must not read as
+        # one-step decode
+        if kind == "decode":
+
+            def decode_fn(*args, **kwargs):
+                return step_fn(*args, **kwargs)
+
+            jitted = jax.jit(decode_fn, donate_argnums=(4,))
+        else:
+
+            def prefill_fn(*args, **kwargs):
+                return step_fn(*args, **kwargs)
+
+            jitted = jax.jit(prefill_fn, donate_argnums=(4,))
         logger.info("compiled %s program B=%d T=%d", kind, b, t)
         return self._cache_jit(kind, cache_key, jitted)
 
@@ -3396,8 +3524,6 @@ class JaxEngine:
         mark = self.metrics.prefill_dispatches + self.metrics.mixed_dispatches
         prev = self._last_emit.get(req.request_id)
         if prev is not None and mark > prev[1]:
-            from dynamo_tpu.telemetry import phases
-
             stall_ms = (now - prev[0]) * 1000.0
             if kstep and n_tokens > 1:
                 stall_ms = max(
@@ -4044,6 +4170,8 @@ class JaxEngine:
             m.kv_pages_watermark,
         )
         m.preemptions = self.scheduler.preemptions
+        m.queue_wait_ms_total = self.scheduler.queue_wait_ms_total
+        m.admissions = self.scheduler.admissions
         if self._spec_draft or self.config.spec_ngram > 0:
             # live acceptance-rate gauge over the spec-step window
             now_s = time.perf_counter()

@@ -34,6 +34,7 @@ from typing import Literal, Optional
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.page_table import PageAllocator
 from dynamo_tpu.engine.request import FinishReason, Request, RequestState
+from dynamo_tpu.telemetry import phases
 from dynamo_tpu.tokens import TokenBlockSequence
 
 logger = logging.getLogger(__name__)
@@ -96,6 +97,19 @@ class Scheduler:
         #: preemption-by-recompute count (page pressure) — exported as
         #: the dynamo_tpu_worker_preemptions_total fleet counter
         self.preemptions = 0
+        #: admission wait of every admitted request, traced or not,
+        #: counted where the wait ends (`_admit`): the sum and the count
+        #: (EngineMetrics.queue_wait_ms_total / .admissions), and the
+        #: waits since the engine last took them for its flight record
+        self.queue_wait_ms_total = 0.0
+        self.admissions = 0
+        self._admit_waits: list[float] = []
+
+    def take_admit_waits(self) -> list[float]:
+        """The waits (ms) of the requests admitted since the last call:
+        one engine step's admissions, for its flight record."""
+        waits, self._admit_waits = self._admit_waits, []
+        return waits
 
     # -- queue interface ---------------------------------------------------
 
@@ -307,11 +321,12 @@ class Scheduler:
             # Requests (unit tests, tools) — an epoch-sized wait there is
             # garbage, not a measurement
             if req.arrival_time:
-                from dynamo_tpu.telemetry import phases
-
                 wait_ms = max(
                     0.0, (time.time() - req.arrival_time) * 1000.0
                 )
+                self.queue_wait_ms_total += wait_ms
+                self.admissions += 1
+                self._admit_waits.append(round(wait_ms, 3))
                 if req.trace_id is not None:
                     # traced request: the wait rides the first StepOutput
                     # onto the engine.generate span (timeline breakdown)

@@ -77,6 +77,14 @@ _WORKER_FIELDS = (
     ("time_decode_dispatch_ms", "counter"),
     ("time_decode_sync_ms", "counter"),
     ("time_decode_host_ms", "counter"),
+    # the rest of the loop (the `engine.stage` / `engine.intake` /
+    # `engine.emit` spans' counters) and every admission's queue wait:
+    # queue_wait_ms_total / admissions = mean wait for a slot
+    ("time_stage_ms", "counter"),
+    ("time_intake_ms", "counter"),
+    ("time_emit_ms", "counter"),
+    ("queue_wait_ms_total", "counter"),
+    ("admissions", "counter"),
     ("prefill_dispatches", "counter"),
     ("decode_dispatches", "counter"),
     ("mixed_dispatches", "counter"),

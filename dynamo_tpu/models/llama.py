@@ -1634,23 +1634,31 @@ def attention_block(
             "attention_impl='xla' — the flash kernels don't implement them"
         )
 
+    # scopes (under the caller's `attn`): `kv_update` the cache write,
+    # `paged` attention that reads cache pages, `flash` attention over
+    # the in-register chunk alone or with paged history (prefill)
     if cfg.attention_impl not in ("pallas", "hybrid"):
-        kv = paged_scatter_kv(
-            kv, layer, k, v, page_tables, positions, valid
-        )
-        if first_chunk and t > 1:
-            attn = _chunk_only_attention(
-                q, k, v, positions, valid, cfg, dpad, mesh=mesh,
-                window=window, sinks=sinks,
+        with jax.named_scope("kv_update"):
+            kv = paged_scatter_kv(
+                kv, layer, k, v, page_tables, positions, valid
             )
+        if first_chunk and t > 1:
+            with jax.named_scope("flash"):
+                attn = _chunk_only_attention(
+                    q, k, v, positions, valid, cfg, dpad, mesh=mesh,
+                    window=window, sinks=sinks,
+                )
             return attn, kv, None
-        k_all, v_all = paged_gather_kv(kv, layer, page_tables, cfg.dtype)
-        if dpad:
-            k_all = k_all[..., : cfg.head_dim]
-            v_all = v_all[..., : cfg.head_dim]
-        attn = paged_attention(
-            q, k_all, v_all, positions, cfg, window=window, sinks=sinks
-        )
+        with jax.named_scope("paged"):
+            k_all, v_all = paged_gather_kv(
+                kv, layer, page_tables, cfg.dtype
+            )
+            if dpad:
+                k_all = k_all[..., : cfg.head_dim]
+                v_all = v_all[..., : cfg.head_dim]
+            attn = paged_attention(
+                q, k_all, v_all, positions, cfg, window=window, sinks=sinks
+            )
         return attn, kv, None
 
     from dynamo_tpu.ops.paged_attention import (
@@ -1691,43 +1699,47 @@ def attention_block(
                 kernel_vmem / 2**20, _PALLAS_DECODE_VMEM_BUDGET / 2**20,
                 b, cfg.num_heads // tp, kv.k.shape[2],
             )
-        attn = _xla_history_attention(
-            q, k, v, kv, layer, page_tables, positions, valid, cfg, dpad,
-        )
+        with jax.named_scope("paged"):
+            attn = _xla_history_attention(
+                q, k, v, kv, layer, page_tables, positions, valid, cfg,
+                dpad,
+            )
     elif t == 1:
-        hist = positions[:, 0]  # tokens already in the cache
-        qd = q[:, 0]
-        if dpad:
-            qd = jnp.pad(qd, ((0, 0), (0, 0), (0, dpad)))
-        acc, m, l = paged_decode_attention(
-            qd, kv.k, kv.v, layer, page_tables, hist,
-            scale_dim=cfg.head_dim, mesh=mesh, work_list=decode_work,
-            k_scale=kv.k_scale, v_scale=kv.v_scale,
-        )  # acc [B,Hq,Dpad] unnormalized, m/l [B,Hq]
-        # Exact merge of the current (unwritten) token: self-attention
-        # score s = q·k_cur/√d folded into the flash running state.
-        g = cfg.q_per_kv
-        kv_of = jnp.arange(cfg.num_heads) // g  # [Hq]
-        k_sel = k[:, 0, kv_of]  # [B, Hq, Dpad]
-        v_sel = v[:, 0, kv_of].astype(jnp.float32)
-        scale = 1.0 / math.sqrt(cfg.head_dim)
-        s_self = jnp.sum(
-            qd.astype(jnp.float32) * k_sel.astype(jnp.float32), axis=-1
-        ) * scale  # [B, Hq]
-        m_star = jnp.maximum(m, s_self)
-        alpha = jnp.exp(m - m_star)
-        beta = jnp.exp(s_self - m_star)
-        out = (alpha[..., None] * acc + beta[..., None] * v_sel) / (
-            alpha * l + beta
-        )[..., None]
-        out = out.astype(cfg.dtype)
-        if dpad:
-            out = out[..., : cfg.head_dim]
-        attn = out.reshape(b, cfg.num_heads * cfg.head_dim)[:, None, :]
+        with jax.named_scope("paged"):
+            hist = positions[:, 0]  # tokens already in the cache
+            qd = q[:, 0]
+            if dpad:
+                qd = jnp.pad(qd, ((0, 0), (0, 0), (0, dpad)))
+            acc, m, l = paged_decode_attention(
+                qd, kv.k, kv.v, layer, page_tables, hist,
+                scale_dim=cfg.head_dim, mesh=mesh, work_list=decode_work,
+                k_scale=kv.k_scale, v_scale=kv.v_scale,
+            )  # acc [B,Hq,Dpad] unnormalized, m/l [B,Hq]
+            # Exact merge of the current (unwritten) token: self-attention
+            # score s = q·k_cur/√d folded into the flash running state.
+            g = cfg.q_per_kv
+            kv_of = jnp.arange(cfg.num_heads) // g  # [Hq]
+            k_sel = k[:, 0, kv_of]  # [B, Hq, Dpad]
+            v_sel = v[:, 0, kv_of].astype(jnp.float32)
+            scale = 1.0 / math.sqrt(cfg.head_dim)
+            s_self = jnp.sum(
+                qd.astype(jnp.float32) * k_sel.astype(jnp.float32), axis=-1
+            ) * scale  # [B, Hq]
+            m_star = jnp.maximum(m, s_self)
+            alpha = jnp.exp(m - m_star)
+            beta = jnp.exp(s_self - m_star)
+            out = (alpha[..., None] * acc + beta[..., None] * v_sel) / (
+                alpha * l + beta
+            )[..., None]
+            out = out.astype(cfg.dtype)
+            if dpad:
+                out = out[..., : cfg.head_dim]
+            attn = out.reshape(b, cfg.num_heads * cfg.head_dim)[:, None, :]
     elif first_chunk:
-        attn = _chunk_only_attention(
-            q, k, v, positions, valid, cfg, dpad, mesh=mesh
-        )
+        with jax.named_scope("flash"):
+            attn = _chunk_only_attention(
+                q, k, v, positions, valid, cfg, dpad, mesh=mesh
+            )
     elif t <= 1024:
         # Prefill chunk with history: paged pages (positions < chunk
         # start) + the current chunk, one online softmax — the flash
@@ -1738,22 +1750,25 @@ def attention_block(
         # oversubscribing VMEM.
         from dynamo_tpu.ops.flash_prefill import paged_prefill_attention
 
-        qp = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, dpad))) if dpad else q
-        start = positions[:, 0]
-        hist_lens = jnp.where(valid[:, 0], start, 0).astype(jnp.int32)
-        cur_lens = jnp.sum(valid, axis=1).astype(jnp.int32)
-        out = paged_prefill_attention(
-            qp, k, v, kv.k, kv.v, layer, page_tables,
-            hist_lens, cur_lens, scale_dim=cfg.head_dim, mesh=mesh,
-            k_scale=kv.k_scale, v_scale=kv.v_scale,
-        )
-        if dpad:
-            out = out[..., : cfg.head_dim]
-        attn = out.reshape(b, t, cfg.num_heads * cfg.head_dim).astype(q.dtype)
+        with jax.named_scope("flash"):
+            qp = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, dpad))) if dpad else q
+            start = positions[:, 0]
+            hist_lens = jnp.where(valid[:, 0], start, 0).astype(jnp.int32)
+            cur_lens = jnp.sum(valid, axis=1).astype(jnp.int32)
+            out = paged_prefill_attention(
+                qp, k, v, kv.k, kv.v, layer, page_tables,
+                hist_lens, cur_lens, scale_dim=cfg.head_dim, mesh=mesh,
+                k_scale=kv.k_scale, v_scale=kv.v_scale,
+            )
+            if dpad:
+                out = out[..., : cfg.head_dim]
+            attn = out.reshape(b, t, cfg.num_heads * cfg.head_dim).astype(q.dtype)
     else:
-        attn = _xla_history_attention(
-            q, k, v, kv, layer, page_tables, positions, valid, cfg, dpad,
-        )
+        with jax.named_scope("paged"):
+            attn = _xla_history_attention(
+                q, k, v, kv, layer, page_tables, positions, valid, cfg,
+                dpad,
+            )
     return attn, kv, (k, v)
 
 
@@ -1819,11 +1834,17 @@ def forward_hidden(
     so the whole window lowers to ONE XLA program with no host in the
     loop.
     """
-    h = params["embed"][tokens].astype(cfg.dtype)  # [B,T,H]
-    if mm_embeds is not None:
-        h = jnp.where(mm_mask[..., None], mm_embeds.astype(cfg.dtype), h)
-    if cfg.scale_embeddings:  # Gemma: normalizer cast to the model dtype
-        h = h * jnp.asarray(math.sqrt(cfg.hidden_size), cfg.dtype)
+    # The named scopes below (embed, attn[/qkv, /kv_update, /paged or
+    # /flash, /out], mlp, final_norm; lm_head in compute_logits) only
+    # name things: a device trace carries them in each operation's
+    # metadata, so device time falls under a part of the model
+    # (docs/observability.md).
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens].astype(cfg.dtype)  # [B,T,H]
+        if mm_embeds is not None:
+            h = jnp.where(mm_mask[..., None], mm_embeds.astype(cfg.dtype), h)
+        if cfg.scale_embeddings:  # Gemma: normalizer cast to model dtype
+            h = h * jnp.asarray(math.sqrt(cfg.hidden_size), cfg.dtype)
     off = cfg.rms_norm_unit_offset
     if cfg.hidden_act == "silu":
         act = jax.nn.silu
@@ -1832,44 +1853,54 @@ def forward_hidden(
     else:
         raise ValueError(f"unknown hidden_act {cfg.hidden_act!r}")
 
-    decode_work = maybe_decode_work(cfg, tokens, positions, kv, page_tables)
+    with jax.named_scope("attn"):
+        decode_work = maybe_decode_work(
+            cfg, tokens, positions, kv, page_tables
+        )
 
     def layer(carry, xs):
         h, kvc = carry
         lp, li = xs
-        x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps, off)
-        b, t, _ = x.shape
-        q = _mm(x, lp, "wq", cfg.dtype)
-        k = _mm(x, lp, "wk", cfg.dtype)
-        v = _mm(x, lp, "wv", cfg.dtype)
-        if cfg.attention_bias:
-            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-        q = q.reshape(b, t, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-        v = v.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-        if cfg.qk_norm:  # Qwen3: head_dim-wide RMSNorm pre-rope
-            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps, off)
-            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps, off)
-        attn, kvc, staged = attention_block(
-            q, k, v, kvc, li, page_tables, positions, valid, cfg,
-            first_chunk=first_chunk, mesh=mesh, decode_work=decode_work,
-            rope_positions=rope_positions,
-        )
-        attn_out = _mm(attn, lp, "wo", cfg.dtype)
-        if cfg.post_block_norms:  # Gemma2: norm the branch, then residual
-            attn_out = rms_norm(
-                attn_out, lp["post_attn_norm"], cfg.rms_norm_eps, off
+        with jax.named_scope("attn"):
+            with jax.named_scope("qkv"):
+                x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps, off)
+                b, t, _ = x.shape
+                q = _mm(x, lp, "wq", cfg.dtype)
+                k = _mm(x, lp, "wk", cfg.dtype)
+                v = _mm(x, lp, "wv", cfg.dtype)
+                if cfg.attention_bias:
+                    q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+                q = q.reshape(b, t, cfg.num_heads, cfg.head_dim)
+                k = k.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
+                v = v.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
+                if cfg.qk_norm:  # Qwen3: head_dim-wide RMSNorm pre-rope
+                    q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps, off)
+                    k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps, off)
+            attn, kvc, staged = attention_block(
+                q, k, v, kvc, li, page_tables, positions, valid, cfg,
+                first_chunk=first_chunk, mesh=mesh, decode_work=decode_work,
+                rope_positions=rope_positions,
             )
-        h = h + attn_out
-        x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps, off)
-        gate = act(_mm(x, lp, "w_gate", cfg.dtype).astype(jnp.float32))
-        up = _mm(x, lp, "w_up", cfg.dtype).astype(jnp.float32)
-        mlp_out = _mm((gate * up).astype(cfg.dtype), lp, "w_down", cfg.dtype)
-        if cfg.post_block_norms:
-            mlp_out = rms_norm(
-                mlp_out, lp["post_mlp_norm"], cfg.rms_norm_eps, off
+            with jax.named_scope("out"):
+                attn_out = _mm(attn, lp, "wo", cfg.dtype)
+                if cfg.post_block_norms:  # Gemma2: norm, then residual
+                    attn_out = rms_norm(
+                        attn_out, lp["post_attn_norm"], cfg.rms_norm_eps,
+                        off,
+                    )
+                h = h + attn_out
+        with jax.named_scope("mlp"):
+            x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps, off)
+            gate = act(_mm(x, lp, "w_gate", cfg.dtype).astype(jnp.float32))
+            up = _mm(x, lp, "w_up", cfg.dtype).astype(jnp.float32)
+            mlp_out = _mm(
+                (gate * up).astype(cfg.dtype), lp, "w_down", cfg.dtype
             )
-        h = h + mlp_out
+            if cfg.post_block_norms:
+                mlp_out = rms_norm(
+                    mlp_out, lp["post_mlp_norm"], cfg.rms_norm_eps, off
+                )
+            h = h + mlp_out
         return (h, kvc), staged
 
     (h, kv_new), staged = lax.scan(
@@ -1877,10 +1908,12 @@ def forward_hidden(
         (h, kv),
         (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)),
     )
-    kv_new = land_staged_kv(
-        kv_new, staged, page_tables, positions, valid, mesh=mesh
-    )
-    h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps, off)
+    with jax.named_scope("attn"), jax.named_scope("kv_update"):
+        kv_new = land_staged_kv(
+            kv_new, staged, page_tables, positions, valid, mesh=mesh
+        )
+    with jax.named_scope("final_norm"):
+        h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps, off)
     return h, kv_new
 
 
@@ -1906,14 +1939,15 @@ def land_staged_kv(
 
 def compute_logits(params: dict, cfg: LlamaConfig, hidden: jax.Array) -> jax.Array:
     """Project hidden states [..., H] to vocab logits [..., V] in f32."""
-    lm_head = params.get("lm_head")
-    if lm_head is None:
-        lm_head = params["embed"].T
-    logits = (hidden @ lm_head).astype(jnp.float32)
-    if cfg.final_logit_softcap:  # Gemma2
-        c = cfg.final_logit_softcap
-        logits = c * jnp.tanh(logits / c)
-    return logits
+    with jax.named_scope("lm_head"):
+        lm_head = params.get("lm_head")
+        if lm_head is None:
+            lm_head = params["embed"].T
+        logits = (hidden @ lm_head).astype(jnp.float32)
+        if cfg.final_logit_softcap:  # Gemma2
+            c = cfg.final_logit_softcap
+            logits = c * jnp.tanh(logits / c)
+        return logits
 
 
 def forward(

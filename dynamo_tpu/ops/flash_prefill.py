@@ -406,6 +406,7 @@ def paged_prefill_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, tp, hq, d), q.dtype),
         interpret=interpret,
+        name="paged_prefill_attention",
         # the static kv-head unroll holds per-head f32 accumulators; at
         # llama3 shapes (Hkv=8, G=4, BQ=128, D=128) that is ~19MB of
         # scoped VMEM — above Mosaic's 16MB default, well under v5e's 128MB
@@ -504,6 +505,7 @@ def flash_prefill_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, tp, d), q.dtype),
         interpret=interpret,
+        name="flash_prefill_attention",
     )(valid_len.astype(jnp.int32), qh, kh, vh)
     # back to [B, T, Hq, D]
     out = out.transpose(0, 3, 1, 2, 4)

@@ -230,6 +230,7 @@ def paged_write(
         # caches alias the outputs, keeping both pools in place
         input_output_aliases={4: 0, 5: 1},
         interpret=jax.default_backend() != "tpu",
+        name="paged_kv_write",
     )(
         run_pages.astype(jnp.int32),
         run_slots.astype(jnp.int32),

@@ -386,6 +386,7 @@ def paged_decode_attention(
         ],
         grid_spec=grid_spec,
         interpret=interpret,
+        name="paged_decode_attention",
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         n_work,

@@ -40,6 +40,14 @@ _DELTA_FIELDS = (
     ("disp_ms", "time_decode_dispatch_ms"),
     ("sync_ms", "time_decode_sync_ms"),
     ("host_ms", "time_decode_host_ms"),
+    # the rest of the loop on the host's clock, to check step by step
+    # against the `engine.*` spans of a profiler capture: schedule and
+    # stage are this step's own; intake and emit run between steps, so a
+    # record carries the emit of the step before it
+    ("sched_ms", "time_schedule_ms"),
+    ("stage_ms", "time_stage_ms"),
+    ("emit_ms", "time_emit_ms"),
+    ("intake_ms", "time_intake_ms"),
     ("overlap_hits", "overlap_hits"),
     ("overlap_rollbacks", "overlap_rollbacks"),
     # speculative decoding (ngram or draft model): drafted/accepted per
@@ -103,6 +111,7 @@ class FlightRecorder:
         free_pages: int = 0,
         active_pages: int = 0,
         watermark: int = 0,
+        admit_wait_ms: Optional[list] = None,
     ) -> dict:
         """Append one step record. `metrics` is the engine's
         EngineMetrics — deltas against the previous record are computed
@@ -123,6 +132,10 @@ class FlightRecorder:
             "active_pages": active_pages,
             "watermark": watermark,
         }
+        if admit_wait_ms:
+            # queue waits (ms) of the requests this step admitted,
+            # traced or not; absent when it admitted none
+            rec["admit_wait_ms"] = admit_wait_ms
         prev = self._prev
         for field, attr in _DELTA_FIELDS:
             cur = getattr(metrics, attr, 0)
