@@ -1485,7 +1485,7 @@ _warned_vmem_reroute: set = set()
 
 
 def maybe_decode_work(cfg, tokens, positions, kv, page_tables):
-    """The decode kernel's (sequence, page) work list is LAYER-INVARIANT:
+    """The decode kernel's work list (its rows with history) is LAYER-INVARIANT:
     build it once per step, outside the layer scan (XLA won't reliably
     hoist the sort out of the loop). Shared by the Llama and MoE forward
     passes; None whenever the step can't take the kernel path."""
@@ -1495,7 +1495,7 @@ def maybe_decode_work(cfg, tokens, positions, kv, page_tables):
         return None
     from dynamo_tpu.ops.paged_attention import decode_work_list
 
-    return decode_work_list(page_tables, positions[:, 0], kv.k.shape[2])
+    return decode_work_list(page_tables, positions[:, 0])
 
 
 def attention_block(
@@ -1670,7 +1670,7 @@ def attention_block(
     kernel_vmem = decode_vmem_bytes(
         b, cfg.num_heads // tp, cfg.kv_head_dim, kv.k.shape[2],
         cfg.num_kv_heads // tp or 1, jnp.dtype(kv.k.dtype).itemsize,
-        quantized=kv.quantized,
+        quantized=kv.quantized, budget=_PALLAS_DECODE_VMEM_BUDGET,
     )
     if t == 1 and (
         (cfg.attention_impl == "hybrid" and b > cfg.pallas_decode_max_batch)
@@ -1714,6 +1714,7 @@ def attention_block(
                 qd, kv.k, kv.v, layer, page_tables, hist,
                 scale_dim=cfg.head_dim, mesh=mesh, work_list=decode_work,
                 k_scale=kv.k_scale, v_scale=kv.v_scale,
+                vmem_budget=_PALLAS_DECODE_VMEM_BUDGET,
             )  # acc [B,Hq,Dpad] unnormalized, m/l [B,Hq]
             # Exact merge of the current (unwritten) token: self-attention
             # score s = q·k_cur/√d folded into the flash running state.

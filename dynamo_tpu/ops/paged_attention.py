@@ -4,26 +4,52 @@ Decode (T=1) attention over the paged KV history. The XLA fallback path
 (models/llama.py:paged_attention) gathers the full per-sequence KV history
 into a dense [B, K, Hkv, D] array in HBM before the matmuls — 2× the HBM
 traffic (read pages, write gather, read gather) plus O(B·MP·S) memory. This
-kernel streams pages HBM→VMEM with multi-buffered async DMA, accumulating a
+kernel streams pages HBM→VMEM with double-buffered async DMA, accumulating a
 flash-style online softmax. KV bytes are read exactly once, nothing is
 materialized.
 
-The work list is FLATTENED: one kernel invocation (grid=(1,)) walks every
-(sequence, page) pair of the batch back to back, so the DMA pipeline stays
-full across the whole batch. The round-3 per-sequence-grid design drained
-its 2-deep pipeline at every grid-cell boundary — at decode batch 128 that
-is 128 pipeline restarts per layer per step, and DMA issue latency (not
-bandwidth) dominated the measured 13 ms/token-row vs the ~4 ms HBM
-roofline (artifacts/tpu/decode_profile.json). Per-page flash merges are
-order-independent (max/rescale/add), so each page read-modify-writes its
-sequence's running (m, l, acc) rows in the VMEM outputs directly — no
-carried state, no sequence-boundary flushes.
+One kernel invocation (grid=(1,)) walks every row of the batch back to
+back, so the DMA pipeline stays full across rows. A turn of the walk is a
+(row, BLOCK of consecutive page ordinals): up to `_block_pages` pages are
+DMA'd into one slot (only the pages that exist; a short last block masks
+its tail like a partly filled page), scored in one dot and folded in with
+one softmax update. A row's blocks are adjacent, so its running
+(m, l, acc) stays in registers and is written once.
+
+Why blocks, measured on a v5e at qwen2-7b's decode shape (B 64, 28/4 heads
+of 128, S 64, bf16, histories 256-1400, 110 MB of K/V a layer; PR 25,
+scripts/paged_decode_bench.py, device time from a trace): the walk that
+took one page a turn ran 810 us a layer; with its DMAs issued and waited
+and no arithmetic, 161 us; with its arithmetic on a resident slot and no
+DMA, 802 us. The body set the pace, not the copies (the 13 ms against 4 ms
+this docstring used to cite was a CPU run). Each turn cast a [64, 4, 128]
+page to f32, gathered each kv head's rows across sublanes, issued eight
+7-row f32 dots and read-modify-wrote the row's state in VMEM. Now, at 8
+pages a block: 172 us as it is, 156 us DMA only, 89 us arithmetic only —
+bound by its copies, at 78 % of the 134 us the chip needs to read the bytes
+at 819 GB/s (phi3-mini's and llama3-8b's shapes: 86 and 89 %). 4 pages a
+block gave 197 us, 2 gave 267; a third slot gave nothing.
 
 Cache layout is [L, P, S, Hkv, D] (models/llama.py KVPages): one (layer,
 page) slice is a contiguous [S, Hkv, D] block, so a single DMA per page
-feeds the compute for EVERY kv head. D is lane-padded to a 128 multiple
-(LlamaConfig.kv_head_dim): Mosaic DMA slices must be 128-aligned in the
-minor dimension.
+feeds the compute for EVERY kv head. The kernel takes it as [S*Hkv, D]
+rows (r = slot*Hkv + h; a bitcast, the same bytes), so a slot holds whole
+(sublane, 128) tiles and no operand is relaid: ONE dot scores all query
+heads against all of a block's rows, and a head keeps its own kv head's
+columns by mask (Hkv times the MXU work on a unit that was idle, instead of
+Hkv gathers). bf16 pools go to the MXU as they are, q/sqrt(d) and the
+softmax weights in bf16 beside them (one pass each; the operand precision
+the chip's default f32 dot gave the old body); products accumulate in f32,
+and scores, m, l, exp and acc are f32.
+D is lane-padded to a 128 multiple (LlamaConfig.kv_head_dim): Mosaic DMA
+slices must be 128-aligned in the minor dimension.
+
+The block size follows from the shapes (`_block_pages`): as many pages as
+reach 1 MiB of K+V, at most 8, at most 2048 key columns, halved while
+`_footprint` would pass the caller's VMEM budget. `_footprint` is what
+`decode_vmem_bytes` reports: the whole-batch q, acc and m|l blocks (twice:
+the pipeline double-buffers them), two K and two V slots, the scale slots,
+and four [Hq, columns] 32-bit temporaries of a block.
 
 The kernel reads HISTORY ONLY (tokens already written to pages — the
 current token's KV is staged and written once per step by ops/kv_update).
@@ -47,204 +73,313 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: DMA pipeline depth (slots per k/v scratch). 4 hides issue latency well
-#: past the 2-deep minimum while costing only 2 extra [S, Hkv, D] buffers.
-_DEPTH = 4
+#: DMA pipeline depth, counted in BLOCKS: one block computes while the next
+#: lands. A block is 0.25-1 MiB, so two slots already hide the issue latency
+#: that took four one-page slots.
+_DEPTH = 2
+#: pages a block holds at most, and the K+V bytes it aims for: past ~1 MiB
+#: a slot only costs VMEM, and a row rarely has more pages to give
+_MAX_BLOCK_PAGES = 8
+_BLOCK_BYTES = 1 << 20
+#: key columns one block may spread over: the score tile [Hq, columns] and
+#: its softmax temporaries stay a few hundred KiB of f32
+_MAX_BLOCK_COLUMNS = 2048
+#: masked scores; finite so that a fully masked (padded) query row stays
+#: NaN-free
+_MASKED = -1e30
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _footprint(
+    pb: int, b: int, hq: int, d: int, s: int, hkv: int, itemsize: int,
+    quantized: bool,
+) -> int:
+    """VMEM bytes of one kernel call whose blocks hold `pb` pages: the
+    whole-batch q / acc / m+l blocks (double-buffered by the pipeline
+    although the grid has one step), `_DEPTH` K and V slots, the scale
+    slots of a quantized pool, and the block's live temporaries."""
+    hqp = _round_up(hq, 8)
+    n = pb * s * hkv  # key columns of a block
+    sub = 32 // itemsize  # sublane tile of the cache dtype
+    whole_batch = 2 * b * _round_up(hqp, sub) * d * itemsize  # q
+    whole_batch += 2 * 2 * b * hqp * d * 4  # acc, and m|l in one block
+    slots = 2 * _DEPTH * _round_up(n, sub) * d * itemsize
+    # limit, scores and p in 32 bits, p again for the MXU
+    temps = 4 * hqp * n * 4
+    if itemsize != 2:
+        temps += 2 * n * d * 4  # K and V of the slot cast for the MXU
+    if quantized:
+        lanes = _round_up(s, 128)
+        slots += 2 * _DEPTH * pb * _round_up(hkv, 8) * lanes * 4
+        temps += lanes * s * hkv * 2  # one-hot slot -> column expander
+        # its three-part operand and product, for K's and V's planes
+        temps += 4 * 3 * pb * _round_up(hkv, 8) * s * hkv * 4
+    return whole_batch + slots + temps
+
+
+def _block_pages(
+    b: int, hq: int, d: int, s: int, hkv: int, itemsize: int,
+    quantized: bool, budget: int | None,
+) -> int:
+    """Pages per block, from the shapes alone: as many as reach
+    `_BLOCK_BYTES` of K+V, at most `_MAX_BLOCK_PAGES`, no more key columns
+    than `_MAX_BLOCK_COLUMNS`; then halved while the call would not fit
+    `budget` (a large batch's q/acc blocks leave less for the slots)."""
+    page_bytes = 2 * s * hkv * d * itemsize
+    pb = max(1, min(
+        _MAX_BLOCK_PAGES, _BLOCK_BYTES // page_bytes,
+        _MAX_BLOCK_COLUMNS // (s * hkv),
+    ))
+    while (
+        budget is not None and pb > 1
+        and _footprint(pb, b, hq, d, s, hkv, itemsize, quantized) > budget
+    ):
+        pb //= 2
+    return pb
 
 
 def _decode_kernel(
     # scalar prefetch
     layer_ref,  # [1] int32 — layer of the stacked cache to read
-    nwork_ref,  # [1] int32 — valid (sequence, page) work items
-    order_ref,  # [B*MP] int32 — work item -> b*MP + page ordinal
-    page_of_ref,  # [B*MP] int32 — work item -> physical page id
+    nrows_ref,  # [1] int32 — rows with history
+    rows_ref,  # [B] int32 — those rows first, in batch order
+    pt_ref,  # [B*MP] int32 — the page tables, flat
     len_ref,  # [B] int32 HISTORY lengths (tokens already in the cache)
     # then (positional, shape depends on `quantized`):
-    #   q_ref,  # [B, HQ, D] VMEM (whole batch's queries, unscaled)
-    #   k_ref,  # [L, P, S, Hkv, D] in HBM/ANY (narrow dtype when quantized)
+    #   q_ref,  # [B, HQP, D] VMEM (whole batch's queries, unscaled; the
+    #           # head axis padded to a sublane tile)
+    #   k_ref,  # [L, P, S*Hkv, D] in HBM/ANY (narrow dtype when quantized)
     #   v_ref,
     #   [ks_ref, vs_ref]  # [L, P, Hkv, S'] f32 scale planes (quantized)
-    # outputs (whole batch resident in VMEM; read-modify-written per page):
-    #   acc_ref,  # [B, HQ, D] f32 — UNNORMALIZED flash accumulator
-    #   m_ref,  # [B, HQ, 128] f32 — running max (lane-broadcast)
-    #   l_ref,  # [B, HQ, 128] f32 — running denominator (lane-broadcast)
+    # outputs (whole batch resident in VMEM; a row is written once):
+    #   acc_ref,  # [B, HQP, D] f32 — UNNORMALIZED flash accumulator
+    #   ml_ref,  # [B, HQP, 128] f32 — lane 0 running max, the rest the
+    #            # running denominator
     # scratch:
-    #   k_scr,  # [DEPTH, S, Hkv, D] VMEM
+    #   k_scr,  # [DEPTH, PB*S*Hkv, D] VMEM: a slot is a block of pages
     #   v_scr,
-    #   [ks_scr, vs_scr]  # [DEPTH, Hkv, S'] f32 VMEM (quantized)
+    #   [ks_scr, vs_scr]  # [DEPTH, PB*SUB, S'] f32 VMEM (quantized)
     #   sem,  # [2 or 4, DEPTH] DMA semaphores: [plane, slot]
     *refs,
     page_size: int,
     scale_dim: int,
+    num_q_heads: int,
     num_kv_heads: int,
-    max_pages: int,  # MP — decodes order_ref into (sequence, ordinal)
+    max_pages: int,  # MP — row stride of pt_ref
+    block_pages: int,
     quantized: bool,
 ):
     if quantized:
-        (q_ref, k_ref, v_ref, ks_ref, vs_ref, acc_ref, m_ref, l_ref,
+        (q_ref, k_ref, v_ref, ks_ref, vs_ref, acc_ref, ml_ref,
          k_scr, v_scr, ks_scr, vs_scr, sem) = refs
     else:
-        (q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
-         k_scr, v_scr, sem) = refs
+        (q_ref, k_ref, v_ref, acc_ref, ml_ref, k_scr, v_scr, sem) = refs
         ks_ref = vs_ref = ks_scr = vs_scr = None
     li = layer_ref[0]
-    n = nwork_ref[0]
-    hq, d = q_ref.shape[1], q_ref.shape[2]
-    g = hq // num_kv_heads
-    s = page_size
+    n_rows = nrows_ref[0]
+    bsz, hqp, d = q_ref.shape
+    hkv, s, pb = num_kv_heads, page_size, block_pages
+    g = num_q_heads // hkv
+    rpp = s * hkv  # cache rows (= key columns) of one page: r = slot*Hkv + h
+    n = pb * rpp
+    sub = _round_up(hkv, 8)  # scale rows a page takes in its slot
     inv_scale = 1.0 / math.sqrt(scale_dim)
+    # What the MXU is fed: a bf16 pool under bf16 queries goes in as it is
+    # and narrow pools convert exactly; q/sqrt(d) and the softmax weights
+    # are rounded to bf16 on the way in, which is what the chip's default-
+    # precision f32 dot made of them before (PR 25 measured that kernel
+    # 1e-3 off a dense f32 reference on the chip, 4e-7 interpreted). The
+    # products accumulate in f32; an f32 cache keeps f32 operands.
+    narrow = jnp.dtype(k_scr.dtype).itemsize <= 2 and q_ref.dtype == jnp.bfloat16
+    mxu = jnp.bfloat16 if narrow else jnp.float32
+
+    def to_mxu(x):  # int8/fp8 rows convert through f32
+        return x if x.dtype == mxu else x.astype(jnp.float32).astype(mxu)
 
     # Rows never visited (zero history) must read as the empty-history
-    # state the caller's merge expects: acc=0, m=-inf, l=0.
+    # state the caller's merge expects: acc=0, m=-inf, l=0. The slots start
+    # finite: a short last block leaves pages unfetched, and 0 * stale NaN
+    # would reach acc through the weights' masked columns.
+    lane = jax.lax.broadcasted_iota(jnp.int32, (hqp, 128), 1)
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-    l_ref[...] = jnp.zeros_like(l_ref)
-
-    # one DMA plane per (cache/scale, slot); scale planes ride the same
-    # pipeline as their pages — a page and its scales land together
-    planes = [(k_ref, k_scr), (v_ref, v_scr)]
+    ml_ref[...] = jnp.broadcast_to(
+        jnp.where(lane == 0, -jnp.inf, 0.0), ml_ref.shape
+    )
+    k_scr[...] = jnp.zeros_like(k_scr)
+    v_scr[...] = jnp.zeros_like(v_scr)
     if quantized:
-        planes += [(ks_ref, ks_scr), (vs_ref, vs_scr)]
+        ks_scr[...] = jnp.zeros_like(ks_scr)
+        vs_scr[...] = jnp.zeros_like(vs_scr)
 
-    def copies(slot, j):
-        return tuple(
-            pltpu.make_async_copy(
-                src.at[li, page_of_ref[j]], dst.at[slot], sem.at[pi, slot]
+    # Column c of a block is cache row c: key position c // Hkv past the
+    # block's first, kv head c % Hkv. One dot scores every query head
+    # against every column; `limit` keeps a head's own columns (position
+    # where the heads match, else out of reach), so one compare against
+    # the tokens left masks other heads and the tail together.
+    col = jax.lax.broadcasted_iota(jnp.int32, (hqp, n), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (hqp, n), 0)
+    col_pos = jax.lax.div(col, hkv)
+    col_head = col - col_pos * hkv
+    own = (col_head * g <= row) & (row < (col_head + 1) * g)
+    limit = jnp.where(own, col_pos, jnp.int32(1 << 30))
+
+    if quantized:
+        # Scales arrive head-major, slot-minor ([Hkv, S'] a page) while the
+        # columns run slot-major: a one-hot [S', S*Hkv] takes slot s to its
+        # Hkv columns on the MXU (three bf16 parts of the f32 scale, each
+        # product exact), and the head's own row is picked per column.
+        sl = ks_scr.shape[2]
+        e_slot = jax.lax.broadcasted_iota(jnp.int32, (sl, rpp), 0)
+        e_col = jax.lax.broadcasted_iota(jnp.int32, (sl, rpp), 1)
+        expand = (
+            (e_slot * hkv <= e_col) & (e_col < (e_slot + 1) * hkv)
+        ).astype(jnp.bfloat16)
+        p_row = jax.lax.broadcasted_iota(jnp.int32, (sub, rpp), 0)
+        p_col = jax.lax.broadcasted_iota(jnp.int32, (sub, rpp), 1)
+        pick = p_row == p_col - jax.lax.div(p_col, hkv) * hkv
+
+        def column_scales(plane):  # [PB*SUB, S'] f32 -> [1, N]
+            hi = plane.astype(jnp.bfloat16).astype(jnp.float32)
+            r1 = plane - hi
+            mid = r1.astype(jnp.bfloat16).astype(jnp.float32)
+            parts = jnp.concatenate([hi, mid, r1 - mid], axis=0)
+            out = jax.lax.dot_general(
+                parts.astype(jnp.bfloat16), expand,
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [3*PB*SUB, S*Hkv]
+            rows_ = pb * sub
+            out = out[:rows_] + out[rows_ : 2 * rows_] + out[2 * rows_ :]
+            return jnp.concatenate(
+                [
+                    jnp.sum(
+                        jnp.where(pick, out[p * sub : (p + 1) * sub], 0.0),
+                        axis=0, keepdims=True,
+                    )
+                    for p in range(pb)
+                ],
+                axis=1,
             )
-            for pi, (src, dst) in enumerate(planes)
-        )
 
-    # prime the pipeline: DEPTH-1 transfers in flight before compute starts
-    for p in range(_DEPTH - 1):
-        @pl.when(p < n)
-        def _(p=p):
-            for c in copies(p, p):
-                c.start()
+    # one DMA plane per (cache/scale); a page and its scales land together
+    planes = [(k_ref, k_scr, rpp, rpp), (v_ref, v_scr, rpp, rpp)]
+    if quantized:
+        planes += [(ks_ref, ks_scr, sub, hkv), (vs_ref, vs_scr, sub, hkv)]
 
-    def body(j, _):
-        slot = jax.lax.rem(j, _DEPTH)
+    def block_copies(b, kb, slot, act):
+        """Start or wait the DMAs of block `kb` of row `b`: one per plane
+        and page that exists, into its place in the slot."""
+        first = kb * pb
 
-        @pl.when(j + _DEPTH - 1 < n)
-        def _():
-            nslot = jax.lax.rem(j + _DEPTH - 1, _DEPTH)
-            for c in copies(nslot, j + _DEPTH - 1):
-                c.start()
+        def page_copies(p, _):
+            page = pt_ref[b * max_pages + first + p]
+            for pi, (src, dst, stride, rows_) in enumerate(planes):
+                at = pl.multiple_of(p * stride, stride)
+                act(pltpu.make_async_copy(
+                    src.at[li, page],
+                    dst.at[slot, pl.ds(at, rows_)],
+                    sem.at[pi, slot],
+                ))
+            return 0
 
-        for c in copies(slot, j):
-            c.wait()
+        pages = -(-len_ref[b] // s)
+        jax.lax.fori_loop(0, jnp.minimum(pb, pages - first), page_copies, 0)
 
-        oj = order_ref[j]
-        bj = oj // max_pages
-        hist = len_ref[bj]
-        q = q_ref[bj].astype(jnp.float32) * inv_scale  # [HQ, D]
-        kp = k_scr[slot].astype(jnp.float32)  # [S, Hkv, D]
-        vp = v_scr[slot].astype(jnp.float32)
-        if quantized:
-            # dequantize in VMEM right after the DMA lands, folded into
-            # the flash merge: a key row's scale multiplies its column of
-            # the scores, a value row's its column of the weights — the
-            # slot-minor planes are already lane-oriented like both, and
-            # no fp page ever touches HBM
-            ksc = ks_scr[slot][:, :s]  # [Hkv, S]
-            vsc = vs_scr[slot][:, :s]
-        key_pos = (oj % max_pages) * s + jax.lax.broadcasted_iota(
-            jnp.int32, (g, s), 1
-        )
-        key_mask = key_pos < hist  # [G, S]
+    @pl.when(n_rows > 0)
+    def _():
+        block_copies(rows_ref[0], 0, 0, lambda c: c.start())
 
-        m_old = m_ref[bj]  # [HQ, 128] (column 0 is the value)
-        l_old = l_ref[bj]
-        acc_old = acc_ref[bj]  # [HQ, D]
+    def row_body(ri, it0):
+        b = rows_ref[ri]
+        hist = len_ref[b]
+        n_blk = -(-hist // (pb * s))
+        # the block after this row's last: the next row's first
+        b_next = rows_ref[jnp.minimum(ri + 1, bsz - 1)]
+        q = (q_ref[b].astype(jnp.float32) * inv_scale).astype(mxu)  # [HQP, D]
 
-        # One DMA fed all heads; the per-head dots are small but the page
-        # walk is DMA-bound, so their latency hides under the next copy.
-        m_out, l_out, a_out = [], [], []
-        for h in range(num_kv_heads):  # static unroll
-            sl = slice(h * g, (h + 1) * g)
-            qh = q[sl]  # [G, D]
-            ms = m_old[sl, :1]  # [G, 1]
-            ls = l_old[sl, :1]
-            accs = acc_old[sl]  # [G, D]
+        def block_body(kb, carry):
+            m, l, acc = carry
+            slot = jax.lax.rem(it0 + kb, _DEPTH)
+            nslot = jax.lax.rem(it0 + kb + 1, _DEPTH)
+            in_row = kb + 1 < n_blk
+
+            @pl.when(in_row | (ri + 1 < n_rows))
+            def _():
+                block_copies(
+                    jnp.where(in_row, b, b_next),
+                    jnp.where(in_row, kb + 1, 0),
+                    nslot, lambda c: c.start(),
+                )
+
+            block_copies(b, kb, slot, lambda c: c.wait())
+
             scores = jax.lax.dot_general(
-                qh, kp[:, h], (((1,), (1,)), ((), ())),
+                q, to_mxu(k_scr[slot]), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [G, S]
+            )  # [HQP, N]
             if quantized:
-                scores = scores * ksc[h : h + 1]
-            scores = jnp.where(key_mask, scores, -1e30)
-            m_new = jnp.maximum(ms, jnp.max(scores, axis=1, keepdims=True))
+                scores = scores * column_scales(ks_scr[slot])
+            scores = jnp.where(limit < hist - kb * (pb * s), scores, _MASKED)
+            m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
             p = jnp.exp(scores - m_new)
-            corr = jnp.exp(ms - m_new)
-            l_new = ls * corr + jnp.sum(p, axis=1, keepdims=True)
-            pv = p * vsc[h : h + 1] if quantized else p
-            a_new = accs * corr + jax.lax.dot_general(
-                pv, vp[:, h], (((1,), (0,)), ((), ())),
+            corr = jnp.exp(m - m_new)
+            l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+            if quantized:
+                p = p * column_scales(vs_scr[slot])
+            pv = jax.lax.dot_general(
+                p.astype(mxu), to_mxu(v_scr[slot]), (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )
-            m_out.append(m_new)
-            l_out.append(l_new)
-            a_out.append(a_new)
-        acc_ref[bj] = jnp.concatenate(a_out, axis=0)
-        m_ref[bj] = jnp.broadcast_to(
-            jnp.concatenate(m_out, axis=0), (hq, 128)
-        )
-        l_ref[bj] = jnp.broadcast_to(
-            jnp.concatenate(l_out, axis=0), (hq, 128)
-        )
-        return 0
+            )  # [HQP, D]
+            return m_new, l_new, acc * corr + pv
 
-    jax.lax.fori_loop(0, n, body, 0)
+        # a row's running state stays in registers from block to block
+        m, l, acc = jax.lax.fori_loop(
+            0, n_blk, block_body,
+            (
+                jnp.full((hqp, 1), -jnp.inf, jnp.float32),
+                jnp.zeros((hqp, 1), jnp.float32),
+                jnp.zeros((hqp, d), jnp.float32),
+            ),
+        )
+        acc_ref[b] = acc
+        ml_ref[b] = jnp.where(lane == 0, m, l)
+        return it0 + n_blk
+
+    jax.lax.fori_loop(0, n_rows, row_body, 0)
 
 
 def decode_work_list(
     page_tables: jax.Array,  # [B, MP] int32
     history_lens: jax.Array,  # [B] int32
-    page_size: int,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Compacted (sequence, page) work list for the decode kernel:
-    (n_work [1], order [B*MP], page_of [B*MP]) with valid pairs first in
-    (b, i) order. `order` encodes both coordinates — the kernel derives
-    b = order//MP, i = order%MP with two scalar ops instead of carrying
-    two more [B*MP] prefetch arrays through SMEM.
+    """What the decode kernel walks: (n_rows [1], rows [B], pages [B*MP]):
+    the rows that have history, first and in batch order, and the page
+    tables flat. The kernel takes a row's pages in blocks and derives
+    every (row, block) from these and the history lengths with scalar
+    arithmetic, so the list does not depend on how the kernel blocks.
 
     LAYER-INVARIANT: build it once per decode step and pass it to every
     layer's paged_decode_attention — inside the per-layer scan body XLA
-    is not guaranteed to hoist the sort, and re-sorting B*MP elements per
-    layer re-adds fixed per-layer overhead the flattened walk exists to
-    remove."""
-    mp = page_tables.shape[1]
-    hist = history_lens.astype(jnp.int32)
-    used = -(-hist // page_size)  # cdiv
-    valid = jnp.arange(mp, dtype=jnp.int32)[None, :] < used[:, None]
-    flat_valid = valid.reshape(-1)
-    order = jnp.argsort(~flat_valid, stable=True).astype(jnp.int32)
-    page_of = page_tables.reshape(-1).astype(jnp.int32)[order]
-    n_work = flat_valid.sum(dtype=jnp.int32).reshape(1)
-    return n_work, order, page_of
+    is not guaranteed to hoist the sort."""
+    has_history = history_lens.astype(jnp.int32) > 0
+    rows = jnp.argsort(~has_history, stable=True).astype(jnp.int32)
+    n_rows = has_history.sum(dtype=jnp.int32).reshape(1)
+    return n_rows, rows, page_tables.reshape(-1).astype(jnp.int32)
 
 
 def decode_vmem_bytes(
     b: int, hq: int, d: int, s: int, hkv: int, itemsize: int,
-    quantized: bool = False,
+    quantized: bool = False, budget: int | None = None,
 ) -> int:
-    """Kernel VMEM footprint estimate: whole-batch q + f32 acc/m/l blocks
-    plus the DMA scratch and the per-slot f32 k/v cast temporaries
-    (`kp`/`vp` in the kernel body — one slot's pages live in f32 while
-    its scores/weights compute). Quantized pools add the f32 scale-plane
-    scratch (and `itemsize` is the narrow dtype's — the scratch shrinks).
-    The caller routes to the XLA gather when this exceeds the budget
-    instead of letting Mosaic fail allocation."""
-    scale_scratch = (
-        2 * _DEPTH * hkv * (-(-s // 128) * 128) * 4 if quantized else 0
-    )
-    return (
-        b * hq * d * itemsize  # q (itemsize of q ≈ cache dtype or wider)
-        + b * hq * d * 4  # acc f32
-        + 2 * b * hq * 128 * 4  # m, l f32 (lane-broadcast)
-        + 2 * _DEPTH * s * hkv * d * itemsize  # k/v scratch
-        + 2 * s * hkv * d * 4  # kp/vp f32 cast of the active slot
-        + scale_scratch
-    )
+    """Kernel VMEM footprint estimate at the block size the kernel would
+    take under `budget` (see `_footprint`). The caller routes to the XLA
+    gather when even one page a block exceeds the budget, instead of
+    letting Mosaic fail allocation."""
+    pb = _block_pages(b, hq, d, s, hkv, itemsize, quantized, budget)
+    return _footprint(pb, b, hq, d, s, hkv, itemsize, quantized)
 
 
 def paged_decode_attention(
@@ -261,6 +396,7 @@ def paged_decode_attention(
     work_list=None,  # precomputed decode_work_list (layer-invariant)
     k_scale: jax.Array | None = None,  # [L, P, Hkv, S'] f32 (quantized pools)
     v_scale: jax.Array | None = None,
+    vmem_budget: int | None = None,  # the caller's, as in decode_vmem_bytes
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """History-only flash attention over the paged cache.
 
@@ -270,8 +406,8 @@ def paged_decode_attention(
     then reduces to pure self-attention.
 
     With `k_scale`/`v_scale` the cache holds quantized rows; each page's
-    scale plane DMAs alongside it and the rows dequantize in VMEM before
-    the flash merge.
+    scale plane DMAs alongside it and scales the scores' and the weights'
+    columns after the dots.
 
     `interpret` defaults to True off-TPU so tests run the same kernel on CPU.
     """
@@ -280,23 +416,22 @@ def paged_decode_attention(
     quantized = k_scale is not None
     hkv, s = k_cache.shape[3], k_cache.shape[2]
     if work_list is None:
-        work_list = decode_work_list(page_tables, history_lens, s)
+        work_list = decode_work_list(page_tables, history_lens)
     if mesh is not None and mesh.shape.get("tp", 1) > 1:
         # Heads are embarrassingly parallel: shard_map the kernel over tp
         # (q/outputs on the head axis, caches on the kv-head axis) — each
         # shard walks the same pages for its own heads, no collectives.
         # The (replicated) work list rides along so shards don't re-sort.
-        from functools import partial
-
         from jax.sharding import PartitionSpec as P
 
-        def sharded(q_, k_, v_, layer_, pt_, hist_, n_, od_, pg_, *scales):
+        def sharded(q_, k_, v_, layer_, pt_, hist_, n_, rows_, pages_, *scales):
             return paged_decode_attention(
                 q_, k_, v_, layer_, pt_, hist_,
                 scale_dim=scale_dim, interpret=interpret, mesh=None,
-                work_list=(n_, od_, pg_),
+                work_list=(n_, rows_, pages_),
                 k_scale=scales[0] if scales else None,
                 v_scale=scales[1] if scales else None,
+                vmem_budget=vmem_budget,
             )
 
         in_specs = [
@@ -325,28 +460,42 @@ def paged_decode_attention(
         return fn(*args)
     b, hq, d = q.shape
     mp = page_tables.shape[1]
-    n_work, order, page_of = work_list
+    n_rows, rows, pages = work_list
+    itemsize = jnp.dtype(k_cache.dtype).itemsize
+    pb = min(mp, _block_pages(
+        b, hq, d, s, hkv, itemsize, quantized, vmem_budget
+    ))
+    hqp = _round_up(hq, 8)
+    if hqp != hq:  # whole sublane tiles of query heads; the pad is masked
+        q = jnp.pad(q, ((0, 0), (0, hqp - hq), (0, 0)))
+    # a page as [S*Hkv, D] rows (r = slot*Hkv + h): the same bytes, and
+    # every dot operand a whole tile
+    cache_shape = (*k_cache.shape[:2], s * hkv, d)
+
+    def whole(*block):
+        return pl.BlockSpec(
+            block, lambda i, li, n, rw, pg, ln: (0,) * len(block)
+        )
 
     in_specs = [
-        pl.BlockSpec(
-            (b, hq, d), lambda i, li, n, od, pg, ln: (0, 0, 0)
-        ),
+        whole(b, hqp, d),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     scratch_shapes = [
-        pltpu.VMEM((_DEPTH, s, hkv, d), k_cache.dtype),
-        pltpu.VMEM((_DEPTH, s, hkv, d), v_cache.dtype),
+        pltpu.VMEM((_DEPTH, pb * s * hkv, d), k_cache.dtype),
+        pltpu.VMEM((_DEPTH, pb * s * hkv, d), v_cache.dtype),
     ]
-    operands = [q, k_cache, v_cache]
+    operands = [q, k_cache.reshape(cache_shape), v_cache.reshape(cache_shape)]
     if quantized:
         in_specs += [
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ]
+        scale_slot = (_DEPTH, pb * _round_up(hkv, 8), k_scale.shape[3])
         scratch_shapes += [
-            pltpu.VMEM((_DEPTH, *k_scale.shape[2:]), jnp.float32),
-            pltpu.VMEM((_DEPTH, *v_scale.shape[2:]), jnp.float32),
+            pltpu.VMEM(scale_slot, jnp.float32),
+            pltpu.VMEM(scale_slot, jnp.float32),
         ]
         operands += [k_scale, v_scale]
     scratch_shapes.append(
@@ -357,42 +506,33 @@ def paged_decode_attention(
         num_scalar_prefetch=5,
         grid=(1,),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec(
-                (b, hq, d), lambda i, li, n, od, pg, ln: (0, 0, 0)
-            ),
-            pl.BlockSpec(
-                (b, hq, 128), lambda i, li, n, od, pg, ln: (0, 0, 0)
-            ),
-            pl.BlockSpec(
-                (b, hq, 128), lambda i, li, n, od, pg, ln: (0, 0, 0)
-            ),
-        ],
+        out_specs=[whole(b, hqp, d), whole(b, hqp, 128)],
         scratch_shapes=scratch_shapes,
     )
-    acc, m, l = pl.pallas_call(
+    acc, ml = pl.pallas_call(
         functools.partial(
             _decode_kernel,
             page_size=s,
             scale_dim=scale_dim or d,
+            num_q_heads=hq,
             num_kv_heads=hkv,
             max_pages=mp,
+            block_pages=pb,
             quantized=quantized,
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, 128), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, 128), jnp.float32),
+            jax.ShapeDtypeStruct((b, hqp, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hqp, 128), jnp.float32),
         ],
         grid_spec=grid_spec,
         interpret=interpret,
         name="paged_decode_attention",
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
-        n_work,
-        order,
-        page_of,
+        n_rows,
+        rows,
+        pages,
         history_lens.astype(jnp.int32),
         *operands,
     )
-    return acc, m[:, :, 0], l[:, :, 0]
+    return acc[:, :hq], ml[:, :hq, 0], ml[:, :hq, 1]
