@@ -1,9 +1,18 @@
 """What one decode step has to read from HBM, from shapes alone. Kept
 with the benchmark so that no later PR can change the yardstick; checked
 against the program's own `kv_page_bytes` and parameter tree in
-chipbench's tests."""
+chipbench's tests.
+
+This is the default cost module: a dense decoder (seven matrices a
+layer, K and V rows per kv head). A configuration of another
+architecture names its own (`costs_module` in its file, see
+`manifest.module_of`), which answers the two questions the per-layer
+readers ask, `step_read_bytes` and `kv_read_bytes`, from what the flight
+record knows, or returns None where it cannot."""
 
 from __future__ import annotations
+
+import sys
 
 LANE = 128  # Mosaic DMA tile: cached head rows are padded to 128 lanes
 
@@ -58,3 +67,31 @@ def decode_step_bytes(hf: dict, live_tokens: float, dense_itemsize: int = 2,
     and the cached K/V of every live token once."""
     return (weight_bytes(hf, dense_itemsize, itemsize)
             + live_tokens * kv_bytes_per_token(hf, itemsize, kernels))
+
+
+# -- the seam the per-layer readers call (ctx["costs"]) ----------------------
+# `weights` is the configuration file's `weights` block (itemsizes),
+# `live_tokens` the cached tokens of the decode rows, `rows` how many
+# rows decode (a dense model reads every weight whatever the rows; a
+# sparse one reads the experts its rows touch).
+
+
+def asked(ctx: dict, name: str):
+    """The function `name` of the cell's cost module (`ctx["costs"]`,
+    this module where a reader's ctx names none), or None."""
+    return getattr(ctx.get("costs") or sys.modules[__name__], name, None)
+
+
+def step_read_bytes(hf: dict, weights: dict, live_tokens: float,
+                    rows: float, kernels: bool = True) -> float | None:
+    """Least bytes one decode step reads (`decode_hbm_share`)."""
+    return decode_step_bytes(hf, live_tokens, weights.get("dense_itemsize", 2),
+                             weights.get("itemsize", 2), kernels)
+
+
+def kv_read_bytes(hf: dict, weights: dict, live_tokens: float,
+                  rows: float, kernels: bool = True) -> float | None:
+    """Least bytes the page walk of one decode step reads
+    (`paged_attn_hbm_share`)."""
+    return live_tokens * kv_bytes_per_token(
+        hf, weights.get("itemsize", 2), kernels)

@@ -1,6 +1,8 @@
 """Model step (models/llama.py step programs): the bytes a fused decode
 dispatch had to read (weights once a step plus the cached K/V of every
-live token, chipbench.costs, from shapes) over the device time of one
+live token, from shapes: `step_read_bytes` of the configuration's cost
+module, `ctx["costs"]`, chipbench.costs unless its file names another;
+None from it leaves the metric out) over the device time of one
 (`jit_multi_fn` in the trace: seconds over count) over the chip's peak
 HBM bandwidth (%). Bound: memory.
 
@@ -23,6 +25,9 @@ def read(ctx):
     dev = tr["modules"].get("jit_multi_fn")
     if not dev or not dev["seconds"]:
         return None
+    step_read_bytes = costs.asked(ctx, "step_read_bytes")
+    if step_read_bytes is None:
+        return None
     w = ctx["weights"]
     page = ctx["page_size"]
     per_dispatch = []
@@ -33,9 +38,11 @@ def read(ctx):
         if k < 1.5:
             continue
         live = max(0.0, r["active_pages"] * page - r["n_decode"] * page / 2)
-        per_dispatch.append(k * costs.decode_step_bytes(
-            ctx["hf"], live, w.get("dense_itemsize", 2),
-            w.get("itemsize", 2), ctx["kernels"]))
+        nbytes = step_read_bytes(ctx["hf"], w, live, r["n_decode"],
+                                 ctx["kernels"])
+        if nbytes is None:
+            return None
+        per_dispatch.append(k * nbytes)
     if not per_dispatch:
         return None
     nbytes = sum(per_dispatch) / len(per_dispatch)

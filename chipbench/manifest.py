@@ -38,6 +38,31 @@ def config_of(manifest: dict, cell_: dict) -> dict:
     raise KeyError(f"no config {cell_['config']!r} in BENCHMARK.json")
 
 
+def module_of(conf: dict, key: str, default):
+    """What a configuration brings of its own, by file: `conf[key]` is the
+    path of a module (from the checkout's root, as a configuration's
+    `file` in BENCHMARK.json), or absent, which means `default`.
+
+    `reference_module` exports `compare(params, hf, streams) -> dict`
+    as chipbench/reference.py (the default) does, and may export
+    `served_widths(cfg) -> dict` keyed by the configuration file's own
+    keys. `costs_module` exports `step_read_bytes` and `kv_read_bytes`
+    as chipbench/costs.py (the default) does; either may be missing or
+    return None, and the metric that asks is then left out of the cell."""
+    path = conf.get(key)
+    if not path:
+        return default
+    return _load(ROOT / path, f"chipbench_{key}_{Path(path).stem}")
+
+
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(
+        name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def traffic_of(cell_: dict, base: Path = HERE) -> dict:
     with open(base / "traffic" / f"{cell_['traffic']}.json") as f:
         return json.load(f)
@@ -55,11 +80,5 @@ def metrics_of(manifest: dict, kind: str, cell_name: str) -> list[dict]:
 def layer_reader(name: str, base: Path = HERE):
     """The reader of one per-layer metric: `read(ctx) -> float | None`
     from chipbench/layer_metrics/<name>.py."""
-    path = base / "layer_metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_layer_metric_{name.replace('.', '_').replace('-', '_')}",
-        path,
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(base / "layer_metrics" / f"{name}.py",
+                 f"chipbench_layer_metric_{name}").read

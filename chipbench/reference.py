@@ -1,4 +1,7 @@
-"""The plain reference: a decoder-only transformer block in jax.numpy,
+"""The default plain reference (a configuration whose file names no
+`reference_module` is compared with this one; another architecture
+brings its own module with the same `compare`, see
+`manifest.module_of`): a decoder-only transformer block in jax.numpy,
 float32, `jax.default_matmul_precision("highest")`, no cache, no
 kernels, no batching. It follows the published Llama/Qwen2/Phi-3 layer
 equations from the configuration file's own keys: RMSNorm, q/k/v
@@ -104,25 +107,33 @@ def log_probs(params: dict, hf: dict, ids, at) -> np.ndarray:
     return np.asarray(out)
 
 
-def compare(params: dict, hf: dict, streams: list[dict]) -> dict:
+def compare(params: dict, hf: dict, streams: list[dict],
+            forward=None) -> dict:
     """Teacher-force each served greedy stream {prompt, out, logprobs}
     through the reference: argmax agreement, the largest |difference| of
     the served token's log-prob, and how far below the reference's own
     best the served token sits (an argmax flip is harmless where that is
-    ~0: seeded random weights make many near-ties)."""
+    ~0: seeded random weights make many near-ties), and the mean
+    |difference| over every token. `forward` is another
+    architecture's `log_probs`, so that its module keeps this arithmetic."""
+    forward = forward or log_probs
     agree = total = 0
-    drift = gap = 0.0
+    drift = gap = drift_sum = 0.0
     for s in streams:
         seq = list(s["prompt"]) + list(s["out"])
         n = len(s["out"])
         at = len(s["prompt"]) - 1 + np.arange(n)
-        lp = log_probs(params, hf, seq, at)
+        lp = forward(params, hf, seq, at)
         served = np.asarray(s["out"])
         of_served = lp[np.arange(n), served]
         agree += int((lp.argmax(-1) == served).sum())
         total += n
-        drift = max(drift, float(np.abs(
-            of_served - np.asarray(s["logprobs"], np.float32)).max()))
+        off = np.abs(of_served - np.asarray(s["logprobs"], np.float32))
+        drift = max(drift, float(off.max()))
+        drift_sum += float(off.sum())
         gap = max(gap, float((lp.max(-1) - of_served).max()))
     return {"tokens": total, "argmax_agreement": agree / max(total, 1),
-            "max_logprob_drift": drift, "max_gap_to_reference_best": gap}
+            "max_logprob_drift": drift, "max_gap_to_reference_best": gap,
+            # the mean over every token: steadier from stream to stream
+            # than the largest of 128, which is an extreme value
+            "mean_logprob_drift": drift_sum / max(total, 1)}

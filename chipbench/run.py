@@ -5,6 +5,10 @@ way `python -m dynamo_tpu.cli.run run in=http out=jax --model <preset>
 <serve flags of the configuration file>` does, drive it over HTTP from
 an asyncio client in this process, stop it, check the outputs, print the
 contract's one JSON line last. Earlier lines are free-form JSON notes.
+What belongs to one configuration arrives as files its own file names:
+its reference and served widths (`reference_module`), its byte counts
+(`costs_module`); see `manifest.module_of`. `--manifest` reads another
+BENCHMARK.json (tests).
 
 Without a TPU the run fails and prints no result. `JAX_PLATFORMS=cpu`
 asks for a rehearsal: the same control flow at the configuration's
@@ -31,7 +35,8 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 
-from chipbench import client, manifest, stats, tokenizer, trace, traffic  # noqa: E402
+from chipbench import (client, costs, manifest, reference, stats,  # noqa: E402
+                       tokenizer, trace, traffic)
 
 #: the traced slice of the window: long enough for hundreds of decode
 #: steps, short enough that the trace stays some tens of MB
@@ -265,22 +270,30 @@ async def greedy_streams(base, model, vocab, n=2, prompt_len=48, out_len=64):
     return streams
 
 
-def check_reference(params, hf: dict, streams, tol: dict) -> dict:
-    from chipbench import reference
-
-    res = reference.compare(params, hf, streams)
+def check_reference(params, hf: dict, streams, tol: dict,
+                    ref=reference) -> dict:
+    """`ref` is the configuration's reference module
+    (`manifest.module_of(conf, "reference_module", ...)`)."""
+    res = ref.compare(params, hf, streams)
     res["tolerance"] = tol
     res["passed"] = bool(
         all(len(s["out"]) == len(s["logprobs"]) == 64 for s in streams)
         and res["argmax_agreement"] >= tol["min_argmax_agreement"]
         and res["max_logprob_drift"] <= tol["max_logprob_drift"]
         and res["max_gap_to_reference_best"] <= tol["max_logprob_drift"]
+        # a configuration may also state a limit on the mean over tokens
+        and res["mean_logprob_drift"] <= tol.get("max_mean_logprob_drift",
+                                                 float("inf"))
     )
     return res
 
 
-def served_widths(cfg) -> dict:
-    """The served model's sizes under the configuration file's keys."""
+def served_widths(cfg, ref=reference) -> dict:
+    """The served model's sizes under the configuration file's keys:
+    the reference module's own `served_widths(cfg)` where it has one
+    (another architecture has other widths), else these seven."""
+    if hasattr(ref, "served_widths"):
+        return ref.served_widths(cfg)
     return {
         "hidden_size": cfg.hidden_size,
         "intermediate_size": cfg.intermediate_size,
@@ -315,6 +328,7 @@ async def run_cell(ns, man: dict, cell: dict, device: dict) -> dict:
     conf = manifest.config_of(man, cell)
     mix = manifest.traffic_of(cell)
     serve = conf if on_chip else conf["rehearsal"]
+    ref_mod = manifest.module_of(conf, "reference_module", reference)
     if not on_chip:
         mix = {**mix, **mix.get("rehearsal", {})}
     hf = conf if on_chip else serve["hf"]
@@ -333,7 +347,7 @@ async def run_cell(ns, man: dict, cell: dict, device: dict) -> dict:
     watch = asyncio.create_task(FAILURES.watch())
     try:
         cfg = getattr(engine.adapter.config, "base", engine.adapter.config)
-        widths = served_widths(cfg)
+        widths = served_widths(cfg, ref_mod)
         memory = engine.memory_report()["totals"]
         note("serve_up", model=preset, flags=flags,
              boot_s=round(time.perf_counter() - t, 2),
@@ -341,8 +355,9 @@ async def run_cell(ns, man: dict, cell: dict, device: dict) -> dict:
              num_pages=args.num_pages, max_seqs=args.max_seqs,
              memory=memory)
         structural = {
+            # every key the module returns, against the file's own
             "widths_as_published": all(
-                widths[k] == hf.get(k, widths[k]) for k in widths),
+                k in hf and widths[k] == hf[k] for k in widths),
             "attention_impl_pallas": (cfg.attention_impl == "pallas"
                                       or not on_chip),
         }
@@ -426,7 +441,8 @@ async def run_cell(ns, man: dict, cell: dict, device: dict) -> dict:
     params = engine.params
     engine.kv = None
     gc.collect()
-    ref = check_reference(params, hf, streams, conf["reference_tolerance"])
+    ref = check_reference(params, hf, streams, conf["reference_tolerance"],
+                          ref_mod)
     note("reference", **ref)
     correct = bool(
         on_chip and red["attempted"] > 0 and red["failed"] == 0
@@ -466,6 +482,7 @@ async def run_cell(ns, man: dict, cell: dict, device: dict) -> dict:
         "engine_now": m1, "memory": memory,
         "trace": reduced, "trace_info": trace_info,
         "hf": hf, "weights": serve.get("weights", {}),
+        "costs": manifest.module_of(conf, "costs_module", costs),
         "page_size": args.page_size,
         "kernels": cfg.attention_impl in ("pallas", "hybrid"),
         "peaks": peaks_for(device["kind"]) if on_chip else None,
@@ -489,8 +506,11 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, default=None)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--manifest", default=None,
+                    help="another BENCHMARK.json (tests; the default is "
+                         "the checkout's)")
     ns = ap.parse_args(argv)
-    man = manifest.load()
+    man = manifest.load(ns.manifest)
     cell = manifest.cell(man, ns.workload)
     if ns.seconds is None:
         ns.seconds = float(man["run_seconds"])

@@ -4,6 +4,11 @@ plan: which client sends what, in which order. No mix needs code of its
 own. Only the closed loop is here: the open-loop mixes of PERF.md
 section 7 bring their branch with the cell that proves them.
 
+Sizes are drawn from distributions given as data (`draw`): `const`
+(`value`), `uniform_int` (`min`..`max`, both ends included) and
+`lognormal` (`median`, `sigma` of the underlying normal, rounded to
+whole numbers and clipped to `min`..`max`).
+
 Steadiness: every size of a plan — prompt lengths, answer lengths, the
 cut of each client's first answer — is drawn from the mix's own
 `shape_seed`, client by client, so every run of a cell offers the same
@@ -14,6 +19,7 @@ content, not in work.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -52,6 +58,10 @@ def draw(dist: dict, rng: np.random.Generator, n: int) -> list[int]:
         return [int(dist["value"])] * n
     if kind == "uniform_int":
         return [int(v) for v in rng.integers(dist["min"], dist["max"] + 1, n)]
+    if kind == "lognormal":
+        raw = rng.lognormal(math.log(dist["median"]), dist["sigma"], n)
+        return [int(v) for v in
+                np.clip(np.rint(raw), dist["min"], dist["max"])]
     raise ValueError(f"unknown distribution {kind!r}")
 
 

@@ -68,6 +68,24 @@ def test_configs(man):
             assert key in conf
 
 
+def test_a_configurations_own_modules_are_files_under_paths(man):
+    """`reference_module` / `costs_module`: optional, and where named a
+    file under `paths` that exports what the harness calls."""
+    from chipbench import costs, reference
+
+    for c in man["configs"]:
+        with open(manifest.ROOT / c["file"]) as f:
+            conf = json.load(f)
+        for key, default, fn in (("reference_module", reference, "compare"),
+                                 ("costs_module", costs, "step_read_bytes")):
+            if key in conf:
+                assert PATH.match(conf[key])
+                assert any(conf[key].startswith(p + "/")
+                           for p in man["paths"])
+            assert callable(getattr(manifest.module_of(conf, key, default),
+                                    fn))
+
+
 def test_workloads(man):
     ws = man["workloads"]
     assert 1 <= len(ws) <= 24
@@ -116,6 +134,23 @@ def test_metrics(man):
         assert manifest.metrics_of(man, "per_layer", c)
 
 
+def test_a_tail_too_unsteady_for_a_bound_is_per_layer_in_that_cell(man):
+    """`itl_p95_ms` is end to end only where its runs repeat inside
+    half of the largest bound (PERF.md 6, PR 26); `qwen2-longgen` reads
+    the same gaps per layer, under another name."""
+    def names(kind, cell):
+        return [m["name"] for m in manifest.metrics_of(man, kind, cell)]
+
+    assert names("end_to_end", "qwen2-longgen") == ["output_tok_s", "setup_s"]
+    assert "itl_p95_ms" in names("end_to_end", "phi3-chat-closed")
+    assert "itl_p95_ms.longgen" in names("per_layer", "qwen2-longgen")
+    assert "itl_p95_ms.longgen" not in names("per_layer", "phi3-chat-closed")
+    read = manifest.layer_reader("itl_p95_ms.longgen")
+    gaps = [float(g) for g in range(1, 102)]
+    assert read({"client": {"gaps_ms": gaps}}) == 96.0
+    assert read({"client": {"gaps_ms": []}}) is None
+
+
 def test_files_under_paths_use_only_the_allowed_characters(man):
     for p in man["paths"]:
         for f in (manifest.ROOT / p).rglob("*"):
@@ -154,7 +189,7 @@ def test_a_cell_a_model_and_a_metric_are_added_by_files_and_entries(
     grown["per_layer"].append({
         "name": "dummy_steps", "unit": "steps", "better": "lower",
         "source": "program_counter", "layer": "engine loop (engine/engine.py)",
-        "moves": "itl_p95_ms", "workloads": ["dummy-cell"]})
+        "moves": "output_tok_s", "workloads": ["dummy-cell"]})
     cell = manifest.cell(grown, "dummy-cell")
     assert manifest.config_of(grown, cell)["preset"] == "tiny"
     got = manifest.traffic_of(cell, base=tmp_path)
@@ -167,6 +202,12 @@ def test_a_cell_a_model_and_a_metric_are_added_by_files_and_entries(
     read = manifest.layer_reader("dummy_steps", base=tmp_path)
     assert read({"flight": [1, 2, 3]}) == 3.0
     assert read({"flight": []}) is None  # nothing to read: left out
+    # a metric with a `workloads` key stays in its cells: the new cell
+    # reports no `itl_p95_ms` and none of another cell's split readings
+    assert [m["name"] for m in
+            manifest.metrics_of(grown, "end_to_end", "dummy-cell")] == \
+        ["output_tok_s", "setup_s"]
+    assert "itl_p95_ms.longgen" not in per_layer
     # the cells that were there report what they reported
     assert [m["name"] for m in
             manifest.metrics_of(grown, "per_layer", "qwen2-longgen")] == \

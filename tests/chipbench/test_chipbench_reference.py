@@ -100,3 +100,24 @@ def test_compare_reports_agreement_drift_and_gap():
     wrong = [{"prompt": prompt, "out": [(t + 1) % 256 for t in out],
               "logprobs": lps}]
     assert reference.compare(params, HF, wrong)["argmax_agreement"] < 1.0
+
+
+def test_a_configuration_may_also_limit_the_mean_drift(monkeypatch):
+    """`max_mean_logprob_drift` in a `reference_tolerance` is a further
+    gate; a file without it (qwen2-7b-int8.json) is judged as before."""
+    from chipbench import control, run
+
+    params = llama.init_params(jax.random.key(1), llama.LlamaConfig.tiny())
+    monkeypatch.setattr(control, "DENSE", ())  # the reference's own greedy
+    streams = control.control_streams(params, HF, 3)
+    for s in streams:
+        s["logprobs"] = [v + 0.02 for v in s["logprobs"]]  # all 0.02 off
+    tol = {"min_argmax_agreement": 0.9, "max_logprob_drift": 0.1}
+    res = run.check_reference(params, HF, streams, tol)
+    assert res["mean_logprob_drift"] == pytest.approx(0.02, abs=1e-4)
+    assert res["max_logprob_drift"] == pytest.approx(0.02, abs=1e-4)
+    assert res["passed"] is True
+    strict = {**tol, "max_mean_logprob_drift": 0.01}
+    assert run.check_reference(params, HF, streams, strict)["passed"] is False
+    loose = {**tol, "max_mean_logprob_drift": 0.03}
+    assert run.check_reference(params, HF, streams, loose)["passed"] is True

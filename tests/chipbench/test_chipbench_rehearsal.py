@@ -5,15 +5,18 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 from chipbench import manifest
 
 
-def test_rehearsal_walks_the_whole_flow_and_is_never_a_result():
+@pytest.mark.parametrize("cell", ["qwen2-longgen", "phi3-chat-closed"])
+def test_rehearsal_walks_the_whole_flow_and_is_never_a_result(cell):
     env = {**os.environ, "JAX_PLATFORMS": "cpu",
            "PYTHONPATH": str(manifest.ROOT)}
     env.pop("XLA_FLAGS", None)
     p = subprocess.run(
-        [sys.executable, "-m", "chipbench.run", "--workload", "qwen2-longgen",
+        [sys.executable, "-m", "chipbench.run", "--workload", cell,
          "--seed", "3000000019", "--seconds", "5", "--trace", "0"],
         cwd=manifest.ROOT, env=env, capture_output=True, text=True,
         timeout=600)
@@ -27,7 +30,7 @@ def test_rehearsal_walks_the_whole_flow_and_is_never_a_result():
     assert last["failed"] == 0
     man = manifest.load()
     want = {m["name"] for m in
-            manifest.metrics_of(man, "end_to_end", "qwen2-longgen")}
+            manifest.metrics_of(man, "end_to_end", cell)}
     assert set(last["metrics"]) == want
     assert all(m["value"] > 0 for m in last["metrics"].values())
     notes = {json.loads(x)["note"]: json.loads(x) for x in lines[:-1]}

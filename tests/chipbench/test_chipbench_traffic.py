@@ -83,6 +83,57 @@ def test_the_ramp_is_a_point_of_the_plan_and_its_lead_covers_a_decode():
             > m["output_tokens"]["max"] / 28.0 + 3.0)
 
 
+LOGNORMAL = {"dist": "lognormal", "median": 256, "sigma": 0.6,
+             "min": 64, "max": 512}
+
+
+def test_lognormal_is_whole_clipped_and_centred():
+    import numpy as np
+
+    got = traffic.draw(LOGNORMAL, np.random.default_rng(5), 20_000)
+    assert all(isinstance(v, int) for v in got)
+    assert min(got) == 64 and max(got) == 512
+    assert 246 <= sorted(got)[len(got) // 2] <= 266
+    # ln(2) / 0.6 = 1.155 standard deviations above the median: 12.4 %
+    assert 0.11 < sum(v == 512 for v in got) / len(got) < 0.14
+    assert 0.005 < sum(v == 64 for v in got) / len(got) < 0.02
+    narrow = traffic.draw({**LOGNORMAL, "sigma": 0.01},
+                          np.random.default_rng(5), 100)
+    assert set(narrow) <= set(range(245, 268))
+
+
+@pytest.mark.parametrize("other", [7, 2**31 + 11])
+def test_a_lognormal_plan_follows_the_shape_seed_and_never_the_seed(other):
+    m = mix("chat-closed")
+    assert m["prompt_tokens"]["dist"] == m["output_tokens"]["dist"] \
+        == "lognormal"
+    a = traffic.plan(m, 3_000_000_019, 32064)
+    assert skeleton(a) == skeleton(traffic.plan(m, other, 32064))
+    moved = traffic.plan({**m, "shape_seed": m["shape_seed"] + 1},
+                         3_000_000_019, 32064)
+    assert skeleton(a) != skeleton(moved)
+    assert [len(c) for c in moved.clients] == [len(c) for c in a.clients]
+
+
+def test_chat_closed_is_the_issues_mix():
+    m = mix("chat-closed")
+    assert (m["loop"], m["clients"]) == ("closed", 16)
+    assert m["sampling"] == {"temperature": 0.7, "top_p": 0.9}
+    assert m["phase_first_request"] is True
+    for key, median, sigma, lo, hi in (("prompt_tokens", 256, 0.6, 64, 512),
+                                       ("output_tokens", 128, 0.8, 16, 512)):
+        d = m[key]
+        assert (d["median"], d["sigma"], d["min"], d["max"]) == \
+            (median, sigma, lo, hi)
+    p = traffic.plan(m, 1, 32064)
+    assert {len(c[0].new_ids) for c in p.clients} == {256}
+    # phi3's sliding window (2047) never binds: no sequence passes 1,024
+    assert max(len(t.new_ids) + t.max_tokens
+               for c in p.clients for t in c) <= 1024
+    prompts = {tuple(t.new_ids) for c in p.clients for t in c}
+    assert len(prompts) == 16 * m["requests_per_client"]  # nothing shared
+
+
 def test_unknown_loop_or_distribution_is_an_error():
     m = mix("longgen")
     with pytest.raises(ValueError):
