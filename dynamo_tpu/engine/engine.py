@@ -3730,11 +3730,10 @@ class JaxEngine:
         the padding and inject restores it, so disagg peers and KVBM tiers
         with different attention impls interoperate (and host/disk tiers
         don't store zero lanes). MLA caches are ASYMMETRIC (k = latent,
-        v = rope key) and unpadded — their widths come straight from the
-        cache."""
+        v = rope key, lane-padded under the kernels like any head)."""
         cfg = self.adapter.config
-        if hasattr(cfg, "kv_lora_rank"):  # MLA: unpadded, asymmetric
-            return (self.kv.k.shape[-1], self.kv.v.shape[-1])
+        if hasattr(cfg, "kv_lora_rank"):  # MLA: asymmetric
+            return (cfg.kv_lora_rank, cfg.qk_rope_head_dim)
         d = cfg.head_dim if hasattr(cfg, "head_dim") else cfg.base.head_dim
         return (d, d)
 

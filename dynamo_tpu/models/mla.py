@@ -11,7 +11,28 @@ as the Llama family — with the cache holding the COMPRESSED latent:
 Per token the cache costs kv_lora+rope floats (576 for V2 shapes) —
 ~9x smaller than the equivalent MHA cache — and every generic subsystem
 (page allocator, prefix caching, tiering, disagg transfer) carries it
-unchanged because they treat KVPages as opaque pages.
+unchanged because they treat KVPages as opaque pages. Under
+attention_impl="pallas" the rope key is cached in whole lane tiles
+(`kv_rope_dim`: 64 -> 128 columns, zeros past the key): a 64-wide minor
+dimension fills half a tile in HBM either way, so a token costs
+(512 + 128) x 2 B = 1280 B a layer there in any layout, and the two
+arrays keep the engine's (k, v) contract and the wire format (512, 64)
+as they are. One 640-wide array would cost the same bytes and a second
+meaning for `KVPages.v`.
+
+Two write disciplines, as models/llama.py's attention_block:
+
+- "xla": scatter the chunk's latent and rope key, then gather the page
+  table's whole width in float32 and attend. Any backend, any mesh; what
+  the CPU tests and the golden tests run.
+- "pallas": the cache is read-only inside the layer scan; the chunk's
+  rows are staged and land once a step (ops/kv_update.paged_write).
+  Decode (T = 1) walks the live pages in ops/paged_attention.py's kernel
+  (`latent=True`: one page is key and value at once, 16 heads in one
+  dot) and folds the current token in exactly; a prefill chunk attends
+  over itself and, unless it is a first chunk, over blocks of its live
+  history pages in a loop of plain XLA (`_latent_prefill_attention`)
+  that stops at the longest row's history, not at the table's width.
 
 Attention runs in the ABSORBED form (the deployment form from the
 DeepSeek-V2 paper): q_nope is projected by W_UK^T into the latent space
@@ -33,9 +54,13 @@ yarn_mscale(factor, mscale_all_dim)^2 — both matching HF).
 
 MoE layers follow HF DeepseekV2MoE semantics: softmax gate -> greedy
 top-k (weights NOT renormalized unless norm_topk_prob) scaled by
-routed_scaling_factor, plus always-on shared experts. Routed experts use
-the same static-shape GShard dispatch/combine as models/moe.py, with the
-expert axis sharded over the mesh's "ep" axis. The first
+routed_scaling_factor, plus always-on shared experts. Routed experts are
+DROPLESS: the N x k assignments are sorted by expert and each projection
+is one grouped matmul over the contiguous groups (ops/grouped_matmul.py:
+`jax.lax.ragged_dot`'s contract, a Pallas kernel on the TPU; operands
+in the model dtype, float32 accumulation), then un-sorted and
+summed with the gate's weights. Every assignment is computed: there is no
+capacity. The expert axis is sharded over the mesh's "ep" axis. The first
 `first_k_dense_replace` layers use a dense MLP (V2-Lite: layer 0) — the
 layer stack is two lax.scans (dense prefix, MoE suffix), keeping params
 scan-stacked without per-layer Python unrolling.
@@ -53,9 +78,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from dynamo_tpu.models.llama import (
+    _PALLAS_DECODE_VMEM_BUDGET as _DECODE_VMEM_BUDGET,
     KVPages,
     _mm,
     _w,
+    maybe_decode_work,
     paged_gather,
     paged_scatter,
     quantize_channelwise_int8,
@@ -103,7 +130,9 @@ class MlaConfig:
     rms_norm_eps: float = 1e-6
     tie_word_embeddings: bool = False
     dtype: Any = jnp.bfloat16
-    attention_impl: str = "xla"  # only the XLA path exists for MLA
+    #: "xla", or "pallas" / "hybrid": the latent page walk in a kernel and
+    #: the staged cache write (module text)
+    attention_impl: str = "xla"
     # -- MoE (None/0 experts = dense model) --------------------------------
     n_routed_experts: int = 0
     n_shared_experts: int = 0
@@ -112,13 +141,6 @@ class MlaConfig:
     first_k_dense_replace: int = 1
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = False
-    capacity_factor: float = 2.0
-    #: routed experts computed per lax.map step in the MoE FFN: bounds the
-    #: f32 expert intermediates (xe/gate/up/down) AND the dequantized
-    #: int8 expert weights to one group's worth instead of all E at once
-    #: — the all-at-once temps (264M+192M+132M at V2-Lite decode shapes)
-    #: OOM'd a v5e chip. 0 = auto-size groups to ~_MOE_CHUNK_BYTES.
-    moe_expert_chunk: int = 0
     #: "greedy" (V2-Lite), "group_limited_greedy" (V2/V2-Chat), or
     #: "noaux_tc" (V3/R1: sigmoid scores + aux-loss-free bias-corrected
     #: group routing). Groups rank by max member (V2) / top-2 sum (V3) of
@@ -153,6 +175,17 @@ class MlaConfig:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
+    def kernels(self) -> bool:
+        return self.attention_impl in ("pallas", "hybrid")
+
+    @property
+    def kv_rope_dim(self) -> int:
+        """Columns of the cached rope key: whole 128-lane tiles under the
+        kernels (Mosaic DMA slices are lane-aligned), zeros past the key."""
+        r = self.qk_rope_head_dim
+        return -(-r // 128) * 128 if self.kernels else r
+
+    @property
     def num_kv_heads(self) -> int:
         """MLA stores ONE shared latent per token (MQA-shaped cache)."""
         return 1
@@ -174,16 +207,22 @@ class MlaConfig:
         return self.num_layers - self.num_dense_layers
 
     @staticmethod
-    def deepseek_v2_lite() -> "MlaConfig":
-        """DeepSeek-V2-Lite (15.7B total / 2.4B active): MLA with direct q,
-        layer 0 dense, 26 MoE layers of 64 routed (top-6, greedy) + 2
-        shared experts. Plain-rope shape for random-weight benching; real
-        checkpoints load their YaRN fields from config.json."""
+    def deepseek_v2_lite(num_layers: int = 27) -> "MlaConfig":
+        """DeepSeek-V2-Lite (15.7B total / 2.4B active) as its config.json
+        publishes it: MLA with direct q, layer 0 dense, 26 MoE layers of
+        64 routed (top-6, greedy) + 2 shared experts, YaRN rope (factor
+        40, mscale = mscale_all_dim = 0.707, original 4096). The softmax
+        scale follows the HF port (`rope_mscale_softmax` False).
+        `num_layers` cuts depth only (the one-chip preset keeps layer 0
+        dense and 7 expert layers); every width stays."""
         return MlaConfig(
             vocab_size=102400, hidden_size=2048, intermediate_size=10944,
-            num_layers=27, num_heads=16, q_lora_rank=None,
+            num_layers=num_layers, num_heads=16, q_lora_rank=None,
             kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
             v_head_dim=128, rope_theta=10000.0,
+            rope_scaling_factor=40.0, rope_beta_fast=32.0,
+            rope_beta_slow=1.0, rope_mscale=0.707,
+            rope_mscale_all_dim=0.707, rope_original_max_position=4096,
             n_routed_experts=64, n_shared_experts=2,
             moe_intermediate_size=1408, num_experts_per_tok=6,
             first_k_dense_replace=1,
@@ -199,7 +238,7 @@ class MlaConfig:
             vocab_size=vocab_size, dtype=jnp.float32, num_layers=3,
             n_routed_experts=4, n_shared_experts=1,
             moe_intermediate_size=32, num_experts_per_tok=2,
-            first_k_dense_replace=1, capacity_factor=4.0,
+            first_k_dense_replace=1,
         )
 
     @staticmethod
@@ -287,14 +326,15 @@ class MlaConfig:
 
 
 def init_kv_pages(cfg: MlaConfig, num_pages: int, page_size: int) -> KVPages:
-    """k holds the latent c_kv, v the shared rope key — see module doc."""
+    """k holds the latent c_kv, v the shared rope key (`kv_rope_dim`
+    columns) — see module doc."""
     return KVPages(
         k=jnp.zeros(
             (cfg.num_layers, num_pages, page_size, 1, cfg.kv_lora_rank),
             cfg.dtype,
         ),
         v=jnp.zeros(
-            (cfg.num_layers, num_pages, page_size, 1, cfg.qk_rope_head_dim),
+            (cfg.num_layers, num_pages, page_size, 1, cfg.kv_rope_dim),
             cfg.dtype,
         ),
     )
@@ -583,6 +623,137 @@ def _interleaved_rope(x: jax.Array, positions: jax.Array, cfg: MlaConfig):
     )
 
 
+#: masked scores; finite so that a padded query row stays NaN-free
+_MASKED = -1e30
+#: history pages one turn of the prefill loop gathers (512 keys at S=64)
+_PREFILL_BLOCK_PAGES = 8
+
+
+def _pad_last(x: jax.Array, width: int) -> jax.Array:
+    return jnp.pad(
+        x, ((0, 0),) * (x.ndim - 1) + ((0, width - x.shape[-1]),)
+    )
+
+
+def _latent_decode(qd, c_cur, pe_cur, k_cache, v_cache, layer, page_tables,
+                   hist, cfg: MlaConfig, decode_work, mesh):
+    """o_lat [B, H, c] f32 of one decode step: the kernel's walk over the
+    history pages, then the current (staged, unwritten) token folded in
+    exactly. qd [B, H, c+R] is the absorbed query, then its rope part;
+    c_cur [B, c] and pe_cur [B, R] the current token's rows."""
+    from dynamo_tpu.ops.paged_attention import (
+        decode_vmem_bytes,
+        paged_decode_attention,
+    )
+
+    b, hn, _ = qd.shape
+    c, scale = cfg.kv_lora_rank, cfg.softmax_scale
+    tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+
+    def walk(rows, work):
+        return paged_decode_attention(
+            qd[rows], k_cache, v_cache, layer, page_tables[rows],
+            hist[rows], scale=scale, latent=True, mesh=mesh,
+            work_list=work, vmem_budget=_DECODE_VMEM_BUDGET,
+        )
+
+    # the kernel keeps the whole batch's q and acc in VMEM: a batch too
+    # large for the budget walks in halves (a second work list, built here)
+    piece = b
+    while piece > 1 and decode_vmem_bytes(
+        piece, hn // tp, c, k_cache.shape[2], 1,
+        jnp.dtype(k_cache.dtype).itemsize, budget=_DECODE_VMEM_BUDGET,
+        rope_dim=cfg.kv_rope_dim,
+    ) > _DECODE_VMEM_BUDGET:
+        piece = -(-piece // 2)
+    if piece == b:
+        acc, m, l = walk(slice(None), decode_work)
+    else:
+        parts = [walk(slice(i, i + piece), None) for i in range(0, b, piece)]
+        acc, m, l = (jnp.concatenate(x) for x in zip(*parts))
+    qf = qd.astype(jnp.float32)
+    s_self = scale * (
+        jnp.einsum("bhc,bc->bh", qf[..., :c], c_cur.astype(jnp.float32))
+        + jnp.einsum("bhr,br->bh", qf[..., c:], pe_cur.astype(jnp.float32))
+    )
+    m_star = jnp.maximum(m, s_self)
+    alpha, beta = jnp.exp(m - m_star), jnp.exp(s_self - m_star)
+    return (
+        alpha[..., None] * acc
+        + beta[..., None] * c_cur.astype(jnp.float32)[:, None, :]
+    ) / (alpha * l + beta)[..., None]
+
+
+def _latent_prefill_attention(
+    q_lat, q_pe, c_kv, k_pe, k_cache, v_cache, layer, page_tables,
+    positions, valid, cfg: MlaConfig, first_chunk: bool,
+):
+    """o_lat [B, T, H, c] f32 of a prefill chunk in the absorbed form, in
+    plain XLA: the chunk over itself (causal by position) and, unless
+    `first_chunk`, over its history in the cache, `_PREFILL_BLOCK_PAGES`
+    pages a turn with one online softmax, for as many turns as the longest
+    row's history needs: the live pages, not the page table's width.
+    Operands go to the MXU in the model dtype; scores, softmax and sums are
+    float32. The history is what lies before the chunk's first position
+    (chunks start page-aligned; the chunk's own rows are staged, not yet
+    in the cache)."""
+    dt, r = cfg.dtype, cfg.qk_rope_head_dim
+    f32 = jnp.float32
+    ql = (q_lat * cfg.softmax_scale).astype(dt)
+    qp = (q_pe.astype(f32) * cfg.softmax_scale).astype(dt)
+
+    def scores(ck, pk):  # [B, K, c], [B, K, r] -> [B, H, T, K] f32
+        return jnp.einsum(
+            "bthc,bkc->bhtk", ql, ck, preferred_element_type=f32
+        ) + jnp.einsum("bthr,bkr->bhtk", qp, pk, preferred_element_type=f32)
+
+    def weighted(p, ck):  # [B, H, T, K] f32, [B, K, c] -> [B, H, T, c] f32
+        return jnp.einsum(
+            "bhtk,bkc->bhtc", p.astype(dt), ck, preferred_element_type=f32
+        )
+
+    q_pos = positions[:, None, :, None]
+    cur_pos = jnp.where(valid, positions, jnp.int32(1 << 30))
+    s = jnp.where(
+        cur_pos[:, None, None, :] <= q_pos,
+        scores(c_kv.astype(dt), k_pe.astype(dt)), _MASKED,
+    )
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    acc = weighted(p, c_kv.astype(dt))
+    if not first_chunk:
+        page = k_cache.shape[2]
+        pb = _PREFILL_BLOCK_PAGES
+        start = jnp.where(valid[:, 0], positions[:, 0], 0)  # [B] history
+        pt = jnp.pad(page_tables, ((0, 0), (0, -page_tables.shape[1] % pb)))
+        lat = lax.dynamic_index_in_dim(k_cache, layer, 0, keepdims=False)
+        rope = lax.dynamic_index_in_dim(v_cache, layer, 0, keepdims=False)
+        b = pt.shape[0]
+
+        def block(i, carry):
+            m, l, acc = carry
+            pages = lax.dynamic_slice_in_dim(pt, i * pb, pb, axis=1)
+            ck = lat[pages].reshape(b, pb * page, -1)
+            pk = rope[pages].reshape(b, pb * page, -1)[..., :r]
+            key_pos = i * (pb * page) + jnp.arange(pb * page)
+            s = jnp.where(
+                key_pos[None, None, None, :] < start[:, None, None, None],
+                scores(ck, pk), _MASKED,
+            )
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            corr = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            return (
+                m_new, l * corr + jnp.sum(p, axis=-1, keepdims=True),
+                acc * corr + weighted(p, ck),
+            )
+
+        n_blk = -(-jnp.max(start) // (pb * page))
+        m, l, acc = lax.fori_loop(0, n_blk, block, (m, l, acc))
+    return (acc / l).transpose(0, 2, 1, 3)
+
+
 def mla_attention(
     x: jax.Array,  # [B, T, H'] post-attn-norm
     lp: dict,
@@ -592,166 +763,187 @@ def mla_attention(
     page_tables: jax.Array,
     positions: jax.Array,
     valid: jax.Array,
+    first_chunk: bool = False,  # static: every row starts at position 0
+    decode_work=None,  # ops.paged_attention.decode_work_list, layer-invariant
+    mesh=None,
 ):
+    """Returns (attn [B, T, H'], k_cache, v_cache, staged): `staged` is
+    None under the xla discipline (the caches come back written) and the
+    chunk's (latent, rope key) rows under the kernels (the caches come
+    back as they went in). Scopes, under the caller's `attn`: `qkv`,
+    `kv_update`, `absorb`, `paged` (reads cache pages: the decode walk),
+    `flash` (a prefill chunk, with or without history), `out`."""
     b, t, _ = x.shape
     hn, r, c = cfg.num_heads, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     n, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
     k_cache, v_cache = kv
 
-    if cfg.q_lora_rank:
-        qa = rms_norm(
-            _mm(x, lp, "wq_a", cfg.dtype).astype(cfg.dtype),
-            lp["q_a_norm"], cfg.rms_norm_eps,
-        )
-        q = _mm(qa, lp, "wq_b", cfg.dtype).reshape(
-            b, t, hn, cfg.qk_head_dim
-        )
-    else:
-        q = _mm(x, lp, "wq", cfg.dtype).reshape(b, t, hn, cfg.qk_head_dim)
-    q_nope, q_pe = q[..., :n], q[..., n:]
-    q_pe = _interleaved_rope(q_pe, positions, cfg)
+    with jax.named_scope("qkv"):
+        if cfg.q_lora_rank:
+            qa = rms_norm(
+                _mm(x, lp, "wq_a", cfg.dtype).astype(cfg.dtype),
+                lp["q_a_norm"], cfg.rms_norm_eps,
+            )
+            q = _mm(qa, lp, "wq_b", cfg.dtype).reshape(
+                b, t, hn, cfg.qk_head_dim
+            )
+        else:
+            q = _mm(x, lp, "wq", cfg.dtype).reshape(
+                b, t, hn, cfg.qk_head_dim
+            )
+        q_nope, q_pe = q[..., :n], q[..., n:]
+        q_pe = _interleaved_rope(q_pe, positions, cfg)
 
-    kv_a = _mm(x, lp, "wkv_a", cfg.dtype)  # [B,T,c+r]
-    c_kv = rms_norm(
-        kv_a[..., :c].astype(cfg.dtype), lp["kv_a_norm"], cfg.rms_norm_eps
-    )
-    k_pe = _interleaved_rope(kv_a[..., c:], positions, cfg)
+        kv_a = _mm(x, lp, "wkv_a", cfg.dtype)  # [B,T,c+r]
+        c_kv = rms_norm(
+            kv_a[..., :c].astype(cfg.dtype), lp["kv_a_norm"],
+            cfg.rms_norm_eps,
+        )
+        k_pe = _interleaved_rope(kv_a[..., c:], positions, cfg).astype(
+            cfg.dtype
+        )
+
+    if cfg.kernels:
+        return _mla_attention_kernels(
+            q_nope, q_pe, c_kv, k_pe, lp, cfg, kv, layer, page_tables,
+            positions, valid, first_chunk, decode_work, mesh,
+        )
 
     # Land this chunk's latent + rope key, then attend over the gathered
     # (history + current) cache — same scatter-then-gather discipline as
     # the Llama XLA path, so causality is pure position masking.
-    k_cache = paged_scatter(
-        k_cache, layer, c_kv[:, :, None, :], page_tables, positions, valid
-    )
-    v_cache = paged_scatter(
-        v_cache, layer, k_pe.astype(cfg.dtype)[:, :, None, :], page_tables,
-        positions, valid,
-    )
-    c_hist = paged_gather(k_cache, layer, page_tables)[:, :, 0]  # [B,K,c]
-    pe_hist = paged_gather(v_cache, layer, page_tables)[:, :, 0]  # [B,K,r]
-
-    wkv_b = _w(lp, "wkv_b", jnp.float32).reshape(c, hn, n + vd)
-    w_uk, w_uv = wkv_b[..., :n], wkv_b[..., n:]
-
-    scale = cfg.softmax_scale
-    q_lat = jnp.einsum(
-        "bthn,chn->bthc", q_nope.astype(jnp.float32),
-        w_uk.astype(jnp.float32),
-    )
-    scores = (
-        jnp.einsum("bthc,bkc->bhtk", q_lat, c_hist.astype(jnp.float32))
-        + jnp.einsum(
-            "bthr,bkr->bhtk", q_pe.astype(jnp.float32),
-            pe_hist.astype(jnp.float32),
+    with jax.named_scope("kv_update"):
+        k_cache = paged_scatter(
+            k_cache, layer, c_kv[:, :, None, :], page_tables, positions,
+            valid,
         )
-    ) * scale
-    kk = c_hist.shape[1]
-    key_pos = jnp.arange(kk)[None, None, None, :]
-    mask = key_pos <= positions[:, None, :, None]
-    scores = jnp.where(mask, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    o_lat = jnp.einsum("bhtk,bkc->bthc", probs, c_hist.astype(jnp.float32))
-    out = jnp.einsum("bthc,chv->bthv", o_lat, w_uv.astype(jnp.float32))
-    out = out.reshape(b, t, hn * vd).astype(cfg.dtype)
-    return _mm(out, lp, "wo", cfg.dtype), k_cache, v_cache
+        v_cache = paged_scatter(
+            v_cache, layer, k_pe[:, :, None, :], page_tables, positions,
+            valid,
+        )
+    with jax.named_scope("paged"):
+        c_hist = paged_gather(k_cache, layer, page_tables)[:, :, 0]  # [B,K,c]
+        pe_hist = paged_gather(v_cache, layer, page_tables)[:, :, 0]  # [B,K,r]
+
+        wkv_b = _w(lp, "wkv_b", jnp.float32).reshape(c, hn, n + vd)
+        w_uk, w_uv = wkv_b[..., :n], wkv_b[..., n:]
+
+        scale = cfg.softmax_scale
+        q_lat = jnp.einsum(
+            "bthn,chn->bthc", q_nope.astype(jnp.float32), w_uk
+        )
+        scores = (
+            jnp.einsum("bthc,bkc->bhtk", q_lat, c_hist.astype(jnp.float32))
+            + jnp.einsum(
+                "bthr,bkr->bhtk", q_pe.astype(jnp.float32),
+                pe_hist.astype(jnp.float32),
+            )
+        ) * scale
+        kk = c_hist.shape[1]
+        key_pos = jnp.arange(kk)[None, None, None, :]
+        mask = key_pos <= positions[:, None, :, None]
+        scores = jnp.where(mask, scores, _MASKED)
+        probs = jax.nn.softmax(scores, axis=-1)
+        o_lat = jnp.einsum(
+            "bhtk,bkc->bthc", probs, c_hist.astype(jnp.float32)
+        )
+    with jax.named_scope("out"):
+        out = jnp.einsum("bthc,chv->bthv", o_lat, w_uv)
+        out = out.reshape(b, t, hn * vd).astype(cfg.dtype)
+        return _mm(out, lp, "wo", cfg.dtype), k_cache, v_cache, None
+
+
+def _mla_attention_kernels(
+    q_nope, q_pe, c_kv, k_pe, lp, cfg: MlaConfig, kv, layer, page_tables,
+    positions, valid, first_chunk, decode_work, mesh,
+):
+    """The kernels' discipline (module text): the cache is read, never
+    written here; the chunk's rows come back as `staged`."""
+    b, t, hn, n = q_nope.shape
+    c, vd = cfg.kv_lora_rank, cfg.v_head_dim
+    k_cache, v_cache = kv
+    wkv_b = _w(lp, "wkv_b", cfg.dtype).reshape(c, hn, n + vd)
+    w_uk, w_uv = wkv_b[..., :n], wkv_b[..., n:]
+    pe_rows = _pad_last(k_pe, cfg.kv_rope_dim)  # the rope key as cached
+
+    with jax.named_scope("absorb"):
+        q_lat = jnp.einsum(
+            "bthn,chn->bthc", q_nope, w_uk,
+            preferred_element_type=jnp.float32,
+        )
+    if t == 1:
+        with jax.named_scope("paged"):
+            qd = jnp.concatenate(
+                [q_lat.astype(cfg.dtype), _pad_last(q_pe, cfg.kv_rope_dim)],
+                axis=-1,
+            )[:, 0]
+            o_lat = _latent_decode(
+                qd, c_kv[:, 0], pe_rows[:, 0], k_cache, v_cache, layer,
+                page_tables, positions[:, 0], cfg, decode_work, mesh,
+            )[:, None]
+    else:
+        with jax.named_scope("flash"):
+            o_lat = _latent_prefill_attention(
+                q_lat, q_pe, c_kv, k_pe, k_cache, v_cache, layer,
+                page_tables, positions, valid, cfg, first_chunk,
+            )
+    with jax.named_scope("out"):
+        out = jnp.einsum(
+            "bthc,chv->bthv", o_lat.astype(cfg.dtype), w_uv,
+            preferred_element_type=jnp.float32,
+        )
+        out = out.reshape(b, t, hn * vd).astype(cfg.dtype)
+        attn = _mm(out, lp, "wo", cfg.dtype)
+    staged = (c_kv[:, :, None, :], pe_rows[:, :, None, :])
+    return attn, k_cache, v_cache, staged
 
 
 # ---------------------------------------------------------------------------
-# MoE FFN (DeepSeek semantics, GShard static dispatch)
+# MoE FFN (DeepSeek semantics, dropless sort-by-expert dispatch)
 # ---------------------------------------------------------------------------
 
 
-#: auto expert-chunk byte budget for _routed_expert_ffn's per-group f32
-#: temporaries + dequantized weights (v5e has ~16G HBM; keep the MoE FFN's
-#: transient share well under the KV pool + params headroom)
-_MOE_CHUNK_BYTES = 128 << 20
-
-
-def _auto_expert_chunk(e: int, cap: int, h: int, i: int) -> int:
-    """Largest divisor of `e` whose per-group transients fit the budget:
-    per expert the FFN holds xe/down ([C, H] f32 each), gate/up ([C, I]
-    f32 each) plus the dequantized f32 weight slices (3·H·I)."""
-    per_expert = 4 * (cap * (2 * h + 2 * i) + 3 * h * i)
-    g = max(1, min(e, _MOE_CHUNK_BYTES // max(per_expert, 1)))
-    while e % g:
-        g -= 1
-    return g
-
-
-def _routed_expert_ffn(
-    xf: jax.Array,  # [N, H] f32 tokens
-    dispatch: jax.Array,  # [N, E, C] f32 one-hot dispatch
-    combine: jax.Array,  # [N, E, C] f32 weighted combine
+def _grouped_ffn(
+    xs: jax.Array,  # [M, H] rows sorted by expert, model dtype
+    expert_of_row: jax.Array,  # [M] int32, ascending
+    group_sizes: jax.Array,  # [E] int32, sums to M
     lp: dict,
     cfg: MlaConfig,
-    cap: int,
+    mesh=None,
+    stack=None,
 ) -> jax.Array:
-    """The routed experts' gated FFN, chunked over expert groups.
+    """The routed experts' gated FFN over contiguous groups of rows: one
+    grouped matmul a projection, operands in the model dtype, float32
+    accumulation. Int8 expert weights go in as the exact integers they
+    are and their per-output-channel scales multiply the product's rows.
+    `stack` is (every expert layer's matrices [L, E, ., .] by name, this
+    layer's index in them): a layer scan hands the matrices in whole, so
+    that the kernel reads this layer's in place (ops/grouped_matmul.py)."""
+    from dynamo_tpu.ops.grouped_matmul import grouped_matmul
 
-    The fused all-experts einsum chain materializes xe [E, C, H] +
-    gate/up [E, C, I] f32 (264M+192M+132M at V2-Lite decode shapes) plus
-    — with int8 expert weights — the full [E, H, I] f32 dequants, which
-    OOMs a single v5e chip. lax.map over groups of `moe_expert_chunk`
-    experts rematerializes per group: same contractions, same f32
-    accumulation within a group, peak transients divided by E/group
-    (the cross-group sum reorders f32 adds — sub-ulp vs the fused path).
-    """
-    nt, e, _ = dispatch.shape
-    h = xf.shape[1]
-    i = cfg.moe_intermediate_size
-    eg = cfg.moe_expert_chunk or _auto_expert_chunk(e, cap, h, i)
-    eg = max(1, min(eg, e))
-    while e % eg:
-        eg -= 1
-
-    def dequant(w, scale):
-        if scale is None:
-            return w.astype(jnp.float32)
-        return w.astype(jnp.float32) * scale.astype(jnp.float32)
-
-    if eg == e:  # one group — the original fused path, no map overhead
-        xe = jnp.einsum("nec,nh->ech", dispatch, xf)
-        gate = jax.nn.silu(
-            jnp.einsum("ech,ehi->eci", xe, _w(lp, "we_gate", jnp.float32))
+    def grouped(x, name):
+        if stack is not None and name in stack[0]:
+            w, layer = stack[0][name], stack[1]
+        else:  # int8: this layer's integers as the model dtype holds them
+            w, layer = lp[name].astype(cfg.dtype), None
+        out = grouped_matmul(
+            x, w, group_sizes, layer=layer,
+            use_kernel=None if mesh is None else False,
         )
-        up = jnp.einsum("ech,ehi->eci", xe, _w(lp, "we_up", jnp.float32))
-        down = jnp.einsum(
-            "eci,eih->ech", gate * up, _w(lp, "we_down", jnp.float32)
-        )
-        return jnp.einsum("nec,ech->nh", combine, down)
+        if name + "_scale" in lp:  # int8: scale [E, 1, out]
+            out = out * lp[name + "_scale"][:, 0][expert_of_row]
+        return out
 
-    ng = e // eg
-    quantized = lp["we_gate"].dtype == jnp.int8
-    xs = {
-        "disp": dispatch.reshape(nt, ng, eg, cap).transpose(1, 0, 2, 3),
-        "comb": combine.reshape(nt, ng, eg, cap).transpose(1, 0, 2, 3),
-    }
-    for name in ("we_gate", "we_up", "we_down"):
-        w = lp[name]
-        xs[name] = w.reshape(ng, eg, *w.shape[1:])
-        if quantized:
-            s = lp[name + "_scale"]
-            xs[name + "_s"] = s.reshape(ng, eg, *s.shape[1:])
-
-    def group(g):
-        wg = dequant(g["we_gate"], g.get("we_gate_s"))
-        wu = dequant(g["we_up"], g.get("we_up_s"))
-        wd = dequant(g["we_down"], g.get("we_down_s"))
-        xe = jnp.einsum("nec,nh->ech", g["disp"], xf)  # [eg, C, H]
-        gate = jax.nn.silu(jnp.einsum("ech,ehi->eci", xe, wg))
-        up = jnp.einsum("ech,ehi->eci", xe, wu)
-        down = jnp.einsum("eci,eih->ech", gate * up, wd)
-        return jnp.einsum("nec,ech->nh", g["comb"], down)  # [N, H]
-
-    return jnp.sum(lax.map(group, xs), axis=0)
+    gate = jax.nn.silu(grouped(xs, "we_gate"))
+    return grouped((gate * grouped(xs, "we_up")).astype(cfg.dtype), "we_down")
 
 
-def _deepseek_moe_ffn(x: jax.Array, lp: dict, cfg: MlaConfig) -> jax.Array:
-    b, t, h = x.shape
-    nt = b * t
+def _gate(xf: jax.Array, lp: dict, cfg: MlaConfig):
+    """(topw [N, k] f32, topi [N, k]): router logits and scores in
+    float32, top-k by the configuration's method, weights scaled by
+    `routed_scaling_factor`."""
+    nt = xf.shape[0]
     e, k = cfg.n_routed_experts, cfg.num_experts_per_tok
-    xf = x.reshape(nt, h)
-
     logits = (xf.astype(jnp.float32)) @ lp["w_router"].astype(jnp.float32)
 
     def _group_mask(choice, rank_fn):
@@ -789,40 +981,65 @@ def _deepseek_moe_ffn(x: jax.Array, lp: dict, cfg: MlaConfig) -> jax.Array:
         if cfg.norm_topk_prob:
             topw = topw / jnp.sum(topw, axis=-1, keepdims=True)
     topw = topw * cfg.routed_scaling_factor
+    return topw, topi
 
-    cap = max(1, int(math.ceil(k * nt / e * cfg.capacity_factor)))
-    # one-hot dispatch with per-expert capacity (same shape discipline as
-    # models/moe.py — over-capacity tokens drop their expert contribution)
-    onehot = jax.nn.one_hot(topi, e, dtype=jnp.float32)  # [N,k,E]
-    pos_in_e = (
-        jnp.cumsum(onehot.reshape(nt * k, e), axis=0).reshape(nt, k, e)
-        - onehot
-    )
-    keep = pos_in_e < cap
-    onehot = onehot * keep
-    slot = jax.nn.one_hot(
-        jnp.sum(pos_in_e, axis=-1, where=onehot > 0, initial=0.0).astype(
-            jnp.int32
-        ),
-        cap,
-        dtype=jnp.float32,
-    )  # [N,k,C]
-    dispatch = jnp.einsum("nke,nkc->nec", onehot, slot)  # [N,E,C]
-    combine = jnp.einsum("nke,nkc,nk->nec", onehot, slot, topw)
 
-    routed = _routed_expert_ffn(
-        xf.astype(jnp.float32), dispatch, combine, lp, cfg, cap
-    )
+def _routed_experts(
+    xf: jax.Array,  # [N, H]
+    topw: jax.Array,  # [N, k] f32
+    topi: jax.Array,  # [N, k] expert ids
+    lp: dict,
+    cfg: MlaConfig,
+    mesh=None,
+    stack=None,
+) -> jax.Array:
+    """[N, H] f32: every one of the N*k assignments computed, whatever
+    the routing. The assignments are sorted by expert (row j of the
+    sorted batch is token order[j] // k under expert expert_of_row[j]),
+    go through the grouped FFN, and come back in token order weighted by
+    the gate."""
+    nt, h = xf.shape
+    e, k = cfg.n_routed_experts, topi.shape[1]
+    with jax.named_scope("route"):
+        flat_e = topi.reshape(nt * k).astype(jnp.int32)
+        order = jnp.argsort(flat_e, stable=True)
+        expert_of_row = flat_e[order]
+        group_sizes = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
+        xs = xf[order // k].astype(cfg.dtype)
+    with jax.named_scope("experts"):
+        ys = _grouped_ffn(
+            xs, expert_of_row, group_sizes, lp, cfg, mesh, stack
+        )
+    with jax.named_scope("route"):
+        back = jnp.zeros((nt * k,), jnp.int32).at[order].set(
+            jnp.arange(nt * k, dtype=jnp.int32)
+        )
+        return jnp.sum(ys[back].reshape(nt, k, h) * topw[..., None], axis=1)
 
-    shared_gate = jax.nn.silu(
-        _mm(xf, lp, "ws_gate", cfg.dtype).astype(jnp.float32)
-    )
-    shared = _mm(
-        (shared_gate * _mm(xf, lp, "ws_up", cfg.dtype).astype(jnp.float32))
-        .astype(cfg.dtype),
-        lp, "ws_down", cfg.dtype,
-    )
-    return (routed.astype(cfg.dtype) + shared).reshape(b, t, h)
+
+def _deepseek_moe_ffn(
+    x: jax.Array, lp: dict, cfg: MlaConfig, mesh=None, stack=None
+) -> jax.Array:
+    """Scopes (under the caller's `mlp`): `moe/route` (gate, top-k, the
+    sort by expert and the weighted un-sort), `moe/experts` (the grouped
+    matmuls), `moe/shared`."""
+    b, t, h = x.shape
+    xf = x.reshape(b * t, h)
+    with jax.named_scope("moe"):
+        with jax.named_scope("route"):
+            topw, topi = _gate(xf, lp, cfg)
+        routed = _routed_experts(xf, topw, topi, lp, cfg, mesh, stack)
+        with jax.named_scope("shared"):
+            shared_gate = jax.nn.silu(
+                _mm(xf, lp, "ws_gate", cfg.dtype).astype(jnp.float32)
+            )
+            shared = _mm(
+                (shared_gate
+                 * _mm(xf, lp, "ws_up", cfg.dtype).astype(jnp.float32))
+                .astype(cfg.dtype),
+                lp, "ws_down", cfg.dtype,
+            )
+        return (routed.astype(cfg.dtype) + shared).reshape(b, t, h)
 
 
 # ---------------------------------------------------------------------------
@@ -845,60 +1062,96 @@ def forward_hidden(
 ) -> tuple[jax.Array, KVPages]:
     if mm_embeds is not None:
         raise ValueError("multimodal prompts are not supported for MLA yet")
-    h = params["embed"][tokens].astype(cfg.dtype)
-    k_cache, v_cache = kv.k, kv.v
-
-    def dense_layer(carry, xs):
-        h, kc, vc = carry
-        lp, li = xs
-        x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
-        attn, kc, vc = mla_attention(
-            x, lp, cfg, (kc, vc), li, page_tables, positions, valid
+    # named scopes as models/llama.py's (embed, attn[/qkv, /kv_update,
+    # /absorb, /paged or /flash, /out], mlp[/moe/route, /moe/experts,
+    # /moe/shared], final_norm; lm_head in compute_logits): a device
+    # trace carries them in each operation's metadata
+    # (docs/observability.md)
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens].astype(cfg.dtype)
+    with jax.named_scope("attn"):
+        decode_work = maybe_decode_work(
+            cfg, tokens, positions, kv, page_tables
         )
-        h = h + attn
-        x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
+
+    def dense_ffn(x, lp):
         gate = jax.nn.silu(_mm(x, lp, "w_gate", cfg.dtype).astype(jnp.float32))
         up = _mm(x, lp, "w_up", cfg.dtype).astype(jnp.float32)
-        h = h + _mm((gate * up).astype(cfg.dtype), lp, "w_down", cfg.dtype)
-        return (h, kc, vc), None
+        return _mm((gate * up).astype(cfg.dtype), lp, "w_down", cfg.dtype)
 
-    def moe_layer(carry, xs):
-        h, kc, vc = carry
-        lp, li = xs
-        x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
-        attn, kc, vc = mla_attention(
-            x, lp, cfg, (kc, vc), li, page_tables, positions, valid
-        )
-        h = h + attn
-        x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
-        h = h + _deepseek_moe_ffn(x, lp, cfg)
-        return (h, kc, vc), None
+    def layer_of(ffn):
+        def layer(carry, xs):
+            h, kc, vc = carry
+            lp, li = xs
+            with jax.named_scope("attn"):
+                x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
+                attn, kc, vc, staged = mla_attention(
+                    x, lp, cfg, (kc, vc), li, page_tables, positions, valid,
+                    first_chunk=first_chunk, decode_work=decode_work,
+                    mesh=mesh,
+                )
+                h = h + attn
+            with jax.named_scope("mlp"):
+                x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
+                h = h + ffn(x, lp, li)
+            return (h, kc, vc), staged
+
+        return layer
 
     nd = cfg.num_dense_layers
-    carry = (h, k_cache, v_cache)
-    if nd:
-        carry, _ = lax.scan(
-            dense_layer, carry,
-            (params["dense_layers"], jnp.arange(nd, dtype=jnp.int32)),
+    # The expert matrices stay out of the scan's per-layer slices where
+    # the grouped matmul can read a layer of the whole stack in place.
+    experts = {
+        n: w for n, w in (params.get("moe_layers") or {}).items()
+        if n in _QUANT_EXPERTS and w.dtype == cfg.dtype
+    }
+
+    def moe_ffn(x, lp, li):
+        return _deepseek_moe_ffn(
+            x, lp, cfg, mesh, (experts, li - nd) if experts else None
         )
-    if cfg.num_moe_layers:
-        carry, _ = lax.scan(
-            moe_layer, carry,
-            (
-                params["moe_layers"],
-                jnp.arange(nd, cfg.num_layers, dtype=jnp.int32),
-            ),
-        )
+
+    carry = (h, kv.k, kv.v)
+    staged = []
+    for group, ffn, lo, hi in (
+        ("dense_layers", lambda x, lp, li: dense_ffn(x, lp), 0, nd),
+        ("moe_layers", moe_ffn, nd, cfg.num_layers),
+    ):
+        if hi > lo:
+            scanned = {
+                n: w for n, w in params[group].items() if n not in experts
+            } if group == "moe_layers" else params[group]
+            carry, st = lax.scan(
+                layer_of(ffn), carry,
+                (scanned, jnp.arange(lo, hi, dtype=jnp.int32)),
+            )
+            staged.append(st)
     h, k_cache, v_cache = carry
-    h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+    if cfg.kernels:
+        # the whole step's rows, every layer, in one write (the cache is
+        # one shared row a token: under a tp mesh it replicates, and the
+        # head-sharded DMA kernel gives way to the XLA scatter)
+        from dynamo_tpu.ops.kv_update import paged_write
+
+        with jax.named_scope("attn"), jax.named_scope("kv_update"):
+            k_cache, v_cache = paged_write(
+                k_cache, v_cache,
+                jnp.concatenate([st[0] for st in staged]),
+                jnp.concatenate([st[1] for st in staged]),
+                page_tables, positions, valid,
+                use_kernel=None if mesh is None else False,
+            )
+    with jax.named_scope("final_norm"):
+        h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
     return h, KVPages(k=k_cache, v=v_cache)
 
 
 def compute_logits(params: dict, cfg: MlaConfig, hidden: jax.Array):
-    lm_head = params.get("lm_head")
-    if lm_head is None:
-        lm_head = params["embed"].T
-    return (hidden @ lm_head).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        lm_head = params.get("lm_head")
+        if lm_head is None:
+            lm_head = params["embed"].T
+        return (hidden @ lm_head).astype(jnp.float32)
 
 
 def forward(params, cfg: MlaConfig, tokens, positions, valid, kv, page_tables):

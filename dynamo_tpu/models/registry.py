@@ -315,6 +315,9 @@ def _mla_presets() -> dict:
 
     return {
         "deepseek-v2-lite": MlaConfig.deepseek_v2_lite,
+        # the depth cut that fits one v5e chip in bf16: layer 0 dense + 7
+        # expert layers, every width and all 64 experts as published
+        "deepseek-v2-lite-8l": lambda: MlaConfig.deepseek_v2_lite(8),
         "mla-tiny": MlaConfig.tiny,
         "mla-tiny-moe": MlaConfig.tiny_moe,
     }
@@ -421,16 +424,8 @@ def get_model(
     if mla_cfg is not None:
         if dtype is not None:
             mla_cfg = _with_dtype(mla_cfg, dtype)
-        if attention_impl not in (None, "auto", "xla"):
-            # MLA's absorbed-latent attention only has the XLA path; the
-            # flash kernels assume per-head K/V pages. An explicit request
-            # gets a WARNING: an operator benchmarking kernels must not
-            # read xla numbers believing they measured pallas.
-            logger.warning(
-                "%s: attention_impl=%s requested but MLA only has the XLA "
-                "path -> serving with attention_impl=xla",
-                name, attention_impl,
-            )
+        if attention_impl is not None:
+            mla_cfg = replace(mla_cfg, attention_impl=attention_impl)
         mla_adapter = _mla_adapter(name, mla_cfg, mesh=mesh)
         if os.path.isdir(name):
             mla_adapter = replace(mla_adapter, default_checkpoint=name)

@@ -25,6 +25,15 @@ either way). The f32 row scales land in their slot-minor planes
 a strided column of single lanes there, which Mosaic's DMA (minor-dim
 slices aligned to the 128-lane tile) cannot express.
 
+A cache of ONE ROW A TOKEN (Hkv = 1: MQA, or models/mla.py's latent cache
+[L, P, S, 1, 512] + [L, P, S, 1, 128]) has its slots in the tiled
+(sublane, lane) dimensions, where a DMA moves whole tiles and the writer
+above cannot address one slot. Its whole pages (a prefill chunk) still go
+through that writer, as [L, P, S, D]; a decode step's single rows go
+through `_rows_kernel`, which reads each row's aligned tile group of
+slots for all layers, puts the row in and writes the group back; K and V
+may differ in width.
+
 input_output_aliasing keeps both caches in place. D must be a 128 multiple
 on TPU (LlamaConfig.kv_head_dim) — Mosaic DMA minor-dim alignment.
 
@@ -56,15 +65,18 @@ def _write_kernel(
     *,
     num_runs: int,
     run: int,
+    align: int,
 ):
     del k_in_ref, v_in_ref
     pairs = ((k_src_ref, k_out_ref), (v_src_ref, v_out_ref))
 
     def copies(i):
+        slot = slots_ref[i]
+        if align:  # a one-row cache: runs start on a tile of slots
+            slot = pl.multiple_of(slot, align)
         return tuple(
             pltpu.make_async_copy(
-                src.at[:, i], dst.at[:, pages_ref[i], pl.ds(slots_ref[i], run)],
-                sem,
+                src.at[:, i], dst.at[:, pages_ref[i], pl.ds(slot, run)], sem,
             )
             for src, dst in pairs
         )
@@ -84,6 +96,122 @@ def _write_kernel(
     # latency is one round, not NR of them.
     jax.lax.fori_loop(0, num_runs, start, 0)
     jax.lax.fori_loop(0, num_runs, drain, 0)
+
+
+def _rows_kernel(
+    pages_ref,  # [B] int32 target page per row (scalar prefetch)
+    slots_ref,  # [B] int32 target slot per row
+    k_src_ref,  # [B, L, D] f32 VMEM — the step's rows, whole
+    v_src_ref,
+    k_in_ref,  # aliased: writes land in place
+    v_in_ref,
+    k_out_ref,  # [L, P, S, D] ANY
+    v_out_ref,
+    k_buf,  # [2, L, TILE, D] VMEM: two rows in flight
+    v_buf,
+    sem,  # [2 (k, v), 2 (slot), 2 (in, out)]
+    *,
+    tile: int,
+):
+    """One row a sequence into a cache of one row a token: the slot lies
+    in the tiled (sublane, lane) dimensions, where a DMA moves whole
+    tiles, so each row's aligned group of `tile` slots is read (all
+    layers in one strided copy), the row put in its place, and the group
+    written back. Row i+1's group is on its way in while row i's is
+    changed and sent out. Rows aim at different pages; frozen rows all
+    aim at the null page, whose content nobody reads."""
+    del k_in_ref, v_in_ref
+    n_rows, layers, _ = k_src_ref.shape
+    planes = ((k_src_ref, k_out_ref, k_buf), (v_src_ref, v_out_ref, v_buf))
+
+    def copies(i, slot, out):
+        first = pl.multiple_of(slots_ref[i] // tile * tile, tile)
+        made = []
+        for pi, (_src, cache, buf) in enumerate(planes):
+            group = cache.at[:, pages_ref[i], pl.ds(first, tile)]
+            pair = (buf.at[slot], group) if out else (group, buf.at[slot])
+            made.append(pltpu.make_async_copy(
+                *pair, sem.at[pi, slot, int(out)]))
+        return made
+
+    for c in copies(0, 0, False):
+        c.start()
+
+    def row(i, _):
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_rows)
+        def _():
+            # the other buffer's last write-back (row i-1) must be out
+            @pl.when(i >= 1)
+            def _():
+                for c in copies(i - 1, 1 - slot, True):
+                    c.wait()
+
+            for c in copies(i + 1, 1 - slot, False):
+                c.start()
+
+        for c in copies(i, slot, False):
+            c.wait()
+        at = slots_ref[i] % tile
+        for src, _cache, buf in planes:
+            here = jax.lax.broadcasted_iota(
+                jnp.int32, buf.shape[2:], 0) == at
+            for l in range(layers):
+                new = jnp.broadcast_to(src[i, l:l + 1, :], buf.shape[2:])
+                buf[slot, l] = jnp.where(
+                    here, new.astype(buf.dtype), buf[slot, l])
+        for c in copies(i, slot, True):
+            c.start()
+        return 0
+
+    jax.lax.fori_loop(0, n_rows, row, 0)
+    # the last two rows' write-backs are still in flight
+    last = n_rows - 1
+    if n_rows > 1:
+        for c in copies(last - 1, (last - 1) % 2, True):
+            c.wait()
+    for c in copies(last, last % 2, True):
+        c.wait()
+
+
+def _write_single_rows(k_cache, v_cache, k_stage, v_stage, page_ids, slot_of):
+    """One decode step's rows ([L, B, 1, 1, D] staged) into a one-row
+    cache, in place, by `_rows_kernel`. The caches go in as [L, P, S, D],
+    the view the page-walk kernel reads: one shape and so one layout for
+    the pool in the whole program. (In plain XLA, one scatter over all
+    layers, or a loop of `dynamic_update_slice`, asks for a layout with
+    the layer axis beside the lanes and copies the whole pool into it and
+    back: 3.8 GB of temporaries at deepseek-v2-lite's cache, the TPU
+    compiler's memory analysis, PR 27.)"""
+    shapes = k_cache.shape, v_cache.shape
+    tile = 32 // jnp.dtype(k_cache.dtype).itemsize
+    layers, b = k_stage.shape[:2]
+    srcs = [
+        stage.reshape(layers, b, stage.shape[-1]).transpose(1, 0, 2)
+        .astype(jnp.float32)
+        for stage in (k_stage, v_stage)
+    ]
+    caches = [c.reshape(*c.shape[:3], c.shape[4]) for c in (k_cache, v_cache)]
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    k_cache, v_cache = pl.pallas_call(
+        functools.partial(_rows_kernel, tile=tile),
+        out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype) for c in caches],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[vmem, vmem] + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
+            scratch_shapes=[
+                pltpu.VMEM((2, layers, tile, c.shape[-1]), c.dtype)
+                for c in caches
+            ] + [pltpu.SemaphoreType.DMA((2, 2, 2))],
+        ),
+        input_output_aliases={4: 0, 5: 1},
+        interpret=jax.default_backend() != "tpu",
+        name="paged_kv_write_rows",
+    )(page_ids.astype(jnp.int32), slot_of.astype(jnp.int32), *srcs, *caches)
+    return k_cache.reshape(shapes[0]), v_cache.reshape(shapes[1])
 
 
 def paged_write(
@@ -184,6 +312,23 @@ def paged_write(
     else:
         k_q, v_q, scales = k_stage, v_stage, ()
 
+    run = min(t, s)
+    # A cache of one row a token (MQA; a latent cache, models/mla.py) has
+    # its slots in the tiled (sublane, lane) dimensions, where Mosaic
+    # cannot DMA part of a tile: runs of whole tiles of slots (a prefill
+    # chunk: page-aligned, a T bucket long) go through the kernel below
+    # (as [L, P, S, D], the same bytes), a decode step's single rows
+    # through `_rows_kernel`. Anything else goes through the scatter,
+    # which on a TPU copies the whole pool into a layout of its own: no
+    # shape the engine sends with the default page size gets there.
+    one_row = k_cache.shape[3] == 1
+    tile = 32 // jnp.dtype(k_cache.dtype).itemsize  # slots a sublane tile
+    if use_kernel and one_row and t == 1 and s % tile == 0:
+        return _write_single_rows(
+            k_cache, v_cache, k_q, v_q, page_ids, slot_of
+        )
+    if one_row and (run % tile or s % tile):
+        use_kernel = False
     if not use_kernel:
         # XLA scatter fallback (CPU, meshes): token-granular, one 5D
         # advanced-index scatter per cache.
@@ -197,7 +342,6 @@ def paged_write(
         )
         return (k_cache, v_cache, *scales)
 
-    run = min(t, s)
     assert t % run == 0, f"chunk T={t} must be a multiple of run={run}"
     runs_per_seq = t // run
     nr = b * runs_per_seq
@@ -208,9 +352,15 @@ def paged_write(
     run_pages = jnp.where(first_valid, run_pages, 0).reshape(-1)
     run_slots = jnp.where(first_valid, first_pos % s, 0).reshape(-1)
 
-    shape_tail = k_stage.shape[3:]
-    k_src = k_q.reshape(L, nr, run, *shape_tail).astype(k_cache.dtype)
-    v_src = v_q.reshape(L, nr, run, *shape_tail).astype(v_cache.dtype)
+    # K and V rows may differ in width (a latent cache: models/mla.py)
+    k_src = k_q.reshape(L, nr, run, *k_stage.shape[3:]).astype(k_cache.dtype)
+    v_src = v_q.reshape(L, nr, run, *v_stage.shape[3:]).astype(v_cache.dtype)
+    shapes = k_cache.shape, v_cache.shape
+    if one_row:
+        k_src, v_src, k_cache, v_cache = (
+            x.reshape(*x.shape[:3], x.shape[4])
+            for x in (k_src, v_src, k_cache, v_cache)
+        )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -220,7 +370,10 @@ def paged_write(
         scratch_shapes=[pltpu.SemaphoreType.DMA],
     )
     k_cache, v_cache = pl.pallas_call(
-        functools.partial(_write_kernel, num_runs=nr, run=run),
+        functools.partial(
+            _write_kernel, num_runs=nr, run=run,
+            align=tile if one_row else 0,
+        ),
         out_shape=[
             jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
             jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
@@ -239,4 +392,4 @@ def paged_write(
         k_cache,
         v_cache,
     )
-    return (k_cache, v_cache, *scales)
+    return (k_cache.reshape(shapes[0]), v_cache.reshape(shapes[1]), *scales)

@@ -95,22 +95,28 @@ def _round_up(x: int, m: int) -> int:
 
 def _footprint(
     pb: int, b: int, hq: int, d: int, s: int, hkv: int, itemsize: int,
-    quantized: bool,
+    quantized: bool, rope_dim: int = 0,
 ) -> int:
     """VMEM bytes of one kernel call whose blocks hold `pb` pages: the
     whole-batch q / acc / m+l blocks (double-buffered by the pipeline
     although the grid has one step), `_DEPTH` K and V slots, the scale
-    slots of a quantized pool, and the block's live temporaries."""
+    slots of a quantized pool, and the block's live temporaries. A latent
+    cache (`rope_dim` > 0) has a `d`-wide page that is key and value at
+    once and a `rope_dim`-wide rope-key page beside it."""
     hqp = _round_up(hq, 8)
     n = pb * s * hkv  # key columns of a block
     sub = 32 // itemsize  # sublane tile of the cache dtype
-    whole_batch = 2 * b * _round_up(hqp, sub) * d * itemsize  # q
-    whole_batch += 2 * 2 * b * hqp * d * 4  # acc, and m|l in one block
-    slots = 2 * _DEPTH * _round_up(n, sub) * d * itemsize
+    kv_width = d + rope_dim if rope_dim else 2 * d  # of one cached token
+    whole_batch = 2 * b * _round_up(hqp, sub) * (d + rope_dim) * itemsize  # q
+    if rope_dim:
+        whole_batch += 2 * b * hqp * (d + 128) * 4  # acc, and m|l
+    else:
+        whole_batch += 2 * 2 * b * hqp * d * 4  # acc, and m|l in one block
+    slots = _DEPTH * _round_up(n, sub) * kv_width * itemsize
     # limit, scores and p in 32 bits, p again for the MXU
     temps = 4 * hqp * n * 4
     if itemsize != 2:
-        temps += 2 * n * d * 4  # K and V of the slot cast for the MXU
+        temps += n * kv_width * 4  # K and V of the slot cast for the MXU
     if quantized:
         lanes = _round_up(s, 128)
         slots += 2 * _DEPTH * pb * _round_up(hkv, 8) * lanes * 4
@@ -122,20 +128,22 @@ def _footprint(
 
 def _block_pages(
     b: int, hq: int, d: int, s: int, hkv: int, itemsize: int,
-    quantized: bool, budget: int | None,
+    quantized: bool, budget: int | None, rope_dim: int = 0,
 ) -> int:
     """Pages per block, from the shapes alone: as many as reach
     `_BLOCK_BYTES` of K+V, at most `_MAX_BLOCK_PAGES`, no more key columns
     than `_MAX_BLOCK_COLUMNS`; then halved while the call would not fit
     `budget` (a large batch's q/acc blocks leave less for the slots)."""
-    page_bytes = 2 * s * hkv * d * itemsize
+    page_bytes = s * hkv * (d + rope_dim if rope_dim else 2 * d) * itemsize
     pb = max(1, min(
         _MAX_BLOCK_PAGES, _BLOCK_BYTES // page_bytes,
         _MAX_BLOCK_COLUMNS // (s * hkv),
     ))
     while (
         budget is not None and pb > 1
-        and _footprint(pb, b, hq, d, s, hkv, itemsize, quantized) > budget
+        and _footprint(
+            pb, b, hq, d, s, hkv, itemsize, quantized, rope_dim
+        ) > budget
     ):
         pb //= 2
     return pb
@@ -165,12 +173,13 @@ def _decode_kernel(
     #   sem,  # [2 or 4, DEPTH] DMA semaphores: [plane, slot]
     *refs,
     page_size: int,
-    scale_dim: int,
+    inv_scale: float,  # what the scores are multiplied by
     num_q_heads: int,
     num_kv_heads: int,
     max_pages: int,  # MP — row stride of pt_ref
     block_pages: int,
     quantized: bool,
+    latent: bool,
 ):
     if quantized:
         (q_ref, k_ref, v_ref, ks_ref, vs_ref, acc_ref, ml_ref,
@@ -180,13 +189,13 @@ def _decode_kernel(
         ks_ref = vs_ref = ks_scr = vs_scr = None
     li = layer_ref[0]
     n_rows = nrows_ref[0]
-    bsz, hqp, d = q_ref.shape
+    bsz, hqp, _ = q_ref.shape
+    d = acc_ref.shape[2]  # a latent q carries its rope part past `d`
     hkv, s, pb = num_kv_heads, page_size, block_pages
     g = num_q_heads // hkv
     rpp = s * hkv  # cache rows (= key columns) of one page: r = slot*Hkv + h
     n = pb * rpp
     sub = _round_up(hkv, 8)  # scale rows a page takes in its slot
-    inv_scale = 1.0 / math.sqrt(scale_dim)
     # What the MXU is fed: a bf16 pool under bf16 queries goes in as it is
     # and narrow pools convert exactly; q/sqrt(d) and the softmax weights
     # are rounded to bf16 on the way in, which is what the chip's default-
@@ -316,10 +325,18 @@ def _decode_kernel(
 
             block_copies(b, kb, slot, lambda c: c.wait())
 
+            k_blk = to_mxu(k_scr[slot])
             scores = jax.lax.dot_general(
-                q, to_mxu(k_scr[slot]), (((1,), (1,)), ((), ())),
+                q[:, :d], k_blk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # [HQP, N]
+            if latent:
+                # the page in k_scr is the latent: scored here, summed as
+                # the value below; v_scr holds the shared rope key
+                scores += jax.lax.dot_general(
+                    q[:, d:], to_mxu(v_scr[slot]), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
             if quantized:
                 scores = scores * column_scales(ks_scr[slot])
             scores = jnp.where(limit < hist - kb * (pb * s), scores, _MASKED)
@@ -330,7 +347,8 @@ def _decode_kernel(
             if quantized:
                 p = p * column_scales(vs_scr[slot])
             pv = jax.lax.dot_general(
-                p.astype(mxu), to_mxu(v_scr[slot]), (((1,), (0,)), ((), ())),
+                p.astype(mxu), k_blk if latent else to_mxu(v_scr[slot]),
+                (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # [HQP, D]
             return m_new, l_new, acc * corr + pv
@@ -372,14 +390,16 @@ def decode_work_list(
 
 def decode_vmem_bytes(
     b: int, hq: int, d: int, s: int, hkv: int, itemsize: int,
-    quantized: bool = False, budget: int | None = None,
+    quantized: bool = False, budget: int | None = None, rope_dim: int = 0,
 ) -> int:
     """Kernel VMEM footprint estimate at the block size the kernel would
     take under `budget` (see `_footprint`). The caller routes to the XLA
     gather when even one page a block exceeds the budget, instead of
     letting Mosaic fail allocation."""
-    pb = _block_pages(b, hq, d, s, hkv, itemsize, quantized, budget)
-    return _footprint(pb, b, hq, d, s, hkv, itemsize, quantized)
+    pb = _block_pages(
+        b, hq, d, s, hkv, itemsize, quantized, budget, rope_dim
+    )
+    return _footprint(pb, b, hq, d, s, hkv, itemsize, quantized, rope_dim)
 
 
 def paged_decode_attention(
@@ -391,6 +411,8 @@ def paged_decode_attention(
     history_lens: jax.Array,  # [B] int32 — tokens already written to pages
     *,
     scale_dim: int | None = None,
+    scale: float | None = None,  # the scores' factor; default 1/sqrt(scale_dim)
+    latent: bool = False,  # k_cache is key AND value, v_cache the rope key
     interpret: bool | None = None,
     mesh=None,
     work_list=None,  # precomputed decode_work_list (layer-invariant)
@@ -408,6 +430,13 @@ def paged_decode_attention(
     With `k_scale`/`v_scale` the cache holds quantized rows; each page's
     scale plane DMAs alongside it and scales the scores' and the weights'
     columns after the dots.
+
+    `latent` walks a latent cache (models/mla.py): `k_cache` [L, P, S, 1, C]
+    holds the compressed latent, which is key and value at once, `v_cache`
+    [L, P, S, 1, R] the rope key every head shares, and `q` is [B, Hq, C+R]
+    (the absorbed latent query, then its rope part). A page is read once
+    and used for both the C+R-wide score dots and the C-wide value sum;
+    acc comes back [B, Hq, C]. Same work list, same block rule.
 
     `interpret` defaults to True off-TPU so tests run the same kernel on CPU.
     """
@@ -427,17 +456,20 @@ def paged_decode_attention(
         def sharded(q_, k_, v_, layer_, pt_, hist_, n_, rows_, pages_, *scales):
             return paged_decode_attention(
                 q_, k_, v_, layer_, pt_, hist_,
-                scale_dim=scale_dim, interpret=interpret, mesh=None,
+                scale_dim=scale_dim, scale=scale, latent=latent,
+                interpret=interpret, mesh=None,
                 work_list=(n_, rows_, pages_),
                 k_scale=scales[0] if scales else None,
                 v_scale=scales[1] if scales else None,
                 vmem_budget=vmem_budget,
             )
 
+        # a latent cache is one shared row a token: it replicates
+        cache_spec = P() if latent else P(None, None, None, "tp", None)
         in_specs = [
             P(None, "tp", None),
-            P(None, None, None, "tp", None),
-            P(None, None, None, "tp", None),
+            cache_spec,
+            cache_spec,
             P(),
             P(),
             P(),
@@ -458,19 +490,27 @@ def paged_decode_attention(
             check_vma=False,
         )
         return fn(*args)
-    b, hq, d = q.shape
+    b, hq, dq = q.shape
+    d, dv = k_cache.shape[4], v_cache.shape[4]
+    if latent and (quantized or hkv != 1 or dq != d + dv):
+        raise ValueError(
+            "a latent walk takes an unquantized one-row cache and "
+            f"q [B, Hq, {d}+{dv}]; got q {q.shape}, Hkv={hkv}"
+        )
     mp = page_tables.shape[1]
     n_rows, rows, pages = work_list
     itemsize = jnp.dtype(k_cache.dtype).itemsize
     pb = min(mp, _block_pages(
-        b, hq, d, s, hkv, itemsize, quantized, vmem_budget
+        b, hq, d, s, hkv, itemsize, quantized, vmem_budget,
+        dv if latent else 0,
     ))
     hqp = _round_up(hq, 8)
     if hqp != hq:  # whole sublane tiles of query heads; the pad is masked
         q = jnp.pad(q, ((0, 0), (0, hqp - hq), (0, 0)))
     # a page as [S*Hkv, D] rows (r = slot*Hkv + h): the same bytes, and
     # every dot operand a whole tile
-    cache_shape = (*k_cache.shape[:2], s * hkv, d)
+    def rows_of(cache):
+        return cache.reshape(*cache.shape[:2], s * hkv, cache.shape[4])
 
     def whole(*block):
         return pl.BlockSpec(
@@ -478,15 +518,15 @@ def paged_decode_attention(
         )
 
     in_specs = [
-        whole(b, hqp, d),
+        whole(b, hqp, dq),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     scratch_shapes = [
         pltpu.VMEM((_DEPTH, pb * s * hkv, d), k_cache.dtype),
-        pltpu.VMEM((_DEPTH, pb * s * hkv, d), v_cache.dtype),
+        pltpu.VMEM((_DEPTH, pb * s * hkv, dv), v_cache.dtype),
     ]
-    operands = [q, k_cache.reshape(cache_shape), v_cache.reshape(cache_shape)]
+    operands = [q, rows_of(k_cache), rows_of(v_cache)]
     if quantized:
         in_specs += [
             pl.BlockSpec(memory_space=pl.ANY),
@@ -513,12 +553,15 @@ def paged_decode_attention(
         functools.partial(
             _decode_kernel,
             page_size=s,
-            scale_dim=scale_dim or d,
+            inv_scale=(
+                1.0 / math.sqrt(scale_dim or d) if scale is None else scale
+            ),
             num_q_heads=hq,
             num_kv_heads=hkv,
             max_pages=mp,
             block_pages=pb,
             quantized=quantized,
+            latent=latent,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b, hqp, d), jnp.float32),

@@ -42,8 +42,15 @@ SHAPES = {
     # llama3-8b: many blocks a row
     "llama3-8b": dict(b=32, hq=32, hkv=8, d=128, hist=(1000, 4000),
                       layers=4),
+    # deepseek-v2-lite as `dsv2lite-docgen` serves it: a latent cache
+    # (one 512-wide row a token that is key and value, and a rope key of
+    # 64 cached as 128 lanes), 16 heads, softmax scale 1/sqrt(128 + 64)
+    "deepseek-v2-lite": dict(b=64, hq=16, hkv=1, d=512, rope=128,
+                             scale_dim=192, hist=(2100, 5700), layers=8),
 }
 REHEARSAL = dict(b=3, hq=8, hkv=2, d=128, hist=(1, 40), layers=2)
+REHEARSAL_LATENT = dict(b=3, hq=8, hkv=1, d=128, rope=128, scale_dim=24,
+                        hist=(1, 40), layers=2)
 PEAKS = json.loads((ROOT / "chipbench" / "peaks.json").read_text())
 
 
@@ -88,14 +95,18 @@ def make_case(shape: dict, seed: int, page: int):
         k = jax.random.normal(keys[0], pool, dtype)
         v = jax.random.normal(keys[1], pool, dtype)
         scales = (None, None)
-    q = jax.random.normal(keys[4], (b, hq, d), shape.get("qdtype", jnp.bfloat16))
+    rope = shape.get("rope", 0)
+    if rope:  # latent: `v` holds the rope key, q its part past `d`
+        v = jax.random.normal(keys[1], (*pool[:4], rope), dtype)
+    q = jax.random.normal(
+        keys[4], (b, hq, d + rope), shape.get("qdtype", jnp.bfloat16))
     return dict(
         q=q, k=k, v=v, k_scale=scales[0], v_scale=scales[1],
         pt=jnp.asarray(ids, jnp.int32), hist=jnp.asarray(hist, jnp.int32),
     )
 
 
-def walk(impl, scale_dim: int, interpret: bool):
+def walk(impl, scale_dim: int, interpret: bool, latent: bool = False):
     """All layers of the pool in one program, as a step program's layer
     scan does: (acc, m, l) of every layer."""
     import jax
@@ -111,7 +122,7 @@ def walk(impl, scale_dim: int, interpret: bool):
             return None, impl.paged_decode_attention(
                 q, k, v, li, pt, hist, scale_dim=scale_dim,
                 work_list=work, k_scale=k_scale, v_scale=v_scale,
-                interpret=interpret,
+                interpret=interpret, **({"latent": True} if latent else {}),
             )
 
         _, out = jax.lax.scan(
@@ -138,22 +149,25 @@ def reference(case: dict, layer: int, scale_dim: int):
             if plane is not None:
                 sc = plane[layer][pt][..., :page]  # [B, MP, Hkv, S]
                 x = x * jnp.swapaxes(sc, 2, 3)[..., None]
-            return jnp.repeat(
-                x.reshape(b, -1, hkv, x.shape[-1]), hq // hkv, axis=2
-            )
+            return x.reshape(b, -1, hkv, x.shape[-1])
 
         kk, vv = rows(k, k_scale), rows(v, v_scale)
+        if q.shape[-1] != k.shape[-1]:  # latent: [latent | rope key], latent
+            kk, vv = jnp.concatenate([kk, vv], axis=-1), kk
+        # a kv head's rows once for its whole group of query heads
+        qg = q.astype(jnp.float32).reshape(b, hkv, hq // hkv, -1)
         s = jnp.einsum(
-            "bhd,bkhd->bhk", q.astype(jnp.float32), kk,
-            precision="highest",
+            "bngd,bknd->bngk", qg, kk, precision="highest",
         ) / math.sqrt(scale_dim)
-        mask = jnp.arange(kk.shape[1])[None, None, :] < hist[:, None, None]
+        mask = jnp.arange(kk.shape[1])[None, None, None, :] < hist[
+            :, None, None, None]
         s = jnp.where(mask, s, -jnp.inf)
         m = jnp.max(s, axis=-1)
         p = jnp.exp(s - m[..., None])
         l = jnp.sum(p, axis=-1)
-        out = jnp.einsum("bhk,bkhd->bhd", p, vv, precision="highest")
-        return out / l[..., None], m, l
+        out = jnp.einsum("bngk,bknd->bngd", p, vv, precision="highest")
+        out = out / l[..., None]
+        return (out.reshape(b, hq, -1), m.reshape(b, hq), l.reshape(b, hq))
 
     return ref(case["q"], case["k"], case["v"], case["pt"], case["hist"],
                case["k_scale"], case["v_scale"])
@@ -181,7 +195,8 @@ def measure(impl, name: str, shape: dict, seed: int, rehearse: bool) -> dict:
     page = 4 if rehearse else PAGE
     case = make_case(shape, seed, page)
     scale_dim = shape.get("scale_dim", shape["d"])
-    fn = walk(impl, scale_dim, interpret=rehearse)
+    rope = shape.get("rope", 0)
+    fn = walk(impl, scale_dim, interpret=rehearse, latent=bool(rope))
     args = (case["q"], case["k"], case["v"], case["pt"], case["hist"],
             case["k_scale"], case["v_scale"])
     acc, m, l = (np.asarray(x[0]) for x in jax.block_until_ready(fn(*args)))
@@ -193,7 +208,8 @@ def measure(impl, name: str, shape: dict, seed: int, rehearse: bool) -> dict:
     err_l = float(np.max(np.abs(l * np.exp(m - want_m) / want_l - 1.0)))
     itemsize = case["k"].dtype.itemsize
     live = int(np.asarray(case["hist"]).sum())
-    kv_bytes = 2 * live * shape["hkv"] * shape["d"] * itemsize
+    kv_bytes = live * shape["hkv"] * itemsize * (
+        shape["d"] + rope if rope else 2 * shape["d"])  # as cached
     out = {
         "shape": name, "rows": shape["b"], "heads": [shape["hq"], shape["hkv"]],
         "head_dim": shape["d"], "kv_dtype": str(case["k"].dtype),
@@ -245,7 +261,8 @@ def main() -> int:
         if not hasattr(impl, key):
             raise SystemExit(f"{ns.impl or 'paged_attention'} has no {key}")
         setattr(impl, key, int(value))
-    shapes = {"rehearsal": REHEARSAL} if ns.rehearse else {
+    shapes = {"rehearsal": REHEARSAL,
+              "rehearsal-latent": REHEARSAL_LATENT} if ns.rehearse else {
         n: SHAPES[n] for n in (ns.shape or SHAPES)}
     failed = 0
     for name, shape in shapes.items():

@@ -324,6 +324,73 @@ def test_whole_llama3_1b_decode_step_compiles_with_both_kernels(topo):
     assert _mosaic_calls(compiled) == 2
 
 
+@pytest.mark.parametrize("rows,t,first_chunk", [
+    pytest.param(64, 1, False, id="decode-64-rows"),
+    pytest.param(1, 512, False, id="prefill-chunk-over-latent-history"),
+    pytest.param(2, 32, False, id="prefill-tail-shorter-than-a-page"),
+])
+def test_deepseek_v2_lite_step_compiles_at_published_widths(
+        topo, rows, t, first_chunk):
+    """One whole step of `deepseek-v2-lite-8l` as `dsv2lite-docgen`
+    serves it, with a larger pool (bf16, 6200 pages against the cell's 5000,
+    --max-context 8192): the latent page
+    walk (in both layer scans), the grouped matmuls and the cache writers
+    go through the TPU compiler, the program fits the chip beside 13.25
+    GB of weights and cache, and nothing copies the KV pool (one scatter
+    over all layers did, and so did a loop of dynamic_update_slice inside
+    the fused decode scan: 4 GB of temporaries)."""
+    adapter = get_model("deepseek-v2-lite-8l", dtype="bfloat16",
+                        attention_impl="pallas")
+    chip = SingleDeviceSharding(topo.devices[0])
+    params = _on(chip, jax.eval_shape(
+        lambda: adapter.init_params(jax.random.key(0))))
+    kv = _on(chip, jax.eval_shape(lambda: adapter.init_kv(6200, PAGE)))
+    assert kv.k.shape[-1] == 512 and kv.v.shape[-1] == 128
+
+    def step(tokens, positions, kv, params, valid, pt):
+        hidden, kv = adapter.forward_hidden(
+            params, tokens, positions, valid, kv, pt,
+            first_chunk=first_chunk,
+        )
+        logits = adapter.compute_logits(params, hidden[:, -1])
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), kv
+
+    def program(params, tokens, positions, valid, kv, pt):
+        if t > 1:
+            return step(tokens, positions, kv, params, valid, pt)
+
+        # decode as the engine fuses it (`multi_fn`): 8 steps in one scan,
+        # the pool its carry. A carry is where XLA is free to pick another
+        # layout for the pool and copy it in and out of the loop.
+        def body(carry, _):
+            tokens, positions, kv = carry
+            ids, kv = step(tokens, positions, kv, params, valid, pt)
+            return (ids[:, None], positions + 1, kv), ids
+
+        (_, _, kv), ids = jax.lax.scan(
+            body, (tokens, positions, kv), None, length=8)
+        return ids, kv
+
+    compiled = jax.jit(program, donate_argnums=(4,)).lower(
+        params, _sds((rows, t), jnp.int32, chip),
+        _sds((rows, t), jnp.int32, chip), _sds((rows, t), jnp.bool_, chip),
+        kv, _sds((rows, 8192 // PAGE), jnp.int32, chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    pool = 8 * 6200 * PAGE * (512 + 128) * 2
+    assert mem.alias_size_in_bytes >= pool  # the cache is updated in place
+    assert mem.temp_size_in_bytes < 2.2e9  # < 0.8 GB of the cache's own size
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    text = compiled.as_text()
+    if t == 1:  # the walk in both layer scans, three gmm, the row writer
+        assert text.count("paged_decode_attention") >= 2
+        assert "paged_kv_write_rows" in text
+        assert mem.temp_size_in_bytes < 0.8e9
+    else:  # whole pages of the chunk through the DMA writer
+        assert "paged_kv_write" in text
+    assert _mosaic_calls(compiled) >= 4
+
+
 def test_engine_refuses_narrow_pages_with_too_few_kv_heads_per_shard():
     """int8 pages under tp=4 leave llama3-1b 2 kv heads per shard, which
     Mosaic cannot DMA: on a TPU the engine says so at construction
