@@ -73,13 +73,15 @@ class EngineConfig:
     #: pinned by tests/test_engine_kstep.py). `--decode-kstep` on the
     #: CLI (vLLM `--num-scheduler-steps` analogue, docs/migrating.md).
     decode_kstep: int = 1
-    #: overlapped decode loop: after dispatching decode step N, dispatch
-    #: step N+1 speculatively (same batch, +1 round, sampled ids fed back
-    #: on device) and read step N's ids back one step lagged via an async
-    #: copy — host postprocessing and array staging hide under device
-    #: compute. Rolled back (overshoot discarded, like decode_multi's
-    #: post-stop tokens) when a finish/preemption/abort/admitted prefill
-    #: changes the batch. Runs on multi-process SPMD meshes: decode ids
+    #: overlapped decode loop: after dispatching step N, dispatch step
+    #: N+1 ahead (the batch the scheduler will return next: a row at its
+    #: token budget gone, its successor admitted, a finished prompt
+    #: joined; sampled ids fed back on device) and read step N's ids back
+    #: one step lagged via an async copy — host postprocessing and array
+    #: staging hide under device compute. Rolled back (overshoot
+    #: discarded, like decode_multi's post-stop tokens) when a sampled
+    #: stop, an abort or a preemption changes the batch (docs/engine.md).
+    #: Runs on multi-process SPMD meshes: decode ids
     #: are replicated on-device, the rollback decision is a pure
     #: function of the (broadcast) event log, so every lockstep host
     #: overlaps and rolls back identically — the lagged readback is the

@@ -318,31 +318,39 @@ def phase(metrics, name: str, *fields: str, **args) -> _Phase:
 
 
 @dataclass
-class _InflightDecode:
-    """One speculatively dispatched decode step whose sampled ids are
-    still on device (async host copy already started). It becomes the
-    real step iff the next scheduled batch is the same decode batch and
-    every request advanced exactly the pending step's token count;
-    otherwise it is rolled back (the ids are overshoot, and the KV it
-    wrote sits past every live sequence's length or in freed pages that
-    later writers fully overwrite before any read)."""
+class _Launched:
+    """One decode-carrying dispatch on the device queue whose sampled ids
+    the host has not read: a pure decode dispatch of `k_steps` fused
+    steps, or a fused mixed step (`pieces` beside the decode rows).
 
-    reqs: tuple
+    Launched for the batch in hand it is read back in the same step.
+    Launched AHEAD of its batch (`JaxEngine._speculate`; `expected` set,
+    the async host copy of its ids already started) it becomes the real
+    step iff the next scheduled batch is the one it was built for: the
+    same requests in the same rows, each advanced exactly the tokens the
+    dispatch before it added, beside the same pieces. Otherwise it is
+    rolled back (the ids are overshoot, and the KV it wrote sits past
+    every live sequence's length, in pages of a prompt that the real
+    dispatch writes again, or in freed pages that later writers fully
+    overwrite before any read)."""
+
+    reqs: tuple  # decode rows: row i of the ids
     b_bucket: int
     k_steps: int
-    token_ids: object  # device array, [B] (k=1) or [K, B]
+    token_ids: object  # device array: [B], [K, B], or mixed [b_dec(+b_pre)]
     lp_data: Optional[tuple]  # device (chosen, top_ids, top_lps) or None
-    #: per-request state the batch must show when this step is consumed
-    expected_num_tokens: tuple
-    expected_out_len: tuple
-    #: program-variant flags at dispatch (same reqs => same flags; kept
-    #: so the next speculation reuses them without recomputation)
-    greedy: bool = False
-    lp: int = -1
-    bias: bool = False
+    #: mixed step: the fused prefill pieces; where one completes its
+    #: prompt (`psamp`) piece j was sampled at row b_bucket + j
+    pieces: tuple = ()
+    psamp: bool = False
     #: dispatched through the decode_kstep program family (on-device
-    #: stop masks); the chained re-speculation stays in the family
+    #: stop masks): the device's emitted counts, and the window's start
     kstep: bool = False
+    n_emit: object = None
+    t0: float = 0.0
+    #: launched ahead: per decode row the (num_tokens, len(output_tokens))
+    #: the batch must show when this dispatch is consumed
+    expected: Optional[tuple] = None
 
 
 @dataclass
@@ -355,7 +363,7 @@ class _InflightSpec:
     host's acceptance scan of the previous step agreed with the
     device's n_acc on every row (no finish/stop truncation — the device
     cannot see those) and the decode batch is unchanged; otherwise it
-    rolls back exactly like _InflightDecode."""
+    rolls back exactly like a _Launched dispatch."""
 
     reqs: tuple
     b_bucket: int
@@ -578,10 +586,10 @@ class JaxEngine:
         #: overlapped decode: the one speculative in-flight dispatch (or
         #: None). Carried ACROSS hosts since the logical-axis refactor:
         #: chained dispatch feeds tokens on-device (replicated outputs),
-        #: so the readback in _consume_inflight is the only per-window
+        #: so the lagged readback (_finish_decode) is the only per-window
         #: host sync and it is identical on every lockstep replica. Off
         #: under prompt-lookup speculation (drafts need host tokens).
-        self._inflight: Optional[_InflightDecode] = None
+        self._inflight: Optional[_Launched] = None
         self._overlap_enabled = (
             config.overlap_decode and config.spec_ngram <= 0
         )
@@ -1257,22 +1265,27 @@ class JaxEngine:
             p *= 2
         return p
 
-    def _pick_decode_steps(self, reqs: list[Request]) -> int:
+    def _pick_decode_steps(
+        self, reqs: list[Request], ahead: Optional[list[int]] = None
+    ) -> int:
         """Fused steps for this dispatch: capped by config, by remaining
         context room, and dropped to 1 when admission is pending (so new
         arrivals don't wait K steps) or when the pool can't pre-grow every
-        sequence's page table K tokens ahead."""
+        sequence's page table K tokens ahead. `ahead[i]` are the tokens a
+        dispatch still on the device adds to row i first (a dispatch
+        launched ahead of its batch, `_speculate`)."""
         k = self.config.decode_steps
         if k <= 1:
             return 1
+        ahead = ahead or [0] * len(reqs)
         # Admission pending AND actually possible this step: stay responsive.
         # (A backlog that can't admit anyway must not forfeit fusion.)
         if self.scheduler.num_waiting() > 0 and self.scheduler.can_admit_head():
             return 1
         cap_tokens = self.config.max_pages_per_seq * self.config.page_size
-        for req in reqs:
-            k = min(k, self.config.max_context - req.num_tokens + 1)
-            k = min(k, cap_tokens - req.num_tokens + 1)
+        for req, a in zip(reqs, ahead):
+            k = min(k, self.config.max_context - req.num_tokens - a + 1)
+            k = min(k, cap_tokens - req.num_tokens - a + 1)
         # Cover the longest remaining completion rounded UP to a power of
         # two (the decode_multi program family stays small — every distinct
         # k is a full-model compile). Requests finishing mid-scan discard
@@ -1280,11 +1293,11 @@ class JaxEngine:
         # ONE dispatch instead of a halving ladder of dispatches, each a
         # full host sync (the sync, not the compute, is what costs).
         rem_max = 0
-        for req in reqs:
+        for req, a in zip(reqs, ahead):
             s = req.sampling
             rem_max = max(
                 rem_max,
-                s.max_tokens - len(req.output_tokens) - req.num_emitted,
+                s.max_tokens - len(req.output_tokens) - req.num_emitted - a,
             )
         p = 1
         while p < max(1, rem_max):
@@ -1296,19 +1309,19 @@ class JaxEngine:
         k = self._pow2_floor(k)
         if k <= 1:
             return 1
-        if not self._grow_pages_for(reqs, k - 1):
+        if not self._grow_pages_for(reqs, [a + k - 1 for a in ahead]):
             return 1  # single-step path handles pressure via preemption
         return k
 
-    def _grow_pages_for(self, reqs: list[Request], ahead: int) -> bool:
-        """Grow every request's page table to cover num_tokens + ahead,
-        with an aggregate need-vs-free pre-check so pool pressure never
-        half-grows the batch. False => nothing was allocated."""
+    def _grow_pages_for(self, reqs: list[Request], ahead: list[int]) -> bool:
+        """Grow every request's page table to cover num_tokens + its
+        `ahead`, with an aggregate need-vs-free pre-check so pool pressure
+        never half-grows the batch. False => nothing was allocated."""
         ps = self.config.page_size
         need = 0
         per_req = []
-        for req in reqs:
-            extra = -(-(req.num_tokens + ahead) // ps) - len(req.pages)
+        for req, a in zip(reqs, ahead):
+            extra = -(-(req.num_tokens + a) // ps) - len(req.pages)
             per_req.append(max(0, extra))
             need += max(0, extra)
         if need > self.allocator.num_free:
@@ -1354,15 +1367,19 @@ class JaxEngine:
             return False
         return all(self._kstep_stop_ids(r) is not None for r in reqs)
 
-    def _pick_kstep(self, reqs: list[Request]) -> int:
+    def _pick_kstep(
+        self, reqs: list[Request], ahead: Optional[list[int]] = None
+    ) -> int:
         """Window size for this decode dispatch; 1 => take the classic
         decode/decode_multi path. Mirrors _pick_decode_steps' admission
         rule (drop to 1 when an admissible request waits) and its
         pow2 snapping, but the page headroom is reserved UP FRONT for
         the whole window via the scheduler's runway clamp — the
-        on-device loop can never ask the host for a page mid-window."""
+        on-device loop can never ask the host for a page mid-window.
+        `ahead` as in _pick_decode_steps."""
         if self._decode_kstep <= 1 or not self._kstep_enabled:
             return 1
+        ahead = ahead or [0] * len(reqs)
         if not self._kstep_candidate(reqs):
             self.metrics.kstep_fallbacks += 1
             logger.debug(
@@ -1376,18 +1393,18 @@ class JaxEngine:
         # max_pages_per_seq would overflow the [B, mp] page table (same
         # per-request caps as _pick_decode_steps)
         cap_tokens = self.config.max_pages_per_seq * self.config.page_size
-        for req in reqs:
-            k = min(k, self.config.max_context - req.num_tokens + 1)
-            k = min(k, cap_tokens - req.num_tokens + 1)
+        for req, a in zip(reqs, ahead):
+            k = min(k, self.config.max_context - req.num_tokens - a + 1)
+            k = min(k, cap_tokens - req.num_tokens - a + 1)
         # cover the longest remaining completion, rounded up to a power
         # of two (same reasoning as _pick_decode_steps: the tail of a
         # wave runs as one window, the program family stays log-sized)
         rem_max = 0
-        for req in reqs:
+        for req, a in zip(reqs, ahead):
             s = req.sampling
             rem_max = max(
                 rem_max,
-                s.max_tokens - len(req.output_tokens) - req.num_emitted,
+                s.max_tokens - len(req.output_tokens) - req.num_emitted - a,
             )
         p = 1
         while p < max(1, rem_max):
@@ -1397,21 +1414,23 @@ class JaxEngine:
             return 1
         # scheduler-guaranteed page runway for the WHOLE window (or a
         # clamped one); _grow_pages_for then actually reserves it
-        k = self.scheduler.clamp_kstep_window(reqs, k)
-        while k > 1 and not self._grow_pages_for(reqs, k - 1):
+        k = self.scheduler.clamp_kstep_window(reqs, k, ahead)
+        while k > 1 and not self._grow_pages_for(
+            reqs, [a + k - 1 for a in ahead]
+        ):
             k //= 2  # pool raced smaller than the clamp's view
         return max(1, k)
 
     def _kstep_arrays(
-        self, reqs: list[Request], pad_to: int, emitted_ahead: int = 0
+        self, reqs: list[Request], pad_to: int, ahead: list[int]
     ) -> tuple[np.ndarray, np.ndarray]:
         """Device inputs for the window's on-device finish evaluation:
         per-row packed stop slots (−1-padded) and per-row emission
         budgets — the EXACT token counts `_finish_reason_for` would
         allow (max_tokens and max_context legs), so the device freeze
         decisions and the host finish scan agree position-for-position.
-        `emitted_ahead` discounts a pending overlapped window's tokens
-        when building the chained window's budgets. Padding rows get
+        `ahead` discounts the tokens a dispatch still on the device
+        emits first, for a window launched ahead of it. Padding rows get
         budget 0 and empty stop sets (they are never alive anyway)."""
         from dynamo_tpu.engine.sampling import STOP_SLOTS
 
@@ -1430,7 +1449,7 @@ class JaxEngine:
                     - req.num_emitted,
                     self.config.max_context - req.num_tokens,
                 )
-                - emitted_ahead,
+                - ahead[i],
             )
         return stops, budgets
 
@@ -1539,7 +1558,7 @@ class JaxEngine:
         for req in reqs:
             if req.num_tokens + s > min(cap_tokens, self.config.max_context):
                 return self._run_decode_plain(reqs)
-        if not self._grow_pages_for(reqs, s):
+        if not self._grow_pages_for(reqs, [s] * len(reqs)):
             return self._run_decode_plain(reqs)
 
         m = self.metrics
@@ -1703,7 +1722,7 @@ class JaxEngine:
             if req.num_tokens + s > min(cap_tokens, self.config.max_context):
                 self._discard_inflight_spec("window over context cap")
                 return self._run_decode_plain(reqs, mixed=mixed)
-        if not self._grow_pages_for(reqs, s):
+        if not self._grow_pages_for(reqs, [s] * len(reqs)):
             self._discard_inflight_spec("page pressure")
             return self._run_decode_plain(reqs, mixed=mixed)
         if self._inflight is not None:
@@ -1875,20 +1894,25 @@ class JaxEngine:
         the host: its catch-up window is the pending step's accepted
         tokens, derived ON DEVICE from (out_ids, n_acc) — the same
         token-feedback trick the plain overlap loop uses, generalized to
-        a data-dependent window length. Only when the scheduler
-        guarantees batch stability (mixed steps count: the chained
-        dispatch lands as the decode leg of the next mixed step), no
+        a data-dependent window length. Only when the decode rows that
+        come next are these (`Scheduler.next_batch`; mixed steps count:
+        the chained dispatch lands as the decode leg of the next mixed
+        step), no
         request can finish inside the pending window's worst case, pages
         can pre-grow to cover both windows, and no penalty history (host
         state) is in play."""
         if not self._overlap_enabled:
             return
-        if not self.scheduler.decode_batch_stable():
-            if not (
-                self._mixed_enabled
-                and self.scheduler.decode_rows_stable(reqs)
-            ):
-                return
+        # the decode rows that come next must be these (how many tokens
+        # the pending step accepts is not known, so none is counted;
+        # the worst case is excluded below)
+        nxt = self.scheduler.next_batch(reqs, 0)
+        if (
+            nxt is None
+            or len(nxt.decode) != len(reqs)
+            or any(a is not b for a, b in zip(nxt.decode, reqs))
+        ):
+            return
         if self._batch_penalty_bucket(reqs):
             return
         s = self.config.spec_draft_tokens
@@ -1906,7 +1930,7 @@ class JaxEngine:
                 return  # the pending step may finish it
             if req.num_tokens + w + s > cap:
                 return
-        if not self._grow_pages_for(reqs, 2 * s + 1):
+        if not self._grow_pages_for(reqs, [2 * s + 1] * len(reqs)):
             return
         m = self.metrics
         with phase(
@@ -2004,55 +2028,122 @@ class JaxEngine:
     def _run_decode_plain(
         self, reqs: list[Request], mixed: bool = False
     ) -> list[StepOutput]:
-        inflight, self._inflight = self._inflight, None
-        if inflight is not None:
-            if self._inflight_matches(inflight, reqs):
-                return self._consume_inflight(inflight, mixed=mixed)
-            self._inflight = inflight  # hand back for the metrics/log
-            self._discard_inflight("decode batch changed")
+        st = self._take_inflight(reqs, (), "decode batch changed")
+        if st is None:
+            st = self._launch_decode(reqs)
+        # Keep the device busy past this step BEFORE blocking on its
+        # result: the dispatch launched ahead computes while the host
+        # scans this step's ids for stops below.
+        self._speculate(st)
+        return self._finish_decode(st, mixed)
+
+    def _stage_decode_rows(
+        self, reqs: list[Request], b_bucket: int,
+        ahead: Optional[list[int]],
+    ):
+        """Host arrays of the decode rows of one dispatch: ((tokens,
+        positions, valid, page table), sampling arrays, all_greedy).
+        `ahead[i]` tokens are still to come to row i from the dispatch on
+        the device (None: a dispatch for the batch in hand): positions
+        and draw counters advance by them, and the row's input token is
+        that dispatch's, fed on the device (`_feed`)."""
+        n = len(reqs)
+        mp = self.config.max_pages_per_seq
+        tokens = np.zeros((b_bucket, 1), np.int32)
+        positions = np.zeros((b_bucket, 1), np.int32)
+        valid = np.zeros((b_bucket, 1), bool)
+        pt = np.zeros((b_bucket, mp), np.int32)
+        for i, req in enumerate(reqs):
+            if ahead is None or not ahead[i]:
+                tokens[i, 0] = req.all_tokens[-1]
+            positions[i, 0] = req.num_tokens - 1
+            valid[i, 0] = True
+            pt[i, : len(req.pages)] = req.pages
+        samp, all_greedy = self._sampling_arrays(reqs, pad_to=b_bucket)
+        if ahead is not None:
+            positions[:n, 0] += ahead
+            # the dispatch on the device advances every draw counter by
+            # the tokens it samples
+            samp[4][:n] += np.asarray(ahead, np.int32)
+        return (tokens, positions, valid, pt), samp, all_greedy
+
+    def _feed(self, feed, tokens):
+        """The `[B, 1]` token input of a dispatch launched ahead of its
+        batch: row i takes `ids.reshape(-1)[src[i]]` of the dispatch
+        still on the device where `src[i] >= 0` (no host round-trip),
+        else the host's `tokens[i]`. One small program per (ids shape,
+        rows)."""
+        ids, src = feed
+        key = ("feed", tuple(ids.shape), tokens.shape[0])
+        fn = self._jit_cache.get(key)
+        if fn is None:
+
+            def feed_fn(ids, src, tokens):
+                picked = ids.reshape(-1)[jnp.maximum(src, 0)]
+                return jnp.where(
+                    src[:, None] >= 0, picked[:, None].astype(jnp.int32),
+                    tokens,
+                )
+
+            fn = self._cache_jit("feed", key, jax.jit(feed_fn))
+        return fn(ids, src, tokens)
+
+    def _launch_decode(
+        self, reqs: list[Request], ahead: Optional[list[int]] = None,
+        feed=None,
+    ) -> Optional[_Launched]:
+        """Stage and launch one pure decode dispatch over `reqs`: an
+        on-device K-step window, one step, or the fused scan. With
+        `ahead`/`feed` it is launched ahead of its batch (`_speculate`);
+        None then means the pool cannot pre-grow the rows' pages."""
         m = self.metrics
+        speculative = ahead is not None
         t0 = time.perf_counter()  # a K-step window's wall: stage to sync
         with phase(
             m, "engine.stage", "time_stage_ms", "time_decode_dispatch_ms"
         ):
             b_bucket = self.config.decode_bucket_for(len(reqs))
-            mp = self.config.max_pages_per_seq
             # On-device K-step window first (config.decode_kstep): finish
             # conditions evaluate ON DEVICE, so no overshoot compute past
             # a stop; k_win == 1 falls through to the classic path (which
-            # is then bit-identical to a decode_kstep-free build).
-            k_win = self._pick_kstep(reqs)
-            k_steps = k_win if k_win > 1 else self._pick_decode_steps(reqs)
-            tokens = np.zeros((b_bucket, 1), np.int32)
-            positions = np.zeros((b_bucket, 1), np.int32)
-            valid = np.zeros((b_bucket, 1), bool)
-            pt = np.zeros((b_bucket, mp), np.int32)
-            for i, req in enumerate(reqs):
-                tokens[i, 0] = req.all_tokens[-1]
-                positions[i, 0] = req.num_tokens - 1
-                valid[i, 0] = True
-                pt[i, : len(req.pages)] = req.pages
-
-            samp, all_greedy = self._sampling_arrays(reqs, pad_to=b_bucket)
+            # is then bit-identical to a decode_kstep-free build). Ahead
+            # of the batch, an ineligible batch is no counted fallback.
+            k_win = 1
+            if not speculative or self._kstep_candidate(reqs):
+                k_win = self._pick_kstep(reqs, ahead)
+            k_steps = (
+                k_win if k_win > 1 else self._pick_decode_steps(reqs, ahead)
+            )
+            if speculative and not self._grow_pages_for(
+                reqs, [a + k_steps - 1 for a in ahead]
+            ):
+                return None
+            base, samp, all_greedy = self._stage_decode_rows(
+                reqs, b_bucket, ahead
+            )
             lp = self._batch_logprobs(reqs)
-            pen = self._batch_penalty_bucket(reqs)
+            # penalty history needs the pending tokens host-side: no
+            # dispatch is launched ahead with one (_speculate)
+            pen = 0 if speculative else self._batch_penalty_bucket(reqs)
             pen_args = (
                 self._penalty_arrays(reqs, b_bucket, pen) if pen else ()
             )
             bias = self._batch_bias(reqs)
             bias_kwargs = self._bias_arrays(reqs, b_bucket) if bias else {}
             host = {
-                "base": (tokens, positions, valid, pt), "samp": samp,
-                "pen": pen_args, "bias": bias_kwargs,
+                "base": base, "samp": samp, "pen": pen_args,
+                "bias": bias_kwargs,
             }
             if k_win > 1:
                 host["stops"], host["budgets"] = self._kstep_arrays(
-                    reqs, b_bucket
+                    reqs, b_bucket, ahead or [0] * len(reqs)
                 )
             elif k_steps == 1:
                 host["last"] = np.zeros(b_bucket, np.int32)
+            if speculative:
+                host["src"] = np.full(b_bucket, -1, np.int32)
+                host["src"][: len(reqs)] = feed[1]
             dev = self._dev_tree(host)
-            args = (self.params, *dev["base"][:3], self.kv, dev["base"][3])
             # logprobs rows never reach a K-step window (_pick_kstep
             # falls back), so that family has no lp variant
             kind, fn = self._decode_program(
@@ -2063,17 +2154,20 @@ class JaxEngine:
                 head = (dev["stops"], dev["budgets"])
             else:
                 head = (dev["last"],) if kind == "decode" else ()
-        lp_data = None
-        n_emit_dev = None
+        lp_data = n_emit = None
         with phase(
             m, "engine.launch", "time_decode_dispatch_ms", kind=kind,
-            rows=b_bucket, k=k_steps, speculative=0,
+            rows=b_bucket, k=k_steps, speculative=int(speculative),
         ):
+            d_tokens, d_positions, d_valid, d_pt = dev["base"]
+            if speculative:
+                d_tokens = self._feed((feed[0], dev["src"]), d_tokens)
             out = fn(
-                *args, *head, *dev["samp"], *dev["pen"], **dev["bias"]
+                self.params, d_tokens, d_positions, d_valid, self.kv, d_pt,
+                *head, *dev["samp"], *dev["pen"], **dev["bias"],
             )
         if k_win > 1:
-            token_ids, n_emit_dev, self.kv = out
+            token_ids, n_emit, self.kv = out
             m.kstep_windows += 1
             m.kstep_steps += k_steps
             m.kstep_window_size = k_steps
@@ -2082,41 +2176,50 @@ class JaxEngine:
             token_ids, lp_data, self.kv = out
         else:
             token_ids, self.kv = out  # [B], or [K, B] when fused
-        # Keep the device busy past this step BEFORE blocking on its
-        # result: the speculated N+1 dispatch computes while the host
-        # scans this step's ids for stops below.
-        self._maybe_speculate(
-            reqs, b_bucket, k_steps, token_ids,
-            greedy=all_greedy, lp=lp, bias=bias, kstep=k_win > 1,
+        return _Launched(
+            reqs=tuple(reqs), b_bucket=b_bucket, k_steps=k_steps,
+            token_ids=token_ids, lp_data=lp_data, kstep=k_win > 1,
+            n_emit=n_emit, t0=t0,
         )
-        with phase(m, "engine.readback", "time_decode_sync_ms", lagged=0):
-            ids = np.asarray(token_ids).reshape(k_steps, b_bucket)
-            lp_arrays = self._materialize_lp(lp_data, k_steps, b_bucket)
-        if k_win > 1:
+
+    def _finish_decode(
+        self, st: _Launched, mixed: bool = False
+    ) -> list[StepOutput]:
+        """Read a pure decode dispatch's ids and postprocess them. For a
+        dispatch launched ahead the async copy started a step ago, so
+        this sync is (near) free."""
+        reqs = list(st.reqs)
+        with phase(
+            self.metrics, "engine.readback", "time_decode_sync_ms",
+            lagged=int(st.expected is not None),
+        ):
+            ids = np.asarray(st.token_ids).reshape(st.k_steps, st.b_bucket)
+            lp_arrays = self._materialize_lp(
+                st.lp_data, st.k_steps, st.b_bucket
+            )
+        if st.kstep and st.expected is None:
             # window wall (dispatch+sync) is the measured column for the
             # decode_kstep family's attainment; /k is the per-step time
             # the stall histogram spreads window emissions by
-            window_ms = (time.perf_counter() - t0) * 1000.0
+            window_ms = (time.perf_counter() - st.t0) * 1000.0
             self.metrics.time_kstep_ms += window_ms
-            self._kstep_step_ms = window_ms / k_steps
-            outputs = self._decode_postprocess(
-                reqs, k_steps, ids, lp_arrays, mixed=mixed, kstep=True
-            )
+            self._kstep_step_ms = window_ms / st.k_steps
+        outputs = self._decode_postprocess(
+            reqs, st.k_steps, ids, lp_arrays, mixed=mixed, kstep=st.kstep
+        )
+        if st.kstep and st.expected is None:
             # device freeze decisions vs the host finish scan: they are
             # the same arithmetic — disagreement means a program bug, so
             # surface it loudly rather than silently trusting either
             host_emitted = sum(len(o.new_token_ids) for o in outputs)
-            dev_emitted = int(np.asarray(n_emit_dev)[: len(reqs)].sum())
+            dev_emitted = int(np.asarray(st.n_emit)[: len(reqs)].sum())
             if host_emitted != dev_emitted:
                 logger.warning(
                     "decode_kstep window disagreement: device emitted "
                     "%d tokens, host accepted %d (K=%d, B=%d)",
-                    dev_emitted, host_emitted, k_steps, len(reqs),
+                    dev_emitted, host_emitted, st.k_steps, len(reqs),
                 )
-            return outputs
-        return self._decode_postprocess(
-            reqs, k_steps, ids, lp_arrays, mixed=mixed
-        )
+        return outputs
 
     def _decode_program(
         self, b_bucket: int, k_steps: int, kstep: bool, greedy: bool,
@@ -2210,11 +2313,11 @@ class JaxEngine:
         are bit-exact vs the XOR scheduler (tests/test_engine_mixed.py).
 
         Two cases run the halves as separate dispatches instead (same
-        semantics, same streams): a matching speculative in-flight decode
-        — mixed steps count as decode steps for the overlap pipeline, so
-        the speculated ids land as the decode half and the prefill chunk
-        dispatches beside them — and multimodal pieces (the fused program
-        has no mm variant)."""
+        semantics, same streams): a decode dispatch launched ahead that
+        matches the decode rows (a prompt arrived that nobody foresaw) —
+        it lands as the decode half and the prefill chunk dispatches
+        beside it — and pieces or rows the fused program has no variant
+        for (multimodal, a K-step window)."""
         reqs_d = list(batch.decode)
         pieces = list(batch.prefill)
         if self._spec_draft and self._spec_active(reqs_d):
@@ -2225,32 +2328,31 @@ class JaxEngine:
             # the chained spec dispatch consumes/primes exactly as in
             # pure decode). The prefill half rides _run_prefill, which
             # also keeps the draft pool covered for the pieces.
-            self.metrics.prefill_dispatches += 1
-            outputs = self._run_prefill(
-                ScheduledBatch(kind="prefill", prefill=batch.prefill),
-                mixed=True,
-            )
+            outputs = self._prefill_beside(batch)
             outputs += self._run_decode_spec_draft(reqs_d, mixed=True)
             return outputs
         if self._inflight_spec is not None:
             self._discard_inflight_spec("speculation inactive")
+        if self._inflight is not None and self._inflight.pieces:
+            # a whole mixed step launched ahead: this one, or nothing
+            st = self._take_inflight(
+                reqs_d, pieces, "mixed composition changed"
+            )
+            if st is not None:
+                self._speculate(st)
+                return self._finish_mixed(st)
         inflight = self._inflight
         use_inflight = inflight is not None and self._inflight_matches(
-            inflight, reqs_d
+            inflight, reqs_d, ()
         )
-        any_mm = any(p.request.mm_embeds is not None for p in pieces)
         # K-step windows compose with mixed steps as the decode LEG
         # beside the prefill chunk (two dispatches, same semantics):
         # the fused mixed program has no kstep variant, and the window
         # path handles its own stops/budgets/runway host arrays.
-        if use_inflight or any_mm or self._kstep_candidate(reqs_d):
-            self.metrics.prefill_dispatches += 1
-            outputs = self._run_prefill(
-                ScheduledBatch(kind="prefill", prefill=batch.prefill),
-                mixed=True,
-            )
-            # consumes (or rolls back) the inflight itself and re-primes
-            # the pipeline when the decode rows stay stable
+        if use_inflight or not self._fusable(reqs_d, pieces):
+            outputs = self._prefill_beside(batch)
+            # consumes (or rolls back) the inflight itself and launches
+            # the next dispatch ahead, the rows that just joined in it
             outputs += self._run_decode_plain(reqs_d, mixed=True)
             return outputs
         if inflight is not None:
@@ -2266,37 +2368,62 @@ class JaxEngine:
         groups: dict[int, list] = {}
         for piece in pieces:
             groups.setdefault(self._bucket_t(piece.length), []).append(piece)
-        t_bucket = max(groups)
-        fuse_pieces = groups.pop(t_bucket)
+        fuse_pieces = groups.pop(max(groups))
         rest = [p for g in groups.values() for p in g]
-        outputs_rest: list[StepOutput] = []
+        outputs: list[StepOutput] = []
         if rest:
             self.metrics.prefill_dispatches += 1
-            outputs_rest = self._run_prefill(
+            outputs = self._run_prefill(
                 ScheduledBatch(kind="prefill", prefill=tuple(rest)),
                 mixed=True,
             )
-        pieces = fuse_pieces
+        st = self._launch_mixed(reqs_d, fuse_pieces)
+        self._speculate(st)
+        return outputs + self._finish_mixed(st)
 
+    def _prefill_beside(self, batch: ScheduledBatch) -> list[StepOutput]:
+        """A mixed batch's pieces as prefill dispatches of their own,
+        read back at once, beside a separate decode half."""
+        self.metrics.prefill_dispatches += 1
+        return self._run_prefill(
+            ScheduledBatch(kind="prefill", prefill=batch.prefill),
+            mixed=True,
+        )
+
+    def _fusable(self, reqs_d: list[Request], pieces: list) -> bool:
+        """Whether a mixed batch runs as the one fused program: no
+        multimodal piece (no mm variant) and no K-step window for the
+        decode rows (no kstep variant)."""
+        return not (
+            any(p.request.mm_embeds is not None for p in pieces)
+            or self._kstep_candidate(reqs_d)
+        )
+
+    def _launch_mixed(
+        self, reqs_d: list[Request], pieces: list,
+        ahead: Optional[list[int]] = None, feed=None,
+    ) -> Optional[_Launched]:
+        """Stage and launch the fused mixed program: decode rows
+        `reqs_d` beside `pieces`, which share one T bucket. With
+        `ahead`/`feed` it is launched ahead of its batch (`_speculate`);
+        None then means the pool cannot pre-grow the decode rows."""
         m = self.metrics
+        speculative = ahead is not None
         with phase(
             m, "engine.stage", "time_stage_ms", "time_decode_dispatch_ms"
         ):
+            if speculative and not self._grow_pages_for(reqs_d, ahead):
+                return None
             b_dec = self.config.decode_bucket_for(len(reqs_d))
             mp = self.config.max_pages_per_seq
             # decode half: identical arrays to a k=1 decode step
-            d_tokens = np.zeros((b_dec, 1), np.int32)
-            d_positions = np.zeros((b_dec, 1), np.int32)
-            d_valid = np.zeros((b_dec, 1), bool)
-            d_pt = np.zeros((b_dec, mp), np.int32)
-            for i, req in enumerate(reqs_d):
-                d_tokens[i, 0] = req.all_tokens[-1]
-                d_positions[i, 0] = req.num_tokens - 1
-                d_valid[i, 0] = True
-                d_pt[i, : len(req.pages)] = req.pages
+            based, samp_d, greedy_d = self._stage_decode_rows(
+                reqs_d, b_dec, ahead
+            )
             # prefill half: one T-bucket group per fused program keeps the
             # compile family at (b_decode_bucket, t_prefill_bucket,
             # b_prefill_bucket)
+            t_bucket = max(self._bucket_t(p.length) for p in pieces)
             b_pre = self._bucket_b(len(pieces))
             p_tokens = np.zeros((b_pre, t_bucket), np.int32)
             p_positions = np.zeros((b_pre, t_bucket), np.int32)
@@ -2318,7 +2445,6 @@ class JaxEngine:
             # sampled row space: decode rows [0, b_dec); when a piece
             # completes its prompt, prefill rows join at [b_dec, b_dec+b_pre)
             pre_reqs = [p.request for p in pieces]
-            samp_d, greedy_d = self._sampling_arrays(reqs_d, pad_to=b_dec)
             if any_last:
                 samp_p, greedy_p = self._sampling_arrays(pre_reqs, pad_to=b_pre)
                 samp = tuple(
@@ -2329,7 +2455,8 @@ class JaxEngine:
             else:
                 samp, all_greedy, row_reqs = samp_d, greedy_d, reqs_d
             lp = self._batch_logprobs(row_reqs)
-            pen = self._batch_penalty_bucket(row_reqs)
+            # no dispatch is launched ahead with a penalty (_speculate)
+            pen = 0 if speculative else self._batch_penalty_bucket(row_reqs)
             if pen:
                 pen_d = self._penalty_arrays(reqs_d, b_dec, pen)
                 if any_last:
@@ -2355,53 +2482,65 @@ class JaxEngine:
                 bias_kwargs = {}
 
             host = {
-                "based": (d_tokens, d_positions, d_valid, d_pt),
+                "based": based,
                 "basep": (p_tokens, p_positions, p_valid, p_pt),
                 "last": last_idx, "samp": samp, "pen": pen_args,
                 "bias": bias_kwargs,
             }
+            if speculative:
+                host["src"] = np.full(b_dec, -1, np.int32)
+                host["src"][: len(reqs_d)] = feed[1]
             dev = self._dev_tree(host)
             fn = self._get_step_fn(
                 "mixed", b_dec, t_bucket, greedy=all_greedy,
                 first_chunk=first_chunk, lp=lp, pen=pen, bias=bias,
                 b_pre=b_pre, psamp=any_last,
             )
-            args = (
-                self.params, *dev["based"][:3], self.kv, dev["based"][3],
-                *dev["basep"], dev["last"],
-            )
         with phase(
             m, "engine.launch", "time_decode_dispatch_ms", kind="mixed",
-            rows=b_dec, t=t_bucket, k=1, speculative=0,
+            rows=b_dec, t=t_bucket, k=1, speculative=int(speculative),
         ):
-            out = fn(*args, *dev["samp"], *dev["pen"], **dev["bias"])
+            d_tokens, d_positions, d_valid, d_pt = dev["based"]
+            if speculative:
+                d_tokens = self._feed((feed[0], dev["src"]), d_tokens)
+            out = fn(
+                self.params, d_tokens, d_positions, d_valid, self.kv, d_pt,
+                *dev["basep"], dev["last"],
+                *dev["samp"], *dev["pen"], **dev["bias"],
+            )
         lp_data = None
         if lp >= 0:
             token_ids, lp_data, self.kv = out
         else:
-            token_ids, self.kv = out
-        if not any_last:
-            # No piece joins decode this step, so the decode rows are
-            # stable: keep the pipeline primed — the speculated dispatch
-            # lands as the decode half of the NEXT mixed (or decode) step.
-            self._maybe_speculate(
-                reqs_d, b_dec, 1, token_ids,
-                greedy=greedy_d, lp=lp, bias=bias,
-            )
-        with phase(m, "engine.readback", "time_decode_sync_ms", lagged=0):
-            ids = np.asarray(token_ids)  # [b_dec] or [b_dec + b_pre]
-            lp_arrays = self._materialize_lp(lp_data, 1, ids.shape[0])
+            token_ids, self.kv = out  # [b_dec] or [b_dec + b_pre]
+        return _Launched(
+            reqs=tuple(reqs_d), b_bucket=b_dec, k_steps=1,
+            token_ids=token_ids, lp_data=lp_data, pieces=tuple(pieces),
+            psamp=any_last,
+        )
+
+    def _finish_mixed(self, st: _Launched) -> list[StepOutput]:
+        """Read a fused mixed dispatch's ids and postprocess both halves,
+        decode rows first."""
+        m = self.metrics
+        b_dec = st.b_bucket
+        with phase(
+            m, "engine.readback", "time_decode_sync_ms",
+            lagged=int(st.expected is not None),
+        ):
+            ids = np.asarray(st.token_ids)  # [b_dec] or [b_dec + b_pre]
+            lp_arrays = self._materialize_lp(st.lp_data, 1, ids.shape[0])
         d_lp = p_lp = None
         if lp_arrays is not None:
             d_lp = tuple(a[:, :b_dec] for a in lp_arrays)
             p_lp = tuple(a[0] for a in lp_arrays)
-        outputs = outputs_rest + self._decode_postprocess(
-            reqs_d, 1, ids[None, :b_dec], d_lp, mixed=True
+        outputs = self._decode_postprocess(
+            list(st.reqs), 1, ids[None, :b_dec], d_lp, mixed=True
         )
         with phase(m, "engine.postprocess", "time_decode_host_ms") as ph:
             n0 = len(outputs)
             self._prefill_postprocess(
-                pieces, ids, p_lp, b_dec, outputs, mixed=True
+                list(st.pieces), ids, p_lp, b_dec, outputs, mixed=True
             )
             ph.note(
                 tokens=len(outputs) - n0,
@@ -2409,159 +2548,85 @@ class JaxEngine:
             )
         return outputs
 
-    # -- overlapped decode (one-step-lagged readback) ----------------------
+    # -- overlapped decode (one dispatch ahead, one-step-lagged readback) --
 
-    def _maybe_speculate(
-        self, reqs: list[Request], b_bucket: int, k_prev: int, ids_dev,
-        greedy: bool, lp: int, bias: bool, kstep: bool = False,
-    ) -> None:
-        """Dispatch the NEXT decode step before the pending step's ids
-        reach the host: same batch, positions advanced by k_prev, tokens
-        = the pending step's last sampled ids sliced ON DEVICE (no host
-        round-trip). Only when the scheduler guarantees batch stability
-        (no admissible waiting request, nothing mid-prefill), every
-        request surely survives the pending step's k_prev tokens, pages
-        can pre-grow to cover the window, and no penalty history (which
-        would need the pending tokens host-side) is in play."""
+    def _speculate(self, pending: _Launched) -> None:
+        """Launch the NEXT decode-carrying dispatch before `pending`'s
+        ids reach the host: the batch `schedule()` will return after it
+        (`Scheduler.next_batch`: rows certain to end in `pending` gone,
+        their successors admitted, a prompt's last piece joined), each
+        row's input token taken from `pending`'s ids ON DEVICE (`_feed`),
+        positions and draw counters advanced by what `pending` adds. A
+        mixed batch is launched as the fused program where it would run
+        as one (`_fusable`, one T bucket), else its decode rows alone
+        (they land as the decode half, `_run_mixed`). Nothing is
+        launched where that batch cannot be known, carries no decode
+        row, a penalty is in play (its history needs the pending tokens
+        host-side) or the rows' pages cannot pre-grow."""
         if not self._overlap_enabled:
             return
-        if not self.scheduler.decode_batch_stable():
-            # Mixed mode: pending prefill work doesn't stall the decode
-            # rows — a speculative decode dispatch still lands as the
-            # decode half of the next mixed step, provided the row set
-            # itself is stable (no admissible arrival, no piece joining
-            # decode). Callers that know a piece completes this step
-            # skip speculation before getting here.
-            if not (
-                self._mixed_enabled
-                and self.scheduler.decode_rows_stable(reqs)
-            ):
-                return
-        if self._batch_penalty_bucket(reqs):
-            return
-        cap = min(
-            self.config.max_context,
-            self.config.max_pages_per_seq * self.config.page_size,
+        nxt = self.scheduler.next_batch(
+            pending.reqs, pending.k_steps, pending.pieces
         )
-        k_next = k_prev
-        for req in reqs:
-            s = req.sampling
-            if (
-                len(req.output_tokens) + req.num_emitted + k_prev
-                >= s.max_tokens
-            ):
-                return  # pending step finishes it: batch will change
-            if req.num_tokens + k_prev >= self.config.max_context:
-                return
-            # never write KV past the page-table cap
-            k_next = min(k_next, cap - (req.num_tokens + k_prev) + 1)
-        if k_next < 1:
+        if nxt is None or not nxt.decode:
             return
-        k_next = self._pow2_floor(k_next)  # reuse the program family
-        if not self._grow_pages_for(reqs, k_prev + k_next - 1):
+        rows, pieces = list(nxt.decode), list(nxt.prefill)
+        fuse = (
+            nxt.kind == "mixed"
+            and self._fusable(rows, pieces)
+            and len({self._bucket_t(p.length) for p in pieces}) == 1
+        )
+        if self._batch_penalty_bucket(
+            rows + [p.request for p in pieces if fuse]
+        ):
             return
-        m = self.metrics
-        with phase(
-            m, "engine.stage", "time_stage_ms", "time_decode_dispatch_ms"
-        ):
-            mp = self.config.max_pages_per_seq
-            positions = np.zeros((b_bucket, 1), np.int32)
-            valid = np.zeros((b_bucket, 1), bool)
-            pt = np.zeros((b_bucket, mp), np.int32)
-            for i, req in enumerate(reqs):
-                positions[i, 0] = req.num_tokens - 1 + k_prev
-                valid[i, 0] = True
-                pt[i, : len(req.pages)] = req.pages
-            samp, _ = self._sampling_arrays(reqs, pad_to=b_bucket)
-            # the pending step advances every draw counter by its k
-            samp[4][: len(reqs)] += k_prev
-            bias_kwargs = self._bias_arrays(reqs, b_bucket) if bias else {}
-            host = {
-                "base": (positions, valid, pt), "samp": samp,
-                "bias": bias_kwargs,
-            }
-            use_kstep = kstep and k_next > 1
-            if use_kstep:
-                # chain the next K-window through the SAME decode_kstep
-                # family: budgets discount the pending window's k_prev
-                # tokens (the early-outs above already guarantee no row
-                # LENGTH-finishes inside the pending window; a sampled
-                # stop still rolls the chained window back at consume
-                # time)
-                host["stops"], host["budgets"] = self._kstep_arrays(
-                    reqs, b_bucket, emitted_ahead=k_prev
-                )
-            elif k_next == 1:
-                host["last"] = np.zeros(b_bucket, np.int32)
-            dev = self._dev_tree(host)
-            d_positions, d_valid, d_pt = dev["base"]
-            # kstep eligibility pinned lp == -1 at the original dispatch;
-            # the chained window inherits it
-            kind, fn = self._decode_program(
-                b_bucket, k_next, kstep=use_kstep, greedy=greedy, lp=lp,
-                pen=0, bias=bias,
-            )
-            if use_kstep:
-                head = (dev["stops"], dev["budgets"])
-            else:
-                head = (dev["last"],) if k_next == 1 else ()
-        lp_data = None
-        with phase(
-            m, "engine.launch", "time_decode_dispatch_ms", kind=kind,
-            rows=b_bucket, k=k_next, speculative=1,
-        ):
-            # on-device token feedback: [B] or [K, B] -> last step [B, 1]
-            d_tokens = (
-                ids_dev if ids_dev.ndim == 2 else ids_dev[None]
-            )[-1][:, None].astype(jnp.int32)
-            out = fn(
-                self.params, d_tokens, d_positions, d_valid, self.kv, d_pt,
-                *head, *dev["samp"], **dev["bias"],
-            )
-            if use_kstep:
-                token_ids, _n_emit, self.kv = out
-                m.kstep_windows += 1
-                m.kstep_steps += k_next
-                m.kstep_window_size = k_next
-                self._kstep_live = k_next
-            elif lp >= 0:
-                token_ids, lp_data, self.kv = out
-            else:
-                token_ids, self.kv = out
-            # one-step-lagged readback: start the device→host copy now so
-            # the next step's sync finds the bytes already landed
-            for arr in (token_ids, *(lp_data or ())):
-                arr.copy_to_host_async()
+        # where in pending's ids each row's input token is, and how many
+        # tokens pending adds to the row first; a row it does not carry
+        # (joined through a dispatch already read) feeds from the host
+        k, b = pending.k_steps, pending.b_bucket
+        comes = {
+            id(r): ((k - 1) * b + i, k) for i, r in enumerate(pending.reqs)
+        }
+        if pending.psamp:
+            for j, p in enumerate(pending.pieces):
+                if p.start + p.length >= len(p.request.prompt_tokens):
+                    comes[id(p.request)] = (b + j, 1)
+        src = [comes.get(id(r), (-1, 0))[0] for r in rows]
+        ahead = [comes.get(id(r), (-1, 0))[1] for r in rows]
+        feed = (pending.token_ids, src)
+        if fuse:
+            st = self._launch_mixed(rows, pieces, ahead, feed)
+        else:
+            st = self._launch_decode(rows, ahead, feed)
+        if st is None:
+            return
+        # one-step-lagged readback: start the device→host copy now so
+        # the next step's sync finds the bytes already landed
+        for arr in (st.token_ids, *(st.lp_data or ())):
+            arr.copy_to_host_async()
+        st.expected = tuple(
+            (r.num_tokens + a, len(r.output_tokens) + a)
+            for r, a in zip(rows, ahead)
+        )
         self.metrics.overlap_dispatches += 1
-        self._inflight = _InflightDecode(
-            reqs=tuple(reqs),
-            b_bucket=b_bucket,
-            k_steps=k_next,
-            token_ids=token_ids,
-            lp_data=lp_data,
-            expected_num_tokens=tuple(r.num_tokens + k_prev for r in reqs),
-            expected_out_len=tuple(
-                len(r.output_tokens) + k_prev for r in reqs
-            ),
-            greedy=greedy,
-            lp=lp,
-            bias=bias,
-            kstep=use_kstep,
-        )
+        self._inflight = st
 
+    @staticmethod
     def _inflight_matches(
-        self, inflight: _InflightDecode, reqs: list[Request]
+        inflight: _Launched, reqs: list[Request], pieces
     ) -> bool:
-        """The speculation is this step iff the scheduled batch is the
-        SAME requests (identity — an aborted+resubmitted id is a new
-        object) in the same rows, and each advanced exactly the pending
-        step's k tokens (a preemption/recompute resets output_tokens and
-        fails here even though num_tokens survives the fold)."""
-        if len(reqs) != len(inflight.reqs):
+        """The dispatch launched ahead is this step iff the scheduled
+        batch is the SAME requests (identity — an aborted+resubmitted id
+        is a new object) in the same rows, each advanced exactly the
+        tokens expected of it (a preemption/recompute resets
+        output_tokens and fails here even though num_tokens survives the
+        fold), beside the same prompt pieces."""
+        if len(reqs) != len(inflight.reqs) or len(pieces) != len(
+            inflight.pieces
+        ):
             return False
-        for r, spec_r, exp_nt, exp_out in zip(
-            reqs, inflight.reqs, inflight.expected_num_tokens,
-            inflight.expected_out_len,
+        for r, spec_r, (exp_nt, exp_out) in zip(
+            reqs, inflight.reqs, inflight.expected
         ):
             if (
                 r is not spec_r
@@ -2569,44 +2634,39 @@ class JaxEngine:
                 or len(r.output_tokens) != exp_out
             ):
                 return False
-        return True
+        return all(
+            p.request is q.request
+            and p.start == q.start
+            and p.length == q.length
+            for p, q in zip(pieces, inflight.pieces)
+        )
 
-    def _consume_inflight(
-        self, inflight: _InflightDecode, mixed: bool = False
-    ) -> list[StepOutput]:
-        """The speculated dispatch IS this step: speculate the next one
-        (so the device never drains), then materialize the one-step-
-        lagged ids — their async copy started last step, so this sync is
-        (near) free — and postprocess."""
+    def _take_inflight(
+        self, reqs: list[Request], pieces, why: str
+    ) -> Optional[_Launched]:
+        """The dispatch launched ahead, if it IS this step (all of it or
+        nothing); else it is rolled back (`why`) and None returned."""
+        inflight = self._inflight
+        if inflight is None:
+            return None
+        if not self._inflight_matches(inflight, reqs, pieces):
+            self._discard_inflight(why)
+            return None
+        self._inflight = None
         self.metrics.overlap_hits += 1
-        reqs = list(inflight.reqs)
-        self._maybe_speculate(
-            reqs, inflight.b_bucket, inflight.k_steps, inflight.token_ids,
-            greedy=inflight.greedy, lp=inflight.lp, bias=inflight.bias,
-            kstep=inflight.kstep,
-        )
-        with phase(
-            self.metrics, "engine.readback", "time_decode_sync_ms", lagged=1
-        ):
-            ids = np.asarray(inflight.token_ids).reshape(
-                inflight.k_steps, inflight.b_bucket
-            )
-            lp_arrays = self._materialize_lp(
-                inflight.lp_data, inflight.k_steps, inflight.b_bucket
-            )
-        return self._decode_postprocess(
-            reqs, inflight.k_steps, ids, lp_arrays, mixed=mixed,
-            kstep=inflight.kstep,
-        )
+        return inflight
 
     def _discard_inflight(self, why: str) -> None:
-        """Roll back a speculated dispatch. The sampled ids are overshoot
-        — dropped exactly like decode_multi's post-stop tokens. Its KV
-        writes are benign: for surviving requests they used the true
-        tokens at the true positions (the real dispatch overwrites them
-        before any read); for finished/preempted requests they sit in
-        released pages whose next owner's writes are stream-ordered
-        after them. Pages grown for the window stay with their requests."""
+        """Roll back a dispatch launched ahead. The sampled ids are
+        overshoot — dropped exactly like decode_multi's post-stop tokens.
+        Its KV writes are benign: for surviving requests they used the
+        true tokens at the true positions (a prompt piece's included; the
+        real dispatch overwrites them before any read, and no page is
+        registered for reuse before it); for finished, aborted or
+        preempted requests they sit in released pages whose next owner's
+        writes are stream-ordered after them. Pages grown for the window,
+        and a request admitted early for it, stay as they are: the next
+        `schedule()` finds the state it would have made itself."""
         inflight, self._inflight = self._inflight, None
         if inflight is None:
             return

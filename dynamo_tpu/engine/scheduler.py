@@ -18,10 +18,9 @@ Policy (one `schedule()` call = one engine step):
    page tables by one page where the next token would overflow; preempt
    the youngest sequences if pages run out.
 
-Decode batches are STABLE between consecutive `schedule()` calls unless
-admission, chunked prefill, or a request-side event (finish, abort,
-preemption) intervenes — `decode_batch_stable()` states the contract the
-engine's overlapped decode pipeline relies on.
+`next_batch()` says, while a dispatch is still on the device, which batch
+the next `schedule()` call returns once it is read back — the contract the
+engine's overlapped decode pipeline relies on (docs/engine.md).
 """
 
 from __future__ import annotations
@@ -72,6 +71,32 @@ class ScheduledBatch:
         if self.kind == "mixed":
             return sum(p.length for p in self.prefill) + len(self.decode)
         return len(self.decode)
+
+
+class _After:
+    """The running set as a dispatch still on the device will leave it,
+    as far as the host knows before reading what it sampled: `gone`
+    requests it is certain to finish, and `computed` (by `id(request)`)
+    the prompt tokens in pages once its pieces have run. With neither,
+    the running set as it is now."""
+
+    def __init__(self, gone=(), computed=None):
+        self._gone = {id(r) for r in gone}
+        self._computed = computed or {}
+
+    def computed(self, req: Request) -> int:
+        return self._computed.get(id(req), req.num_computed_tokens)
+
+    def state(self, req: Request) -> RequestState:
+        if id(req) in self._gone:
+            return RequestState.FINISHED
+        if id(req) in self._computed:
+            done = self._computed[id(req)] >= len(req.prompt_tokens)
+            return RequestState.DECODE if done else RequestState.PREFILL
+        return req.state
+
+
+_NOW = _After()
 
 
 class Scheduler:
@@ -161,7 +186,7 @@ class Scheduler:
     def num_running(self) -> int:
         return len(self.running)
 
-    def clamp_kstep_window(self, reqs, k: int) -> int:
+    def clamp_kstep_window(self, reqs, k: int, ahead=None) -> int:
         """Page-runway guarantee for on-device K-step decode windows
         (EngineConfig.decode_kstep): the fused program writes K tokens
         of KV per row with NO host allocation mid-window, so every page
@@ -171,70 +196,109 @@ class Scheduler:
         engine then pre-grows via its normal growth path, which can
         still preempt-by-recompute if a race shrinks the pool. Returns
         the clamped window (>= 1); K=1 needs no runway beyond classic
-        stepping's."""
+        stepping's. `ahead[i]` are tokens a dispatch still on the device
+        adds to row i before this window starts."""
         ps = self.config.page_size
+        ahead = ahead or [0] * len(reqs)
         while k > 1:
             need = 0
-            for req in reqs:
+            for req, a in zip(reqs, ahead):
                 need += max(
                     0,
-                    -(-(req.num_tokens + k - 1) // ps) - len(req.pages),
+                    -(-(req.num_tokens + a + k - 1) // ps) - len(req.pages),
                 )
             if need <= self.allocator.num_free:
                 return k
             k //= 2
         return 1
 
-    def decode_batch_stable(self) -> bool:
-        """The overlap contract (engine `overlap_decode`, docs/engine.md):
-        absent request-side events, the NEXT `schedule()` call returns
-        the same decode batch iff no waiting request is admissible right
-        now and no running request still needs prefill — admission and
-        chunked prefill are the only scheduler-side sources of batch
-        change. The engine detects the request-side invalidations
-        (finish, abort, preemption-recompute) per request at consume
-        time; this predicate covers the scheduler side so a speculative
-        next-step dispatch is only issued when it has a chance to land."""
-        if any(r.state == RequestState.PREFILL for r in self.running):
-            return False
-        return not (self.waiting and self.can_admit_head())
-
-    def decode_rows_stable(self, reqs) -> bool:
-        """Mixed-mode overlap contract: mixed steps COUNT AS decode steps
-        for the overlapped pipeline, so a speculative decode dispatch can
-        still land as the decode half of the next mixed step — provided
-        the decode-row set itself is stable. That holds iff no waiting
-        request is admissible right now and the DECODE-state set is
-        exactly `reqs` in order (a prefill piece completing its prompt
-        joins decode and changes the rows; the engine checks that
-        host-side via the pieces before calling)."""
-        if self.waiting and self.can_admit_head():
-            return False
-        decodable = [r for r in self.running if r.state == RequestState.DECODE]
-        return len(decodable) == len(reqs) and all(
-            a is b for a, b in zip(decodable, reqs)
+    def ends_within(self, req: Request, k: int) -> bool:
+        """Whether `req` is certain to finish within its next `k` sampled
+        tokens whatever they are: the token budget or the context runs
+        out (the engine's `_finish_reason_for` length legs). A sampled
+        stop is not certain, so it is not counted."""
+        s = req.sampling
+        return (
+            len(req.output_tokens) + req.num_emitted + k >= s.max_tokens
+            or req.num_tokens + k >= self.config.max_context
         )
+
+    def next_batch(
+        self, decode, k: int, prefill=()
+    ) -> Optional[ScheduledBatch]:
+        """The batch that comes next: what `schedule()` returns once the
+        dispatch now on the device (decode rows `decode`, `k` tokens
+        each; prompt pieces `prefill`) has been read back, as far as the
+        host can know it before reading what was sampled. Three things
+        change the batch and are known ahead: a row certain to end in
+        that dispatch (`ends_within`) leaves; a waiting request that is
+        admissible once those rows are gone is admitted NOW, its pages
+        taken from the free pool as it stands (the leavers' pages are
+        still being written), so its first piece is in the batch; a
+        piece that completes its prompt joins the decode rows, in
+        `running` order. Request-side events the host cannot know (a
+        sampled stop, an abort, a preemption) are caught when the engine
+        compares this batch with the scheduled one.
+
+        None where the batch cannot be known: rows leave and their
+        slots are not all taken now. Whoever takes one changes the
+        batch: a request the pool can pay for only with the leavers'
+        pages (`schedule()` admits it), or one that has not arrived yet
+        (a client that gets its last token sends its next prompt), whose
+        first chunk must not wait behind a dispatch built without it.
+
+        Reads only replicated scheduler state, so every process of a
+        multi-process mesh computes the same batch. Page growth of the
+        decode rows is the caller's (the engine pre-grows them)."""
+        gone = [r for r in decode if self.ends_within(r, k)]
+        computed = {}
+        for p in prefill:
+            computed[id(p.request)] = p.start + p.length
+            if p.start + p.length >= len(
+                p.request.prompt_tokens
+            ) and self.ends_within(p.request, 1):
+                gone.append(p.request)  # its first token is its last
+        self._admit(gone=len(gone))
+        if gone and len(self.running) - len(gone) < self.config.max_seqs:
+            return None
+        after = _After(gone, computed)
+        pieces = self._schedule_prefill(after)
+        rows = tuple(
+            r for r in self.running if after.state(r) == RequestState.DECODE
+        )[: self.config.decode_buckets[-1]]
+        return self._compose(pieces, rows)
+
+    def _compose(
+        self, prefill: Optional[ScheduledBatch], decode: tuple
+    ) -> Optional[ScheduledBatch]:
+        """One step from its halves: a `mixed` batch when both exist and
+        mixed steps are on, else prefill first (the XOR policy)."""
+        if prefill is not None and decode and self.mixed_enabled:
+            return ScheduledBatch(
+                kind="mixed", prefill=prefill.prefill, decode=decode
+            )
+        if prefill is not None:
+            return prefill
+        if decode:
+            return ScheduledBatch(kind="decode", decode=decode)
+        return None
 
     # -- the step ----------------------------------------------------------
 
     def schedule(self) -> Optional[ScheduledBatch]:
         self._admit()
         prefill = self._schedule_prefill()
-        if prefill is not None and self.mixed_enabled:
-            # Piggyback the decode batch onto the prefill dispatch: one
+        decode = None
+        if prefill is None or self.mixed_enabled:
+            # Beside a prefill chunk the decode batch piggybacks: one
             # `mixed` step instead of a decode-stalling prefill step.
             # _schedule_decode's side effects (page growth, preemption of
             # the youngest DECODE victim) apply exactly as they would on
             # the decode step the XOR policy runs after the backlog.
             decode = self._schedule_decode()
-            if decode is not None:
-                return ScheduledBatch(
-                    kind="mixed", prefill=prefill.prefill,
-                    decode=decode.decode,
-                )
-        if prefill is not None:
-            return prefill
-        return self._schedule_decode()
+        return self._compose(
+            prefill, decode.decode if decode is not None else ()
+        )
 
     def _watermark_pages(self) -> int:
         return int(self.allocator.num_pages * self.config.admission_watermark)
@@ -257,10 +321,17 @@ class Scheduler:
                  FinishReason.ERROR)
             )
 
-    def _admit(self) -> None:
+    def _admit(self, gone: int = 0) -> None:
+        """Admit from the head of the queue while slots and pages allow.
+        `gone` rows of `running` are certain to end in the dispatch on
+        the device (`next_batch`): their slots count as free, their
+        pages do not."""
         ps = self.config.page_size
         self._drop_expired_waiting()
-        while self.waiting and len(self.running) < self.config.max_seqs:
+        while (
+            self.waiting
+            and len(self.running) - gone < self.config.max_seqs
+        ):
             req = self.waiting[0]
             # A prompt that can never fit the pool (even with everything else
             # evicted) would block the queue head forever: doom it instead.
@@ -336,7 +407,7 @@ class Scheduler:
                     "queue_wait_ms", wait_ms, trace_id=req.trace_id
                 )
 
-    def _mixed_max_pieces(self) -> Optional[int]:
+    def _mixed_max_pieces(self, at: _After = _NOW) -> Optional[int]:
         """Piece-count cap for a step that will carry the decode batch:
         the engine samples mixed steps over one combined row space of
         BUCKETED halves (decode bucket + prefill-piece bucket), so the
@@ -350,7 +421,7 @@ class Scheduler:
         if not self.mixed_enabled:
             return None
         n_dec = sum(
-            1 for r in self.running if r.state == RequestState.DECODE
+            1 for r in self.running if at.state(r) == RequestState.DECODE
         )
         if not n_dec:
             return None
@@ -361,7 +432,7 @@ class Scheduler:
             b_pre *= 2
         return b_pre
 
-    def _prefill_step_budget(self) -> int:
+    def _prefill_step_budget(self, at: _After = _NOW) -> int:
         """Token budget for this prefill step. Adaptive policy: grow
         toward the whole un-prefilled backlog (capped) so a saturation
         burst drains in a few large dispatches — see EngineConfig
@@ -370,13 +441,13 @@ class Scheduler:
         if self.config.prefill_budget_policy != "adaptive":
             return base
         pending = sum(
-            len(r.prompt_tokens) - r.num_computed_tokens
+            len(r.prompt_tokens) - at.computed(r)
             for r in self.running
-            if r.state == RequestState.PREFILL
+            if at.state(r) == RequestState.PREFILL
         )
         cap = self.config.effective_prefill_budget_max
         budget = max(base, min(pending, cap))
-        max_pieces = self._mixed_max_pieces()
+        max_pieces = self._mixed_max_pieces(at)
         if max_pieces is not None:
             # A mixed step's combined row count must stay inside the
             # finite shape family: clamp the GROWN budget so it can never
@@ -387,21 +458,26 @@ class Scheduler:
             )
         return budget
 
-    def _schedule_prefill(self) -> Optional[ScheduledBatch]:
+    def _schedule_prefill(
+        self, at: _After = _NOW
+    ) -> Optional[ScheduledBatch]:
+        # `at` is the running set the pieces are cut from: as it is now,
+        # or as the dispatch on the device leaves it (next_batch).
         # Each piece is capped at prefill_chunk tokens; the step budget
         # spans sequences. The engine groups same-bucket pieces into one
         # batched [B, T] program, so packing many prompts here turns into
         # fewer, larger dispatches rather than serial B=1 launches.
-        budget = self._prefill_step_budget()
+        budget = self._prefill_step_budget(at)
         ps = self.config.page_size
-        max_pieces = self._mixed_max_pieces()
+        max_pieces = self._mixed_max_pieces(at)
         pieces: list[PrefillPiece] = []
         for req in self.running:
-            if req.state != RequestState.PREFILL or budget <= 0:
+            if at.state(req) != RequestState.PREFILL or budget <= 0:
                 continue
             if max_pieces is not None and len(pieces) >= max_pieces:
                 break  # mixed row-space cap (see _mixed_max_pieces)
-            remaining = len(req.prompt_tokens) - req.num_computed_tokens
+            start = at.computed(req)
+            remaining = len(req.prompt_tokens) - start
             take = min(remaining, self.config.prefill_chunk, budget)
             if take < remaining:
                 # Mid-prompt chunks end on page boundaries so every chunk
@@ -410,9 +486,7 @@ class Scheduler:
                 take = (take // ps) * ps
             if take <= 0:
                 continue
-            pieces.append(
-                PrefillPiece(request=req, start=req.num_computed_tokens, length=take)
-            )
+            pieces.append(PrefillPiece(request=req, start=start, length=take))
             budget -= take
         if not pieces:
             return None

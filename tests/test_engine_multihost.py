@@ -117,6 +117,36 @@ def test_multihost_pipeline_bit_exact_vs_single_host(
         assert mh.metrics.overlap_hits > 0, "overlap never engaged"
 
 
+def test_multihost_streams_bit_exact_across_an_admission(cpu_mesh_devices):
+    """More requests than slots, so a row that ends at its max_tokens
+    hands its slot on while a dispatch is in flight: the successor is
+    admitted ahead and its first piece rides the mixed step launched
+    ahead, its token fed on device (replicated ids through the gather).
+    Every process decides that from replicated scheduler state alone:
+    the forced multi-host streams equal the single-host ones and the
+    synchronous reference's."""
+    queued = dict(max_seqs=2, decode_buckets=(1, 2), decode_steps=1)
+    reqs = [
+        (rid, prompt, SamplingParams(
+            temperature=s.temperature, top_p=s.top_p, seed=s.seed,
+            max_tokens=s.max_tokens, ignore_eos=True))
+        for rid, prompt, s in _workload()[:5]
+    ]
+    ref_sync = _run(
+        _make(topology="tp=2,dp=2", overlap_decode=False,
+              mixed_steps=False, **queued),
+        reqs,
+    )
+    ref_host = _run(_make(topology="tp=2,dp=2", **queued), reqs)
+    mh = _make(topology="tp=2,dp=2", force_multihost=True, **queued)
+    got = _run(mh, reqs)
+    assert got == ref_host
+    assert got == ref_sync
+    m = mh.metrics
+    assert m.mixed_dispatches >= 3 and m.overlap_rollbacks == 0
+    assert m.overlap_hits >= m.decode_dispatches + m.mixed_dispatches - 2
+
+
 def test_multihost_mesh_report_carries_logical_groups(cpu_mesh_devices):
     """/v1/debug/mesh under the forced multi-host mesh: multiprocess
     flag set, non-replicated logical param groups, rule provenance."""

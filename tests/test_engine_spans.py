@@ -200,12 +200,15 @@ def test_rollback_is_a_zero_length_span_with_its_reason(tmp_path):
     run(eng)
     cap = Capture(tmp_path, eng.metrics)
     with cap:
-        # an admission in the middle of a wave changes the batch under
-        # the speculated next dispatch
+        # an abort in the middle of a wave changes the batch under the
+        # dispatch launched ahead (an admission no longer does: the
+        # loop launches the batch that comes next, ISSUE 28)
         eng.add_request("a2", *WORK[0][1:])
+        eng.add_request("b2", *WORK[1][1:])
         for _ in range(4):
             eng.step()
-        run(eng, [("b2", *WORK[1][1:])])
+        assert eng.abort_request("b2")
+        run(eng, [("c2", *WORK[2][1:])])
     rollbacks = [e for e in cap.spans if e["name"] == "engine.rollback"]
     assert cap.delta["overlap_rollbacks"] >= 1
     assert len(rollbacks) == cap.delta["overlap_rollbacks"]
@@ -214,6 +217,36 @@ def test_rollback_is_a_zero_length_span_with_its_reason(tmp_path):
     # a capture with prompts in it: prefill launches say so
     kinds = {e["kind"] for e in cap.spans if e["name"] == "engine.launch"}
     assert "prefill" in kinds or "mixed" in kinds
+
+
+def test_launches_about_an_admission_are_marked_speculative(tmp_path):
+    """More requests than slots, max_tokens ends: inside the capture
+    every decode-carrying launch is made ahead of its batch while a
+    request waits, the mixed steps that admit a successor among them
+    (`speculative=1`), and their readbacks lag."""
+    cfg = dict(overlap_decode=True, decode_steps=1, max_seqs=2,
+               decode_buckets=(1, 2))
+    work = [(f"s{i}", [3 + i, 5, 8, 13], SamplingParams(
+        max_tokens=4 + (i % 3), ignore_eos=True)) for i in range(6)]
+    eng = make_engine(**cfg)
+    run(eng, work)  # warm every program
+    for rid, prompt, s in work:
+        eng.add_request(rid + "b", list(prompt), s)
+    eng.step()  # the two prompts that find a slot
+    with Capture(tmp_path, eng.metrics) as cap:
+        eng.run_to_completion()
+    launches = [e for e in cap.spans if e["name"] == "engine.launch"]
+    mixed = [e for e in launches if e["kind"] == "mixed"]
+    assert len(mixed) >= 3 and all(e["speculative"] == 1 for e in mixed)
+    # not ahead: the first, and the one after the row that ends with
+    # nobody left waiting for its slot
+    assert [e["speculative"] for e in launches].count(0) <= 2
+    assert cap.delta["overlap_rollbacks"] == 0
+    assert cap.delta["overlap_hits"] >= (
+        cap.delta["decode_dispatches"] + cap.delta["mixed_dispatches"] - 2)
+    lagged = [e["lagged"] for e in cap.spans
+              if e["name"] == "engine.readback"]
+    assert sum(lagged) == cap.delta["overlap_hits"]
 
 
 def test_first_call_of_a_program_is_a_compile_span_inside_its_launch(

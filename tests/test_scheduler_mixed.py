@@ -185,3 +185,216 @@ def test_mixed_piece_cap_keeps_combined_rows_in_family():
     assert batch is not None and batch.kind == "mixed"
     assert len(batch.prefill) <= 2  # 4 (bucket cap) - 2 decodables
     assert len(batch.prefill) + len(batch.decode) <= cfg.decode_buckets[-1]
+
+
+# -- the batch that comes next (Scheduler.next_batch, ISSUE 28) -----------
+
+
+def _sched(**over):
+    cfg = EngineConfig(**{**_cfg(True).__dict__, **over})
+    alloc = PageAllocator(cfg.num_pages, cfg.page_size)
+    return Scheduler(cfg, alloc), alloc
+
+
+def _req(rid: str, plen: int, max_tokens: int) -> Request:
+    return Request(
+        request_id=rid, prompt_tokens=list(range(1, plen + 1)),
+        sampling=SamplingParams(max_tokens=max_tokens),
+    )
+
+
+def _apply(batch, s: Scheduler) -> None:
+    """What the engine does with a batch once its ids are read: pieces
+    computed (a completed prompt samples its first token), one token a
+    decode row, a request at its budget finished."""
+    def emit(req):
+        req.output_tokens.append(7)
+        if len(req.output_tokens) + req.num_emitted >= req.sampling.max_tokens:
+            s.finish(req)
+
+    for piece in batch.prefill:
+        req = piece.request
+        req.num_computed_tokens += piece.length
+        if req.prefill_done:
+            req.state = RequestState.DECODE
+            emit(req)
+    for req in batch.decode:
+        req.num_computed_tokens += 1
+        emit(req)
+
+
+def _same(a, b) -> bool:
+    return (
+        a is not None and b is not None and a.kind == b.kind
+        and len(a.decode) == len(b.decode)
+        and all(x is y for x, y in zip(a.decode, b.decode))
+        and [(p.request.request_id, p.start, p.length) for p in a.prefill]
+        == [(p.request.request_id, p.start, p.length) for p in b.prefill]
+    )
+
+
+def test_next_batch_admits_the_successor_beside_the_leavers_pages():
+    """The early admission's slot and page arithmetic: the row certain
+    to end counts as gone for the slot, its pages do not count as free;
+    the successor's pages come from the pool as it stands and the batch
+    is the mixed step `schedule()` then returns."""
+    s, alloc = _sched(max_seqs=2, num_pages=16)
+    short, long_, nxt = _req("short", 6, 3), _req("long", 6, 9), _req("n", 5, 4)
+    for r in (short, long_, nxt):
+        s.add_request(r)
+    _apply(s.schedule(), s)  # both prompts; "n" waits for a slot
+    _apply(s.schedule(), s)  # short has 2 of its 3 tokens
+    batch = s.schedule()
+    assert batch.kind == "decode" and s.waiting == [nxt]
+    free0, held = alloc.num_free, list(short.pages)
+    assert s.ends_within(short, 1) and not s.ends_within(long_, 1)
+    ahead = s.next_batch(batch.decode, 1)
+    # admitted now: one row more than the slots, briefly; its two pages
+    # (5 + 1 tokens) came from the free pool, short's are still its own
+    assert s.waiting == [] and s.running == [short, long_, nxt]
+    assert nxt.state == RequestState.PREFILL and len(nxt.pages) == 2
+    assert alloc.num_free == free0 - 2 and short.pages == held
+    assert ahead.kind == "mixed" and ahead.decode == (long_,)
+    assert [(p.request, p.start, p.length) for p in ahead.prefill] == [
+        (nxt, 0, 5)
+    ]
+    _apply(batch, s)  # short ends, its pages return
+    assert s.running == [long_, nxt]
+    assert _same(s.schedule(), ahead)
+    # the piece completes the prompt: "n" joins at its place in running
+    joined = s.next_batch(ahead.decode, 1, ahead.prefill)
+    assert joined.kind == "decode" and joined.decode == (long_, nxt)
+    _apply(ahead, s)
+    assert _same(s.schedule(), joined)
+
+
+def test_next_batch_unknown_where_the_pool_needs_the_leavers_pages():
+    """No early admission the pool cannot pay without the pages that
+    are still being written: next_batch says it cannot know the batch,
+    admits nobody, and schedule() admits at its old place."""
+    s, alloc = _sched(max_seqs=2, num_pages=6)  # 5 usable pages
+    short, long_, nxt = _req("short", 6, 3), _req("long", 6, 9), _req("n", 5, 4)
+    for r in (short, long_, nxt):
+        s.add_request(r)
+    for _ in range(2):
+        _apply(s.schedule(), s)
+    batch = s.schedule()
+    assert alloc.num_free < 2  # "n" needs 2 pages
+    assert s.next_batch(batch.decode, 1) is None
+    assert s.waiting == [nxt] and s.running == [short, long_]
+    _apply(batch, s)
+    after = s.schedule()
+    assert after.kind == "mixed" and after.prefill[0].request is nxt
+
+
+def test_next_batch_unknown_where_a_freed_slot_stays_empty():
+    """A row ends and nobody waits for its slot: whoever arrives next
+    takes it at the next schedule(), so no batch is named (the engine
+    launches nothing behind that dispatch, and the arrival's first
+    chunk does not wait behind one). With the slots still full, or
+    nothing ending, the batch is named."""
+    s, _ = _sched(max_seqs=2, num_pages=16)
+    short, long_ = _req("short", 6, 3), _req("long", 6, 9)
+    for r in (short, long_):
+        s.add_request(r)
+    _apply(s.schedule(), s)
+    batch = s.schedule()
+    same = s.next_batch(batch.decode, 1)  # nothing ends in this one
+    assert same.kind == "decode" and same.decode == (short, long_)
+    _apply(batch, s)
+    batch = s.schedule()
+    assert s.ends_within(short, 1)
+    assert s.next_batch(batch.decode, 1) is None
+    _apply(batch, s)
+    assert s.schedule().decode == (long_,)
+
+
+def test_next_batch_first_token_is_the_last():
+    """A prompt whose first token is its whole budget never joins the
+    decode rows, and frees its slot for the request behind it."""
+    s, _ = _sched(max_seqs=2, num_pages=16)
+    a, one, nxt = _req("a", 4, 9), _req("one", 4, 1), _req("n", 4, 4)
+    s.add_request(a)
+    _apply(s.schedule(), s)
+    s.add_request(one)
+    s.add_request(nxt)
+    batch = s.schedule()
+    assert batch.kind == "mixed" and batch.prefill[0].request is one
+    ahead = s.next_batch(batch.decode, 1, batch.prefill)
+    assert ahead.decode == (a,) and ahead.prefill[0].request is nxt
+    _apply(batch, s)
+    assert _same(s.schedule(), ahead)
+
+
+def _simulate_ahead(seed: int, steps: int = 400):
+    """The scheduler driven as the overlapped engine drives it: after
+    every batch, the batch that comes next is asked for BEFORE the
+    batch's effects are applied. Returns (compared, agreed, emissions,
+    budgets)."""
+    s, alloc = _sched(num_pages=40)
+    usable = alloc.num_free
+    rng = np.random.default_rng(seed)
+    emissions: dict[str, list[int]] = {}
+    budgets: dict[str, int] = {}
+    arrivals = compared = agreed = 0
+    ahead = None
+    for _ in range(steps):
+        arrived = False
+        if arrivals < 40 and rng.random() < 0.35:
+            r = _req(f"r{arrivals}", int(rng.integers(1, 20)),
+                     int(rng.integers(1, 12)))
+            s.add_request(r)
+            budgets[r.request_id] = r.sampling.max_tokens
+            arrivals += 1
+            arrived = True
+        pre0 = s.preemptions
+        roomy = alloc.num_free >= len(s.running)  # no row stalls for a page
+        batch = s.schedule()
+        assert not s.doomed
+        assert len(s.running) <= s.config.max_seqs
+        _check_page_accounting(s, alloc, usable)
+        if ahead is not None and not arrived and roomy and (
+            s.preemptions == pre0
+        ):
+            compared += 1
+            agreed += _same(batch, ahead)
+            assert _same(batch, ahead), (seed, batch, ahead)
+        ahead = None
+        if batch is None:
+            if arrivals >= 40 and not s.has_work:
+                break
+            continue
+        if batch.decode:
+            ahead = s.next_batch(batch.decode, 1, batch.prefill)
+            _check_page_accounting(s, alloc, usable)
+        for piece in batch.prefill:
+            req = piece.request
+            assert piece.start == req.num_computed_tokens
+            req.num_computed_tokens += piece.length
+            if req.prefill_done:
+                req.state = RequestState.DECODE
+        for req in [p.request for p in batch.prefill] + list(batch.decode):
+            if req.state != RequestState.DECODE:
+                continue
+            if req in batch.decode:
+                req.num_computed_tokens += 1
+            idx = req.num_emitted + len(req.output_tokens)
+            req.output_tokens.append(idx)
+            emissions.setdefault(req.request_id, []).append(idx)
+            if idx + 1 >= req.sampling.max_tokens:
+                s.finish(req)
+    assert not s.has_work and alloc.num_free == usable
+    return compared, agreed, emissions, budgets
+
+
+@pytest.mark.parametrize("seed", [3, 11, 29, 47])
+def test_next_batch_is_the_batch_schedule_returns(seed):
+    """The property the engine's dispatch ahead rests on: with every end
+    a max_tokens end, no arrival in between and room to grow, the batch
+    next_batch names is the batch schedule() then returns: same kind,
+    same request objects in the same rows, same pieces. Early admission
+    leaks no page and never leaves more rows than slots at a schedule."""
+    compared, agreed, emissions, budgets = _simulate_ahead(seed)
+    assert compared > 20 and agreed == compared
+    for rid, toks in emissions.items():
+        assert toks == list(range(budgets[rid])), rid
