@@ -276,167 +276,30 @@ def _spec_ab(
     }
 
 
-def _kstep_ab(
-    model: str = "tiny", pairs: int = 3, num_requests: int = 8,
-    osl: int = 64, kstep: int = 8,
-) -> dict:
-    """On-device K-step decode window A/B (ISSUE 16): the decode-bound
-    workload (tiny prompts, long outputs) with the fused decode_kstep
-    window on (K=kstep) vs classic per-token stepping (K=1). BOTH arms
-    run in ONE warm engine — `eng._decode_kstep` toggles the live window
-    target, the engine stays built with decode_kstep=kstep so the policy
-    gate is open — and the arms interleave per pair so box-load drift
-    cancels. overlap_decode is off in both arms (the CPU backend
-    serializes the speculative dispatch, which would bill the K=1 arm
-    for pipelining the chip gets free) and decode_steps is pinned to 1
-    so the K=1 arm is the true host-per-token loop docs/PERF.md prices.
-
-    The ASSERTED number is the deterministic dispatch-level model:
-    modeled_ms_per_token_ratio =
-    (ms/dispatch K=1 / tokens/dispatch K=1) /
-    (ms/dispatch K / tokens/dispatch K), medians over pairs — the K arm
-    lands ~K tokens per host visit at well under K x the dispatch cost,
-    so the ratio is the host-loop tax the window removes. Wall tok/s
-    rides along unasserted."""
-    import gc
-
-    import numpy as np
-
-    from dynamo_tpu.engine import EngineConfig
-    from dynamo_tpu.engine.engine import JaxEngine
-    from dynamo_tpu.engine.request import SamplingParams
-
-    base = EngineConfig.for_tests() if model == "tiny" else None
-    over = {
-        "model": model,
-        "decode_kstep": kstep,
-        "num_pages": max(256, num_requests * 8),
-        "page_size": 16,
-        "max_pages_per_seq": 16,
-        "prefill_chunk": 64,
-        "decode_buckets": (1, 2, 4, 8),
-        "max_seqs": max(8, num_requests),
-        "decode_steps": 1,
-        "overlap_decode": False,
-        "enable_prefix_caching": False,
-    }
-    if base is not None:
-        cfg = EngineConfig(**{**base.__dict__, **over})
-    else:
-        cfg = EngineConfig(**over)
-    eng = JaxEngine(cfg)
-    rng = np.random.default_rng(0)
-
-    def drive(tag: str) -> dict:
-        m = eng.metrics
-        keys = (
-            "time_decode_ms", "decode_dispatches", "generated_tokens",
-            "kstep_windows", "kstep_steps",
-        )
-        before = {k: getattr(m, k) for k in keys}
-        t0 = time.perf_counter()
-        for i in range(num_requests):
-            eng.add_request(
-                f"{tag}{i}",
-                [int(x) for x in rng.integers(1, 200, 12)],
-                SamplingParams(temperature=0.0, max_tokens=osl),
-            )
-        gen = 0
-        while eng.has_work:
-            for out in eng.step():
-                gen += len(out.new_token_ids)
-        elapsed = time.perf_counter() - t0
-        eng.drain_overlap()
-        d = {k: getattr(m, k) - v for k, v in before.items()}
-        disp = max(1, d["decode_dispatches"])
-        return {
-            "tok_s": round(gen / elapsed, 1),
-            "ms_per_dispatch": round(d["time_decode_ms"] / disp, 4),
-            "tok_per_dispatch": round(d["generated_tokens"] / disp, 3),
-            "decode_dispatches": d["decode_dispatches"],
-            "kstep_windows": d["kstep_windows"],
-            "kstep_steps": d["kstep_steps"],
-        }
-
-    # warm both arms (compiles + caches)
-    eng._decode_kstep = kstep
-    drive("warm_on")
-    eng._decode_kstep = 1
-    drive("warm_off")
-    on_runs, off_runs = [], []
-    for p in range(pairs):
-        eng._decode_kstep = kstep
-        on_runs.append(drive(f"on{p}"))
-        eng._decode_kstep = 1
-        off_runs.append(drive(f"off{p}"))
-    del eng
-    gc.collect()
-
-    import statistics
-
-    def med(runs, k):
-        return statistics.median(r[k] for r in runs)
-
-    ms_on, ms_off = med(on_runs, "ms_per_dispatch"), med(
-        off_runs, "ms_per_dispatch"
-    )
-    tpd_on, tpd_off = med(on_runs, "tok_per_dispatch"), med(
-        off_runs, "tok_per_dispatch"
-    )
-    modeled = (
-        (ms_off / tpd_off) / (ms_on / tpd_on)
-        if tpd_off and tpd_on and ms_on
-        else None
-    )
-    return {
-        "model": model,
-        "kstep": kstep,
-        "batch": num_requests,
-        "pairs": pairs,
-        "kstep_on": {
-            "tok_s": med(on_runs, "tok_s"),
-            "ms_per_dispatch": ms_on,
-            "tok_per_dispatch": tpd_on,
-            "windows": med(on_runs, "kstep_windows"),
-            "steps": med(on_runs, "kstep_steps"),
-        },
-        "kstep_off": {
-            "tok_s": med(off_runs, "tok_s"),
-            "ms_per_dispatch": ms_off,
-            "tok_per_dispatch": tpd_off,
-        },
-        "wall_tok_s_ratio": round(
-            med(on_runs, "tok_s") / max(1e-9, med(off_runs, "tok_s")), 3
-        ),
-        "modeled_ms_per_token_ratio": (
-            round(modeled, 3) if modeled is not None else None
-        ),
-    }
-
-
 def _multihost_pipeline_ab(
     model: str = "tiny", pairs: int = 3, num_requests: int = 8,
-    osl: int = 64, kstep: int = 8, topology: str = "tp=2,dp=2",
+    osl: int = 64, decode_steps: int = 8, topology: str = "tp=2,dp=2",
 ) -> dict:
     """The fast decode pipeline carried across hosts (ISSUE 20): under a
     FORCED multi-host mesh (EngineConfig.force_multihost over the CPU
     device grid — the engine takes the multi-controller code paths
-    without a fabric), the K-step pipeline ON vs the old multi-host
-    behavior (the pre-lift auto-off: synchronous per-token stepping).
-    ONE warm engine; the arms toggle `eng._decode_kstep` live and
-    interleave per pair so box-load drift cancels. Like _kstep_ab, the
-    TIMED arms keep overlap off (the CPU backend serializes the
-    speculative dispatch, billing the ON arm for pipelining the chip
-    gets free); a separate UN-timed probe drive then runs with overlap
-    re-enabled and reports its engagement (`overlap_probe`) — proof the
-    multi-host overlap path works, without letting its CPU artifact
-    pollute the model.
+    without a fabric), the fused decode scan (`decode_steps` tokens a
+    dispatch) ON vs the old multi-host behavior (the pre-lift auto-off:
+    synchronous per-token stepping). ONE warm engine; the arms swap the
+    engine's `decode_steps` live and interleave per pair so box-load
+    drift cancels. The TIMED arms keep overlap off (the CPU backend
+    serializes the dispatch launched ahead, billing the ON arm for
+    pipelining the chip gets free); a separate UN-timed probe drive then
+    runs with overlap re-enabled and reports its engagement
+    (`overlap_probe`) — proof the multi-host overlap path works, without
+    letting its CPU artifact pollute the model.
 
-    The ASSERTED number is the deterministic dispatch-level model (same
-    construction as _kstep_ab): modeled_ms_per_token_ratio =
+    The ASSERTED number is the deterministic dispatch-level model:
+    modeled_ms_per_token_ratio =
     (ms/dispatch / tok/dispatch, pipeline off) / (same, pipeline on) —
-    the per-window host sync the lift removes from every replica's
+    the per-token host sync the lift removes from every replica's
     lockstep loop. Wall tok/s rides along unasserted."""
+    import dataclasses
     import gc
 
     import numpy as np
@@ -450,7 +313,6 @@ def _multihost_pipeline_ab(
         "model": model,
         "topology": topology,
         "force_multihost": True,
-        "decode_kstep": kstep,
         "num_pages": max(256, num_requests * 8),
         "page_size": 16,
         "max_pages_per_seq": 16,
@@ -473,7 +335,7 @@ def _multihost_pipeline_ab(
         m = eng.metrics
         keys = (
             "time_decode_ms", "decode_dispatches", "generated_tokens",
-            "kstep_windows", "overlap_hits",
+            "overlap_hits",
         )
         before = {k: getattr(m, k) for k in keys}
         t0 = time.perf_counter()
@@ -496,23 +358,25 @@ def _multihost_pipeline_ab(
             "ms_per_dispatch": round(d["time_decode_ms"] / disp, 4),
             "tok_per_dispatch": round(d["generated_tokens"] / disp, 3),
             "decode_dispatches": d["decode_dispatches"],
-            "kstep_windows": d["kstep_windows"],
             "overlap_hits": d["overlap_hits"],
         }
 
-    eng._decode_kstep = kstep
+    def fuse(k: int) -> None:
+        eng.config = dataclasses.replace(eng.config, decode_steps=k)
+
+    fuse(decode_steps)
     drive("warm_on")
-    eng._decode_kstep = 1
+    fuse(1)
     drive("warm_off")
     on_runs, off_runs = [], []
     for p in range(pairs):
-        eng._decode_kstep = kstep
+        fuse(decode_steps)
         on_runs.append(drive(f"on{p}"))
-        eng._decode_kstep = 1
+        fuse(1)
         off_runs.append(drive(f"off{p}"))
     # un-timed probe: the overlap path itself, live on the forced
     # multi-host mesh (its timing is a CPU serialization artifact)
-    eng._decode_kstep = kstep
+    fuse(decode_steps)
     eng._overlap_enabled = True
     probe = drive("probe")
     del eng
@@ -537,24 +401,20 @@ def _multihost_pipeline_ab(
     return {
         "model": model,
         "topology": topology,
-        "kstep": kstep,
+        "decode_steps": decode_steps,
         "batch": num_requests,
         "pairs": pairs,
         "pipeline_on": {
             "tok_s": med(on_runs, "tok_s"),
             "ms_per_dispatch": ms_on,
             "tok_per_dispatch": tpd_on,
-            "kstep_windows": med(on_runs, "kstep_windows"),
         },
         "pipeline_off": {
             "tok_s": med(off_runs, "tok_s"),
             "ms_per_dispatch": ms_off,
             "tok_per_dispatch": tpd_off,
         },
-        "overlap_probe": {
-            "overlap_hits": probe["overlap_hits"],
-            "kstep_windows": probe["kstep_windows"],
-        },
+        "overlap_probe": {"overlap_hits": probe["overlap_hits"]},
         "wall_tok_s_ratio": round(
             med(on_runs, "tok_s") / max(1e-9, med(off_runs, "tok_s")), 3
         ),
@@ -1192,8 +1052,8 @@ def _slo_overhead_ab(pairs: int = 3, osl: int = 32, n_req: int = 8) -> dict:
     on_med = statistics.median(rates["on"])
     off_med = statistics.median(rates["off"])
     modeled = measured = None
-    # the engine observes once per EMISSION (a fused K-step emission
-    # spreads one observe over its K tokens): price the MEASURED call
+    # the engine observes once per EMISSION (a fused dispatch's delivery
+    # spreads one observe over its tokens): price the MEASURED call
     # pattern, not a one-observe-per-token worst case
     obs_per_token = on_observes / on_tokens if on_tokens else 1.0
     fin_per_token = on_finishes / on_tokens if on_tokens else 1.0 / osl
@@ -2385,27 +2245,6 @@ def main() -> None:
             # the headline artifact
             spec_ab = {"error": f"{type(e).__name__}: {e}"}
 
-    # On-device K-step decode window A/B (ISSUE 16): ms/token with the
-    # fused decode_kstep window (K tokens per host visit) vs the classic
-    # per-token loop. Runs by default in CPU mode (tiny); BENCH_KSTEP
-    # sets K and forces it on TPU with the headline model.
-    kstep_ab = None
-    default_kstep = "8" if platform != "tpu" else "0"
-    kstep_k = int(os.environ.get("BENCH_KSTEP", default_kstep))
-    if kstep_k > 1:
-        try:
-            kstep_ab = _kstep_ab(
-                model=os.environ.get(
-                    "BENCH_KSTEP_MODEL",
-                    "tiny" if platform != "tpu" else model,
-                ),
-                pairs=int(os.environ.get("BENCH_KSTEP_PAIRS", "3")),
-                kstep=kstep_k,
-            )
-        except Exception as e:  # noqa: BLE001 — A/B failure must not kill
-            # the headline artifact
-            kstep_ab = {"error": f"{type(e).__name__}: {e}"}
-
     # Multi-host pipeline A/B (ISSUE 20): the decode pipeline carried
     # across hosts vs the old multi-host auto-off, under the forced
     # multi-host CPU mesh. Runs by default in CPU mode (tiny);
@@ -2601,7 +2440,6 @@ def main() -> None:
                 **({"overlap_ab": overlap_ab} if overlap_ab else {}),
                 **({"mixed_ab": mixed_ab} if mixed_ab else {}),
                 **({"spec_ab": spec_ab} if spec_ab else {}),
-                **({"kstep_ab": kstep_ab} if kstep_ab else {}),
                 **(
                     {"multihost_pipeline_ab": multihost_ab}
                     if multihost_ab

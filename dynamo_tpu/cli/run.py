@@ -76,7 +76,6 @@ def _engine_config(args, eos_token_ids: tuple = ()) -> EngineConfig:
             if getattr(args, "decode_steps", None) is not None
             else {}
         ),
-        decode_kstep=getattr(args, "decode_kstep", 1),
     )
 
 
@@ -874,16 +873,6 @@ def build_parser() -> argparse.ArgumentParser:
         "--decode-steps", type=int, default=None, dest="decode_steps",
         help="decode steps fused per dispatch (host sync per K tokens/seq)."
              " Default: engine default (8)",
-    )
-    runp.add_argument(
-        "--decode-kstep", type=int, default=1, dest="decode_kstep",
-        help="fuse K decode iterations into ONE on-device program per "
-             "dispatch: sampling, stop checks, and paged-KV writes run "
-             "on device, the host syncs once per K tokens (vLLM's "
-             "--num-scheduler-steps analogue). 1 (default) = classic "
-             "per-step loop, bit-identical streams; K>1 stays bit-exact "
-             "(including on multi-host SPMD meshes) and auto-disables "
-             "under speculation and logprobs rows",
     )
     runp.add_argument(
         "--host-kv-bytes", type=int, default=0, dest="host_kv_bytes",

@@ -59,8 +59,6 @@ def output_to_dict(out: StepOutput) -> dict:
         d["mixed"] = True
     if out.spec:
         d["spec"] = True
-    if out.kstep:
-        d["kstep"] = True
     # tracing enrichment (traced requests only — these keys are absent
     # from the wire when tracing is off, keeping it bit-identical):
     # measured queue wait / prefill-induced stall for the engine span
@@ -153,10 +151,6 @@ class AsyncEngineRunner:
             queue_wait_budget_s=cfg.stall_queue_wait_s,
             hard_deadline_s=cfg.stall_hard_deadline_s,
             on_wedged=self._wedge_request,
-            # K-step windows emit once per K tokens: the live window
-            # size floors the stall threshold so a healthy K-window is
-            # not misread as a stalled stream (decode_kstep bugfix)
-            window_steps=lambda: getattr(eng, "_kstep_live", 1),
         )
         self.watchdog.start()
         try:
@@ -421,7 +415,6 @@ class AsyncEngineRunner:
             generated = 0
             mixed_seen = False
             spec_seen = False
-            kstep_seen = False
             async for item in self.drain(context, request.request_id, q):
                 if generated == 0:
                     sp.add_event("first_token")
@@ -433,10 +426,6 @@ class AsyncEngineRunner:
                     # at least one token rode a speculative verify step
                     spec_seen = True
                     sp.set_attr("spec", True)
-                if not kstep_seen and item.get("kstep"):
-                    # at least one token rode an on-device K-step window
-                    kstep_seen = True
-                    sp.set_attr("kstep", True)
                 qw = item.get("queue_wait_ms")
                 if qw is not None:
                     # measured admission wait (timeline breakdown input)

@@ -50,29 +50,6 @@ class EngineConfig:
     #: Finish conditions are applied on the host afterwards — up to K-1 speculative
     #: tokens past a stop are computed and dropped. 1 = classic stepping.
     decode_steps: int = 8
-    #: on-device K-step decode windows (ROADMAP item 2a, the host-loop
-    #: elimination lever): run K decode iterations inside ONE XLA program
-    #: with per-iteration on-device sampling, on-device stop-condition
-    #: masks (eos/stop-token/max_tokens freeze finished rows mid-window;
-    #: frozen rows waste only masked lanes), and on-device paged KV
-    #: writes + position advances — the host reads back [K, B] ids plus
-    #: per-row emitted counts once per window instead of deciding every
-    #: step. Differs from decode_steps (decode_multi) in that finish
-    #: conditions are evaluated ON DEVICE, so no overshoot tokens are
-    #: computed past a stop, and the scheduler reserves the whole
-    #: window's page runway up front (or clamps the window). Composes
-    #: with overlap_decode (the next window chains speculatively off
-    #: device outputs) and mixed_steps (the window runs as the decode
-    #: leg beside the prefill chunk). Auto-disabled, with a logged
-    #: reason, for spec_ngram/spec_draft (they already batch steps),
-    #: logprobs rows, and oversized stop sets. Runs on multi-process
-    #: SPMD meshes too: window outcomes are replicated on-device, so
-    #: every lockstep host reads back identical [K, B] ids and emit
-    #: counts. 1 (default) = off: the classic path, bit-identical.
-    #: Token streams at K>1 are bit-exact vs K=1 (greedy AND sampled —
-    #: pinned by tests/test_engine_kstep.py). `--decode-kstep` on the
-    #: CLI (vLLM `--num-scheduler-steps` analogue, docs/migrating.md).
-    decode_kstep: int = 1
     #: overlapped decode loop: after dispatching step N, dispatch step
     #: N+1 ahead (the batch the scheduler will return next: a row at its
     #: token budget gone, its successor admitted, a finished prompt
@@ -285,12 +262,6 @@ class EngineConfig:
             raise ValueError(
                 f"spec_draft_tokens must be >= 1, got "
                 f"{self.spec_draft_tokens}"
-            )
-        if self.decode_kstep < 1:
-            raise ValueError(
-                f"decode_kstep must be >= 1, got {self.decode_kstep} "
-                "(1 = classic stepping; K>1 fuses K on-device iterations "
-                "per dispatch)"
             )
         if self.prefill_budget_policy not in ("fixed", "adaptive"):
             raise ValueError(

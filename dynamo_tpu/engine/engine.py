@@ -195,20 +195,6 @@ class EngineMetrics:
     overlap_dispatches: int = 0
     overlap_hits: int = 0
     overlap_rollbacks: int = 0
-    #: on-device K-step decode windows (EngineConfig.decode_kstep):
-    #: windows dispatched (speculatively-chained ones included), device
-    #: iterations run inside them (steps/windows = the average fused K),
-    #: the most recent window's size (gauge), and dispatches where a
-    #: configured K>1 window fell back to the classic path (logprobs
-    #: rows or an oversized stop set in the batch)
-    kstep_windows: int = 0
-    kstep_steps: int = 0
-    kstep_window_size: int = 0
-    kstep_fallbacks: int = 0
-    #: cumulative wall ms of K-step window dispatch+sync — with
-    #: kstep_windows it is the decode_kstep program family's measured
-    #: ms/dispatch column in /v1/debug/programs attainment
-    time_kstep_ms: float = 0.0
     #: engine-internals plane (fleet telemetry, docs/observability.md):
     #: jit-cache misses (one full XLA compile each) and their cumulative
     #: wall cost — climbing in steady state means the program family is
@@ -269,7 +255,6 @@ class EngineMetrics:
         "time_stage_ms", "time_intake_ms", "time_emit_ms",
         "prefill_dispatches", "decode_dispatches", "mixed_dispatches",
         "overlap_dispatches", "overlap_hits", "overlap_rollbacks",
-        "kstep_windows", "kstep_steps", "time_kstep_ms",
     )
 
     def to_dict(self) -> dict:
@@ -343,11 +328,6 @@ class _Launched:
     #: prompt (`psamp`) piece j was sampled at row b_bucket + j
     pieces: tuple = ()
     psamp: bool = False
-    #: dispatched through the decode_kstep program family (on-device
-    #: stop masks): the device's emitted counts, and the window's start
-    kstep: bool = False
-    n_emit: object = None
-    t0: float = 0.0
     #: launched ahead: per decode row the (num_tokens, len(output_tokens))
     #: the batch must show when this dispatch is consumed
     expected: Optional[tuple] = None
@@ -602,34 +582,6 @@ class JaxEngine:
             config.mixed_steps and config.spec_ngram <= 0
         )
         self.scheduler.mixed_enabled = self._mixed_enabled
-        #: on-device K-step decode windows (config.decode_kstep): same
-        #: policy surface as overlap/mixed — off under BOTH speculation
-        #: modes (they already batch steps per dispatch); stays ON for
-        #: multi-process meshes (the scan keeps feedback, stop checks,
-        #: and page-table state on-device; the [K, B] readback is
-        #: replicated). _decode_kstep is the live window target (bench
-        #: A/B toggles it on a warm engine); per-dispatch eligibility
-        #: (logprobs rows, stop-set size, page runway) is decided in
-        #: _pick_kstep.
-        self._decode_kstep = config.decode_kstep
-        self._kstep_enabled = (
-            config.decode_kstep > 1
-            and config.spec_ngram <= 0
-            and not self._spec_draft
-        )
-        if config.decode_kstep > 1 and not self._kstep_enabled:
-            logger.info(
-                "decode_kstep=%d auto-disabled: speculative decoding "
-                "already batches steps per dispatch",
-                config.decode_kstep,
-            )
-        #: live K-step window state: the last dispatched window size
-        #: (the stall watchdog floors its threshold at a multiple of it)
-        #: and the device-measured per-step ms of that window (spreads
-        #: window emissions in the decode-stall histogram so a healthy
-        #: K-wide gap is not booked as a prefill stall)
-        self._kstep_live = 1
-        self._kstep_step_ms = 0.0
         #: per-request last token-emission mark for the decode-stall
         #: histogram: request_id -> (perf_counter at emission, prefill+
         #: mixed dispatch count at emission). A later emission whose
@@ -1334,125 +1286,6 @@ class JaxEngine:
                 req.pages.extend(got)
         return True
 
-    # -- on-device K-step decode windows (config.decode_kstep) -------------
-
-    def _kstep_stop_ids(self, req: Request) -> Optional[tuple[int, ...]]:
-        """This request's device-side stop set (eos ∪ stop_token_ids; an
-        ignore_eos request stops on NOTHING — `_finish_reason_for`
-        ignores both sets for it), or None when it exceeds the static
-        STOP_SLOTS packing and the window must fall back to the
-        host-side finish scan."""
-        from dynamo_tpu.engine.sampling import STOP_SLOTS
-
-        s = req.sampling
-        if s.ignore_eos:
-            return ()
-        ids = tuple(
-            dict.fromkeys(
-                tuple(self.config.eos_token_ids) + tuple(s.stop_token_ids)
-            )
-        )
-        return ids if len(ids) <= STOP_SLOTS else None
-
-    def _kstep_candidate(self, reqs: list[Request]) -> bool:
-        """Side-effect-free eligibility for a K-step window over these
-        rows: configured on, policy-enabled, no logprobs rows (the fused
-        window threads no per-position logprob state), every stop set
-        fits STOP_SLOTS. Mixed steps use this to decide whether to split
-        the K-window out as their decode leg; _pick_kstep layers the
-        stateful clamps (admission latency, page runway) on top."""
-        if self._decode_kstep <= 1 or not self._kstep_enabled:
-            return False
-        if self._batch_logprobs(reqs) >= 0:
-            return False
-        return all(self._kstep_stop_ids(r) is not None for r in reqs)
-
-    def _pick_kstep(
-        self, reqs: list[Request], ahead: Optional[list[int]] = None
-    ) -> int:
-        """Window size for this decode dispatch; 1 => take the classic
-        decode/decode_multi path. Mirrors _pick_decode_steps' admission
-        rule (drop to 1 when an admissible request waits) and its
-        pow2 snapping, but the page headroom is reserved UP FRONT for
-        the whole window via the scheduler's runway clamp — the
-        on-device loop can never ask the host for a page mid-window.
-        `ahead` as in _pick_decode_steps."""
-        if self._decode_kstep <= 1 or not self._kstep_enabled:
-            return 1
-        ahead = ahead or [0] * len(reqs)
-        if not self._kstep_candidate(reqs):
-            self.metrics.kstep_fallbacks += 1
-            logger.debug(
-                "kstep fallback: logprobs rows or oversized stop set"
-            )
-            return 1
-        if self.scheduler.num_waiting() > 0 and self.scheduler.can_admit_head():
-            return 1  # stay responsive: new arrivals don't wait K steps
-        k = self._pow2_floor(self._decode_kstep)
-        # context/page-table room: growing a window past max_context or
-        # max_pages_per_seq would overflow the [B, mp] page table (same
-        # per-request caps as _pick_decode_steps)
-        cap_tokens = self.config.max_pages_per_seq * self.config.page_size
-        for req, a in zip(reqs, ahead):
-            k = min(k, self.config.max_context - req.num_tokens - a + 1)
-            k = min(k, cap_tokens - req.num_tokens - a + 1)
-        # cover the longest remaining completion, rounded up to a power
-        # of two (same reasoning as _pick_decode_steps: the tail of a
-        # wave runs as one window, the program family stays log-sized)
-        rem_max = 0
-        for req, a in zip(reqs, ahead):
-            s = req.sampling
-            rem_max = max(
-                rem_max,
-                s.max_tokens - len(req.output_tokens) - req.num_emitted - a,
-            )
-        p = 1
-        while p < max(1, rem_max):
-            p *= 2
-        k = self._pow2_floor(min(k, p))
-        if k <= 1:
-            return 1
-        # scheduler-guaranteed page runway for the WHOLE window (or a
-        # clamped one); _grow_pages_for then actually reserves it
-        k = self.scheduler.clamp_kstep_window(reqs, k, ahead)
-        while k > 1 and not self._grow_pages_for(
-            reqs, [a + k - 1 for a in ahead]
-        ):
-            k //= 2  # pool raced smaller than the clamp's view
-        return max(1, k)
-
-    def _kstep_arrays(
-        self, reqs: list[Request], pad_to: int, ahead: list[int]
-    ) -> tuple[np.ndarray, np.ndarray]:
-        """Device inputs for the window's on-device finish evaluation:
-        per-row packed stop slots (−1-padded) and per-row emission
-        budgets — the EXACT token counts `_finish_reason_for` would
-        allow (max_tokens and max_context legs), so the device freeze
-        decisions and the host finish scan agree position-for-position.
-        `ahead` discounts the tokens a dispatch still on the device
-        emits first, for a window launched ahead of it. Padding rows get
-        budget 0 and empty stop sets (they are never alive anyway)."""
-        from dynamo_tpu.engine.sampling import STOP_SLOTS
-
-        stops = np.full((pad_to, STOP_SLOTS), -1, np.int32)
-        budgets = np.zeros(pad_to, np.int32)
-        for i, req in enumerate(reqs):
-            ids = self._kstep_stop_ids(req)  # eligibility pre-checked
-            if ids:
-                stops[i, : len(ids)] = ids
-            s = req.sampling
-            budgets[i] = max(
-                0,
-                min(
-                    s.max_tokens
-                    - len(req.output_tokens)
-                    - req.num_emitted,
-                    self.config.max_context - req.num_tokens,
-                )
-                - ahead[i],
-            )
-        return stops, budgets
-
     # -- speculative decode (prompt lookup / n-gram) ------------------------
 
     def _spec_eligible(self, reqs: list[Request]) -> bool:
@@ -2092,28 +1925,17 @@ class JaxEngine:
         self, reqs: list[Request], ahead: Optional[list[int]] = None,
         feed=None,
     ) -> Optional[_Launched]:
-        """Stage and launch one pure decode dispatch over `reqs`: an
-        on-device K-step window, one step, or the fused scan. With
-        `ahead`/`feed` it is launched ahead of its batch (`_speculate`);
-        None then means the pool cannot pre-grow the rows' pages."""
+        """Stage and launch one pure decode dispatch over `reqs`: one
+        step, or the fused scan. With `ahead`/`feed` it is launched ahead
+        of its batch (`_speculate`); None then means the pool cannot
+        pre-grow the rows' pages."""
         m = self.metrics
         speculative = ahead is not None
-        t0 = time.perf_counter()  # a K-step window's wall: stage to sync
         with phase(
             m, "engine.stage", "time_stage_ms", "time_decode_dispatch_ms"
         ):
             b_bucket = self.config.decode_bucket_for(len(reqs))
-            # On-device K-step window first (config.decode_kstep): finish
-            # conditions evaluate ON DEVICE, so no overshoot compute past
-            # a stop; k_win == 1 falls through to the classic path (which
-            # is then bit-identical to a decode_kstep-free build). Ahead
-            # of the batch, an ineligible batch is no counted fallback.
-            k_win = 1
-            if not speculative or self._kstep_candidate(reqs):
-                k_win = self._pick_kstep(reqs, ahead)
-            k_steps = (
-                k_win if k_win > 1 else self._pick_decode_steps(reqs, ahead)
-            )
+            k_steps = self._pick_decode_steps(reqs, ahead)
             if speculative and not self._grow_pages_for(
                 reqs, [a + k_steps - 1 for a in ahead]
             ):
@@ -2134,27 +1956,18 @@ class JaxEngine:
                 "base": base, "samp": samp, "pen": pen_args,
                 "bias": bias_kwargs,
             }
-            if k_win > 1:
-                host["stops"], host["budgets"] = self._kstep_arrays(
-                    reqs, b_bucket, ahead or [0] * len(reqs)
-                )
-            elif k_steps == 1:
+            if k_steps == 1:
                 host["last"] = np.zeros(b_bucket, np.int32)
             if speculative:
                 host["src"] = np.full(b_bucket, -1, np.int32)
                 host["src"][: len(reqs)] = feed[1]
             dev = self._dev_tree(host)
-            # logprobs rows never reach a K-step window (_pick_kstep
-            # falls back), so that family has no lp variant
-            kind, fn = self._decode_program(
-                b_bucket, k_steps, kstep=k_win > 1, greedy=all_greedy,
-                lp=lp, pen=pen, bias=bias,
+            kind = "decode" if k_steps == 1 else "decode_multi"
+            fn = self._get_step_fn(
+                kind, b_bucket, k_steps, greedy=all_greedy, lp=lp, pen=pen,
+                bias=bias,
             )
-            if kind == "decode_kstep":
-                head = (dev["stops"], dev["budgets"])
-            else:
-                head = (dev["last"],) if kind == "decode" else ()
-        lp_data = n_emit = None
+            head = (dev["last"],) if kind == "decode" else ()
         with phase(
             m, "engine.launch", "time_decode_dispatch_ms", kind=kind,
             rows=b_bucket, k=k_steps, speculative=int(speculative),
@@ -2166,20 +1979,14 @@ class JaxEngine:
                 self.params, d_tokens, d_positions, d_valid, self.kv, d_pt,
                 *head, *dev["samp"], *dev["pen"], **dev["bias"],
             )
-        if k_win > 1:
-            token_ids, n_emit, self.kv = out
-            m.kstep_windows += 1
-            m.kstep_steps += k_steps
-            m.kstep_window_size = k_steps
-            self._kstep_live = k_steps
-        elif lp >= 0:
+        lp_data = None
+        if lp >= 0:
             token_ids, lp_data, self.kv = out
         else:
             token_ids, self.kv = out  # [B], or [K, B] when fused
         return _Launched(
             reqs=tuple(reqs), b_bucket=b_bucket, k_steps=k_steps,
-            token_ids=token_ids, lp_data=lp_data, kstep=k_win > 1,
-            n_emit=n_emit, t0=t0,
+            token_ids=token_ids, lp_data=lp_data,
         )
 
     def _finish_decode(
@@ -2197,43 +2004,8 @@ class JaxEngine:
             lp_arrays = self._materialize_lp(
                 st.lp_data, st.k_steps, st.b_bucket
             )
-        if st.kstep and st.expected is None:
-            # window wall (dispatch+sync) is the measured column for the
-            # decode_kstep family's attainment; /k is the per-step time
-            # the stall histogram spreads window emissions by
-            window_ms = (time.perf_counter() - st.t0) * 1000.0
-            self.metrics.time_kstep_ms += window_ms
-            self._kstep_step_ms = window_ms / st.k_steps
-        outputs = self._decode_postprocess(
-            reqs, st.k_steps, ids, lp_arrays, mixed=mixed, kstep=st.kstep
-        )
-        if st.kstep and st.expected is None:
-            # device freeze decisions vs the host finish scan: they are
-            # the same arithmetic — disagreement means a program bug, so
-            # surface it loudly rather than silently trusting either
-            host_emitted = sum(len(o.new_token_ids) for o in outputs)
-            dev_emitted = int(np.asarray(st.n_emit)[: len(reqs)].sum())
-            if host_emitted != dev_emitted:
-                logger.warning(
-                    "decode_kstep window disagreement: device emitted "
-                    "%d tokens, host accepted %d (K=%d, B=%d)",
-                    dev_emitted, host_emitted, st.k_steps, len(reqs),
-                )
-        return outputs
-
-    def _decode_program(
-        self, b_bucket: int, k_steps: int, kstep: bool, greedy: bool,
-        lp: int, pen: int, bias: bool,
-    ) -> tuple[str, Callable]:
-        """(kind, program) of a pure decode dispatch of `k_steps` steps:
-        an on-device K-step window, one step, or the fused scan."""
-        if kstep:
-            kind, lp = "decode_kstep", -1
-        else:
-            kind = "decode" if k_steps == 1 else "decode_multi"
-        return kind, self._get_step_fn(
-            kind, b_bucket, k_steps, greedy=greedy, lp=lp, pen=pen,
-            bias=bias,
+        return self._decode_postprocess(
+            reqs, st.k_steps, ids, lp_arrays, mixed=mixed
         )
 
     @staticmethod
@@ -2250,7 +2022,7 @@ class JaxEngine:
 
     def _decode_postprocess(
         self, reqs: list[Request], k_steps: int, ids: np.ndarray, lp_arrays,
-        mixed: bool = False, kstep: bool = False,
+        mixed: bool = False,
     ) -> list[StepOutput]:
         """Host half of a decode step: scan sampled ids for finish
         conditions (dropping overshoot past a stop), append accepted
@@ -2294,7 +2066,7 @@ class JaxEngine:
                 outputs.extend(
                     self._accept_tokens(
                         req, accepted, finish, lps=lps, tops=tops,
-                        mixed=mixed, kstep=kstep,
+                        mixed=mixed,
                     )
                 )
                 self._register_pages(req)
@@ -2316,8 +2088,8 @@ class JaxEngine:
         semantics, same streams): a decode dispatch launched ahead that
         matches the decode rows (a prompt arrived that nobody foresaw) —
         it lands as the decode half and the prefill chunk dispatches
-        beside it — and pieces or rows the fused program has no variant
-        for (multimodal, a K-step window)."""
+        beside it — and pieces the fused program has no variant for
+        (multimodal)."""
         reqs_d = list(batch.decode)
         pieces = list(batch.prefill)
         if self._spec_draft and self._spec_active(reqs_d):
@@ -2345,11 +2117,7 @@ class JaxEngine:
         use_inflight = inflight is not None and self._inflight_matches(
             inflight, reqs_d, ()
         )
-        # K-step windows compose with mixed steps as the decode LEG
-        # beside the prefill chunk (two dispatches, same semantics):
-        # the fused mixed program has no kstep variant, and the window
-        # path handles its own stops/budgets/runway host arrays.
-        if use_inflight or not self._fusable(reqs_d, pieces):
+        if use_inflight or not self._fusable(pieces):
             outputs = self._prefill_beside(batch)
             # consumes (or rolls back) the inflight itself and launches
             # the next dispatch ahead, the rows that just joined in it
@@ -2390,14 +2158,11 @@ class JaxEngine:
             mixed=True,
         )
 
-    def _fusable(self, reqs_d: list[Request], pieces: list) -> bool:
+    @staticmethod
+    def _fusable(pieces: list) -> bool:
         """Whether a mixed batch runs as the one fused program: no
-        multimodal piece (no mm variant) and no K-step window for the
-        decode rows (no kstep variant)."""
-        return not (
-            any(p.request.mm_embeds is not None for p in pieces)
-            or self._kstep_candidate(reqs_d)
-        )
+        multimodal piece (the fused program has no mm variant)."""
+        return all(p.request.mm_embeds is None for p in pieces)
 
     def _launch_mixed(
         self, reqs_d: list[Request], pieces: list,
@@ -2573,7 +2338,7 @@ class JaxEngine:
         rows, pieces = list(nxt.decode), list(nxt.prefill)
         fuse = (
             nxt.kind == "mixed"
-            and self._fusable(rows, pieces)
+            and self._fusable(pieces)
             and len({self._bucket_t(p.length) for p in pieces}) == 1
         )
         if self._batch_penalty_bucket(
@@ -3120,100 +2885,6 @@ class JaxEngine:
             )
             return self._cache_jit(kind, cache_key, jitted)
 
-        if kind == "decode_kstep":
-            # K decode iterations with ON-DEVICE finish evaluation
-            # (config.decode_kstep): like decode_multi's fused scan, but
-            # an `alive` mask carries each row's stop/budget state so
-            # finished rows freeze mid-window — their lanes compute
-            # masked garbage, their KV writes redirect to the null page
-            # (forward_hidden valid=False => ops/kv_update.paged_write
-            # page 0), their positions/draw counters/penalty counts stop
-            # advancing. Because counters and counts advance only while
-            # alive, every surviving row's gumbel stream and penalty
-            # state are IDENTICAL to K=1 sequential stepping (where the
-            # finished row simply leaves the batch) — the bit-exactness
-            # contract tests/test_engine_kstep.py pins. The host reads
-            # back [K, B] ids + per-row emitted counts once per window.
-            # No logprobs variant: logprobs rows fall back (lp == -1).
-            k_steps = t
-
-            def kstep_fn(params, tokens, positions, valid, kv, pt,
-                         stops, budgets,
-                         temps, top_ps, top_ks, seeds, counters,
-                         freq=None, pres=None, rep_p=None,
-                         out_toks=None, out_valid=None,
-                         bias_ids=None, bias_vals=None, bias_gated=None,
-                         min_toks=None):
-                from dynamo_tpu.engine.sampling import stop_mask
-
-                if pen:
-                    from dynamo_tpu.engine.sampling import (
-                        build_output_counts,
-                    )
-
-                    counts0 = build_output_counts(
-                        out_toks, out_valid, adapter.vocab_size
-                    )
-                else:
-                    counts0 = jnp.zeros((), jnp.float32)  # unused carry
-                alive0 = valid[:, 0]  # padding rows start frozen
-                n0 = jnp.zeros((valid.shape[0],), jnp.int32)
-
-                def body(carry, _):
-                    (tokens, positions, kv, counters, counts, alive,
-                     n_emit) = carry
-                    v = valid & alive[:, None]
-                    hidden, kv = adapter.forward_hidden(
-                        params, tokens, positions, v, kv, pt
-                    )
-                    logits = adapter.compute_logits(params, hidden[:, -1])
-                    ids = pick(
-                        logits, (temps, top_ps, top_ks, seeds, counters),
-                        counts=counts if pen else None, freq=freq,
-                        pres=pres, rep_p=rep_p,
-                        bias_args=(
-                            (bias_ids, bias_vals, bias_gated, min_toks)
-                            if bias
-                            else None
-                        ),
-                    )
-                    emit_i = alive.astype(jnp.int32)
-                    n_emit = n_emit + emit_i
-                    if pen:
-                        rows = jnp.arange(ids.shape[0])
-                        counts = counts.at[rows, ids].add(
-                            alive.astype(jnp.float32)
-                        )
-                    # emit-then-freeze: a stop token (or the budget's
-                    # last token) IS emitted — the row freezes for the
-                    # REST of the window, matching the host scan that
-                    # appends the token and then breaks on its finish
-                    alive = (
-                        alive
-                        & ~stop_mask(ids, stops)
-                        & (n_emit < budgets)
-                    )
-                    with jax.named_scope("feedback"):
-                        carry = (
-                            ids[:, None], positions + emit_i[:, None], kv,
-                            counters + emit_i, counts, alive, n_emit,
-                        )
-                    return carry, ids
-
-                (_, _, kv, _, _, _, n_emit), all_ids = jax.lax.scan(
-                    body,
-                    (tokens, positions, kv, counters, counts0, alive0, n0),
-                    None, length=k_steps,
-                )
-                return rep(all_ids), rep(n_emit), kv  # [K, B], [B]
-
-            jitted = jax.jit(kstep_fn, donate_argnums=(4,))
-            logger.info(
-                "compiled decode_kstep program B=%d K=%d greedy=%s",
-                b, k_steps, greedy,
-            )
-            return self._cache_jit(kind, cache_key, jitted)
-
         if kind == "mixed":
             # One fused program per (b=decode bucket, t=prefill T bucket,
             # b_pre=prefill row bucket): prefill chunk KV+decode token in
@@ -3564,31 +3235,18 @@ class JaxEngine:
                 return piece.request.trace_id
         return None
 
-    def _observe_emission(
-        self, req: Request, finished: bool, n_tokens: int = 1,
-        kstep: bool = False,
-    ) -> None:
+    def _observe_emission(self, req: Request, finished: bool) -> None:
         """Decode-stall histogram bookkeeping: observe the gap since this
         request's previous token emission whenever a prefill-carrying
         dispatch (pure prefill or mixed) ran in between — the prefill-
         attributed stall one running request experienced. Under the XOR
         scheduler these gaps are whole backlog drains; under mixed steps
-        they collapse to one step.
-
-        A K-step window delivers its K tokens in one host visit, so the
-        raw gap is K× the per-token cadence even when nothing stalled:
-        discount the device-measured healthy window time (per-step ms ×
-        n_tokens) before observing, leaving only true prefill-induced
-        excess in the histogram."""
+        they collapse to one step."""
         now = time.perf_counter()
         mark = self.metrics.prefill_dispatches + self.metrics.mixed_dispatches
         prev = self._last_emit.get(req.request_id)
         if prev is not None and mark > prev[1]:
             stall_ms = (now - prev[0]) * 1000.0
-            if kstep and n_tokens > 1:
-                stall_ms = max(
-                    0.0, stall_ms - self._kstep_step_ms * n_tokens
-                )
             if req.trace_id is not None:
                 # traced request: accumulate so the final StepOutput can
                 # carry the request's TOTAL prefill-induced stall onto
@@ -3605,7 +3263,7 @@ class JaxEngine:
     def _observe_slo(self, req: Request, n_tokens: int, finished: bool) -> None:
         """Feed the worker-side SLO sketches (config.fleet_telemetry):
         TTFT on the first emission, per-token ITL on later ones (a fused
-        K-step emission spreads its gap over its K tokens), e2e + the
+        dispatch's delivery spreads its gap over its tokens), e2e + the
         SLA/goodput judgement at finish. arrival_time is 0.0 for
         directly-constructed Requests (unit tests, tools) — those skip
         the wall-clock metrics rather than record epoch-sized garbage."""
@@ -3646,7 +3304,6 @@ class JaxEngine:
         tops: Optional[tuple] = None,
         mixed: bool = False,
         spec: bool = False,
-        kstep: bool = False,
     ) -> list[StepOutput]:
         chain = self.scheduler.chains.get(req.request_id)
         for tok in tokens:
@@ -3655,10 +3312,7 @@ class JaxEngine:
                 chain.append(tok)
         self.metrics.generated_tokens += len(tokens)
         if tokens:
-            self._observe_emission(
-                req, finished=finish is not None,
-                n_tokens=len(tokens), kstep=kstep,
-            )
+            self._observe_emission(req, finished=finish is not None)
             if self.slo is not None:
                 self._observe_slo(req, len(tokens), finish is not None)
         if finish is not None:
@@ -3677,7 +3331,6 @@ class JaxEngine:
                 cached_tokens=req.num_cached_prompt_tokens if first else None,
                 mixed=mixed,
                 spec=spec,
-                kstep=kstep,
                 # tracing enrichment (traced requests only; None — and
                 # absent from the wire — otherwise): queue wait on the
                 # first output, accumulated decode stall on the last
@@ -4284,7 +3937,6 @@ class JaxEngine:
         "prefill_nosample": ("time_prefill_ms", "prefill_dispatches"),
         "decode": ("time_decode_ms", "decode_dispatches"),
         "decode_multi": ("time_decode_ms", "decode_dispatches"),
-        "decode_kstep": ("time_kstep_ms", "kstep_windows"),
         "spec_verify": ("time_decode_ms", "decode_dispatches"),
         "spec_fused": ("time_decode_ms", "decode_dispatches"),
         "spec_draft_prefill": ("time_prefill_ms", "prefill_dispatches"),
@@ -4544,7 +4196,7 @@ class JaxEngine:
 
     #: flight-record kinds whose step wall time counts as a decode
     #: dispatch for the straggler gauge
-    _DISPATCH_KINDS = ("decode", "decode_multi", "decode_kstep", "mixed")
+    _DISPATCH_KINDS = ("decode", "decode_multi", "mixed")
 
     def dispatch_stats(self) -> dict:
         """Recent-window decode dispatch wall-time stats (the per-host
@@ -4571,8 +4223,8 @@ class JaxEngine:
                     "mean_ms": round(sum(vals) / len(vals), 3),
                 }
         m = self.metrics
-        disp = m.decode_dispatches + m.mixed_dispatches + m.kstep_windows
-        total = m.time_decode_ms + m.time_mixed_ms + m.time_kstep_ms
+        disp = m.decode_dispatches + m.mixed_dispatches
+        total = m.time_decode_ms + m.time_mixed_ms
         mean = round(total / disp, 3) if disp else None
         return {"n": disp, "p50_ms": mean, "p95_ms": mean, "mean_ms": mean}
 

@@ -154,10 +154,6 @@ class StepOutput:
     #: spec_draft_model) — surfaces as the `spec` attribute on the
     #: engine.generate trace span
     spec: bool = False
-    #: emitted by an on-device K-step decode window
-    #: (EngineConfig.decode_kstep > 1) — surfaces as the `kstep`
-    #: attribute on the engine.generate trace span
-    kstep: bool = False
     #: tracing enrichment (first output of a TRACED request only; None
     #: otherwise — the wire shape is unchanged when tracing is off):
     #: admission-to-schedule wait, for the trace timeline breakdown

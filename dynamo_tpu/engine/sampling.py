@@ -91,28 +91,6 @@ def apply_logit_bias(
     return logits.at[rows, bias_ids].add(vals)
 
 
-#: static per-row stop-token slots for the on-device K-step decode
-#: window (EngineConfig.decode_kstep): each row's eos ∪ stop_token_ids
-#: set is packed into this many −1-padded slots; requests needing more
-#: fall back to the host-side finish scan (mirrors BIAS_SLOTS)
-STOP_SLOTS = 8
-
-
-def stop_mask(
-    ids: jax.Array,  # [B] i32 sampled token per row
-    stop_slots: jax.Array,  # [B, S] i32 stop-token ids (−1-padded)
-) -> jax.Array:  # [B] bool — this row's token is one of its stop tokens
-    """On-device stop-condition check for the fused K-step decode window:
-    a row whose sampled token matches any of its packed stop slots is
-    frozen for the rest of the window (the stop token itself IS emitted
-    first — `_finish_reason_for` appends it host-side too, so the device
-    freeze decision and the host finish scan agree position-for-
-    position). Padding slots are −1 and can never match a sampled id."""
-    return jnp.any(
-        (ids[:, None] == stop_slots) & (stop_slots >= 0), axis=1
-    )
-
-
 def sample(
     logits: jax.Array,  # [B, V] f32
     temperature: jax.Array,  # [B] f32 (<=0 => greedy)

@@ -186,32 +186,6 @@ class Scheduler:
     def num_running(self) -> int:
         return len(self.running)
 
-    def clamp_kstep_window(self, reqs, k: int, ahead=None) -> int:
-        """Page-runway guarantee for on-device K-step decode windows
-        (EngineConfig.decode_kstep): the fused program writes K tokens
-        of KV per row with NO host allocation mid-window, so every page
-        the window needs must exist before dispatch. Halve K until the
-        whole batch's runway (pages to cover num_tokens + K - 1 per row,
-        beyond what each row already holds) fits in the free pool — the
-        engine then pre-grows via its normal growth path, which can
-        still preempt-by-recompute if a race shrinks the pool. Returns
-        the clamped window (>= 1); K=1 needs no runway beyond classic
-        stepping's. `ahead[i]` are tokens a dispatch still on the device
-        adds to row i before this window starts."""
-        ps = self.config.page_size
-        ahead = ahead or [0] * len(reqs)
-        while k > 1:
-            need = 0
-            for req, a in zip(reqs, ahead):
-                need += max(
-                    0,
-                    -(-(req.num_tokens + a + k - 1) // ps) - len(req.pages),
-                )
-            if need <= self.allocator.num_free:
-                return k
-            k //= 2
-        return 1
-
     def ends_within(self, req: Request, k: int) -> bool:
         """Whether `req` is certain to finish within its next `k` sampled
         tokens whatever they are: the token budget or the context runs

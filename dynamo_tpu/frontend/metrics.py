@@ -220,8 +220,6 @@ class FrontendMetrics:
         from dynamo_tpu.telemetry import debug as _debug
 
         lines.extend(_debug.spec_lines())  # fixed dynamo_tpu_spec_* name
-        # on-device K-step decode windows (EngineConfig.decode_kstep)
-        lines.extend(_debug.kstep_lines())
         # data-integrity rejections (disk-tier checksum misses, corrupt
         # transfer frames): process-global like the phase histograms
         lines.extend(_debug.integrity_lines())

@@ -91,16 +91,6 @@ _WORKER_FIELDS = (
     ("overlap_dispatches", "counter"),
     ("overlap_hits", "counter"),
     ("overlap_rollbacks", "counter"),
-    # on-device K-step decode windows (EngineConfig.decode_kstep):
-    # steps/windows is the realized fusion depth, window_size the live
-    # target after clamps, fallbacks the per-dispatch eligibility misses
-    # (logprobs rows, oversized stop sets); time/windows is the
-    # decode_kstep family's measured ms per window
-    ("kstep_windows", "counter"),
-    ("kstep_steps", "counter"),
-    ("kstep_fallbacks", "counter"),
-    ("kstep_window_size", "gauge"),
-    ("time_kstep_ms", "counter"),
     # speculative decoding (spec_ngram / spec_draft_model): drafts
     # proposed vs accepted — their ratio times S is the extra tokens per
     # verify dispatch; the skip counters say WHY speculation sat out
@@ -203,7 +193,6 @@ _FLEET_WORKER_FIELDS = (
     "stalls_total", "overload_rejects", "deadline_expired", "flips_total",
     "spec_drafted", "spec_accepted", "spec_skipped_ineligible",
     "spec_skipped_cooldown", "spec_accept_rate", "spec_window_drafted",
-    "kstep_windows", "kstep_steps", "kstep_window_size",
     "handovers_total", "handover_fallbacks_total", "handover_bytes_total",
     "handover_blocks_total", "handovers_adopted_total",
     "kv_transfer_corrupt_total",
@@ -1295,8 +1284,6 @@ class MetricsService:
         from dynamo_tpu.telemetry import debug as _debug
 
         lines += _debug.spec_lines(PREFIX)
-        # on-device K-step decode windows — same both-surfaces contract
-        lines += _debug.kstep_lines(PREFIX)
         # data-integrity rejections (disk-tier checksum misses, corrupt
         # transfer frames) — same both-surfaces contract as spec_lines
         lines += _debug.integrity_lines(PREFIX)

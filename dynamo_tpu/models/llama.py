@@ -1828,12 +1828,9 @@ def forward_hidden(
     they replace the token-id embedding lookup (the placeholder ids under
     the mask are ignored).
 
-    The fused K-step decode window (EngineConfig.decode_kstep) calls
-    this inside a lax.scan with per-iteration valid masks: rows frozen
-    mid-window keep the same [B, 1] shapes and their paged_write lanes
-    redirect to the null page (valid=False contract in ops/kv_update),
-    so the whole window lowers to ONE XLA program with no host in the
-    loop.
+    The fused decode scan (`decode_multi`) calls this inside a lax.scan:
+    padding rows keep the same [B, 1] shapes and their paged_write lanes
+    redirect to the null page (valid=False contract in ops/kv_update).
     """
     # The named scopes below (embed, attn[/qkv, /kv_update, /paged or
     # /flash, /out], mlp, final_norm; lm_head in compute_logits) only

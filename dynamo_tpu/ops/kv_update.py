@@ -240,11 +240,9 @@ def paged_write(
     axis, every shard writes its own lanes of the same rows.
 
     valid=False lanes redirect to page 0 (the engine's reserved null
-    page) instead of skipping the write — that redirect is what lets the
-    fused K-step decode window (EngineConfig.decode_kstep) freeze
-    finished rows MID-WINDOW entirely on device: a frozen row keeps
-    dispatching through the same program shape, its KV writes land in
-    the null page, and its real pages are untouched for the next owner.
+    page) instead of skipping the write: a padding row of a bucketed
+    batch runs through the same program shape as a live one, its KV
+    write lands in the null page, and no real page is touched.
     """
     quantized = k_scale is not None
     if use_kernel is None:

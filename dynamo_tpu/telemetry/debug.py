@@ -162,37 +162,6 @@ def spec_lines(prefix: str = "dynamo_tpu") -> list[str]:
     ]
 
 
-def kstep_lines(prefix: str = "dynamo_tpu") -> list[str]:
-    """Process-global K-step decode-window exposition, summed over the
-    registered in-process engines (EngineConfig.decode_kstep):
-    windows/steps/fallback counters plus the live window-size gauge.
-    Included by BOTH Prometheus surfaces like spec_lines; the per-WORKER
-    fleet view rides the metrics frames as `{prefix}_worker_kstep_*`.
-    Always emitted (zeros when no engine fuses windows) so dashboards
-    and the panel-name gate see the families."""
-    windows = steps = fallbacks = 0
-    window_size = 0
-    for eng in registered_engines().values():
-        m = getattr(eng, "metrics", None)
-        if m is None:
-            continue
-        windows += getattr(m, "kstep_windows", 0)
-        steps += getattr(m, "kstep_steps", 0)
-        fallbacks += getattr(m, "kstep_fallbacks", 0)
-        # gauge: the largest live window across engines (0 = classic)
-        window_size = max(window_size, getattr(m, "kstep_window_size", 0))
-    return [
-        f"# TYPE {prefix}_kstep_windows_total counter",
-        f"{prefix}_kstep_windows_total {windows}",
-        f"# TYPE {prefix}_kstep_steps_total counter",
-        f"{prefix}_kstep_steps_total {steps}",
-        f"# TYPE {prefix}_kstep_fallbacks_total counter",
-        f"{prefix}_kstep_fallbacks_total {fallbacks}",
-        f"# TYPE {prefix}_kstep_window_size gauge",
-        f"{prefix}_kstep_window_size {window_size}",
-    ]
-
-
 def integrity_lines(prefix: str = "dynamo_tpu") -> list[str]:
     """Process-global data-integrity counters: KV bytes whose checksum
     failed verification and were REJECTED — disk-tier blocks at rest

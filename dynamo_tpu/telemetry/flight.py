@@ -54,11 +54,6 @@ _DELTA_FIELDS = (
     # step — a record with tokens but no spec_drafted is a plain step
     ("spec_drafted", "spec_drafted"),
     ("spec_accepted", "spec_accepted"),
-    # on-device K-step decode windows: a record with kstep_steps > 1×
-    # kstep_windows carries a fused multi-token window; per-step time is
-    # the record's step_ms / kstep_steps
-    ("kstep_windows", "kstep_windows"),
-    ("kstep_steps", "kstep_steps"),
     ("compiles", "compiles"),
     ("compile_ms", "compile_ms"),
     ("preempted", "preemptions"),

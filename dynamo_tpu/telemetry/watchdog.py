@@ -144,18 +144,10 @@ class StallWatchdog:
         interval_s: float = 1.0,
         clock=time.monotonic,
         counters: Optional[StallCounters] = None,
-        window_steps: Optional[Callable[[], int]] = None,
     ):
         #: live ITL estimate (ms) from the SLO plane; None = no traffic
         #: yet, fall back to the floor
         self._itl_estimate_ms = itl_estimate_ms
-        #: live emission window size (tokens per host visit): 1 for the
-        #: classic per-token loop, K under on-device K-step decode
-        #: windows (EngineConfig.decode_kstep). A healthy K-window
-        #: stream emits every K×ITL, so the stall factor is floored at
-        #: 2K — otherwise a configured factor below K would diagnose
-        #: every healthy stream as stalled.
-        self._window_steps = window_steps
         self.flight = flight
         self.stall_factor = stall_factor
         self.stall_min_s = stall_min_s
@@ -211,10 +203,7 @@ class StallWatchdog:
 
     def stall_threshold_s(self) -> float:
         """N× the SLO plane's live ITL estimate, floored at stall_min_s
-        (cold engines / first compiles legitimately take seconds). Under
-        K-step decode windows the factor itself is floored at 2× the
-        live window size — emissions arrive once per K tokens, so K×ITL
-        gaps are the healthy cadence, not a stall."""
+        (cold engines / first compiles legitimately take seconds)."""
         est = None
         if self._itl_estimate_ms is not None:
             try:
@@ -223,15 +212,7 @@ class StallWatchdog:
                 est = None
         if est is None or est <= 0:
             return self.stall_min_s
-        factor = self.stall_factor
-        if self._window_steps is not None:
-            try:
-                k = int(self._window_steps())
-            except Exception:
-                k = 1
-            if k > 1:
-                factor = max(factor, 2.0 * k)
-        return max(self.stall_min_s, factor * est / 1000.0)
+        return max(self.stall_min_s, self.stall_factor * est / 1000.0)
 
     def check(self, now: Optional[float] = None) -> list[dict]:
         """One watchdog pass: returns the NEW diagnoses (already logged

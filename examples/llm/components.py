@@ -12,8 +12,7 @@ Config keys (YAML per service, see configs/):
   Worker:     model, engine (jax|echo|mock), router-mode, page-size,
               num-pages, max-context, dtype, disagg, max-local-prefill,
               prefill-chunk, prefill-budget, prefill-policy (fixed|adaptive),
-              prefill-budget-max, max-seqs, decode-steps, decode-kstep,
-              spec-ngram,
+              prefill-budget-max, max-seqs, decode-steps, spec-ngram,
               spec-draft, spec-draft-tokens, spec-draft-checkpoint,
               quantize, host-kv-bytes, disk-kv-bytes, disk-kv-dir,
               dp, tp, sp, ep
@@ -41,7 +40,6 @@ def _engine_config(cfg: dict):
         max_seqs=int(cfg.get("max-seqs", 64)),
         dtype=cfg.get("dtype", "bfloat16"),
         decode_steps=int(cfg.get("decode-steps", 8)),
-        decode_kstep=int(cfg.get("decode-kstep", 1)),
         spec_ngram=int(cfg.get("spec-ngram", 0)),
         spec_draft_model=cfg.get("spec-draft"),
         spec_draft_tokens=int(cfg.get("spec-draft-tokens", 4)),

@@ -11,9 +11,6 @@ floor, to locate the gap:
   full_pallas_kvq   — pallas with kv_quantize=int8 (halved KV traffic)
   dense_floor       — model forward with attention replaced by identity
                       (weight-streaming floor for the dense stack)
-  kstep_sweep       — the decode_kstep program (on-device sampling, stop
-                      checks, paged-KV writes) ms/step + roofline
-                      attainment vs K in {1,2,4,8,16}
 
 For each impl the SAME program is also timed WITHOUT the host loop
 (`pure_*`): fixed device inputs, one block per dispatch. That DIRECT
@@ -187,64 +184,6 @@ def time_pure_program(eng, batch: int) -> dict:
     }
 
 
-def time_kstep_sweep(eng, batch: int, roof: dict) -> dict:
-    """ISSUE 16 leg: the decode_kstep program (on-device sampling, stop
-    checks, paged-KV writes — ONE host sync per K tokens) timed pure for
-    K in {1,2,4,8,16}. ms/step should be ~flat across K while ms/dispatch
-    grows ~linearly; `attainment` is roofline_ms_per_step / measured
-    ms/step, the number /v1/debug/programs reports live for the
-    decode_kstep family. Read next to host_overhead_ms_*: the K-window
-    pays the host overhead once per K steps instead of every step."""
-    import jax
-    import numpy as np
-
-    from dynamo_tpu.engine.sampling import STOP_SLOTS
-
-    mp = eng.config.max_pages_per_seq
-    tokens = np.ones((batch, 1), np.int32)
-    positions = np.full((batch, 1), ISL - 1, np.int32)
-    valid = np.ones((batch, 1), bool)
-    pt = np.zeros((batch, mp), np.int32)
-    for i in range(batch):
-        pt[i, :4] = 1 + 4 * i + np.arange(4)
-    # no stop tokens, unbounded budgets: every row stays alive the whole
-    # window, so the timed program does the full K steps of work
-    stops = np.full((batch, STOP_SLOTS), -1, np.int32)
-    budgets = np.full((batch,), 1 << 30, np.int32)
-    samp, _ = eng._sampling_arrays([], pad_to=batch)
-    dev = eng._dev_tree({"base": (tokens, positions, valid, pt),
-                         "ctl": (stops, budgets), "samp": samp})
-    d_tokens, d_positions, d_valid, d_pt = dev["base"]
-    d_stops, d_budgets = dev["ctl"]
-    out = {}
-    for k in (1, 2, 4, 8, 16):
-        fn = eng._get_step_fn(
-            "decode_kstep", batch, k, greedy=True, lp=-1, pen=0,
-            bias=False,
-        )
-        kv = eng.kv
-        ids, _n, kv = fn(eng.params, d_tokens, d_positions, d_valid, kv,
-                         d_pt, d_stops, d_budgets, *dev["samp"])
-        jax.block_until_ready(ids)
-        n = 5
-        t0 = time.perf_counter()
-        for _ in range(n):
-            ids, _n, kv = fn(eng.params, d_tokens, d_positions, d_valid,
-                             kv, d_pt, d_stops, d_budgets, *dev["samp"])
-            jax.block_until_ready(ids)
-        dt = (time.perf_counter() - t0) / n
-        eng.kv = kv
-        ms_step = 1000 * dt / k
-        out[str(k)] = {
-            "ms_per_dispatch": round(1000 * dt, 3),
-            "ms_per_step": round(ms_step, 3),
-            "attainment": round(
-                roof["roofline_ms_per_step"] / ms_step, 3
-            ) if ms_step > 0 else None,
-        }
-    return out
-
-
 def time_dense_floor(batch: int) -> dict:
     """Weight-streaming floor: the same parameter stack driven as pure
     dense matmuls (one token per sequence, attention output zeroed via a
@@ -311,11 +250,6 @@ def main() -> None:
             row[f"full_{tag}"] = time_full(eng, batch)
             row[f"pure_{tag}"] = time_pure_program(eng, batch)
             row[f"roofline_{tag}"] = roofline(eng, batch)
-            if tag == "pallas":
-                # K-step window sweep on the serving-default impl only
-                row["kstep_sweep"] = time_kstep_sweep(
-                    eng, batch, row[f"roofline_{tag}"]
-                )
             full = row[f"full_{tag}"]
             if full["dispatches"]:
                 # the DIRECT program-vs-host split: serve ms/dispatch −

@@ -1,6 +1,6 @@
 """The fast decode pipeline carried across hosts: overlap_decode,
-mixed_steps, and decode_kstep are no longer auto-disabled on
-multi-process SPMD meshes. `EngineConfig.force_multihost` makes a
+mixed_steps and the fused decode scan run on multi-process SPMD
+meshes. `EngineConfig.force_multihost` makes a
 single-process engine take the multi-controller code paths (replicated
 decode outputs, addressable-shard readbacks, lockstep-safe scheduling)
 so CPU tests pin the contract deterministically: per-process token
@@ -44,8 +44,8 @@ def _workload():
                 ),
             )
         )
-    # one long steady wave so the overlap/kstep pipeline actually
-    # engages after the staggered finishes drain
+    # one long steady wave so the overlap pipeline and the fused scan
+    # actually engage after the staggered finishes drain
     reqs.append(
         (
             "long",
@@ -68,8 +68,6 @@ def test_force_multihost_takes_multiproc_paths(cpu_mesh_devices):
     assert eng._rep_sharding is not None
     # the pipeline stays ON: no multi-host auto-off anymore
     assert eng._overlap_enabled and eng._mixed_enabled
-    eng2 = _make(topology="tp=2,dp=2", force_multihost=True, decode_kstep=4)
-    assert eng2._kstep_enabled
 
 
 def test_speculation_still_disables_pipeline_multihost(cpu_mesh_devices):
@@ -78,20 +76,18 @@ def test_speculation_still_disables_pipeline_multihost(cpu_mesh_devices):
     of topology."""
     eng = _make(
         topology="tp=2,dp=2", force_multihost=True, spec_ngram=3,
-        decode_kstep=4,
     )
     assert eng._multiproc is True
     assert not eng._overlap_enabled
     assert not eng._mixed_enabled
-    assert not eng._kstep_enabled
 
 
-@pytest.mark.parametrize("kstep", [1, 4])
+@pytest.mark.parametrize("decode_steps", [1, 4])
 def test_multihost_pipeline_bit_exact_vs_single_host(
-    kstep, cpu_mesh_devices
+    decode_steps, cpu_mesh_devices
 ):
-    """THE acceptance pin: the full pipeline (overlap + mixed + kstep)
-    under the forced multi-host mesh produces per-request token streams
+    """THE acceptance pin: the full pipeline (overlap + mixed + the fused
+    scan) under the forced multi-host mesh produces per-request token streams
     bit-identical to the same engine without the multi-host paths, and
     to the fully synchronous single-host reference."""
     reqs = _workload()
@@ -101,20 +97,18 @@ def test_multihost_pipeline_bit_exact_vs_single_host(
         reqs,
     )
     ref_host = _run(
-        _make(topology="tp=2,dp=2", decode_kstep=kstep, decode_steps=1),
-        reqs,
+        _make(topology="tp=2,dp=2", decode_steps=decode_steps), reqs,
     )
     mh = _make(
-        topology="tp=2,dp=2", force_multihost=True, decode_kstep=kstep,
-        decode_steps=1,
+        topology="tp=2,dp=2", force_multihost=True,
+        decode_steps=decode_steps,
     )
     got = _run(mh, reqs)
     assert got == ref_host
     assert got == ref_sync
-    if kstep > 1:
-        assert mh.metrics.kstep_windows > 0, "kstep never engaged"
-    else:
-        assert mh.metrics.overlap_hits > 0, "overlap never engaged"
+    assert mh.metrics.overlap_hits > 0, "overlap never engaged"
+    if decode_steps > 1:
+        assert mh.compiles_by_kind.get("decode_multi"), "never fused"
 
 
 def test_multihost_streams_bit_exact_across_an_admission(cpu_mesh_devices):

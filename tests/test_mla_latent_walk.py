@@ -192,7 +192,7 @@ def test_engine_serves_through_the_walk_and_the_reference_agrees():
     eng = JaxEngine(EngineConfig(
         model="mla-tiny-moe", attention_impl="pallas", num_pages=64,
         page_size=PAGE, max_pages_per_seq=16, decode_buckets=(2,),
-        prefill_chunk=8, max_seqs=2, dtype="float32", decode_kstep=4))
+        prefill_chunk=8, max_seqs=2, dtype="float32", decode_steps=4))
     assert eng.adapter.config.attention_impl == "pallas"
     assert eng.kv.v.shape[-1] == 128
     rng = np.random.default_rng(1)
