@@ -34,6 +34,15 @@ from dynamo_tpu.testing import faults
 
 logger = logging.getLogger(__name__)
 
+#: longest the loop holds a step back for the takers of free decode
+#: slots (`AsyncEngineRunner._await_takers`): over a client's way back
+#: on one host (20-37 ms p5-p95, 48 ms p98 on the chip) with its tail
+#: (a taker later than this changed the batches of one run in eight at
+#: 60 ms); the engine allows three quarters of a decode dispatch, which
+#: is about this in the benchmark's cells (8 steps of 15-18 ms) and less
+#: for a small model
+TAKERS_WAIT_S = 0.1
+
 
 class AsyncEngine(Protocol):
     async def generate(
@@ -312,9 +321,43 @@ class AsyncEngineRunner:
             self._post(rid, {"token_ids": [], "finish_reason": "error"})
             self._post(rid, None)
 
+    def _await_takers(self) -> None:
+        """Hold the next step back while it is free to: a decode slot
+        has no taker and a dispatch launched ahead keeps the device busy
+        (`JaxEngine.takers_wait_s`). A client that got its last token
+        sends its next prompt a round trip later; a step that ends about
+        then (a fused mixed step of a short chunk does) would take the
+        prompt now or a whole decode dispatch later by the millisecond,
+        and every later batch follows from it. Waits until the free
+        slots have takers in the inbox, the dispatch lands, something
+        else wants the loop, or the shorter of the engine's allowance
+        and `TAKERS_WAIT_S`."""
+        wait_s = getattr(self.engine, "takers_wait_s", None)
+        if wait_s is None:
+            return
+        allowed = self._takers_allowance(wait_s)
+        if allowed <= 0:
+            return
+        deadline = time.perf_counter() + min(allowed, TAKERS_WAIT_S)
+        with phase(None, "engine.wait"):
+            while not self._stop and time.perf_counter() < deadline:
+                # short naps: whether the dispatch has landed is polled
+                self._wake.wait(timeout=0.002)
+                self._wake.clear()
+                if self._takers_allowance(wait_s) <= 0:
+                    return
+
+    def _takers_allowance(self, wait_s) -> float:
+        with self._lock:
+            if self._aborts or self._ops:
+                return 0.0
+            queued = len(self._pending)
+        return wait_s(queued)
+
     def _run(self) -> None:
         eng = self.engine
         while not self._stop:
+            self._await_takers()
             with phase(eng.metrics, "engine.intake", "time_intake_ms") as ph:
                 pending, aborts, ops = self._drain_inbox()
                 self._run_ops(ops)

@@ -189,6 +189,13 @@ class EngineMetrics:
     prefill_dispatches: int = 0
     decode_dispatches: int = 0
     mixed_dispatches: int = 0
+    #: decode rows that rode a prompt chunk's pass over the weights in a
+    #: fused mixed program, summed where the dispatch is read: over
+    #: mixed_dispatches, the rows an admission saved a weight stream of
+    #: their own (0 on the split path, whose halves are two programs;
+    #: models/moe.py counts them too but still passes twice:
+    #: `registry._two_pass_mixed`)
+    mixed_shared_rows: int = 0
     #: overlapped decode pipeline: speculative next-step dispatches
     #: issued / consumed as the real step / rolled back (overshoot
     #: discarded because the batch changed underneath them)
@@ -254,6 +261,7 @@ class EngineMetrics:
         "time_decode_host_ms",
         "time_stage_ms", "time_intake_ms", "time_emit_ms",
         "prefill_dispatches", "decode_dispatches", "mixed_dispatches",
+        "mixed_shared_rows",
         "overlap_dispatches", "overlap_hits", "overlap_rollbacks",
     )
 
@@ -570,6 +578,9 @@ class JaxEngine:
         #: host sync and it is identical on every lockstep replica. Off
         #: under prompt-lookup speculation (drafts need host tokens).
         self._inflight: Optional[_Launched] = None
+        #: wall seconds of a pure decode dispatch: the longest of the
+        #: recent ones, fading a tenth a dispatch (`takers_wait_s`)
+        self._decode_wall_s = 0.0
         self._overlap_enabled = (
             config.overlap_decode and config.spec_ngram <= 0
         )
@@ -917,6 +928,11 @@ class JaxEngine:
                 outputs += self._run_decode(batch)
                 dt_ms = (time.perf_counter() - t2) * 1000.0
                 self.metrics.time_decode_ms += dt_ms
+                # a dispatch read late (it landed during a stall) must
+                # not read as a short one: keep the longest, fading
+                self._decode_wall_s = max(
+                    dt_ms / 1000.0, 0.9 * self._decode_wall_s
+                )
                 phases.observe("decode_step_ms", dt_ms, trace_id=batch_tid)
             self.metrics.steps += 1
             if self._fleet_telemetry:
@@ -2078,11 +2094,24 @@ class JaxEngine:
     def _run_mixed(self, batch: ScheduledBatch) -> list[StepOutput]:
         """One stall-free step: a bounded prefill chunk AND the decode
         batch fused into a single XLA program — one `_dev_tree` transfer,
-        one readback. Decode rows ride the same [B, 1] page-walk path as
-        a pure decode step and prefill pieces the same [B, T] chunk path
-        as a pure prefill step (pages are per-request disjoint, so the
-        halves cannot read each other's writes) — greedy token streams
-        are bit-exact vs the XOR scheduler (tests/test_engine_mixed.py).
+        one readback, one pass over the weights. The program runs every
+        norm, projection and the FFN on the prompt rows and the decode
+        rows together (`ModelAdapter.forward_hidden_mixed`); attention,
+        rope and the cache write run per group, decode rows through the
+        same [B, 1] page walk as a pure decode step and prefill pieces
+        through the same [B, T] chunk path as a pure prefill step (pages
+        are per-request disjoint, so the groups cannot read each other's
+        writes). The mathematics of a row is that of the XOR scheduler's
+        two programs; its floats agree with theirs to rounding, not bit
+        for bit: a compiler may order a row's matmul sums by how many rows
+        share the matmul (XLA:CPU does; a logit's last bits move, so an
+        argmax or top-k tie could fall the other way). What
+        tests/test_engine_mixed.py pins: token streams equal to the XOR
+        scheduler's, reported logprobs bitwise where the row counts leave
+        the matmuls as they were and to 1e-5 elsewhere; on the chip every
+        run's `correct` compares with the plain reference.
+        `mixed_shared_rows` counts the decode rows that shared a chunk's
+        weight pass.
 
         Two cases run the halves as separate dispatches instead (same
         semantics, same streams): a decode dispatch launched ahead that
@@ -2289,6 +2318,7 @@ class JaxEngine:
         decode rows first."""
         m = self.metrics
         b_dec = st.b_bucket
+        m.mixed_shared_rows += len(st.reqs)
         with phase(
             m, "engine.readback", "time_decode_sync_ms",
             lagged=int(st.expected is not None),
@@ -2444,6 +2474,25 @@ class JaxEngine:
         self.metrics.overlap_rollbacks += 1
         with phase(None, "engine.rollback", why=why):
             pass
+
+    def takers_wait_s(self, queued: int = 0) -> float:
+        """How long the loop's next step can be held back for the takers
+        of free decode slots, `queued` of whom are in the runner's inbox
+        (`AsyncEngineRunner._await_takers`): while a fused decode scan
+        launched ahead still runs on the device, a slot is free and
+        nobody waits for it, three quarters of the wall of a recent
+        decode dispatch, so the next launch (a host turn of a few
+        milliseconds) still lands behind a busy device. An
+        arrival's place in the dispatches then does not turn on a
+        millisecond. 0 where nothing is launched ahead, it is a
+        one-token dispatch or it has landed (the device would idle), or
+        every free slot has its taker."""
+        st = self._inflight
+        if st is None or st.k_steps < 2 or st.token_ids.is_ready():
+            return 0.0
+        s = self.scheduler
+        room = self.config.max_seqs - s.num_running() - s.num_waiting()
+        return 0.75 * self._decode_wall_s if room > queued else 0.0
 
     def drain_overlap(self) -> None:
         """Public: discard any speculative in-flight decode dispatch
@@ -2888,12 +2937,19 @@ class JaxEngine:
         if kind == "mixed":
             # One fused program per (b=decode bucket, t=prefill T bucket,
             # b_pre=prefill row bucket): prefill chunk KV+decode token in
-            # a single dispatch. The halves run the SAME forward paths as
-            # the pure programs (decode [B, 1] page walk, prefill [B, T]
-            # chunk), so per-row numerics — and greedy token streams —
-            # are identical to the XOR scheduler's. psamp selects whether
-            # prefill rows sample (some piece completes its prompt);
-            # without it only decode rows pay the lm_head.
+            # a single dispatch and ONE pass over the weights
+            # (`adapter.forward_hidden_mixed`). Shared by the two groups
+            # of rows: every norm, projection and the whole FFN, which run
+            # on the prompt rows and the decode rows concatenated. Per
+            # group: rope, attention and the cache write, the code the
+            # pure programs run (decode [B, 1] page walk, prefill [B, T]
+            # chunk). Per-row results are the XOR scheduler's two programs'
+            # to rounding (a row's matmul sums may be ordered by the row
+            # count of the matmul it shares: `_run_mixed`); the tests pin
+            # equal token streams.
+            # psamp selects whether prefill rows sample (some piece
+            # completes its prompt); without it only decode rows pay the
+            # lm_head.
 
             def mixed_fn(params, d_tokens, d_positions, d_valid, kv, d_pt,
                          p_tokens, p_positions, p_valid, p_pt, last_idx,
@@ -2902,15 +2958,13 @@ class JaxEngine:
                          out_toks=None, out_valid=None,
                          bias_ids=None, bias_vals=None, bias_gated=None,
                          min_toks=None):
-                # prefill half first (the XOR policy's order); page
-                # tables are per-request disjoint, so neither half can
+                # prompt rows first (the XOR policy's order); page
+                # tables are per-request disjoint, so neither group can
                 # read the other's writes
-                hidden_p, kv = adapter.forward_hidden(
-                    params, p_tokens, p_positions, p_valid, kv, p_pt,
+                hidden_p, hidden_d, kv = adapter.forward_hidden_mixed(
+                    params, (p_tokens, p_positions, p_valid, p_pt),
+                    (d_tokens, d_positions, d_valid, d_pt), kv,
                     first_chunk=first_chunk,
-                )
-                hidden_d, kv = adapter.forward_hidden(
-                    params, d_tokens, d_positions, d_valid, kv, d_pt
                 )
                 last_h = hidden_d[:, -1]  # [B_dec, H] (T=1)
                 if psamp:
@@ -3847,24 +3901,34 @@ class JaxEngine:
 
     def _register_pages(self, req: Request) -> None:
         """Content-address any newly *filled* pages (enables prefix sharing
-        and emits 'stored' KV events for routers)."""
+        and emits 'stored' KV events for routers). Called for every row of
+        every dispatch, so it resumes at `req.registered_blocks` and does
+        not walk the request's pages from block 0: a 64-row batch of
+        4k-token contexts was ~4,100 allocator calls a dispatch, each a
+        ctypes call that hands the interpreter lock to the thread
+        delivering tokens."""
         if not self.config.enable_prefix_caching or req.mm_embeds is not None:
             return
         chain = self.scheduler.chains.get(req.request_id)
         if chain is None:
             return
-        ps = self.config.page_size
-        full_computed = min(req.num_computed_tokens, len(chain) ) // ps
-        for bi in range(full_computed):
-            if bi >= len(req.pages):
-                break
+        full_computed = min(
+            min(req.num_computed_tokens, len(chain)) // self.config.page_size,
+            len(req.pages),
+        )
+        done = req.registered_blocks
+        for bi in range(done, full_computed):
             block = chain.blocks[bi]
-            self.allocator.register(
+            if self.allocator.register(
                 req.pages[bi],
                 block.sequence_hash,
                 block.parent_sequence_hash,
                 block.tokens,
-            )
+            ) and bi == done:
+                # a block refused as a duplicate is offered again next
+                # time (the page it duplicates may have been evicted)
+                done += 1
+        req.registered_blocks = done
 
     def _refresh_metrics(self) -> None:
         # Complete async KVBM offloads started last step (double buffer:

@@ -195,21 +195,25 @@ class PageAllocator:
         seq_hash: int,
         parent_hash: Optional[int],
         tokens: tuple[int, ...],
-    ) -> None:
-        """Content-address a *full* page so future requests can share it."""
+    ) -> bool:
+        """Content-address a *full* page so future requests can share it.
+        True when the page carries a registration afterwards (now or from
+        before), False when its content is already cached under another
+        page (two seqs computed the same block concurrently; the existing
+        registration is kept, and a caller may offer this page again)."""
+        if page in self._page_meta:
+            # in step with the native pool's own table: it evicts only
+            # inside allocate / clear_cache, which drain at once
+            return True
         if self._np is not None:
             if not self._nlib.dyn_pool_register(
                 self._np, page, seq_hash & 0xFFFFFFFFFFFFFFFF
             ):
-                return
+                return False
         else:
-            if page in self._page_meta:
-                return
             prev = self._by_hash.get(seq_hash)
             if prev is not None and prev != page:
-                # Duplicate content under two pages (two seqs computed the
-                # same block concurrently). Keep the existing registration.
-                return
+                return False
             self._by_hash[seq_hash] = page
         self._page_meta[page] = (seq_hash, parent_hash, tokens)
         self.stats.stored_blocks += 1
@@ -221,6 +225,7 @@ class PageAllocator:
                 token_blocks=(tokens,),
             )
         )
+        return True
 
     def lookup(self, seq_hashes: Sequence[int]) -> list[int]:
         """Longest cached prefix: page ids for leading hashes present.

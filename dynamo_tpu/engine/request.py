@@ -79,6 +79,10 @@ class Request:
     state: RequestState = RequestState.WAITING
     output_tokens: list[int] = field(default_factory=list)
     pages: list[int] = field(default_factory=list)
+    #: leading blocks of `pages` that carry their content address: where
+    #: `JaxEngine._register_pages` resumes instead of walking from block 0
+    #: every step. Set wherever `pages` is assigned (admission).
+    registered_blocks: int = 0
     #: tokens whose KV is already in pages (prefix-cache hits + prefilled)
     num_computed_tokens: int = 0
     #: prompt tokens served from the prefix cache at admission

@@ -220,6 +220,11 @@ class Scheduler:
         pages (`schedule()` admits it), or one that has not arrived yet
         (a client that gets its last token sends its next prompt), whose
         first chunk must not wait behind a dispatch built without it.
+        Behind a mixed step (`prefill`) with nobody waiting the batch is
+        named all the same: one token a row is over before a client is
+        back, so no taker is lost by the launch, and the loop waits for
+        them under it (`AsyncEngineRunner._await_takers`) where it would
+        else meet them, or not, by the millisecond.
 
         Reads only replicated scheduler state, so every process of a
         multi-process mesh computes the same batch. Page growth of the
@@ -233,7 +238,8 @@ class Scheduler:
             ) and self.ends_within(p.request, 1):
                 gone.append(p.request)  # its first token is its last
         self._admit(gone=len(gone))
-        if gone and len(self.running) - len(gone) < self.config.max_seqs:
+        slots_left = len(self.running) - len(gone) < self.config.max_seqs
+        if gone and slots_left and (self.waiting or not prefill):
             return None
         after = _After(gone, computed)
         pieces = self._schedule_prefill(after)
@@ -355,6 +361,7 @@ class Scheduler:
                 self.allocator.free(cached_pages)
                 break
             req.pages = cached_pages + fresh
+            req.registered_blocks = len(cached_pages)  # looked up by hash
             req.num_cached_prompt_tokens = len(cached_pages) * ps
             req.num_computed_tokens = req.num_cached_prompt_tokens
             req.state = RequestState.PREFILL

@@ -88,6 +88,7 @@ _WORKER_FIELDS = (
     ("prefill_dispatches", "counter"),
     ("decode_dispatches", "counter"),
     ("mixed_dispatches", "counter"),
+    ("mixed_shared_rows", "counter"),
     ("overlap_dispatches", "counter"),
     ("overlap_hits", "counter"),
     ("overlap_rollbacks", "counter"),

@@ -27,7 +27,7 @@ import logging
 import math
 from dataclasses import dataclass, replace
 from functools import partial
-from typing import Any, NamedTuple, Optional
+from typing import Any, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -1803,24 +1803,59 @@ def _xla_history_attention(
 # ---------------------------------------------------------------------------
 
 
-def forward_hidden(
+class StepGroup(NamedTuple):
+    """One group of rows of a model step: requests that go through
+    attention together, with their own positions, validity and page
+    tables. Every program kind but `mixed` runs one group; the fused mixed
+    step runs two (a prompt chunk [B_pre, T], then the decode rows
+    [B_dec, 1]) so that the dense work of a layer reads its weights once
+    for both (`forward_groups`)."""
+
+    tokens: jax.Array  # [B, T] int32
+    positions: jax.Array  # [B, T] int32 absolute positions (padding: any)
+    valid: jax.Array  # [B, T] bool — which (b,t) are real tokens
+    page_tables: jax.Array  # [B, MP] int32
+    first_chunk: bool = False  # static: every row starts at position 0
+    rope_positions: Optional[jax.Array] = None  # [3,B,T] m-RoPE streams
+    mm_embeds: Optional[jax.Array] = None  # [B, T, H] multimodal embeds
+    mm_mask: Optional[jax.Array] = None  # [B, T] bool — use mm_embeds here
+
+
+def join_rows(xs: list) -> jax.Array:
+    """The groups' activations [B_g, T_g, ...] as what a layer's dense
+    work runs on: one group goes through as it is; several are flattened
+    to rows and concatenated, [sum(B_g * T_g), ...]."""
+    if len(xs) == 1:
+        return xs[0]
+    return jnp.concatenate([x.reshape(-1, *x.shape[2:]) for x in xs])
+
+
+def split_rows(x: jax.Array, groups) -> list:
+    """`join_rows` undone: each group's [B_g, T_g, ...] out of the rows."""
+    if len(groups) == 1:
+        return [x]
+    out, lo = [], 0
+    for g in groups:
+        b, t = g.tokens.shape
+        out.append(x[lo : lo + b * t].reshape(b, t, *x.shape[1:]))
+        lo += b * t
+    return out
+
+
+def forward_groups(
     params: dict,
     cfg: LlamaConfig,
-    tokens: jax.Array,  # [B, T] int32
-    positions: jax.Array,  # [B, T] int32 absolute positions (padding: any)
-    valid: jax.Array,  # [B, T] bool — which (b,t) are real tokens
+    groups: Sequence[StepGroup],
     kv: KVPages,
-    page_tables: jax.Array,  # [B, MP] int32
-    mm_embeds: Optional[jax.Array] = None,  # [B, T, H] multimodal embeds
-    mm_mask: Optional[jax.Array] = None,  # [B, T] bool — use mm_embeds here
-    first_chunk: bool = False,  # static: every row starts at position 0
     mesh=None,  # tp mesh: the Pallas kernels shard_map over it
-    rope_positions: Optional[jax.Array] = None,  # [3,B,T] m-RoPE streams
-) -> tuple[jax.Array, KVPages]:
-    """One model step over a token chunk; returns (hidden [B,T,H] post final
-    norm, new kv). The engine applies `compute_logits` only at the positions
-    it samples from — for a 512-token prefill chunk the full-chunk lm_head
-    matmul would otherwise dominate the step's FLOPs.
+) -> tuple[list, KVPages]:
+    """One model step over the groups' token chunks: ONE layer scan, in
+    which the norms, the projections and the FFN run on every group's
+    rows together (each weight is read once) and attention runs per group
+    (`attention_block`: its own rope, page walk or flash chunk, staging).
+    Returns ([hidden [B_g, T_g, H] post final norm per group], new kv).
+    Groups hold disjoint requests, so no group reads what another
+    writes in the step.
 
     Covers prefill (T = chunk), decode (T = 1), and prefix-cache continuation
     (positions start past 0) uniformly. Multimodal (llava-style) prompts
@@ -1838,9 +1873,15 @@ def forward_hidden(
     # metadata, so device time falls under a part of the model
     # (docs/observability.md).
     with jax.named_scope("embed"):
-        h = params["embed"][tokens].astype(cfg.dtype)  # [B,T,H]
-        if mm_embeds is not None:
-            h = jnp.where(mm_mask[..., None], mm_embeds.astype(cfg.dtype), h)
+        hs = []
+        for g in groups:
+            h = params["embed"][g.tokens].astype(cfg.dtype)  # [B,T,H]
+            if g.mm_embeds is not None:
+                h = jnp.where(
+                    g.mm_mask[..., None], g.mm_embeds.astype(cfg.dtype), h
+                )
+            hs.append(h)
+        h = join_rows(hs)
         if cfg.scale_embeddings:  # Gemma: normalizer cast to model dtype
             h = h * jnp.asarray(math.sqrt(cfg.hidden_size), cfg.dtype)
     off = cfg.rms_norm_unit_offset
@@ -1852,9 +1893,10 @@ def forward_hidden(
         raise ValueError(f"unknown hidden_act {cfg.hidden_act!r}")
 
     with jax.named_scope("attn"):
-        decode_work = maybe_decode_work(
-            cfg, tokens, positions, kv, page_tables
-        )
+        works = [
+            maybe_decode_work(cfg, g.tokens, g.positions, kv, g.page_tables)
+            for g in groups
+        ]
 
     def layer(carry, xs):
         h, kvc = carry
@@ -1862,25 +1904,31 @@ def forward_hidden(
         with jax.named_scope("attn"):
             with jax.named_scope("qkv"):
                 x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps, off)
-                b, t, _ = x.shape
                 q = _mm(x, lp, "wq", cfg.dtype)
                 k = _mm(x, lp, "wk", cfg.dtype)
                 v = _mm(x, lp, "wv", cfg.dtype)
                 if cfg.attention_bias:
                     q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-                q = q.reshape(b, t, cfg.num_heads, cfg.head_dim)
-                k = k.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-                v = v.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
+                lead = x.shape[:-1]
+                q = q.reshape(*lead, cfg.num_heads, cfg.head_dim)
+                k = k.reshape(*lead, cfg.num_kv_heads, cfg.head_dim)
+                v = v.reshape(*lead, cfg.num_kv_heads, cfg.head_dim)
                 if cfg.qk_norm:  # Qwen3: head_dim-wide RMSNorm pre-rope
                     q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps, off)
                     k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps, off)
-            attn, kvc, staged = attention_block(
-                q, k, v, kvc, li, page_tables, positions, valid, cfg,
-                first_chunk=first_chunk, mesh=mesh, decode_work=decode_work,
-                rope_positions=rope_positions,
-            )
+            attns, staged = [], []
+            for g, work, qg, kg, vg in zip(
+                groups, works, *(split_rows(a, groups) for a in (q, k, v))
+            ):
+                attn, kvc, st = attention_block(
+                    qg, kg, vg, kvc, li, g.page_tables, g.positions, g.valid,
+                    cfg, first_chunk=g.first_chunk, mesh=mesh,
+                    decode_work=work, rope_positions=g.rope_positions,
+                )
+                attns.append(attn)
+                staged.append(st)
             with jax.named_scope("out"):
-                attn_out = _mm(attn, lp, "wo", cfg.dtype)
+                attn_out = _mm(join_rows(attns), lp, "wo", cfg.dtype)
                 if cfg.post_block_norms:  # Gemma2: norm, then residual
                     attn_out = rms_norm(
                         attn_out, lp["post_attn_norm"], cfg.rms_norm_eps,
@@ -1899,7 +1947,7 @@ def forward_hidden(
                     mlp_out, lp["post_mlp_norm"], cfg.rms_norm_eps, off
                 )
             h = h + mlp_out
-        return (h, kvc), staged
+        return (h, kvc), tuple(staged)
 
     (h, kv_new), staged = lax.scan(
         layer,
@@ -1907,12 +1955,43 @@ def forward_hidden(
         (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)),
     )
     with jax.named_scope("attn"), jax.named_scope("kv_update"):
-        kv_new = land_staged_kv(
-            kv_new, staged, page_tables, positions, valid, mesh=mesh
-        )
+        for g, st in zip(groups, staged):
+            kv_new = land_staged_kv(
+                kv_new, st, g.page_tables, g.positions, g.valid, mesh=mesh
+            )
     with jax.named_scope("final_norm"):
         h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps, off)
-    return h, kv_new
+    return split_rows(h, groups), kv_new
+
+
+def forward_hidden(
+    params: dict,
+    cfg: LlamaConfig,
+    tokens: jax.Array,  # [B, T] int32
+    positions: jax.Array,  # [B, T] int32 absolute positions (padding: any)
+    valid: jax.Array,  # [B, T] bool — which (b,t) are real tokens
+    kv: KVPages,
+    page_tables: jax.Array,  # [B, MP] int32
+    mm_embeds: Optional[jax.Array] = None,  # [B, T, H] multimodal embeds
+    mm_mask: Optional[jax.Array] = None,  # [B, T] bool — use mm_embeds here
+    first_chunk: bool = False,  # static: every row starts at position 0
+    mesh=None,  # tp mesh: the Pallas kernels shard_map over it
+    rope_positions: Optional[jax.Array] = None,  # [3,B,T] m-RoPE streams
+) -> tuple[jax.Array, KVPages]:
+    """One model step over a token chunk, the one-group `forward_groups`;
+    returns (hidden [B,T,H] post final norm, new kv). The engine applies
+    `compute_logits` only at the positions it samples from — for a
+    512-token prefill chunk the full-chunk lm_head matmul would otherwise
+    dominate the step's FLOPs."""
+    (h,), kv = forward_groups(
+        params, cfg,
+        [StepGroup(
+            tokens, positions, valid, page_tables, first_chunk,
+            rope_positions, mm_embeds, mm_mask,
+        )],
+        kv, mesh=mesh,
+    )
+    return h, kv
 
 
 def land_staged_kv(

@@ -80,13 +80,16 @@ from jax import lax
 from dynamo_tpu.models.llama import (
     _PALLAS_DECODE_VMEM_BUDGET as _DECODE_VMEM_BUDGET,
     KVPages,
+    StepGroup,
     _mm,
     _w,
+    join_rows,
     maybe_decode_work,
     paged_gather,
     paged_scatter,
     quantize_channelwise_int8,
     rms_norm,
+    split_rows,
 )
 
 #: weight names quantized by quantize_params_int8 / init_params_int8
@@ -755,28 +758,29 @@ def _latent_prefill_attention(
 
 
 def mla_attention(
-    x: jax.Array,  # [B, T, H'] post-attn-norm
+    x: jax.Array,  # the groups' rows (llama.join_rows), post-attn-norm
     lp: dict,
     cfg: MlaConfig,
     kv: tuple,  # (k_cache, v_cache) full stacked
     layer: jax.Array,
-    page_tables: jax.Array,
-    positions: jax.Array,
-    valid: jax.Array,
-    first_chunk: bool = False,  # static: every row starts at position 0
-    decode_work=None,  # ops.paged_attention.decode_work_list, layer-invariant
+    groups,  # llama.StepGroup, one or two
+    works,  # per group: ops.paged_attention.decode_work_list or None
     mesh=None,
 ):
-    """Returns (attn [B, T, H'], k_cache, v_cache, staged): `staged` is
-    None under the xla discipline (the caches come back written) and the
+    """Returns (attn, k_cache, v_cache, staged), `attn` shaped like `x`.
+    Every projection (q, the latent, the absorbed query, the value
+    up-projection, `wo`) runs on all the groups' rows at once; rope, the
+    cache write or staging and attention itself run per group, each with
+    its own positions and page tables. `staged` holds, per group, None
+    under the xla discipline (the caches come back written) and the
     chunk's (latent, rope key) rows under the kernels (the caches come
     back as they went in). Scopes, under the caller's `attn`: `qkv`,
-    `kv_update`, `absorb`, `paged` (reads cache pages: the decode walk),
-    `flash` (a prefill chunk, with or without history), `out`."""
-    b, t, _ = x.shape
-    hn, r, c = cfg.num_heads, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    `kv_update`, `absorb`, `paged` (reads cache pages: the decode walk,
+    and the xla discipline's gather), `flash` (a prefill chunk under the
+    kernels, with or without history), `out`."""
+    hn, c = cfg.num_heads, cfg.kv_lora_rank
     n, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
-    k_cache, v_cache = kv
+    lead = x.shape[:-1]
 
     with jax.named_scope("qkv"):
         if cfg.q_lora_rank:
@@ -784,94 +788,96 @@ def mla_attention(
                 _mm(x, lp, "wq_a", cfg.dtype).astype(cfg.dtype),
                 lp["q_a_norm"], cfg.rms_norm_eps,
             )
-            q = _mm(qa, lp, "wq_b", cfg.dtype).reshape(
-                b, t, hn, cfg.qk_head_dim
-            )
+            q = _mm(qa, lp, "wq_b", cfg.dtype)
         else:
-            q = _mm(x, lp, "wq", cfg.dtype).reshape(
-                b, t, hn, cfg.qk_head_dim
-            )
-        q_nope, q_pe = q[..., :n], q[..., n:]
-        q_pe = _interleaved_rope(q_pe, positions, cfg)
-
-        kv_a = _mm(x, lp, "wkv_a", cfg.dtype)  # [B,T,c+r]
+            q = _mm(x, lp, "wq", cfg.dtype)
+        q = q.reshape(*lead, hn, cfg.qk_head_dim)
+        kv_a = _mm(x, lp, "wkv_a", cfg.dtype)  # [..., c+r]
         c_kv = rms_norm(
             kv_a[..., :c].astype(cfg.dtype), lp["kv_a_norm"],
             cfg.rms_norm_eps,
         )
-        k_pe = _interleaved_rope(kv_a[..., c:], positions, cfg).astype(
-            cfg.dtype
+
+    # operands in the model dtype under the kernels, float32 under xla;
+    # float32 accumulation in both
+    wdt = cfg.dtype if cfg.kernels else jnp.float32
+    wkv_b = _w(lp, "wkv_b", wdt).reshape(c, hn, n + vd)
+    w_uk, w_uv = wkv_b[..., :n], wkv_b[..., n:]
+    with jax.named_scope("absorb"):
+        q_lat = jnp.einsum(
+            "...hn,chn->...hc", q[..., :n].astype(wdt), w_uk,
+            preferred_element_type=jnp.float32,
         )
 
-    if cfg.kernels:
-        return _mla_attention_kernels(
-            q_nope, q_pe, c_kv, k_pe, lp, cfg, kv, layer, page_tables,
-            positions, valid, first_chunk, decode_work, mesh,
-        )
+    attend = _attend_kernels if cfg.kernels else _attend_xla
+    o_lats, staged = [], []
+    parts = (q_lat, q[..., n:], c_kv, kv_a[..., c:])
+    for g, work, ql, qp, ck, kp in zip(
+        groups, works, *(split_rows(a, groups) for a in parts)
+    ):
+        with jax.named_scope("qkv"):
+            qp = _interleaved_rope(qp, g.positions, cfg)
+            kp = _interleaved_rope(kp, g.positions, cfg).astype(cfg.dtype)
+        o_lat, kv, st = attend(ql, qp, ck, kp, cfg, kv, layer, g, work, mesh)
+        o_lats.append(o_lat)
+        staged.append(st)
 
-    # Land this chunk's latent + rope key, then attend over the gathered
-    # (history + current) cache — same scatter-then-gather discipline as
-    # the Llama XLA path, so causality is pure position masking.
+    with jax.named_scope("out"):
+        out = jnp.einsum(
+            "...hc,chv->...hv", join_rows(o_lats).astype(wdt), w_uv,
+            preferred_element_type=jnp.float32,
+        )
+        out = out.reshape(*lead, hn * vd).astype(cfg.dtype)
+        return _mm(out, lp, "wo", cfg.dtype), *kv, tuple(staged)
+
+
+def _attend_xla(q_lat, q_pe, c_kv, k_pe, cfg: MlaConfig, kv, layer, g,
+                work, mesh):
+    """One group under the xla discipline: land the chunk's latent and
+    rope key, then attend over the gathered (history + current) cache:
+    the same scatter-then-gather as the Llama XLA path, so causality is
+    pure position masking. Returns (o_lat [B, T, H, c] f32, the caches
+    written, None)."""
+    k_cache, v_cache = kv
     with jax.named_scope("kv_update"):
         k_cache = paged_scatter(
-            k_cache, layer, c_kv[:, :, None, :], page_tables, positions,
-            valid,
+            k_cache, layer, c_kv[:, :, None, :], g.page_tables, g.positions,
+            g.valid,
         )
         v_cache = paged_scatter(
-            v_cache, layer, k_pe[:, :, None, :], page_tables, positions,
-            valid,
+            v_cache, layer, k_pe[:, :, None, :], g.page_tables, g.positions,
+            g.valid,
         )
     with jax.named_scope("paged"):
-        c_hist = paged_gather(k_cache, layer, page_tables)[:, :, 0]  # [B,K,c]
-        pe_hist = paged_gather(v_cache, layer, page_tables)[:, :, 0]  # [B,K,r]
-
-        wkv_b = _w(lp, "wkv_b", jnp.float32).reshape(c, hn, n + vd)
-        w_uk, w_uv = wkv_b[..., :n], wkv_b[..., n:]
-
-        scale = cfg.softmax_scale
-        q_lat = jnp.einsum(
-            "bthn,chn->bthc", q_nope.astype(jnp.float32), w_uk
-        )
+        # [B, K, c] and [B, K, r]
+        c_hist = paged_gather(k_cache, layer, g.page_tables)[:, :, 0]
+        pe_hist = paged_gather(v_cache, layer, g.page_tables)[:, :, 0]
         scores = (
             jnp.einsum("bthc,bkc->bhtk", q_lat, c_hist.astype(jnp.float32))
             + jnp.einsum(
                 "bthr,bkr->bhtk", q_pe.astype(jnp.float32),
                 pe_hist.astype(jnp.float32),
             )
-        ) * scale
+        ) * cfg.softmax_scale
         kk = c_hist.shape[1]
         key_pos = jnp.arange(kk)[None, None, None, :]
-        mask = key_pos <= positions[:, None, :, None]
+        mask = key_pos <= g.positions[:, None, :, None]
         scores = jnp.where(mask, scores, _MASKED)
         probs = jax.nn.softmax(scores, axis=-1)
         o_lat = jnp.einsum(
             "bhtk,bkc->bthc", probs, c_hist.astype(jnp.float32)
         )
-    with jax.named_scope("out"):
-        out = jnp.einsum("bthc,chv->bthv", o_lat, w_uv)
-        out = out.reshape(b, t, hn * vd).astype(cfg.dtype)
-        return _mm(out, lp, "wo", cfg.dtype), k_cache, v_cache, None
+    return o_lat, (k_cache, v_cache), None
 
 
-def _mla_attention_kernels(
-    q_nope, q_pe, c_kv, k_pe, lp, cfg: MlaConfig, kv, layer, page_tables,
-    positions, valid, first_chunk, decode_work, mesh,
-):
-    """The kernels' discipline (module text): the cache is read, never
-    written here; the chunk's rows come back as `staged`."""
-    b, t, hn, n = q_nope.shape
-    c, vd = cfg.kv_lora_rank, cfg.v_head_dim
+def _attend_kernels(q_lat, q_pe, c_kv, k_pe, cfg: MlaConfig, kv, layer, g,
+                    work, mesh):
+    """One group under the kernels' discipline (module text): the cache
+    is read, never written here; the chunk's rows come back as `staged`.
+    Returns (o_lat [B, T, H, c] f32, the caches as they came, staged)."""
     k_cache, v_cache = kv
-    wkv_b = _w(lp, "wkv_b", cfg.dtype).reshape(c, hn, n + vd)
-    w_uk, w_uv = wkv_b[..., :n], wkv_b[..., n:]
     pe_rows = _pad_last(k_pe, cfg.kv_rope_dim)  # the rope key as cached
-
-    with jax.named_scope("absorb"):
-        q_lat = jnp.einsum(
-            "bthn,chn->bthc", q_nope, w_uk,
-            preferred_element_type=jnp.float32,
-        )
-    if t == 1:
+    if q_lat.shape[1] == 1:
         with jax.named_scope("paged"):
             qd = jnp.concatenate(
                 [q_lat.astype(cfg.dtype), _pad_last(q_pe, cfg.kv_rope_dim)],
@@ -879,23 +885,15 @@ def _mla_attention_kernels(
             )[:, 0]
             o_lat = _latent_decode(
                 qd, c_kv[:, 0], pe_rows[:, 0], k_cache, v_cache, layer,
-                page_tables, positions[:, 0], cfg, decode_work, mesh,
+                g.page_tables, g.positions[:, 0], cfg, work, mesh,
             )[:, None]
     else:
         with jax.named_scope("flash"):
             o_lat = _latent_prefill_attention(
                 q_lat, q_pe, c_kv, k_pe, k_cache, v_cache, layer,
-                page_tables, positions, valid, cfg, first_chunk,
+                g.page_tables, g.positions, g.valid, cfg, g.first_chunk,
             )
-    with jax.named_scope("out"):
-        out = jnp.einsum(
-            "bthc,chv->bthv", o_lat.astype(cfg.dtype), w_uv,
-            preferred_element_type=jnp.float32,
-        )
-        out = out.reshape(b, t, hn * vd).astype(cfg.dtype)
-        attn = _mm(out, lp, "wo", cfg.dtype)
-    staged = (c_kv[:, :, None, :], pe_rows[:, :, None, :])
-    return attn, k_cache, v_cache, staged
+    return o_lat, kv, (c_kv[:, :, None, :], pe_rows[:, :, None, :])
 
 
 # ---------------------------------------------------------------------------
@@ -1023,8 +1021,8 @@ def _deepseek_moe_ffn(
     """Scopes (under the caller's `mlp`): `moe/route` (gate, top-k, the
     sort by expert and the weighted un-sort), `moe/experts` (the grouped
     matmuls), `moe/shared`."""
-    b, t, h = x.shape
-    xf = x.reshape(b * t, h)
+    h = x.shape[-1]
+    xf = x.reshape(-1, h)  # [B, T, H] of one group or the joined rows
     with jax.named_scope("moe"):
         with jax.named_scope("route"):
             topw, topi = _gate(xf, lp, cfg)
@@ -1039,7 +1037,7 @@ def _deepseek_moe_ffn(
                 .astype(cfg.dtype),
                 lp, "ws_down", cfg.dtype,
             )
-        return (routed.astype(cfg.dtype) + shared).reshape(b, t, h)
+        return (routed.astype(cfg.dtype) + shared).reshape(x.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -1047,20 +1045,19 @@ def _deepseek_moe_ffn(
 # ---------------------------------------------------------------------------
 
 
-def forward_hidden(
+def forward_groups(
     params: dict,
     cfg: MlaConfig,
-    tokens: jax.Array,
-    positions: jax.Array,
-    valid: jax.Array,
+    groups,  # llama.StepGroup, one or two
     kv: KVPages,
-    page_tables: jax.Array,
-    mm_embeds: Optional[jax.Array] = None,
-    mm_mask: Optional[jax.Array] = None,
-    first_chunk: bool = False,
     mesh=None,
-) -> tuple[jax.Array, KVPages]:
-    if mm_embeds is not None:
+) -> tuple[list, KVPages]:
+    """models/llama.py's `forward_groups` for this family: one pass over
+    the layers in which the projections, the gate, the dropless dispatch,
+    the grouped expert matmuls and the shared experts run on every group's
+    rows together, attention per group (`mla_attention`). Returns ([hidden
+    [B_g, T_g, H] post final norm per group], new kv)."""
+    if any(g.mm_embeds is not None for g in groups):
         raise ValueError("multimodal prompts are not supported for MLA yet")
     # named scopes as models/llama.py's (embed, attn[/qkv, /kv_update,
     # /absorb, /paged or /flash, /out], mlp[/moe/route, /moe/experts,
@@ -1068,11 +1065,14 @@ def forward_hidden(
     # trace carries them in each operation's metadata
     # (docs/observability.md)
     with jax.named_scope("embed"):
-        h = params["embed"][tokens].astype(cfg.dtype)
-    with jax.named_scope("attn"):
-        decode_work = maybe_decode_work(
-            cfg, tokens, positions, kv, page_tables
+        h = join_rows(
+            [params["embed"][g.tokens].astype(cfg.dtype) for g in groups]
         )
+    with jax.named_scope("attn"):
+        works = [
+            maybe_decode_work(cfg, g.tokens, g.positions, kv, g.page_tables)
+            for g in groups
+        ]
 
     def dense_ffn(x, lp):
         gate = jax.nn.silu(_mm(x, lp, "w_gate", cfg.dtype).astype(jnp.float32))
@@ -1086,9 +1086,7 @@ def forward_hidden(
             with jax.named_scope("attn"):
                 x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
                 attn, kc, vc, staged = mla_attention(
-                    x, lp, cfg, (kc, vc), li, page_tables, positions, valid,
-                    first_chunk=first_chunk, decode_work=decode_work,
-                    mesh=mesh,
+                    x, lp, cfg, (kc, vc), li, groups, works, mesh=mesh
                 )
                 h = h + attn
             with jax.named_scope("mlp"):
@@ -1128,22 +1126,48 @@ def forward_hidden(
             staged.append(st)
     h, k_cache, v_cache = carry
     if cfg.kernels:
-        # the whole step's rows, every layer, in one write (the cache is
-        # one shared row a token: under a tp mesh it replicates, and the
-        # head-sharded DMA kernel gives way to the XLA scatter)
+        # each group's rows of the whole step, every layer, in one write
+        # (the cache is one shared row a token: under a tp mesh it
+        # replicates, and the head-sharded DMA kernel gives way to the XLA
+        # scatter)
         from dynamo_tpu.ops.kv_update import paged_write
 
         with jax.named_scope("attn"), jax.named_scope("kv_update"):
-            k_cache, v_cache = paged_write(
-                k_cache, v_cache,
-                jnp.concatenate([st[0] for st in staged]),
-                jnp.concatenate([st[1] for st in staged]),
-                page_tables, positions, valid,
-                use_kernel=None if mesh is None else False,
-            )
+            for i, g in enumerate(groups):
+                k_cache, v_cache = paged_write(
+                    k_cache, v_cache,
+                    jnp.concatenate([st[i][0] for st in staged]),
+                    jnp.concatenate([st[i][1] for st in staged]),
+                    g.page_tables, g.positions, g.valid,
+                    use_kernel=None if mesh is None else False,
+                )
     with jax.named_scope("final_norm"):
         h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-    return h, KVPages(k=k_cache, v=v_cache)
+    return split_rows(h, groups), KVPages(k=k_cache, v=v_cache)
+
+
+def forward_hidden(
+    params: dict,
+    cfg: MlaConfig,
+    tokens: jax.Array,
+    positions: jax.Array,
+    valid: jax.Array,
+    kv: KVPages,
+    page_tables: jax.Array,
+    mm_embeds: Optional[jax.Array] = None,
+    mm_mask: Optional[jax.Array] = None,
+    first_chunk: bool = False,
+    mesh=None,
+) -> tuple[jax.Array, KVPages]:
+    (h,), kv = forward_groups(
+        params, cfg,
+        [StepGroup(
+            tokens, positions, valid, page_tables, first_chunk,
+            mm_embeds=mm_embeds, mm_mask=mm_mask,
+        )],
+        kv, mesh=mesh,
+    )
+    return h, kv
 
 
 def compute_logits(params: dict, cfg: MlaConfig, hidden: jax.Array):

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from dynamo_tpu.models import llama as llama_mod
 from dynamo_tpu.models import qwen2vl as qwen2vl_mod
-from dynamo_tpu.models.llama import KVPages, LlamaConfig
+from dynamo_tpu.models.llama import KVPages, LlamaConfig, StepGroup
 
 logger = logging.getLogger(__name__)
 
@@ -32,6 +32,12 @@ class ModelAdapter:
     init_params: Callable[[jax.Array], Any]
     forward: Callable[..., tuple[jax.Array, KVPages]]  # (params, tokens, positions, valid, kv, pt) -> (logits, kv)
     forward_hidden: Callable[..., tuple[jax.Array, KVPages]]  # same in, (hidden, kv) out
+    #: the fused mixed step's model pass: (params, prompt, decode, kv,
+    #: first_chunk=False) -> (hidden_p, hidden_d, kv), `prompt` and
+    #: `decode` each (tokens, positions, valid, page_tables). llama and
+    #: mla read every weight once for both (`_one_pass_mixed`); moe runs
+    #: its two `forward_hidden` passes (`_two_pass_mixed`)
+    forward_hidden_mixed: Callable[..., tuple]
     compute_logits: Callable[[Any, jax.Array], jax.Array]  # (params, hidden) -> logits
     #: (num_pages, page_size, kv_quantize=None) -> KVPages; families
     #: without quantized pages raise on kv_quantize != None
@@ -126,6 +132,40 @@ _LLAMA_PRESETS.update(
 )
 
 
+def _two_pass_mixed(forward_hidden):
+    """`ModelAdapter.forward_hidden_mixed` of a family with no two-group
+    layer body: two `forward_hidden` passes back to back, prompt first.
+    models/moe.py keeps it for a reason of its own: its expert dispatch
+    has a capacity that follows the row count, so which rows overflow an
+    expert would change if the groups shared a pass."""
+
+    def forward_hidden_mixed(params, prompt, decode, kv, first_chunk=False):
+        h_p, kv = forward_hidden(
+            params, *prompt[:3], kv, prompt[3], first_chunk=first_chunk
+        )
+        h_d, kv = forward_hidden(params, *decode[:3], kv, decode[3])
+        return h_p, h_d, kv
+
+    return forward_hidden_mixed
+
+
+def _one_pass_mixed(forward_groups, cfg, mesh):
+    """`ModelAdapter.forward_hidden_mixed` of a family whose layer body
+    takes groups of rows (`forward_groups` of models/llama.py, models/
+    mla.py): the prompt chunk and the decode rows through ONE layer scan,
+    prompt first."""
+
+    def forward_hidden_mixed(params, prompt, decode, kv, first_chunk=False):
+        (h_p, h_d), kv = forward_groups(
+            params, cfg,
+            [StepGroup(*prompt, first_chunk=first_chunk), StepGroup(*decode)],
+            kv, mesh=mesh,
+        )
+        return h_p, h_d, kv
+
+    return forward_hidden_mixed
+
+
 def _llama_adapter(
     name: str, cfg: LlamaConfig, mesh=None
 ) -> ModelAdapter:
@@ -166,6 +206,9 @@ def _llama_adapter(
         quantize_params=llama_mod.quantize_params_int8,
         init_params_quantized=lambda key: llama_mod.init_params_int8(
             key, cfg
+        ),
+        forward_hidden_mixed=_one_pass_mixed(
+            llama_mod.forward_groups, cfg, mesh
         ),
     )
 
@@ -244,6 +287,9 @@ def _mla_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
         init_params_quantized=lambda key: mla_mod.init_params_int8(
             key, cfg
         ),
+        forward_hidden_mixed=_one_pass_mixed(
+            mla_mod.forward_groups, cfg, mesh
+        ),
     )
 
 
@@ -276,6 +322,7 @@ def _moe_adapter(name: str, moe_cfg, mesh=None) -> ModelAdapter:
         init_params=lambda key: moe_mod.init_params(key, cfg),
         forward=fwd,
         forward_hidden=fwd_hidden,
+        forward_hidden_mixed=_two_pass_mixed(fwd_hidden),
         compute_logits=lambda params, h: llama_mod.compute_logits(
             params, cfg.base, h
         ),

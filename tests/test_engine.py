@@ -114,6 +114,39 @@ def test_prefix_cache_hit_same_output(engine_factory):
     assert cold.run_to_completion()["p3"] == first
 
 
+def test_register_pages_resumes_where_it_stopped(engine_factory):
+    """`_register_pages` runs for every row of every dispatch: it offers
+    each filled block to the allocator once, not all of them from block 0
+    each step (ISSUE 30: ~4,100 ctypes calls a dispatch at 64 rows of
+    4k-token contexts), and a request that is preempted and re-admitted
+    starts over on its new pages."""
+    eng = engine_factory(max_pages_per_seq=16, num_pages=128, decode_steps=1)
+    ps = eng.config.page_size
+    calls = []
+    register = eng.allocator.register
+    eng.allocator.register = lambda page, *a: (
+        calls.append(page), register(page, *a))[1]
+    prompt = [int(x) for x in np.random.default_rng(3).integers(1, 200, 21)]
+    eng.add_request("r", prompt, _greedy(24))
+    req = eng.scheduler.waiting[0]
+    while len(req.output_tokens) < 12:
+        eng.step()
+    blocks = req.num_computed_tokens // ps
+    assert req.registered_blocks == blocks >= 7
+    assert len(calls) == blocks  # once a block, a dozen dispatches in
+
+    assert eng.scheduler._preempt_youngest(excluding=None)
+    eng.allocator.clear_cache()  # its old pages lose their addresses
+    del calls[:]
+    eng.run_to_completion()
+    # the last token is never computed, and the step that ends a request
+    # releases its pages before it would register them
+    full = (len(prompt) + 24 - 2) // ps
+    assert req.registered_blocks == full
+    assert len(calls) == len(set(calls)) == full
+    assert eng.allocator.stats.stored_blocks == blocks + full
+
+
 def test_eos_stops_generation(engine_factory):
     eng = engine_factory()
     eng.add_request("r", [5, 17, 42, 99, 3], _greedy(6))

@@ -476,3 +476,148 @@ def test_small_pool_falls_back(engine_factory):
     got, early, infl, m = run(True)
     assert early == ["keep"] and infl is None
     assert got == ref and m.overlap_rollbacks == 0 and m.overlap_hits > 0
+
+
+# -- takers of free slots (PR 30: a step that ends about when a client is
+# back must not decide the batches by the millisecond) --------------------
+
+
+def test_launch_ahead_behind_a_mixed_step_with_a_leaver(engine_factory):
+    """A row ends in a fused mixed step and nobody waits for its slot:
+    the next decode dispatch is launched ahead all the same (the rows
+    that stay and the prompt that joined), so the device stays covered
+    while the runner waits for the slot's taker; a pure decode dispatch
+    with a leaver still launches nothing. Streams are the synchronous
+    engine's."""
+
+    def run(overlap):
+        eng = engine_factory(overlap_decode=overlap, decode_steps=1, max_seqs=3)
+        for rid, prompt, n in (("a", [1, 2, 3], 2), ("short", [4, 5, 6], 3),
+                               ("long", [7, 8, 9, 1], 12)):
+            eng.add_request(
+                rid, prompt, SamplingParams(max_tokens=n, ignore_eos=True))
+        out, seen = {}, []
+        for step in range(3):
+            for o in eng.step():
+                out.setdefault(o.request_id, []).extend(o.new_token_ids)
+            infl = eng._inflight
+            seen.append(None if infl is None else
+                        tuple(r.request_id for r in infl.reqs))
+            if step == 1:  # `a` is gone, its slot free, nobody waited
+                eng.add_request(
+                    "new", [2, 7, 1, 8],
+                    SamplingParams(max_tokens=5, ignore_eos=True))
+        while eng.has_work:
+            for o in eng.step():
+                out.setdefault(o.request_id, []).extend(o.new_token_ids)
+        return out, seen, eng.metrics
+
+    ref, _, _ = run(False)
+    got, seen, m = run(True)
+    assert got == ref
+    # after the decode step in which `a` ended: nothing launched ahead;
+    # after the mixed step in which `short` ended: long and new are
+    assert seen[1] is None and seen[2] == ("long", "new")
+    assert m.mixed_dispatches >= 1 and m.overlap_rollbacks == 0
+
+
+class _Landed:
+    def __init__(self, ready):
+        self.ready = ready
+
+    def is_ready(self):
+        return self.ready
+
+
+def test_takers_wait_s(engine_factory):
+    """An allowance only while a fused scan launched ahead is still on
+    the device and a free slot has no taker: 3/4 of a decode dispatch."""
+    import dataclasses
+
+    eng = engine_factory(overlap_decode=True, decode_steps=8, max_seqs=4)
+    for rid, prompt in (("a", [1, 2, 3]), ("b", [4, 5, 6, 7])):
+        eng.add_request(rid, prompt, SamplingParams(max_tokens=40, ignore_eos=True))
+    assert eng.takers_wait_s() == 0.0  # nothing launched
+    while eng._inflight is None or eng._inflight.k_steps < 2:
+        eng.step()
+    assert eng._decode_wall_s > 0.0  # a decode dispatch was timed
+    infl = eng._inflight
+    eng._inflight = dataclasses.replace(infl, token_ids=_Landed(False))
+    eng._decode_wall_s = 0.1
+    assert eng.takers_wait_s() == pytest.approx(0.075)  # two slots free
+    assert eng.takers_wait_s(queued=1) == pytest.approx(0.075)
+    assert eng.takers_wait_s(queued=2) == 0.0  # both takers are in
+    eng.add_request("c", [9, 9], SamplingParams(max_tokens=4))
+    assert eng.takers_wait_s(queued=1) == 0.0  # c waits for the other
+    eng._inflight = dataclasses.replace(infl, token_ids=_Landed(True))
+    assert eng.takers_wait_s() == 0.0  # landed: the device would idle
+    eng._inflight = dataclasses.replace(
+        infl, token_ids=_Landed(False), k_steps=1)
+    assert eng.takers_wait_s() == 0.0  # one token: over too soon
+    eng._inflight = infl
+    # a dispatch read late does not pass for a short one
+    eng._decode_wall_s = 0.1
+    eng.step()
+    assert eng._decode_wall_s >= 0.09
+    eng.run_to_completion()
+
+
+@pytest.mark.parametrize(
+    "room, queued, arrives_ms, lands_ms, allowed, waits",
+    [
+        (0, 0, None, None, 1.0, 0.0),   # no free slot, or nothing ahead
+        (1, 1, None, None, 1.0, 0.0),   # the taker is in the inbox
+        (1, 0, None, None, 1.0, 0.2),   # nobody comes: the cap
+        (2, 1, None, None, 1.0, 0.2),   # one of two takers: the cap
+        (1, 0, None, None, 0.08, 0.08),  # the engine allows less
+        (1, 0, 15, None, 1.0, 0.0),     # the taker arrives: at once
+        (1, 0, None, 15, 1.0, 0.0),     # the dispatch lands: at once
+    ],
+    ids=["no-room", "taker-queued", "cap", "one-of-two", "allowance",
+         "arrival", "landed"],
+)
+def test_runner_awaits_takers(monkeypatch, room, queued, arrives_ms,
+                              lands_ms, allowed, waits):
+    """The runner holds a step back only for takers of free slots under
+    a dispatch launched ahead, no longer than the engine allows and no
+    longer than TAKERS_WAIT_S."""
+    import threading
+    import time
+
+    from dynamo_tpu.engine import async_engine
+
+    monkeypatch.setattr(async_engine, "TAKERS_WAIT_S", 0.2)
+    state = {"room": room}
+
+    class Eng:
+        def takers_wait_s(self, queued):
+            return allowed if state["room"] > queued else 0.0
+
+    runner = async_engine.AsyncEngineRunner(Eng())
+    runner._pending = [("req", None)] * queued
+
+    def later(ms, fn):
+        def go():
+            time.sleep(ms / 1000.0)
+            fn()
+            runner._wake.set()
+        threading.Thread(target=go, daemon=True).start()
+
+    if arrives_ms is not None:
+        def arrive():
+            with runner._lock:
+                runner._pending.append(("req", None))
+        later(arrives_ms, arrive)
+    if lands_ms is not None:
+        later(lands_ms, lambda: state.update(room=0))
+    t = time.perf_counter()
+    runner._await_takers()
+    dt = time.perf_counter() - t
+    if waits:
+        assert waits - 0.02 <= dt < waits + 0.5
+    else:
+        assert dt < 0.07
+    # an engine without the hook (a test double) is never waited on
+    t = time.perf_counter()
+    async_engine.AsyncEngineRunner(object())._await_takers()
+    assert time.perf_counter() - t < 0.05

@@ -148,8 +148,9 @@ def test_native_python_parity_fuzz(monkeypatch):
             h = hashes[next_hash % len(hashes)] + next_hash
             next_hash += 1
             toks = tuple(rng.randrange(100) for _ in range(4))
-            a.register(held_a[i][j], h, None, toks)
-            b.register(held_b[i][j], h, None, toks)
+            assert a.register(held_a[i][j], h, None, toks) == b.register(
+                held_b[i][j], h, None, toks
+            ), f"step {step}"
         elif op < 0.9:  # lookup a random chain
             k = rng.randrange(1, 6)
             chain = [hashes[rng.randrange(len(hashes))] for _ in range(k)]
@@ -171,3 +172,31 @@ def test_native_python_parity_fuzz(monkeypatch):
     assert a.num_free == b.num_free
     assert a.clear_cache() == b.clear_cache()
     assert a.num_free == 32
+
+
+@pytest.mark.parametrize("backend", ["native", "python"])
+def test_register_says_whether_the_page_is_addressed(backend, monkeypatch):
+    """What the engine's resumed walk (`Request.registered_blocks`) rests
+    on: True = the page carries a registration (new, or from before: no
+    second event), False = its content is cached under another page and
+    the page may be offered again once that one is evicted."""
+    events = []
+    if backend == "python":
+        a = _forced_python_allocator(
+            monkeypatch, num_pages=4, page_size=2, on_event=events.append
+        )
+    else:
+        from dynamo_tpu.native import ensure_built
+
+        if ensure_built() is None:
+            pytest.skip("native library unavailable")
+        a = PageAllocator(num_pages=4, page_size=2, on_event=events.append)
+    p1, p2 = a.allocate(2)
+    assert a.register(p1, 7, None, (1, 2)) is True
+    assert a.register(p1, 7, None, (1, 2)) is True
+    assert a.register(p2, 7, None, (1, 2)) is False
+    assert [e.kind for e in events] == ["stored"]
+    a.free([p1])
+    a.clear_cache()  # p1's registration goes
+    assert a.register(p2, 7, None, (1, 2)) is True
+    assert [e.kind for e in events] == ["stored", "removed", "stored"]

@@ -309,6 +309,32 @@ def test_next_batch_unknown_where_a_freed_slot_stays_empty():
     assert s.schedule().decode == (long_,)
 
 
+def test_next_batch_behind_a_mixed_step_is_named_with_a_slot_left_empty():
+    """A row ends in a MIXED step and nobody waits for its slot: the
+    batch is named all the same (the rows that stay, the prompt that
+    joins): one token a row is over before a client is back, and the
+    runner waits for the takers under the dispatch launched ahead
+    (`AsyncEngineRunner._await_takers`). The batch schedule() returns
+    once the step is read is that batch."""
+    s, _ = _sched(max_seqs=3, num_pages=16)
+    short, long_ = _req("short", 6, 3), _req("long", 6, 9)
+    for r in (short, long_):
+        s.add_request(r)
+    _apply(s.schedule(), s)
+    _apply(s.schedule(), s)
+    new = _req("new", 4, 5)
+    s.add_request(new)
+    batch = s.schedule()
+    assert batch.kind == "mixed" and batch.prefill[0].request is new
+    assert s.ends_within(short, 1)
+    # a pure decode step with the same leaver names nothing
+    assert s.next_batch(batch.decode, 1) is None
+    ahead = s.next_batch(batch.decode, 1, batch.prefill)
+    assert ahead.kind == "decode" and ahead.decode == (long_, new)
+    _apply(batch, s)
+    assert _same(s.schedule(), ahead)
+
+
 def test_next_batch_first_token_is_the_last():
     """A prompt whose first token is its whole budget never joins the
     decode rows, and frees its slot for the request behind it."""
