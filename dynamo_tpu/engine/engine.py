@@ -27,6 +27,7 @@ PartitionSpecs and the same jitted programs run SPMD over the mesh.
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import threading
@@ -251,6 +252,21 @@ class EngineMetrics:
     #: the doctor's host-skew rule compares across hosts
     host: int = 0
     dispatch_p95_ms: float = 0.0
+    #: recurrent-state plane (a model with state-space layers; all 0
+    #: otherwise; docs/observability.md): slots of the state pool and the
+    #: high watermark of slots held, the pool's device bytes (both
+    #: generations), admissions that took a slot (each starts its sequence
+    #: from zeros), rollbacks of a dispatch launched ahead that had
+    #: advanced the state of rows still alive (their slots read as the last
+    #: dispatch taken left them: `JaxEngine._discard_inflight`), and
+    #: prefix-cache hits refused because pages without the state at their
+    #: boundary cannot be used
+    state_slots: int = 0
+    state_slots_live: int = 0
+    state_pool_bytes: int = 0
+    state_resets: int = 0
+    state_restores: int = 0
+    prefix_hits_refused_state: int = 0
 
     #: the timing plane's field names — the one list consumers (perf
     #: harness, dashboards) should iterate instead of restating
@@ -308,6 +324,19 @@ def phase(metrics, name: str, *fields: str, **args) -> _Phase:
     and their counters). `metrics` is an EngineMetrics, or None for an
     engine double that keeps none."""
     return _Phase(metrics, name, fields, args)
+
+
+def _pages_only(method):
+    """A method of the page-movement surface (disagg transfer planes,
+    handover, KV tiers): refused for a model whose sequences also keep a
+    recurrent state (`JaxEngine._refuse_state_transfer`)."""
+
+    @functools.wraps(method)
+    def guarded(self, *args, **kwargs):
+        self._refuse_state_transfer(method.__name__)
+        return method(self, *args, **kwargs)
+
+    return guarded
 
 
 @dataclass
@@ -424,6 +453,18 @@ class JaxEngine:
         acfg = self.adapter.config
         if not hasattr(acfg, "num_heads"):
             acfg = acfg.base
+        #: a model with state-space layers keeps a recurrent state a
+        #: sequence in a slot pool beside the pages (models/nemotron_h.py);
+        #: slots for every running sequence plus an eighth more, so that a
+        #: successor admitted ahead of a row certain to end (`next_batch`)
+        #: finds one while the leaver still holds its own
+        self._stateful = self.adapter.state_layers > 0
+        self._state_slots = (
+            config.max_seqs + max(1, config.max_seqs // 8)
+            if self._stateful else 0
+        )
+        if self._stateful:
+            self._refuse_for_state(config)
         if mc.tp > 1:
             # MLA's shared-latent cache replicates over tp (the q heads
             # still shard) — only head-sharded caches need kv divisibility.
@@ -487,10 +528,14 @@ class JaxEngine:
             )
         else:
             self.allocator = PageAllocator(
-                config.num_pages, config.page_size, on_event=on_kv_event
+                config.num_pages, config.page_size, on_event=on_kv_event,
+                state_slots=self._state_slots,
             )
         self.scheduler = Scheduler(config, self.allocator)
-        self.metrics = EngineMetrics(kv_total_pages=config.num_pages - 1)
+        self.metrics = EngineMetrics(
+            kv_total_pages=config.num_pages - 1,
+            state_slots=self._state_slots,
+        )
         #: mid-decode deadline expiries, bumped by the runner (its abort
         #: path) — folded with the scheduler's pre-admission drops into
         #: metrics.deadline_expired
@@ -634,6 +679,7 @@ class JaxEngine:
         kv = self.adapter.init_kv(
             config.num_pages, config.page_size,
             kv_quantize=config.kv_quantize,
+            **({"state_slots": self._state_slots} if self._stateful else {}),
         )
         if self.mesh is not None:
             specs = self.adapter.param_specs(quantized=bool(config.quantize))
@@ -661,10 +707,13 @@ class JaxEngine:
         # scale planes) vs what the same pool costs at the model dtype —
         # the ~2x effective-capacity claim, measured not asserted.
         m = self.metrics
+        m.state_pool_bytes = int(
+            sum(x.nbytes for x in self._state_pools(kv))
+        )
         m.kv_pool_bytes = int(
             sum(x.nbytes for x in jax.tree.leaves(kv))
             + sum(x.nbytes for x in jax.tree.leaves(self.draft_kv))
-        )
+        ) - m.state_pool_bytes
         model_itemsize = jnp.dtype(
             getattr(self.adapter.config, "dtype", None)
             or self.adapter.config.base.dtype
@@ -693,6 +742,76 @@ class JaxEngine:
             }
         else:
             self._batch_shardings = None
+
+    @staticmethod
+    def _state_pools(kv) -> tuple:
+        """The cache's slot pools of recurrent state (none for a family
+        whose only per-sequence state is pages)."""
+        return tuple(
+            x for x in (getattr(kv, "conv", None), getattr(kv, "ssm", None))
+            if x is not None
+        )
+
+    def _refuse_for_state(self, config: EngineConfig) -> None:
+        """A sequence of a model with state-space layers is its pages AND
+        its recurrent state. What moves, shares, narrows or rewinds pages
+        alone would serve half a sequence: refused at start-up."""
+        name = config.model
+
+        def no(what: str, instead: str):
+            raise ValueError(
+                f"{name} has state-space layers (a recurrent state a "
+                f"sequence beside its KV pages): {what} is not supported "
+                f"for it; {instead}"
+            )
+
+        if config.kv_quantize:
+            no(f"kv_quantize={config.kv_quantize!r}",
+               "run with kv_quantize=None")
+        if config.host_kv_cache_bytes > 0 or config.disk_kv_cache_bytes > 0:
+            no("KVBM offload (host_kv_cache_bytes / disk_kv_cache_bytes)",
+               "pages in a lower tier would come back without the state "
+               "at their boundary; run without the tiers")
+        if config.spec_ngram > 0 or config.spec_draft_model is not None:
+            no("speculative decoding (spec_ngram / spec_draft_*)",
+               "a rejected draft token has already advanced the state; "
+               "run without speculation")
+
+    def _refuse_state_transfer(self, what: str) -> None:
+        """Guard of the page-movement surface (disagg transfer planes,
+        handover, tier promotion): pages of a stateful model never travel
+        without their state."""
+        if self._stateful:
+            raise ValueError(
+                f"{self.config.model} has state-space layers: {what} would "
+                "move a sequence's KV pages without its recurrent state; "
+                "disaggregated prefill, handover and KV tiers are not "
+                "supported for it (ROADMAP R8)"
+            )
+
+    def _row_tables(self, pt: np.ndarray, reqs):
+        """What a step function takes as `pt`: the page tables [B, MP]
+        and, for a model with state-space layers, beside them the rows'
+        state entries [B, 2] (read, write): a row reads its state where
+        the last dispatch TAKEN left it (generation `state_gen` of its
+        slot) and writes the other generation, which becomes the state
+        only when this dispatch is taken (`_commit_state`). Padding rows
+        keep (0, 0), the null slot."""
+        if not self._stateful:
+            return pt
+        stride = self._state_slots + 1
+        rows = np.zeros((pt.shape[0], 2), np.int32)
+        for i, req in enumerate(reqs):
+            rows[i, 0] = req.state_gen * stride + req.state_slot
+            rows[i, 1] = (1 - req.state_gen) * stride + req.state_slot
+        return (pt, rows)
+
+    def _commit_state(self, reqs) -> None:
+        """The dispatch that carried `reqs` is the real step: what it
+        wrote IS each row's state from now on."""
+        if self._stateful:
+            for req in reqs:
+                req.state_gen ^= 1
 
     def _put_global(self, tree, shardings):
         """Place a host pytree onto the mesh. Single-process: device_put.
@@ -1102,7 +1221,10 @@ class JaxEngine:
                             mm_embeds[i, off] = req.mm_embeds[j]
                             mm_mask[i, off] = True
 
-            host = {"base": (tokens, positions, valid, pt)}
+            host = {"base": (
+                tokens, positions, valid,
+                self._row_tables(pt, [p.request for p in pieces]),
+            )}
             if any_mm:
                 host["mm"] = (mm_embeds, mm_mask)
             # Every piece starting at 0 (un-chunked prompts, no prefix
@@ -1157,6 +1279,7 @@ class JaxEngine:
             speculative=0,
         ):
             out = fn(*args, *tail, **kwargs)
+        self._commit_state([p.request for p in pieces])
         ids = lp_data = None
         if not any_last:
             self.kv = out
@@ -1914,7 +2037,31 @@ class JaxEngine:
             # the dispatch on the device advances every draw counter by
             # the tokens it samples
             samp[4][:n] += np.asarray(ahead, np.int32)
-        return (tokens, positions, valid, pt), samp, all_greedy
+        return (
+            (tokens, positions, valid, self._row_tables(pt, reqs)),
+            samp, all_greedy,
+        )
+
+    def _decode_rows_bucket(
+        self, n: int, kind: str, k_steps: int, lp: int, pen: int,
+        bias: bool, greedy: bool,
+    ) -> int:
+        """The row bucket of a pure decode dispatch over `n` rows: the
+        smallest of `decode_buckets` that holds them, unless that
+        program has never run and the same program over more rows has.
+        A first call costs seconds (5-16 for a hybrid model's step),
+        padding rows cost microseconds: a batch that thins out (a lull,
+        streams cut at once and aborted one by one) keeps the program it
+        has instead of walking down through every smaller bucket, one
+        first call each."""
+        exact = self.config.decode_bucket_for(n)
+        for b in self.config.decode_buckets:
+            if b >= exact and (
+                kind, b, k_steps, greedy, False, False, lp, pen, bias, 0,
+                False,
+            ) in self._jit_cache:
+                return b
+        return exact
 
     def _feed(self, feed, tokens):
         """The `[B, 1]` token input of a dispatch launched ahead of its
@@ -1950,23 +2097,27 @@ class JaxEngine:
         with phase(
             m, "engine.stage", "time_stage_ms", "time_decode_dispatch_ms"
         ):
-            b_bucket = self.config.decode_bucket_for(len(reqs))
             k_steps = self._pick_decode_steps(reqs, ahead)
             if speculative and not self._grow_pages_for(
                 reqs, [a + k_steps - 1 for a in ahead]
             ):
                 return None
-            base, samp, all_greedy = self._stage_decode_rows(
-                reqs, b_bucket, ahead
-            )
+            kind = "decode" if k_steps == 1 else "decode_multi"
             lp = self._batch_logprobs(reqs)
             # penalty history needs the pending tokens host-side: no
             # dispatch is launched ahead with one (_speculate)
             pen = 0 if speculative else self._batch_penalty_bucket(reqs)
+            bias = self._batch_bias(reqs)
+            b_bucket = self._decode_rows_bucket(
+                len(reqs), kind, k_steps, lp, pen, bias,
+                all(r.sampling.temperature <= 0.0 for r in reqs),
+            )
+            base, samp, all_greedy = self._stage_decode_rows(
+                reqs, b_bucket, ahead
+            )
             pen_args = (
                 self._penalty_arrays(reqs, b_bucket, pen) if pen else ()
             )
-            bias = self._batch_bias(reqs)
             bias_kwargs = self._bias_arrays(reqs, b_bucket) if bias else {}
             host = {
                 "base": base, "samp": samp, "pen": pen_args,
@@ -1978,7 +2129,6 @@ class JaxEngine:
                 host["src"] = np.full(b_bucket, -1, np.int32)
                 host["src"][: len(reqs)] = feed[1]
             dev = self._dev_tree(host)
-            kind = "decode" if k_steps == 1 else "decode_multi"
             fn = self._get_step_fn(
                 kind, b_bucket, k_steps, greedy=all_greedy, lp=lp, pen=pen,
                 bias=bias,
@@ -2000,6 +2150,8 @@ class JaxEngine:
             token_ids, lp_data, self.kv = out
         else:
             token_ids, self.kv = out  # [B], or [K, B] when fused
+        if not speculative:
+            self._commit_state(reqs)
         return _Launched(
             reqs=tuple(reqs), b_bucket=b_bucket, k_steps=k_steps,
             token_ids=token_ids, lp_data=lp_data,
@@ -2277,7 +2429,10 @@ class JaxEngine:
 
             host = {
                 "based": based,
-                "basep": (p_tokens, p_positions, p_valid, p_pt),
+                "basep": (
+                    p_tokens, p_positions, p_valid,
+                    self._row_tables(p_pt, [p.request for p in pieces]),
+                ),
                 "last": last_idx, "samp": samp, "pen": pen_args,
                 "bias": bias_kwargs,
             }
@@ -2307,6 +2462,8 @@ class JaxEngine:
             token_ids, lp_data, self.kv = out
         else:
             token_ids, self.kv = out  # [b_dec] or [b_dec + b_pre]
+        if not speculative:
+            self._commit_state([*reqs_d, *(p.request for p in pieces)])
         return _Launched(
             reqs=tuple(reqs_d), b_bucket=b_dec, k_steps=1,
             token_ids=token_ids, lp_data=lp_data, pieces=tuple(pieces),
@@ -2449,6 +2606,9 @@ class JaxEngine:
             return None
         self._inflight = None
         self.metrics.overlap_hits += 1
+        self._commit_state(
+            [*inflight.reqs, *(p.request for p in inflight.pieces)]
+        )
         return inflight
 
     def _discard_inflight(self, why: str) -> None:
@@ -2461,18 +2621,36 @@ class JaxEngine:
         preempted requests they sit in released pages whose next owner's
         writes are stream-ordered after them. Pages grown for the window,
         and a request admitted early for it, stay as they are: the next
-        `schedule()` finds the state it would have made itself."""
+        `schedule()` finds the state it would have made itself.
+
+        Its writes of RECURRENT state (a model with state-space layers)
+        would not be benign in place: a state is not written by position,
+        so the real dispatch would advance every surviving row a second
+        time. They went to the other generation of each row's slot
+        (`_row_tables`), which only `_take_inflight` makes the row's
+        state; here nothing is committed, so every surviving row still
+        reads what the last dispatch taken left it, and the next dispatch
+        overwrites what this one wrote. `state_rows` of the span counts
+        the rows that were put back that way."""
         inflight, self._inflight = self._inflight, None
         if inflight is None:
             return
-        self._note_rollback(why)
+        state_rows = 0
+        if self._stateful:
+            state_rows = sum(
+                1 for r in (
+                    *inflight.reqs, *(p.request for p in inflight.pieces)
+                ) if r.state_slot
+            )
+            self.metrics.state_restores += int(state_rows > 0)
+        self._note_rollback(why, state_rows)
         logger.debug("overlap rollback: %s", why)
 
-    def _note_rollback(self, why: str) -> None:
+    def _note_rollback(self, why: str, state_rows: int = 0) -> None:
         """Count a rolled-back speculative dispatch, and mark the moment
         in a running capture: a zero-length `engine.rollback` span."""
         self.metrics.overlap_rollbacks += 1
-        with phase(None, "engine.rollback", why=why):
+        with phase(None, "engine.rollback", why=why, state_rows=state_rows):
             pass
 
     def takers_wait_s(self, queued: int = 0) -> float:
@@ -2875,6 +3053,7 @@ class JaxEngine:
 
         if kind == "decode_multi":
             k_steps = t  # the (b, t) slot carries (bucket, fused steps)
+            stateful = self._stateful
 
             def multi_fn(params, tokens, positions, valid, kv, pt,
                          temps, top_ps, top_ks, seeds, counters,
@@ -2892,10 +3071,17 @@ class JaxEngine:
                     counts0 = jnp.zeros((), jnp.float32)  # unused carry
 
                 def body(carry, _):
-                    tokens, positions, kv, counters, counts = carry
+                    tokens, positions, kv, counters, counts, *state = carry
+                    # a recurrent state is read where `pt` says in the
+                    # first fused step, then where the step before wrote it
+                    pt_k = (pt[0], state[0]) if stateful else pt
                     hidden, kv = adapter.forward_hidden(
-                        params, tokens, positions, valid, kv, pt
+                        params, tokens, positions, valid, kv, pt_k
                     )
+                    if stateful:
+                        state = [jnp.broadcast_to(
+                            state[0][:, 1:], state[0].shape
+                        )]
                     logits = adapter.compute_logits(params, hidden[:, -1])
                     ids = pick(
                         logits, (temps, top_ps, top_ks, seeds, counters),
@@ -2915,13 +3101,15 @@ class JaxEngine:
                     with jax.named_scope("feedback"):
                         carry = (
                             ids[:, None], positions + 1, kv, counters + 1,
-                            counts,
+                            counts, *state,
                         )
                     return carry, out
 
-                (_, _, kv, _, _), (all_ids, all_lp) = jax.lax.scan(
-                    body, (tokens, positions, kv, counters, counts0), None,
-                    length=k_steps,
+                (_, _, kv, *_), (all_ids, all_lp) = jax.lax.scan(
+                    body,
+                    (tokens, positions, kv, counters, counts0,
+                     *((pt[1],) if stateful else ())),
+                    None, length=k_steps,
                 )
                 if lp >= 0:
                     return rep(all_ids), rep(all_lp), kv  # [K, B] (+ lp)
@@ -3424,6 +3612,12 @@ class JaxEngine:
         reference delegates this to its engines; here it shares the prefill
         programs' chunked execution and page pool). Pages are scratch:
         allocated for attention across chunks, freed before returning."""
+        if self._stateful:
+            raise ValueError(
+                f"{self.config.model} has state-space layers: /v1/embeddings "
+                "is not supported for it (its scratch pages would need a "
+                "scratch state slot)"
+            )
         out: list[np.ndarray] = []
         ps = self.config.page_size
         mp = self.config.max_pages_per_seq
@@ -3504,6 +3698,7 @@ class JaxEngine:
         d = cfg.head_dim if hasattr(cfg, "head_dim") else cfg.base.head_dim
         return (d, d)
 
+    @_pages_only
     def extract_pages(self, page_ids: Sequence[int]):
         """Pull KV pages to host in the canonical wire format:
         (k, v) as [L, Hkv, n, page_size, D] — layout- and padding-agnostic
@@ -3565,6 +3760,7 @@ class JaxEngine:
             )
         return parts[0] if len(parts) == 1 else np.concatenate(parts, axis=1)
 
+    @_pages_only
     def extract_pages_async(self, page_ids: Sequence[int]):
         """Async variant: the page gather + canonical transpose run on
         device and the device→host copy is started without blocking; the
@@ -3581,6 +3777,7 @@ class JaxEngine:
         v.copy_to_host_async()
         return k, v
 
+    @_pages_only
     def inject_pages(self, page_ids: Sequence[int], k: np.ndarray, v: np.ndarray) -> None:
         """Write transferred KV pages (canonical [L, Hkv, n, S, D]) into
         this engine's pool in place. Host arrays become uncommitted device
@@ -3604,6 +3801,7 @@ class JaxEngine:
             return
         self.inject_pages_device(page_ids, jnp.asarray(k), jnp.asarray(v))
 
+    @_pages_only
     def inject_pages_device(self, page_ids: Sequence[int], k, v) -> None:
         """Device-path inject: k/v are jax arrays (canonical
         [L, Hkv, n, S, D] — D+4 int8 with trailing packed scales on
@@ -3688,6 +3886,7 @@ class JaxEngine:
     # (reference: KvBlockManager::export_local_blockset / onboard_blocks —
     # block_manager.rs:121,169)
 
+    @_pages_only
     def serve_blocks(self, seq_hashes: Sequence[int]):
         """Export the longest locally-resident chain of `seq_hashes` for a
         peer: (metas, k, v) with metas=[(seq_hash, parent, tokens)...] and
@@ -3736,6 +3935,7 @@ class JaxEngine:
         v = parts_v[0] if len(parts_v) == 1 else np.concatenate(parts_v, axis=2)
         return metas, k, v
 
+    @_pages_only
     def adopt_blocks(self, metas: Sequence[tuple], k, v) -> int:
         """Land a peer-served chain into this engine's prefix cache:
         allocate fresh pages, inject the bytes, register the hashes (which
@@ -3787,6 +3987,7 @@ class JaxEngine:
 
         return topo_order_metas(list(self.allocator._page_meta.values()))
 
+    @_pages_only
     def export_blocks_by_hash(self, seq_hashes: Sequence[int]):
         """Extract the subset of `seq_hashes` still device-registered as
         (metas, k, v) in the canonical wire format — the handover batch
@@ -3815,6 +4016,7 @@ class JaxEngine:
                 alloc.free(pages)
         return metas, np.asarray(k), np.asarray(v)
 
+    @_pages_only
     def prepare_handover_adopt(self, metas: Sequence[tuple]):
         """Successor-side reservation: allocate fresh pages for the
         not-yet-resident blocks of `metas`. Returns (pages, kept_metas,
@@ -3855,6 +4057,7 @@ class JaxEngine:
         straight back to the free list — no leak, no half-adopted KV."""
         self.allocator.free(pages)
 
+    @_pages_only
     def allocate_for_remote_prefill(
         self,
         request_id: str,
@@ -3879,6 +4082,7 @@ class JaxEngine:
         req.pages = pages
         return req
 
+    @_pages_only
     def add_prefilled(self, req: Request, first_token: int) -> list[StepOutput]:
         """Admit a remote-prefilled request into decode: its pages hold the
         prompt KV; accept the prefill worker's first sampled token and let
@@ -3946,6 +4150,12 @@ class JaxEngine:
             m.kv_pages_watermark,
         )
         m.preemptions = self.scheduler.preemptions
+        if self._stateful:
+            m.state_slots_live = self.allocator.slots_watermark
+            m.state_resets = self.allocator.slots_taken
+            m.prefix_hits_refused_state = (
+                self.allocator.prefix_hits_refused_state
+            )
         m.queue_wait_ms_total = self.scheduler.queue_wait_ms_total
         m.admissions = self.scheduler.admissions
         if self._spec_draft or self.config.spec_ngram > 0:
@@ -4180,10 +4390,16 @@ class JaxEngine:
         produced the live numbers."""
         from dynamo_tpu.platform import device_hbm_bytes
 
-        kv_by_dev = self._per_device_bytes((self.kv, self.draft_kv))
+        state_by_dev = self._per_device_bytes(self._state_pools(self.kv))
+        kv_by_dev = {
+            key: n - state_by_dev.get(key, 0)
+            for key, n in self._per_device_bytes(
+                (self.kv, self.draft_kv)
+            ).items()
+        }
         weights = self._weights_by_device
         total_w = sum(weights.values())
-        total_kv = sum(kv_by_dev.values())
+        total_kv = sum(kv_by_dev.values()) + sum(state_by_dev.values())
         prog_bytes = [
             p["bytes"] for p in list(self.programs.values())
             if p.get("bytes")
@@ -4204,6 +4420,7 @@ class JaxEngine:
                 "kind": str(getattr(d, "device_kind", "cpu")),
                 "weights_bytes": w,
                 "kv_pool_bytes": kvb,
+                "state_pool_bytes": int(state_by_dev.get(key, 0)),
                 "scratch_bytes": scratch_each,
             }
             try:
@@ -4221,7 +4438,7 @@ class JaxEngine:
                     stats.get("peak_bytes_in_use") or live
                 )
             else:
-                live = w + kvb + scratch_each
+                live = w + kvb + row["state_pool_bytes"] + scratch_each
                 row["live_bytes"] = live
                 row["limit_bytes"] = limit_nominal
                 row["free_bytes"] = max(0, limit_nominal - live)
@@ -4230,8 +4447,8 @@ class JaxEngine:
         totals = {
             f: sum(r[f] for r in devices.values())
             for f in (
-                "weights_bytes", "kv_pool_bytes", "scratch_bytes",
-                "live_bytes", "free_bytes", "peak_bytes",
+                "weights_bytes", "kv_pool_bytes", "state_pool_bytes",
+                "scratch_bytes", "live_bytes", "free_bytes", "peak_bytes",
             )
         }
         return {"source": source, "devices": devices, "totals": totals}

@@ -70,11 +70,24 @@ class PageAllocator:
         num_pages: int,
         page_size: int,
         on_event: Optional[Callable[[KvEvent], None]] = None,
+        state_slots: int = 0,
     ):
         if num_pages < 2:
             raise ValueError("need at least 2 pages (page 0 is the null page)")
         self.num_pages = num_pages
         self.page_size = page_size
+        #: the second kind of per-sequence cache (a model with state-space
+        #: layers): `state_slots` slots of recurrent state, 1..state_slots
+        #: (slot 0 is the null slot, as page 0 is the null page). A request
+        #: holds ONE for its life in `running`; the same admission hands it
+        #: its pages and its slot (`Scheduler._admit`). With slots, a prefix
+        #: hit is refused: cached pages without the recurrent state at
+        #: their boundary cannot be continued from (snapshots: ROADMAP R8)
+        self.state_slots = state_slots
+        self._free_slots_list: list[int] = list(range(state_slots, 0, -1))
+        self.slots_watermark = 0  # most slots ever held at once
+        self.slots_taken = 0  # admissions that took (and so zeroed) a slot
+        self.prefix_hits_refused_state = 0
         #: page id -> (seq_hash, parent_hash, tokens) for registered pages
         self._page_meta: dict[int, tuple[int, Optional[int], tuple[int, ...]]] = {}
         self._on_event = on_event
@@ -133,6 +146,30 @@ class PageAllocator:
             got = self._nlib.dyn_pool_peek_reclaimable(self._np, out, n)
             return list(out[:got])
         return list(self._reclaimable)[:n]
+
+    # -- state slots ------------------------------------------------------
+
+    @property
+    def num_free_slots(self) -> int:
+        return len(self._free_slots_list)
+
+    def allocate_slot(self) -> Optional[int]:
+        """A free state slot, or None. Its content is whatever its last
+        owner left: a sequence that starts at position 0 starts from zeros
+        whatever the slot holds (models/nemotron_h.py)."""
+        if not self._free_slots_list:
+            return None
+        self.slots_taken += 1
+        slot = self._free_slots_list.pop()
+        self.slots_watermark = max(
+            self.slots_watermark, self.state_slots - self.num_free_slots
+        )
+        return slot
+
+    def free_slot(self, slot: int) -> None:
+        if slot in self._free_slots_list or not 0 < slot <= self.state_slots:
+            raise ValueError(f"double free of state slot {slot}")
+        self._free_slots_list.append(slot)
 
     # -- allocation --------------------------------------------------------
 
@@ -230,8 +267,12 @@ class PageAllocator:
     def lookup(self, seq_hashes: Sequence[int]) -> list[int]:
         """Longest cached prefix: page ids for leading hashes present.
 
-        Acquires a reference on each returned page.
+        Acquires a reference on each returned page. With state slots
+        (a model with state-space layers) there is never a hit: counted
+        in `match_length`, which every admission asks first.
         """
+        if self.state_slots:
+            return []
         if self._np is not None:
             n = len(seq_hashes)
             pages: list[int] = []
@@ -273,7 +314,16 @@ class PageAllocator:
         self.register(page, seq_hash, parent_hash, tokens)
 
     def match_length(self, seq_hashes: Sequence[int]) -> int:
-        """Cached-prefix length in blocks, without acquiring references."""
+        """Cached-prefix length in blocks, without acquiring references.
+        With state slots: 0, and a hit that would have been is counted
+        (`prefix_hits_refused_state`)."""
+        if self.state_slots:
+            if self._match_length(seq_hashes):
+                self.prefix_hits_refused_state += 1
+            return 0
+        return self._match_length(seq_hashes)
+
+    def _match_length(self, seq_hashes: Sequence[int]) -> int:
         if self._np is not None:
             n = len(seq_hashes)
             if not n:

@@ -91,6 +91,13 @@ class Request:
     #: (keeps the max_tokens budget correct across recompute)
     num_emitted: int = 0
     finish_reason: Optional[FinishReason] = None
+    #: a model with state-space layers: the slot of the state pool that
+    #: holds this sequence's recurrent state while it is in `running` (0 =
+    #: none: the null slot), and which of the slot's two generations holds
+    #: the state as the last dispatch TAKEN left it (engine/engine.py
+    #: `_row_tables`, `_commit_state`)
+    state_slot: int = 0
+    state_gen: int = 0
     #: disaggregated serving: keep pages allocated after finish so a prefill
     #: worker can extract their KV for transfer (released via release_held)
     hold_pages: bool = False

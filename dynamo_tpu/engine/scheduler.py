@@ -356,10 +356,23 @@ class Scheduler:
             max_reuse = (len(req.prompt_tokens) - 1) // ps
             while len(cached_pages) > max_reuse:
                 self.allocator.free([cached_pages.pop()])
+            if self.allocator.state_slots and not (
+                self.allocator.num_free_slots
+            ):
+                # every state slot is held (rows certain to end still hold
+                # theirs while `next_batch` admits ahead): wait, as for pages
+                self.allocator.free(cached_pages)
+                break
             fresh = self.allocator.allocate(total_pages - len(cached_pages))
             if fresh is None:
                 self.allocator.free(cached_pages)
                 break
+            if self.allocator.state_slots:
+                # one admission, both kinds of cache: the sequence's state
+                # slot for its life in `running`, read from generation 0
+                # (it starts at position 0, so from zeros)
+                req.state_slot = self.allocator.allocate_slot()
+                req.state_gen = 0
             req.pages = cached_pages + fresh
             req.registered_blocks = len(cached_pages)  # looked up by hash
             req.num_cached_prompt_tokens = len(cached_pages) * ps
@@ -559,6 +572,7 @@ class Scheduler:
         if request.hold_pages and request.pages:
             self.held[request.request_id] = request.pages
             request.pages = []
+            self._release(request)  # its state slot, if it has one
         else:
             self._release(request)
         self.chains.pop(request.request_id, None)
@@ -580,3 +594,6 @@ class Scheduler:
         if request.pages:
             self.allocator.free(request.pages)
             request.pages = []
+        if request.state_slot:
+            self.allocator.free_slot(request.state_slot)
+            request.state_slot = 0

@@ -92,6 +92,17 @@ _WORKER_FIELDS = (
     ("overlap_dispatches", "counter"),
     ("overlap_hits", "counter"),
     ("overlap_rollbacks", "counter"),
+    # recurrent-state plane (a model with state-space layers, 0 for every
+    # other; docs/observability.md): slots of the state pool, the high
+    # watermark of slots held, the pool's bytes, admissions that took a
+    # slot, rollbacks that had surviving rows' state to leave untouched,
+    # prefix hits refused for want of the state at the pages' boundary
+    ("state_slots", "gauge"),
+    ("state_slots_live", "gauge"),
+    ("state_pool_bytes", "gauge"),
+    ("state_resets", "counter"),
+    ("state_restores", "counter"),
+    ("prefix_hits_refused_state", "counter"),
     # speculative decoding (spec_ngram / spec_draft_model): drafts
     # proposed vs accepted — their ratio times S is the extra tokens per
     # verify dispatch; the skip counters say WHY speculation sat out

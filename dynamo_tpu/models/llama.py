@@ -65,6 +65,19 @@ class LlamaConfig:
     pallas_decode_max_batch: int = 32
     #: q/k/v projection bias — the Qwen2 family's one architectural delta
     attention_bias: bool = False
+    #: False: no rotary embedding on any layer (Nemotron-H's attention
+    #: layers: position reaches the model through its state-space layers;
+    #: models/nemotron_h.py). The kernels need nothing for it: q and k go
+    #: in unrotated
+    use_rope: bool = True
+    #: a prefill chunk over paged history walks the pages in the flash
+    #: kernel (ops/flash_prefill.py). False: gather the history and attend
+    #: in plain XLA instead. Worth it where the history is small whatever
+    #: the context (Nemotron-H: 4 attention layers of 2 KV heads, 2 MB a
+    #: layer at 4,096 tokens) and the kernel's compile time is not: a
+    #: third of a mixed program's (16.0 -> 9.9 s through the TPU compiler
+    #: here; PERF.md 6, PR 31)
+    prefill_history_kernel: bool = True
     #: Qwen3: per-head RMSNorm on q and k (head_dim-wide), applied after
     #: the projections, before rope
     qk_norm: bool = False
@@ -1547,7 +1560,9 @@ def attention_block(
     use_rope = (
         (layer + 1) % cfg.nope_every != 0 if cfg.nope_every else None
     )
-    if cfg.rope_local_theta is not None:
+    if not cfg.use_rope:
+        pass
+    elif cfg.rope_local_theta is not None:
         # Gemma3: global layers rope at rope_theta (with optional linear
         # scaling), local layers at rope_local_theta — select between the
         # two tiny [D/2] frequency tables, one rope application each.
@@ -1741,7 +1756,7 @@ def attention_block(
             attn = _chunk_only_attention(
                 q, k, v, positions, valid, cfg, dpad, mesh=mesh
             )
-    elif t <= 1024:
+    elif t <= 1024 and cfg.prefill_history_kernel:
         # Prefill chunk with history: paged pages (positions < chunk
         # start) + the current chunk, one online softmax — the flash
         # kernel walks pages with double-buffered DMA instead of
@@ -1819,6 +1834,9 @@ class StepGroup(NamedTuple):
     rope_positions: Optional[jax.Array] = None  # [3,B,T] m-RoPE streams
     mm_embeds: Optional[jax.Array] = None  # [B, T, H] multimodal embeds
     mm_mask: Optional[jax.Array] = None  # [B, T] bool — use mm_embeds here
+    #: [B, 2] int32 — where each row's recurrent state is read and where it
+    #: is written (entries of the slot pool; models/nemotron_h.py)
+    state_rows: Optional[jax.Array] = None
 
 
 def join_rows(xs: list) -> jax.Array:

@@ -932,17 +932,28 @@ def _grouped_ffn(
             out = out * lp[name + "_scale"][:, 0][expert_of_row]
         return out
 
+    if getattr(cfg, "expert_mlp", "swiglu") == "relu2":
+        # Nemotron-H: an ungated expert of two matrices, down(relu(up x)^2)
+        up = jnp.maximum(grouped(xs, "we_up"), 0.0)
+        return grouped((up * up).astype(cfg.dtype), "we_down")
     gate = jax.nn.silu(grouped(xs, "we_gate"))
     return grouped((gate * grouped(xs, "we_up")).astype(cfg.dtype), "we_down")
 
 
-def _gate(xf: jax.Array, lp: dict, cfg: MlaConfig):
+def _gate(xf: jax.Array, lp: dict, cfg: MlaConfig, precision=None):
     """(topw [N, k] f32, topi [N, k]): router logits and scores in
     float32, top-k by the configuration's method, weights scaled by
-    `routed_scaling_factor`."""
+    `routed_scaling_factor`. `precision` is the logits matmul's: on a TPU
+    a float32 product rounds its operands to bfloat16 unless told
+    otherwise, which is a router in bfloat16 weights (models/nemotron_h.py
+    asks for the highest: where a chip holds a share of the experts, a
+    flipped sixth expert adds or removes a whole expert)."""
     nt = xf.shape[0]
     e, k = cfg.n_routed_experts, cfg.num_experts_per_tok
-    logits = (xf.astype(jnp.float32)) @ lp["w_router"].astype(jnp.float32)
+    logits = jnp.matmul(
+        xf.astype(jnp.float32), lp["w_router"].astype(jnp.float32),
+        precision=precision,
+    )
 
     def _group_mask(choice, rank_fn):
         g = cfg.n_group
@@ -990,18 +1001,28 @@ def _routed_experts(
     cfg: MlaConfig,
     mesh=None,
     stack=None,
+    held=None,
 ) -> jax.Array:
     """[N, H] f32: every one of the N*k assignments computed, whatever
     the routing. The assignments are sorted by expert (row j of the
     sorted batch is token order[j] // k under expert expert_of_row[j]),
     go through the grouped FFN, and come back in token order weighted by
-    the gate."""
+    the gate. `held` = (first, count) says which experts `lp` holds where
+    that is a share of them (an expert-parallel deployment's chip,
+    models/nemotron_h.py): an assignment to an expert held elsewhere sorts
+    past the groups, where the grouped matmul computes nothing, and adds
+    nothing here."""
     nt, h = xf.shape
     e, k = cfg.n_routed_experts, topi.shape[1]
     with jax.named_scope("route"):
         flat_e = topi.reshape(nt * k).astype(jnp.int32)
+        if held is not None:
+            first, e = held
+            flat_e = flat_e - first
+            flat_e = jnp.where((flat_e >= 0) & (flat_e < e), flat_e, e)
         order = jnp.argsort(flat_e, stable=True)
         expert_of_row = flat_e[order]
+        # (an index past the groups is dropped: jax's default for a scatter)
         group_sizes = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
         xs = xf[order // k].astype(cfg.dtype)
     with jax.named_scope("experts"):
@@ -1009,6 +1030,8 @@ def _routed_experts(
             xs, expert_of_row, group_sizes, lp, cfg, mesh, stack
         )
     with jax.named_scope("route"):
+        if held is not None:  # rows past the groups hold whatever was there
+            ys = jnp.where((expert_of_row < e)[:, None], ys, 0.0)
         back = jnp.zeros((nt * k,), jnp.int32).at[order].set(
             jnp.arange(nt * k, dtype=jnp.int32)
         )

@@ -62,6 +62,15 @@ class ModelAdapter:
     #: time — init_params + quantize_params peaks at full-model dtype
     #: size, which for 8B+ configs exceeds a single chip's HBM
     init_params_quantized: Optional[Callable[[jax.Array], Any]] = None
+    #: layers that keep a recurrent state a sequence (Mamba-2): 0 for every
+    #: family whose only per-sequence state is pages. Where it is not 0 the
+    #: engine hands `init_kv` a `state_slots` count, passes each row's
+    #: (read, write) slot entries beside its page table (`pt` is then the
+    #: pair (page tables, state rows [B, 2])), and refuses what would move
+    #: or share half of a sequence's state (docs/models.md)
+    state_layers: int = 0
+    #: bytes of one sequence's state, one generation (state_layers > 0)
+    state_slot_bytes: int = 0
 
 
 def _kv_pages_spec(kv_quantize=None, shard_heads: bool = True):
@@ -343,6 +352,90 @@ def _moe_adapter(name: str, moe_cfg, mesh=None) -> ModelAdapter:
     )
 
 
+def _nemotron_h_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
+    """Nemotron-H: pages for its attention layers and a state slot a
+    sequence for its Mamba-2 layers. `pt` of every step function is the
+    pair (page tables, state rows)."""
+    from dynamo_tpu.models import nemotron_h as nh
+
+    if mesh is not None:
+        raise ValueError(
+            f"{name}: Nemotron-H runs on one chip (its experts over a "
+            "mesh are not implemented): use tp=dp=ep=sp=1"
+        )
+
+    def fwd_hidden(params, tokens, positions, valid, kv, pt, **kw):
+        if kw.pop("mm_embeds", None) is not None:
+            raise ValueError("multimodal prompts are not supported for "
+                             "Nemotron-H")
+        kw.pop("mm_mask", None)
+        return nh.forward_hidden(
+            params, cfg, tokens, positions, valid, kv, *pt, **kw
+        )
+
+    def fwd(params, tokens, positions, valid, kv, pt):
+        h, kv = fwd_hidden(params, tokens, positions, valid, kv, pt)
+        return nh.compute_logits(params, cfg, h), kv
+
+    def fwd_mixed(params, prompt, decode, kv, first_chunk=False):
+        (h_p, h_d), kv = nh.forward_groups(
+            params, cfg,
+            [
+                StepGroup(*prompt[:3], prompt[3][0], first_chunk,
+                          state_rows=prompt[3][1]),
+                StepGroup(*decode[:3], decode[3][0],
+                          state_rows=decode[3][1]),
+            ],
+            kv,
+        )
+        return h_p, h_d, kv
+
+    def init_kv(num_pages, page_size, kv_quantize=None, state_slots=0):
+        if kv_quantize:
+            raise ValueError(
+                "kv_quantize is not supported for a model with state-space "
+                "layers (Nemotron-H): its recurrent state is float32 and "
+                "its four attention layers' pages are a twentieth of the "
+                "state; run with kv_quantize=None"
+            )
+        return nh.init_cache(cfg, num_pages, page_size, state_slots)
+
+    def no_mesh_specs(*_a, **_k):
+        from dynamo_tpu.parallel.logical import resolve
+
+        return resolve(nh.nemotron_h_logical_axes(cfg))
+
+    return ModelAdapter(
+        name=name,
+        config=cfg,
+        vocab_size=cfg.vocab_size,
+        init_params=lambda key: nh.init_params(key, cfg),
+        forward=fwd,
+        forward_hidden=fwd_hidden,
+        forward_hidden_mixed=fwd_mixed,
+        compute_logits=lambda params, h: nh.compute_logits(params, cfg, h),
+        init_kv=init_kv,
+        param_specs=no_mesh_specs,
+        kv_spec=lambda kv_quantize=None: None,
+        logical_axes=lambda quantized=False: nh.nemotron_h_logical_axes(cfg),
+        state_layers=cfg.count("M"),
+        state_slot_bytes=nh.state_bytes_per_slot(cfg),
+    )
+
+
+def _nemotron_h_presets() -> dict:
+    from dynamo_tpu.models.nemotron_h import NemotronHConfig
+
+    return {
+        # NVIDIA-Nemotron-3-Nano-30B-A3B as published: 52 layers, 128
+        # experts (63 GB in bf16: shape tests and a later multi-chip issue)
+        "nemotron3-nano": NemotronHConfig.nemotron3_nano,
+        # one chip of its 8-chip deployment: 28 layers, 16 experts held
+        "nemotron3-nano-28l-16e": NemotronHConfig.nemotron3_nano_1chip,
+        "nemotron-h-tiny": NemotronHConfig.tiny,
+    }
+
+
 def _moe_presets() -> dict:
     from dynamo_tpu.models.moe import MoeConfig
 
@@ -376,7 +469,7 @@ def list_presets() -> list[str]:
     dry-resolves each one's logical axes through the rule table."""
     return sorted(_LLAMA_PRESETS) + sorted(_moe_presets()) + sorted(
         _mla_presets()
-    )
+    ) + sorted(_nemotron_h_presets())
 
 
 def get_model(
@@ -414,6 +507,13 @@ def get_model(
         moe_cfg = moe_presets[key]()
     elif key in mla_presets:
         mla_cfg = mla_presets[key]()
+    elif key in _nemotron_h_presets():
+        nh_cfg = _nemotron_h_presets()[key]()
+        if dtype is not None:
+            nh_cfg = _with_dtype(nh_cfg, dtype)
+        if attention_impl is not None:
+            nh_cfg = replace(nh_cfg, attention_impl=attention_impl)
+        return _nemotron_h_adapter(name, nh_cfg, mesh=mesh)
     elif os.path.isdir(name) and os.path.exists(os.path.join(name, "config.json")):
         with open(os.path.join(name, "config.json")) as f:
             hf = json.load(f)
@@ -458,6 +558,9 @@ def get_model(
             # Multimodal Gemma3 dumps (model_type "gemma3") and
             # RecurrentGemma remain refused rather than served
             # silently wrong (text-only Gemma3ForCausalLM is covered).
+            # A recurrent state as such is served (models/nemotron_h.py,
+            # by preset); RecurrentGemma's RG-LRU block and a nemotron_h
+            # checkpoint directory have no loader here.
         ):
             cfg = LlamaConfig.from_hf_config(hf)
         else:
@@ -465,7 +568,7 @@ def get_model(
     else:
         raise ValueError(
             f"unknown model {name!r}; presets: "
-            f"{sorted(_LLAMA_PRESETS) + sorted(moe_presets) + sorted(mla_presets)} "
+            f"{list_presets()} "
             "or a local HF checkpoint directory"
         )
     if mla_cfg is not None:

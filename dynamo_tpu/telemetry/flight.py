@@ -50,6 +50,12 @@ _DELTA_FIELDS = (
     ("intake_ms", "time_intake_ms"),
     ("overlap_hits", "overlap_hits"),
     ("overlap_rollbacks", "overlap_rollbacks"),
+    # a model with state-space layers: slots taken (each from zeros),
+    # rollbacks that left surviving rows' state as the last taken
+    # dispatch wrote it, prefix hits refused
+    ("state_resets", "state_resets"),
+    ("state_restores", "state_restores"),
+    ("prefix_refused_state", "prefix_hits_refused_state"),
     # speculative decoding (ngram or draft model): drafted/accepted per
     # step — a record with tokens but no spec_drafted is a plain step
     ("spec_drafted", "spec_drafted"),

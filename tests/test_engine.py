@@ -447,3 +447,38 @@ def test_step_phase_timing_metrics():
     assert m["decode_dispatches"] >= 1
     assert m["time_prefill_ms"] > 0 and m["time_decode_ms"] > 0
     assert m["time_schedule_ms"] >= 0
+
+
+@pytest.mark.parametrize("decode_steps", [1, 4])
+def test_a_thinning_batch_keeps_the_decode_program_it_has(
+        engine_factory, decode_steps):
+    """Rows that leave one by one (streams cut at once and aborted as
+    their closes arrive) do not walk the batch down through every smaller
+    row bucket, one first call each: the dispatch pads up to the program
+    that has already run. The survivors' tokens are those of a run that
+    never had the neighbours' bucket to fall back on."""
+    prompts = {f"r{i}": [3 + i, 17, 42, 9 + i, 5] for i in range(4)}
+
+    def run(abort: bool):
+        eng = engine_factory(decode_steps=decode_steps)
+        for rid, p in prompts.items():
+            eng.add_request(rid, p, _greedy(16))
+        out: dict = {rid: [] for rid in prompts}
+        steps = 0
+        while eng.has_work:
+            for o in eng.step():
+                out[o.request_id].extend(o.new_token_ids)
+            steps += 1
+            if abort and steps == 3:
+                eng.abort_request("r2")
+                eng.abort_request("r3")
+                seen = set(eng.programs)
+        return eng, out, (seen if abort else None)
+
+    eng, out, seen = run(abort=True)
+    decode = [k for k in eng.programs if k[0].startswith("decode")]
+    assert decode and {k[1] for k in decode} == {4}  # never a 2-row program
+    assert set(eng.programs) == seen  # no first call after the aborts
+    _, alone, _ = run(abort=False)
+    for rid in ("r0", "r1"):
+        assert out[rid] == alone[rid] and len(out[rid]) == 16

@@ -621,3 +621,151 @@ def test_runner_awaits_takers(monkeypatch, room, queued, arrives_ms,
     t = time.perf_counter()
     async_engine.AsyncEngineRunner(object())._await_takers()
     assert time.perf_counter() - t < 0.05
+
+
+# -- a model with state-space layers: a rolled-back dispatch must not -------
+# -- advance a surviving row's recurrent state (docs/engine.md) -------------
+
+_HYBRID = dict(
+    model="nemotron-h-tiny", num_pages=256, max_pages_per_seq=32,
+    prefill_chunk=16, max_seqs=4, decode_buckets=(1, 2, 4),
+)
+
+
+def _drive(eng, reqs, events=None):
+    """Run to completion; `events[n]` is called after step n. Per request
+    (tokens, logprobs)."""
+    for rid, prompt, s in reqs:
+        eng.add_request(rid, prompt, s)
+    toks, lps, steps = {}, {}, 0
+    while eng.has_work:
+        for o in eng.step():
+            toks.setdefault(o.request_id, []).extend(o.new_token_ids)
+            lps.setdefault(o.request_id, []).extend(o.logprobs or ())
+        steps += 1
+        if events and steps in events:
+            events[steps](eng)
+    return toks, lps
+
+
+def _hybrid_reqs(n=3, max_tokens=20, prompt=9, **kw):
+    rng = np.random.default_rng(11)
+    return [
+        (f"h{i}", [int(x) for x in rng.integers(3, 250, prompt + 3 * i)],
+         SamplingParams(max_tokens=max_tokens + 2 * i, ignore_eos=True,
+                        logprobs=0, **kw))
+        for i in range(n)
+    ]
+
+
+def _same_streams(got, ref, only=None):
+    """Tokens equal; log-probs to 1e-4 (float32: a row's matmul sums may
+    be ordered by how many rows share the matmul, `_run_mixed`)."""
+    for rid in only or ref[0]:
+        assert got[0][rid] == ref[0][rid], rid
+        np.testing.assert_allclose(
+            got[1][rid], ref[1][rid], atol=1e-4, err_msg=rid)
+
+
+def _in_place(monkeypatch):
+    """The build this PR must not be: a dispatch launched ahead updates a
+    row's state where it is read, and nothing is committed."""
+    def rows(self, pt, reqs):
+        out = np.zeros((pt.shape[0], 2), np.int32)
+        for i, r in enumerate(reqs):
+            out[i] = r.state_slot
+        return (pt, out)
+
+    monkeypatch.setattr(JaxEngine, "_row_tables", rows)
+    monkeypatch.setattr(JaxEngine, "_commit_state", lambda self, reqs: None)
+
+
+@pytest.mark.parametrize("decode_steps", [1, 4])
+@pytest.mark.parametrize("event", ["abort", "preempt", "sampled-stop"])
+def test_hybrid_rollback_leaves_surviving_state_untouched(
+        engine_factory, event, decode_steps):
+    """Launch-ahead on, a model with Mamba-2 layers: an abort of a
+    neighbour, a preemption, and a stop nobody foresaw each roll back a
+    dispatch that had already advanced every row's recurrent state by
+    1-4 tokens. The surviving rows' tokens AND log-probs are the
+    synchronous loop's."""
+    reqs = _hybrid_reqs()
+    solo = _drive(
+        engine_factory(**_HYBRID, overlap_decode=False,
+                       decode_steps=decode_steps), reqs)
+    events, only = None, None
+    if event == "abort":
+        events = {5: lambda e: e.abort_request("h1")}
+        only = ["h0", "h2"]
+    elif event == "preempt":
+        events = {5: lambda e: e.scheduler._preempt_youngest(excluding=None)}
+    else:  # h1 stops on a token it samples mid-wave
+        reqs[1] = (reqs[1][0], reqs[1][1], SamplingParams(
+            max_tokens=22, logprobs=0, stop_token_ids=(solo[0]["h1"][6],)))
+    ref = solo
+    if event != "abort":  # the event is part of what the rows compute
+        ref = _drive(engine_factory(**_HYBRID, overlap_decode=False,
+                                    decode_steps=decode_steps), reqs, events)
+    eng = engine_factory(**_HYBRID, overlap_decode=True,
+                         decode_steps=decode_steps)
+    got = _drive(eng, reqs, events)
+    m = eng.metrics
+    assert m.overlap_rollbacks > 0 and m.state_restores > 0
+    assert m.overlap_hits > 0
+    _same_streams(got, ref, only)
+    assert eng.allocator.num_free_slots == eng.allocator.state_slots
+
+
+def test_hybrid_rolled_back_mixed_step_runs_its_chunk_again(engine_factory):
+    """A mixed step launched ahead carries the next chunk of a long
+    prompt beside the decode rows; an abort rolls it back after it has
+    advanced the prompt's slot by a chunk. The chunk runs again from the
+    state the last taken dispatch left, and the stream is the
+    synchronous loop's."""
+    rng = np.random.default_rng(5)
+    reqs = _hybrid_reqs(n=2, max_tokens=30)
+    long = ("long", [int(x) for x in rng.integers(3, 250, 75)],
+            SamplingParams(max_tokens=8, ignore_eos=True, logprobs=0))
+
+    def run(overlap):
+        eng = engine_factory(**_HYBRID, overlap_decode=overlap,
+                             decode_steps=1)
+        seen = {"rolled_mixed": 0}
+
+        def late(e):
+            e.add_request(*long)
+
+        def watch_abort(e):
+            infl = e._inflight
+            if infl is not None and infl.pieces:
+                seen["rolled_mixed"] += 1
+            e.abort_request("h0")
+
+        out = _drive(eng, reqs, {4: late, 7: watch_abort})
+        return out, eng.metrics, seen
+
+    ref, _, _ = run(False)
+    got, m, seen = run(True)
+    assert seen["rolled_mixed"] == 1  # the dispatch rolled back was mixed
+    assert m.overlap_rollbacks > 0 and m.state_restores > 0
+    _same_streams(got, ref, ["long", "h1"])
+
+
+def test_hybrid_rollback_test_fails_on_an_in_place_build(
+        engine_factory, monkeypatch):
+    """The control of the tests above: with the state updated where it is
+    read and no commit, the same abort leaves the survivors' streams
+    wrong (their state advanced twice)."""
+    reqs = _hybrid_reqs()
+    ref = _drive(engine_factory(**_HYBRID, overlap_decode=False,
+                                decode_steps=4), reqs)
+    _in_place(monkeypatch)
+    eng = engine_factory(**_HYBRID, overlap_decode=True, decode_steps=4)
+    got = _drive(eng, reqs, {5: lambda e: e.abort_request("h1")})
+    assert eng.metrics.overlap_rollbacks > 0
+    wrong = [
+        rid for rid in ("h0", "h2")
+        if got[0][rid] != ref[0][rid]
+        or not np.allclose(got[1][rid], ref[1][rid], atol=1e-3)
+    ]
+    assert wrong

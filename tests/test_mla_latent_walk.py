@@ -306,6 +306,10 @@ def test_grouped_matmul_kernel_is_ragged_dot(sizes):
     assert _tiling(2048, 1408, 2) == (128, 2048, 1408)
     assert _tiling(1408, 2048, 2) == (128, 1408, 2048)
     assert _tiling(7168, 2048, 2) == (128, 7168, 384)  # a wider model splits N
+    # Nemotron-H's two projections (1856 stored as 1920) under the same
+    # rule: two tiles each, the second hanging 384 columns over the edge
+    assert _tiling(2688, 1920, 2) == (128, 2688, 1152)
+    assert _tiling(1920, 2688, 2) == (128, 1920, 1536)
 
 
 def test_one_row_cache_writes_land_where_the_scatter_puts_them():

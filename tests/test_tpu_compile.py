@@ -391,6 +391,124 @@ def test_deepseek_v2_lite_step_compiles_at_published_widths(
     assert _mosaic_calls(compiled) >= 4
 
 
+def test_nemotron3_nano_state_comparison_compiles_at_published_widths(topo):
+    """What `correct` runs beside the log-probs (chipbench/references/
+    nemotron_h.py `served_states`): 64 tokens of ONE row through the
+    decode kernel inside a scan, in a pool of one slot, the entries
+    traced."""
+    import importlib.util
+
+    from chipbench import manifest
+
+    spec = importlib.util.spec_from_file_location(
+        "ref_nemotron_h_tpu",
+        manifest.ROOT / "chipbench/references/nemotron_h.py")
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    adapter = get_model("nemotron3-nano-28l-16e", dtype="bfloat16",
+                        attention_impl="pallas")
+    chip = SingleDeviceSharding(topo.devices[0])
+    pool = _on(chip, jax.eval_shape(
+        lambda: adapter.init_kv(2, 1, state_slots=1))).ssm
+    assert pool.shape == (12, 4, 64, 64, 128) and pool.dtype == jnp.float32
+    f32 = jnp.float32
+    tr = {"start": _sds((64, 64, 128), f32, chip),
+          "u": _sds((64, 64, 64), f32, chip),
+          "decay": _sds((64, 64), f32, chip),
+          "b": _sds((64, 8, 128), f32, chip),
+          "c": _sds((64, 8, 128), f32, chip)}
+    compiled = jax.jit(ref.decode_through_the_pool).lower(
+        pool, _sds((), jnp.int32, chip), tr).compile()
+    assert "ssm_decode_step" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows,t,b_pre", [
+    pytest.param(64, 1, 0, id="decode-64-rows-fused-8"),
+    pytest.param(1, 512, 0, id="prefill-chunk-from-a-slot"),
+    pytest.param(64, 128, 1, id="mixed-64-rows-beside-a-chunk"),
+])
+def test_nemotron3_nano_cut_step_compiles_at_published_widths(
+        topo, rows, t, b_pre):
+    """Whole steps of `nemotron3-nano-28l-16e` as `nano3-chat-churn`
+    serves it (bf16, 3200 pages, 72 state slots in two generations,
+    --max-context 4096): the state kernel and the row writer of the
+    Mamba-2 layers, the page walk at 16 query heads a KV head, the grouped
+    matmul at 2688 x 1920 (1856 padded to whole lanes) go through the TPU
+    compiler inside the scan over units of seven layers; both pools are
+    updated in place, and the program fits the chip beside 6.9 GB of
+    weights, 3.8 GB of state and 0.84 GB of pages."""
+    adapter = get_model("nemotron3-nano-28l-16e", dtype="bfloat16",
+                        attention_impl="pallas")
+    chip = SingleDeviceSharding(topo.devices[0])
+    params = _on(chip, jax.eval_shape(
+        lambda: adapter.init_params(jax.random.key(0))))
+    kv = _on(chip, jax.eval_shape(
+        lambda: adapter.init_kv(3200, PAGE, state_slots=72)))
+    assert kv.ssm.shape == (12, 146, 64, 64, 128)
+    assert kv.conv.shape == (12, 146, 144, 128)
+    mp = 4096 // PAGE
+
+    def rows_of(b, tt):
+        return (
+            _sds((b, tt), jnp.int32, chip), _sds((b, tt), jnp.int32, chip),
+            _sds((b, tt), jnp.bool_, chip),
+            (_sds((b, mp), jnp.int32, chip), _sds((b, 2), jnp.int32, chip)),
+        )
+
+    def head(params, hidden):
+        logits = adapter.compute_logits(params, hidden[:, -1])
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    if b_pre:  # the fused mixed step: a prompt chunk beside the decode rows
+
+        def program(params, kv, prompt, decode):
+            h_p, h_d, kv = adapter.forward_hidden_mixed(
+                params, prompt, decode, kv)
+            return head(params, h_d), kv
+
+        args = (rows_of(b_pre, t), rows_of(rows, 1))
+    elif t > 1:
+
+        def program(params, kv, tokens, positions, valid, pt):
+            hidden, kv = adapter.forward_hidden(
+                params, tokens, positions, valid, kv, pt)
+            return head(params, hidden), kv
+
+        args = rows_of(rows, t)
+    else:  # decode as the engine fuses it: 8 steps, the pools the carry,
+        # the state read at one entry in the first step and then where the
+        # step before wrote it
+
+        def program(params, kv, tokens, positions, valid, pt):
+            def body(carry, _):
+                tokens, positions, kv, pt = carry
+                hidden, kv = adapter.forward_hidden(
+                    params, tokens, positions, valid, kv, pt)
+                ids = head(params, hidden)
+                pt = (pt[0], jnp.broadcast_to(pt[1][:, 1:], pt[1].shape))
+                return (ids[:, None], positions + 1, kv, pt), ids
+
+            (_, _, kv, _), ids = jax.lax.scan(
+                body, (tokens, positions, kv, pt), None, length=8)
+            return ids, kv
+
+        args = rows_of(rows, t)
+
+    compiled = jax.jit(program, donate_argnums=(1,)).lower(
+        params, kv, *args).compile()
+    mem = compiled.memory_analysis()
+    pools = sum(np.prod(x.shape) * x.dtype.itemsize
+                for x in (kv.k, kv.v, kv.conv, kv.ssm))
+    assert mem.alias_size_in_bytes >= pools  # both caches updated in place
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    text = compiled.as_text()
+    assert "state_write_rows" in text
+    if t == 1 or b_pre:
+        assert "ssm_decode_step" in text
+        assert "paged_decode_attention" in text
+    assert _mosaic_calls(compiled) >= 4
+
+
 def test_engine_refuses_narrow_pages_with_too_few_kv_heads_per_shard():
     """int8 pages under tp=4 leave llama3-1b 2 kv heads per shard, which
     Mosaic cannot DMA: on a TPU the engine says so at construction
