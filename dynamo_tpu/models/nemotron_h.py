@@ -78,6 +78,56 @@ PATTERN_NANO3 = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 LANE = 128
 
 
+class Mamba2Dims(NamedTuple):
+    """What the Mamba-2 mixer and its slot pools read of a configuration:
+    every family with such a mixer provides one (`cfg.mamba`)."""
+
+    num_heads: int
+    head_dim: int
+    state_size: int
+    n_groups: int
+    conv_kernel: int
+    chunk_size: int
+    rms_norm_eps: float
+    dtype: Any
+    #: the state kernels (ops/ssm_state.py) where there is a TPU
+    kernels: bool
+
+    @property
+    def d_inner(self) -> int:
+        return self.num_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.n_groups * self.state_size
+
+    @property
+    def in_proj_dim(self) -> int:
+        return self.d_inner + self.conv_dim + self.num_heads
+
+    @property
+    def conv_state_shape(self) -> tuple:
+        """A slot's conv window, `conv_kernel - 1` rows of `conv_dim`, as
+        the pool holds it: in rows of 128 lanes where that divides (a DMA
+        moves whole (sublane, lane) tiles)."""
+        n = (self.conv_kernel - 1) * self.conv_dim
+        if n % LANE == 0:
+            return (n // LANE, LANE)
+        return (self.conv_kernel - 1, self.conv_dim)
+
+    @property
+    def ssm_state_shape(self) -> tuple:
+        return (self.num_heads, self.head_dim, self.state_size)
+
+    @property
+    def slot_bytes(self) -> int:
+        """One layer's state of one sequence, one generation (the conv
+        window in the model dtype, the SSM state float32)."""
+        conv = math.prod(self.conv_state_shape) * jnp.dtype(
+            self.dtype).itemsize
+        return conv + math.prod(self.ssm_state_shape) * 4
+
+
 @dataclass(frozen=True)
 class NemotronHConfig:
     vocab_size: int = 256
@@ -125,16 +175,16 @@ class NemotronHConfig:
         return self.attention_impl in ("pallas", "hybrid")
 
     @property
-    def d_inner(self) -> int:
-        return self.mamba_num_heads * self.mamba_head_dim
+    def mamba(self) -> Mamba2Dims:
+        return Mamba2Dims(
+            self.mamba_num_heads, self.mamba_head_dim, self.ssm_state_size,
+            self.n_groups, self.conv_kernel, self.chunk_size,
+            self.rms_norm_eps, self.dtype, self.kernels,
+        )
 
     @property
-    def conv_dim(self) -> int:
-        return self.d_inner + 2 * self.n_groups * self.ssm_state_size
-
-    @property
-    def in_proj_dim(self) -> int:
-        return self.d_inner + self.conv_dim + self.mamba_num_heads
+    def state_layers(self) -> int:
+        return self.count("M")
 
     @property
     def experts_here(self) -> int:
@@ -152,21 +202,6 @@ class NemotronHConfig:
 
     def count(self, kind: str) -> int:
         return self.pattern.count(kind)
-
-    @property
-    def conv_state_shape(self) -> tuple:
-        """A slot's conv window, `conv_kernel - 1` rows of `conv_dim`, as
-        the pool holds it: in rows of 128 lanes where that divides (a DMA
-        moves whole (sublane, lane) tiles)."""
-        n = (self.conv_kernel - 1) * self.conv_dim
-        if n % LANE == 0:
-            return (n // LANE, LANE)
-        return (self.conv_kernel - 1, self.conv_dim)
-
-    @property
-    def ssm_state_shape(self) -> tuple:
-        return (self.mamba_num_heads, self.mamba_head_dim,
-                self.ssm_state_size)
 
     @property
     def attn_cfg(self) -> LlamaConfig:
@@ -269,27 +304,27 @@ class HybridCache(NamedTuple):
         return KVPages(k=self.k, v=self.v)
 
 
-def state_bytes_per_slot(cfg: NemotronHConfig) -> int:
-    """Bytes one GENERATION of one sequence's state takes over all M
-    layers (the conv window in the model dtype, the SSM state float32)."""
-    conv = math.prod(cfg.conv_state_shape) * jnp.dtype(cfg.dtype).itemsize
-    return cfg.count("M") * (conv + math.prod(cfg.ssm_state_shape) * 4)
+def state_bytes_per_slot(cfg) -> int:
+    """Bytes one GENERATION of one sequence's state takes over all the
+    layers that keep one. `cfg` is any family's configuration with a
+    Mamba-2 mixer (`mamba`, `state_layers`, `attn_cfg`)."""
+    return cfg.state_layers * cfg.mamba.slot_bytes
 
 
 def init_cache(
-    cfg: NemotronHConfig, num_pages: int, page_size: int, state_slots: int
+    cfg, num_pages: int, page_size: int, state_slots: int
 ) -> HybridCache:
     """`state_slots` sequences' state besides the null slot, two
     generations each."""
-    a = cfg.attn_cfg
+    a, m = cfg.attn_cfg, cfg.mamba
     page = (a.num_layers, num_pages, page_size, a.num_kv_heads,
             a.kv_head_dim)
     entries = 2 * (state_slots + 1)
-    nm = cfg.count("M")
+    nm = cfg.state_layers
     return HybridCache(
         k=jnp.zeros(page, cfg.dtype), v=jnp.zeros(page, cfg.dtype),
-        conv=jnp.zeros((nm, entries, *cfg.conv_state_shape), cfg.dtype),
-        ssm=jnp.zeros((nm, entries, *cfg.ssm_state_shape), jnp.float32),
+        conv=jnp.zeros((nm, entries, *m.conv_state_shape), cfg.dtype),
+        ssm=jnp.zeros((nm, entries, *m.ssm_state_shape), jnp.float32),
     )
 
 
@@ -298,18 +333,23 @@ def init_cache(
 # ---------------------------------------------------------------------------
 
 
+def mamba_shapes(m: Mamba2Dims, hidden: int) -> dict:
+    """The mixer's own leaves, a layer."""
+    return {
+        "in_proj": (hidden, m.in_proj_dim),
+        "conv_w": (m.conv_kernel, m.conv_dim),
+        "conv_b": (m.conv_dim,), "dt_bias": (m.num_heads,),
+        "A_log": (m.num_heads,), "D": (m.num_heads,),
+        "gate_norm": (m.d_inner,), "out_proj": (m.d_inner, hidden),
+    }
+
+
 def _shapes(cfg: NemotronHConfig) -> dict:
     h, e = cfg.hidden_size, cfg.experts_here
     w, sw = cfg.expert_width, cfg.moe_shared_expert_intermediate_size
     qd, kvd = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
     return {
-        "mamba": {
-            "norm": (h,), "in_proj": (h, cfg.in_proj_dim),
-            "conv_w": (cfg.conv_kernel, cfg.conv_dim),
-            "conv_b": (cfg.conv_dim,), "dt_bias": (cfg.mamba_num_heads,),
-            "A_log": (cfg.mamba_num_heads,), "D": (cfg.mamba_num_heads,),
-            "gate_norm": (cfg.d_inner,), "out_proj": (cfg.d_inner, h),
-        },
+        "mamba": {"norm": (h,), **mamba_shapes(cfg.mamba, h)},
         "attn": {
             "norm": (h,), "wq": (h, qd), "wk": (h, kvd), "wv": (h, kvd),
             "wo": (qd, h),
@@ -488,7 +528,7 @@ def ssm_recurrence(x, dt, a_head, bmat, cmat, s0):
     return jnp.moveaxis(ys, 0, 1), s
 
 
-def _conv_and_state(xbc, prev, n_valid, lp, cfg: NemotronHConfig):
+def _conv_and_state(xbc, prev, n_valid, lp, cfg: Mamba2Dims):
     """The causal depthwise conv over a chunk that continues `prev` (the
     window the sequence left: [B, K-1, C]), then SiLU; and the window it
     leaves, the last K-1 rows before the first padding token."""
@@ -508,25 +548,30 @@ def _conv_and_state(xbc, prev, n_valid, lp, cfg: NemotronHConfig):
 def mamba_mixer(
     x: jax.Array,  # the groups' rows (join_rows), post-norm
     lp: dict,
-    cfg: NemotronHConfig,
+    cfg: Mamba2Dims,
     conv_pool: jax.Array,
     ssm_pool: jax.Array,
-    layer,  # this layer's index among the M layers
+    layer,  # this layer's index among the layers that keep a state
     groups,
+    in_proj_scale: Optional[jax.Array] = None,  # [in_proj_dim]
 ):
     """Returns (out shaped like x, conv_pool, ssm_pool). The projections,
     the gate and the norm run on every group's rows at once; the conv and
-    the recurrence per group, each row from its own slot. Scopes, under
+    the recurrence per group, each row from its own slot. `in_proj_scale`
+    multiplies `in_proj`'s OUTPUT column by column (models/falcon_h1.py's
+    `ssm_multipliers` over the z, x, B, C and dt segments). Scopes, under
     the caller's `attn` (the layer's sequence mixer): `ssm/in_proj`,
     `ssm/conv`, `ssm/scan`, `ssm/gate_norm`, `ssm/out`."""
     f32 = jnp.float32
-    h_, p_, n_, g_ = (cfg.mamba_num_heads, cfg.mamba_head_dim,
-                      cfg.ssm_state_size, cfg.n_groups)
+    h_, p_, n_, g_ = (cfg.num_heads, cfg.head_dim, cfg.state_size,
+                      cfg.n_groups)
     di = cfg.d_inner
     use_kernel = None if cfg.kernels else False  # None: on a TPU
     with jax.named_scope("ssm"):
         with jax.named_scope("in_proj"):
             zxbcdt = _mm(x, lp, "in_proj", cfg.dtype)
+            if in_proj_scale is not None:
+                zxbcdt = zxbcdt * in_proj_scale.astype(zxbcdt.dtype)
             z = zxbcdt[..., :di]
             xbc = zxbcdt[..., di : di + cfg.conv_dim]
             dt_raw = zxbcdt[..., di + cfg.conv_dim :]
@@ -567,7 +612,9 @@ def mamba_mixer(
                     )
                     y = y[:, None]
                 else:
-                    s0 = ssm_state.read_rows(ssm_pool, layer, ridx)
+                    s0 = ssm_state.read_rows(
+                        ssm_pool, layer, ridx, use_kernel=use_kernel
+                    )
                     s0 = jnp.where(fresh[:, None, None, None], 0.0, s0)
                     y, s_end = ssd_chunk_scan(
                         xs, dt, a_head, bmat, cmat, s0, cfg.chunk_size
@@ -669,7 +716,7 @@ def forward_groups(
     def mamba_layer(h, pools, lp, li):
         with jax.named_scope("attn"):
             x = rms_norm(h, lp["norm"], eps)
-            out, *pools = mamba_mixer(x, lp, cfg, *pools, li, groups)
+            out, *pools = mamba_mixer(x, lp, cfg.mamba, *pools, li, groups)
         return h + out, tuple(pools)
 
     def attn_layer(h, kv, lp, li):

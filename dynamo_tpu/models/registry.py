@@ -352,33 +352,35 @@ def _moe_adapter(name: str, moe_cfg, mesh=None) -> ModelAdapter:
     )
 
 
-def _nemotron_h_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
-    """Nemotron-H: pages for its attention layers and a state slot a
-    sequence for its Mamba-2 layers. `pt` of every step function is the
-    pair (page tables, state rows)."""
+def _hybrid_adapter(name: str, cfg, mod, family: str, axes,
+                    mesh=None) -> ModelAdapter:
+    """A family with Mamba-2 layers (`mod`: models/nemotron_h.py or
+    models/falcon_h1.py): pages for its attention and a state slot a
+    sequence for its state-space layers, in one `HybridCache`. `pt` of
+    every step function is the pair (page tables, state rows)."""
     from dynamo_tpu.models import nemotron_h as nh
 
     if mesh is not None:
         raise ValueError(
-            f"{name}: Nemotron-H runs on one chip (its experts over a "
-            "mesh are not implemented): use tp=dp=ep=sp=1"
+            f"{name}: {family} runs on one chip (its layers over a mesh "
+            "are not implemented): use tp=dp=ep=sp=1"
         )
 
     def fwd_hidden(params, tokens, positions, valid, kv, pt, **kw):
         if kw.pop("mm_embeds", None) is not None:
             raise ValueError("multimodal prompts are not supported for "
-                             "Nemotron-H")
+                             f"{family}")
         kw.pop("mm_mask", None)
-        return nh.forward_hidden(
+        return mod.forward_hidden(
             params, cfg, tokens, positions, valid, kv, *pt, **kw
         )
 
     def fwd(params, tokens, positions, valid, kv, pt):
         h, kv = fwd_hidden(params, tokens, positions, valid, kv, pt)
-        return nh.compute_logits(params, cfg, h), kv
+        return mod.compute_logits(params, cfg, h), kv
 
     def fwd_mixed(params, prompt, decode, kv, first_chunk=False):
-        (h_p, h_d), kv = nh.forward_groups(
+        (h_p, h_d), kv = mod.forward_groups(
             params, cfg,
             [
                 StepGroup(*prompt[:3], prompt[3][0], first_chunk,
@@ -394,33 +396,62 @@ def _nemotron_h_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
         if kv_quantize:
             raise ValueError(
                 "kv_quantize is not supported for a model with state-space "
-                "layers (Nemotron-H): its recurrent state is float32 and "
-                "its four attention layers' pages are a twentieth of the "
-                "state; run with kv_quantize=None"
+                f"layers ({family}): a sequence is its pages AND a float32 "
+                "recurrent state that stays float32, and narrowing the "
+                "pages alone has no tested path beside the state pool; run "
+                "with kv_quantize=None"
             )
         return nh.init_cache(cfg, num_pages, page_size, state_slots)
 
     def no_mesh_specs(*_a, **_k):
         from dynamo_tpu.parallel.logical import resolve
 
-        return resolve(nh.nemotron_h_logical_axes(cfg))
+        return resolve(axes(cfg))
 
     return ModelAdapter(
         name=name,
         config=cfg,
         vocab_size=cfg.vocab_size,
-        init_params=lambda key: nh.init_params(key, cfg),
+        init_params=lambda key: mod.init_params(key, cfg),
         forward=fwd,
         forward_hidden=fwd_hidden,
         forward_hidden_mixed=fwd_mixed,
-        compute_logits=lambda params, h: nh.compute_logits(params, cfg, h),
+        compute_logits=lambda params, h: mod.compute_logits(params, cfg, h),
         init_kv=init_kv,
         param_specs=no_mesh_specs,
         kv_spec=lambda kv_quantize=None: None,
-        logical_axes=lambda quantized=False: nh.nemotron_h_logical_axes(cfg),
-        state_layers=cfg.count("M"),
+        logical_axes=lambda quantized=False: axes(cfg),
+        state_layers=cfg.state_layers,
         state_slot_bytes=nh.state_bytes_per_slot(cfg),
     )
+
+
+def _nemotron_h_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
+    from dynamo_tpu.models import nemotron_h as nh
+
+    return _hybrid_adapter(name, cfg, nh, "Nemotron-H",
+                           nh.nemotron_h_logical_axes, mesh)
+
+
+def _falcon_h1_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
+    from dynamo_tpu.models import falcon_h1 as fh
+
+    return _hybrid_adapter(name, cfg, fh, "Falcon-H1",
+                           fh.falcon_h1_logical_axes, mesh)
+
+
+def _falcon_h1_presets() -> dict:
+    from dynamo_tpu.models.falcon_h1 import FalconH1Config
+
+    return {
+        # Falcon-H1-34B-Instruct as published: 72 layers (67 GB in bf16:
+        # shape tests and a later multi-chip issue)
+        "falcon-h1-34b": FalconH1Config.falcon_h1_34b,
+        # one stage of a pipeline over depth: 6 of the 72 layers, with the
+        # embedding and the head (chipbench/configs/falcon-h1-34b-1chip.json)
+        "falcon-h1-34b-6l": lambda: FalconH1Config.falcon_h1_34b(6),
+        "falcon-h1-tiny": FalconH1Config.tiny,
+    }
 
 
 def _nemotron_h_presets() -> dict:
@@ -469,7 +500,7 @@ def list_presets() -> list[str]:
     dry-resolves each one's logical axes through the rule table."""
     return sorted(_LLAMA_PRESETS) + sorted(_moe_presets()) + sorted(
         _mla_presets()
-    ) + sorted(_nemotron_h_presets())
+    ) + sorted(_nemotron_h_presets()) + sorted(_falcon_h1_presets())
 
 
 def get_model(
@@ -507,13 +538,19 @@ def get_model(
         moe_cfg = moe_presets[key]()
     elif key in mla_presets:
         mla_cfg = mla_presets[key]()
-    elif key in _nemotron_h_presets():
-        nh_cfg = _nemotron_h_presets()[key]()
+    elif key in _nemotron_h_presets() or key in _falcon_h1_presets():
+        # a family with state-space layers: an adapter of its own
+        presets, adapter = (
+            (_nemotron_h_presets(), _nemotron_h_adapter)
+            if key in _nemotron_h_presets()
+            else (_falcon_h1_presets(), _falcon_h1_adapter)
+        )
+        hy_cfg = presets[key]()
         if dtype is not None:
-            nh_cfg = _with_dtype(nh_cfg, dtype)
+            hy_cfg = _with_dtype(hy_cfg, dtype)
         if attention_impl is not None:
-            nh_cfg = replace(nh_cfg, attention_impl=attention_impl)
-        return _nemotron_h_adapter(name, nh_cfg, mesh=mesh)
+            hy_cfg = replace(hy_cfg, attention_impl=attention_impl)
+        return adapter(name, hy_cfg, mesh=mesh)
     elif os.path.isdir(name) and os.path.exists(os.path.join(name, "config.json")):
         with open(os.path.join(name, "config.json")) as f:
             hf = json.load(f)

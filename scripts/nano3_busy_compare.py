@@ -1,19 +1,20 @@
-"""python scripts/nano3_busy_compare.py [--rehearse] [--seed N]
+"""python scripts/nano3_busy_compare.py [--cell NAME] [--rehearse] [--seed N]
 
-The comparison `nano3-chat-churn`'s own `correct` cannot make (its two
+The comparison a state model's cell's own `correct` cannot make (its two
 greedy streams of 48 + 64 tokens cross no chunk boundary and run beside
 no other row): ONE request under the cell's own shapes, outside every
-timing, teacher-forced against the plain reference
-(chipbench/references/nemotron_h.py) on logits.
+timing, teacher-forced against the configuration's plain reference
+(its `reference_module`) on logits. `--cell` is `nano3-chat-churn` (the
+default) or `falconh1-longdoc`.
 
-The engine is the configuration's (`nemotron3-nano-30b-a3b-1chip`: its
-preset and serve flags, launch-ahead on, fused 8-step dispatches, mixed
-steps). The other slots are kept busy with the cell's traffic (prompt
-and answer lengths from `chat-churn.json`, sampled 0.7 / 0.9, a new
-request for every one that ends, so admissions run beside the target
-all the way). The target: a prompt of ~1,300 tokens (three chunks of
-512, the last one padded into its bucket), then 64 greedy tokens with
-their log-probs. Rollbacks are FORCED before and among the compared
+The engine is the cell's configuration's (its preset and serve flags,
+launch-ahead on, fused 8-step dispatches, mixed steps). The other slots
+are kept busy with the cell's traffic (prompt and answer lengths from
+its traffic file, sampled 0.7 / 0.9, a new request for every one that
+ends, so admissions run beside the target all the way). The target: a
+prompt of ~1,300 tokens for `nano3-chat-churn` (three chunks of 512) or
+~5,000 for `falconh1-longdoc` (ten chunks), the last chunk padded into
+its bucket, then 64 greedy tokens with their log-probs. Rollbacks are FORCED before and among the compared
 tokens: a neighbour is aborted while a dispatch launched ahead is on the
 device (during the target's prefill, and twice during its decode), so
 the target's state has been advanced by a dispatch that was then thrown
@@ -51,9 +52,10 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--seed", type=int, default=20260928)
     ap.add_argument("--prompt", type=int, default=None)
+    ap.add_argument("--cell", default="nano3-chat-churn")
     ns = ap.parse_args(argv)
     man = manifest.load()
-    cell = manifest.cell(man, "nano3-chat-churn")
+    cell = manifest.cell(man, ns.cell)
     conf = manifest.config_of(man, cell)
     mix = manifest.traffic_of(cell)
     on_chip = jax.devices()[0].platform == "tpu"
@@ -71,7 +73,9 @@ def main(argv=None) -> int:
     cfg = eng.config
     vocab = hf["vocab_size"]
     rng = np.random.default_rng(ns.seed)
-    n_prompt = ns.prompt or (1300 if on_chip else 2 * cfg.prefill_chunk + 11)
+    n_prompt = ns.prompt or (
+        {"falconh1-longdoc": 5000}.get(ns.cell, 1300) if on_chip
+        else 2 * cfg.prefill_chunk + 11)
     shape = np.random.default_rng(7)
     counter = iter(range(1 << 30))
 

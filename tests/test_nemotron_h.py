@@ -56,7 +56,8 @@ def test_presets_are_the_published_model_and_its_cut():
     assert (full.num_layers, full.count("M"), full.count("E"),
             full.count("*")) == (52, 23, 23, 6)
     assert full.segments == [("MEMEM*E", 5), ("MEMEMEM*EMEMEMEME", 1)]
-    assert (full.d_inner, full.conv_dim, full.in_proj_dim) == (
+    assert (full.mamba.d_inner, full.mamba.conv_dim,
+            full.mamba.in_proj_dim) == (
         4096, 6144, 10304)
     n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(jax.eval_shape(
         lambda: nh.init_params(jax.random.key(0), full))))
@@ -93,9 +94,23 @@ def test_chunked_scan_is_the_token_by_token_recurrence(t, chunk, valid):
     np.testing.assert_allclose(s, s_valid, atol=1e-4)
 
 
-def test_state_kernels_interpreted_match_plain_jnp():
+@pytest.mark.parametrize("h,p,n,g,block_bytes,block", [
+    # Nemotron-3-Nano's shape class: a block is the whole row
+    pytest.param(4, 16, 16, 2, None, 4, id="one-block-a-row"),
+    # Falcon-H1-34B's: a block is one group of the row's heads
+    pytest.param(4, 16, 32, 2, 2 * 16 * 32 * 4, 2, id="a-block-a-group"),
+    pytest.param(8, 8, 128, 2, 4 * 8 * 128 * 4, 4, id="two-groups-of-four"),
+    # a group too large for a block: a whole fraction of it
+    pytest.param(4, 16, 32, 1, 2 * 16 * 32 * 4, 2, id="half-a-group"),
+    pytest.param(4, 16, 16, 2, 16 * 16 * 4, 1, id="a-head-a-block"),
+])
+def test_state_kernels_interpreted_match_plain_jnp(
+        monkeypatch, h, p, n, g, block_bytes, block):
+    if block_bytes is not None:
+        monkeypatch.setattr(ssm_state, "STATE_BLOCK_BYTES", block_bytes)
+    assert ssm_state.head_block(h, h // g, p, n) == block
     rng = np.random.default_rng(3)
-    layers, entries, h, p, n, g, b = 3, 7, 4, 16, 16, 2, 4
+    layers, entries, b = 3, 7, 4
     pool = jnp.asarray(rng.normal(size=(layers, entries, h, p, n)),
                        jnp.float32)
     u = jnp.asarray(rng.normal(size=(b, h, p)), jnp.float32)
@@ -105,11 +120,15 @@ def test_state_kernels_interpreted_match_plain_jnp():
     ridx, widx = jnp.array([1, 2, 5, 0]), jnp.array([4, 3, 5, 0])
     y0, p0 = ssm_state.ssm_decode_step(
         pool, 1, ridx, widx, u, dec, bm, cm, use_kernel=False)
+    y_def, new_def = ssm_state.ssm_decode_reference(
+        pool[1, ridx], u, dec, bm, cm)
+    np.testing.assert_array_equal(y0, y_def)
     y1, p1 = ssm_state.ssm_decode_step(
         pool, jnp.int32(1), ridx, widx, u, dec, bm, cm, use_kernel=True,
         interpret=True)
     np.testing.assert_allclose(y1, y0, atol=1e-5)
     np.testing.assert_allclose(p1, p0, atol=1e-6)
+    np.testing.assert_allclose(p1[1, widx[:3]], new_def[:3], atol=1e-6)
     # the read entries of rows that write elsewhere, and every other
     # layer, are as they were
     np.testing.assert_array_equal(p1[1, 1:3], pool[1, 1:3])
@@ -119,6 +138,12 @@ def test_state_kernels_interpreted_match_plain_jnp():
     w1 = ssm_state.write_rows(pool, jnp.int32(2), widx, rows,
                               use_kernel=True)
     np.testing.assert_array_equal(w1[:, 1:], w0[:, 1:])  # 0: the null slot
+    # the row reader is the writer's mirror: one DMA a row
+    np.testing.assert_array_equal(
+        ssm_state.read_rows(w1, jnp.int32(2), widx[:3], use_kernel=True),
+        ssm_state.read_rows(w1, 2, widx[:3]))
+    np.testing.assert_array_equal(
+        ssm_state.read_rows(w1, 2, widx[:3]), rows[:3])
 
 
 def _serve(adapter, params, toks, chunks, t_bucket=None, slot=1, slots=4):

@@ -422,13 +422,27 @@ def test_nemotron3_nano_state_comparison_compiles_at_published_widths(topo):
     assert "ssm_decode_step" in compiled.as_text()
 
 
-@pytest.mark.parametrize("rows,t,b_pre", [
-    pytest.param(64, 1, 0, id="decode-64-rows-fused-8"),
-    pytest.param(1, 512, 0, id="prefill-chunk-from-a-slot"),
-    pytest.param(64, 128, 1, id="mixed-64-rows-beside-a-chunk"),
+#: (preset, pages, state slots, --max-context, ssm pool, conv pool)
+_NANO3 = ("nemotron3-nano-28l-16e", 3200, 72, 4096,
+          (12, 146, 64, 64, 128), (12, 146, 144, 128))
+_FALCON = ("falcon-h1-34b-6l", 3300, 36, 8192,
+           (6, 74, 32, 128, 256), (6, 74, 120, 128))
+
+
+@pytest.mark.parametrize("served,rows,t,b_pre", [
+    pytest.param(_NANO3, 64, 1, 0, id="decode-64-rows-fused-8"),
+    pytest.param(_NANO3, 1, 512, 0, id="prefill-chunk-from-a-slot"),
+    pytest.param(_NANO3, 64, 128, 1, id="mixed-64-rows-beside-a-chunk"),
+    pytest.param(_FALCON, 32, 1, 0, id="falcon-h1-decode-32-rows-fused-8"),
+    pytest.param(_FALCON, 32, 512, 1,
+                 id="falcon-h1-mixed-32-rows-beside-a-chunk"),
+    pytest.param(_FALCON, 32, 512, 2,
+                 id="falcon-h1-mixed-32-rows-beside-two-pieces"),
+    pytest.param(_FALCON, 32, 512, 4,
+                 id="falcon-h1-mixed-32-rows-beside-four-pieces"),
 ])
-def test_nemotron3_nano_cut_step_compiles_at_published_widths(
-        topo, rows, t, b_pre):
+def test_hybrid_cut_step_compiles_at_published_widths(
+        topo, served, rows, t, b_pre):
     """Whole steps of `nemotron3-nano-28l-16e` as `nano3-chat-churn`
     serves it (bf16, 3200 pages, 72 state slots in two generations,
     --max-context 4096): the state kernel and the row writer of the
@@ -436,17 +450,23 @@ def test_nemotron3_nano_cut_step_compiles_at_published_widths(
     matmul at 2688 x 1920 (1856 padded to whole lanes) go through the TPU
     compiler inside the scan over units of seven layers; both pools are
     updated in place, and the program fits the chip beside 6.9 GB of
-    weights, 3.8 GB of state and 0.84 GB of pages."""
-    adapter = get_model("nemotron3-nano-28l-16e", dtype="bfloat16",
-                        attention_impl="pallas")
+    weights, 3.8 GB of state and 0.84 GB of pages. And of
+    `falcon-h1-34b-6l` as `falconh1-longdoc` serves it (bf16, 3300 pages,
+    36 slots, --max-context 8192): the state kernel blocked over heads
+    (one group of 16 heads x 128 x 256 a grid step), the row writer moving
+    4.19 MB rows, the page walk at 5 query heads a KV head and the flash
+    chunk over paged history in every layer of a plain scan, beside 10.5
+    GB of weights, 1.9 GB of state and 2.6 GB of pages."""
+    preset, pages, slots, context, ssm_shape, conv_shape = served
+    adapter = get_model(preset, dtype="bfloat16", attention_impl="pallas")
     chip = SingleDeviceSharding(topo.devices[0])
     params = _on(chip, jax.eval_shape(
         lambda: adapter.init_params(jax.random.key(0))))
     kv = _on(chip, jax.eval_shape(
-        lambda: adapter.init_kv(3200, PAGE, state_slots=72)))
-    assert kv.ssm.shape == (12, 146, 64, 64, 128)
-    assert kv.conv.shape == (12, 146, 144, 128)
-    mp = 4096 // PAGE
+        lambda: adapter.init_kv(pages, PAGE, state_slots=slots)))
+    assert kv.ssm.shape == ssm_shape
+    assert kv.conv.shape == conv_shape
+    mp = context // PAGE
 
     def rows_of(b, tt):
         return (
@@ -503,6 +523,10 @@ def test_nemotron3_nano_cut_step_compiles_at_published_widths(
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
     text = compiled.as_text()
     assert "state_write_rows" in text
+    if t > 1:  # a chunk starts from its slot's state: one DMA a row, and
+        # no temporary the size of the pool (XLA's gather made two)
+        assert "state_read_rows" in text
+        assert mem.temp_size_in_bytes < 1.2e9
     if t == 1 or b_pre:
         assert "ssm_decode_step" in text
         assert "paged_decode_attention" in text
