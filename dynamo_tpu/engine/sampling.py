@@ -2,15 +2,16 @@
 
 One jitted function handles a heterogeneous batch (per-row params) so decode
 stays a single XLA program: greedy rows take argmax, sampling rows take a
-Gumbel draw over the top-k/top-p-masked, temperature-scaled distribution.
+Gumbel draw over the top-k/top-p-masked, temperature-scaled distribution:
+the `k_cap` most likely tokens (requested top_k values above k_cap are
+clamped) under their *true* probabilities (one logsumexp over the vocab).
 
-TPU note: a full-vocab argsort is a bitonic network over 128k lanes and
-costs tens of milliseconds — it would dominate the whole decode step. The
-sampler instead takes the top `k_cap` candidates with lax.top_k (already
-sorted) and computes their *true* probabilities under the full distribution
-via one logsumexp over the vocab. Sampling is thus truncated to the k_cap
-most likely tokens (requested top_k values above k_cap are clamped); top-p
-mass is exact w.r.t. the full softmax.
+TPU note: nothing as wide as the vocabulary is sorted. `top_candidates`
+keeps k_cap blocks of 128 ids by their maxima, then k_cap sub-blocks of 16,
+and stable-sorts 1,188-2,040 maxima, 512 maxima and 1,024 values: exact
+including ties. `lax.top_k(scaled, 64)` there cost 1.40-1.98 ms a step at
+64 x 102k-152k and 32 x 261k logits (this: 0.22-0.30) and left equal values
+as its kernel happened on them (v5e, `scripts/sample_bench.py`; PERF.md 6).
 """
 
 from __future__ import annotations
@@ -22,6 +23,10 @@ _NEG_INF = -1e30
 
 #: static candidate-set bound; per-request top_k is clamped to this
 DEFAULT_K_CAP = 64
+
+#: ids a block of `top_candidates` holds: the TPU's lane width over the
+#: vocabulary, then sub-blocks of the chosen blocks (constants: PERF.md 6)
+CAND_BLOCKS = (128, 16)
 
 
 def build_output_counts(
@@ -91,6 +96,85 @@ def apply_logit_bias(
     return logits.at[rows, bias_ids].add(vals)
 
 
+def _total_order(bits: jax.Array) -> jax.Array:
+    """i32 bits of floats <-> keys in floats' total order; its own inverse."""
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
+def _top_keys(keys, k, widths):
+    """One level: a row's k largest i32 keys and their positions, [B, k]."""
+    b, n = keys.shape
+    w = widths[0] if widths else n
+    nb = -(-n // w)
+    if nb <= k:  # every block would be chosen: one stable sort
+        place = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (b, n))
+        top, pos = jax.lax.sort((~keys, place), num_keys=1, is_stable=True)
+        return ~top[:, :k], pos[:, :k]
+    pad = ((0, 0), (0, nb * w - n))  # past V: a key below every float's
+    blocks = jnp.pad(keys, pad, constant_values=-(2**31)).reshape(b, nb, w)
+    _, chosen = _top_keys(jnp.max(blocks, axis=-1), k, ())
+    chosen = jnp.sort(chosen, axis=-1)  # ascending: positions keep id order
+    inside = jnp.take_along_axis(blocks, chosen[:, :, None], axis=1)
+    top, pos = _top_keys(inside.reshape(b, k * w), k, widths[1:])
+    picked = (pos // w)[:, :, None] == jnp.arange(k)  # [B, K, K] one-hot
+    block = jnp.sum(jnp.where(picked, chosen[:, None, :], 0), axis=-1)
+    return top, block * w + pos % w  # (a gather of k scalars a row is slower)
+
+
+def top_candidates(scaled: jax.Array, k_cap: int) -> tuple[jax.Array, jax.Array]:
+    """(values [B, K] descending, ids [B, K]): the k_cap <= V largest of
+    each row of `scaled` [B, V] f32, of equal values the lower id first (-0
+    below +0): what `lax.top_k(scaled, k_cap)` is documented to return and
+    returns on the CPU, bit for bit, here on every backend.
+
+    A row is blocks of `CAND_BLOCKS[0]` consecutive ids. Block maxima,
+    stably sorted, choose k_cap blocks; their numbers are put in ascending
+    order and their values gathered in that order; the same step over
+    sub-blocks of `CAND_BLOCKS[1]` leaves k_cap x 16 values, and a stable
+    sort ends it. With no more blocks than k_cap (tiny vocabularies) that
+    static shape alone goes straight to the sort.
+
+    Exact at each level: let t be the k_cap-th largest value. A block with
+    an entry > t has a maximum > t; there are fewer than k_cap such blocks
+    and the sort over maxima takes them all. A stable top-k takes the
+    lowest-numbered t-entries; those in blocks whose maximum is exactly t
+    are a prefix, by id, of all t-entries in such blocks, so lie in a prefix,
+    by number, of those blocks, and the stable sort takes just the lowest-
+    numbered blocks among equal maxima. Gathered in ascending block order,
+    positions keep id order, so the next level breaks ties as a stable top-k
+    would. -inf (min_tokens bans, whole blocks) is an ordinary value."""
+    bits = jax.lax.bitcast_convert_type(scaled, jnp.int32)
+    top, ids = _top_keys(_total_order(bits), k_cap, CAND_BLOCKS)
+    return jax.lax.bitcast_convert_type(_total_order(top), jnp.float32), ids
+
+
+def _masked_candidates(logits, temperature, top_p, top_k, seeds, counters, k_cap):
+    """What `sample` and `spec_accept_step` draw from: (greedy [B], cand_idx,
+    keep, masked scaled logits, gumbel noise at the row's counter: [B, K])."""
+    k_cap = min(k_cap, logits.shape[1])
+    greedy = temperature <= 0.0
+    safe_t = jnp.where(greedy, 1.0, jnp.maximum(temperature, 1e-6))
+    scaled = logits / safe_t[:, None]
+
+    cand_logits, cand_idx = top_candidates(scaled, k_cap)  # [B, K] descending
+    lse = jax.scipy.special.logsumexp(scaled, axis=-1, keepdims=True)
+    probs = jnp.exp(cand_logits - lse)  # true full-softmax mass of candidates
+    cum = jnp.cumsum(probs, axis=-1)
+    # top-p: keep tokens whose preceding mass is < p (first always kept)
+    keep_p = (cum - probs) < top_p[:, None]
+    # top-k: keep the first k ranks (k == 0 disables => k_cap)
+    eff_k = jnp.where(top_k > 0, jnp.minimum(top_k, k_cap), k_cap)
+    keep = keep_p & (jnp.arange(k_cap)[None, :] < eff_k[:, None])
+    masked = jnp.where(keep, cand_logits, _NEG_INF)
+
+    def row_gumbel(seed, counter):
+        key = jax.random.fold_in(jax.random.key(seed), counter)
+        return jax.random.gumbel(key, (k_cap,), jnp.float32)
+
+    gumbel = jax.vmap(row_gumbel)(seeds, counters)  # [B, K]
+    return greedy, cand_idx, keep, masked, gumbel
+
+
 def sample(
     logits: jax.Array,  # [B, V] f32
     temperature: jax.Array,  # [B] f32 (<=0 => greedy)
@@ -103,31 +187,9 @@ def sample(
     """Per-row PRNG: each request draws from key(seed) folded with its own
     token counter, so a (prompt, seed) pair reproduces exactly regardless of
     what else shares the batch or how steps interleave."""
-    b, v = logits.shape
-    k_cap = min(k_cap, v)
-    greedy = temperature <= 0.0
-    safe_t = jnp.where(greedy, 1.0, jnp.maximum(temperature, 1e-6))
-    scaled = logits / safe_t[:, None]
-
-    # Top-k_cap candidates, descending — the only vocab-wide work besides
-    # one reduction for the softmax denominator.
-    cand_logits, cand_idx = jax.lax.top_k(scaled, k_cap)  # [B, K]
-    lse = jax.scipy.special.logsumexp(scaled, axis=-1, keepdims=True)
-    probs = jnp.exp(cand_logits - lse)  # true full-softmax mass of candidates
-    cum = jnp.cumsum(probs, axis=-1)
-    ranks = jnp.arange(k_cap)[None, :]
-    # top-p: keep tokens whose preceding mass is < p (first always kept)
-    keep_p = (cum - probs) < top_p[:, None]
-    # top-k: keep the first k ranks (k == 0 disables => k_cap)
-    eff_k = jnp.where(top_k > 0, jnp.minimum(top_k, k_cap), k_cap)
-    keep = keep_p & (ranks < eff_k[:, None])
-    masked = jnp.where(keep, cand_logits, _NEG_INF)
-
-    def row_gumbel(seed, counter):
-        key = jax.random.fold_in(jax.random.key(seed), counter)
-        return jax.random.gumbel(key, (k_cap,), jnp.float32)
-
-    gumbel = jax.vmap(row_gumbel)(seeds, counters)  # [B, K]
+    greedy, cand_idx, _, masked, gumbel = _masked_candidates(
+        logits, temperature, top_p, top_k, seeds, counters, k_cap
+    )
     sampled_rank = jnp.argmax(masked + gumbel, axis=-1)  # [B]
     sampled = jnp.take_along_axis(cand_idx, sampled_rank[:, None], axis=-1)[:, 0]
     return jnp.where(greedy, jnp.argmax(logits, axis=-1), sampled).astype(jnp.int32)
@@ -159,39 +221,17 @@ def spec_accept_step(
     spec-on sampling is distributionally identical to spec-off sampling
     (pinned by tests/test_spec_draft.py). Greedy rows (temperature<=0)
     take the argmax and accept iff it equals the draft — the bit-exact
-    greedy path. The bonus position (has_draft=False) draws with the
-    SAME fold_in(key(seed), counter) gumbel stream as `sample()`, so a
-    bonus token is bit-identical to what the plain sampler would have
-    drawn at that counter.
+    greedy path. The bonus position (has_draft=False) IS `sample()` at
+    that counter.
     """
-    b, v = logits.shape
-    k_cap = min(k_cap, v)
-    greedy = temperature <= 0.0
-    safe_t = jnp.where(greedy, 1.0, jnp.maximum(temperature, 1e-6))
-    scaled = logits / safe_t[:, None]
-    cand_logits, cand_idx = jax.lax.top_k(scaled, k_cap)  # [B, K]
-    lse = jax.scipy.special.logsumexp(scaled, axis=-1, keepdims=True)
-    probs = jnp.exp(cand_logits - lse)
-    cum = jnp.cumsum(probs, axis=-1)
-    ranks = jnp.arange(k_cap)[None, :]
-    keep_p = (cum - probs) < top_p[:, None]
-    eff_k = jnp.where(top_k > 0, jnp.minimum(top_k, k_cap), k_cap)
-    keep = keep_p & (ranks < eff_k[:, None])
-    masked = jnp.where(keep, cand_logits, _NEG_INF)
-    greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    def row_gumbel(seed, counter):
-        key = jax.random.fold_in(jax.random.key(seed), counter)
-        return jax.random.gumbel(key, (k_cap,), jnp.float32)
-
-    gumbel = jax.vmap(row_gumbel)(seeds, counters)  # [B, K]
-
     if not has_draft:
-        rank = jnp.argmax(masked + gumbel, axis=-1)
-        samp_tok = jnp.take_along_axis(cand_idx, rank[:, None], axis=-1)[:, 0]
-        chosen = jnp.where(greedy, greedy_tok, samp_tok).astype(jnp.int32)
-        return chosen, jnp.ones((b,), bool)
+        chosen = sample(logits, temperature, top_p, top_k, seeds, counters, k_cap)
+        return chosen, jnp.ones(chosen.shape, bool)
 
+    greedy, cand_idx, keep, masked, gumbel = _masked_candidates(
+        logits, temperature, top_p, top_k, seeds, counters, k_cap
+    )
+    greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     # p_eff(draft): the draft's true mass under the kept-candidate softmax
     kept_lse = jax.scipy.special.logsumexp(masked, axis=-1, keepdims=True)
     is_draft = cand_idx == draft[:, None]

@@ -533,6 +533,44 @@ def test_hybrid_cut_step_compiles_at_published_widths(
     assert _mosaic_calls(compiled) >= 4
 
 
+@pytest.mark.parametrize("rows,vocab", [
+    (64, 152_064),  # qwen2-longgen
+    (32, 261_120),  # falconh1-longdoc
+    (16, 32_064),  # phi3-chat-closed: not a multiple of the block
+])
+def test_sample_compiles_with_nothing_sorted_as_wide_as_the_vocabulary(
+    topo, rows, vocab
+):
+    """`engine.sampling.sample` alone at a cell's rows x vocabulary: its
+    temporaries stay under two float32 copies of the logits, and no sort or
+    top-k of the optimized program takes an operand V wide."""
+    import re
+
+    from dynamo_tpu.engine.sampling import sample
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    row = lambda dtype: _sds((rows,), dtype, chip)  # noqa: E731
+    compiled = jax.jit(sample).lower(
+        _sds((rows, vocab), jnp.float32, chip), row(jnp.float32),
+        row(jnp.float32), row(jnp.int32), row(jnp.uint32), row(jnp.int32),
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * rows * vocab * 4
+    text = compiled.as_text()
+    # names the program defines with a [rows, vocab] result (tuples too)
+    wide = set(re.findall(
+        rf"%([\w.\-]+) = [^=]*?\[{rows},{vocab}\][^=]*? [\w\-]+\(", text
+    ))
+    assert wide, "no [rows, vocab] value found: the HLO text reads otherwise"
+    sorts = [
+        line for line in text.splitlines()
+        if re.search(r" sort\(|custom_call_target=\"(TopK|ApproxTopK)", line)
+    ]
+    assert sorts, "no sort found: the HLO text reads otherwise"
+    for line in sorts:
+        operands = re.findall(r"%([\w.\-]+)", line.split(" = ", 1)[1])
+        assert not wide & set(operands), line[:300]
+
+
 def test_engine_refuses_narrow_pages_with_too_few_kv_heads_per_shard():
     """int8 pages under tp=4 leave llama3-1b 2 kv heads per shard, which
     Mosaic cannot DMA: on a TPU the engine says so at construction
