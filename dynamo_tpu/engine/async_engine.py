@@ -224,11 +224,17 @@ class AsyncEngineRunner:
 
     def _emit(self, outputs) -> None:
         wd = self.watchdog
+        clock = getattr(self.engine.metrics, "dry_clock", None)
         with phase(
             self.engine.metrics, "engine.emit", "time_emit_ms",
             posted=len(outputs),
         ):
             for out in outputs:
+                if clock is not None:
+                    # posting a fused dispatch's tokens takes
+                    # milliseconds (the event loop's thread contends for
+                    # the interpreter): a boundary of the dry clock each
+                    clock.poll()
                 if wd is not None and out.new_token_ids:
                     # engine-side progress mark: a wedged engine thread
                     # stops exactly these, which is what the watchdog
@@ -339,7 +345,9 @@ class AsyncEngineRunner:
         if allowed <= 0:
             return
         deadline = time.perf_counter() + min(allowed, TAKERS_WAIT_S)
-        with phase(None, "engine.wait"):
+        # the engine HAS work here, so this wait is on the dry clock
+        # (`dry_wait_ms`); `_idle_wait`'s is not: nothing is held up
+        with phase(getattr(self.engine, "metrics", None), "engine.wait"):
             while not self._stop and time.perf_counter() < deadline:
                 # short naps: whether the dispatch has landed is polled
                 self._wake.wait(timeout=0.002)

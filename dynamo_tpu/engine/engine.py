@@ -57,6 +57,7 @@ from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 from dynamo_tpu.parallel.shardings import batch_spec, shardings_for
 from dynamo_tpu import telemetry
 from dynamo_tpu.telemetry import phases
+from dynamo_tpu.telemetry.flight import PHASES as _LOOP_PHASES, DryClock
 from dynamo_tpu.tokens import TokenBlockSequence
 
 logger = logging.getLogger(__name__)
@@ -267,6 +268,38 @@ class EngineMetrics:
     state_resets: int = 0
     state_restores: int = 0
     prefix_hits_refused_state: int = 0
+    #: the dry clock (telemetry/flight.py `DryClock`; all 0 with
+    #: `flight_recorder=False`): cumulative host ms during which the
+    #: device had NOTHING queued while the engine had work, from the first
+    #: loop-phase boundary that found the newest launch's output ready
+    #: until the next program call returned. dry_ms is all of it; the
+    #: seven dry_<phase>_ms (twins of the time_*_ms counters, one per
+    #: `engine.<phase>` span) and dry_wait_ms (the wait for takers of
+    #: free slots, `AsyncEngineRunner._await_takers`) say under which
+    #: phase it passed, and what dry_ms holds beyond them passed between
+    #: two phases. dry_slack_ms is the measure's uncertainty (the stretch
+    #: between the last boundary that found the device busy and the first
+    #: that found it ready): true dry time lies in [dry_ms, dry_ms +
+    #: dry_slack_ms]. launches counts every program call of a step kind
+    #: (pure prefill programs too), dry_launches those made with the
+    #: device empty: high beside a high overlap_hits means launches ahead
+    #: of the batch but behind the device.
+    dry_ms: float = 0.0
+    dry_slack_ms: float = 0.0
+    dry_wait_ms: float = 0.0
+    dry_intake_ms: float = 0.0
+    dry_schedule_ms: float = 0.0
+    dry_stage_ms: float = 0.0
+    dry_launch_ms: float = 0.0
+    dry_readback_ms: float = 0.0
+    dry_postprocess_ms: float = 0.0
+    dry_emit_ms: float = 0.0
+    dry_launches: int = 0
+    launches: int = 0
+
+    #: the engine's `DryClock`, or None (not a field: `phase` finds the
+    #: clock where it finds the counters, and `to_dict` leaves it out)
+    dry_clock = None
 
     #: the timing plane's field names — the one list consumers (perf
     #: harness, dashboards) should iterate instead of restating
@@ -282,7 +315,14 @@ class EngineMetrics:
     )
 
     def to_dict(self) -> dict:
-        return dict(self.__dict__)
+        d = dict(self.__dict__)
+        d.pop("dry_clock", None)
+        return d
+
+
+#: spans whose enter and exit are boundaries of the dry clock, by the
+#: name the clock knows each under
+_CLOCKED = {f"engine.{p}": p for p in (*_LOOP_PHASES, "wait")}
 
 
 class _Phase:
@@ -290,25 +330,58 @@ class _Phase:
     `jax.profiler.TraceAnnotation` span on the profiler's clock (a no-op
     in C++ unless a capture is running — the engine's own `POST
     /v1/debug/profile` or anybody's `jax.profiler.start_trace`), and the
-    elapsed host time added to the named cumulative-ms counters."""
+    elapsed host time added to the named cumulative-ms counters. Where
+    the metrics carry a dry clock (`EngineMetrics.dry_clock`, with the
+    flight recorder) the enter and exit of a loop phase are also its
+    boundaries, and a launch (`launched`) its dispatch: every program
+    call of a step kind is counted there (`launches`), pure prefill
+    programs included."""
 
-    __slots__ = ("_metrics", "_fields", "_span", "_t0")
+    __slots__ = ("_metrics", "_fields", "_span", "_t0", "_clock", "_name",
+                 "_args")
 
     def __init__(self, metrics, name: str, fields: tuple, args: dict):
         self._metrics = metrics
         self._fields = fields
         self._span = jax.profiler.TraceAnnotation(name, **args)
+        clock = getattr(metrics, "dry_clock", None)
+        self._name = _CLOCKED.get(name)
+        self._clock = clock if self._name is not None else None
+        self._args = args
 
     def __enter__(self) -> "_Phase":
         self._span.__enter__()
         self._t0 = time.perf_counter()
+        # the clock's boundaries lie INSIDE the span and the counter, so
+        # the two still time the same stretch (tests/test_engine_spans.py)
+        if self._clock is not None:
+            self._clock.enter(self._name)
         return self
 
     def note(self, **args) -> None:
         """Span args known only once the phase has run."""
         self._span.set_metadata(**args)
 
+    def launched(self, out) -> Optional[int]:
+        """Inside `engine.launch`, right after the program call returned
+        `out` (one device array of its outputs): the dispatch's `seq` on
+        the flight recorder's timeline and in the span. None, and
+        nothing else, without a dry clock."""
+        clock = self._clock
+        if clock is None:
+            return None
+        seq = clock.launched(out, self._args)
+        self._span.set_metadata(seq=seq)
+        return seq
+
     def __exit__(self, *exc) -> bool:
+        clock = self._clock
+        if clock is not None:
+            clock.exit(self._name, self._args.get("seq"))
+            if self._name == "launch":
+                # the span's exit on the host's monotonic clock: one span
+                # gives the offset between that clock and the profiler's
+                self._span.set_metadata(t_host_ns=time.perf_counter_ns())
         dt_ms = (time.perf_counter() - self._t0) * 1000.0
         self._span.__exit__(*exc)
         m = self._metrics
@@ -324,6 +397,12 @@ def phase(metrics, name: str, *fields: str, **args) -> _Phase:
     and their counters). `metrics` is an EngineMetrics, or None for an
     engine double that keeps none."""
     return _Phase(metrics, name, fields, args)
+
+
+def _seq_arg(seq: Optional[int]) -> dict:
+    """`engine.readback`'s `seq` arg: the dispatch it reads (no arg
+    without a dry clock)."""
+    return {} if seq is None else {"seq": seq}
 
 
 def _pages_only(method):
@@ -368,6 +447,9 @@ class _Launched:
     #: launched ahead: per decode row the (num_tokens, len(output_tokens))
     #: the batch must show when this dispatch is consumed
     expected: Optional[tuple] = None
+    #: its number on the flight recorder's dispatch timeline (None
+    #: without the recorder)
+    seq: Optional[int] = None
 
 
 @dataclass
@@ -395,6 +477,7 @@ class _InflightSpec:
     #: speculation can never be consumed
     expected_num_tokens: Optional[tuple] = None
     expected_out_len: Optional[tuple] = None
+    seq: Optional[int] = None  # as _Launched.seq
 
 
 class JaxEngine:
@@ -559,6 +642,9 @@ class JaxEngine:
             self.flight: Optional["FlightRecorder"] = FlightRecorder(
                 config.flight_ring
             )
+            # the recorder's dry clock rides the metrics: `phase` finds
+            # it where it finds the counters (runner phases too)
+            self.metrics.dry_clock = DryClock(self.metrics)
         else:
             self.flight = None
         #: armed jax.profiler capture (request_profile): {steps_left,
@@ -1092,6 +1178,7 @@ class JaxEngine:
                         self.metrics.kv_pages_watermark,
                     ),
                     admit_wait_ms=admit_waits,
+                    timeline=self.metrics.dry_clock.take(),
                 )
         if not self.scheduler.has_work:
             # the wave ended on a sampled stop the speculation couldn't
@@ -1100,6 +1187,7 @@ class JaxEngine:
                 self._discard_inflight("idle")
             if self._inflight_spec is not None:
                 self._discard_inflight_spec("idle")
+            self._park_clock()
         self._refresh_metrics()
         return outputs, batch is not None
 
@@ -1276,9 +1364,14 @@ class JaxEngine:
             args = (self.params, *dev["base"][:3], self.kv, dev["base"][3])
         with phase(
             m, "engine.launch", kind="prefill", rows=b_bucket, t=t_bucket,
-            speculative=0,
-        ):
+            speculative=0, n_rows=len(pieces), b_pre=b_bucket,
+            chunk_tokens=sum(p.length for p in pieces),
+        ) as ph:
             out = fn(*args, *tail, **kwargs)
+            # a chunk that samples nothing returns the cache alone
+            seq = ph.launched(
+                out[0] if any_last else jax.tree_util.tree_leaves(out)[0]
+            )
         self._commit_state([p.request for p in pieces])
         ids = lp_data = None
         if not any_last:
@@ -1289,7 +1382,8 @@ class JaxEngine:
             else:
                 token_ids, self.kv = out
             with phase(
-                m, "engine.readback", "time_decode_sync_ms", lagged=0
+                m, "engine.readback", "time_decode_sync_ms", lagged=0,
+                **_seq_arg(seq),
             ):
                 if lp >= 0:
                     lp_data = tuple(np.asarray(x) for x in lp_raw)
@@ -1564,11 +1658,16 @@ class JaxEngine:
         with phase(
             m, "engine.launch", "time_decode_dispatch_ms",
             kind="spec_verify", rows=b_bucket, t=t, speculative=0,
-        ):
+            n_rows=len(reqs),
+        ) as ph:
             target_ids, self.kv = fn(
                 self.params, d_tokens, d_positions, d_valid, self.kv, d_pt,
             )
-        with phase(m, "engine.readback", "time_decode_sync_ms", lagged=0):
+            seq = ph.launched(target_ids)
+        with phase(
+            m, "engine.readback", "time_decode_sync_ms", lagged=0,
+            **_seq_arg(seq),
+        ):
             target = np.asarray(target_ids)  # [B, t]
         outputs: list[StepOutput] = []
         step_drafted = step_accepted = 0
@@ -1722,7 +1821,7 @@ class JaxEngine:
                 )
                 with phase(
                     self.metrics, "engine.readback", "time_decode_sync_ms",
-                    lagged=1,
+                    lagged=1, **_seq_arg(inflight.seq),
                 ):
                     out = np.asarray(inflight.out_ids)
                     drafts = np.asarray(inflight.draft_ids)
@@ -1766,19 +1865,24 @@ class JaxEngine:
         with phase(
             m, "engine.launch", "time_decode_dispatch_ms",
             kind="spec_fused", rows=b_bucket, t=w, speculative=0,
-        ):
+            n_rows=len(reqs),
+        ) as ph:
             out_ids, draft_ids, n_acc, self.kv, self.draft_kv = fn(
                 self.params, self.draft_params, d_tokens, d_len, d_pos0,
                 self.kv, self.draft_kv, d_pt, *dev["samp"], *dev["pen"],
                 **dev["bias"],
             )
+            seq = ph.launched(out_ids)
         # keep the device busy past this step BEFORE blocking on its
         # result (same discipline as _run_decode_plain)
         self._maybe_chain_spec(
             reqs, b_bucket, out_ids, n_acc, samp[4],
             greedy=all_greedy, bias=bias,
         )
-        with phase(m, "engine.readback", "time_decode_sync_ms", lagged=0):
+        with phase(
+            m, "engine.readback", "time_decode_sync_ms", lagged=0,
+            **_seq_arg(seq),
+        ):
             out = np.asarray(out_ids)
             drafts = np.asarray(draft_ids)
             n_acc_h = np.asarray(n_acc)
@@ -1927,7 +2031,8 @@ class JaxEngine:
         with phase(
             m, "engine.launch", "time_decode_dispatch_ms",
             kind="spec_fused", rows=b_bucket, t=w, speculative=1,
-        ):
+            n_rows=len(reqs),
+        ) as ph:
             # verify-start counters advance by the pending acceptance —
             # a device add, no host round-trip
             cv0 = jnp.asarray(counters_v0) + n_acc
@@ -1936,6 +2041,7 @@ class JaxEngine:
                 self.kv, self.draft_kv, d_pt, *dev["samp"], cv0,
                 **dev["bias"],
             )
+            seq = ph.launched(out2)
             for arr in (out2, drafts2, nacc2):
                 arr.copy_to_host_async()
         self.metrics.overlap_dispatches += 1
@@ -1948,6 +2054,7 @@ class JaxEngine:
             counters_v0=cv0,
             greedy=greedy,
             bias=bias,
+            seq=seq,
         )
 
     def _spec_inflight_matches(
@@ -2128,7 +2235,9 @@ class JaxEngine:
             if speculative:
                 host["src"] = np.full(b_bucket, -1, np.int32)
                 host["src"][: len(reqs)] = feed[1]
+            self._poll_clock()
             dev = self._dev_tree(host)
+            self._poll_clock()
             fn = self._get_step_fn(
                 kind, b_bucket, k_steps, greedy=all_greedy, lp=lp, pen=pen,
                 bias=bias,
@@ -2137,14 +2246,17 @@ class JaxEngine:
         with phase(
             m, "engine.launch", "time_decode_dispatch_ms", kind=kind,
             rows=b_bucket, k=k_steps, speculative=int(speculative),
-        ):
+            n_rows=len(reqs),
+        ) as ph:
             d_tokens, d_positions, d_valid, d_pt = dev["base"]
             if speculative:
                 d_tokens = self._feed((feed[0], dev["src"]), d_tokens)
+                self._poll_clock()
             out = fn(
                 self.params, d_tokens, d_positions, d_valid, self.kv, d_pt,
                 *head, *dev["samp"], *dev["pen"], **dev["bias"],
             )
+            seq = ph.launched(out[0])
         lp_data = None
         if lp >= 0:
             token_ids, lp_data, self.kv = out
@@ -2154,7 +2266,7 @@ class JaxEngine:
             self._commit_state(reqs)
         return _Launched(
             reqs=tuple(reqs), b_bucket=b_bucket, k_steps=k_steps,
-            token_ids=token_ids, lp_data=lp_data,
+            token_ids=token_ids, lp_data=lp_data, seq=seq,
         )
 
     def _finish_decode(
@@ -2166,7 +2278,7 @@ class JaxEngine:
         reqs = list(st.reqs)
         with phase(
             self.metrics, "engine.readback", "time_decode_sync_ms",
-            lagged=int(st.expected is not None),
+            lagged=int(st.expected is not None), **_seq_arg(st.seq),
         ):
             ids = np.asarray(st.token_ids).reshape(st.k_steps, st.b_bucket)
             lp_arrays = self._materialize_lp(
@@ -2202,6 +2314,8 @@ class JaxEngine:
             self.metrics, "engine.postprocess", "time_decode_host_ms"
         ) as ph:
             for i, req in enumerate(reqs):
+                if not i & 15:
+                    self._poll_clock()
                 accepted: list[int] = []
                 finish: Optional[FinishReason] = None
                 for kk in range(k_steps):
@@ -2388,6 +2502,7 @@ class JaxEngine:
                 if piece.start + piece.length >= len(req.prompt_tokens):
                     any_last = True
             first_chunk = all(p.start == 0 for p in pieces)
+            self._poll_clock()
             # sampled row space: decode rows [0, b_dec); when a piece
             # completes its prompt, prefill rows join at [b_dec, b_dec+b_pre)
             pre_reqs = [p.request for p in pieces]
@@ -2439,7 +2554,9 @@ class JaxEngine:
             if speculative:
                 host["src"] = np.full(b_dec, -1, np.int32)
                 host["src"][: len(reqs_d)] = feed[1]
+            self._poll_clock()
             dev = self._dev_tree(host)
+            self._poll_clock()
             fn = self._get_step_fn(
                 "mixed", b_dec, t_bucket, greedy=all_greedy,
                 first_chunk=first_chunk, lp=lp, pen=pen, bias=bias,
@@ -2448,15 +2565,19 @@ class JaxEngine:
         with phase(
             m, "engine.launch", "time_decode_dispatch_ms", kind="mixed",
             rows=b_dec, t=t_bucket, k=1, speculative=int(speculative),
-        ):
+            n_rows=len(reqs_d), b_pre=b_pre,
+            chunk_tokens=sum(p.length for p in pieces),
+        ) as ph:
             d_tokens, d_positions, d_valid, d_pt = dev["based"]
             if speculative:
                 d_tokens = self._feed((feed[0], dev["src"]), d_tokens)
+                self._poll_clock()
             out = fn(
                 self.params, d_tokens, d_positions, d_valid, self.kv, d_pt,
                 *dev["basep"], dev["last"],
                 *dev["samp"], *dev["pen"], **dev["bias"],
             )
+            seq = ph.launched(out[0])
         lp_data = None
         if lp >= 0:
             token_ids, lp_data, self.kv = out
@@ -2467,7 +2588,7 @@ class JaxEngine:
         return _Launched(
             reqs=tuple(reqs_d), b_bucket=b_dec, k_steps=1,
             token_ids=token_ids, lp_data=lp_data, pieces=tuple(pieces),
-            psamp=any_last,
+            psamp=any_last, seq=seq,
         )
 
     def _finish_mixed(self, st: _Launched) -> list[StepOutput]:
@@ -2478,7 +2599,7 @@ class JaxEngine:
         m.mixed_shared_rows += len(st.reqs)
         with phase(
             m, "engine.readback", "time_decode_sync_ms",
-            lagged=int(st.expected is not None),
+            lagged=int(st.expected is not None), **_seq_arg(st.seq),
         ):
             ids = np.asarray(st.token_ids)  # [b_dec] or [b_dec + b_pre]
             lp_arrays = self._materialize_lp(st.lp_data, 1, ids.shape[0])
@@ -2520,6 +2641,7 @@ class JaxEngine:
         nxt = self.scheduler.next_batch(
             pending.reqs, pending.k_steps, pending.pieces
         )
+        self._poll_clock()  # planning the batch ahead runs under no phase
         if nxt is None or not nxt.decode:
             return
         rows, pieces = list(nxt.decode), list(nxt.prefill)
@@ -2665,6 +2787,7 @@ class JaxEngine:
         millisecond. 0 where nothing is launched ahead, it is a
         one-token dispatch or it has landed (the device would idle), or
         every free slot has its taker."""
+        self._poll_clock()  # the wait naps: a boundary each time it asks
         st = self._inflight
         if st is None or st.k_steps < 2 or st.token_ids.is_ready():
             return 0.0
@@ -2677,6 +2800,23 @@ class JaxEngine:
         (idle/stop paths; also pins the sync/overlap boundary in tests)."""
         self._discard_inflight("drained")
         self._discard_inflight_spec("drained")
+        if not self.scheduler.has_work:
+            self._park_clock()
+
+    def _poll_clock(self) -> None:
+        """A boundary of the dry clock INSIDE a phase, where a phase is
+        long enough to hide when the device finished (staging, the
+        transfer, the stop scan): free while the device is known dry."""
+        clock = self.metrics.dry_clock
+        if clock is not None:
+            clock.poll()
+
+    def _park_clock(self) -> None:
+        """Nothing to run: what passes until the next step is not dry
+        time (`DryClock.park`)."""
+        clock = self.metrics.dry_clock
+        if clock is not None:
+            clock.park()
 
     # -- shared ------------------------------------------------------------
 

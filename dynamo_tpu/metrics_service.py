@@ -92,6 +92,14 @@ _WORKER_FIELDS = (
     ("overlap_dispatches", "counter"),
     ("overlap_hits", "counter"),
     ("overlap_rollbacks", "counter"),
+    # the dry clock (telemetry/flight.py DryClock; 0 with the flight
+    # recorder off): host ms the device had nothing queued while the
+    # engine had work, every program call of a step kind, and those made
+    # with the device empty. dry_launches high beside overlap_hits high =
+    # launches ahead of the batch but behind the device
+    ("dry_ms", "counter"),
+    ("dry_launches", "counter"),
+    ("launches", "counter"),
     # recurrent-state plane (a model with state-space layers, 0 for every
     # other; docs/observability.md): slots of the state pool, the high
     # watermark of slots held, the pool's bytes, admissions that took a

@@ -89,3 +89,50 @@ def test_flight_off_is_bit_identical_and_recorder_absent():
         else:
             assert eng.flight is None
     assert outs[True] == outs[False]
+
+
+def test_records_carry_the_dispatch_timeline_and_the_dry_deltas():
+    """ISSUE 38: `disp` (one entry per program the step launched) and
+    `ready` (one per dispatch whose ids it read), json-safe like the
+    rest, beside the per-step deltas of the dry clock's counters."""
+    eng = JaxEngine(EngineConfig.for_tests())
+    for i in range(3):
+        eng.add_request(
+            f"r{i}", [1 + i, 2, 3, 4, 5],
+            SamplingParams(temperature=0.0, max_tokens=9),
+        )
+    eng.run_to_completion()
+    recs = eng.flight.snapshot()
+    json.dumps(recs)
+    disp = [e for r in recs for e in r.get("disp", ())]
+    ready = [e for r in recs for e in r.get("ready", ())]
+    assert len(disp) == eng.metrics.launches == sum(
+        r.get("launches", 0) for r in recs)
+    assert {"seq", "kind", "rows", "n_rows", "k", "ahead",
+            "t_launch"} <= set(disp[0])
+    assert {"seq", "kind", "t_ready", "blocked_ms"} <= set(ready[0])
+    assert {e["seq"] for e in ready} <= {e["seq"] for e in disp}
+    # the prompt's step launched a prefill and read it at once
+    pre = next(r for r in recs if r["kind"] == "prefill")
+    assert pre["disp"][0]["kind"] == "prefill"
+    assert pre["disp"][0]["chunk_tokens"] == pre["prefill_tokens"] == 15
+    assert pre["ready"][0]["seq"] == pre["disp"][0]["seq"]
+    # a step that launched nothing and read nothing has neither key
+    assert all(r.get("disp", True) and r.get("ready", True) for r in recs)
+    assert sum(r.get("dry_launches", 0) for r in recs) == (
+        eng.metrics.dry_launches) >= 1
+
+
+def test_record_step_takes_a_timeline_and_leaves_empty_ones_out():
+    fl = FlightRecorder()
+    m = EngineMetrics()
+    m.dry_ms, m.dry_stage_ms, m.launches, m.dry_launches = 7.5, 5.0, 2, 1
+    rec = fl.record_step(
+        m, kind="mixed", step_ms=3.0,
+        timeline={"ready": [{"seq": 4, "t_ready": 9.5, "blocked_ms": 0.1}]},
+    )
+    assert rec["ready"][0]["seq"] == 4 and "disp" not in rec
+    assert (rec["dry_ms"], rec["dry_stage_ms"]) == (7.5, 5.0)
+    assert (rec["launches"], rec["dry_launches"]) == (2, 1)
+    quiet = fl.record_step(m, kind="decode", step_ms=1.0, timeline={})
+    assert not {"disp", "ready", "dry_ms", "launches"} & set(quiet)
