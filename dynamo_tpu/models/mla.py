@@ -30,9 +30,10 @@ Two write disciplines, as models/llama.py's attention_block:
   Decode (T = 1) walks the live pages in ops/paged_attention.py's kernel
   (`latent=True`: one page is key and value at once, 16 heads in one
   dot) and folds the current token in exactly; a prefill chunk attends
-  over itself and, unless it is a first chunk, over blocks of its live
-  history pages in a loop of plain XLA (`_latent_prefill_attention`)
-  that stops at the longest row's history, not at the table's width.
+  over its live history pages, a block of them a turn, and over itself
+  in one kernel with one online softmax, the 16 heads folded into the
+  tile's rows (ops/flash_prefill.latent_prefill_attention): each row
+  stops at its own history, and no score leaves VMEM.
 
 Attention runs in the ABSORBED form (the deployment form from the
 DeepSeek-V2 paper): q_nope is projected by W_UK^T into the latent space
@@ -628,8 +629,6 @@ def _interleaved_rope(x: jax.Array, positions: jax.Array, cfg: MlaConfig):
 
 #: masked scores; finite so that a padded query row stays NaN-free
 _MASKED = -1e30
-#: history pages one turn of the prefill loop gathers (512 keys at S=64)
-_PREFILL_BLOCK_PAGES = 8
 
 
 def _pad_last(x: jax.Array, width: int) -> jax.Array:
@@ -688,73 +687,30 @@ def _latent_decode(qd, c_cur, pe_cur, k_cache, v_cache, layer, page_tables,
 
 
 def _latent_prefill_attention(
-    q_lat, q_pe, c_kv, k_pe, k_cache, v_cache, layer, page_tables,
-    positions, valid, cfg: MlaConfig, first_chunk: bool,
+    q_lat, q_pe, c_kv, pe_rows, k_cache, v_cache, layer, page_tables,
+    positions, valid, cfg: MlaConfig, first_chunk: bool, mesh=None,
 ):
-    """o_lat [B, T, H, c] f32 of a prefill chunk in the absorbed form, in
-    plain XLA: the chunk over itself (causal by position) and, unless
-    `first_chunk`, over its history in the cache, `_PREFILL_BLOCK_PAGES`
-    pages a turn with one online softmax, for as many turns as the longest
-    row's history needs: the live pages, not the page table's width.
-    Operands go to the MXU in the model dtype; scores, softmax and sums are
-    float32. The history is what lies before the chunk's first position
-    (chunks start page-aligned; the chunk's own rows are staged, not yet
-    in the cache)."""
-    dt, r = cfg.dtype, cfg.qk_rope_head_dim
-    f32 = jnp.float32
-    ql = (q_lat * cfg.softmax_scale).astype(dt)
+    """o_lat [B, T, H, c] (model dtype) of a prefill chunk in the absorbed
+    form: the chunk over its history in the cache and over itself (causal by
+    position) in ONE kernel with one online softmax
+    (ops/flash_prefill.latent_prefill_attention). Operands go to the MXU
+    in the model dtype, the queries scaled before they are rounded to
+    it; scores, softmax and sums are float32. The history is what lies
+    before the chunk's first position (chunks start page-aligned; the
+    chunk's own rows are staged, not yet in the cache: `c_kv` and
+    `pe_rows`, the rope key as cached), none for a `first_chunk`."""
+    from dynamo_tpu.ops.flash_prefill import latent_prefill_attention
+
+    dt, f32 = cfg.dtype, jnp.float32
+    start = jnp.where(valid[:, 0], positions[:, 0], 0)  # [B] history
     qp = (q_pe.astype(f32) * cfg.softmax_scale).astype(dt)
-
-    def scores(ck, pk):  # [B, K, c], [B, K, r] -> [B, H, T, K] f32
-        return jnp.einsum(
-            "bthc,bkc->bhtk", ql, ck, preferred_element_type=f32
-        ) + jnp.einsum("bthr,bkr->bhtk", qp, pk, preferred_element_type=f32)
-
-    def weighted(p, ck):  # [B, H, T, K] f32, [B, K, c] -> [B, H, T, c] f32
-        return jnp.einsum(
-            "bhtk,bkc->bhtc", p.astype(dt), ck, preferred_element_type=f32
-        )
-
-    q_pos = positions[:, None, :, None]
-    cur_pos = jnp.where(valid, positions, jnp.int32(1 << 30))
-    s = jnp.where(
-        cur_pos[:, None, None, :] <= q_pos,
-        scores(c_kv.astype(dt), k_pe.astype(dt)), _MASKED,
+    return latent_prefill_attention(
+        (q_lat * cfg.softmax_scale).astype(dt),
+        _pad_last(qp, cfg.kv_rope_dim), c_kv.astype(dt), pe_rows, k_cache,
+        v_cache, layer, page_tables,
+        jnp.zeros_like(start) if first_chunk else start,
+        jnp.sum(valid, axis=1), mesh=mesh,
     )
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    acc = weighted(p, c_kv.astype(dt))
-    if not first_chunk:
-        page = k_cache.shape[2]
-        pb = _PREFILL_BLOCK_PAGES
-        start = jnp.where(valid[:, 0], positions[:, 0], 0)  # [B] history
-        pt = jnp.pad(page_tables, ((0, 0), (0, -page_tables.shape[1] % pb)))
-        lat = lax.dynamic_index_in_dim(k_cache, layer, 0, keepdims=False)
-        rope = lax.dynamic_index_in_dim(v_cache, layer, 0, keepdims=False)
-        b = pt.shape[0]
-
-        def block(i, carry):
-            m, l, acc = carry
-            pages = lax.dynamic_slice_in_dim(pt, i * pb, pb, axis=1)
-            ck = lat[pages].reshape(b, pb * page, -1)
-            pk = rope[pages].reshape(b, pb * page, -1)[..., :r]
-            key_pos = i * (pb * page) + jnp.arange(pb * page)
-            s = jnp.where(
-                key_pos[None, None, None, :] < start[:, None, None, None],
-                scores(ck, pk), _MASKED,
-            )
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            corr = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new)
-            return (
-                m_new, l * corr + jnp.sum(p, axis=-1, keepdims=True),
-                acc * corr + weighted(p, ck),
-            )
-
-        n_blk = -(-jnp.max(start) // (pb * page))
-        m, l, acc = lax.fori_loop(0, n_blk, block, (m, l, acc))
-    return (acc / l).transpose(0, 2, 1, 3)
 
 
 def mla_attention(
@@ -819,12 +775,12 @@ def mla_attention(
             qp = _interleaved_rope(qp, g.positions, cfg)
             kp = _interleaved_rope(kp, g.positions, cfg).astype(cfg.dtype)
         o_lat, kv, st = attend(ql, qp, ck, kp, cfg, kv, layer, g, work, mesh)
-        o_lats.append(o_lat)
+        o_lats.append(o_lat.astype(wdt))
         staged.append(st)
 
     with jax.named_scope("out"):
         out = jnp.einsum(
-            "...hc,chv->...hv", join_rows(o_lats).astype(wdt), w_uv,
+            "...hc,chv->...hv", join_rows(o_lats), w_uv,
             preferred_element_type=jnp.float32,
         )
         out = out.reshape(*lead, hn * vd).astype(cfg.dtype)
@@ -874,7 +830,9 @@ def _attend_kernels(q_lat, q_pe, c_kv, k_pe, cfg: MlaConfig, kv, layer, g,
                     work, mesh):
     """One group under the kernels' discipline (module text): the cache
     is read, never written here; the chunk's rows come back as `staged`.
-    Returns (o_lat [B, T, H, c] f32, the caches as they came, staged)."""
+    Returns (o_lat [B, T, H, c], float32 from the decode walk and the
+    model dtype from a prefill chunk's kernel, the caches as they came,
+    staged)."""
     k_cache, v_cache = kv
     pe_rows = _pad_last(k_pe, cfg.kv_rope_dim)  # the rope key as cached
     if q_lat.shape[1] == 1:
@@ -890,8 +848,9 @@ def _attend_kernels(q_lat, q_pe, c_kv, k_pe, cfg: MlaConfig, kv, layer, g,
     else:
         with jax.named_scope("flash"):
             o_lat = _latent_prefill_attention(
-                q_lat, q_pe, c_kv, k_pe, k_cache, v_cache, layer,
+                q_lat, q_pe, c_kv, pe_rows, k_cache, v_cache, layer,
                 g.page_tables, g.positions, g.valid, cfg, g.first_chunk,
+                mesh,
             )
     return o_lat, kv, (c_kv[:, :, None, :], pe_rows[:, :, None, :])
 

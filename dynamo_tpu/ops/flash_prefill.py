@@ -13,6 +13,13 @@ frontier prunes key blocks strictly above the diagonal, and a per-sequence
 `valid_len` (scalar-prefetched) masks the padding tail, matching the
 fallback's semantics (invalid queries produce ignored rows).
 
+Three kernels: `flash_prefill_attention` (a first chunk: no history),
+`paged_prefill_attention` (a GQA chunk over paged history and itself) and
+`latent_prefill_attention` (the same for a latent cache, models/mla.py:
+one row a token that is key and value at once, a rope key beside it, bf16
+operands on the MXU, a block of pages a turn; a body of its own at the
+end of the file, since those needs conflict with the GQA body's).
+
 Parity note: the reference gets its prefill kernels from vLLM/TRT-LLM
 (engine-delegated, SURVEY.md §2.9); here the engine is first-class so the
 kernel lives in-tree, next to the decode kernel (ops/paged_attention.py).
@@ -510,3 +517,292 @@ def flash_prefill_attention(
     # back to [B, T, Hq, D]
     out = out.transpose(0, 3, 1, 2, 4)
     return out.reshape(b, tp, hq, d)[:, :t]
+
+
+# ---------------------------------------------------------------------------
+# A latent cache (models/mla.py): one row a token that is key AND value
+# ---------------------------------------------------------------------------
+
+#: history pages one turn of the latent kernel takes (512 keys at S = 64:
+#: a block as wide as the MXU's pass over it is long, PR 25's finding)
+LATENT_BLOCK_PAGES = 8
+#: chunk rows of one grid cell; the heads fold into its rows (x H)
+LATENT_BLOCK_Q = 128
+#: chunk keys of one turn over the chunk itself
+LATENT_BLOCK_CUR = 256
+_MASKED = -1e30  # finite: a padded query row stays NaN-free
+
+
+def _latent_kernel(
+    # scalar prefetch
+    layer_ref,  # [1] int32
+    pt_ref,  # [B, MP] int32 page tables (SMEM)
+    hist_ref,  # [B] int32: tokens already in the cache (chunk start)
+    cur_ref,  # [B] int32: valid tokens in THIS chunk
+    # inputs
+    ql_ref,  # [1, H, BQ, C] VMEM: the absorbed queries, scaled
+    qp_ref,  # [1, H, BQ, R] VMEM: their rope part, scaled, zeros past it
+    lcur_ref,  # [1, T, C] VMEM: this chunk's latent rows (staged)
+    rcur_ref,  # [1, T, R] VMEM: this chunk's rope keys as cached
+    lat_hbm,  # [L, P, S, C] ANY: the latent pool
+    rope_hbm,  # [L, P, S, R] ANY: the rope-key pool
+    # output
+    o_ref,  # [1, H, BQ, C] in the queries' dtype
+    # scratch
+    lat_scr,  # [2, PB*S, C] VMEM: a slot is a block of pages
+    rope_scr,  # [2, PB*S, R]
+    m_scr,  # [H*BQ, 128] f32 running max (every lane the same)
+    l_scr,  # [H*BQ, 128] f32 running denominator
+    acc_scr,  # [H*BQ, C] f32
+    sem,  # [2, 2] DMA semaphores: [plane, slot]
+    *,
+    page_size: int,
+    block_pages: int,
+    block_cur: int,
+):
+    b = pl.program_id(0)
+    qi = pl.program_id(1)
+    li = layer_ref[0]
+    hn, bq, c = ql_ref.shape[1], ql_ref.shape[2], ql_ref.shape[3]
+    t = lcur_ref.shape[1]
+    s, pb, mp = page_size, block_pages, pt_ref.shape[1]
+    rows = hn * bq
+    hist = hist_ref[b]
+    cur = cur_ref[b]
+    n_blk = pl.cdiv(hist, pb * s)
+    mxu = lat_scr.dtype  # the pool's dtype is the model's: no cast to f32
+
+    def copies(slot, blk):
+        """The DMAs of history block `blk` into `slot`, one a page and
+        plane. A block's last pages may lie past the row's history: they
+        fetch whatever page the table names there (clamped to its width)
+        and their keys are masked, so every turn moves `pb` pages and a
+        wait is its start's twin."""
+        out = []
+        for p in range(pb):
+            page = pt_ref[b, jnp.minimum(blk * pb + p, mp - 1)]
+            for pi, (src, dst) in enumerate(
+                ((lat_hbm, lat_scr), (rope_hbm, rope_scr))
+            ):
+                out.append(pltpu.make_async_copy(
+                    src.at[li, page],
+                    dst.at[slot, pl.ds(p * s, s)],
+                    sem.at[pi, slot],
+                ))
+        return out
+
+    @pl.when(n_blk > 0)  # the first block lands under the chunk's own turns
+    def _():
+        for cp in copies(0, 0):
+            cp.start()
+
+    ql = ql_ref[0].reshape(rows, c)
+    qp = qp_ref[0].reshape(rows, qp_ref.shape[3])
+    # chunk-relative index of each folded row's query; built 2D via rem
+    # (see _prefill_kernel's row_pos note)
+    row_rel = qi * bq + jax.lax.rem(
+        jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), bq
+    )
+
+    def fold(lat, rope, mask, first=False):
+        """One turn of the online softmax over keys `lat` [K, C] (they are
+        the values too) and `rope` [K, R]; `first` starts the state."""
+        sc = jax.lax.dot_general(
+            ql, lat, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) + jax.lax.dot_general(
+            qp, rope, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [H*BQ, K]
+        sc = jnp.where(mask, sc, _MASKED)
+        m_cur = jnp.max(sc, axis=1, keepdims=True)
+        m_new = m_cur if first else jnp.maximum(m_scr[:, :1], m_cur)
+        p = jnp.exp(sc - m_new)
+        l_new = jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(mxu), lat, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [H*BQ, C]
+        if not first:
+            corr = jnp.exp(m_scr[:, :1] - m_new)
+            l_new += corr * l_scr[:, :1]
+            pv += corr * acc_scr[...]
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        acc_scr[...] = pv
+
+    # -- the chunk over itself: causal by position, padding masked, key
+    # blocks wholly above this cell's diagonal skipped ------------------------
+    for j in range(t // block_cur):
+        def turn(j=j):
+            at = pl.ds(j * block_cur, block_cur)
+            key = j * block_cur + jax.lax.broadcasted_iota(
+                jnp.int32, (block_cur, 1), 0
+            )
+            # rows past `cur` may hold anything: as values a zero weight
+            # does not silence a NaN, so they go in as zeros
+            lat = jnp.where(key < cur, lcur_ref[0, at, :], 0).astype(mxu)
+            col = j * block_cur + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_cur), 1
+            )
+            fold(
+                lat, rcur_ref[0, at, :],
+                (col <= row_rel) & (col < cur), first=j == 0,
+            )
+
+        if j == 0:
+            turn()
+        else:
+            pl.when(j * block_cur < (qi + 1) * bq)(turn)
+
+    # -- the history: every key lies before the chunk, the last block's
+    # tail past `hist` masked ------------------------------------------------
+    def body(i, _):
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_blk)
+        def _():
+            for cp in copies(1 - slot, i + 1):
+                cp.start()
+
+        for cp in copies(slot, i):
+            cp.wait()
+        key_pos = i * (pb * s) + jax.lax.broadcasted_iota(
+            jnp.int32, (1, pb * s), 1
+        )
+        fold(lat_scr[slot], rope_scr[slot], key_pos < hist)
+        return 0
+
+    jax.lax.fori_loop(0, n_blk, body, 0)
+    inv = 1.0 / l_scr[:, :1]
+    o_ref[0] = (acc_scr[...] * inv).astype(o_ref.dtype).reshape(hn, bq, c)
+
+
+def latent_prefill_attention(
+    q_lat: jax.Array,  # [B, T, H, C] absorbed queries, SCALED, model dtype
+    q_pe: jax.Array,  # [B, T, H, R] their rope part, scaled, zeros past it
+    lat_cur: jax.Array,  # [B, T, C] this chunk's latent rows
+    rope_cur: jax.Array,  # [B, T, R] this chunk's rope keys as cached
+    k_cache: jax.Array,  # [L, P, S, 1, C] the latent pool (history)
+    v_cache: jax.Array,  # [L, P, S, 1, R] the rope-key pool
+    layer: jax.Array,  # scalar int32
+    page_tables: jax.Array,  # [B, MP] int32
+    hist_lens: jax.Array,  # [B] int32: tokens already written to pages
+    cur_lens: jax.Array,  # [B] int32: valid tokens in this chunk
+    *,
+    interpret: bool | None = None,
+    mesh=None,
+) -> jax.Array:
+    """A prefill chunk of a latent-cache model (models/mla.py, absorbed
+    form) over its paged history and over itself, one online softmax:
+    `softmax(q_lat . latent + q_pe . rope_key) . latent` with one latent
+    row (key and value at once) and one rope key a token. The H heads fold
+    into the tile's rows ([H x BQ, C + R] against [K, C + R]: one KV
+    "head", a group of H); history pages arrive `LATENT_BLOCK_PAGES` a
+    turn by double-buffered DMA from the stacked pools, `layer` a
+    prefetched scalar; operands reach the MXU in the dtype they come in,
+    scores, softmax, sums and the accumulator are float32 and live in
+    VMEM a tile at a time. A history of 0 runs no turn.
+
+    Returns o_lat [B, T, H, C] in the queries' dtype (the float32
+    quotient rounded once, on the way out: what the caller's value
+    up-projection takes); rows past cur_lens are unspecified, and what
+    rows past cur_lens hold on the way in reaches no other row.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if mesh is not None and mesh.shape.get("tp", 1) > 1:
+        from jax.sharding import PartitionSpec as P
+
+        # heads are independent; the one-row cache replicates over tp
+        heads = P(None, None, "tp", None)
+        fn = jax.shard_map(
+            functools.partial(
+                latent_prefill_attention, interpret=interpret, mesh=None
+            ),
+            mesh=mesh,
+            in_specs=(heads, heads) + (P(),) * 8,
+            out_specs=heads,
+            check_vma=False,
+        )
+        return fn(q_lat, q_pe, lat_cur, rope_cur, k_cache, v_cache, layer,
+                  page_tables, hist_lens, cur_lens)
+
+    b, t, hn, c = q_lat.shape
+    s, r = k_cache.shape[2], v_cache.shape[4]
+    if k_cache.shape[3] != 1 or (
+        q_pe.shape[-1], lat_cur.shape[-1], rope_cur.shape[-1]
+    ) != (r, c, r):
+        raise ValueError(
+            "a latent chunk takes a one-row cache and rows as it caches "
+            f"them; got pools {k_cache.shape} / {v_cache.shape}, rows "
+            f"{lat_cur.shape} / {rope_cur.shape}, q_pe {q_pe.shape}"
+        )
+    bq = min(LATENT_BLOCK_Q, t)
+    tp = -(-t // bq) * bq
+    pb = min(LATENT_BLOCK_PAGES, page_tables.shape[1])
+    if tp != t:  # whole query blocks; `cur_lens` masks the tail
+        q_lat, q_pe, lat_cur, rope_cur = (
+            jnp.pad(x, ((0, 0), (0, tp - t)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q_lat, q_pe, lat_cur, rope_cur)
+        )
+    # head-major queries: a cell's [H, BQ, .] block is its folded tile
+    ql = q_lat.transpose(0, 2, 1, 3)
+    qp = q_pe.transpose(0, 2, 1, 3)
+    block_cur = math.gcd(tp, LATENT_BLOCK_CUR)
+
+    def q_block(width):
+        return pl.BlockSpec(
+            (1, hn, bq, width), lambda bi, qi, li, pt, hl, cl: (bi, 0, qi, 0)
+        )
+
+    def chunk_block(width):
+        return pl.BlockSpec(
+            (1, tp, width), lambda bi, qi, li, pt, hl, cl: (bi, 0, 0)
+        )
+
+    out = pl.pallas_call(
+        functools.partial(
+            _latent_kernel, page_size=s, block_pages=pb, block_cur=block_cur
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b, tp // bq),
+            in_specs=[
+                q_block(c), q_block(r), chunk_block(c), chunk_block(r),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=q_block(c),
+            scratch_shapes=[
+                pltpu.VMEM((2, pb * s, c), k_cache.dtype),
+                pltpu.VMEM((2, pb * s, r), v_cache.dtype),
+                pltpu.VMEM((hn * bq, 128), jnp.float32),
+                pltpu.VMEM((hn * bq, 128), jnp.float32),
+                pltpu.VMEM((hn * bq, c), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hn, tp, c), q_lat.dtype),
+        interpret=interpret,
+        name="latent_prefill_attention",
+        # a [16 x 128, 512] tile holds 4 MB of accumulator, as much again
+        # of scores and of weights, and its blocks twice: ~45 MB at
+        # DeepSeek-V2-Lite's widths, of v5e's 128. The limit assumes a
+        # chip with 128 MB of VMEM (v5e, v6e), as `paged_prefill_
+        # attention`'s above does: one with less refuses it at compile
+        # time, and wants a smaller LATENT_BLOCK_Q and a limit of its own
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=100 * 1024 * 1024
+        ),
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        page_tables.astype(jnp.int32),
+        hist_lens.astype(jnp.int32),
+        cur_lens.astype(jnp.int32),
+        ql, qp, lat_cur, rope_cur,
+        # a page as [S, C] rows: the same bytes
+        k_cache.reshape(*k_cache.shape[:3], c),
+        v_cache.reshape(*v_cache.shape[:3], r),
+    )
+    return out[:, :, :t].transpose(0, 2, 1, 3)

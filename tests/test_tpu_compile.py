@@ -17,6 +17,7 @@ has no option for it. A compile that passes is not a chip run —
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -158,7 +159,9 @@ def _compile_flash_prefill(adapter, chip, b=4, t=128):
     ).compile()
 
 
-def _compile_paged_prefill(adapter, kv, chip, b=4, t=128):
+def _paged_prefill_call(adapter, kv, chip, b=4, t=128):
+    """(fn, its arguments' shapes) of one GQA history-chunk call, placed
+    on `chip` or, with None, nowhere (a trace, not a compile)."""
     cfg = adapter.config
     d = cfg.kv_head_dim
     cur = _sds((b, t, cfg.num_kv_heads, d), cfg.dtype, chip)
@@ -169,11 +172,16 @@ def _compile_paged_prefill(adapter, kv, chip, b=4, t=128):
             scale_dim=cfg.head_dim, k_scale=kv.k_scale, v_scale=kv.v_scale,
         )
 
-    return jax.jit(fn).lower(
+    return fn, (
         _sds((b, t, cfg.num_heads, d), cfg.dtype, chip), cur, cur,
-        _on(chip, kv), _sds((b, MAX_PAGES), jnp.int32, chip),
+        _on(chip, kv) if chip else kv, _sds((b, MAX_PAGES), jnp.int32, chip),
         _sds((b,), jnp.int32, chip), _sds((b,), jnp.int32, chip),
-    ).compile()
+    )
+
+
+def _compile_paged_prefill(adapter, kv, chip, b=4, t=128):
+    fn, shapes = _paged_prefill_call(adapter, kv, chip, b, t)
+    return jax.jit(fn).lower(*shapes).compile()
 
 
 #: (head_dim override, extra config overrides): llama3-1b's own 64 lanes
@@ -328,6 +336,7 @@ def test_whole_llama3_1b_decode_step_compiles_with_both_kernels(topo):
     pytest.param(64, 1, False, id="decode-64-rows"),
     pytest.param(1, 512, False, id="prefill-chunk-over-latent-history"),
     pytest.param(2, 32, False, id="prefill-tail-shorter-than-a-page"),
+    pytest.param(4, 512, False, id="four-prompts-side-by-side"),
 ])
 def test_deepseek_v2_lite_step_compiles_at_published_widths(
         topo, rows, t, first_chunk):
@@ -338,7 +347,14 @@ def test_deepseek_v2_lite_step_compiles_at_published_widths(
     go through the TPU compiler, the program fits the chip beside 13.25
     GB of weights and cache, and nothing copies the KV pool (one scatter
     over all layers did, and so did a loop of dynamic_update_slice inside
-    the fused decode scan: 4 GB of temporaries)."""
+    the fused decode scan: 4 GB of temporaries). A prefill chunk attends
+    over its latent history in ONE kernel (once in each layer scan): no
+    float32 score, weight or accumulator tensor of the XLA loop it
+    replaced ([B, 16, T, 512]: 16 MB each at T 512) is left in the
+    program, nor the 0.4 GB copy of a layer's pool that loop sliced out,
+    and the temporaries lie under that loop's (PR 38's tree compiled
+    here: 550.8 MB at 1 x 512, 520.1 at 2 x 32, 784.8 at 4 x 512; this
+    tree 33.6, 6.0 and 309.6)."""
     adapter = get_model("deepseek-v2-lite-8l", dtype="bfloat16",
                         attention_impl="pallas")
     chip = SingleDeviceSharding(topo.devices[0])
@@ -388,7 +404,66 @@ def test_deepseek_v2_lite_step_compiles_at_published_widths(
         assert mem.temp_size_in_bytes < 0.8e9
     else:  # whole pages of the chunk through the DMA writer
         assert "paged_kv_write" in text
-    assert _mosaic_calls(compiled) >= 4
+        assert len(re.findall(
+            r"%latent_prefill_attention[.\d]* = \S+ custom-call", text)) == 2
+        assert not re.search(r"f32\[\d+,16,\d+,512\]", text)
+        assert mem.temp_size_in_bytes < {1: 100e6, 2: 100e6, 4: 400e6}[rows]
+    # three grouped matmuls and a cache writer, and the attention kernel of
+    # the kind of step in both layer scans
+    assert _mosaic_calls(compiled) >= 6
+
+
+def _primitives(jaxpr, seen):
+    """Count of every primitive in `jaxpr` and the jaxprs under it, a dot
+    besides under its operands' dtypes."""
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        seen[name] = seen.get(name, 0) + 1
+        if name == "dot_general":
+            key = "dot:" + "/".join(str(v.aval.dtype) for v in eqn.invars)
+            seen[key] = seen.get(key, 0) + 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _primitives(sub, seen)
+    return seen
+
+
+@pytest.mark.parametrize("kvq", [None, "int8"])
+def test_gqa_history_kernel_is_what_it_was_before_the_latent_one(kvq):
+    """`ops/flash_prefill.py` gained a latent kernel beside `_hist_kernel`
+    (PR 39); the four cells that run `paged_prefill_attention` (qwen2,
+    phi3, nemotron-h, falcon-h1: rotary or plain GQA over dense pages)
+    must trace to the kernel they traced to on PR 38's tree, read here
+    from the jaxpr at llama shapes: one kernel of that name, a cell a
+    prompt, ONE page a slot of its double buffer, float32 operands at
+    every dot (the latent kernel's bf16 operands and its block of 8
+    pages are a body of its own), the same 100 MB limit."""
+    adapter = _adapter()
+    fn, shapes = _paged_prefill_call(
+        adapter, _kv_shapes(adapter, kvq), None)
+    jaxpr = jax.make_jaxpr(fn)(*shapes)
+    assert "latent" not in str(jaxpr)
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    grid = call.params["grid_mapping"]
+    assert call.params["name"] == "paged_prefill_attention"
+    assert grid.grid == (4, 1) and grid.num_index_operands == 4
+    assert call.params["compiler_params"][
+        "mosaic_tpu"].vmem_limit_bytes == 100 * 1024 * 1024
+    body = call.params["jaxpr"]
+    scratch = [str(v.aval) for v in
+               body.invars[-grid.num_scratch_operands:]]
+    page = {None: "bfloat16", "int8": "int8"}[kvq] + "[2,64,8,128]"
+    scales = ["Ref<vmem>{float32[2,8,128]}"] * 2 if kvq else []
+    planes = 4 if kvq else 2
+    assert scratch == [f"Ref<vmem>{{{page}}}"] * 2 + scales + [
+        f"Ref<semaphore_mem>{{dma_sem[{planes},2]}}"]
+    seen = _primitives(body, {})
+    assert seen["dot_general"] == seen["dot:float32/float32"] == 32
+    assert (seen["dma_start"], seen["dma_wait"]) == (2 * planes, planes)
+    assert (seen["exp"], seen["while"], seen["cond"]) == (32, 2, 2)
 
 
 def test_nemotron3_nano_state_comparison_compiles_at_published_widths(topo):
