@@ -43,6 +43,7 @@ def _engine_config(args, eos_token_ids: tuple = ()) -> EngineConfig:
         page_size=args.page_size,
         max_pages_per_seq=args.max_context // args.page_size,
         prefill_chunk=args.prefill_chunk,
+        prefill_buckets=getattr(args, "prefill_buckets", None),
         max_seqs=args.max_seqs,
         dtype=args.dtype,
         dp=args.dp,
@@ -995,6 +996,13 @@ def build_parser() -> argparse.ArgumentParser:
     )
     runp.add_argument("--max-context", type=int, default=4096, dest="max_context")
     runp.add_argument("--prefill-chunk", type=int, default=512, dest="prefill_chunk")
+    runp.add_argument(
+        "--prefill-buckets", type=int, nargs="+", default=None,
+        dest="prefill_buckets",
+        help="the T buckets a prompt piece is padded to, ascending, the "
+        "last one --prefill-chunk (default: powers of two from 32): fewer "
+        "buckets are fewer step programs to load, paid for in padding",
+    )
     runp.add_argument(
         "--prefill-budget", type=int, default=None, dest="prefill_budget",
         help="prefill tokens per step across sequences (default 4x "

@@ -505,12 +505,10 @@ class AsyncEngineRunner:
                 if sp is not None
                 else None,
             )
+        ended = False  # the engine said its last word on this request
         try:
             while True:
                 if context.cancelled:
-                    with self._lock:
-                        self._aborts.append(request_id)
-                    self._wake.set()
                     return
                 # race the queue against cancellation: a client that
                 # disconnects while its request still sits in the
@@ -519,6 +517,7 @@ class AsyncEngineRunner:
                 item = await queue_get_or_cancelled(context, q)
                 if item is CANCELLED:
                     continue  # loop re-checks context.cancelled -> abort
+                ended = item is None or "error" in item
                 if item is None:
                     return
                 if "error" in item:
@@ -529,6 +528,16 @@ class AsyncEngineRunner:
                     raise RuntimeError(item["error"])
                 yield item
         finally:
+            if not ended:
+                # cancelled, or the consumer went away: a client that
+                # hangs up mid-stream fails the frontend's next write and
+                # this generator is CLOSED where it stands (at the yield),
+                # never resumed to see `context.cancelled`; without the
+                # abort the engine decodes the stream to its last token
+                # for nobody
+                with self._lock:
+                    self._aborts.append(request_id)
+                self._wake.set()
             if wd is not None:
                 wd.done(request_id)
             with self._lock:

@@ -27,6 +27,12 @@ class EngineConfig:
     #: per-sequence prefill chunk length (a prompt is processed in chunks of
     #: at most this many tokens; also the max prefill T bucket)
     prefill_chunk: int = 512
+    #: the T buckets a prompt piece is padded to, ascending, the last one
+    #: `prefill_chunk` (None => powers of two from 32 up to it). Every
+    #: bucket is a step program a decode bucket, a piece-row bucket and a
+    #: sampling variant: a model whose programs are dear to load names few
+    #: (`--prefill-buckets 32 512`) and pays for them in padding
+    prefill_buckets: Optional[tuple[int, ...]] = None
     #: total prefill tokens per step across sequences (None => 4×chunk).
     #: Pieces of the same length bucket run as ONE batched [B, T] program —
     #: this is what lets many short/medium prompts prefill in one dispatch.
@@ -223,6 +229,16 @@ class EngineConfig:
 
             for axis, n in parse_topology(self.topology).items():
                 object.__setattr__(self, axis, n)
+        if self.prefill_buckets is not None:
+            object.__setattr__(
+                self, "prefill_buckets", tuple(self.prefill_buckets))
+            if (list(self.prefill_buckets) != sorted(set(self.prefill_buckets))
+                    or self.prefill_buckets[-1] != max(self.prefill_chunk, 32)
+                    or self.prefill_buckets[0] < 1):
+                raise ValueError(
+                    f"prefill_buckets {self.prefill_buckets} must ascend to "
+                    f"prefill_chunk ({max(self.prefill_chunk, 32)})"
+                )
         if self.prefill_chunk % self.page_size != 0:
             raise ValueError(
                 f"prefill_chunk ({self.prefill_chunk}) must be a multiple of "

@@ -268,6 +268,13 @@ class EngineMetrics:
     state_resets: int = 0
     state_restores: int = 0
     prefix_hits_refused_state: int = 0
+    #: a model whose decode walk reads a CHOSEN part of a row's pages
+    #: (`ModelAdapter.walk_pages`; 0 for every other): pages the lists
+    #: handed to the walks named and pages those rows held, a KV head and
+    #: a layer each, COUNTED ON THE DEVICE by the step programs and read
+    #: back beside each decode-carrying dispatch's ids (`_count_walk`)
+    walk_pages_named: int = 0
+    walk_pages_live: int = 0
     #: the dry clock (telemetry/flight.py `DryClock`; all 0 with
     #: `flight_recorder=False`): cumulative host ms during which the
     #: device had NOTHING queued while the engine had work, from the first
@@ -450,6 +457,9 @@ class _Launched:
     #: its number on the flight recorder's dispatch timeline (None
     #: without the recorder)
     seq: Optional[int] = None
+    #: device int32 [2] or None: the cache's running count of the pages
+    #: its decode walks read, as this dispatch leaves it (`_count_walk`)
+    walk: object = None
 
 
 @dataclass
@@ -548,6 +558,14 @@ class JaxEngine:
         )
         if self._stateful:
             self._refuse_for_state(config)
+        # the device's running count of what its decode walks read: a
+        # copy taken behind each dispatch (the cache itself is donated to
+        # the next one), read where that dispatch's ids are
+        self._walk_peek = None
+        if self.adapter.walk_pages is not None:
+            copy = jax.jit(lambda count: count + 0)
+            self._walk_peek = lambda kv: copy(self.adapter.walk_pages(kv))
+        self._walk_seen = np.zeros(2, np.int64)
         if mc.tp > 1:
             # MLA's shared-latent cache replicates over tp (the q heads
             # still shard) — only head-sharded caches need kv divisibility.
@@ -1177,6 +1195,9 @@ class JaxEngine:
                         getattr(self.allocator, "watermark", 0),
                         self.metrics.kv_pages_watermark,
                     ),
+                    ctx_min=min(
+                        (r.num_tokens for r in batch.decode), default=0
+                    ),
                     admit_wait_ms=admit_waits,
                     timeline=self.metrics.dry_clock.take(),
                 )
@@ -1232,6 +1253,8 @@ class JaxEngine:
                 f"prefill piece of {n} tokens exceeds the T-bucket cap "
                 f"{cap} (pieces must be chunked at prefill_chunk)"
             )
+        if self.config.prefill_buckets:
+            return next(t for t in self.config.prefill_buckets if t >= n)
         t = 32
         while t < n:
             t *= 2
@@ -1308,6 +1331,9 @@ class JaxEngine:
                         if 0 <= off < piece.length:
                             mm_embeds[i, off] = req.mm_embeds[j]
                             mm_mask[i, off] = True
+            # a model that keeps no non-sampling twin samples every chunk
+            # (a token of a piece that is not its prompt's last is unread)
+            any_last = any_last or not self.adapter.step_twins
 
             host = {"base": (
                 tokens, positions, valid,
@@ -2267,6 +2293,7 @@ class JaxEngine:
         return _Launched(
             reqs=tuple(reqs), b_bucket=b_bucket, k_steps=k_steps,
             token_ids=token_ids, lp_data=lp_data, seq=seq,
+            walk=self._walk_peek and self._walk_peek(self.kv),
         )
 
     def _finish_decode(
@@ -2284,9 +2311,21 @@ class JaxEngine:
             lp_arrays = self._materialize_lp(
                 st.lp_data, st.k_steps, st.b_bucket
             )
+            self._count_walk(st)
         return self._decode_postprocess(
             reqs, st.k_steps, ids, lp_arrays, mixed=mixed
         )
+
+    def _count_walk(self, st: _Launched) -> None:
+        """Add what the device counted since the last dispatch read (a
+        dispatch rolled back in between walked its pages too)."""
+        if st.walk is None:
+            return
+        now = np.asarray(st.walk).astype(np.int64)
+        named, live = (now - self._walk_seen) % (1 << 32)
+        self.metrics.walk_pages_named += int(named)
+        self.metrics.walk_pages_live += int(live)
+        self._walk_seen = now
 
     @staticmethod
     def _materialize_lp(lp_data, k_steps: int, b_bucket: int):
@@ -2501,6 +2540,7 @@ class JaxEngine:
                 last_idx[i] = piece.length - 1
                 if piece.start + piece.length >= len(req.prompt_tokens):
                     any_last = True
+            any_last = any_last or not self.adapter.step_twins
             first_chunk = all(p.start == 0 for p in pieces)
             self._poll_clock()
             # sampled row space: decode rows [0, b_dec); when a piece
@@ -2589,6 +2629,7 @@ class JaxEngine:
             reqs=tuple(reqs_d), b_bucket=b_dec, k_steps=1,
             token_ids=token_ids, lp_data=lp_data, pieces=tuple(pieces),
             psamp=any_last, seq=seq,
+            walk=self._walk_peek and self._walk_peek(self.kv),
         )
 
     def _finish_mixed(self, st: _Launched) -> list[StepOutput]:
@@ -2603,6 +2644,7 @@ class JaxEngine:
         ):
             ids = np.asarray(st.token_ids)  # [b_dec] or [b_dec + b_pre]
             lp_arrays = self._materialize_lp(st.lp_data, 1, ids.shape[0])
+            self._count_walk(st)
         d_lp = p_lp = None
         if lp_arrays is not None:
             d_lp = tuple(a[:, :b_dec] for a in lp_arrays)
@@ -3113,6 +3155,9 @@ class JaxEngine:
         pen: int = 0, bias: bool = False, b_pre: int = 0,
         psamp: bool = False,
     ) -> Callable:
+        # a model that keeps no history-free twin runs every chunk as a
+        # later one (`ModelAdapter.step_twins`)
+        first_chunk = first_chunk and self.adapter.step_twins
         cache_key = (
             kind, b, t, greedy, mm, first_chunk, lp, pen, bias, b_pre,
             psamp,

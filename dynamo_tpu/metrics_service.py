@@ -111,6 +111,10 @@ _WORKER_FIELDS = (
     ("state_resets", "counter"),
     ("state_restores", "counter"),
     ("prefix_hits_refused_state", "counter"),
+    # a decode walk that reads a chosen part of a row's pages: pages named
+    # by the selected lists and pages the rows hold (0 for other models)
+    ("walk_pages_named", "counter"),
+    ("walk_pages_live", "counter"),
     # speculative decoding (spec_ngram / spec_draft_model): drafts
     # proposed vs accepted — their ratio times S is the extra tokens per
     # verify dispatch; the skip counters say WHY speculation sat out

@@ -71,6 +71,18 @@ class ModelAdapter:
     state_layers: int = 0
     #: bytes of one sequence's state, one generation (state_layers > 0)
     state_slot_bytes: int = 0
+    #: a model whose decode walk reads a chosen part of a row's pages:
+    #: cache -> the device's running count, int32 [2], of (pages the lists
+    #: given to the walks named, pages those rows held); the engine reads
+    #: it beside each dispatch's ids (`EngineMetrics.walk_pages_named` /
+    #: `walk_pages_live`)
+    walk_pages: Optional[Callable] = None
+    #: False for a model whose step programs are dear to load: the engine
+    #: then keeps ONE prefill-carrying program a shape where it would keep
+    #: twins, a history-free one for chunks that all start a prompt
+    #: (`first_chunk`) and one that samples nothing for chunks none of
+    #: which ends one (every chunk then samples, the token unread)
+    step_twins: bool = True
 
 
 def _kv_pages_spec(kv_quantize=None, shard_heads: bool = True):
@@ -401,7 +413,8 @@ def _hybrid_adapter(name: str, cfg, mod, family: str, axes,
                 "pages alone has no tested path beside the state pool; run "
                 "with kv_quantize=None"
             )
-        return nh.init_cache(cfg, num_pages, page_size, state_slots)
+        return getattr(mod, "init_cache", nh.init_cache)(
+            cfg, num_pages, page_size, state_slots)
 
     def no_mesh_specs(*_a, **_k):
         from dynamo_tpu.parallel.logical import resolve
@@ -422,7 +435,10 @@ def _hybrid_adapter(name: str, cfg, mod, family: str, axes,
         kv_spec=lambda kv_quantize=None: None,
         logical_axes=lambda quantized=False: axes(cfg),
         state_layers=cfg.state_layers,
-        state_slot_bytes=nh.state_bytes_per_slot(cfg),
+        state_slot_bytes=getattr(
+            mod, "state_bytes_per_slot", nh.state_bytes_per_slot)(cfg),
+        walk_pages=getattr(mod, "walk_count", None),
+        step_twins=getattr(mod, "STEP_TWINS", True),
     )
 
 
@@ -438,6 +454,29 @@ def _falcon_h1_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
 
     return _hybrid_adapter(name, cfg, fh, "Falcon-H1",
                            fh.falcon_h1_logical_axes, mesh)
+
+
+def _minicpm_sala_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
+    from dynamo_tpu.models import minicpm_sala as sala
+
+    return _hybrid_adapter(name, cfg, sala, "MiniCPM-SALA",
+                           sala.minicpm_sala_logical_axes, mesh)
+
+
+def _minicpm_sala_presets() -> dict:
+    from dynamo_tpu.models.minicpm_sala import MiniCPMSALAConfig
+
+    return {
+        # MiniCPM-SALA as published: 32 layers (19 GB in bf16: shape tests
+        # and a later multi-chip issue)
+        "minicpm-sala-9b": MiniCPMSALAConfig.minicpm_sala_9b,
+        # one stage of a two-stage pipeline over depth: the published
+        # layers 9-24 (4 sparse, 12 lightning), with the embedding and the
+        # head (chipbench/configs/minicpm-sala-9b-1chip.json)
+        "minicpm-sala-9b-16l": lambda: MiniCPMSALAConfig.minicpm_sala_9b(
+            range(9, 25)),
+        "minicpm-sala-tiny": MiniCPMSALAConfig.tiny,
+    }
 
 
 def _falcon_h1_presets() -> dict:
@@ -494,13 +533,22 @@ def _mla_presets() -> dict:
     }
 
 
+#: the families that keep a recurrent state a sequence beside its pages
+_STATE_FAMILIES = (
+    (_nemotron_h_presets, _nemotron_h_adapter),
+    (_falcon_h1_presets, _falcon_h1_adapter),
+    (_minicpm_sala_presets, _minicpm_sala_adapter),
+)
+
+
 def list_presets() -> list[str]:
     """Every serveable preset id (llama + MoE + MLA families) — the
     iteration surface for `scripts/dryrun_70b.py --check-rules`, which
     dry-resolves each one's logical axes through the rule table."""
     return sorted(_LLAMA_PRESETS) + sorted(_moe_presets()) + sorted(
         _mla_presets()
-    ) + sorted(_nemotron_h_presets()) + sorted(_falcon_h1_presets())
+    ) + sorted(_nemotron_h_presets()) + sorted(_falcon_h1_presets()) + sorted(
+        _minicpm_sala_presets())
 
 
 def get_model(
@@ -538,12 +586,11 @@ def get_model(
         moe_cfg = moe_presets[key]()
     elif key in mla_presets:
         mla_cfg = mla_presets[key]()
-    elif key in _nemotron_h_presets() or key in _falcon_h1_presets():
+    elif any(key in presets() for presets, _ in _STATE_FAMILIES):
         # a family with state-space layers: an adapter of its own
-        presets, adapter = (
-            (_nemotron_h_presets(), _nemotron_h_adapter)
-            if key in _nemotron_h_presets()
-            else (_falcon_h1_presets(), _falcon_h1_adapter)
+        presets, adapter = next(
+            (presets(), adapter) for presets, adapter in _STATE_FAMILIES
+            if key in presets()
         )
         hy_cfg = presets[key]()
         if dtype is not None:

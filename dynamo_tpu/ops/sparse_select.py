@@ -1,0 +1,375 @@
+"""Block-sparse attention that selects pages inside the page walk
+(InfLLM-v2's dense-sparse switchable attention, models/minicpm_sala.py).
+
+A query at position `t` with `n = t + 1` tokens of context attends
+
+- over all of them while `n < dense_len`;
+- from there on over `topk` BLOCKS of `block_size` keys, chosen for its KV
+  head alone: the compressed keys `Kc_j = mean(k[stride j : stride j +
+  kernel])` of every window that ends at or before `t` are scored by each
+  query head of the KV head, `p_h = softmax_j(q_h . Kc_j * scale)`, summed
+  over those heads, max-pooled onto the blocks a window touches (`B_b =
+  max P_j, j in [cpb b - pad, cpb b + cpb - 1]`, `cpb = block / stride`
+  windows start in a block and `pad = kernel / stride - 1` more reach into
+  it from the block before); the first `init_blocks` blocks and the blocks
+  that hold the last `window_size` tokens always count as chosen; ties go
+  to the earlier block.
+
+A block IS a page (`EngineConfig.page_size == block_size`), and a KV head
+IS a sequence of its own here: the cache of a sparse layer is laid out
+[L, P * Hkv, S, 1, D] (page `p` of KV head `h` at `p * Hkv + h`), the
+layout of an MQA cache, so a row of `Hkv` KV heads is `Hkv` VIRTUAL rows of
+one KV head each with `Hq / Hkv` query heads, and a virtual row's page
+table `tables[b] * Hkv + h` may name ANY of its pages in any number. The
+decode walk (ops/paged_attention.py) and the staged cache write
+(ops/kv_update.py) take such rows as they take any other: a selected list
+is a short page table whose pages are all full but the last
+(`decode_lists`). A prompt chunk attends through `masked_attention` here.
+
+Beside a page live its `cpb` compressed keys, in a pool [L, P * Hkv * cpb,
+D] (row `(p * Hkv + h) * cpb + j % cpb` for the window `j` that STARTS in
+the page; the last of a page reaches `kernel - stride` tokens into the
+next). A window's key is written by the step that computes its last token
+(`fresh_windows`, `land_compressed`), from the chunk's own keys and the
+`kernel - 1` cached ones before them. Nothing reads a window that ends
+after the query, so what a dispatch that is rolled back wrote is never
+seen and is written again when the sequence comes by for good; a page that
+is freed takes its compressed keys with it.
+
+Everything here is plain `jax.numpy`: the selection (scope `attn/select`)
+is a gather of the row's compressed keys, one small batched matmul, a
+softmax and two sorts of a few hundred blocks.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+#: a score no pooled probability reaches: blocks that always count as chosen
+_FORCED = 1e30
+#: bytes of float32 scores a tile of `masked_attention` holds
+_SCORE_TILE_BYTES = 96 << 20
+
+
+class SparseDims(NamedTuple):
+    """MiniCPM4's `sparse_config`."""
+
+    kernel_size: int = 32
+    kernel_stride: int = 16
+    block_size: int = 64
+    init_blocks: int = 1
+    window_size: int = 2048
+    topk: int = 64
+    dense_len: int = 8192
+
+    @property
+    def per_block(self) -> int:
+        """Compressed keys whose window starts in one block."""
+        return self.block_size // self.kernel_stride
+
+    @property
+    def reach(self) -> int:
+        """Windows of the block before that reach into a block."""
+        return self.kernel_size // self.kernel_stride - 1
+
+    @property
+    def list_pages(self) -> int:
+        """The longest list a decode row walks: `topk` pages, or every
+        page of a row that is still dense."""
+        return max(self.topk, -(-self.dense_len // self.block_size))
+
+    def check(self, page_size: int) -> None:
+        if page_size != self.block_size:
+            raise ValueError(
+                f"a selection block is a page: --page-size {page_size} "
+                f"must equal the model's block_size {self.block_size}"
+            )
+        if (self.block_size % self.kernel_stride
+                or self.kernel_size % self.kernel_stride):
+            raise ValueError("kernel_stride must divide kernel_size and "
+                             "block_size")
+
+
+# ---------------------------------------------------------------------------
+# Compressed keys
+# ---------------------------------------------------------------------------
+
+
+def history_tail(k_cache, layer, tables, start, n: int):
+    """The `n` cached keys before position `start[b]` of each (virtual)
+    row, oldest first: [B, n, D] from a one-row cache [L, P, S, 1, D];
+    zeros where the row has no such position."""
+    s = k_cache.shape[2]
+    pos = start[:, None] - n + jnp.arange(n, dtype=jnp.int32)[None]
+    ok = pos >= 0
+    pos = jnp.maximum(pos, 0)
+    page = jnp.take_along_axis(tables, pos // s, axis=1)
+    rows = k_cache[layer, page, pos % s, 0]  # one gather, no pool slice
+    return jnp.where(ok[..., None], rows, 0)
+
+
+def fresh_windows(k_chunk, tail, positions, valid, dims: SparseDims):
+    """The compressed keys of the windows that END inside a chunk.
+    k_chunk [B, T, D] the chunk's keys, tail [B, kernel - 1, D] the cached
+    keys before it. Returns (kc [B, T, D]: at chunk token i the mean of
+    the `kernel` keys ending there; ends [B, T]: token i ends a window; j
+    [B, T]: which)."""
+    kk, st = dims.kernel_size, dims.kernel_stride
+    f32 = jnp.float32
+    ext = jnp.concatenate([tail.astype(f32), k_chunk.astype(f32)], axis=1)
+    csum = jnp.cumsum(ext, axis=1)
+    csum = jnp.pad(csum, ((0, 0), (1, 0), (0, 0)))
+    t = k_chunk.shape[1]
+    kc = (csum[:, kk : kk + t] - csum[:, :t]) / kk
+    n = positions + 1
+    ends = valid & (n % st == 0) & (n >= kk)
+    return kc.astype(k_chunk.dtype), ends, (n - kk) // st
+
+
+def gather_compressed(kc_pool, layer, tables, dims: SparseDims,
+                      heads: int = 1):
+    """The (virtual) rows' compressed keys in window order: [B, MP * cpb,
+    D] from the pool [L, P * cpb, D]. `heads` > 1: the rows come `heads`
+    a sequence (b * heads + h, pages p * heads + h), whose compressed
+    keys of one page lie side by side in the pool: one slice of `heads x
+    cpb` rows a page serves them all (half the slices, twice the size)."""
+    cpb = dims.per_block
+    b, mp = tables.shape
+    d = kc_pool.shape[-1]
+    starts = (tables[::heads] * cpb).reshape(-1)
+    out = jax.vmap(lambda at: lax.dynamic_slice(
+        kc_pool, (layer, at, 0), (1, heads * cpb, d)))(starts)
+    out = out.reshape(b // heads, mp, heads, cpb, d)
+    return jnp.moveaxis(out, 2, 1).reshape(b, mp * cpb, d)
+
+
+def with_fresh(kc_hist, fresh, ends, start, dims: SparseDims):
+    """`kc_hist` [B, NC, D] with the windows that end inside the chunk
+    (which the pool does not hold yet) put in their places."""
+    kk, st = dims.kernel_size, dims.kernel_stride
+    t = fresh.shape[1]
+    j = jnp.arange(kc_hist.shape[1], dtype=jnp.int32)[None]
+    i = j * st + (kk - 1) - start[:, None]  # the chunk token that ends j
+    inside = (i >= 0) & (i < t)
+    i = jnp.clip(i, 0, t - 1)
+    inside &= jnp.take_along_axis(ends, i, axis=1)
+    picked = jnp.take_along_axis(fresh, i[..., None], axis=1)
+    return jnp.where(inside[..., None], picked, kc_hist)
+
+
+def land_compressed(kc_pool, staged, tables, dims: SparseDims):
+    """Write a step's fresh compressed keys, all layers at once. `staged`
+    is (kc [L, B, T, D], ends [B, T], j [B, T]); what ends no window is
+    dropped."""
+    kc, ends, j = staged
+    cpb = dims.per_block
+    page = jnp.take_along_axis(
+        tables, jnp.clip(j // cpb, 0, tables.shape[1] - 1), axis=1)
+    rows = jnp.where(ends, page * cpb + j % cpb, kc_pool.shape[1])
+    return kc_pool.at[:, rows.reshape(-1)].set(
+        kc.reshape(kc.shape[0], -1, kc.shape[-1]).astype(kc_pool.dtype),
+        mode="drop",
+    )
+
+
+# ---------------------------------------------------------------------------
+# Selection
+# ---------------------------------------------------------------------------
+
+
+def select_blocks(q, kc, positions, dims: SparseDims, scale: float):
+    """Which blocks each query attends over, for its KV head: q [B, T, G,
+    D] the KV head's query heads, kc [B, NB * cpb, D] the (virtual) row's
+    compressed keys, positions [B, T]. Returns selected [B, T, NB] bool:
+    every block up to the query's own while its context is under
+    `dense_len`, else the `topk` of the rule in this module's docstring."""
+    f32 = jnp.float32
+    kk, st, s = dims.kernel_size, dims.kernel_stride, dims.block_size
+    cpb, reach = dims.per_block, dims.reach
+    nc = kc.shape[1]
+    nb = nc // cpb
+    n = positions + 1
+    last = jnp.where(n >= kk, (n - kk) // st, -1)  # the newest whole window
+    seen = jnp.arange(nc, dtype=jnp.int32) <= last[..., None]  # [B, T, NC]
+    sc = jnp.einsum("btgd,bjd->btgj", q, kc, preferred_element_type=f32)
+    sc = jnp.where(seen[:, :, None], sc * scale, -1e30)
+    p = jax.nn.softmax(sc, axis=-1) * seen[:, :, None]
+    pj = jnp.pad(p.sum(axis=2), ((0, 0), (0, 0), (reach, 0)),
+                 constant_values=-1.0)  # [B, T, reach + NC]
+    score = pj[..., :nc].reshape(*pj.shape[:2], nb, cpb).max(axis=-1)
+    for r in range(reach):  # the windows that start in the block before
+        score = jnp.maximum(score, pj[..., cpb + r :: cpb][..., :nb])
+    blk = jnp.arange(nb, dtype=jnp.int32)
+    own = (positions // s)[..., None]
+    near = (jnp.maximum(positions - dims.window_size + 1, 0) // s)[..., None]
+    exists = blk <= own
+    forced = (blk < dims.init_blocks) | (blk >= near)
+    score = jnp.where(forced, _FORCED, score)
+    score = jnp.where(exists, score, -_FORCED)
+    order = jnp.argsort(-score, axis=-1, stable=True)
+    rank = jnp.argsort(order, axis=-1)
+    dense = (n < dims.dense_len)[..., None]
+    return exists & (dense | (rank < dims.topk))
+
+
+def _page_lists(has, tables, k: int):
+    """The pages of `tables` [B, MP] that `has` [B, NB] marks, in
+    ascending block order, then zeros: (pages [B, k], how many)."""
+    idx = jnp.argsort(~has, axis=-1, stable=True)[:, :k].astype(jnp.int32)
+    count = has.sum(axis=-1).astype(jnp.int32)
+    pages = jnp.take_along_axis(tables, idx, axis=1)
+    if pages.shape[1] < k:
+        pages = jnp.pad(pages, ((0, 0), (0, k - pages.shape[1])))
+    return jnp.where(jnp.arange(k)[None] < count[:, None], pages, 0), count
+
+
+def decode_lists(selected, tables, hist, dims: SparseDims):
+    """A decode row's selection as what the page walk takes: (pages [B,
+    K] of `tables`, the selected blocks that hold cached tokens in
+    ascending order, then zeros; lens [B]: the tokens those pages hold,
+    every page full but the last). selected [B, NB], hist [B] the tokens
+    already cached (the query's own position)."""
+    s = dims.block_size
+    blk = jnp.arange(selected.shape[1], dtype=jnp.int32)[None]
+    pages, count = _page_lists(
+        selected & (blk * s < hist[:, None]), tables, dims.list_pages)
+    tail = hist - s * ((hist - 1) // s)  # tokens in the last cached block
+    lens = jnp.where(hist > 0, s * (count - 1) + tail, 0)
+    return pages, lens.astype(jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# Attention under a block mask (a prompt chunk; every step off the TPU)
+# ---------------------------------------------------------------------------
+
+
+def dense_blocks(positions, n_blocks: int, dims: SparseDims):
+    """The selection of queries that all stand under `dense_len`: every
+    block up to the query's own. [B, T, NB] bool."""
+    blk = jnp.arange(n_blocks, dtype=jnp.int32)
+    return blk <= (positions // dims.block_size)[..., None]
+
+
+def masked_attention(
+    q,  # [B, T, G, D]
+    k_cache, v_cache,  # [L, P, S, 1, Dc]: one-row caches
+    layer,
+    tables,  # [B, MP]
+    q_pos,  # [B, T]
+    selected,  # [B, T, MP]
+    dims: SparseDims,
+    scale: float,
+    hist_len: Optional[jax.Array] = None,  # [B]: cached keys that count
+    k_cur=None, v_cur=None,  # [B, T, D]: the chunk's own keys, not cached
+    cur_pos=None,  # [B, T]; out of reach where a token is padding
+):
+    """Causal softmax attention of each query over the keys of its
+    selected blocks: the sums of a walk over those blocks, computed as
+    dense scores under a mask with one online softmax over TILES of the
+    row's pages, as many tiles as the longest row has history (a loop
+    with a dynamic end: a chunk early in its prompt pays for the keys it
+    has, not for `max_context`), then over the chunk's own keys. With
+    `hist_len` None every key is read from the cache (the scatter
+    discipline wrote the chunk there first). Returns [B, T, G, D] in q's
+    dtype."""
+    f32 = jnp.float32
+    b, t, g, d = q.shape
+    s, mp = k_cache.shape[2], tables.shape[1]
+    if hist_len is None:
+        hist_len = jnp.max(q_pos, axis=1) + 1
+    pt = 1  # pages a tile: the most under the score budget, a power of two
+    while pt * 2 <= mp and b * t * g * pt * 2 * s * 4 <= _SCORE_TILE_BYTES:
+        pt *= 2
+    pad = -mp % pt
+    tables = jnp.pad(tables, ((0, 0), (0, pad)))
+    selected = jnp.pad(selected, ((0, 0), (0, 0), (0, pad)))
+
+    def fold(carry, sc, keep, vals):
+        m, l, acc = carry
+        sc = jnp.where(keep[:, :, None], sc * scale, -1e30)
+        m_new = jnp.maximum(m, sc.max(axis=-1))
+        p = jnp.where(keep[:, :, None], jnp.exp(sc - m_new[..., None]), 0.0)
+        corr = jnp.exp(m - m_new)
+        pv = jnp.einsum("btgk,bkd->btgd", p.astype(vals.dtype), vals,
+                        preferred_element_type=f32)
+        return (m_new, l * corr + p.sum(axis=-1),
+                acc * corr[..., None] + pv)
+
+    def tile(i, carry):
+        pages = lax.dynamic_slice(tables, (0, i * pt), (b, pt))
+        rows = lambda c: c[layer, pages, :, 0].reshape(  # noqa: E731
+            b, pt * s, c.shape[-1])[..., :d]
+        pos = i * (pt * s) + jnp.arange(pt * s, dtype=jnp.int32)
+        keep = jnp.repeat(
+            lax.dynamic_slice(selected, (0, 0, i * pt), (b, t, pt)),
+            s, axis=-1)
+        keep &= (pos[None] < hist_len[:, None])[:, None, :]
+        keep &= pos[None, None, :] <= q_pos[..., None]
+        kt = rows(k_cache)
+        sc = jnp.einsum("btgd,bkd->btgk", q, kt, preferred_element_type=f32)
+        return fold(carry, sc, keep, rows(v_cache))
+
+    carry = (jnp.full((b, t, g), -1e30, f32), jnp.zeros((b, t, g), f32),
+             jnp.zeros((b, t, g, d), f32))
+    n_tiles = (jnp.max(hist_len) + pt * s - 1) // (pt * s)
+    carry = lax.fori_loop(0, n_tiles, tile, carry)
+    if k_cur is not None:
+        blk = jnp.clip(cur_pos // s, 0, selected.shape[-1] - 1)
+        keep = jnp.take_along_axis(
+            selected, jnp.broadcast_to(blk[:, None, :], (b, t, blk.shape[1])),
+            axis=-1)
+        keep &= cur_pos[:, None, :] <= q_pos[..., None]
+        sc = jnp.einsum("btgd,bkd->btgk", q, k_cur,
+                        preferred_element_type=f32)
+        carry = fold(carry, sc, keep, v_cur)
+    _, l, acc = carry
+    return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+
+
+def history_lists(selected, tables, start, dims: SparseDims):
+    """A chunk's selection as page lists over its HISTORY, a list a query:
+    (pages [B * T, K], lens [B * T]). A chunk starts on a page (the
+    scheduler's invariant), so the blocks before `start[b] // S` are
+    whole pages of cached tokens and the rest is the chunk's own.
+    selected [B, T, NB], tables [B, MP], start [B]."""
+    b, t, nb = selected.shape
+    s = dims.block_size
+    cached = jnp.arange(nb, dtype=jnp.int32)[None] < (start // s)[:, None]
+    pages, count = _page_lists(
+        (selected & cached[:, None, :]).reshape(b * t, nb),
+        jnp.repeat(tables, t, axis=0), dims.list_pages)
+    return pages, count * s
+
+
+def chunk_part(q, k_cur, v_cur, q_pos, cur_pos, selected, dims: SparseDims,
+               scale: float):
+    """A chunk's queries over the chunk's OWN keys (not cached yet),
+    under the causal and the block mask, as an unnormalised softmax:
+    (acc [B, T, G, D] f32, m [B, T, G], l [B, T, G]) for `merge_parts`."""
+    f32 = jnp.float32
+    b, t, g, d = q.shape
+    blk = jnp.clip(cur_pos // dims.block_size, 0, selected.shape[-1] - 1)
+    keep = jnp.take_along_axis(
+        selected, jnp.broadcast_to(blk[:, None, :], (b, t, t)), axis=-1)
+    keep &= cur_pos[:, None, :] <= q_pos[..., None]
+    sc = jnp.einsum("btgd,bkd->btgk", q, k_cur, preferred_element_type=f32)
+    sc = jnp.where(keep[:, :, None], sc * scale, -1e30)
+    m = sc.max(axis=-1)
+    p = jnp.where(keep[:, :, None], jnp.exp(sc - m[..., None]), 0.0)
+    acc = jnp.einsum("btgk,bkd->btgd", p.astype(v_cur.dtype), v_cur,
+                     preferred_element_type=f32)
+    return acc, m, p.sum(axis=-1)
+
+
+def merge_parts(a, b_):
+    """Two unnormalised softmax parts (acc, m, l) over disjoint keys as
+    one normalised output, float32."""
+    (acc1, m1, l1), (acc2, m2, l2) = a, b_
+    m = jnp.maximum(m1, m2)
+    w1, w2 = jnp.exp(m1 - m), jnp.exp(m2 - m)
+    den = jnp.maximum(w1 * l1 + w2 * l2, 1e-30)
+    return (w1[..., None] * acc1 + w2[..., None] * acc2) / den[..., None]

@@ -9,8 +9,9 @@ record into a bounded deque. A record holds, as `record_step` writes it:
 
 - `seq`, `ts` (wall clock when the step ended; consumers cut windows by
   it), `kind`, `step_ms`;
-- the batch: `n_decode` / `b_decode` (real rows / bucket), `n_prefill`,
-  `t_bucket`, `prefill_tokens`;
+- the batch: `n_decode` / `b_decode` (real rows / bucket), `ctx_min` (the
+  shortest sequence among the decode rows, tokens; absent without decode
+  rows), `n_prefill`, `t_bucket`, `prefill_tokens`;
 - the queues and the pool: `waiting`, `running`, `free_pages`,
   `active_pages`, `watermark`;
 - `admit_wait_ms`: the queue waits of the requests this step admitted
@@ -20,7 +21,8 @@ record into a bounded deque. A record holds, as `record_step` writes it:
   `host_ms`, `sched_ms`, `stage_ms`, `emit_ms`, `intake_ms`), the
   launch-ahead pipeline (`overlap_hits`, `overlap_rollbacks`), the
   recurrent-state plane (`state_resets`, `state_restores`,
-  `prefix_refused_state`), speculation (`spec_drafted`,
+  `prefix_refused_state`), a selecting walk (`walk_pages_named`,
+  `walk_pages_live`), speculation (`spec_drafted`,
   `spec_accepted`), `compiles` / `compile_ms`, `preempted`, `tokens`,
   and the dry clock's counters (`dry_ms`, `dry_slack_ms`, `dry_wait_ms`,
   `dry_<phase>_ms`, `dry_launches`, `launches`);
@@ -91,6 +93,9 @@ _DELTA_FIELDS = (
     ("state_resets", "state_resets"),
     ("state_restores", "state_restores"),
     ("prefix_refused_state", "prefix_hits_refused_state"),
+    # a model whose decode walk reads a chosen part of a row's pages
+    ("walk_pages_named", "walk_pages_named"),
+    ("walk_pages_live", "walk_pages_live"),
     # speculative decoding (ngram or draft model): drafted/accepted per
     # step — a record with tokens but no spec_drafted is a plain step
     ("spec_drafted", "spec_drafted"),
@@ -388,6 +393,7 @@ class FlightRecorder:
         free_pages: int = 0,
         active_pages: int = 0,
         watermark: int = 0,
+        ctx_min: int = 0,
         admit_wait_ms: Optional[list] = None,
         timeline: Optional[dict] = None,
     ) -> dict:
@@ -410,6 +416,9 @@ class FlightRecorder:
             "active_pages": active_pages,
             "watermark": watermark,
         }
+        if n_decode:
+            # the shortest sequence among the step's decode rows, tokens
+            rec["ctx_min"] = ctx_min
         if admit_wait_ms:
             # queue waits (ms) of the requests this step admitted,
             # traced or not; absent when it admitted none

@@ -5,15 +5,17 @@ greedy streams of 48 + 64 tokens cross no chunk boundary and run beside
 no other row): ONE request under the cell's own shapes, outside every
 timing, teacher-forced against the configuration's plain reference
 (its `reference_module`) on logits. `--cell` is `nano3-chat-churn` (the
-default) or `falconh1-longdoc`.
+default), `falconh1-longdoc` or `sala-longctx`.
 
 The engine is the cell's configuration's (its preset and serve flags,
 launch-ahead on, fused 8-step dispatches, mixed steps). The other slots
 are kept busy with the cell's traffic (prompt and answer lengths from
 its traffic file, sampled 0.7 / 0.9, a new request for every one that
 ends, so admissions run beside the target all the way). The target: a
-prompt of ~1,300 tokens for `nano3-chat-churn` (three chunks of 512) or
-~5,000 for `falconh1-longdoc` (ten chunks), the last chunk padded into
+prompt of ~1,300 tokens for `nano3-chat-churn` (three chunks of 512),
+~5,000 for `falconh1-longdoc` (ten chunks) or 12,288 for `sala-longctx`
+(24 chunks, the last eight past `dense_len`, so that every decoded token
+selects 64 of its 193 pages), the last chunk padded into
 its bucket, then 64 greedy tokens with their log-probs. Rollbacks are FORCED before and among the compared
 tokens: a neighbour is aborted while a dispatch launched ahead is on the
 device (during the target's prefill, and twice during its decode), so
@@ -74,7 +76,8 @@ def main(argv=None) -> int:
     vocab = hf["vocab_size"]
     rng = np.random.default_rng(ns.seed)
     n_prompt = ns.prompt or (
-        {"falconh1-longdoc": 5000}.get(ns.cell, 1300) if on_chip
+        {"falconh1-longdoc": 5000, "sala-longctx": 12288}.get(ns.cell, 1300)
+        if on_chip
         else 2 * cfg.prefill_chunk + 11)
     shape = np.random.default_rng(7)
     counter = iter(range(1 << 30))

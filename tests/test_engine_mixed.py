@@ -377,6 +377,48 @@ def test_bucket_t_guard_rejects_oversized_piece(engine_factory):
         eng._bucket_t(cap + 1)
 
 
+@pytest.mark.parametrize("buckets,piece,bucket", [
+    (None, 33, 64), (None, 100, 128), ((32, 128), 33, 128),
+    ((32, 128), 32, 32), ((128,), 5, 128), ((16, 64, 128), 17, 64)])
+def test_named_t_buckets_take_the_place_of_the_powers_of_two(
+        engine_factory, buckets, piece, bucket):
+    """`EngineConfig.prefill_buckets` (`--prefill-buckets`): a piece is
+    padded to the first named bucket that holds it, so a model whose step
+    programs are dear to load keeps few of them."""
+    eng = engine_factory(prefill_chunk=128, prefill_buckets=buckets)
+    assert eng._bucket_t(piece) == bucket
+
+
+@pytest.mark.parametrize("buckets", [(64, 32, 128), (32, 64), (0, 128),
+                                     (32, 32, 128)])
+def test_t_buckets_that_do_not_ascend_to_the_chunk_are_refused(buckets):
+    base = EngineConfig.for_tests()
+    with pytest.raises(ValueError, match="prefill_buckets"):
+        EngineConfig(**{**base.__dict__, "prefill_chunk": 128,
+                        "prefill_buckets": buckets})
+
+
+def test_two_t_buckets_serve_the_streams_of_the_default_ones(engine_factory):
+    """Padding a tail piece further changes no token: the same requests
+    under the default buckets and under (32, 128), and only those two T
+    among the second engine's step programs."""
+    rng = np.random.default_rng(3)
+    reqs = [(f"r{i}", [int(x) for x in rng.integers(3, 200, n)],
+             SamplingParams(max_tokens=6, temperature=0.0))
+            for i, n in enumerate((150, 40, 70))]
+    outs = []
+    for buckets in (None, (32, 128)):
+        eng = engine_factory(prefill_chunk=128, prefill_buckets=buckets,
+                             num_pages=256, max_pages_per_seq=48)
+        for rid, prompt, sp in reqs[:1]:
+            eng.add_request(rid, prompt, sp)
+        outs.append(_drive(eng, late=reqs[1:], late_at=3))
+        ts = {k[2] for k in eng.programs
+              if k[0] in ("mixed", "prefill", "prefill_nosample")}
+    assert outs[0] == outs[1]
+    assert ts <= {32, 128} and 128 in ts
+
+
 def test_decode_stall_histogram_observed(engine_factory):
     """dynamo_tpu_phase_decode_stall_ms: gaps between a running request's
     token emissions with a prefill-carrying dispatch in between land in

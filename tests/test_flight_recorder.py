@@ -64,6 +64,9 @@ def test_engine_steps_append_records_with_buckets_and_compiles():
     assert pre["prefill_tokens"] == 15
     dec = next(r for r in recs if r["kind"] in ("decode", "mixed"))
     assert dec["n_decode"] == 3 and dec["b_decode"] == 4  # bucket of 3
+    # the shortest sequence among the decode rows (prompts of 5 tokens
+    # decoding 4): only where rows decode
+    assert 6 <= dec["ctx_min"] <= 9 and "ctx_min" not in pre
     # the first steps carry the jit-compile events
     assert sum(r.get("compiles", 0) for r in recs) == eng.metrics.compiles
     assert all(r["step_ms"] > 0 for r in recs)

@@ -596,6 +596,46 @@ def test_disconnect_while_waiting_frees_the_slot(tiny_cfg):
     assert eng.allocator.num_free == free_before
 
 
+@pytest.mark.parametrize("how", ["closed", "collected"])
+def test_a_stream_abandoned_mid_decode_is_aborted(tiny_cfg, how):
+    """A client that hangs up MID-STREAM fails the frontend's next write,
+    and the handler leaves its `async for`: the stream's generators are
+    closed where they stand (explicitly, or when collected), never resumed
+    to look at the context. The engine must stop decoding for nobody: the
+    request is aborted, its pages come back, and far fewer tokens than it
+    asked for were generated."""
+    from dynamo_tpu.engine.async_engine import AsyncEngineRunner
+
+    eng = JaxEngine(replace(tiny_cfg, num_pages=256, max_pages_per_seq=64))
+    free_before = eng.allocator.num_free
+    faults.install(seed=0).add_rule("engine.step", "delay", delay_ms=5.0)
+
+    async def go():
+        runner = AsyncEngineRunner(eng)
+        runner.start()
+        try:
+            stream = runner.generate(Context(), _pre("left", max_tokens=200))
+            seen = 0
+            async for item in stream:
+                seen += len(item.get("token_ids", ()))
+                if seen >= 3:
+                    break
+            if how == "closed":
+                await stream.aclose()
+            else:
+                del stream
+            deadline = time.time() + 10
+            while eng.scheduler.has_work and time.time() < deadline:
+                await asyncio.sleep(0.02)
+            assert not eng.scheduler.has_work
+        finally:
+            runner.stop()
+
+    run(go())
+    assert eng.metrics.generated_tokens < 100
+    assert eng.allocator.num_free == free_before
+
+
 # -- disagg dead-letter (satellite 2) ---------------------------------------
 
 

@@ -608,6 +608,149 @@ def test_hybrid_cut_step_compiles_at_published_widths(
     assert _mosaic_calls(compiled) >= 4
 
 
+def test_gqa_decode_walk_is_what_it_was_before_the_selecting_one():
+    """PR 41 makes the decode walk read a CHOSEN part of a row (a list of
+    pages a KV head, models/minicpm_sala.py) without a line of
+    `ops/paged_attention.py`: a KV head of such a model is a row of its
+    own over a one-row cache. The five other cells' page lists stay per
+    ROW, and a decode program of `qwen2-7b` traces to the walk it traced
+    to on PR 39's tree, read here from the jaxpr at its served widths (64
+    rows, 28 / 4 heads of 128, int8 weights aside): ONE kernel of that
+    name in a layer, five prefetched scalars of which the flat page
+    tables hold rows x max pages entries, the whole batch's q and acc
+    resident, two slots of 8 pages x 64 x 4 rows, two dots a block."""
+    adapter = get_model("qwen2-7b", dtype="bfloat16",
+                        attention_impl="pallas")
+    cfg = adapter.config
+    b, mp, pages = 64, 8192 // PAGE, 1700
+    kv = jax.eval_shape(lambda: adapter.init_kv(pages, PAGE))
+    q = jax.ShapeDtypeStruct((b, cfg.num_heads, 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v, pt, hist: paged_decode_attention(
+            q, k, v, jnp.int32(0), pt, hist, scale_dim=128)
+    )(q, kv.k, kv.v, jax.ShapeDtypeStruct((b, mp), jnp.int32),
+      jax.ShapeDtypeStruct((b,), jnp.int32))
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    grid = call.params["grid_mapping"]
+    assert call.params["name"] == "paged_decode_attention"
+    assert grid.grid == (1,) and grid.num_index_operands == 5
+    shapes = [str(v.aval) for v in call.invars]
+    assert shapes[:5] == ["int32[1]", "int32[1]", f"int32[{b}]",
+                          f"int32[{b * mp}]", f"int32[{b}]"]
+    assert shapes[5:] == [
+        "bfloat16[64,32,128]",  # 28 query heads in whole sublane tiles
+        f"bfloat16[28,{pages},256,128]", f"bfloat16[28,{pages},256,128]"]
+    body = call.params["jaxpr"]
+    scratch = [str(v.aval) for v in
+               body.invars[-grid.num_scratch_operands:]]
+    assert scratch == ["Ref<vmem>{bfloat16[2,2048,128]}"] * 2 + [
+        "Ref<semaphore_mem>{dma_sem[2,2]}"]
+    seen = _primitives(body, {})
+    assert seen["dot_general"] == seen["dot:bfloat16/bfloat16"] == 2
+    assert (seen["dma_start"], seen["dma_wait"]) == (4, 2)
+    # and the selecting walk IS that kernel: a KV head a row, 16 query
+    # heads, a list of at most 128 pages of one row each
+    sala = get_model("minicpm-sala-9b-16l", dtype="bfloat16",
+                     attention_impl="pallas")
+    skv = jax.eval_shape(lambda: sala.init_kv(9000, PAGE, state_slots=36))
+    assert skv.k.shape == (4, 18000, 64, 1, 128)
+    assert skv.kc.shape == (4, 72000, 128)
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v, pt, hist: paged_decode_attention(
+            q, k, v, jnp.int32(0), pt, hist, scale_dim=128)
+    )(jax.ShapeDtypeStruct((64, 16, 128), jnp.bfloat16), skv.k, skv.v,
+      jax.ShapeDtypeStruct((64, 128), jnp.int32),
+      jax.ShapeDtypeStruct((64,), jnp.int32))
+    (walk,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    assert walk.params["name"] == "paged_decode_attention"
+    assert _primitives(walk.params["jaxpr"], {}) == seen
+
+
+@pytest.mark.parametrize("rows,t,b_pre", [
+    pytest.param(32, 1, 0, id="decode-32-rows-fused-8"),
+    pytest.param(32, 512, 1, id="mixed-32-rows-beside-a-chunk"),
+    pytest.param(32, 512, 4, id="mixed-32-rows-beside-four-pieces"),
+])
+def test_minicpm_sala_step_compiles_at_published_widths(
+        topo, rows, t, b_pre):
+    """Whole steps of `minicpm-sala-9b-16l` as `sala-longctx` serves it
+    (bf16, 9,000 pages of 64 with their compressed keys, 36 state slots in
+    two generations, --max-context 18432): the page walk over a selected
+    list at one KV head a row (64 virtual rows, 16 query heads), the
+    single-row cache writer, the state kernel at one group a head (the
+    whole 2.1 MB row a grid step), the row writer and reader of a prompt
+    chunk's state go through the TPU compiler inside ONE body a kind of
+    layer (a scan over the sparse layers, a loop over the lightning layers
+    that follow each); every pool is updated in place; the program
+    fits the chip beside 10.08 GB of weights, 1.86 GB of state and 2.43 GB
+    of pages. The temporaries stay small: without the barrier in
+    `models/minicpm_sala._heads` the compiler transposed q, k and v of
+    every layer into 1.38 GB of copies ahead of the layer loops."""
+    adapter = get_model("minicpm-sala-9b-16l", dtype="bfloat16",
+                        attention_impl="pallas")
+    chip = SingleDeviceSharding(topo.devices[0])
+    params = _on(chip, jax.eval_shape(
+        lambda: adapter.init_params(jax.random.key(0))))
+    kv = _on(chip, jax.eval_shape(
+        lambda: adapter.init_kv(9000, PAGE, state_slots=36)))
+    assert kv.ssm.shape == (12, 74, 32, 128, 128) and kv.conv is None
+    mp = 18432 // PAGE
+
+    def rows_of(b, tt):
+        return (
+            _sds((b, tt), jnp.int32, chip), _sds((b, tt), jnp.int32, chip),
+            _sds((b, tt), jnp.bool_, chip),
+            (_sds((b, mp), jnp.int32, chip), _sds((b, 2), jnp.int32, chip)),
+        )
+
+    def head(params, hidden):
+        logits = adapter.compute_logits(params, hidden[:, -1])
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    if b_pre:
+
+        def program(params, kv, prompt, decode):
+            h_p, h_d, kv = adapter.forward_hidden_mixed(
+                params, prompt, decode, kv)
+            return head(params, h_d), kv
+
+        args = (rows_of(b_pre, t), rows_of(rows, 1))
+    else:
+
+        def program(params, kv, tokens, positions, valid, pt):
+            def body(carry, _):
+                tokens, positions, kv, pt = carry
+                hidden, kv = adapter.forward_hidden(
+                    params, tokens, positions, valid, kv, pt)
+                ids = head(params, hidden)
+                pt = (pt[0], jnp.broadcast_to(pt[1][:, 1:], pt[1].shape))
+                return (ids[:, None], positions + 1, kv, pt), ids
+
+            (_, _, kv, _), ids = jax.lax.scan(
+                body, (tokens, positions, kv, pt), None, length=8)
+            return ids, kv
+
+        args = rows_of(rows, t)
+
+    compiled = jax.jit(program, donate_argnums=(1,)).lower(
+        params, kv, *args).compile()
+    mem = compiled.memory_analysis()
+    pools = sum(np.prod(x.shape) * x.dtype.itemsize
+                for x in (kv.k, kv.v, kv.kc, kv.ssm))
+    assert mem.alias_size_in_bytes >= pools  # every pool in place
+    assert mem.temp_size_in_bytes < 1.0e9  # 0.03 / 0.23 / 0.88 GB
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    text = compiled.as_text()
+    assert "ssm_decode_step" in text and "paged_decode_attention" in text
+    if t > 1:
+        assert "state_read_rows" in text and "state_write_rows" in text
+    # one body a kind: the walk, the page writer and the state kernel of a
+    # decode step; a chunk adds its own walk, its kernel and its state rows
+    assert _mosaic_calls(compiled) >= (7 if b_pre else 3)
+
+
 @pytest.mark.parametrize("rows,vocab", [
     (64, 152_064),  # qwen2-longgen
     (32, 261_120),  # falconh1-longdoc
