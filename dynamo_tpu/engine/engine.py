@@ -275,6 +275,12 @@ class EngineMetrics:
     #: back beside each decode-carrying dispatch's ids (`_count_walk`)
     walk_pages_named: int = 0
     walk_pages_live: int = 0
+    #: the same count's other half, of such a model's PROMPT CHUNKS past
+    #: the switch to its sparse rule: pages the query tiles' lists named
+    #: (what the chunk kernel read, a page once a tile) and pages their
+    #: queries' selections named (what a walk a query would have read)
+    chunk_pages_read: int = 0
+    chunk_pages_named: int = 0
     #: the dry clock (telemetry/flight.py `DryClock`; all 0 with
     #: `flight_recorder=False`): cumulative host ms during which the
     #: device had NOTHING queued while the engine had work, from the first
@@ -457,8 +463,9 @@ class _Launched:
     #: its number on the flight recorder's dispatch timeline (None
     #: without the recorder)
     seq: Optional[int] = None
-    #: device int32 [2] or None: the cache's running count of the pages
-    #: its decode walks read, as this dispatch leaves it (`_count_walk`)
+    #: device int32 [4] or None: the cache's running count of the pages
+    #: its walks and chunk tiles read, as this dispatch leaves it
+    #: (`_count_walk`)
     walk: object = None
 
 
@@ -558,14 +565,14 @@ class JaxEngine:
         )
         if self._stateful:
             self._refuse_for_state(config)
-        # the device's running count of what its decode walks read: a
-        # copy taken behind each dispatch (the cache itself is donated to
-        # the next one), read where that dispatch's ids are
+        # the device's running count of what its walks and chunk tiles
+        # read: a copy taken behind each dispatch (the cache itself is
+        # donated to the next one), read where that dispatch's ids are
         self._walk_peek = None
         if self.adapter.walk_pages is not None:
             copy = jax.jit(lambda count: count + 0)
             self._walk_peek = lambda kv: copy(self.adapter.walk_pages(kv))
-        self._walk_seen = np.zeros(2, np.int64)
+        self._walk_seen = np.zeros(4, np.int64)
         if mc.tp > 1:
             # MLA's shared-latent cache replicates over tp (the q heads
             # still shard) — only head-sharded caches need kv divisibility.
@@ -2322,9 +2329,11 @@ class JaxEngine:
         if st.walk is None:
             return
         now = np.asarray(st.walk).astype(np.int64)
-        named, live = (now - self._walk_seen) % (1 << 32)
-        self.metrics.walk_pages_named += int(named)
-        self.metrics.walk_pages_live += int(live)
+        for name, n in zip(
+            ("walk_pages_named", "walk_pages_live", "chunk_pages_read",
+             "chunk_pages_named"), (now - self._walk_seen) % (1 << 32),
+        ):
+            setattr(self.metrics, name, getattr(self.metrics, name) + int(n))
         self._walk_seen = now
 
     @staticmethod

@@ -115,6 +115,10 @@ _WORKER_FIELDS = (
     # by the selected lists and pages the rows hold (0 for other models)
     ("walk_pages_named", "counter"),
     ("walk_pages_live", "counter"),
+    # its sparse prompt chunks: pages the query tiles read and pages their
+    # queries' selections named (named / read: reads saved by the tile)
+    ("chunk_pages_read", "counter"),
+    ("chunk_pages_named", "counter"),
     # speculative decoding (spec_ngram / spec_draft_model): drafts
     # proposed vs accepted — their ratio times S is the extra tokens per
     # verify dispatch; the skip counters say WHY speculation sat out

@@ -31,15 +31,16 @@ scalars act at run time:
   compressed keys beside the pages: `SalaCache`.
 
 A decode step selects inside the walk (scope `attn/select`, then the page
-walk over the selected list, `attn/paged`). A prompt chunk none of whose
-queries has reached `dense_len` is a dense chunk in the kernel that takes
-a one-row cache (`latent_prefill_attention`, the V pool as its latent and
-the K pool as its rope key: the GQA chunk kernels cannot slice one KV head
-of a tiled page); in one that has, every query walks ITS OWN list of
-cached pages in the decode kernel (a query a row) and the chunk's own keys
-are one dense tile beside it, the two parts merged exactly. Both under
-scope `attn/flash`. (Without the kernels, `attention_impl` "xla", dense
-scores under each query's block mask: `sparse_select.masked_attention`.)
+walk over the selected list, `attn/paged`). A prompt chunk attends by
+TILE of 128 queries in a kernel of its own (ops/sparse_chunk.py
+`sparse_chunk_attention`, scope `attn/flash`; the GQA chunk kernels cannot
+slice one KV head of a tiled page): the cached pages ANY query of the tile
+chose are read once, a block of pages a turn, a mask bit a (query, page)
+says whose they are, and the chunk's own keys take the first turns of the
+same online softmax. A chunk none of whose queries has reached `dense_len`
+skips the selection's sorts (`dense_blocks`: every page, every bit).
+(Without the kernels, `attention_impl` "xla", dense scores under each
+query's block mask: `sparse_select.masked_attention`.)
 
 The layers are held in TWO stacks, one a kind, each in layer order
 (`params["sparse"]`, `params["lightning"]`), and a step program holds each
@@ -86,16 +87,15 @@ MIXERS_9B = tuple(
     SPARSE if i in (0, 9, 16, 17, 22, 29, 30, 31) else LIGHTNING
     for i in range(32)
 )
-#: queries of a sparse chunk that walk their lists in one call of the
-#: decode kernel (its q, acc and m | l blocks hold the call's rows in VMEM:
-#: 40 KB a row of 16 query heads)
-WALK_ROWS = 128
+#: float32 scores over the compressed keys that one call of the selection
+#: may hold (`sparse_attention`): a prompt step selects its rows in groups
+SELECT_BYTES = 96 << 20
 #: `ModelAdapter.step_twins`: a step program of this family holds eight
 #: Pallas kernels in two layer bodies and costs 6-7 s to load from the
 #: compile cache, 15-19 s to compile (PERF.md 6, PR 41), so the engine
 #: keeps one program a shape: no step here reads `StepGroup.first_chunk`
-#: (a sparse chunk's history is its page lists, a lightning chunk starts
-#: from its slot), and the head over a chunk's last rows costs nothing
+#: (a sparse chunk's history is its tiles' page lists, a lightning chunk
+#: starts from its slot), and the head over a chunk's last rows costs nothing
 #: beside the decode rows'
 STEP_TWINS = False
 #: the muP scalars a test can miss
@@ -239,10 +239,13 @@ class SalaCache(NamedTuple):
     P * Hkv, S, 1, D]: page p of KV head h at p * Hkv + h); `kc` the
     compressed keys beside them ([L, P * Hkv * per_block, D]); `ssm` the
     lightning layers' slot pool (ops/ssm_state.py). No conv window.
-    `walked` counts, on the device, what the decode walks READ: int32 [2],
-    the pages their lists named and the pages their rows held (a KV head
-    and a sparse layer each), summed over every decode step since the
-    cache was made and wrapping at 2**32 (`pages_walked`)."""
+    `walked` counts, on the device, what the steps READ of the pages, a KV
+    head and a sparse layer each, summed since the cache was made and
+    wrapping at 2**32: int32 [4], the pages the decode walks' lists named
+    and the pages their rows held (`pages_walked`), then the pages a
+    sparse prompt chunk's TILES read and the pages its queries'
+    selections named (ops/sparse_chunk.py: their ratio is what reading a
+    page once a tile saves)."""
 
     k: jax.Array
     v: jax.Array
@@ -299,7 +302,7 @@ def init_cache(cfg: MiniCPMSALAConfig, num_pages: int, page_size: int,
                      cfg.dtype),
         ssm=jnp.zeros((cfg.state_layers, 2 * (state_slots + 1),
                        cfg.lightning_heads, ld, ld), jnp.float32),
-        walked=jnp.zeros((2,), jnp.int32),
+        walked=jnp.zeros((4,), jnp.int32),
     )
 
 
@@ -532,24 +535,42 @@ def sparse_attention(
     """Attention of virtual rows under the selection rule, in the write
     discipline of models/llama.py `attention_block`. Returns (attn [B',
     T, G * D], kv, the staged (k, v) or None, the step's fresh compressed
-    keys (kc [B', T, Dc], ends, j) for `land_compressed`, and what a
-    decode step's walks read, `pages_walked`; zeros for a chunk)."""
+    keys (kc [B', T, Dc], ends, j) for `land_compressed`, and what the
+    step read of the cache, int32 [4]: `pages_walked` of a decode step's
+    walks, then the pages the tiles of a chunk's rows past `dense_len`
+    read and the pages their queries' selections named; zeros where a
+    step has none of them)."""
     dims, acfg = cfg.sparse, cfg.attn_cfg
     b, t, g, d = q.shape
     scale = 1.0 / math.sqrt(d)
     dpad = acfg.kv_head_dim - d
     start = positions[:, 0]
-    no_walk = jnp.zeros((2,), jnp.int32)
+    none = jnp.zeros((2,), jnp.int32)
+    past = valid & (positions + 1 >= dims.dense_len)  # under the sparse rule
     with jax.named_scope("select"):
         kc, fresh = compressed_keys_of(
             k, kv, kc_pool, layer, tables, positions, valid, cfg)
 
+    def chosen():
+        """`select_blocks`, as many rows at a time as keep its float32
+        scores ([rows, T, G, NC]) under `SELECT_BYTES`: 32 prompts of 32
+        tokens side by side hold as many as four pieces of 512."""
+        n = b
+        while n % 2 == 0 and n * t * g * kc.shape[1] * 4 > SELECT_BYTES:
+            n //= 2
+        if n == b:
+            return ss.select_blocks(q, kc, positions, dims, scale)
+        return lax.map(
+            lambda a: ss.select_blocks(*a, dims, scale),
+            tuple(x.reshape(b // n, n, *x.shape[1:])
+                  for x in (q, kc, positions)),
+        ).reshape(b, t, -1)
+
     def select():
-        """Each query's blocks; the sort is skipped where every query of
-        the step stands under `dense_len`."""
+        """Each query's blocks; the sorts are skipped where every query
+        of the step stands under `dense_len`."""
         return lax.cond(
-            jnp.any(valid & (positions + 1 >= dims.dense_len)),
-            lambda: ss.select_blocks(q, kc, positions, dims, scale),
+            jnp.any(past), chosen,
             lambda: ss.dense_blocks(positions, tables.shape[1], dims),
         )
 
@@ -561,80 +582,47 @@ def sparse_attention(
         with jax.named_scope("paged"):
             attn = ss.masked_attention(
                 q, kv.k, kv.v, layer, tables, positions, sel, dims, scale)
-        walk = no_walk
+        walk = none
         if t == 1:  # the lists a walk would take, for their count alone
             walk = pages_walked(
                 ss.decode_lists(sel[:, 0], tables, start, dims)[1], start,
                 valid[:, 0], dims.block_size)
-        return attn.reshape(b, t, g * d), kv, None, fresh, walk
+        return (attn.reshape(b, t, g * d), kv, None, fresh,
+                jnp.concatenate([walk, none]))
     if t == 1:
         with jax.named_scope("select"):
             _, pages, lens = decode_selection(q, kc, tables, positions, cfg)
         attn, kv, staged = attention_block(
             q, k, v, kv, layer, pages, lens[:, None], valid, acfg)
-        return attn, kv, staged, fresh, pages_walked(
-            lens, start, valid[:, 0], dims.block_size)
+        return attn, kv, staged, fresh, jnp.concatenate([pages_walked(
+            lens, start, valid[:, 0], dims.block_size), none])
     # a prompt chunk: the cache is read-only (its history), the chunk's
-    # own keys are in hand and staged for the step's one write
+    # own keys are in hand and staged for the step's one write. It attends
+    # by TILE of queries over the cached pages ANY query of the tile chose,
+    # each read once a tile, a mask bit a (query, page), and over its own
+    # keys (ops/sparse_chunk.py); a query under `dense_len` names every
+    # page up to its own, so a dense chunk is the same kernel with every
+    # bit set (PERF.md 6, PR 42: past ~2,000 tokens of history it beats
+    # `latent_prefill_attention` fed a zero latent half)
+    from dynamo_tpu.ops.sparse_chunk import sparse_chunk_attention
+
     pad = ((0, 0), (0, 0), (0, 0), (0, dpad))
     k_pad, v_pad = (jnp.pad(k, pad), jnp.pad(v, pad)) if dpad else (k, v)
-    hist_len = jnp.where(valid[:, 0], start, 0).astype(jnp.int32)
-
-    def dense():
-        """Every query stands under `dense_len`: causal attention over
-        the row's whole history and the chunk, in the chunk kernel that
-        takes a one-row cache (ops/flash_prefill.py
-        `latent_prefill_attention`: `softmax(q_lat . latent + q_pe .
-        rope_key) . latent` over a latent pool that is key and value at
-        once and a rope-key pool beside it). Here the V pool stands where
-        the latent does and the K pool where the rope key does, under
-        queries whose latent part is zero: `softmax(q . K) . V`."""
-        from dynamo_tpu.ops.flash_prefill import latent_prefill_attention
-
+    with jax.named_scope("select"):
+        sel = select()
+    with jax.named_scope("flash"):
         q_s = (q.astype(jnp.float32) * scale).astype(q.dtype)
-        if dpad:
-            q_s = jnp.pad(q_s, pad)
-        with jax.named_scope("flash"):
-            out = latent_prefill_attention(
-                jnp.zeros_like(q_s), q_s, v_pad[:, :, 0], k_pad[:, :, 0],
-                kv.v, kv.k, layer, tables, hist_len,
-                jnp.sum(valid, axis=1).astype(jnp.int32),
-            )
-        return out[..., :d].reshape(b, t, g * d)
-
-    def sparse():
-        """Some query has reached `dense_len`: each query walks ITS OWN
-        list of cached pages in the decode kernel, a query a row (a chunk
-        starts on a page, so its history is whole pages), `WALK_ROWS`
-        rows a call; the chunk's own keys are one dense tile; the two
-        parts merge exactly."""
-        from dynamo_tpu.ops.paged_attention import paged_decode_attention
-
-        with jax.named_scope("select"):
-            sel = ss.select_blocks(q, kc, positions, dims, scale)
-            pages, lens = ss.history_lists(sel, tables, start, dims)
-        with jax.named_scope("flash"):
-            rows = b * t
-            qr = q.reshape(rows, g, d)
-            if dpad:
-                qr = jnp.pad(qr, ((0, 0), (0, 0), (0, dpad)))
-            n = math.gcd(rows, WALK_ROWS)
-            split = lambda x: x.reshape(rows // n, n, *x.shape[1:])  # noqa: E731
-            acc, m, l = lax.map(
-                lambda a: paged_decode_attention(
-                    a[0], kv.k, kv.v, layer, a[1], a[2], scale_dim=d),
-                (split(qr), split(pages), split(lens)))
-            hist = (acc.reshape(b, t, g, -1)[..., :d], m.reshape(b, t, g),
-                    l.reshape(b, t, g))
-            own = ss.chunk_part(
-                q, k[:, :, 0], v[:, :, 0], positions,
-                jnp.where(valid, positions, 1 << 30), sel, dims, scale)
-            return ss.merge_parts(hist, own).astype(q.dtype).reshape(
-                b, t, g * d)
-
-    attn = lax.cond(
-        jnp.any(valid & (positions + 1 >= dims.dense_len)), sparse, dense)
-    return attn, kv, (k_pad, v_pad), fresh, no_walk
+        out, (tiles, named) = sparse_chunk_attention(
+            jnp.pad(q_s, pad) if dpad else q_s, k_pad[:, :, 0],
+            v_pad[:, :, 0], kv.k, kv.v, layer, tables, sel,
+            jnp.where(valid[:, 0], start, 0), valid)
+    # counted for the rows the sparse rule reached: under `dense_len` a
+    # tile of 128 names every page 128 times, whatever the queries are
+    read = jnp.stack([
+        jnp.sum(jnp.where(jnp.any(past, axis=1), n, 0), dtype=jnp.int32)
+        for n in (tiles.sum(axis=1), named)])
+    return (out[..., :d].reshape(b, t, g * d), kv, (k_pad, v_pad), fresh,
+            jnp.concatenate([none, read]))
 
 
 def land_sparse(kv: KVPages, kc_pool, staged, fresh, tables, positions,
@@ -653,8 +641,9 @@ def land_sparse(kv: KVPages, kc_pool, staged, fresh, tables, positions,
 def sparse_mixer(x, lp, cfg: MiniCPMSALAConfig, kv, kc_pool, layer, groups,
                  rows):
     """Returns (out shaped like x, kv, per group the staged (k, v) and
-    the fresh compressed keys, `pages_walked` of the decode rows). `rows`
-    is `virtual_rows` of each group.
+    the fresh compressed keys, what the groups read of the cache: the
+    int32 [4] of `sparse_attention`, summed). `rows` is `virtual_rows` of
+    each group.
     Scopes, under the caller's `attn`: `qkv`, `select`, `paged`, `flash`,
     `kv_update`, `out`."""
     f32, dtype, eps = jnp.float32, cfg.dtype, cfg.rms_norm_eps
@@ -665,7 +654,7 @@ def sparse_mixer(x, lp, cfg: MiniCPMSALAConfig, kv, kc_pool, layer, groups,
         k = rms_norm(_heads(x, lp, "wk", hkv, d, dtype), lp["k_norm"], eps)
         v = _heads(x, lp, "wv", hkv, d, dtype)
         z = _mm(x, lp, "wz", dtype)
-    attns, staged, walked = [], [], jnp.zeros((2,), jnp.int32)
+    attns, staged, walked = [], [], jnp.zeros((4,), jnp.int32)
     for g, (tables, positions, valid), qg, kg, vg in zip(
         groups, rows, *(split_rows(a, groups) for a in (q, k, v))
     ):

@@ -72,10 +72,11 @@ class ModelAdapter:
     #: bytes of one sequence's state, one generation (state_layers > 0)
     state_slot_bytes: int = 0
     #: a model whose decode walk reads a chosen part of a row's pages:
-    #: cache -> the device's running count, int32 [2], of (pages the lists
-    #: given to the walks named, pages those rows held); the engine reads
-    #: it beside each dispatch's ids (`EngineMetrics.walk_pages_named` /
-    #: `walk_pages_live`)
+    #: cache -> the device's running count, int32 [4], of (pages the lists
+    #: given to the walks named, pages those rows held, pages its sparse
+    #: prompt chunks' tiles read, pages their queries named); the engine
+    #: reads it beside each dispatch's ids (`EngineMetrics.walk_pages_named`
+    #: / `walk_pages_live` / `chunk_pages_read` / `chunk_pages_named`)
     walk_pages: Optional[Callable] = None
     #: False for a model whose step programs are dear to load: the engine
     #: then keeps ONE prefill-carrying program a shape where it would keep

@@ -24,7 +24,9 @@ table `tables[b] * Hkv + h` may name ANY of its pages in any number. The
 decode walk (ops/paged_attention.py) and the staged cache write
 (ops/kv_update.py) take such rows as they take any other: a selected list
 is a short page table whose pages are all full but the last
-(`decode_lists`). A prompt chunk attends through `masked_attention` here.
+(`decode_lists`). A prompt chunk takes its selection as a mask: by tile of
+queries over the union of their pages in ops/sparse_chunk.py, and off the
+TPU as dense scores under it, `masked_attention` here.
 
 Beside a page live its `cpb` compressed keys, in a pool [L, P * Hkv * cpb,
 D] (row `(p * Hkv + h) * cpb + j % cpb` for the window `j` that STARTS in
@@ -243,7 +245,7 @@ def decode_lists(selected, tables, hist, dims: SparseDims):
 
 
 # ---------------------------------------------------------------------------
-# Attention under a block mask (a prompt chunk; every step off the TPU)
+# Attention under a block mask (every step off the TPU)
 # ---------------------------------------------------------------------------
 
 
@@ -328,48 +330,3 @@ def masked_attention(
         carry = fold(carry, sc, keep, v_cur)
     _, l, acc = carry
     return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
-
-
-def history_lists(selected, tables, start, dims: SparseDims):
-    """A chunk's selection as page lists over its HISTORY, a list a query:
-    (pages [B * T, K], lens [B * T]). A chunk starts on a page (the
-    scheduler's invariant), so the blocks before `start[b] // S` are
-    whole pages of cached tokens and the rest is the chunk's own.
-    selected [B, T, NB], tables [B, MP], start [B]."""
-    b, t, nb = selected.shape
-    s = dims.block_size
-    cached = jnp.arange(nb, dtype=jnp.int32)[None] < (start // s)[:, None]
-    pages, count = _page_lists(
-        (selected & cached[:, None, :]).reshape(b * t, nb),
-        jnp.repeat(tables, t, axis=0), dims.list_pages)
-    return pages, count * s
-
-
-def chunk_part(q, k_cur, v_cur, q_pos, cur_pos, selected, dims: SparseDims,
-               scale: float):
-    """A chunk's queries over the chunk's OWN keys (not cached yet),
-    under the causal and the block mask, as an unnormalised softmax:
-    (acc [B, T, G, D] f32, m [B, T, G], l [B, T, G]) for `merge_parts`."""
-    f32 = jnp.float32
-    b, t, g, d = q.shape
-    blk = jnp.clip(cur_pos // dims.block_size, 0, selected.shape[-1] - 1)
-    keep = jnp.take_along_axis(
-        selected, jnp.broadcast_to(blk[:, None, :], (b, t, t)), axis=-1)
-    keep &= cur_pos[:, None, :] <= q_pos[..., None]
-    sc = jnp.einsum("btgd,bkd->btgk", q, k_cur, preferred_element_type=f32)
-    sc = jnp.where(keep[:, :, None], sc * scale, -1e30)
-    m = sc.max(axis=-1)
-    p = jnp.where(keep[:, :, None], jnp.exp(sc - m[..., None]), 0.0)
-    acc = jnp.einsum("btgk,bkd->btgd", p.astype(v_cur.dtype), v_cur,
-                     preferred_element_type=f32)
-    return acc, m, p.sum(axis=-1)
-
-
-def merge_parts(a, b_):
-    """Two unnormalised softmax parts (acc, m, l) over disjoint keys as
-    one normalised output, float32."""
-    (acc1, m1, l1), (acc2, m2, l2) = a, b_
-    m = jnp.maximum(m1, m2)
-    w1, w2 = jnp.exp(m1 - m), jnp.exp(m2 - m)
-    den = jnp.maximum(w1 * l1 + w2 * l2, 1e-30)
-    return (w1[..., None] * acc1 + w2[..., None] * acc2) / den[..., None]

@@ -22,7 +22,8 @@ record into a bounded deque. A record holds, as `record_step` writes it:
   launch-ahead pipeline (`overlap_hits`, `overlap_rollbacks`), the
   recurrent-state plane (`state_resets`, `state_restores`,
   `prefix_refused_state`), a selecting walk (`walk_pages_named`,
-  `walk_pages_live`), speculation (`spec_drafted`,
+  `walk_pages_live`) and its sparse prompt chunks (`chunk_pages_read`,
+  `chunk_pages_named`), speculation (`spec_drafted`,
   `spec_accepted`), `compiles` / `compile_ms`, `preempted`, `tokens`,
   and the dry clock's counters (`dry_ms`, `dry_slack_ms`, `dry_wait_ms`,
   `dry_<phase>_ms`, `dry_launches`, `launches`);
@@ -96,6 +97,9 @@ _DELTA_FIELDS = (
     # a model whose decode walk reads a chosen part of a row's pages
     ("walk_pages_named", "walk_pages_named"),
     ("walk_pages_live", "walk_pages_live"),
+    # and whose sparse prompt chunks read a page once a tile of queries
+    ("chunk_pages_read", "chunk_pages_read"),
+    ("chunk_pages_named", "chunk_pages_named"),
     # speculative decoding (ngram or draft model): drafted/accepted per
     # step — a record with tokens but no spec_drafted is a plain step
     ("spec_drafted", "spec_drafted"),

@@ -1,11 +1,17 @@
 """Where a mixed step's device time goes, from the newest trace a
 `--trace 1` benchmark run left under this checkout's run directory
 (`.chipbench_run/trace/`): device self time per named scope inside
-`jit_mixed_fn` and `jit_multi_fn`, ms a dispatch, the operations
-under `attn/flash` by name, and `latent_flash_ms_per_mixed_step` (ISSUE
-39's reading: the prefill chunk's attention, scope `attn/flash` inside
-`jit_mixed_fn`, ms a mixed step; for a latent model the kernel
-`latent_prefill_attention`, floor 1.25 ms at `docgen`'s mean history).
+`jit_mixed_fn` and `jit_multi_fn`, ms a dispatch, each operation under
+the DEEPEST scope one of the benchmark's readers names (`mlp/moe/*` and
+`attn/absorb` of chipbench/subscopes.py, `attn/ssm/*` of ssmscopes.py,
+`attn/select` of sparsescopes.py; `attn` alone is then what none of them
+names: a state model's mixers, a selecting model's selection), the
+operations under `attn/flash` by name, and
+`latent_flash_ms_per_mixed_step` (ISSUE 39's reading: the prefill chunk's
+attention, scope `attn/flash` inside `jit_mixed_fn`, ms a mixed step; for
+a latent model the kernel `latent_prefill_attention`, floor 1.25 ms at
+`docgen`'s mean history; for MiniCPM-SALA the kernel
+`sparse_chunk_attention` and the tile lists it is handed).
 Reads files only (run it after the benchmark's process has gone;
 `JAX_PLATFORMS=cpu` keeps it off the chip).
 
@@ -33,8 +39,23 @@ def flash_ms_per_mixed_step(loaded: dict) -> float | None:
     return 1e3 * per_scope["attn/flash"] / per_scope["_count"]
 
 
+def load_deepest(path: str) -> dict:
+    """`hostspans.load`'s dict with each device operation under the
+    longest scope any of the three deep readers gives it (they sort one
+    trace's operations alike, so their lists run in step)."""
+    from chipbench import sparsescopes, ssmscopes, subscopes
+
+    loads = [m.load_deep(path) for m in (subscopes, ssmscopes, sparsescopes)]
+    devices = {
+        plane: {"modules": dev["modules"], "ops": [
+            max(same, key=lambda op: len(op[3])) for same in zip(
+                *(one["devices"][plane]["ops"] for one in loads))]}
+        for plane, dev in loads[0]["devices"].items()}
+    return {"spans": loads[0]["spans"], "devices": devices}
+
+
 def main(argv=None) -> int:
-    from chipbench import hostspans, subscopes
+    from chipbench import hostspans
 
     argv = sys.argv[1:] if argv is None else argv
     path = argv[0] if argv else hostspans.newest_xplane()
@@ -42,7 +63,7 @@ def main(argv=None) -> int:
         print("mixed_step_breakdown: no trace under the run directory",
               file=sys.stderr)
         return 2
-    loaded = subscopes.load_deep(path)
+    loaded = load_deepest(path)
     for module in ("jit_mixed_fn", "jit_multi_fn"):
         per_scope = hostspans.scope_self_s(loaded, module) or {}
         n = per_scope.get("_count") or 1

@@ -165,7 +165,12 @@ def main(argv=None) -> int:
         "state_slots_live": m["state_slots_live"],
         "mixed_dispatches": m["mixed_dispatches"],
         "decode_dispatches": m["decode_dispatches"],
-        "preemptions": m["preemptions"]}), flush=True)
+        "preemptions": m["preemptions"],
+        # a selecting model's counts of what its walks and its sparse
+        # chunks' tiles read (0 elsewhere), every row of the run
+        **{k: m.get(k, 0) for k in (
+            "walk_pages_named", "walk_pages_live", "chunk_pages_read",
+            "chunk_pages_named")}}), flush=True)
     params = eng.params
     eng.kv = None
     gc.collect()

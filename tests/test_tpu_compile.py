@@ -109,6 +109,14 @@ def _mosaic_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _kernel_calls(text: str, name: str) -> int:
+    """Calls of the Pallas kernel `name` in an optimized program's text:
+    a custom call is named after its kernel."""
+    return len(re.findall(
+        rf"%{name}(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text))
+
+
 def _compile_decode(adapter, kv, chip, b=8):
     cfg = adapter.config
     d = cfg.kv_head_dim
@@ -687,7 +695,15 @@ def test_minicpm_sala_step_compiles_at_published_widths(
     fits the chip beside 10.08 GB of weights, 1.86 GB of state and 2.43 GB
     of pages. The temporaries stay small: without the barrier in
     `models/minicpm_sala._heads` the compiler transposed q, k and v of
-    every layer into 1.38 GB of copies ahead of the layer loops."""
+    every layer into 1.38 GB of copies ahead of the layer loops. A prompt
+    chunk attends by tile of queries in a kernel of its own (PR 42,
+    ops/sparse_chunk.py), dense or past `dense_len`: the decode kernel
+    stays the decode rows' alone (on PR 41's tree a sparse chunk's 1,024
+    queries walked a list each through a second one, and a dense chunk
+    ran the latent kernel), and the program's temporaries are no larger
+    than that tree's (186,084,864 bytes beside one piece, 833,350,144
+    beside four: the [rows, 64]-page lists and 33 MB of XLA scores a piece
+    went)."""
     adapter = get_model("minicpm-sala-9b-16l", dtype="bfloat16",
                         attention_impl="pallas")
     chip = SingleDeviceSharding(topo.devices[0])
@@ -740,15 +756,19 @@ def test_minicpm_sala_step_compiles_at_published_widths(
     pools = sum(np.prod(x.shape) * x.dtype.itemsize
                 for x in (kv.k, kv.v, kv.kc, kv.ssm))
     assert mem.alias_size_in_bytes >= pools  # every pool in place
-    assert mem.temp_size_in_bytes < 1.0e9  # 0.03 / 0.23 / 0.88 GB
+    assert mem.temp_size_in_bytes <= {0: 1.0e9, 1: 186_084_864,
+                                      4: 833_350_144}[b_pre]
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
     text = compiled.as_text()
     assert "ssm_decode_step" in text and "paged_decode_attention" in text
     if t > 1:
         assert "state_read_rows" in text and "state_write_rows" in text
+        assert _kernel_calls(text, "sparse_chunk_attention") == 1
+        assert _kernel_calls(text, "latent_prefill_attention") == 0
+    assert _kernel_calls(text, "paged_decode_attention") == 1
     # one body a kind: the walk, the page writer and the state kernel of a
-    # decode step; a chunk adds its own walk, its kernel and its state rows
-    assert _mosaic_calls(compiled) >= (7 if b_pre else 3)
+    # decode step; a chunk adds its kernel and its state rows
+    assert _mosaic_calls(compiled) >= (6 if b_pre else 3)
 
 
 @pytest.mark.parametrize("rows,vocab", [
