@@ -565,6 +565,7 @@ class JaxEngine:
         )
         if self._stateful:
             self._refuse_for_state(config)
+        self._refuse_for_adapter(config)
         # the device's running count of what its walks and chunk tiles
         # read: a copy taken behind each dispatch (the cache itself is
         # donated to the next one), read where that dispatch's ids are
@@ -888,10 +889,31 @@ class JaxEngine:
                "a rejected draft token has already advanced the state; "
                "run without speculation")
 
+    def _refuse_for_adapter(self, config: EngineConfig) -> None:
+        """What the model's adapter says it cannot serve
+        (`ModelAdapter.refuses`): refused at start-up, with its reason."""
+        asked = {
+            "kv_tiers": (config.host_kv_cache_bytes > 0
+                         or config.disk_kv_cache_bytes > 0),
+            "speculation": (config.spec_ngram > 0
+                            or config.spec_draft_model is not None),
+        }
+        for what, why in self.adapter.refuses:
+            if asked.get(what):
+                raise ValueError(
+                    f"{config.model}: {what} is not supported for it ({why})"
+                )
+
     def _refuse_state_transfer(self, what: str) -> None:
         """Guard of the page-movement surface (disagg transfer planes,
         handover, tier promotion): pages of a stateful model never travel
-        without their state."""
+        without their state, nor a page without all its residents."""
+        for refused, why in self.adapter.refuses:
+            if refused == "page_transfer":
+                raise ValueError(
+                    f"{self.config.model}: {what} is not supported for it "
+                    f"({why})"
+                )
         if self._stateful:
             raise ValueError(
                 f"{self.config.model} has state-space layers: {what} would "

@@ -84,6 +84,12 @@ class ModelAdapter:
     #: (`first_chunk`) and one that samples nothing for chunks none of
     #: which ends one (every chunk then samples, the token unread)
     step_twins: bool = True
+    #: what this family cannot serve, (feature, why) pairs the engine
+    #: refuses with the sentence: "kv_tiers" (KVBM offload),
+    #: "speculation", "page_transfer" (disaggregated prefill, handover). A
+    #: family whose page holds more than K and V rows names all three:
+    #: what moves or rewinds K and V alone would leave the rest behind
+    refuses: tuple = ()
 
 
 def _kv_pages_spec(kv_quantize=None, shard_heads: bool = True):
@@ -464,6 +470,87 @@ def _minicpm_sala_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
                            sala.minicpm_sala_logical_axes, mesh)
 
 
+def _keye_vl_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
+    """Keye-VL's language model (models/keye_vl.py): pages that hold K, V
+    and the indexer's keys in one `KeyeCache`, no state slot: a prefix hit
+    shares all three with the page and is accepted."""
+    from dynamo_tpu.models import keye_vl as kv_mod
+
+    if mesh is not None:
+        raise ValueError(
+            f"{name}: Keye-VL runs on one chip (its layers over a mesh are "
+            "not implemented): use tp=dp=ep=sp=1"
+        )
+
+    def fwd_hidden(params, tokens, positions, valid, kv, pt, **kw):
+        if kw.pop("mm_embeds", None) is not None:
+            raise ValueError(
+                "multimodal prompts are not supported for Keye-VL: the "
+                "vision tower is not built (its sizes are not published in "
+                "the language model's config)")
+        kw.pop("mm_mask", None)
+        return kv_mod.forward_hidden(
+            params, cfg, tokens, positions, valid, kv, pt, **kw)
+
+    def fwd(params, tokens, positions, valid, kv, pt):
+        h, kv = fwd_hidden(params, tokens, positions, valid, kv, pt)
+        return kv_mod.compute_logits(params, cfg, h), kv
+
+    def init_kv(num_pages, page_size, kv_quantize=None):
+        if kv_quantize:
+            raise ValueError(
+                "kv_quantize is not supported for Keye-VL: the indexer "
+                "scores every cached token against index keys kept in "
+                "bfloat16 beside the pages, and narrowing K and V alone has "
+                "no tested path beside them; run with kv_quantize=None"
+            )
+        return kv_mod.init_cache(cfg, num_pages, page_size)
+
+    def no_mesh_specs(*_a, **_k):
+        from dynamo_tpu.parallel.logical import resolve
+
+        return resolve(kv_mod.keye_vl_logical_axes(cfg))
+
+    why = ("a page of this family holds the indexer's keys beside its K "
+           "and V rows, and this would move or rewind K and V alone")
+    return ModelAdapter(
+        name=name,
+        config=cfg,
+        vocab_size=cfg.vocab_size,
+        init_params=lambda key: kv_mod.init_params(key, cfg),
+        forward=fwd,
+        forward_hidden=fwd_hidden,
+        forward_hidden_mixed=_one_pass_mixed(
+            kv_mod.forward_groups, cfg, mesh),
+        compute_logits=lambda params, h: kv_mod.compute_logits(
+            params, cfg, h),
+        init_kv=init_kv,
+        param_specs=no_mesh_specs,
+        kv_spec=lambda kv_quantize=None: None,
+        logical_axes=lambda quantized=False: kv_mod.keye_vl_logical_axes(
+            cfg),
+        walk_pages=kv_mod.walk_count,
+        step_twins=kv_mod.STEP_TWINS,
+        refuses=tuple((what, why) for what in (
+            "kv_tiers", "speculation", "page_transfer")),
+    )
+
+
+def _keye_vl_presets() -> dict:
+    from dynamo_tpu.models.keye_vl import KeyeVLConfig
+
+    return {
+        # the language model of Keye-VL-2.0-30B-A3B as published: 48
+        # layers, 128 experts (61 GB in bf16: shape tests and a later
+        # multi-chip issue)
+        "keye-vl2-30b-a3b": KeyeVLConfig.keye_vl2_30b_a3b,
+        # one chip of its deployment: a pipeline stage of 8 layers, 16 of
+        # the 128 experts (chipbench/configs/keye-vl2-30b-a3b-1chip.json)
+        "keye-vl2-30b-a3b-8l-16e": KeyeVLConfig.keye_vl2_1chip,
+        "keye-vl2-tiny": KeyeVLConfig.tiny,
+    }
+
+
 def _minicpm_sala_presets() -> dict:
     from dynamo_tpu.models.minicpm_sala import MiniCPMSALAConfig
 
@@ -534,11 +621,14 @@ def _mla_presets() -> dict:
     }
 
 
-#: the families that keep a recurrent state a sequence beside its pages
+#: the families whose cache is more than K and V pages, each with an
+#: adapter of its own: a recurrent state a sequence beside its pages, or
+#: (Keye-VL) a third resident of the page itself
 _STATE_FAMILIES = (
     (_nemotron_h_presets, _nemotron_h_adapter),
     (_falcon_h1_presets, _falcon_h1_adapter),
     (_minicpm_sala_presets, _minicpm_sala_adapter),
+    (_keye_vl_presets, _keye_vl_adapter),
 )
 
 
@@ -549,7 +639,7 @@ def list_presets() -> list[str]:
     return sorted(_LLAMA_PRESETS) + sorted(_moe_presets()) + sorted(
         _mla_presets()
     ) + sorted(_nemotron_h_presets()) + sorted(_falcon_h1_presets()) + sorted(
-        _minicpm_sala_presets())
+        _minicpm_sala_presets()) + sorted(_keye_vl_presets())
 
 
 def get_model(
@@ -588,7 +678,7 @@ def get_model(
     elif key in mla_presets:
         mla_cfg = mla_presets[key]()
     elif any(key in presets() for presets, _ in _STATE_FAMILIES):
-        # a family with state-space layers: an adapter of its own
+        # a family with an adapter of its own
         presets, adapter = next(
             (presets(), adapter) for presets, adapter in _STATE_FAMILIES
             if key in presets()

@@ -162,6 +162,8 @@ def _decode_kernel(
     #   k_ref,  # [L, P, S*Hkv, D] in HBM/ANY (narrow dtype when quantized)
     #   v_ref,
     #   [ks_ref, vs_ref]  # [L, P, Hkv, S'] f32 scale planes (quantized)
+    #   [bits_ref]  # [B, blocks * N] int32 VMEM (`token_bits`): which key
+    #               # columns of each row's blocks the row attends
     # outputs (whole batch resident in VMEM; a row is written once):
     #   acc_ref,  # [B, HQP, D] f32 — UNNORMALIZED flash accumulator
     #   ml_ref,  # [B, HQP, 128] f32 — lane 0 running max, the rest the
@@ -180,8 +182,14 @@ def _decode_kernel(
     block_pages: int,
     quantized: bool,
     latent: bool,
+    token_bits: bool = False,
 ):
-    if quantized:
+    bits_ref = None
+    if token_bits:
+        (q_ref, k_ref, v_ref, bits_ref, acc_ref, ml_ref,
+         k_scr, v_scr, sem) = refs
+        ks_ref = vs_ref = ks_scr = vs_scr = None
+    elif quantized:
         (q_ref, k_ref, v_ref, ks_ref, vs_ref, acc_ref, ml_ref,
          k_scr, v_scr, ks_scr, vs_scr, sem) = refs
     else:
@@ -340,6 +348,13 @@ def _decode_kernel(
             if quantized:
                 scores = scores * column_scales(ks_scr[slot])
             scores = jnp.where(limit < hist - kb * (pb * s), scores, _MASKED)
+            if token_bits:
+                # a row that attends CHOSEN tokens (ops/token_select.py):
+                # a block with none of them leaves sums of no meaning
+                # that the first chosen key's correction wipes
+                at = pl.multiple_of(kb * n, n)
+                keep = bits_ref[pl.ds(b, 1), pl.ds(at, n)]
+                scores = jnp.where(keep != 0, scores, _MASKED)
             m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
             p = jnp.exp(scores - m_new)
             corr = jnp.exp(m - m_new)
@@ -419,6 +434,7 @@ def paged_decode_attention(
     k_scale: jax.Array | None = None,  # [L, P, Hkv, S'] f32 (quantized pools)
     v_scale: jax.Array | None = None,
     vmem_budget: int | None = None,  # the caller's, as in decode_vmem_bytes
+    token_bits: jax.Array | None = None,  # [B, MP * S] bool: keys attended
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """History-only flash attention over the paged cache.
 
@@ -438,11 +454,18 @@ def paged_decode_attention(
     and used for both the C+R-wide score dots and the C-wide value sum;
     acc comes back [B, Hq, C]. Same work list, same block rule.
 
+    `token_bits` walks the same pages and attends only the cached tokens
+    it names (a bit a (row, position); models/keye_vl.py, whose indexer
+    chooses them): resident in VMEM as one int32 a key column.
+
     `interpret` defaults to True off-TPU so tests run the same kernel on CPU.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     quantized = k_scale is not None
+    if token_bits is not None and (quantized or latent or (
+            mesh is not None and mesh.shape.get("tp", 1) > 1)):
+        raise ValueError("token_bits: an unquantized GQA cache on one chip")
     hkv, s = k_cache.shape[3], k_cache.shape[2]
     if work_list is None:
         work_list = decode_work_list(page_tables, history_lens)
@@ -538,6 +561,16 @@ def paged_decode_attention(
             pltpu.VMEM(scale_slot, jnp.float32),
         ]
         operands += [k_scale, v_scale]
+    extra = {}
+    if token_bits is not None:
+        # a column a (position, kv head), padded to whole blocks
+        cols = -(-mp // pb) * pb * s
+        bits = jnp.pad(token_bits.astype(jnp.int32),
+                       ((0, 0), (0, cols - token_bits.shape[1])))
+        operands.append(jnp.repeat(bits, hkv, axis=1))
+        in_specs.append(whole(b, cols * hkv))
+        extra = dict(compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024))
     scratch_shapes.append(
         pltpu.SemaphoreType.DMA((4 if quantized else 2, _DEPTH))
     )
@@ -562,6 +595,7 @@ def paged_decode_attention(
             block_pages=pb,
             quantized=quantized,
             latent=latent,
+            token_bits=token_bits is not None,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b, hqp, d), jnp.float32),
@@ -570,6 +604,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         interpret=interpret,
         name="paged_decode_attention",
+        **extra,
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         n_rows,

@@ -370,3 +370,266 @@ def sparse_chunk_attention(
         v_cache.reshape(*v_cache.shape[:3], d),
     )
     return out[:, :, :t].transpose(0, 2, 1, 3), (counts, named)
+
+
+# ---------------------------------------------------------------------------
+# A mask bit a (query, KEY): token selection (ops/token_select.py)
+# ---------------------------------------------------------------------------
+
+
+def _token_kernel(
+    # scalar prefetch
+    layer_ref,  # [1] int32
+    pt_ref,  # [B, MPP] int32: the rows' page tables, padded to whole turns
+    npages_ref,  # [B] int32: cached pages before the chunk
+    cur_ref,  # [B] int32: valid tokens in THIS chunk
+    # inputs
+    q_ref,  # [1, Hq, BQ, D] VMEM: the tile's queries, scaled
+    mh_ref,  # [1, BQ, MPP * S] VMEM int8: query x cached key
+    mo_ref,  # [1, BQ, TP] VMEM int8: query x chunk key (causal inside)
+    kcur_ref,  # [1, TP, Hkv, D] VMEM: this chunk's keys
+    vcur_ref,  # [1, TP, Hkv, D]
+    k_hbm,  # [L, P, S, Hkv, D] ANY: the K pool as the cache lays it out
+    v_hbm,  # [L, P, S, Hkv, D]
+    # output
+    o_ref,  # [1, Hq, BQ, D]
+    # scratch
+    k_scr,  # [2, PB * S, Hkv, D] VMEM: a slot is a turn's pages
+    v_scr,
+    kh_scr,  # [Hkv, max(PB * S, cur), D] VMEM: a turn's keys by head
+    vh_scr,
+    m_scr,  # [Hkv, G * BQ, 128] f32 running max (every lane the same)
+    l_scr,  # [Hkv, G * BQ, 128] f32 running denominator
+    acc_scr,  # [Hkv, G * BQ, D] f32
+    sem,  # [2, 2] DMA semaphores: [plane, slot]
+    *,
+    page_size: int,
+    block_pages: int,
+    block_cur: int,
+    kv_heads: int,
+):
+    b = pl.program_id(0)
+    qi = pl.program_id(1)
+    li = layer_ref[0]
+    hq, bq, d = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
+    s, pb, hkv = page_size, block_pages, kv_heads
+    g = hq // hkv
+    rows = g * bq
+    cur = cur_ref[b]
+    n_blk = pl.cdiv(npages_ref[b], pb)
+    mxu = k_scr.dtype
+
+    def copies(slot, blk):
+        """A turn's pages, one DMA a page and plane; entries past the
+        row's cached pages name whatever the table holds there (the
+        chunk's own pages, the null page): no query's bit is set."""
+        out = []
+        for p in range(pb):
+            page = pt_ref[b, blk * pb + p]
+            for pi, (src, dst) in enumerate(
+                ((k_hbm, k_scr), (v_hbm, v_scr))
+            ):
+                out.append(pltpu.make_async_copy(
+                    src.at[li, page],
+                    dst.at[slot, pl.ds(p * s, s)],
+                    sem.at[pi, slot],
+                ))
+        return out
+
+    @pl.when(n_blk > 0)
+    def _():
+        for cp in copies(0, 0):
+            cp.start()
+
+    m_scr[...] = jnp.full(m_scr.shape, _MASKED, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    def fold(keys: int, keep):
+        """One turn of every KV head's online softmax over the first
+        `keys` rows of `kh_scr` / `vh_scr` [Hkv, K, D] under `keep` [BQ,
+        keys]: ONE set a query token, the same for all its heads. A loop
+        over the heads, so that a turn's body is compiled once (the four
+        heads unrolled in each of three turns took the program's compile
+        from 18 to 35 s, and a cold run's requests past their clients'
+        120 s without a byte: PERF.md 6, PR 43)."""
+        def head(h, _):
+            q = q_ref[0, pl.ds(h * g, g)].reshape(rows, d)
+            sc = jax.lax.dot_general(
+                q, kh_scr[h, :keys], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [G * BQ, K]
+            sc = jnp.where(
+                keep[None], sc.reshape(g, bq, -1), _MASKED).reshape(rows, -1)
+            m_old = m_scr[h, :, :1]
+            m_new = jnp.maximum(m_old, jnp.max(sc, axis=1, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            corr = jnp.exp(m_old - m_new)
+            l_new = corr * l_scr[h, :, :1] + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = corr * acc_scr[h] + jax.lax.dot_general(
+                p.astype(mxu), vh_scr[h, :keys], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+            return 0
+
+        jax.lax.fori_loop(0, hkv, head, 0)
+
+    # -- the chunk over itself: key blocks wholly above the tile skipped ----
+    def own(j, _):
+        start = j * block_cur
+        if not isinstance(j, int):  # a traced turn: whole lane tiles
+            start = pl.multiple_of(start, block_cur)
+        at = pl.ds(start, block_cur)
+        key = j * block_cur + jax.lax.broadcasted_iota(
+            jnp.int32, (block_cur, 1), 0
+        )
+        for h in range(hkv):  # a head's rows, a head a plane
+            kh_scr[h, :block_cur] = kcur_ref[0, at, h, :].astype(mxu)
+            # rows past `cur` may hold anything: zeros as values
+            vh_scr[h, :block_cur] = jnp.where(
+                key < cur, vcur_ref[0, at, h, :], 0).astype(mxu)
+        fold(block_cur, mo_ref[0, :, at].astype(jnp.int32) != 0)
+        return 0
+
+    if kcur_ref.shape[1] == block_cur:  # one turn, whatever its width
+        own(0, 0)
+    else:  # several: `block_cur` is whole pages and whole lane tiles
+        jax.lax.fori_loop(0, pl.cdiv((qi + 1) * bq, block_cur), own, 0)
+
+    # -- the cached pages, `pb` a turn ----------------------------------------
+    def body(i, _):
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_blk)
+        def _():
+            for cp in copies(1 - slot, i + 1):
+                cp.start()
+
+        for cp in copies(slot, i):
+            cp.wait()
+        at = pl.ds(pl.multiple_of(i * (pb * s), pb * s), pb * s)
+        # a head's rows out of the pages' [K, Hkv, D] through float32, as
+        # `paged_prefill_attention` takes them
+        kp = k_scr[slot].astype(jnp.float32)
+        vp = v_scr[slot].astype(jnp.float32)
+        for h in range(hkv):
+            kh_scr[h, :pb * s] = kp[:, h].astype(mxu)
+            vh_scr[h, :pb * s] = vp[:, h].astype(mxu)
+        fold(pb * s, mh_ref[0, :, at].astype(jnp.int32) != 0)
+        return 0
+
+    jax.lax.fori_loop(0, n_blk, body, 0)
+    for h in range(hkv):
+        inv = 1.0 / jnp.maximum(l_scr[h, :, :1], 1e-30)
+        o_ref[0, h * g:(h + 1) * g] = (acc_scr[h] * inv).astype(
+            o_ref.dtype).reshape(g, bq, d)
+
+
+def token_chunk_attention(
+    q: jax.Array,  # [B, T, Hq, D] SCALED, model dtype
+    k_cur: jax.Array,  # [B, T, Hkv, D] this chunk's keys
+    v_cur: jax.Array,  # [B, T, Hkv, D]
+    k_cache: jax.Array,  # [L, P, S, Hkv, D] the K pool (history)
+    v_cache: jax.Array,
+    layer: jax.Array,  # scalar int32
+    tables: jax.Array,  # [B, MP] int32
+    chosen: jax.Array,  # [B, T, MP * S] bool: each query's keys, by position
+    hist: jax.Array,  # [B] int32: tokens cached before the chunk, whole pages
+    valid: jax.Array,  # [B, T] bool, a prefix of each row
+    *,
+    interpret: bool | None = None,
+):
+    """`softmax(q . K) . V` of each chunk query over the KEYS `chosen`
+    names for it (at positions up to its own): the cached ones from the
+    pools' pages, every cached page of the row read once a tile of
+    `CHUNK_BLOCK_Q` queries and `CHUNK_BLOCK_PAGES` a turn, the chunk's
+    own from `k_cur` / `v_cur` (not cached yet). One set a query token: the mask is shared by a tile's KV heads,
+    each an online softmax of its own in the same grid cell.
+
+    Returns [B, T, Hq, D] in the queries' dtype, rows past a row's valid
+    prefix unspecified."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, t, hq, d = q.shape
+    s, hkv = k_cache.shape[2], k_cache.shape[3]
+    mp = tables.shape[1]
+    if k_cache.shape[-1] != d or (k_cur.shape, v_cur.shape) != (
+            (b, t, hkv, d),) * 2 or chosen.shape != (b, t, mp * s):
+        raise ValueError(
+            f"pools {k_cache.shape}, rows {k_cur.shape} / {v_cur.shape}, "
+            f"q {q.shape}, chosen {chosen.shape}"
+        )
+    bq, tp, pb, cur = chunk_blocking(t, s, mp)
+    mpp = _round_up(mp, pb)
+    i8 = jnp.int8
+    pos = jnp.arange(mp * s, dtype=jnp.int32)[None, None]
+    live = chosen & valid[..., None]
+    m_hist = (live & (pos < hist[:, None, None])).astype(i8)
+    # the chunk's own keys: columns `hist` .. `hist + T` of the mask
+    own = jax.vmap(lambda m, h: jax.lax.dynamic_slice_in_dim(
+        jnp.pad(m, ((0, 0), (0, t))), h, t, axis=1))(live, hist)
+    m_own = own.astype(i8)
+    pad_t = ((0, 0), (0, tp - t))
+    m_hist = jnp.pad(m_hist, pad_t + ((0, (mpp - mp) * s),))
+    m_own = jnp.pad(m_own, pad_t + ((0, tp - t),))
+    if tp != t:
+        q, k_cur, v_cur = (
+            jnp.pad(x, pad_t + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k_cur, v_cur)
+        )
+    nt = tp // bq
+    g = hq // hkv
+
+    def q_block():
+        return pl.BlockSpec(
+            (1, hq, bq, d), lambda bi, qi, *_: (bi, 0, qi, 0))
+
+    def chunk_block():
+        return pl.BlockSpec(
+            (1, tp, hkv, d), lambda bi, qi, *_: (bi, 0, 0, 0))
+
+    out = pl.pallas_call(
+        functools.partial(
+            _token_kernel, page_size=s, block_pages=pb, block_cur=cur,
+            kv_heads=hkv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b, nt),
+            in_specs=[
+                q_block(),
+                pl.BlockSpec((1, bq, mpp * s),
+                             lambda bi, qi, *_: (bi, qi, 0)),
+                pl.BlockSpec((1, bq, tp), lambda bi, qi, *_: (bi, qi, 0)),
+                chunk_block(), chunk_block(),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=q_block(),
+            scratch_shapes=[
+                pltpu.VMEM((2, pb * s, hkv, d), k_cache.dtype),
+                pltpu.VMEM((2, pb * s, hkv, d), v_cache.dtype),
+                pltpu.VMEM((hkv, max(pb * s, cur), d), k_cache.dtype),
+                pltpu.VMEM((hkv, max(pb * s, cur), d), v_cache.dtype),
+                pltpu.VMEM((hkv, g * bq, 128), jnp.float32),
+                pltpu.VMEM((hkv, g * bq, 128), jnp.float32),
+                pltpu.VMEM((hkv, g * bq, d), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hq, tp, d), q.dtype),
+        interpret=interpret,
+        name="token_chunk_attention",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=100 * 1024 * 1024
+        ),
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        jnp.pad(tables.astype(jnp.int32), ((0, 0), (0, mpp - mp))),
+        (hist // s).astype(jnp.int32),
+        jnp.sum(valid, axis=1).astype(jnp.int32),
+        q.transpose(0, 2, 1, 3),  # head-major: a tile's [Hq, BQ, D] block
+        m_hist, m_own, k_cur, v_cur, k_cache, v_cache,
+    )
+    return out[:, :, :t].transpose(0, 2, 1, 3)

@@ -771,6 +771,83 @@ def test_minicpm_sala_step_compiles_at_published_widths(
     assert _mosaic_calls(compiled) >= (6 if b_pre else 3)
 
 
+@pytest.mark.parametrize("rows,t,b_pre", [
+    pytest.param(32, 1, 0, id="decode-32-rows-fused-8"),
+    pytest.param(32, 512, 1, id="mixed-32-rows-beside-a-chunk"),
+    pytest.param(32, 32, 32, id="mixed-32-rows-beside-32-short-prompts"),
+])
+def test_keye_vl_step_compiles_at_published_widths(topo, rows, t, b_pre):
+    """Whole steps of `keye-vl2-30b-a3b-8l-16e` as `keye-longctx` serves
+    it (bf16, 9,000 pages of 64 with their index keys, --max-context
+    18432): the index keys gathered through the page tables and landed
+    as one scatter of rows with no copy of their pool, the scores and the
+    sort-free selection, the decode rows' page walk under a bit a token
+    (`token_bits`: 9.4 MB of int32 columns resident in VMEM), a prompt
+    chunk's token-mask kernel taking the pools as the cache lays them out
+    (ops/sparse_chunk.py `token_chunk_attention`), the
+    grouped matmuls over the 16 held experts reading their layer of the
+    stack in place, and the staged K and V landed once: every pool
+    updated in place, the program beside 2.79 GB of weights and 10.03 GB
+    of pages inside the chip."""
+    adapter = get_model("keye-vl2-30b-a3b-8l-16e", dtype="bfloat16",
+                        attention_impl="pallas")
+    chip = SingleDeviceSharding(topo.devices[0])
+    params = _on(chip, jax.eval_shape(
+        lambda: adapter.init_params(jax.random.key(0))))
+    kv = _on(chip, jax.eval_shape(lambda: adapter.init_kv(9000, PAGE)))
+    assert kv.ki.shape == (4, 9000, PAGE, 128)
+    mp = 18432 // PAGE
+
+    def rows_of(b, tt):
+        return (
+            _sds((b, tt), jnp.int32, chip), _sds((b, tt), jnp.int32, chip),
+            _sds((b, tt), jnp.bool_, chip), _sds((b, mp), jnp.int32, chip),
+        )
+
+    def head(params, hidden):
+        logits = adapter.compute_logits(params, hidden[:, -1])
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    if b_pre:
+
+        def program(params, kv, prompt, decode):
+            h_p, h_d, kv = adapter.forward_hidden_mixed(
+                params, prompt, decode, kv)
+            return head(params, h_d), kv
+
+        args = (rows_of(b_pre, t), rows_of(rows, 1))
+    else:
+
+        def program(params, kv, tokens, positions, valid, pt):
+            def body(carry, _):
+                tokens, positions, kv = carry
+                hidden, kv = adapter.forward_hidden(
+                    params, tokens, positions, valid, kv, pt)
+                ids = head(params, hidden)
+                return (ids[:, None], positions + 1, kv), ids
+
+            (_, _, kv), ids = jax.lax.scan(
+                body, (tokens, positions, kv), None, length=8)
+            return ids, kv
+
+        args = rows_of(rows, t)
+
+    compiled = jax.jit(program, donate_argnums=(1,)).lower(
+        params, kv, *args).compile()
+    mem = compiled.memory_analysis()
+    pools = sum(np.prod(x.shape) * x.dtype.itemsize
+                for x in (kv.k, kv.v, kv.ki))
+    assert mem.alias_size_in_bytes >= pools  # every pool in place
+    assert mem.temp_size_in_bytes <= 1.2e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    text = compiled.as_text()
+    # ONE body: a chunk's token-mask kernel, and the decode rows' page walk
+    # (under a bit a token: the gather of chosen rows is gone)
+    assert _kernel_calls(text, "token_chunk_attention") == (1 if b_pre else 0)
+    assert _kernel_calls(text, "paged_decode_attention") == 1
+    assert mem.temp_size_in_bytes <= (0.55e9 if b_pre else 0.4e9)
+
+
 @pytest.mark.parametrize("rows,vocab", [
     (64, 152_064),  # qwen2-longgen
     (32, 261_120),  # falconh1-longdoc
