@@ -29,10 +29,13 @@ attention (`sa_config`, DeepSeek-Sparse-Attention's lightning indexer).
 The cache (`KeyeCache`) is models/llama.py's K and V pools [L, P, S, Hkv,
 D] and beside them the INDEX KEYS, a row a token at its page's slot, TWO
 LAYERS' keys side by side in a row ([L / 2, P, S, 2 Di]: `index_pool`). A
-step scores one gathered copy of a row's keys with its own tokens' put in
-from what is in hand (`attn/index`), stages its index keys as it stages K
-and V, and lands every layer's once (`attn/kv_update`); the selection
-(`attn/select`) is exact and sort-free. A decode row walks its pages
+step scores a row's cached index keys out of that pool IN PLACE, streamed
+page by page through one kernel (`step_scores` over ops/index_scores.py), and
+its own tokens' from what is in hand (`attn/index`; without the kernels
+one gathered copy of the row's keys, `index_keys_of`, under
+`ts.index_scores`), stages its index keys as it stages K and V, and lands
+every layer's once (`attn/kv_update`); the selection (`attn/select`) is
+exact and sort-free. A decode row walks its pages
 under a bit a cached token (`attn/paged`, ops/paged_attention.py
 `token_bits`) and merges its own token; a prompt chunk attends by
 tile of queries under a mask bit a (query, key) (`attn/flash`,
@@ -397,31 +400,64 @@ def chunk_pairs(positions, valid, topk: int):
     ]).astype(jnp.int32)
 
 
-def chosen_keys(qi, w, ki, positions, valid, topk: int):
-    """bool [B, T, N]: the keys each query attends. qi [B, T, J, Di], w [B,
-    T, J] float32, ki [B, N, Di] the rows' index keys by position (the
-    step's own among them), positions / valid [B, T]. Scopes `index` (the
-    scores) and `select`; a step none of whose queries has more than
-    `topk` tokens of context computes neither."""
+def put_own(scores, own, at):
+    """One row's scores [T, N] by position with those of the chunk's own
+    keys `own` [T, T] put in from column `at` on (contiguous positions;
+    the pool does not hold them yet). Columns past `N` belong to padding
+    rows and fall away."""
+    n, t = scores.shape[1], own.shape[1]
+    start = jnp.clip(at, 0, n - t)  # what the update's clamp would do
+    shift = at - start
+    held = lax.dynamic_slice_in_dim(scores, start, t, axis=1)
+    col = jnp.arange(t, dtype=jnp.int32)[None]
+    return lax.dynamic_update_slice_in_dim(
+        scores, jnp.where(col >= shift, jnp.roll(own, shift, axis=1), held),
+        start, axis=1)
+
+
+def step_scores(qi, w, ki_new, tables, positions, valid, ki_pool, layer):
+    """The index scores of a group's queries by position, float32 [B, T,
+    MP * S], on the TPU: the cached keys scored out of the pool in place
+    (ops/index_scores.py), the step's own from `ki_new` and put in. What
+    `ts.index_scores(qi, w, index_keys_of(..))` gives up to every query's
+    own position; past it the two differ and nothing reads."""
+    from dynamo_tpu.ops.index_scores import paged_index_scores
+
+    hist = jnp.where(valid[:, 0], positions[:, 0], 0).astype(jnp.int32)
+    if qi.shape[1] == 1:  # a decode row: its own token's score, a scalar
+        sc = paged_index_scores(qi, w, ki_pool, layer, tables, hist)
+        own = ts.index_scores(qi, w, ki_new.astype(ki_pool.dtype))
+        at = jnp.arange(sc.shape[-1], dtype=jnp.int32)[None, None]
+        return jnp.where(at == positions[:, :, None], own, sc)
+    sc, own = paged_index_scores(
+        qi, w, ki_pool, layer, tables, hist, ki_new)
+    return jax.vmap(put_own)(sc, own, hist)
+
+
+def chosen_keys(score, rows, n: int, positions, valid, topk: int):
+    """bool [B, T, N]: the keys each query attends. `score(*rows)` gives
+    the float32 index scores [B, T, N] by position (the step's own keys
+    among them) of the queries whose arrays `rows` holds, a sequence
+    each; positions / valid [B, T]. Scopes `index` (the scores) and
+    `select`; a step none of whose queries has more than `topk` tokens of
+    context computes neither."""
     b, t = positions.shape
-    n = ki.shape[1]
     context = jnp.where(valid, positions + 1, 0).astype(jnp.int32)
 
     def one(args):
-        qi_, w_, ki_, ctx = args
+        *rows_, ctx = args
         with jax.named_scope("index"):
-            sc = ts.index_scores(qi_, w_, ki_)
+            sc = score(*rows_)
         with jax.named_scope("select"):
-            rows = sc.shape[0] * t
+            r = sc.shape[0] * t
             return ts.select_tokens(
-                sc.reshape(rows, n), ctx.reshape(rows), topk
-            ).reshape(-1, t, n)
+                sc.reshape(r, n), ctx.reshape(r), topk).reshape(-1, t, n)
 
     def select():
         if b > 1 and b * t * n * 4 > SELECT_BYTES:  # a row at a time
             return lax.map(one, tuple(
-                x[:, None] for x in (qi, w, ki, context)))[:, 0]
-        return one((qi, w, ki, context))
+                x[:, None] for x in (*rows, context)))[:, 0]
+        return one((*rows, context))
 
     def everything():
         return jnp.arange(n, dtype=jnp.int32)[None, None] < context[..., None]
@@ -483,8 +519,7 @@ def token_attention(
     scale = 1.0 / math.sqrt(d)
     dpad = cfg.attn_cfg.kv_head_dim - d
     none = jnp.zeros((2,), jnp.int32)
-    with jax.named_scope("index"):
-        ki = index_keys_of(ki_pool, layer, tables, ki_new, positions)
+    n = tables.shape[1] * s
     q_s = (q.astype(jnp.float32) * scale).astype(q.dtype)
     context = jnp.where(valid, positions + 1, 0)[:, 0]
     counted = jnp.concatenate([
@@ -493,7 +528,10 @@ def token_attention(
     if not cfg.kernels:
         with jax.named_scope("kv_update"):
             kv = paged_scatter_kv(kv, layer, k, v, tables, positions, valid)
-        chosen = chosen_keys(qi, w, ki, positions, valid, topk)
+        with jax.named_scope("index"):
+            ki = index_keys_of(ki_pool, layer, tables, ki_new, positions)
+        chosen = chosen_keys(
+            ts.index_scores, (qi, w, ki), n, positions, valid, topk)
         with jax.named_scope("paged"):
             attn = ts.masked_attention(
                 q_s, paged_gather(kv.k, layer, tables)[..., :d],
@@ -504,7 +542,8 @@ def token_attention(
     k_pad, v_pad = (jnp.pad(k, pad), jnp.pad(v, pad)) if dpad else (k, v)
     if t == 1:
         with jax.named_scope("index"):
-            sc = ts.index_scores(qi, w, ki)[:, 0]
+            sc = step_scores(qi, w, ki_new, tables, positions, valid,
+                             ki_pool, layer)[:, 0]
         with jax.named_scope("select"):
             chosen = ts.select_tokens(sc, context, topk)  # [B, N]
         with jax.named_scope("paged"):
@@ -515,7 +554,9 @@ def token_attention(
                 (k_pad, v_pad), counted, chosen[:, None])
     from dynamo_tpu.ops.sparse_chunk import token_chunk_attention
 
-    chosen = chosen_keys(qi, w, ki, positions, valid, topk)
+    chosen = chosen_keys(
+        lambda *rows: step_scores(*rows, ki_pool, layer),
+        (qi, w, ki_new, tables, positions, valid), n, positions, valid, topk)
     with jax.named_scope("flash"):
         out = token_chunk_attention(
             jnp.pad(q_s, pad) if dpad else q_s, k_pad, v_pad, kv.k, kv.v,

@@ -30,7 +30,10 @@ and V rows, 1 KB each, in place of the walk was measured and deleted:
 
 Everything here is plain `jax.numpy` (scopes `attn/index`,
 `attn/select`); off the TPU attention is dense scores under the mask
-(`masked_attention`).
+(`masked_attention`). On the TPU the scores come from the kernel of
+ops/index_scores.py, which reads a row's index keys out of the pool in
+place; `index_scores` over a gathered copy is the path without kernels
+and what that kernel is judged against (tests/test_index_scores.py).
 """
 
 from __future__ import annotations

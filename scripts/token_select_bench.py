@@ -2,7 +2,14 @@
 jitted loop over the 8 layers of `keye-longctx`'s pools, 9,000 pages of
 64, timed whole with `block_until_ready`) of
 
-- `index`: a decode step's index scores (32 rows over their index keys);
+- `index_scores` (a line of its own, PR 45): the index scores as plain
+  XLA (`ts.index_scores` over the gathered copy `index_keys_of` makes)
+  against the kernel that reads the pool in place (`keye_vl.step_scores`
+  over `ops/index_scores.paged_index_scores`), a decode step's 32 rows
+  and a 512-query chunk: ms a layer, the kernel's own events from a
+  profiler trace, the bytes it fetches and their floor at the chip's HBM
+  rate, the largest difference of a live score, the selections' agreement;
+- `index`: a decode step's index scores as the program computes them;
 - `select`: the exact top 2,048 as a mask;
 - `walk_bits`: `ops/paged_attention.paged_decode_attention` over the
   rows' WHOLE contexts under the selection's bit a token, and
@@ -15,7 +22,8 @@ under the same selection. (PR 43 also timed a gather of the chosen K and V
 rows here, 2.62 ms a layer, and deleted it.)
 
     python scripts/token_select_bench.py [--rows 32] [--context 8192 13312
-        17920] [--seed N] [--rehearse]
+        17920] [--seed N] [--only index_scores] [--set INDEX_DEPTH=2]
+        [--rehearse]
 
 One JSON line a context on stdout; refuses a backend that is not a TPU
 unless `--rehearse` (the tiny preset's widths, interpreted, never a
@@ -46,6 +54,115 @@ def timed(fn, *args, n=5):
     return (time.perf_counter() - t) / n * 1e3, out
 
 
+def kernel_ms(fn, args, kernel: str, calls: int = 3):
+    """ms a call inside `kernel`'s own device events, from a trace of
+    `calls` calls (None where the trace names none: off the chip)."""
+    import tempfile
+
+    import jax
+
+    from chipbench import hostspans, trace
+
+    with tempfile.TemporaryDirectory() as tmp:
+        jax.profiler.start_trace(tmp)
+        for _ in range(calls):
+            jax.block_until_ready(fn(*args))
+        jax.profiler.stop_trace()
+        path = trace.find_xplane(tmp)
+        devices = hostspans.load(path)["devices"] if path else {}
+    for dev in devices.values():
+        took = [end - start for name, start, end, _ in dev["ops"]
+                if name.startswith("%" + kernel)]
+        return sum(took) / calls * 1e3 if took else None
+    return None
+
+
+def index_scores_section(layers, pool, tables, rows, context, t_chunk, cfg,
+                         keys, peak) -> dict:
+    """XLA against the kernel at one context: a decode step of `rows`
+    rows at `context` tokens and a `t_chunk`-query chunk whose last query
+    stands there."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.models.keye_vl import index_keys_of, step_scores
+    from dynamo_tpu.ops import index_scores as ix
+    from dynamo_tpu.ops import token_select as ts
+
+    n_l, page = 2 * pool.shape[0], pool.shape[2]
+    nj, di, topk = cfg.index_heads, cfg.index_head_dim, cfg.index_topk
+    dt = pool.dtype
+    item = jnp.dtype(dt).itemsize
+
+    def case(b, t, first):
+        pos = first + jnp.broadcast_to(
+            jnp.arange(t, dtype=jnp.int32)[None], (b, t))
+        qi = jax.random.normal(keys[0], (b, t, nj, di), dt)
+        w = jax.random.normal(keys[1], (b, t, nj), jnp.float32) / math.sqrt(
+            nj * di)
+        own = jax.random.normal(keys[2], (b, t, di), dt)
+        valid = jnp.ones((b, t), bool)
+        tb = tables[:b]
+        xla = layers(lambda li, kp: ts.index_scores(
+            qi, w, index_keys_of(kp, li, tb, own, pos)))
+        kernel = layers(lambda li, kp: step_scores(
+            qi, w, own, tb, pos, valid, kp, li))
+        ms_x, ref = timed(xla, pool, n=3)
+        ms_k, got = timed(kernel, pool, n=3)
+        live = np.arange(ref.shape[-1])[None, None] <= np.asarray(pos)[
+            ..., None]
+        diff = float(jnp.max(jnp.where(live[None], jnp.abs(got - ref), 0.0)))
+        ctx = (pos + 1).reshape(-1)
+        pick = jax.jit(lambda x: ts.select_tokens(
+            x.reshape(b * t, -1), ctx, topk))
+        li = n_l - 1
+        same = float(jnp.mean(jnp.all(pick(got[li]) == pick(ref[li]),
+                                      axis=-1)))
+        name = "paged_index_scores" + ("_chunk" if t > 1 else "")
+        own_ms = kernel_ms(kernel, (pool,), name)
+        return (ms_x / n_l, ms_k / n_l,
+                None if own_ms is None else own_ms / n_l, diff, same,
+                float(jnp.std(ref[li][live])))
+
+    dx, dk, down, ddiff, dsame, dstd = case(rows, 1, context - 1)
+    hist = (context - t_chunk) // page * page
+    cx, ck, cown, cdiff, csame, cstd = case(1, t_chunk, hist)
+    # what the kernel fetches: whole pages of a pair row a token
+    pages = -(-(context - 1) // page)
+    fetched = rows * pages * page * pool.shape[3] * item
+    floor_ms = fetched / peak["hbm_bytes_per_s"] * 1e3
+    tiles = -(-t_chunk // ix.INDEX_BLOCK_Q)
+    keys_read = -(-hist // page) * page
+    flop = 2.0 * t_chunk * nj * pool.shape[3] * (keys_read + t_chunk)
+    out = {
+        "context": context, "rows": rows, "layers": n_l,
+        "chunk_history": hist, "platform": jax.default_backend(),
+        "decode_max_abs_diff": ddiff, "decode_scores_std": dstd,
+        "decode_selection_agreement": dsame,
+        "chunk_max_abs_diff": cdiff, "chunk_scores_std": cstd,
+        "chunk_selection_agreement": csame,
+        "ms_a_layer": {"decode_xla": dx, "decode_kernel": dk,
+                       "chunk_xla": cx, "chunk_kernel": ck},
+        "kernel_own_ms_a_layer": {"paged_index_scores": down,
+                                  "paged_index_scores_chunk": cown},
+        "decode_bytes_fetched": fetched, "decode_floor_ms": floor_ms,
+        "decode_program_hbm_share": 100.0 * floor_ms / dk,
+        "chunk_bytes_fetched": tiles * keys_read * pool.shape[3] * item,
+        "chunk_out_bytes": t_chunk * tables.shape[1] * page * 4,
+        "chunk_gflop_executed": flop / 1e9,
+        "blocking": {k: getattr(ix, k) for k in (
+            "INDEX_BLOCK_PAGES", "INDEX_DEPTH", "INDEX_BLOCK_Q",
+            "INDEX_COLUMNS")},
+    }
+    if down:
+        out["decode_kernel_hbm_share"] = 100.0 * floor_ms / down
+    if cown:
+        out["chunk_kernel_mxu_share"] = 100.0 * flop / (
+            cown * 1e-3) / peak["bf16_flops_per_s"]
+    return out
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rows", type=int, default=32)
@@ -53,13 +170,19 @@ def main(argv=None) -> int:
                     default=[8192, 13312, 17920])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--only", choices=["index_scores"],
+                    help="that section alone")
+    ap.add_argument("--set", action="append", default=[],
+                    metavar="NAME=VALUE",
+                    help="a module constant of ops/index_scores.py")
     ns = ap.parse_args(argv)
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from dynamo_tpu.models.keye_vl import KeyeVLConfig, index_keys_of
+    from dynamo_tpu.models.keye_vl import KeyeVLConfig, step_scores
+    from dynamo_tpu.ops import index_scores as ix
     from dynamo_tpu.ops import token_select as ts
     from dynamo_tpu.ops.paged_attention import paged_decode_attention
     from dynamo_tpu.ops.sparse_chunk import token_chunk_attention
@@ -75,6 +198,14 @@ def main(argv=None) -> int:
     else:
         cfg, pages, page, max_ctx = KeyeVLConfig.tiny(), 64, 4, 64
         rows, contexts, t_chunk = 3, [40], 8
+    for item in ns.set:
+        name, value = item.split("=", 1)
+        if not hasattr(ix, name):
+            raise SystemExit(f"ops/index_scores.py has no {name}")
+        setattr(ix, name, int(value) if value.isdigit() else value)
+    peak = json.loads((ROOT / "chipbench" / "peaks.json").read_text()).get(
+        jax.devices()[0].device_kind) or {
+        "hbm_bytes_per_s": float("nan"), "bf16_flops_per_s": float("nan")}
     n_l, hq, hkv, d = (cfg.num_layers, cfg.num_heads, cfg.num_kv_heads,
                        cfg.head_dim)
     nj, di, topk = cfg.index_heads, cfg.index_head_dim, cfg.index_topk
@@ -100,6 +231,11 @@ def main(argv=None) -> int:
         for r in range(rows):
             tables[r, :need] = rng.permutation(np.arange(1, pages))[:need]
         tables = jnp.asarray(tables)
+        print(json.dumps({"index_scores": index_scores_section(
+            layers, ki_pool, tables, rows, context, t_chunk, cfg, ks[3:6],
+            peak)}), flush=True)
+        if ns.only:
+            continue
         pos = jnp.full((rows, 1), context - 1, jnp.int32)
         ctx = pos[:, 0] + 1
         q = jax.random.normal(ks[3], (rows, hq, d), dt)
@@ -108,8 +244,8 @@ def main(argv=None) -> int:
             nj * di)
         own = jax.random.normal(ks[6], (rows, 1, di), dt)
 
-        index = layers(lambda li, kp: ts.index_scores(
-            qi, w, index_keys_of(kp, li, tables, own, pos))[:, 0])
+        index = layers(lambda li, kp: step_scores(
+            qi, w, own, tables, pos, jnp.ones((rows, 1), bool), kp, li)[:, 0])
         ms_index, sc = timed(index, ki_pool)
         select = jax.jit(lambda s: jax.lax.map(
             lambda x: ts.select_tokens(x, ctx, topk), s))
@@ -150,8 +286,7 @@ def main(argv=None) -> int:
         t1 = tables[:1]
 
         def chosen_of(li, kp):
-            kis = index_keys_of(kp, li, t1, cown, cpos)
-            sc = ts.index_scores(cqi, cw, kis)
+            sc = step_scores(cqi, cw, cown, t1, cpos, cvalid, kp, li)
             return ts.select_tokens(
                 sc[0], cpos[0] + 1, topk)[None]
 

@@ -845,7 +845,23 @@ def test_keye_vl_step_compiles_at_published_widths(topo, rows, t, b_pre):
     # (under a bit a token: the gather of chosen rows is gone)
     assert _kernel_calls(text, "token_chunk_attention") == (1 if b_pre else 0)
     assert _kernel_calls(text, "paged_decode_attention") == 1
-    assert mem.temp_size_in_bytes <= (0.55e9 if b_pre else 0.4e9)
+    # 204 / 52 / 451 MB of temporaries since the gathered keys went
+    assert mem.temp_size_in_bytes <= {0: 0.25e9, 1: 0.1e9, 32: 0.5e9}[b_pre]
+    # the index scores read the pool in place (PR 45, ops/index_scores.py):
+    # one kernel for the decode rows, one for a chunk's tiles, and neither
+    # the gathered copy of the rows' keys with the other layer's half
+    # ([B, MP x S, 128], 151 MB at 32 rows) nor a chunk row's is built
+    assert _kernel_calls(text, "paged_index_scores") == 1
+    assert _kernel_calls(text, "paged_index_scores_chunk") == (
+        1 if b_pre else 0)
+    for copy in (f"bf16[{rows},{mp * PAGE},128]",
+                 f"bf16[{rows},{mp},{PAGE},128]",
+                 f"bf16[{b_pre},{mp * PAGE},128]",
+                 f"bf16[{b_pre},{mp},{PAGE},128]",
+                 f"bf16[1,{mp * PAGE},128]", f"bf16[1,{mp},{PAGE},128]",
+                 f"bf16[{rows},{mp * PAGE},64]",
+                 f"bf16[{b_pre},{mp * PAGE},64]", f"bf16[1,{mp * PAGE},64]"):
+        assert copy not in text, copy
 
 
 @pytest.mark.parametrize("rows,vocab", [
