@@ -30,17 +30,22 @@ scalars act at run time:
   holds a KV head as a row of its own ([L, P * Hkv, S, 1, D]) and the
   compressed keys beside the pages: `SalaCache`.
 
-A decode step selects inside the walk (scope `attn/select`, then the page
-walk over the selected list, `attn/paged`). A prompt chunk attends by
-TILE of 128 queries in a kernel of its own (ops/sparse_chunk.py
+A decode step selects inside the walk (scope `attn/select`: the kernel of
+ops/block_scores.py scores the sequence's compressed keys out of the pool
+in place, `ss.blocks_of_scores` counts out the top blocks, `ss.decode_lists`
+lays out the list; then the page walk over it, `attn/paged`). A prompt
+chunk's queries are scored by the same kernel, a tile at a time; it attends
+by TILE of 128 queries in a kernel of its own (ops/sparse_chunk.py
 `sparse_chunk_attention`, scope `attn/flash`; the GQA chunk kernels cannot
 slice one KV head of a tiled page): the cached pages ANY query of the tile
 chose are read once, a block of pages a turn, a mask bit a (query, page)
 says whose they are, and the chunk's own keys take the first turns of the
-same online softmax. A chunk none of whose queries has reached `dense_len`
-skips the selection's sorts (`dense_blocks`: every page, every bit).
-(Without the kernels, `attention_impl` "xla", dense scores under each
-query's block mask: `sparse_select.masked_attention`.)
+same online softmax. A query that has not reached `dense_len` chooses
+every page up to its own: every bit.
+(Without the kernels, `attention_impl` "xla": a gathered copy of the
+compressed keys, `ss.select_blocks`' sorts, skipped by a `lax.cond` where
+no query of the step has reached `dense_len`, and dense scores under each
+query's block mask, `sparse_select.masked_attention`.)
 
 The layers are held in TWO stacks, one a kind, each in layer order
 (`params["sparse"]`, `params["lightning"]`), and a step program holds each
@@ -79,6 +84,7 @@ from dynamo_tpu.models.llama import (
 from dynamo_tpu.models.nemotron_h import ssd_chunk_scan
 from dynamo_tpu.ops import sparse_select as ss
 from dynamo_tpu.ops import ssm_state
+from dynamo_tpu.ops.block_scores import paged_block_scores
 from dynamo_tpu.ops.sparse_select import SparseDims
 
 SPARSE, LIGHTNING = "minicpm4", "lightning-attn"
@@ -483,26 +489,35 @@ def virtual_rows(cfg: MiniCPMSALAConfig, g: StepGroup):
             jnp.repeat(g.valid, hkv, axis=0))
 
 
-def compressed_keys_of(k, kv, kc_pool, layer, tables, positions, valid,
-                       cfg: MiniCPMSALAConfig):
-    """The virtual rows' compressed keys as a step's queries see them:
-    (kc [B', NB * per_block, D] in window order, the pool's with the
-    windows that end inside this chunk put in; the step's fresh ones (kc
-    [B', T, Dc], ends, j) for `land_compressed`). k [B', T, 1, D]."""
-    dims, d = cfg.sparse, k.shape[-1]
-    dpad = cfg.attn_cfg.kv_head_dim - d
-    start = positions[:, 0]
+def fresh_keys_of(k, kv, layer, tables, positions, valid,
+                  cfg: MiniCPMSALAConfig):
+    """The compressed keys of the windows that end inside a step, for
+    `land_compressed` and for the step's own selection: (kc [B', T, Dc],
+    ends, j) of `ss.fresh_windows`. k [B', T, 1, D]."""
+    dims = cfg.sparse
+    dpad = cfg.attn_cfg.kv_head_dim - k.shape[-1]
     k_row = k[:, :, 0]
     if dpad:
         k_row = jnp.pad(k_row, ((0, 0), (0, 0), (0, dpad)))
-    tail = ss.history_tail(kv.k, layer, tables, start, dims.kernel_size - 1)
-    fresh = ss.fresh_windows(k_row, tail, positions, valid, dims)
+    tail = ss.history_tail(kv.k, layer, tables, positions[:, 0],
+                           dims.kernel_size - 1)
+    return ss.fresh_windows(k_row, tail, positions, valid, dims)
+
+
+def compressed_keys_of(k, kv, kc_pool, layer, tables, positions, valid,
+                       cfg: MiniCPMSALAConfig):
+    """The virtual rows' compressed keys as a step's queries see them, a
+    gathered COPY (the path without kernels; the kernel of
+    ops/block_scores.py reads the pool in place): (kc [B', NB *
+    per_block, D] in window order, the pool's with the windows that end
+    inside this chunk put in; the step's fresh ones, `fresh_keys_of`)."""
+    fresh = fresh_keys_of(k, kv, layer, tables, positions, valid, cfg)
     kc = ss.with_fresh(
-        ss.gather_compressed(kc_pool, layer, tables, dims,
+        ss.gather_compressed(kc_pool, layer, tables, cfg.sparse,
                              heads=cfg.num_kv_heads),
-        fresh[0], fresh[1], start, dims,
+        fresh[0], fresh[1], positions[:, 0], cfg.sparse,
     )
-    return kc[..., :d], fresh
+    return kc[..., :k.shape[-1]], fresh
 
 
 def pages_walked(lens, hist, valid, page_size: int):
@@ -547,38 +562,35 @@ def sparse_attention(
     start = positions[:, 0]
     none = jnp.zeros((2,), jnp.int32)
     past = valid & (positions + 1 >= dims.dense_len)  # under the sparse rule
-    with jax.named_scope("select"):
-        kc, fresh = compressed_keys_of(
-            k, kv, kc_pool, layer, tables, positions, valid, cfg)
-
-    def chosen():
-        """`select_blocks`, as many rows at a time as keep its float32
-        scores ([rows, T, G, NC]) under `SELECT_BYTES`: 32 prompts of 32
-        tokens side by side hold as many as four pieces of 512."""
-        n = b
-        while n % 2 == 0 and n * t * g * kc.shape[1] * 4 > SELECT_BYTES:
-            n //= 2
-        if n == b:
-            return ss.select_blocks(q, kc, positions, dims, scale)
-        return lax.map(
-            lambda a: ss.select_blocks(*a, dims, scale),
-            tuple(x.reshape(b // n, n, *x.shape[1:])
-                  for x in (q, kc, positions)),
-        ).reshape(b, t, -1)
-
-    def select():
-        """Each query's blocks; the sorts are skipped where every query
-        of the step stands under `dense_len`."""
-        return lax.cond(
-            jnp.any(past), chosen,
-            lambda: ss.dense_blocks(positions, tables.shape[1], dims),
-        )
-
     if acfg.attention_impl not in ("pallas", "hybrid"):
+        with jax.named_scope("select"):
+            kc, fresh = compressed_keys_of(
+                k, kv, kc_pool, layer, tables, positions, valid, cfg)
+
+        def chosen():
+            """`select_blocks`, as many rows at a time as keep its float32
+            scores ([rows, T, G, NC]) under `SELECT_BYTES`: 32 prompts of
+            32 tokens side by side hold as many as four pieces of 512."""
+            n = b
+            while n % 2 == 0 and n * t * g * kc.shape[1] * 4 > SELECT_BYTES:
+                n //= 2
+            if n == b:
+                return ss.select_blocks(q, kc, positions, dims, scale)
+            return lax.map(
+                lambda a: ss.select_blocks(*a, dims, scale),
+                tuple(x.reshape(b // n, n, *x.shape[1:])
+                      for x in (q, kc, positions)),
+            ).reshape(b, t, -1)
+
         with jax.named_scope("kv_update"):
             kv = paged_scatter_kv(kv, layer, k, v, tables, positions, valid)
         with jax.named_scope("select"):
-            sel = select()
+            # the sorts are skipped where every query of the step stands
+            # under `dense_len`
+            sel = lax.cond(
+                jnp.any(past), chosen,
+                lambda: ss.dense_blocks(positions, tables.shape[1], dims),
+            )
         with jax.named_scope("paged"):
             attn = ss.masked_attention(
                 q, kv.k, kv.v, layer, tables, positions, sel, dims, scale)
@@ -589,9 +601,19 @@ def sparse_attention(
                 valid[:, 0], dims.block_size)
         return (attn.reshape(b, t, g * d), kv, None, fresh,
                 jnp.concatenate([walk, none]))
+    # with kernels the selection reads the compressed keys out of the pool
+    # in place and neither sorts nor branches: a step none of whose
+    # queries has reached `dense_len` scores its (few) pages too
+    with jax.named_scope("select"):
+        fresh = fresh_keys_of(k, kv, layer, tables, positions, valid, cfg)
+        sel = ss.blocks_of_scores(
+            paged_block_scores(q, kc_pool, layer, tables, positions, valid,
+                               fresh, dims, scale, cfg.num_kv_heads),
+            positions, dims)
     if t == 1:
         with jax.named_scope("select"):
-            _, pages, lens = decode_selection(q, kc, tables, positions, cfg)
+            pages, lens = ss.decode_lists(sel[:, 0], tables, start, dims,
+                                          counted=True)
         attn, kv, staged = attention_block(
             q, k, v, kv, layer, pages, lens[:, None], valid, acfg)
         return attn, kv, staged, fresh, jnp.concatenate([pages_walked(
@@ -608,8 +630,6 @@ def sparse_attention(
 
     pad = ((0, 0), (0, 0), (0, 0), (0, dpad))
     k_pad, v_pad = (jnp.pad(k, pad), jnp.pad(v, pad)) if dpad else (k, v)
-    with jax.named_scope("select"):
-        sel = select()
     with jax.named_scope("flash"):
         q_s = (q.astype(jnp.float32) * scale).astype(q.dtype)
         out, (tiles, named) = sparse_chunk_attention(

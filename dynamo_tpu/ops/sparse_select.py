@@ -38,9 +38,18 @@ after the query, so what a dispatch that is rolled back wrote is never
 seen and is written again when the sequence comes by for good; a page that
 is freed takes its compressed keys with it.
 
-Everything here is plain `jax.numpy`: the selection (scope `attn/select`)
-is a gather of the row's compressed keys, one small batched matmul, a
-softmax and two sorts of a few hundred blocks.
+Everything here is plain `jax.numpy`. On the TPU (`attention_impl` pallas
+/ hybrid) the selection (scope `attn/select`) reads the pool IN PLACE: the
+kernel of ops/block_scores.py streams a sequence's compressed keys page
+by page through VMEM and writes the rule's block scores, `blocks_of_scores`
+picks the top `topk` of them by counting passes (ops/token_select.py
+`select_tokens`: exact, ties to the earlier block, no sort) and
+`decode_lists(counted=True)` lays the walk's list out by prefix sums.
+`gather_compressed`, `with_fresh`, `select_blocks` and `_page_lists` (a
+gathered copy of the row's compressed keys with the fresh windows put in,
+one small batched matmul, a softmax and three sorts of a few hundred
+blocks) are the path without kernels, and what the kernel path is judged
+against (tests/test_block_scores.py).
 """
 
 from __future__ import annotations
@@ -50,6 +59,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from dynamo_tpu.ops.token_select import select_tokens
 
 #: a score no pooled probability reaches: blocks that always count as chosen
 _FORCED = 1e30
@@ -183,14 +194,13 @@ def land_compressed(kc_pool, staged, tables, dims: SparseDims):
 # ---------------------------------------------------------------------------
 
 
-def select_blocks(q, kc, positions, dims: SparseDims, scale: float):
-    """Which blocks each query attends over, for its KV head: q [B, T, G,
-    D] the KV head's query heads, kc [B, NB * cpb, D] the (virtual) row's
-    compressed keys, positions [B, T]. Returns selected [B, T, NB] bool:
-    every block up to the query's own while its context is under
-    `dense_len`, else the `topk` of the rule in this module's docstring."""
+def pooled_scores(q, kc, positions, dims: SparseDims, scale: float):
+    """The rule's block scores of each query for its KV head, before the
+    forced blocks: q [B, T, G, D] the KV head's query heads, kc [B, NB *
+    cpb, D] the (virtual) row's compressed keys, positions [B, T].
+    float32 [B, T, NB]."""
     f32 = jnp.float32
-    kk, st, s = dims.kernel_size, dims.kernel_stride, dims.block_size
+    kk, st = dims.kernel_size, dims.kernel_stride
     cpb, reach = dims.per_block, dims.reach
     nc = kc.shape[1]
     nb = nc // cpb
@@ -205,17 +215,59 @@ def select_blocks(q, kc, positions, dims: SparseDims, scale: float):
     score = pj[..., :nc].reshape(*pj.shape[:2], nb, cpb).max(axis=-1)
     for r in range(reach):  # the windows that start in the block before
         score = jnp.maximum(score, pj[..., cpb + r :: cpb][..., :nb])
-    blk = jnp.arange(nb, dtype=jnp.int32)
+    return score
+
+
+def ranked_blocks(score, positions, dims: SparseDims):
+    """The selection from the rule's block scores [B, T, NB], by two
+    sorts: every block up to the query's own while its context is under
+    `dense_len`, else the `topk` of the rule in this module's docstring.
+    bool [B, T, NB]."""
+    score, exists, dense = _candidates(score, positions, dims)
+    order = jnp.argsort(-score, axis=-1, stable=True)
+    rank = jnp.argsort(order, axis=-1)
+    return exists & (dense | (rank < dims.topk))
+
+
+def select_blocks(q, kc, positions, dims: SparseDims, scale: float):
+    """Which blocks each query attends over, for its KV head:
+    `ranked_blocks` of `pooled_scores`."""
+    return ranked_blocks(pooled_scores(q, kc, positions, dims, scale),
+                         positions, dims)
+
+
+def _candidates(score, positions, dims: SparseDims):
+    """(The block scores [.., NB] of queries at `positions` [..] as the
+    rule ranks them: `_FORCED` where a block always counts as chosen,
+    `-_FORCED` past the query's own; exists [.., NB]: the blocks up to
+    the query's own; dense [.., 1]: the query's context is under
+    `dense_len`)."""
+    s = dims.block_size
+    blk = jnp.arange(score.shape[-1], dtype=jnp.int32)
     own = (positions // s)[..., None]
     near = (jnp.maximum(positions - dims.window_size + 1, 0) // s)[..., None]
     exists = blk <= own
     forced = (blk < dims.init_blocks) | (blk >= near)
     score = jnp.where(forced, _FORCED, score)
     score = jnp.where(exists, score, -_FORCED)
-    order = jnp.argsort(-score, axis=-1, stable=True)
-    rank = jnp.argsort(order, axis=-1)
-    dense = (n < dims.dense_len)[..., None]
-    return exists & (dense | (rank < dims.topk))
+    return score, exists, (positions + 1 < dims.dense_len)[..., None]
+
+
+def blocks_of_scores(score, positions, dims: SparseDims):
+    """`ranked_blocks`' selection from the rule's block scores [B, T, NB]
+    (ops/block_scores.py), with no sort: the `topk` highest of a query's
+    existing blocks, ties to the earlier, by the counting passes of
+    ops/token_select.py."""
+    b, t, nb = score.shape
+    # a row a (virtual row, query) from here on: candidates made in three
+    # axes and flattened after are a reshape XLA's TPU compiler refuses
+    # beside four pieces ("Reshape should have supported layout")
+    positions = positions.reshape(-1)
+    score, exists, dense = _candidates(
+        score.reshape(b * t, nb), positions, dims)
+    context = jnp.minimum(positions // dims.block_size + 1, nb)
+    top = select_tokens(score, context.astype(jnp.int32), dims.topk)
+    return (exists & (dense | top)).reshape(b, t, nb)
 
 
 def _page_lists(has, tables, k: int):
@@ -229,15 +281,28 @@ def _page_lists(has, tables, k: int):
     return jnp.where(jnp.arange(k)[None] < count[:, None], pages, 0), count
 
 
-def decode_lists(selected, tables, hist, dims: SparseDims):
+def _counted_lists(has, tables, k: int):
+    """`_page_lists` with no sort: the i-th marked block's page goes to
+    slot `cumsum(has) - 1`, a one-hot sum a slot."""
+    nb = has.shape[1]
+    slot = jnp.cumsum(has, axis=-1, dtype=jnp.int32) - 1
+    hit = has[..., None] & (
+        slot[..., None] == jnp.arange(k, dtype=jnp.int32)[None, None])
+    pages = jnp.sum(jnp.where(hit, tables[:, :nb, None], 0), axis=1)
+    return pages.astype(tables.dtype), slot[:, -1] + 1
+
+
+def decode_lists(selected, tables, hist, dims: SparseDims,
+                 counted: bool = False):
     """A decode row's selection as what the page walk takes: (pages [B,
     K] of `tables`, the selected blocks that hold cached tokens in
     ascending order, then zeros; lens [B]: the tokens those pages hold,
     every page full but the last). selected [B, NB], hist [B] the tokens
-    already cached (the query's own position)."""
+    already cached (the query's own position). `counted`: by prefix sums
+    (the TPU path), not a sort; the same lists to the bit."""
     s = dims.block_size
     blk = jnp.arange(selected.shape[1], dtype=jnp.int32)[None]
-    pages, count = _page_lists(
+    pages, count = (_counted_lists if counted else _page_lists)(
         selected & (blk * s < hist[:, None]), tables, dims.list_pages)
     tail = hist - s * ((hist - 1) // s)  # tokens in the last cached block
     lens = jnp.where(hist > 0, s * (count - 1) + tail, 0)

@@ -8,15 +8,27 @@ time the MXU needs for the multiplications under the selection.
 
     python scripts/sparse_chunk_bench.py [--case NAME ...] [--tree DIR]
                      [--sweep NAME=V1,V2 ...] [--seed N] [--rehearse]
+                     [--only select]
 
 `--tree DIR` times another checkout's `dynamo_tpu` on the same inputs
 (PR 41's, from `git archive`: every query of a sparse chunk walked a page
 list of its own through the decode kernel, and a `dense-*` case, every
 query under `dense_len`, ran `latent_prefill_attention` fed a zero latent
 half). `--sweep` re-times under each value of a module constant of
-`ops/sparse_chunk.py` (the blocking). One JSON line a case and setting on
-stdout; refuses a backend that is not a TPU unless `--rehearse` (the tiny
-preset's widths, interpreted, never a number).
+`ops/sparse_chunk.py` or `ops/block_scores.py` (the blocking). One JSON
+line a case and setting on stdout; refuses a backend that is not a TPU
+unless `--rehearse` (the tiny preset's widths, interpreted, never a
+number).
+
+`--only select` (PR 46) times the SELECTION alone (scope `attn/select`),
+XLA -> kernel, at 64 x 1 decode rows (32 sequences) and one 512-token
+piece over 8,192 / 12,288 / 16,384 tokens in the same 4 layers: the path
+without kernels whole and SPLIT into programs of their own (the gathered
+copy with the fresh windows put in; scores, softmax and the pool onto
+blocks; the sorts and the walk's list), the kernel path whole
+(`paged_block_scores`, `blocks_of_scores`, `decode_lists(counted=True)`)
+with the kernel's own events beside it, the largest difference of a block
+score and the share of (query, block) bits the two selections agree on.
 """
 
 from __future__ import annotations
@@ -189,6 +201,175 @@ def program_seconds(trace_dir: str) -> dict:
             "kernels": kernels}
 
 
+SELECT_CASES = {
+    f"{kind}-{ctx // 1024}k": dict(
+        t=t, cur=[t] * rows, hist=[ctx - t + 1 if t == 1 else ctx] * rows)
+    for ctx in (8192, 12288, 16384)
+    for kind, t, rows in (("decode", 1, 32), ("piece", 512, 1))
+}
+SELECT_REHEARSAL = {
+    "decode": dict(t=1, cur=[1, 1, 0], hist=[67, 35, 0]),
+    "piece": dict(t=16, cur=[16, 9], hist=[36, 24]),
+}
+
+
+def select_programs(sala, cfg):
+    """The selection of every sparse layer as programs of their own: the
+    path without kernels whole and in its three parts (each part takes
+    the part before as an argument), and the kernel path."""
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.models.llama import KVPages
+    from dynamo_tpu.ops import block_scores as bs
+    from dynamo_tpu.ops import sparse_select as ss
+
+    dims, hkv = cfg.sparse, cfg.num_kv_heads
+    scale = 1.0 / math.sqrt(cfg.head_dim)
+
+    def layers(fn, n, *xs):
+        return jax.lax.scan(
+            lambda _, a: (None, fn(*a)), None,
+            (jnp.arange(n, dtype=jnp.int32), *xs))[1]
+
+    def lists(sel, tables, pos, counted):
+        if sel.shape[1] > 1:
+            return sel
+        return ss.decode_lists(sel[:, 0], tables, pos[:, 0], dims,
+                               counted=counted)
+
+    def copy(q, k, k_pool, v_pool, kc_pool, tables, pos, valid):
+        kv = KVPages(k=k_pool, v=v_pool)
+        return layers(lambda li: sala.compressed_keys_of(
+            k, kv, kc_pool, li, tables, pos, valid, cfg)[0],
+            k_pool.shape[0])
+
+    def scores(q, kc, pos):
+        return layers(lambda li, kc_l: ss.pooled_scores(
+            q, kc_l, pos, dims, scale), kc.shape[0], kc)
+
+    def sorts(score, tables, pos):
+        return layers(lambda li, sc: lists(
+            ss.ranked_blocks(sc, pos, dims), tables, pos, False),
+            score.shape[0], score)
+
+    def xla(q, k, k_pool, v_pool, kc_pool, tables, pos, valid):
+        kv = KVPages(k=k_pool, v=v_pool)
+
+        def layer(li):
+            with jax.named_scope("attn"), jax.named_scope("select"):
+                kc, _ = sala.compressed_keys_of(
+                    k, kv, kc_pool, li, tables, pos, valid, cfg)
+                sc = ss.pooled_scores(q, kc, pos, dims, scale)
+                return sc, lists(ss.ranked_blocks(sc, pos, dims), tables,
+                                 pos, False)
+
+        return layers(layer, k_pool.shape[0])
+
+    def kernel(q, k, k_pool, v_pool, kc_pool, tables, pos, valid):
+        kv = KVPages(k=k_pool, v=v_pool)
+
+        def layer(li):
+            with jax.named_scope("attn"), jax.named_scope("select"):
+                fresh = sala.fresh_keys_of(k, kv, li, tables, pos, valid, cfg)
+                sc = bs.paged_block_scores(
+                    q, kc_pool, li, tables, pos, valid, fresh, dims, scale,
+                    hkv)
+                return sc, lists(ss.blocks_of_scores(sc, pos, dims), tables,
+                                 pos, True)
+
+        return layers(layer, k_pool.shape[0])
+
+    return {n: jax.jit(f) for n, f in dict(
+        copy=copy, scores=scores, sorts=sorts, xla=xla,
+        kernel=kernel).items()}
+
+
+def module_seconds(trace_dir: str, names) -> dict:
+    """Summed device seconds and calls of each `jit_<name>` in a trace,
+    the time inside the kernel `paged_block_scores`' own events, and the
+    ten longest operations inside `jit_kernel`."""
+    from chipbench import sparsescopes, trace
+
+    loaded = sparsescopes.load_deep(trace.find_xplane(trace_dir))
+    out = {n: [0.0, 0] for n in names}
+    own, inside = 0.0, {}
+    for dev in loaded["devices"].values():
+        spans = []
+        for name, start, end in dev["modules"]:
+            if name.startswith("jit_") and name[4:] in out:
+                out[name[4:]][0] += end - start
+                out[name[4:]][1] += 1
+            if name == "jit_kernel":
+                spans.append((start, end))
+        for name, start, end, _scope in dev["ops"]:
+            if name.startswith("%paged_block_scores"):
+                own += end - start
+            if (not name.startswith("%while")
+                    and any(a <= start < b for a, b in spans)):
+                inside[name] = inside.get(name, 0.0) + end - start
+        break  # one chip
+    return out, own, sorted(inside.items(), key=lambda kv: -kv[1])[:10]
+
+
+def measure_select(sala, name: str, case: dict, seed: int,
+                   rehearse: bool) -> dict:
+    """One line of `--only select`: us a layer of each program."""
+    import jax
+    import numpy as np
+    from dataclasses import replace
+
+    cfg = (sala.MiniCPMSALAConfig.tiny() if rehearse
+           else sala.MiniCPMSALAConfig.minicpm_sala_9b(range(9, 25)))
+    cfg = replace(cfg, attention_impl="pallas")
+    page, pages, mp = (4, 40, 20) if rehearse else (64, 9000, 288)
+    data = make_case(cfg, case, seed, page, pages, mp)
+    progs = select_programs(sala, cfg)
+    whole = tuple(data[n] for n in (
+        "q", "k", "k_pool", "v_pool", "kc_pool", "tables", "pos", "valid"))
+    kc = jax.block_until_ready(progs["copy"](*whole))
+    sc_x, sel_x = jax.block_until_ready(progs["xla"](*whole))
+    sc_k, sel_k = jax.block_until_ready(progs["kernel"](*whole))
+    calls = {"copy": whole, "scores": (data["q"], kc, data["pos"]),
+             "sorts": (sc_x, data["tables"], data["pos"]),
+             "xla": whole, "kernel": whole}
+    live = np.asarray(data["valid"])[None, :, :, None] & (
+        np.arange(mp)[None, None, None]
+        <= (np.asarray(data["pos"]) // page)[None, ..., None])
+    ok = np.asarray(data["valid"])  # a padding query's bits are nobody's
+    same = [(np.asarray(a) == np.asarray(b))[:, ok[:, 0] if case["t"] == 1
+                                             else ok]
+            for a, b in zip(jax.tree.leaves(sel_x), jax.tree.leaves(sel_k))]
+    layers = cfg.sparse_layers
+    out = {
+        "case": name, "t": case["t"], "sequences": len(case["cur"]),
+        "hist": case["hist"][0], "layers": layers,
+        "max_score_diff": float(np.max(np.abs(np.where(
+            live, np.asarray(sc_x) - np.asarray(sc_k), 0.0)))),
+        # decode: the lists' (pages, lens); a piece: its [T, NB] bits
+        "selection_agreement": float(np.mean([x.mean() for x in same])),
+        "device": jax.devices()[0].device_kind,
+    }
+    if rehearse:
+        return out
+    with tempfile.TemporaryDirectory() as tmp:
+        jax.profiler.start_trace(tmp)
+        for _ in range(3):
+            for n, args in calls.items():
+                jax.block_until_ready(progs[n](*args))
+        jax.profiler.stop_trace()
+        secs, own, ops = module_seconds(tmp, calls)
+    us = {n: round(s / max(c, 1) / layers * 1e6, 1)
+          for n, (s, c) in secs.items()}
+    n = max(secs["kernel"][1], 1) * layers
+    out.update(us_per_layer=us,
+               kernel_own_us_per_layer=round(own / n * 1e6, 1),
+               # the kernel path's longest operations (loops left out)
+               kernel_path_ops_us_per_layer={
+                   k: round(v / n * 1e6, 1) for k, v in ops})
+    return out
+
+
 def measure(sala, name: str, case: dict, seed: int,
             rehearse: bool) -> dict:
     import jax
@@ -250,11 +431,15 @@ def measure(sala, name: str, case: dict, seed: int,
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--case", action="append", choices=sorted(CASES))
+    ap.add_argument("--case", action="append",
+                    choices=sorted({**CASES, **SELECT_CASES}))
     ap.add_argument("--tree", default=str(ROOT),
                     help="the checkout whose dynamo_tpu is timed")
     ap.add_argument("--sweep", action="append", default=[],
-                    metavar="NAME=V1,V2", help="ops/sparse_chunk constant")
+                    metavar="NAME=V1,V2",
+                    help="a constant of ops/sparse_chunk or ops/block_scores")
+    ap.add_argument("--only", choices=["select"],
+                    help="the selection alone, XLA split -> kernel")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--rehearse", action="store_true")
     ns = ap.parse_args()
@@ -272,27 +457,39 @@ def main() -> int:
         from dynamo_tpu.ops import sparse_chunk
     except ImportError:  # a tree from before the tile kernel
         sparse_chunk = None
+    try:
+        from dynamo_tpu.ops import block_scores
+    except ImportError:  # a tree from before the selection's kernel
+        block_scores = None
     names, values = [], []
     for item in ns.sweep:
         key, vals = item.split("=", 1)
-        if not hasattr(sparse_chunk, key):
-            raise SystemExit(f"ops/sparse_chunk.py has no {key}")
-        names.append(key)
+        owner = next((m for m in (sparse_chunk, block_scores)
+                      if hasattr(m, key)), None)
+        if owner is None:
+            raise SystemExit(f"no ops module of the bench has {key}")
+        names.append((owner, key))
         values.append([int(x) for x in vals.split(",")])
-    cases = REHEARSAL if ns.rehearse else {
-        n: CASES[n] for n in (ns.case or CASES)}
+    if ns.only == "select":
+        table = SELECT_REHEARSAL if ns.rehearse else SELECT_CASES
+        run = measure_select
+    else:
+        table = REHEARSAL if ns.rehearse else CASES
+        run = measure
+    cases = {n: table[n] for n in (
+        ns.case if ns.case and not ns.rehearse else table)}
     failed = 0
     for setting in itertools.product(*values):
-        for key, value in zip(names, setting):
-            setattr(sparse_chunk, key, value)
+        for (owner, key), value in zip(names, setting):
+            setattr(owner, key, value)
         for name, case in cases.items():
             try:
-                doc = measure(sala, name, case, ns.seed, ns.rehearse)
+                doc = run(sala, name, case, ns.seed, ns.rehearse)
             except Exception as e:  # noqa: BLE001 — the others still run
                 doc = {"case": name,
                        "error": f"{type(e).__name__}: {e}"[:2000]}
                 failed += 1
-            doc["set"] = dict(zip(names, setting))
+            doc["set"] = {k: v for (_, k), v in zip(names, setting)}
             doc["tree"] = ns.tree
             print(json.dumps(doc), flush=True)
     return 1 if failed else 0

@@ -438,6 +438,20 @@ def _primitives(jaxpr, seen):
     return seen
 
 
+def _shapes(jaxpr, seen: set):
+    """(primitive, shape) of every value `jaxpr` and the jaxprs under it
+    bind."""
+    for eqn in jaxpr.eqns:
+        seen.update((eqn.primitive.name, tuple(v.aval.shape))
+                    for v in eqn.outvars if hasattr(v.aval, "shape"))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _shapes(sub, seen)
+    return seen
+
+
 @pytest.mark.parametrize("kvq", [None, "int8"])
 def test_gqa_history_kernel_is_what_it_was_before_the_latent_one(kvq):
     """`ops/flash_prefill.py` gained a latent kernel beside `_hist_kernel`
@@ -700,10 +714,17 @@ def test_minicpm_sala_step_compiles_at_published_widths(
     ops/sparse_chunk.py), dense or past `dense_len`: the decode kernel
     stays the decode rows' alone (on PR 41's tree a sparse chunk's 1,024
     queries walked a list each through a second one, and a dense chunk
-    ran the latent kernel), and the program's temporaries are no larger
-    than that tree's (186,084,864 bytes beside one piece, 833,350,144
-    beside four: the [rows, 64]-page lists and 33 MB of XLA scores a piece
-    went)."""
+    ran the latent kernel). Since PR 46 the selection reads the
+    compressed keys out of the pool in place: the kernel
+    `paged_block_scores` (ops/block_scores.py) stands in the decode
+    program once and in a mixed program twice (the prompt's rows and the
+    decode rows'), no program traces a sort or a gathered copy of the
+    rows' compressed keys ([64, 1152, 128] bf16, 18.9 MB a layer), and
+    the temporaries fell with the float32 scores a piece held:
+    11,916,800 bytes beside one piece (186,084,864 on PR 45's tree),
+    163,373,568 beside four (833,350,144), 77,358,592 for the fused
+    decode program (77,313,536: its largest live set was never the
+    copy)."""
     adapter = get_model("minicpm-sala-9b-16l", dtype="bfloat16",
                         attention_impl="pallas")
     chip = SingleDeviceSharding(topo.devices[0])
@@ -750,14 +771,19 @@ def test_minicpm_sala_step_compiles_at_published_widths(
 
         args = rows_of(rows, t)
 
-    compiled = jax.jit(program, donate_argnums=(1,)).lower(
-        params, kv, *args).compile()
+    traced = jax.jit(program, donate_argnums=(1,)).trace(params, kv, *args)
+    shapes = _shapes(traced.jaxpr.jaxpr, set())
+    # the top 64 and the walk's list by counting: nothing sorts a row's
+    # blocks (the walk's work list sorts its 64 rows), and nothing holds
+    # a copy of a row's compressed keys
+    assert not any(p == "sort" and s[-1:] == (mp,) for p, s in shapes)
+    assert not any(s[-2:] == (mp * 4, 128) for _, s in shapes)
+    compiled = traced.lower().compile()
     mem = compiled.memory_analysis()
     pools = sum(np.prod(x.shape) * x.dtype.itemsize
                 for x in (kv.k, kv.v, kv.kc, kv.ssm))
     assert mem.alias_size_in_bytes >= pools  # every pool in place
-    assert mem.temp_size_in_bytes <= {0: 1.0e9, 1: 186_084_864,
-                                      4: 833_350_144}[b_pre]
+    assert mem.temp_size_in_bytes <= {0: 96e6, 1: 24e6, 4: 200e6}[b_pre]
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
     text = compiled.as_text()
     assert "ssm_decode_step" in text and "paged_decode_attention" in text
@@ -766,9 +792,11 @@ def test_minicpm_sala_step_compiles_at_published_widths(
         assert _kernel_calls(text, "sparse_chunk_attention") == 1
         assert _kernel_calls(text, "latent_prefill_attention") == 0
     assert _kernel_calls(text, "paged_decode_attention") == 1
-    # one body a kind: the walk, the page writer and the state kernel of a
-    # decode step; a chunk adds its kernel and its state rows
-    assert _mosaic_calls(compiled) >= (6 if b_pre else 3)
+    assert _kernel_calls(text, "paged_block_scores") == (2 if b_pre else 1)
+    # one body a kind: the selection's scores, the walk, the page writer
+    # and the state kernel of a decode step; a chunk adds its kernels and
+    # its state rows
+    assert _mosaic_calls(compiled) >= (8 if b_pre else 4)
 
 
 @pytest.mark.parametrize("rows,t,b_pre", [
