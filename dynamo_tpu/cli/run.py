@@ -951,7 +951,7 @@ def build_parser() -> argparse.ArgumentParser:
         "--no-fleet-telemetry", action="store_false",
         dest="fleet_telemetry", default=True,
         help="disable the live fleet telemetry plane (worker SLO "
-             "sketches, live MFU gauge, fleet-frame publishing; on by "
+             "sketches, live tokens/s gauge, fleet-frame publishing; on by "
              "default — host-side metrics only, the token path is "
              "identical either way; docs/observability.md)",
     )

@@ -183,7 +183,7 @@ class EngineConfig:
     enable_prefix_caching: bool = True
     #: live fleet telemetry (docs/observability.md "Fleet view & SLO
     #: accounting"): per-request TTFT/ITL/e2e quantile sketches + SLA
-    #: counters on the engine, the live MFU gauge, and the worker's
+    #: counters on the engine, the live tokens/s gauge, and the worker's
     #: fleet-frame publishing. Host-side metrics only — the token path
     #: is identical either way; off (`--no-fleet-telemetry`) skips the
     #: bookkeeping entirely (bench.py `slo_overhead` prices it <1%).

@@ -216,13 +216,8 @@ class EngineMetrics:
     #: small for this workload" signal
     kv_pages_watermark: int = 0
     preemptions: int = 0
-    #: live utilization over a sliding window (~10 s): token throughput
-    #: and the model-FLOPs utilization it implies against the chip's
-    #: roofline peak (2*active-params FLOPs/token / device_peak_flops —
-    #: same arithmetic as bench.py's headline MFU; docs/PERF.md maps it
-    #: to the measured decode roofline ceiling of ~0.43)
+    #: token throughput over a sliding window (~10 s)
     tokens_per_s: float = 0.0
-    mfu: float = 0.0
     #: overload-protection plane (docs/operations.md "Overload &
     #: draining"): requests refused at admission because the bounded
     #: waiting queue (EngineConfig.max_waiting) was full — climbing
@@ -236,15 +231,12 @@ class EngineMetrics:
     #: "Reading the perf plane"): byte rollups summed over this process's
     #: addressable devices. weights = the param trees' shard bytes,
     #: kv_pool = the paged KV pool (mirrors kv_pool_bytes but lives in
-    #: the hbm_* family the plane exposes), scratch = the largest
-    #: compiled program's cost_analysis bytes beyond resident weights+KV
-    #: (a transient-buffer ESTIMATE, documented in memory_report), free/
-    #: peak from jax device memory_stats on TPU with the accounted CPU
-    #: fallback. Refreshed by refresh_memory_metrics() on the publish
-    #: cadence — the token path never touches them.
+    #: the hbm_* family the plane exposes), free/peak from jax device
+    #: memory_stats on TPU with the accounted CPU fallback. Refreshed by
+    #: refresh_memory_metrics() on the publish cadence — the token path
+    #: never touches them.
     hbm_weights_bytes: int = 0
     hbm_kv_pool_bytes: int = 0
-    hbm_scratch_bytes: int = 0
     hbm_free_bytes: int = 0
     hbm_peak_bytes: int = 0
     #: mesh introspection plane (GET /v1/debug/mesh): this replica's
@@ -653,11 +645,10 @@ class JaxEngine:
         #: compile counter by program kind (prefill/decode/mixed/...) —
         #: published in the worker's fleet frame as per-kind labels
         self.compiles_by_kind: dict[str, int] = {}
-        #: per-program cost table (docs/observability.md "Debugging a
-        #: slow or stuck worker"): cache_key -> {kind, compile_ms,
-        #: flops, bytes} from the compiled program's cost_analysis();
-        #: programs_report() joins it with measured per-kind dispatch
-        #: time into roofline %-attainment (GET /v1/debug/programs)
+        #: the compile table (docs/observability.md "Debugging a slow or
+        #: stuck worker"): cache_key -> {kind, key, compile_ms}, one entry
+        #: a program's first call; programs_report() rolls it up by kind
+        #: (GET /v1/debug/programs)
         self.programs: dict[tuple, dict] = {}
         #: flight recorder (config.flight_recorder): bounded ring of
         #: per-step records appended at deque cost from step(); None
@@ -686,8 +677,9 @@ class JaxEngine:
 
         self.debug_name = _debug.register_engine(self)
         #: fleet telemetry plane (config.fleet_telemetry; mutable so the
-        #: bench A/B can toggle one warm engine): SLO sketches + the MFU
-        #: window. All host-side — the token path never reads them.
+        #: bench A/B can toggle one warm engine): SLO sketches + the
+        #: throughput window. All host-side — the token path never reads
+        #: them.
         self._fleet_telemetry = config.fleet_telemetry
         if self._fleet_telemetry:
             from dynamo_tpu.telemetry.slo import SloTracker
@@ -699,7 +691,7 @@ class JaxEngine:
         #: itl_samples, last_emit_perf_t]
         self._slo_marks: dict[str, list] = {}
         #: (perf_t, tokens_computed) per recent step, for the windowed
-        #: tokens/s + MFU gauges
+        #: tokens/s gauge
         from collections import deque
 
         self._thru_window: deque = deque()
@@ -807,14 +799,6 @@ class JaxEngine:
         self.kv = kv
         if self._spec_draft:
             self._init_draft_model(config, impl)
-        # Live-MFU constants: FLOPs/token follow the ACTIVE parameters
-        # (MoE: top_k of E experts — total params would overstate ~8x),
-        # against the chip's public peak (nominal off-TPU so the gauge
-        # stays a plausible (0,1] number on dev boxes).
-        from dynamo_tpu.platform import device_peak_flops
-
-        self._peak_flops = device_peak_flops()
-        self._n_active_params = self._active_param_count(params)
         # KV-pool byte gauges: actual device bytes (quantized pages +
         # scale planes) vs what the same pool costs at the model dtype —
         # the ~2x effective-capacity claim, measured not asserted.
@@ -1189,8 +1173,7 @@ class JaxEngine:
             self.metrics.steps += 1
             if self._fleet_telemetry:
                 # tokens this step pushed through the model (prefill
-                # chunk tokens + emitted decode tokens — a conservative
-                # undercount of forward-pass work, so MFU never flatters)
+                # chunk tokens + emitted decode tokens)
                 step_toks = sum(p.length for p in batch.prefill) + (
                     self.metrics.generated_tokens - gen0
                 )
@@ -3074,78 +3057,14 @@ class JaxEngine:
             & 0xFFFFFFFF
         )
 
-    def _active_param_count(self, params) -> int:
-        """Parameters active per token (MoE: routed-expert leaves scaled
-        by top_k/E) — the FLOPs/token basis of the live MFU gauge."""
-        n_params = sum(int(x.size) for x in jax.tree.leaves(params))
-        acfg = self.adapter.config
-        n_experts = getattr(acfg, "n_routed_experts", 0) or getattr(
-            acfg, "num_experts", 0
-        )
-        top_k = getattr(acfg, "num_experts_per_tok", None) or getattr(
-            acfg, "top_k", 0
-        )
-        if not (n_experts and top_k):
-            return n_params
-        expert_elems = sum(
-            int(leaf.size)
-            for path, leaf in jax.tree_util.tree_leaves_with_path(params)
-            if any(
-                getattr(k, "key", "").startswith("we_")
-                and not getattr(k, "key", "").endswith("_scale")
-                for k in path
-            )
-        )
-        return n_params - expert_elems + expert_elems * top_k // n_experts
-
-    @staticmethod
-    def _cost_scalars(cost) -> tuple[Optional[float], Optional[float]]:
-        """Normalize a cost_analysis() result — a dict, occasionally
-        None — into (flops, bytes_accessed)."""
-        if not isinstance(cost, dict):
-            return None, None
-
-        def pick(*keys):
-            for k in keys:
-                v = cost.get(k)
-                if isinstance(v, (int, float)) and v == v and v >= 0:
-                    return float(v)
-            return None
-
-        return pick("flops"), pick("bytes accessed", "bytes_accessed")
-
-    def _program_cost(self, jitted: Callable, args, kwargs):
-        """Trace+lower the jitted program (NO XLA compile — ~ms, vs the
-        compile's 10s of ms to seconds) and read the lowering's
-        cost_analysis() flops / bytes accessed: the cost-model numerator
-        of /v1/debug/programs' roofline attainment. Deliberately NOT
-        `.lower().compile()`: caching the AOT Compiled object would skip
-        jax's C++ jit fastpath on every steady-state dispatch (~6%
-        per-call measured), and the AOT executable cache is disjoint
-        from the traced path's, so it would also compile twice. Also
-        counts the Mosaic kernels in the lowered text (`tpu_custom_call`;
-        0 where the Pallas kernels run interpreted or the path is XLA's).
-        Returns (flops, bytes, mosaic_calls), all None on any refusal:
-        cost analysis varies by backend and the serving path must never
-        depend on it."""
-        try:
-            lowered = jitted.lower(*args, **kwargs)
-            cost = lowered.cost_analysis()
-            mosaic_calls = lowered.as_text().count("tpu_custom_call")
-        except Exception:
-            logger.debug("lowered cost_analysis unavailable", exc_info=True)
-            return None, None, None
-        return (*self._cost_scalars(cost), mosaic_calls)
-
     def _cache_jit(self, kind: str, cache_key, jitted: Callable) -> Callable:
         """Install a jitted program into the cache wrapped so its FIRST
-        invocation — where XLA actually compiles — is counted, timed
-        (dynamo_tpu_phase_compile_ms; wall time of compile+first run,
-        compile-dominated), spanned in the trace ring, and cost-modeled
-        (the lowering's cost_analysis flops/bytes land in self.programs
-        for GET /v1/debug/programs). The wrapper replaces itself with
-        the bare jitted fn after that one call, so the steady-state
-        dispatch path pays nothing."""
+        invocation — where jax traces and lowers it and XLA compiles —
+        is counted, timed (dynamo_tpu_phase_compile_ms; wall time of
+        that plus the first run), spanned in the trace ring and listed
+        in self.programs for GET /v1/debug/programs. The wrapper
+        replaces itself with the bare jitted fn after that one call, so
+        the steady-state dispatch path pays nothing."""
 
         def first_call(*args, **kwargs):
             ms0 = self.metrics.compile_ms
@@ -3156,9 +3075,6 @@ class JaxEngine:
                 "engine.compile", service="engine",
                 attrs={"kind": kind, "key": str(cache_key)},
             ):
-                flops, nbytes, mosaic_calls = self._program_cost(
-                    jitted, args, kwargs
-                )
                 out = jitted(*args, **kwargs)
             dt_ms = self.metrics.compile_ms - ms0
             self.metrics.compiles += 1
@@ -3171,9 +3087,6 @@ class JaxEngine:
                 "kind": kind,
                 "key": str(cache_key),
                 "compile_ms": round(dt_ms, 3),
-                "flops": flops,
-                "bytes": nbytes,
-                "mosaic_calls": mosaic_calls,
             }
             return out
 
@@ -4394,7 +4307,7 @@ class JaxEngine:
             self.scheduler.deadline_drops + self._runner_deadline_expired
         )
         if self._fleet_telemetry:
-            # windowed throughput -> live MFU against the roofline peak
+            # windowed throughput
             now = time.perf_counter()
             w = self._thru_window
             while w and now - w[0][0] > self._thru_window_s:
@@ -4403,111 +4316,35 @@ class JaxEngine:
                 span = now - w[0][0]
                 toks = self._thru_tokens
                 if span > 1e-3 and toks:
-                    rate = toks / span
-                    m.tokens_per_s = round(rate, 2)
-                    m.mfu = min(
-                        1.0,
-                        2.0 * self._n_active_params * rate
-                        / self._peak_flops,
-                    )
+                    m.tokens_per_s = round(toks / span, 2)
             else:
                 # window drained: an idle worker must report zero, not
                 # its last busy throughput forever
                 m.tokens_per_s = 0.0
-                m.mfu = 0.0
 
-    # -- debug plane: program cost model + on-demand profiling ------------
+    # -- debug plane: the compile table + on-demand profiling -------------
     # (docs/observability.md "Debugging a slow or stuck worker")
 
-    #: program kind -> the (cumulative ms, dispatch count) metrics pair
-    #: whose ratio is that kind's measured ms/dispatch. Decode-family
-    #: kinds share the decode columns; mixed steps land in time_mixed_ms.
-    _MEASURED_BY_KIND = {
-        "prefill": ("time_prefill_ms", "prefill_dispatches"),
-        "prefill_nosample": ("time_prefill_ms", "prefill_dispatches"),
-        "decode": ("time_decode_ms", "decode_dispatches"),
-        "decode_multi": ("time_decode_ms", "decode_dispatches"),
-        "spec_verify": ("time_decode_ms", "decode_dispatches"),
-        "spec_fused": ("time_decode_ms", "decode_dispatches"),
-        "spec_draft_prefill": ("time_prefill_ms", "prefill_dispatches"),
-        "mixed": ("time_mixed_ms", "mixed_dispatches"),
-    }
-
-    @staticmethod
-    def _roofline_ms(
-        flops: Optional[float], nbytes: Optional[float],
-        peak_flops: float, peak_bytes_s: float,
-    ) -> Optional[float]:
-        """Cost-model floor for one dispatch: the slower of the compute
-        roof (flops / peak FLOP/s) and the memory roof (bytes accessed /
-        peak HBM bytes/s) — the same arithmetic as docs/PERF.md's
-        decode-roofline table, per compiled program."""
-        t = 0.0
-        if flops and peak_flops:
-            t = max(t, flops / peak_flops)
-        if nbytes and peak_bytes_s:
-            t = max(t, nbytes / peak_bytes_s)
-        return round(t * 1e3, 6) if t > 0 else None
-
     def programs_report(self) -> dict:
-        """GET /v1/debug/programs: every compiled program's cost model
-        (compile ms, cost_analysis flops/bytes, roofline ms) plus a
-        per-kind rollup joining the kind's production-shape program (its
-        most expensive one — smaller warmup buckets would flatter the
-        number) with the measured ms/dispatch from the step-phase
-        counters into roofline %-attainment. Note the measured column is
-        host wall time per dispatch — under overlap_decode it contains
-        host-loop overhead the roofline doesn't, which is exactly the
-        gap ROADMAP item 3 (on-device multi-step scheduling) attacks."""
-        from dynamo_tpu.platform import device_peak_bytes_per_s
-
-        peak_f = self._peak_flops
-        peak_b = device_peak_bytes_per_s()
-        m = self.metrics
-        programs: list[dict] = []
-        kinds: dict[str, dict] = {}
+        """GET /v1/debug/programs: every program this engine has loaded
+        (kind, key, first-call ms) plus a per-kind rollup: how many
+        programs, how many compiles, their summed first-call ms. A kind
+        whose `compiles` climbs in steady state is the program family
+        churning (doctor's compile-storm points here)."""
         # list() first: the engine thread inserts on steady-state
         # recompiles (the compile-storm case this report diagnoses)
         # while the publish loop / debug endpoints iterate here
-        for p in list(self.programs.values()):
-            rl = self._roofline_ms(p["flops"], p["bytes"], peak_f, peak_b)
-            programs.append(dict(p, roofline_ms=rl))
+        programs = [dict(p) for p in list(self.programs.values())]
+        kinds: dict[str, dict] = {}
+        for p in programs:
             k = kinds.setdefault(
                 p["kind"],
-                {"programs": 0, "compile_ms": 0.0, "flops": None,
-                 "bytes": None, "roofline_ms": None},
+                {"programs": 0, "compile_ms": 0.0,
+                 "compiles": self.compiles_by_kind.get(p["kind"], 0)},
             )
             k["programs"] += 1
             k["compile_ms"] = round(k["compile_ms"] + p["compile_ms"], 3)
-            if p["flops"] is not None and (
-                k["flops"] is None or p["flops"] > k["flops"]
-            ):
-                k["flops"], k["bytes"], k["roofline_ms"] = (
-                    p["flops"], p["bytes"], rl
-                )
-        for kind, k in kinds.items():
-            k["compiles"] = self.compiles_by_kind.get(kind, 0)
-            pair = self._MEASURED_BY_KIND.get(kind)
-            measured = None
-            if pair is not None:
-                total_ms, disp = getattr(m, pair[0]), getattr(m, pair[1])
-                if disp:
-                    measured = round(total_ms / disp, 3)
-            k["measured_ms_per_dispatch"] = measured
-            # 6 digits: tiny CPU-dev attainments (roofline µs vs a
-            # compile-laden first dispatch's 100s of ms) must not round
-            # to an indistinguishable 0.0
-            k["attainment"] = (
-                round(min(1.0, k["roofline_ms"] / measured), 6)
-                if k["roofline_ms"] and measured
-                else None
-            )
-        return {
-            "peak_flops": peak_f,
-            "peak_bytes_per_s": peak_b,
-            "programs": programs,
-            "kinds": kinds,
-        }
+        return {"programs": programs, "kinds": kinds}
 
     def programs_wire(self) -> dict:
         """The compact per-kind rollup that rides the metrics frame."""
@@ -4592,18 +4429,13 @@ class JaxEngine:
 
         Accounted components: `weights` (param-tree shard bytes, cached
         at construction — they never change), `kv_pool` (paged KV +
-        draft KV incl. quantization scale planes), `scratch` — an
-        ESTIMATE: the hungriest compiled program's cost_analysis bytes
-        accessed beyond the resident weights+KV it streams (the
-        transient-buffer proxy PR 7's cost capture affords; XLA exposes
-        no true temp-allocation number pre-execution), split evenly
-        across local devices. live/free/peak come from jax device
-        `memory_stats()` where the backend provides them (TPU); the
-        documented CPU fallback is pure accounting — live =
-        weights+kv+scratch, free = platform.device_hbm_bytes() − live
-        (the shared per-generation table, same sourcing as the program
-        cost model's peaks), peak = live. `source` names which path
-        produced the live numbers."""
+        draft KV incl. quantization scale planes) and `state_pool`.
+        live/free/peak come from jax device `memory_stats()` where the
+        backend provides them (TPU); the documented CPU fallback is
+        pure accounting — live = weights + pools, free =
+        platform.device_hbm_bytes() − live (the per-generation table),
+        peak = live. `source` names which path produced the live
+        numbers."""
         from dynamo_tpu.platform import device_hbm_bytes
 
         state_by_dev = self._per_device_bytes(self._state_pools(self.kv))
@@ -4614,21 +4446,10 @@ class JaxEngine:
             ).items()
         }
         weights = self._weights_by_device
-        total_w = sum(weights.values())
-        total_kv = sum(kv_by_dev.values()) + sum(state_by_dev.values())
-        prog_bytes = [
-            p["bytes"] for p in list(self.programs.values())
-            if p.get("bytes")
-        ]
-        scratch_total = max(
-            0, int(max(prog_bytes, default=0)) - total_w - total_kv
-        )
-        devs = jax.local_devices()
-        scratch_each = scratch_total // max(1, len(devs))
         limit_nominal = int(device_hbm_bytes())
         devices: dict[str, dict] = {}
         source = "accounted"
-        for d in devs:
+        for d in jax.local_devices():
             key = self._device_key(d)
             w = int(weights.get(key, 0))
             kvb = int(kv_by_dev.get(key, 0))
@@ -4637,7 +4458,6 @@ class JaxEngine:
                 "weights_bytes": w,
                 "kv_pool_bytes": kvb,
                 "state_pool_bytes": int(state_by_dev.get(key, 0)),
-                "scratch_bytes": scratch_each,
             }
             try:
                 stats = d.memory_stats()
@@ -4654,7 +4474,7 @@ class JaxEngine:
                     stats.get("peak_bytes_in_use") or live
                 )
             else:
-                live = w + kvb + row["state_pool_bytes"] + scratch_each
+                live = w + kvb + row["state_pool_bytes"]
                 row["live_bytes"] = live
                 row["limit_bytes"] = limit_nominal
                 row["free_bytes"] = max(0, limit_nominal - live)
@@ -4664,7 +4484,7 @@ class JaxEngine:
             f: sum(r[f] for r in devices.values())
             for f in (
                 "weights_bytes", "kv_pool_bytes", "state_pool_bytes",
-                "scratch_bytes", "live_bytes", "free_bytes", "peak_bytes",
+                "live_bytes", "free_bytes", "peak_bytes",
             )
         }
         return {"source": source, "devices": devices, "totals": totals}
@@ -4679,7 +4499,6 @@ class JaxEngine:
         m = self.metrics
         m.hbm_weights_bytes = t["weights_bytes"]
         m.hbm_kv_pool_bytes = t["kv_pool_bytes"]
-        m.hbm_scratch_bytes = t["scratch_bytes"]
         m.hbm_free_bytes = t["free_bytes"]
         m.hbm_peak_bytes = t["peak_bytes"]
         try:

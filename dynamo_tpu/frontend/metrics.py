@@ -232,7 +232,7 @@ class FrontendMetrics:
         # serving — docs/operations.md "KV index consistency"
         lines.extend(_debug.kv_index_lines())
         # HBM accounting plane (docs/observability.md "Reading the perf
-        # plane"): per-device weights/kv_pool/scratch/free/peak bytes of
+        # plane"): per-device weights/kv_pool/free/peak bytes of
         # the in-process engines
         lines.extend(_debug.hbm_lines())
         text = "\n".join(lines) + "\n"

@@ -139,13 +139,12 @@ _WORKER_FIELDS = (
     ("ext_consecutive_failures", "gauge"),
     # engine-internals plane (fleet telemetry): jit-cache misses + their
     # cumulative wall cost, page-pool pressure (high-watermark +
-    # preemption-by-recompute), and the live utilization gauges
+    # preemption-by-recompute), and the live throughput gauge
     ("compiles", "counter"),
     ("compile_ms", "counter"),
     ("kv_pages_watermark", "gauge"),
     ("preemptions", "counter"),
     ("tokens_per_s", "gauge"),
-    ("mfu", "gauge"),
     # stall watchdog (telemetry/watchdog.py): stalls diagnosed on this
     # worker — climbing means streams are wedging (the per-cause split
     # is in the worker's own dynamo_tpu_stalls_total{cause} and in the
@@ -195,13 +194,12 @@ _WORKER_FIELDS = (
     ("kvbm_disk_hits_total", "counter"),
     # HBM accounting plane (docs/observability.md "Reading the perf
     # plane"): per-worker byte totals summed over the worker's local
-    # devices — weights (param-tree shards), KV pool, compiled-program
-    # scratch estimate, free and peak. On CPU the engine falls back to
-    # accounted sums (source="accounted" in the /v1/debug/memory doc);
-    # the per-device split rides the frames' "memory" report
+    # devices — weights (param-tree shards), KV pool, free and peak.
+    # On CPU the engine falls back to accounted sums
+    # (source="accounted" in the /v1/debug/memory doc); the per-device
+    # split rides the frames' "memory" report
     ("hbm_weights_bytes", "gauge"),
     ("hbm_kv_pool_bytes", "gauge"),
-    ("hbm_scratch_bytes", "gauge"),
     ("hbm_free_bytes", "gauge"),
     ("hbm_peak_bytes", "gauge"),
     # multi-host SPMD introspection: jax.process_index() of the worker
@@ -217,7 +215,7 @@ _FLEET_WORKER_FIELDS = (
     "kv_usage", "kv_free_pages", "kv_active_pages", "kv_total_pages",
     "kv_pages_watermark", "preemptions", "num_running", "num_waiting",
     "steps", "generated_tokens", "requests_received", "compiles",
-    "compile_ms", "tokens_per_s", "mfu", "prefix_hit_rate",
+    "compile_ms", "tokens_per_s", "prefix_hit_rate",
     "stalls_total", "overload_rejects", "deadline_expired", "flips_total",
     "spec_drafted", "spec_accepted", "spec_skipped_ineligible",
     "spec_skipped_cooldown", "spec_accept_rate", "spec_window_drafted",
@@ -231,8 +229,8 @@ _FLEET_WORKER_FIELDS = (
     "kvbm_host_blocks", "kvbm_disk_blocks", "kvbm_demotions_total",
     "kvbm_promotions_total", "kvbm_host_hits_total",
     "kvbm_disk_hits_total",
-    "hbm_weights_bytes", "hbm_kv_pool_bytes", "hbm_scratch_bytes",
-    "hbm_free_bytes", "hbm_peak_bytes", "host", "dispatch_p95_ms",
+    "hbm_weights_bytes", "hbm_kv_pool_bytes", "hbm_free_bytes",
+    "hbm_peak_bytes", "host", "dispatch_p95_ms",
 )
 
 
@@ -859,7 +857,7 @@ class MetricsService:
                     }
                 st = role_stats.setdefault(
                     role,
-                    {"workers": 0, "kv_usage": [], "mfu": [],
+                    {"workers": 0, "kv_usage": [],
                      "tokens_per_s": 0.0, "preemptions": 0,
                      "spec_drafted": 0, "spec_accepted": 0,
                      "spec_rate_num": 0.0, "spec_rate_den": 0,
@@ -868,8 +866,6 @@ class MetricsService:
                 st["workers"] += 1
                 if "kv_usage" in w:
                     st["kv_usage"].append(float(w["kv_usage"]))
-                if "mfu" in w:
-                    st["mfu"].append(float(w["mfu"]))
                 st["tokens_per_s"] += float(w.get("tokens_per_s", 0.0))
                 st["preemptions"] += int(w.get("preemptions", 0))
                 st["spec_drafted"] += int(w.get("spec_drafted", 0))
@@ -947,11 +943,6 @@ class MetricsService:
                 "kv_usage": (
                     round(sum(st["kv_usage"]) / len(st["kv_usage"]), 4)
                     if st["kv_usage"]
-                    else None
-                ),
-                "mfu": (
-                    round(sum(st["mfu"]) / len(st["mfu"]), 6)
-                    if st["mfu"]
                     else None
                 ),
                 "tokens_per_s": round(st["tokens_per_s"], 2),
@@ -1180,11 +1171,6 @@ class MetricsService:
                  lambda role, st: (
                      sum(st["kv_usage"]) / len(st["kv_usage"])
                      if st["kv_usage"] else None
-                 )),
-                ("mfu", "gauge",
-                 lambda role, st: (
-                     sum(st["mfu"]) / len(st["mfu"])
-                     if st["mfu"] else None
                  )),
                 ("tokens_per_s", "gauge",
                  lambda role, st: st["tokens_per_s"]),
@@ -1502,7 +1488,7 @@ class MetricsService:
 
     async def _debug_memory(self, request: web.Request) -> web.Response:
         """Fleet-wide HBM accounting: each worker's per-device
-        weights/kv_pool/scratch/free/peak byte breakdown, as published
+        weights/kv_pool/free/peak byte breakdown, as published
         in its frames (engine.memory_report())."""
         workers = {}
         for iid, (m, age, comp) in sorted(self._snapshot_all().items()):

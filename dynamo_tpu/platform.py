@@ -68,83 +68,45 @@ def enable_persistent_compile_cache() -> str:
     return jax.config.jax_compilation_cache_dir
 
 
-#: per-generation public chip numbers, ONE table for every consumer:
-#: device_kind substring tag -> (bf16 peak FLOP/s, peak HBM bytes/s,
-#: HBM capacity bytes per chip). The MFU gauge, the program cost model
-#: (/v1/debug/programs) and the HBM accounting plane (/v1/debug/memory)
-#: all resolve through _device_peaks() so their denominators can never
-#: disagree (they used to live as two drifting copies below). Order
+#: HBM capacity bytes per chip by TPU generation (public numbers):
+#: device_kind substring tag -> capacity. The chip's peaks (FLOP/s,
+#: bytes/s) live with the benchmark, in chipbench/peaks.json. Order
 #: matters: longer/more-specific tags first ("v5e" before "v5lite"
 #: would both miss "v5 lite" after the space strip — keep both).
-_TPU_GENERATIONS = (
-    ("v6e", (918e12, 1640e9, 32e9)),
-    ("v6", (918e12, 1640e9, 32e9)),
-    ("v5p", (459e12, 2765e9, 95e9)),
-    ("v5e", (197e12, 819e9, 16e9)),
-    ("v5lite", (197e12, 819e9, 16e9)),
-    ("v4", (275e12, 1228e9, 32e9)),
+_TPU_HBM_BYTES = (
+    ("v6e", 32e9),
+    ("v6", 32e9),
+    ("v5p", 95e9),
+    ("v5e", 16e9),
+    ("v5lite", 16e9),
+    ("v4", 32e9),
 )
-
-#: column indexes into the _TPU_GENERATIONS rows + their env overrides
-#: and nominal CPU-dev fallbacks (documented in each public accessor)
-_PEAK_COLUMNS = {
-    "flops": (0, "DYNTPU_PEAK_FLOPS", 1e12),
-    "bytes_per_s": (1, "DYNTPU_PEAK_BYTES", 1e11),
-    "hbm_bytes": (2, "DYNTPU_HBM_BYTES", 16e9),
-}
-
-
-def _device_peaks(column: str) -> float:
-    """Resolve one peak column for the attached accelerator: the TPU
-    generation table on TPU (a kind the table does not know is an
-    error), the column's env override elsewhere, else its nominal
-    CPU-dev fallback."""
-    idx, env_var, nominal = _PEAK_COLUMNS[column]
-    import jax
-
-    if jax.default_backend() == "tpu":
-        kind = jax.devices()[0].device_kind
-        tag_of = kind.lower().replace(" ", "")
-        for tag, peaks in _TPU_GENERATIONS:
-            if tag in tag_of:
-                return peaks[idx]
-        raise ValueError(
-            f"TPU device_kind {kind!r} matches no row of "
-            "dynamo_tpu.platform._TPU_GENERATIONS; add its public peaks"
-        )
-    try:
-        env = float(os.environ.get(env_var, "") or 0.0)
-        if env > 0:
-            return env
-    except ValueError:
-        pass
-    return nominal
-
-
-def device_peak_flops() -> float:
-    """Per-chip peak FLOP/s for the attached accelerator — the
-    denominator of the live MFU gauge (docs/PERF.md "Live MFU gauge").
-    TPU generations resolve to their public bf16 peaks; off-TPU the
-    fallback comes from DYNTPU_PEAK_FLOPS (else a nominal 1e12 so the
-    gauge stays a plausible (0,1] number on CPU dev boxes instead of
-    vanishing)."""
-    return _device_peaks("flops")
-
-
-def device_peak_bytes_per_s() -> float:
-    """Per-chip peak HBM bandwidth — the memory roof of the per-program
-    cost model (engine.programs_report / GET /v1/debug/programs). TPU
-    generations resolve to their public HBM numbers; off-TPU the
-    fallback comes from DYNTPU_PEAK_BYTES (else a nominal 1e11 so
-    attainment stays a plausible fraction on CPU dev boxes)."""
-    return _device_peaks("bytes_per_s")
 
 
 def device_hbm_bytes() -> float:
     """Per-chip HBM capacity — the `free` denominator of the HBM
     accounting plane (engine.memory_report / GET /v1/debug/memory) when
     the backend exposes no memory_stats (the documented CPU fallback).
-    TPU generations resolve to their public capacities; off-TPU the
-    fallback comes from DYNTPU_HBM_BYTES (else a nominal 16e9, the v5e
-    capacity, so free/peak stay plausible on CPU dev boxes)."""
-    return _device_peaks("hbm_bytes")
+    TPU generations resolve to their public capacities (a kind the
+    table does not know is an error); off-TPU the fallback comes from
+    DYNTPU_HBM_BYTES (else a nominal 16e9, the v5e capacity, so
+    free/peak stay plausible on CPU dev boxes)."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        kind = jax.devices()[0].device_kind
+        tag_of = kind.lower().replace(" ", "")
+        for tag, capacity in _TPU_HBM_BYTES:
+            if tag in tag_of:
+                return capacity
+        raise ValueError(
+            f"TPU device_kind {kind!r} matches no row of "
+            "dynamo_tpu.platform._TPU_HBM_BYTES; add its public capacity"
+        )
+    try:
+        env = float(os.environ.get("DYNTPU_HBM_BYTES", "") or 0.0)
+        if env > 0:
+            return env
+    except ValueError:
+        pass
+    return 16e9

@@ -6,11 +6,11 @@ in single-process serving, the metrics service for its own process) can
 serve:
 
   GET  /v1/debug/flight[?n=]   the flight-recorder window per engine
-  GET  /v1/debug/programs      per-program cost-model attainment
-                               (compile cost, cost_analysis flops/bytes,
-                               measured ms/dispatch vs roofline)
+  GET  /v1/debug/programs      the compile table: every loaded program
+                               (kind, key, first-call ms) and a rollup
+                               by kind
   GET  /v1/debug/memory        per-device HBM byte breakdown (weights /
-                               kv_pool / scratch / live / free / peak —
+                               kv_pool / live / free / peak —
                                engine.memory_report, docs/
                                observability.md "Reading the perf
                                plane")
@@ -213,14 +213,14 @@ def kv_index_lines(prefix: str = "dynamo_tpu") -> list[str]:
 
 #: the hbm_* family names in exposition order — one list shared by the
 #: emitter below, the memory-report totals, and the tests that pin them
-HBM_COMPONENTS = ("weights", "kv_pool", "scratch", "free", "peak")
+HBM_COMPONENTS = ("weights", "kv_pool", "free", "peak")
 
 
 def hbm_lines(prefix: str = "dynamo_tpu") -> list[str]:
     """Process-global HBM accounting exposition, per DEVICE, from the
     registered in-process engines' memory_report (docs/observability.md
-    "Reading the perf plane"): `{prefix}_hbm_{weights,kv_pool,scratch,
-    free,peak}_bytes{device=...}`. Included by BOTH Prometheus surfaces
+    "Reading the perf plane"): `{prefix}_hbm_{weights,kv_pool,free,
+    peak}_bytes{device=...}`. Included by BOTH Prometheus surfaces
     like spec_lines; the per-WORKER fleet rollup rides the metrics
     frames as `{prefix}_worker_hbm_*` instead. Always emitted (a zeroed
     device="0" series when no engine lives here) so dashboards and the
@@ -281,7 +281,7 @@ def flight_payload(n_str: Optional[str]) -> tuple[dict, int]:
 
 
 def programs_payload() -> tuple[dict, int]:
-    """GET /v1/debug/programs -> per-engine program cost tables."""
+    """GET /v1/debug/programs -> per-engine compile tables."""
     engines = {}
     for name, eng in sorted(registered_engines().items()):
         report = getattr(eng, "programs_report", None)

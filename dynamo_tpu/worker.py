@@ -1856,8 +1856,8 @@ class Worker:
                         md = eng.metrics
                         for f in (
                             "hbm_weights_bytes", "hbm_kv_pool_bytes",
-                            "hbm_scratch_bytes", "hbm_free_bytes",
-                            "hbm_peak_bytes", "host", "dispatch_p95_ms",
+                            "hbm_free_bytes", "hbm_peak_bytes", "host",
+                            "dispatch_p95_ms",
                         ):
                             m[f] = getattr(md, f)
                         m["mesh"] = eng.mesh_report()

@@ -1,9 +1,9 @@
 #!/usr/bin/env python3
 """doctor: one-shot rule-based diagnosis of a dynamo-tpu fleet.
 
-Snapshots the metrics service's `/v1/fleet`, `/v1/debug/flight` and
-`/v1/debug/programs`, runs the rule set below over them, and prints one
-human-readable report — the "why is this worker slow/stuck" companion
+Snapshots the metrics service's `/v1/fleet` and `/v1/debug/flight`,
+runs the rule set below over them, and prints one human-readable
+report — the "why is this worker slow/stuck" companion
 to fleet_top's "what are the numbers" view:
 
     python scripts/doctor.py --url http://127.0.0.1:9091
@@ -76,10 +76,6 @@ Rules (each emits severity + worker + evidence + suggested action):
                        N+ consecutive ticks while the fleet still burns
                        its SLO budget — scaling is out of headroom; the
                        fix is capacity or shedding, not the loop
-  low-attainment       a program kind's measured ms/dispatch sits far
-                       off its cost-model roofline (GET /v1/debug/
-                       programs) — host-loop overhead, not the chip, is
-                       the limit (ROADMAP item 3)
   slow-trace-          the N worst KEPT traces (metrics service
   attribution          GET /v1/traces?sort=duration — the tail sampler
                        keeps every anomalous trace) are dominated by an
@@ -123,8 +119,6 @@ SKEW_FRACTION = 0.25
 COMPILE_STORM_FRACTION = 0.3
 #: free pages at or below this fraction of total = exhausted
 POOL_FREE_FRACTION = 0.02
-#: decode-attainment below this = the host loop, not the chip, rules
-ATTAINMENT_FLOOR = 0.05
 #: waiting queue deeper than max(this, 4x running) while the role burns
 #: its SLO budget = saturated with no admission caps
 QUEUE_DEPTH_FLOOR = 8
@@ -232,12 +226,11 @@ def _flight_records(flight: dict, iid: str) -> list[dict]:
 def diagnose(
     fleet: dict,
     flight: Optional[dict] = None,
-    programs: Optional[dict] = None,
     traces: Optional[dict] = None,
     ledger: Optional[list] = None,
 ) -> list[dict]:
-    """Pure rule pass: (/v1/fleet, /v1/debug/flight, /v1/debug/programs,
-    /v1/traces) snapshots [+ perf-ledger rows] -> ordered findings
+    """Pure rule pass: (/v1/fleet, /v1/debug/flight, /v1/traces)
+    snapshots [+ perf-ledger rows] -> ordered findings
     (severity: critical > warning > info)."""
     findings: list[dict] = []
     workers = (fleet or {}).get("workers") or {}
@@ -588,29 +581,6 @@ def diagnose(
     findings.extend(_trace_rules(traces, workers))
     findings.extend(_host_skew_rules(workers))
     findings.extend(_perf_regression_rules(ledger))
-
-    for iid, p in sorted(((programs or {}).get("workers") or {}).items()):
-        for kind, k in sorted((p.get("kinds") or {}).items()):
-            att = k.get("attainment")
-            if att is not None and att < ATTAINMENT_FLOOR and kind in (
-                "decode", "decode_multi", "mixed"
-            ):
-                findings.append(_finding(
-                    "info", "low-attainment", iid,
-                    f"{iid}: {kind} runs at {att * 100:.2f}% of its "
-                    "cost-model roofline "
-                    f"({k.get('measured_ms_per_dispatch')}ms measured vs "
-                    f"{k.get('roofline_ms')}ms roofline)",
-                    {"kind": kind, **{
-                        f: k.get(f) for f in (
-                            "attainment", "measured_ms_per_dispatch",
-                            "roofline_ms", "flops", "bytes",
-                        )
-                    }},
-                    "the host loop, not the chip, is the limit — see "
-                    "docs/PERF.md (decode roofline) and ROADMAP item 3 "
-                    "(on-device multi-step scheduling)",
-                ))
 
     order = {"critical": 0, "warning": 1, "info": 2}
     findings.sort(key=lambda f: (order.get(f["severity"], 9), str(f["worker"])))
@@ -1054,10 +1024,6 @@ def main(argv=None) -> int:
         help="recorded /v1/debug/flight JSON file instead of fetching",
     )
     ap.add_argument(
-        "--programs", default=None,
-        help="recorded /v1/debug/programs JSON file instead of fetching",
-    )
-    ap.add_argument(
         "--traces", default=None,
         help="recorded /v1/traces JSON file instead of fetching",
     )
@@ -1082,10 +1048,6 @@ def main(argv=None) -> int:
     flight = (
         load(args.flight) if args.flight
         else (_fetch(args.url, "/v1/debug/flight") if not args.snapshot else {})
-    )
-    programs = (
-        load(args.programs) if args.programs
-        else (_fetch(args.url, "/v1/debug/programs") if not args.snapshot else {})
     )
     traces = (
         load(args.traces) if args.traces
@@ -1112,9 +1074,7 @@ def main(argv=None) -> int:
             except OSError as e:
                 print(f"ledger {args.ledger} unreadable: {e}",
                       file=sys.stderr)
-    findings = diagnose(
-        fleet, flight or {}, programs or {}, traces or {}, ledger_rows
-    )
+    findings = diagnose(fleet, flight or {}, traces or {}, ledger_rows)
     if args.json:
         print(json.dumps(findings, indent=2))
     else:

@@ -11,7 +11,7 @@ who want the fleet at a glance without Grafana:
     python scripts/fleet_top.py --events --watch 2  # tail it
 
 Per worker: role, model, req/s, tok/s, TTFT/ITL p50/p95, KV-pool %,
-live MFU, jit compiles, stall count (dynamo_tpu_stalls_total, via the
+jit compiles, stall count (dynamo_tpu_stalls_total, via the
 worker frames' stalls_total), KVBM tier residency + hit split
 (TIER/HIT — docs/operations.md "The KV economy"), HBM byte breakdown
 (HBM w/kv/free — the worker frames' hbm_*_bytes gauges, summed over
@@ -95,7 +95,7 @@ def render(snap: dict, traces=None) -> str:
     cols = (
         ("WORKER", 22), ("ROLE", 8), ("MODEL", 12), ("REQ/S", 7),
         ("TOK/S", 8), ("TTFT p50/p95", 14), ("ITL p50/p95", 12),
-        ("KV%", 6), ("WM", 6), ("MFU", 7), ("COMP", 5), ("PREEMPT", 7),
+        ("KV%", 6), ("WM", 6), ("COMP", 5), ("PREEMPT", 7),
         ("SPEC%", 6), ("TIER/HIT", 12), ("HBM w/kv/free", 15),
         ("STALLS", 6), ("BURN", 6),
         ("WORST-TRACE", 16), ("AGE s", 6),
@@ -116,7 +116,7 @@ def render(snap: dict, traces=None) -> str:
             f"{_fmt(_pct(slo, 'itl_ms', 'p95'), 0)}",
             _fmt(kv * 100.0 if kv is not None else None, 0),
             _fmt(w.get("kv_pages_watermark"), 0),
-            _fmt(w.get("mfu"), 4), _fmt(w.get("compiles"), 0),
+            _fmt(w.get("compiles"), 0),
             _fmt(w.get("preemptions"), 0),
             # live draft-acceptance rate (speculative decoding), keyed
             # on the windowed draft count so the three states read
@@ -203,7 +203,6 @@ def render(snap: dict, traces=None) -> str:
         out.append(
             f"  {role:<6} {r.get('workers', 0)} workers  "
             f"tok/s {_fmt(r.get('tokens_per_s'))}  "
-            f"mfu {_fmt(r.get('mfu'), 4)}  "
             f"kv {_fmt((r.get('kv_usage') or 0) * 100, 0)}%  "
             f"compiles {sum((r.get('compiles_by_kind') or {}).values())}"
         )

@@ -97,7 +97,7 @@ def roofline(eng, batch: int) -> dict:
     return {
         "weight_bytes": weight_bytes,
         "kv_read_bytes_per_step": int(kv_read),
-        "roofline_ms_per_step": round(1000 * total / (HBM_GB_S * 1e9), 3),
+        "hbm_floor_ms_per_step": round(1000 * total / (HBM_GB_S * 1e9), 3),
     }
 
 

@@ -63,7 +63,7 @@ def test_compile_cache_dir_is_fixed_in_the_checkout_otherwise(
     assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.2
 
 
-# -- device peaks -------------------------------------------------------------
+# -- device capacity -----------------------------------------------------------
 
 
 class _Dev:
@@ -79,8 +79,6 @@ def test_device_peaks_resolves_v5e_kind_strings(monkeypatch, kind):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "devices", lambda *a: [_Dev(kind)])
-    assert platform.device_peak_flops() == 197e12
-    assert platform.device_peak_bytes_per_s() == 819e9
     assert platform.device_hbm_bytes() == 16e9
 
 
@@ -93,9 +91,9 @@ def test_device_peaks_raises_for_a_tpu_kind_the_table_does_not_know(
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("TPU v9 mega")])
-    monkeypatch.setenv("DYNTPU_PEAK_FLOPS", "5e12")  # no way around it
+    monkeypatch.setenv("DYNTPU_HBM_BYTES", "5e9")  # no way around it
     with pytest.raises(ValueError, match="TPU v9 mega"):
-        platform.device_peak_flops()
+        platform.device_hbm_bytes()
 
 
 def test_require_platform_refuses_a_cpu_it_was_not_asked_for(monkeypatch):

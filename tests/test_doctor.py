@@ -1,5 +1,5 @@
 """scripts/doctor.py: rule-based fleet diagnosis over recorded
-/v1/fleet + /v1/debug/{flight,programs} snapshots (pure `diagnose()`),
+/v1/fleet + /v1/debug/flight snapshots (pure `diagnose()`),
 the text report, and the offline CLI path."""
 
 import importlib.util
@@ -127,24 +127,10 @@ FLIGHT = {
     },
 }
 
-PROGRAMS = {
-    "workers": {
-        "w-slow": {
-            "kinds": {
-                "decode_multi": {
-                    "attainment": 0.002, "roofline_ms": 0.01,
-                    "measured_ms_per_dispatch": 5.0,
-                    "flops": 1e6, "bytes": 1e6,
-                },
-            },
-        },
-    },
-}
-
 
 def test_rules_fire_on_the_recorded_fleet():
     doctor = _load_doctor()
-    findings = doctor.diagnose(FLEET, FLIGHT, PROGRAMS)
+    findings = doctor.diagnose(FLEET, FLIGHT)
     by_rule = {}
     for f in findings:
         by_rule.setdefault(f["rule"], []).append(f)
@@ -158,7 +144,7 @@ def test_rules_fire_on_the_recorded_fleet():
     assert [f["worker"] for f in by_rule["decode-stall"]] == ["w-xor"]
     assert [f["worker"] for f in by_rule["skewed-worker"]] == ["w-slow"]
     assert [f["evidence"]["role"] for f in by_rule["sla-burn"]] == ["decode"]
-    assert [f["worker"] for f in by_rule["low-attainment"]] == ["w-slow"]
+    assert "low-attainment" not in by_rule
     # overload fires in BOTH directions with opposite prescriptions
     overload = {f["worker"]: f for f in by_rule["overload"]}
     assert set(overload) == {"w-shed", "w-unbounded"}
@@ -205,7 +191,7 @@ def test_handover_rules_fire_on_recorded_snapshots():
         },
         "roles": {},
     }
-    findings = doctor.diagnose(fleet, {}, {})
+    findings = doctor.diagnose(fleet, {})
     by_rule = {}
     for f in findings:
         by_rule.setdefault(f["rule"], []).append(f)
@@ -234,7 +220,7 @@ def test_handover_rules_fire_on_recorded_snapshots():
         },
         "roles": {},
     }
-    findings = doctor.diagnose(storm, {}, {})
+    findings = doctor.diagnose(storm, {})
     storms = [f for f in findings if f["rule"] == "handover-fallback-storm"]
     assert len(storms) == 1 and storms[0]["severity"] == "warning"
     assert storms[0]["evidence"]["handover_fallbacks_total"] == 6
@@ -248,7 +234,7 @@ def test_handover_rules_fire_on_recorded_snapshots():
         "roles": {},
     }
     assert not [
-        f for f in doctor.diagnose(ok, {}, {})
+        f for f in doctor.diagnose(ok, {})
         if f["rule"] == "handover-fallback-storm"
     ]
 
@@ -264,7 +250,7 @@ def test_migration_storm_rule_fires_on_recorded_snapshots():
     def storms(workers):
         return [
             f for f in doctor.diagnose(
-                {"workers": workers, "roles": {}}, {}, {}
+                {"workers": workers, "roles": {}}, {}
             )
             if f["rule"] == "migration-storm"
         ]
@@ -321,7 +307,7 @@ def test_tier_pressure_rule_fires_on_recorded_snapshots():
             **extra,
         }}, "roles": {}}
         return [
-            f for f in doctor.diagnose(fleet, {}, {})
+            f for f in doctor.diagnose(fleet, {})
             if f["rule"] == "tier-pressure"
         ]
 
@@ -357,7 +343,7 @@ def test_snapshot_only_mode_does_not_flag_busy_workers_as_stalled():
     with no records are the NORM there, not wedged engines (the silent-
     worker rule only fires when flight data was actually collected)."""
     doctor = _load_doctor()
-    findings = doctor.diagnose(FLEET, {}, {})
+    findings = doctor.diagnose(FLEET, {})
     silent = [
         f for f in findings
         if f["rule"] == "stalled-worker" and f["worker"] == "w-silent"
@@ -410,7 +396,7 @@ def test_planner_oscillation_rule_fires_on_alternating_directions():
              "from": 3, "to": 2},
         ]),
     }
-    findings = doctor.diagnose(fleet, {}, {})
+    findings = doctor.diagnose(fleet, {})
     osc = [f for f in findings if f["rule"] == "planner-oscillation"]
     assert len(osc) == 1, findings
     assert osc[0]["severity"] == "warning"
@@ -432,7 +418,7 @@ def test_planner_flip_storm_fires_inside_cooldown_window():
              "dst": "decode"},
         ], flips_total=3),
     }
-    findings = doctor.diagnose(fleet, {}, {})
+    findings = doctor.diagnose(fleet, {})
     osc = [f for f in findings if f["rule"] == "planner-oscillation"]
     assert len(osc) == 1, findings
     assert "flip storm" in osc[0]["summary"]
@@ -448,7 +434,7 @@ def test_sla_unrecovered_fires_at_the_clamp():
             signals={"burn_rate": 2.3, "sla_attainment": 0.91},
         ),
     }
-    findings = doctor.diagnose(fleet, {}, {})
+    findings = doctor.diagnose(fleet, {})
     unrec = [f for f in findings if f["rule"] == "sla-unrecovered"]
     assert len(unrec) == 1, findings
     assert unrec[0]["severity"] == "critical"
@@ -461,7 +447,7 @@ def test_sla_unrecovered_fires_at_the_clamp():
     ):
         fleet["planner"] = planner
         assert not [
-            f for f in doctor.diagnose(fleet, {}, {})
+            f for f in doctor.diagnose(fleet, {})
             if f["rule"] == "sla-unrecovered"
         ]
 
@@ -480,7 +466,7 @@ def test_planner_rules_quiet_on_healthy_planner():
              "from": 4, "to": 3},
         ]),
     }
-    findings = doctor.diagnose(fleet, {}, {})
+    findings = doctor.diagnose(fleet, {})
     assert not [
         f for f in findings
         if f["rule"] in ("planner-oscillation", "sla-unrecovered")
@@ -502,14 +488,14 @@ def test_clean_fleet_reports_all_clear():
         "w1": {"records": [_rec() for _ in range(8)]},
         "w2": {"records": [_rec() for _ in range(8)]},
     }}
-    findings = doctor.diagnose(fleet, flight, {})
+    findings = doctor.diagnose(fleet, flight)
     assert findings == []
     assert "all clear" in doctor.render_report(fleet, findings)
 
 
 def test_report_renders_and_cli_runs_offline(tmp_path):
     doctor = _load_doctor()
-    findings = doctor.diagnose(FLEET, FLIGHT, PROGRAMS)
+    findings = doctor.diagnose(FLEET, FLIGHT)
     text = doctor.render_report(FLEET, findings)
     assert "dynamo-tpu doctor: 12 worker(s)" in text
     assert "[CRITICAL" in text and "dead-worker" in text
@@ -518,14 +504,11 @@ def test_report_renders_and_cli_runs_offline(tmp_path):
 
     snap = tmp_path / "fleet.json"
     fl = tmp_path / "flight.json"
-    pr = tmp_path / "programs.json"
     snap.write_text(json.dumps(FLEET))
     fl.write_text(json.dumps(FLIGHT))
-    pr.write_text(json.dumps(PROGRAMS))
     out = subprocess.run(
         [sys.executable, str(REPO / "scripts" / "doctor.py"),
-         "--snapshot", str(snap), "--flight", str(fl),
-         "--programs", str(pr)],
+         "--snapshot", str(snap), "--flight", str(fl)],
         capture_output=True, text=True, timeout=60,
     )
     # exit code 2 signals critical findings (probe-friendly)
@@ -553,7 +536,7 @@ def test_kv_index_drift_rule_severities():
 
     def drift_findings(f):
         return [
-            x for x in doctor.diagnose(f, {}, {})
+            x for x in doctor.diagnose(f, {})
             if x["rule"] == "kv-index-drift"
         ]
 
@@ -617,7 +600,7 @@ def test_slow_trace_attribution_rule():
 
     def rule_findings(traces):
         return [
-            f for f in doctor.diagnose(fleet, {}, {}, traces)
+            f for f in doctor.diagnose(fleet, {}, traces)
             if f["rule"] == "slow-trace-attribution"
         ]
 
@@ -682,7 +665,7 @@ def test_control_plane_degraded_rule_severities():
         },
     }
     hits = [
-        f for f in doctor.diagnose(fleet, {}, {})
+        f for f in doctor.diagnose(fleet, {})
         if f["rule"] == "control-plane-degraded"
     ]
     assert hits and hits[0]["severity"] == "critical"
@@ -698,7 +681,7 @@ def test_control_plane_degraded_rule_severities():
         "control_plane": {"degraded": True, "disconnected_s": 6.0},
     }
     hits2 = [
-        f for f in doctor.diagnose(fleet2, {}, {})
+        f for f in doctor.diagnose(fleet2, {})
         if f["rule"] == "control-plane-degraded"
     ]
     assert hits2 and hits2[0]["severity"] == "warning"
@@ -715,7 +698,7 @@ def test_control_plane_degraded_rule_severities():
         "control_plane": {"degraded": False},
     }
     hits3 = [
-        f for f in doctor.diagnose(fleet3, {}, {})
+        f for f in doctor.diagnose(fleet3, {})
         if f["rule"] == "control-plane-degraded"
     ]
     assert len(hits3) == 1
@@ -732,7 +715,7 @@ def test_replication_lag_rule():
                    "fence": 1},
     }}
     hits = [
-        f for f in doctor.diagnose(base, {}, {})
+        f for f in doctor.diagnose(base, {})
         if f["rule"] == "replication-lag"
     ]
     assert hits and hits[0]["severity"] == "warning"
@@ -741,7 +724,7 @@ def test_replication_lag_rule():
     # small lag: healthy replication, quiet
     base["control_plane"]["broker"]["repl_lag_records"] = 3
     assert not [
-        f for f in doctor.diagnose(base, {}, {})
+        f for f in doctor.diagnose(base, {})
         if f["rule"] == "replication-lag"
     ]
     # no standby attached: lag is meaningless, quiet
@@ -749,7 +732,7 @@ def test_replication_lag_rule():
         "repl_subscribers": 0, "repl_lag_records": 99999,
     }
     assert not [
-        f for f in doctor.diagnose(base, {}, {})
+        f for f in doctor.diagnose(base, {})
         if f["rule"] == "replication-lag"
     ]
 
@@ -771,7 +754,7 @@ def test_host_skew_rule_names_the_straggler_host():
                  "kv_total_pages": 512},
     }}
     hits = [
-        f for f in doctor.diagnose(fleet, {}, {})
+        f for f in doctor.diagnose(fleet, {})
         if f["rule"] == "host-skew"
     ]
     assert len(hits) == 1, hits
@@ -783,7 +766,7 @@ def test_host_skew_rule_names_the_straggler_host():
     # a dead worker's frame must not drive the skew verdict
     fleet["workers"]["w-h1"]["last_seen_s"] = 42.0
     assert not [
-        f for f in doctor.diagnose(fleet, {}, {})
+        f for f in doctor.diagnose(fleet, {})
         if f["rule"] == "host-skew"
     ]
 
@@ -793,7 +776,7 @@ def test_host_skew_rule_names_the_straggler_host():
         for k, v in fleet["workers"].items()
     }}
     assert not [
-        f for f in doctor.diagnose(single, {}, {})
+        f for f in doctor.diagnose(single, {})
         if f["rule"] == "host-skew"
     ]
 
@@ -811,7 +794,7 @@ def test_host_skew_rule_ignores_sub_floor_p95():
                 "kv_total_pages": 512},
     }}
     assert not [
-        f for f in doctor.diagnose(fleet, {}, {})
+        f for f in doctor.diagnose(fleet, {})
         if f["rule"] == "host-skew"
     ]
 
@@ -830,7 +813,7 @@ def test_perf_regression_rule_fires_on_same_fingerprint_drop():
         perf_ledger.make_row("rB", "bench", {"tok_s": 500.0}, cfg),
     ]
     hits = [
-        f for f in doctor.diagnose({"workers": {}}, {}, {}, {}, rows)
+        f for f in doctor.diagnose({"workers": {}}, {}, {}, rows)
         if f["rule"] == "perf-regression"
     ]
     assert len(hits) == 1, hits
@@ -843,14 +826,14 @@ def test_perf_regression_rule_fires_on_same_fingerprint_drop():
         "rB", "bench", {"tok_s": 500.0}, {"model": "large", "isl": 64}
     )
     assert not [
-        f for f in doctor.diagnose({"workers": {}}, {}, {}, {}, rows)
+        f for f in doctor.diagnose({"workers": {}}, {}, {}, rows)
         if f["rule"] == "perf-regression"
     ]
 
     # in-band drift: quiet
     rows[1] = perf_ledger.make_row("rB", "bench", {"tok_s": 580.0}, cfg)
     assert not [
-        f for f in doctor.diagnose({"workers": {}}, {}, {}, {}, rows)
+        f for f in doctor.diagnose({"workers": {}}, {}, {}, rows)
         if f["rule"] == "perf-regression"
     ]
 

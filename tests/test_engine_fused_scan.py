@@ -402,7 +402,6 @@ def test_no_window_in_any_export():
     assert not [k for k in frame if "kstep" in k]
     assert not [f for f in EngineMetrics.TIMING_FIELDS if "kstep" in f]
     assert not [f for pair in _DELTA_FIELDS for f in pair if "kstep" in f]
-    assert "decode_kstep" not in JaxEngine._MEASURED_BY_KIND
     svc = MetricsService(object())
     frame.update(instance_id="w1", model="tiny", component="backend",
                  role="decode")

@@ -4,7 +4,7 @@
   produce a /v1/fleet snapshot whose MERGED TTFT/ITL percentiles sit
   within 1% rank of the exact offline percentiles of the raw worker
   observations, with compile counters, page-pool gauges, and a
-  (0,1]-bounded MFU gauge present per worker; both Prometheus
+  tokens/s gauge present per worker; both Prometheus
   expositions (fleet + frontend SLO) pass the promlint gate.
 - hardening: a worker vanishing between polls ages out of the snapshot
   (last_seen_s), malformed frames are logged-and-skipped, and the pump
@@ -175,14 +175,15 @@ def test_fleet_snapshot_e2e():
                     assert w["kv_free_pages"] >= 0
                     assert w["kv_pages_watermark"] > 0
                     assert w["kv_total_pages"] > 0
-                    assert 0.0 < w["mfu"] <= 1.0, (iid, w.get("mfu"))
+                    assert w["tokens_per_s"] > 0.0, (iid, w)
+                    assert "mfu" not in w
                     assert w["last_seen_s"] < 5.0
                     assert "slo" in w and w["slo"]["requests_total"] > 0
                     # debug plane (ISSUE 7): healthy workers report the
                     # watchdog counter at zero
                     assert w["stalls_total"] == 0, (iid, w)
 
-                # the workers' flight windows + program cost rollups
+                # the workers' flight windows + compile tables
                 # rode the frames into the metrics service
                 async with s.get(f"{mbase}/v1/debug/flight?n=8") as r:
                     assert r.status == 200
@@ -197,8 +198,8 @@ def test_fleet_snapshot_e2e():
                     assert r.status == 200
                     pdoc = await r.json()
                 for iid, pw in pdoc["workers"].items():
-                    assert any(
-                        k.get("attainment") is not None
+                    assert pw["kinds"] and all(
+                        set(k) == {"programs", "compiles", "compile_ms"}
                         for k in pw["kinds"].values()
                     ), (iid, pw)
                 role = snap["roles"]["decode"]
@@ -218,7 +219,8 @@ def test_fleet_snapshot_e2e():
             assert "dynamo_tpu_fleet_goodput_tokens_total{" in fleet_text
             assert "dynamo_tpu_fleet_burn_rate{" in fleet_text
             assert "dynamo_tpu_fleet_compile_total{" in fleet_text
-            assert "dynamo_tpu_worker_mfu{" in fleet_text
+            assert "dynamo_tpu_worker_tokens_per_s{" in fleet_text
+            assert "_mfu" not in fleet_text
             assert "dynamo_tpu_worker_compiles_total{" in fleet_text
             assert "dynamo_tpu_worker_kv_pages_watermark{" in fleet_text
             assert 'dynamo_tpu_slo_ttft_ms{endpoint="chat"' in front_text
@@ -276,7 +278,9 @@ def test_worker_vanishes_and_malformed_frames_never_kill_the_pump():
             await rt_w.fabric.publish(
                 f"{METRICS_SUBJECT}.backend.junk", ["not", "a", "dict"]
             )
-            await publish("w-garbage", slo="not-a-wire", mfu="NaN-ish")
+            await publish(
+                "w-garbage", slo="not-a-wire", tokens_per_s="NaN-ish"
+            )
             await asyncio.sleep(0.2)
 
             snap = svc.fleet_snapshot()
@@ -284,7 +288,7 @@ def test_worker_vanishes_and_malformed_frames_never_kill_the_pump():
                 "w-stable", "w-vanishes", "w-garbage"
             }
             assert "slo" not in snap["workers"]["w-garbage"]
-            assert "mfu" not in snap["workers"]["w-garbage"]
+            assert "tokens_per_s" not in snap["workers"]["w-garbage"]
             assert snap["workers"]["w-stable"]["last_seen_s"] < 0.6
 
             def fleet_counter(text, name):
@@ -443,7 +447,7 @@ RECORDED_SNAPSHOT = {
             "stalls_by_cause": {"stalled_stream": 1, "queue_wait": 1},
             "num_running": 9, "num_waiting": 1, "compiles": 14,
             "compiles_by_kind": {"prefill": 6, "decode_multi": 8},
-            "mfu": 0.241, "tokens_per_s": 812.0,
+            "tokens_per_s": 812.0,
             "kvbm_host_blocks": 12, "kvbm_disk_blocks": 3,
             "kvbm_demotions_total": 15, "kvbm_promotions_total": 6,
             "kvbm_host_hits_total": 5, "kvbm_disk_hits_total": 1,
@@ -465,14 +469,14 @@ RECORDED_SNAPSHOT = {
         "worker-prefill-1": {
             "role": "prefill", "component": "prefill", "model": "llama3-1b",
             "last_seen_s": 1.1, "req_s": 4.0, "tok_s": 4100.0,
-            "kv_usage": 0.11, "compiles": 4, "mfu": 0.38,
+            "kv_usage": 0.11, "compiles": 4,
         },
     },
     "roles": {
-        "decode": {"workers": 1, "kv_usage": 0.42, "mfu": 0.241,
+        "decode": {"workers": 1, "kv_usage": 0.42,
                    "tokens_per_s": 812.0, "preemptions": 3,
                    "compiles_by_kind": {"prefill": 6, "decode_multi": 8}},
-        "prefill": {"workers": 1, "kv_usage": 0.11, "mfu": 0.38,
+        "prefill": {"workers": 1, "kv_usage": 0.11,
                     "tokens_per_s": 4100.0, "preemptions": 0,
                     "compiles_by_kind": {}},
     },
@@ -584,7 +588,8 @@ def test_fleet_top_renders_recorded_snapshot(tmp_path):
     text = ft.render(RECORDED_SNAPSHOT)
     assert "worker-decode-1" in text
     assert "decode" in text and "prefill" in text
-    assert "0.2410" in text  # worker MFU
+    assert "MFU" not in text and "mfu" not in text
+    assert "tok/s 812.0" in text  # the role's throughput in the footer
     assert "130.1" in text or "130/" in text  # ttft p50 in fleet footer
     assert "burn rate 2.50x" in text
     assert "goodput 25100/25600 tokens" in text
@@ -641,11 +646,11 @@ def test_no_fleet_telemetry_is_bit_identical():
         outs[on] = eng.run_to_completion()
         if on:
             assert eng.slo is not None
-            assert eng.metrics.mfu >= 0.0
+            assert eng.metrics.tokens_per_s >= 0.0
         else:
             assert eng.slo is None
             assert len(eng._thru_window) == 0
-            assert eng.metrics.mfu == 0.0
+            assert eng.metrics.tokens_per_s == 0.0
     assert outs[True] == outs[False]
 
 

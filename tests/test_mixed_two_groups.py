@@ -169,21 +169,30 @@ def test_engine_mixed_program_holds_one_layer_scan():
     eng = JaxEngine(EngineConfig.for_tests(decode_steps=1))
     traced, texts = [], []
     real_pass = eng.adapter.forward_hidden_mixed
-    real_cost = eng._program_cost
+    real_cache_jit = eng._cache_jit
 
     def counting(*a, **kw):
         traced.append(1)
         return real_pass(*a, **kw)
 
-    def spy(jitted, args, kwargs):
-        if getattr(jitted, "__name__", "") == "mixed_fn":
+    def spy(kind, cache_key, jitted):
+        first_call = real_cache_jit(kind, cache_key, jitted)
+        if getattr(jitted, "__name__", "") != "mixed_fn":
+            return first_call
+
+        def lowered_first(*args, **kwargs):
             n0 = len(traced)
             texts.append(jitted.lower(*args, **kwargs).as_text())
             assert len(traced) == n0 + 1  # one model pass a program
-        return real_cost(jitted, args, kwargs)
+            out = first_call(*args, **kwargs)
+            assert len(traced) == n0 + 1  # the call shares the lowering
+            return out
+
+        eng._jit_cache[cache_key] = lowered_first
+        return lowered_first
 
     object.__setattr__(eng.adapter, "forward_hidden_mixed", counting)
-    eng._program_cost = spy
+    eng._cache_jit = spy
     eng.add_request(
         "a", [5, 6, 7], SamplingParams(max_tokens=24, ignore_eos=True)
     )

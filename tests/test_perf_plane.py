@@ -56,15 +56,21 @@ def test_memory_report_reconciles_with_engine_accounting(engine):
     assert abs(total_kv - expected_kv) <= max(1, 0.01 * expected_kv)
 
     # totals are exactly the column sums of the device rows
-    for comp in ("weights", "kv_pool", "scratch", "free", "peak", "live"):
+    assert set(rep["totals"]) == {
+        "weights_bytes", "kv_pool_bytes", "state_pool_bytes",
+        "live_bytes", "free_bytes", "peak_bytes",
+    }
+    for comp in ("weights", "kv_pool", "state_pool", "free", "peak",
+                 "live"):
         key = f"{comp}_bytes"
         assert rep["totals"][key] == sum(
             d[key] for d in rep["devices"].values()
         )
-    # accounted-fallback invariants: live = w+kv+scratch, free = limit-live
+    # accounted-fallback invariants: live = weights + pools, free =
+    # limit - live
     for d in rep["devices"].values():
         assert d["live_bytes"] == (
-            d["weights_bytes"] + d["kv_pool_bytes"] + d["scratch_bytes"]
+            d["weights_bytes"] + d["kv_pool_bytes"] + d["state_pool_bytes"]
         )
         assert d["free_bytes"] == max(0, d["limit_bytes"] - d["live_bytes"])
         assert d["peak_bytes"] >= d["live_bytes"]
@@ -80,14 +86,13 @@ def test_memory_report_reconciles_with_engine_accounting(engine):
 
 
 def test_memory_and_programs_agree_on_peaks(engine):
-    """Bugfix satellite: /v1/debug/programs (roofline) and
-    /v1/debug/memory (HBM limits) source their per-generation peaks
-    from the ONE platform table — no drift between the surfaces."""
+    """/v1/debug/memory takes its HBM limit from the platform's
+    capacity table (the name predates PR 47: /v1/debug/programs carries
+    no peaks any more; the chip's live in chipbench/peaks.json)."""
     from dynamo_tpu.platform import device_hbm_bytes
 
     rep = engine.memory_report()
-    prog = engine.programs_report()
-    assert prog["peak_flops"] > 0
+    assert "peak_flops" not in engine.programs_report()
     for d in rep["devices"].values():
         assert d["limit_bytes"] == int(device_hbm_bytes())
 
@@ -264,7 +269,7 @@ def test_metrics_service_fleet_memory_mesh_and_host_skew():
             frame = {
                 "instance_id": "w1",
                 "hbm_weights_bytes": 1000, "hbm_kv_pool_bytes": 500,
-                "hbm_scratch_bytes": 100, "hbm_free_bytes": 4000,
+                "hbm_free_bytes": 4000,
                 "hbm_peak_bytes": 1600, "host": 1,
                 "dispatch_p95_ms": 12.5,
                 "memory": {
