@@ -100,6 +100,12 @@ def _wire_unpack(arr, d_true: int, pool_dtype):
     return payload, scale
 
 
+#: `ModelAdapter.walk_pages`' running count, in order, as EngineMetrics
+#: names its numbers
+_WALK_COUNTS = ("walk_pages_named", "walk_pages_live", "chunk_pages_read",
+                "chunk_pages_named", "moe_experts_touched")
+
+
 @dataclass
 class EngineMetrics:
     """Worker load snapshot published to routers/planner (parity with the
@@ -273,6 +279,10 @@ class EngineMetrics:
     #: queries' selections named (what a walk a query would have read)
     chunk_pages_read: int = 0
     chunk_pages_named: int = 0
+    #: where that model holds a share of its experts and counts a fifth
+    #: number (models/dots3.py): the held experts some row of a step chose,
+    #: an expert layer each: the matrices its grouped matmuls read
+    moe_experts_touched: int = 0
     #: the dry clock (telemetry/flight.py `DryClock`; all 0 with
     #: `flight_recorder=False`): cumulative host ms during which the
     #: device had NOTHING queued while the engine had work, from the first
@@ -555,7 +565,10 @@ class JaxEngine:
             config.max_seqs + max(1, config.max_seqs // 8)
             if self._stateful else 0
         )
-        if self._stateful:
+        #: a slot that holds KV written by position (a window layer's ring,
+        #: models/dots3.py) keeps one generation: `_row_tables`
+        self._state_in_place = self.adapter.state_in_place
+        if self._stateful and not self._state_in_place:
             self._refuse_for_state(config)
         self._refuse_for_adapter(config)
         # the device's running count of what its walks and chunk tiles
@@ -565,7 +578,7 @@ class JaxEngine:
         if self.adapter.walk_pages is not None:
             copy = jax.jit(lambda count: count + 0)
             self._walk_peek = lambda kv: copy(self.adapter.walk_pages(kv))
-        self._walk_seen = np.zeros(4, np.int64)
+        self._walk_seen = np.zeros(len(_WALK_COUNTS), np.int64)
         if mc.tp > 1:
             # MLA's shared-latent cache replicates over tp (the q heads
             # still shard) — only head-sharded caches need kv divisibility.
@@ -844,7 +857,8 @@ class JaxEngine:
         """The cache's slot pools of recurrent state (none for a family
         whose only per-sequence state is pages)."""
         return tuple(
-            x for x in (getattr(kv, "conv", None), getattr(kv, "ssm", None))
+            x for x in (getattr(kv, name, None)
+                        for name in ("conv", "ssm", "ring", "ring_pe"))
             if x is not None
         )
 
@@ -913,10 +927,12 @@ class JaxEngine:
         the last dispatch TAKEN left it (generation `state_gen` of its
         slot) and writes the other generation, which becomes the state
         only when this dispatch is taken (`_commit_state`). Padding rows
-        keep (0, 0), the null slot."""
+        keep (0, 0), the null slot. A slot benign in place
+        (`ModelAdapter.state_in_place`) has one generation: both entries
+        are the row's slot."""
         if not self._stateful:
             return pt
-        stride = self._state_slots + 1
+        stride = 0 if self._state_in_place else self._state_slots + 1
         rows = np.zeros((pt.shape[0], 2), np.int32)
         for i, req in enumerate(reqs):
             rows[i, 0] = req.state_gen * stride + req.state_slot
@@ -925,8 +941,9 @@ class JaxEngine:
 
     def _commit_state(self, reqs) -> None:
         """The dispatch that carried `reqs` is the real step: what it
-        wrote IS each row's state from now on."""
-        if self._stateful:
+        wrote IS each row's state from now on (nothing to do where the
+        slot is benign in place)."""
+        if self._stateful and not self._state_in_place:
             for req in reqs:
                 req.state_gen ^= 1
 
@@ -2333,13 +2350,11 @@ class JaxEngine:
         dispatch rolled back in between walked its pages too)."""
         if st.walk is None:
             return
-        now = np.asarray(st.walk).astype(np.int64)
-        for name, n in zip(
-            ("walk_pages_named", "walk_pages_live", "chunk_pages_read",
-             "chunk_pages_named"), (now - self._walk_seen) % (1 << 32),
-        ):
+        now = np.asarray(st.walk).astype(np.int64)  # four numbers, or five
+        seen = self._walk_seen[:now.size]
+        for name, n in zip(_WALK_COUNTS, (now - seen) % (1 << 32)):
             setattr(self.metrics, name, getattr(self.metrics, name) + int(n))
-        self._walk_seen = now
+        seen[:] = now
 
     @staticmethod
     def _materialize_lp(lp_data, k_steps: int, b_bucket: int):
@@ -2814,7 +2829,7 @@ class JaxEngine:
         if inflight is None:
             return
         state_rows = 0
-        if self._stateful:
+        if self._stateful and not self._state_in_place:
             state_rows = sum(
                 1 for r in (
                     *inflight.reqs, *(p.request for p in inflight.pieces)
@@ -3743,9 +3758,9 @@ class JaxEngine:
         allocated for attention across chunks, freed before returning."""
         if self._stateful:
             raise ValueError(
-                f"{self.config.model} has state-space layers: /v1/embeddings "
-                "is not supported for it (its scratch pages would need a "
-                "scratch state slot)"
+                f"{self.config.model} keeps a state slot a sequence beside "
+                "its pages: /v1/embeddings is not supported for it (its "
+                "scratch pages would need a scratch state slot)"
             )
         out: list[np.ndarray] = []
         ps = self.config.page_size

@@ -119,6 +119,9 @@ _WORKER_FIELDS = (
     # queries' selections named (named / read: reads saved by the tile)
     ("chunk_pages_read", "counter"),
     ("chunk_pages_named", "counter"),
+    # a chip that holds a share of the experts: the held experts a step's
+    # rows chose, an expert layer each (models/dots3.py; 0 for the others)
+    ("moe_experts_touched", "counter"),
     # speculative decoding (spec_ngram / spec_draft_model): drafts
     # proposed vs accepted — their ratio times S is the extra tokens per
     # verify dispatch; the skip counters say WHY speculation sat out

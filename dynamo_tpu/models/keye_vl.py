@@ -415,22 +415,25 @@ def put_own(scores, own, at):
         start, axis=1)
 
 
-def step_scores(qi, w, ki_new, tables, positions, valid, ki_pool, layer):
+def step_scores(qi, w, ki_new, tables, positions, valid, ki_pool, layer,
+                paired: bool = True):
     """The index scores of a group's queries by position, float32 [B, T,
     MP * S], on the TPU: the cached keys scored out of the pool in place
     (ops/index_scores.py), the step's own from `ki_new` and put in. What
     `ts.index_scores(qi, w, index_keys_of(..))` gives up to every query's
-    own position; past it the two differ and nothing reads."""
+    own position; past it the two differ and nothing reads. `paired`
+    False: a pool of one layer's key a row (models/dots3.py)."""
     from dynamo_tpu.ops.index_scores import paged_index_scores
 
     hist = jnp.where(valid[:, 0], positions[:, 0], 0).astype(jnp.int32)
     if qi.shape[1] == 1:  # a decode row: its own token's score, a scalar
-        sc = paged_index_scores(qi, w, ki_pool, layer, tables, hist)
+        sc = paged_index_scores(qi, w, ki_pool, layer, tables, hist,
+                                paired=paired)
         own = ts.index_scores(qi, w, ki_new.astype(ki_pool.dtype))
         at = jnp.arange(sc.shape[-1], dtype=jnp.int32)[None, None]
         return jnp.where(at == positions[:, :, None], own, sc)
     sc, own = paged_index_scores(
-        qi, w, ki_pool, layer, tables, hist, ki_new)
+        qi, w, ki_pool, layer, tables, hist, ki_new, paired=paired)
     return jax.vmap(put_own)(sc, own, hist)
 
 

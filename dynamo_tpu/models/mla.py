@@ -629,6 +629,10 @@ def _interleaved_rope(x: jax.Array, positions: jax.Array, cfg: MlaConfig):
 
 #: masked scores; finite so that a padded query row stays NaN-free
 _MASKED = -1e30
+#: the decode walk's VMEM budget under token bits: that call raises the
+#: kernel's limit to 64 MiB (ops/paged_attention.py), so a whole batch of
+#: 32 rows x 128 heads (34 MB of q, acc and m|l) walks in ONE call
+_BITS_VMEM_BUDGET = 48 << 20
 
 
 def _pad_last(x: jax.Array, width: int) -> jax.Array:
@@ -638,11 +642,14 @@ def _pad_last(x: jax.Array, width: int) -> jax.Array:
 
 
 def _latent_decode(qd, c_cur, pe_cur, k_cache, v_cache, layer, page_tables,
-                   hist, cfg: MlaConfig, decode_work, mesh):
+                   hist, cfg: MlaConfig, decode_work, mesh, chosen=None):
     """o_lat [B, H, c] f32 of one decode step: the kernel's walk over the
     history pages, then the current (staged, unwritten) token folded in
     exactly. qd [B, H, c+R] is the absorbed query, then its rope part;
-    c_cur [B, c] and pe_cur [B, R] the current token's rows."""
+    c_cur [B, c] and pe_cur [B, R] the current token's rows. `chosen`
+    [B, MP * S] bool names the tokens a row attends (models/dots3.py: the
+    walk under a bit a cached token, and the row's own token folded in
+    only where it is chosen)."""
     from dynamo_tpu.ops.paged_attention import (
         decode_vmem_bytes,
         paged_decode_attention,
@@ -651,12 +658,14 @@ def _latent_decode(qd, c_cur, pe_cur, k_cache, v_cache, layer, page_tables,
     b, hn, _ = qd.shape
     c, scale = cfg.kv_lora_rank, cfg.softmax_scale
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+    budget = _DECODE_VMEM_BUDGET if chosen is None else _BITS_VMEM_BUDGET
 
     def walk(rows, work):
         return paged_decode_attention(
             qd[rows], k_cache, v_cache, layer, page_tables[rows],
             hist[rows], scale=scale, latent=True, mesh=mesh,
-            work_list=work, vmem_budget=_DECODE_VMEM_BUDGET,
+            work_list=work, vmem_budget=budget,
+            token_bits=None if chosen is None else chosen[rows],
         )
 
     # the kernel keeps the whole batch's q and acc in VMEM: a batch too
@@ -664,9 +673,9 @@ def _latent_decode(qd, c_cur, pe_cur, k_cache, v_cache, layer, page_tables,
     piece = b
     while piece > 1 and decode_vmem_bytes(
         piece, hn // tp, c, k_cache.shape[2], 1,
-        jnp.dtype(k_cache.dtype).itemsize, budget=_DECODE_VMEM_BUDGET,
+        jnp.dtype(k_cache.dtype).itemsize, budget=budget,
         rope_dim=cfg.kv_rope_dim,
-    ) > _DECODE_VMEM_BUDGET:
+    ) > budget:
         piece = -(-piece // 2)
     if piece == b:
         acc, m, l = walk(slice(None), decode_work)
@@ -678,6 +687,9 @@ def _latent_decode(qd, c_cur, pe_cur, k_cache, v_cache, layer, page_tables,
         jnp.einsum("bhc,bc->bh", qf[..., :c], c_cur.astype(jnp.float32))
         + jnp.einsum("bhr,br->bh", qf[..., c:], pe_cur.astype(jnp.float32))
     )
+    if chosen is not None:
+        own = jnp.take_along_axis(chosen, hist[:, None], axis=1)  # [B, 1]
+        s_self = jnp.where(own, s_self, _MASKED)
     m_star = jnp.maximum(m, s_self)
     alpha, beta = jnp.exp(m - m_star), jnp.exp(s_self - m_star)
     return (
@@ -689,6 +701,7 @@ def _latent_decode(qd, c_cur, pe_cur, k_cache, v_cache, layer, page_tables,
 def _latent_prefill_attention(
     q_lat, q_pe, c_kv, pe_rows, k_cache, v_cache, layer, page_tables,
     positions, valid, cfg: MlaConfig, first_chunk: bool, mesh=None,
+    chosen=None,
 ):
     """o_lat [B, T, H, c] (model dtype) of a prefill chunk in the absorbed
     form: the chunk over its history in the cache and over itself (causal by
@@ -698,7 +711,9 @@ def _latent_prefill_attention(
     it; scores, softmax and sums are float32. The history is what lies
     before the chunk's first position (chunks start page-aligned; the
     chunk's own rows are staged, not yet in the cache: `c_kv` and
-    `pe_rows`, the rope key as cached), none for a `first_chunk`."""
+    `pe_rows`, the rope key as cached), none for a `first_chunk`. `chosen`
+    [B, T, MP * S] bool names each query's keys by position
+    (models/dots3.py)."""
     from dynamo_tpu.ops.flash_prefill import latent_prefill_attention
 
     dt, f32 = cfg.dtype, jnp.float32
@@ -709,8 +724,74 @@ def _latent_prefill_attention(
         _pad_last(qp, cfg.kv_rope_dim), c_kv.astype(dt), pe_rows, k_cache,
         v_cache, layer, page_tables,
         jnp.zeros_like(start) if first_chunk else start,
-        jnp.sum(valid, axis=1), mesh=mesh,
+        jnp.sum(valid, axis=1), mesh=mesh, chosen=chosen,
     )
+
+
+def latent_projections(x, lp, cfg: MlaConfig, rescale=None):
+    """A layer's latent projections of the normed input x [.., H], scope
+    `qkv`: (q [.., heads, nope + rope] before the rotary embedding, the
+    latent c_kv [.., c] normed, `kv_a` [.., c + r] whose last r columns
+    are the rope key before the rotary embedding, the query latent c_q
+    [.., q_lora_rank] normed, or None where q is projected directly).
+    `rescale` = (a_q, a_kv) multiplies the normed latents (models/dots3.py;
+    the rope key is left alone)."""
+    hn, c = cfg.num_heads, cfg.kv_lora_rank
+    with jax.named_scope("qkv"):
+        qa = None
+        if cfg.q_lora_rank:
+            qa = rms_norm(
+                _mm(x, lp, "wq_a", cfg.dtype).astype(cfg.dtype),
+                lp["q_a_norm"], cfg.rms_norm_eps,
+            )
+            if rescale:
+                qa = (qa * rescale[0]).astype(cfg.dtype)
+            q = _mm(qa, lp, "wq_b", cfg.dtype)
+        else:
+            q = _mm(x, lp, "wq", cfg.dtype)
+        q = q.reshape(*x.shape[:-1], hn, cfg.qk_head_dim)
+        kv_a = _mm(x, lp, "wkv_a", cfg.dtype)  # [..., c+r]
+        c_kv = rms_norm(
+            kv_a[..., :c].astype(cfg.dtype), lp["kv_a_norm"],
+            cfg.rms_norm_eps,
+        )
+        if rescale:
+            c_kv = (c_kv * rescale[1]).astype(cfg.dtype)
+    return q, c_kv, kv_a, qa
+
+
+def absorbed_query(q, lp, cfg: MlaConfig):
+    """(q_lat [.., heads, c] float32, W_UV [c, heads, v]): the nope part
+    of the queries q [.., heads, nope + rope] through W_UK into the latent
+    space (scope `absorb`), and the
+    value half of `wkv_b` for `latent_output`. Operands in the model dtype
+    under the kernels, float32 under xla; float32 accumulation in both."""
+    hn, c = cfg.num_heads, cfg.kv_lora_rank
+    n, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+    wdt = cfg.dtype if cfg.kernels else jnp.float32
+    wkv_b = _w(lp, "wkv_b", wdt).reshape(c, hn, n + vd)
+    w_uk, w_uv = wkv_b[..., :n], wkv_b[..., n:]
+    with jax.named_scope("absorb"):
+        q_lat = jnp.einsum(
+            "...hn,chn->...hc", q[..., :n].astype(wdt), w_uk,
+            preferred_element_type=jnp.float32,
+        )
+    return q_lat, w_uv
+
+
+def latent_output(o_lat, w_uv, lp, cfg: MlaConfig, gate=None):
+    """The attention block's output from o_lat [.., heads, c] (in W_UV's
+    dtype): the value up-projection, then `wo`; `gate` [.., heads]
+    multiplies each head's output before `wo` (models/dots3.py). The
+    caller names the scope."""
+    out = jnp.einsum(
+        "...hc,chv->...hv", o_lat, w_uv,
+        preferred_element_type=jnp.float32,
+    )
+    if gate is not None:
+        out = out * gate[..., None].astype(jnp.float32)
+    out = out.reshape(*out.shape[:-2], -1).astype(cfg.dtype)
+    return _mm(out, lp, "wo", cfg.dtype)
 
 
 def mla_attention(
@@ -734,36 +815,9 @@ def mla_attention(
     `kv_update`, `absorb`, `paged` (reads cache pages: the decode walk,
     and the xla discipline's gather), `flash` (a prefill chunk under the
     kernels, with or without history), `out`."""
-    hn, c = cfg.num_heads, cfg.kv_lora_rank
-    n, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
-    lead = x.shape[:-1]
-
-    with jax.named_scope("qkv"):
-        if cfg.q_lora_rank:
-            qa = rms_norm(
-                _mm(x, lp, "wq_a", cfg.dtype).astype(cfg.dtype),
-                lp["q_a_norm"], cfg.rms_norm_eps,
-            )
-            q = _mm(qa, lp, "wq_b", cfg.dtype)
-        else:
-            q = _mm(x, lp, "wq", cfg.dtype)
-        q = q.reshape(*lead, hn, cfg.qk_head_dim)
-        kv_a = _mm(x, lp, "wkv_a", cfg.dtype)  # [..., c+r]
-        c_kv = rms_norm(
-            kv_a[..., :c].astype(cfg.dtype), lp["kv_a_norm"],
-            cfg.rms_norm_eps,
-        )
-
-    # operands in the model dtype under the kernels, float32 under xla;
-    # float32 accumulation in both
-    wdt = cfg.dtype if cfg.kernels else jnp.float32
-    wkv_b = _w(lp, "wkv_b", wdt).reshape(c, hn, n + vd)
-    w_uk, w_uv = wkv_b[..., :n], wkv_b[..., n:]
-    with jax.named_scope("absorb"):
-        q_lat = jnp.einsum(
-            "...hn,chn->...hc", q[..., :n].astype(wdt), w_uk,
-            preferred_element_type=jnp.float32,
-        )
+    n, c = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q, c_kv, kv_a, _ = latent_projections(x, lp, cfg)
+    q_lat, w_uv = absorbed_query(q, lp, cfg)
 
     attend = _attend_kernels if cfg.kernels else _attend_xla
     o_lats, staged = [], []
@@ -775,25 +829,22 @@ def mla_attention(
             qp = _interleaved_rope(qp, g.positions, cfg)
             kp = _interleaved_rope(kp, g.positions, cfg).astype(cfg.dtype)
         o_lat, kv, st = attend(ql, qp, ck, kp, cfg, kv, layer, g, work, mesh)
-        o_lats.append(o_lat.astype(wdt))
+        o_lats.append(o_lat.astype(w_uv.dtype))
         staged.append(st)
 
     with jax.named_scope("out"):
-        out = jnp.einsum(
-            "...hc,chv->...hv", join_rows(o_lats), w_uv,
-            preferred_element_type=jnp.float32,
-        )
-        out = out.reshape(*lead, hn * vd).astype(cfg.dtype)
-        return _mm(out, lp, "wo", cfg.dtype), *kv, tuple(staged)
+        return (latent_output(join_rows(o_lats), w_uv, lp, cfg), *kv,
+                tuple(staged))
 
 
 def _attend_xla(q_lat, q_pe, c_kv, k_pe, cfg: MlaConfig, kv, layer, g,
-                work, mesh):
+                work, mesh, keep=None):
     """One group under the xla discipline: land the chunk's latent and
     rope key, then attend over the gathered (history + current) cache:
     the same scatter-then-gather as the Llama XLA path, so causality is
-    pure position masking. Returns (o_lat [B, T, H, c] f32, the caches
-    written, None)."""
+    pure position masking; `keep` [B, T, K] bool masks further (the
+    tokens a query's indexer chose, models/dots3.py). Returns (o_lat [B, T,
+    H, c] f32, the caches written, None)."""
     k_cache, v_cache = kv
     with jax.named_scope("kv_update"):
         k_cache = paged_scatter(
@@ -818,6 +869,8 @@ def _attend_xla(q_lat, q_pe, c_kv, k_pe, cfg: MlaConfig, kv, layer, g,
         kk = c_hist.shape[1]
         key_pos = jnp.arange(kk)[None, None, None, :]
         mask = key_pos <= g.positions[:, None, :, None]
+        if keep is not None:
+            mask = mask & keep[:, None]
         scores = jnp.where(mask, scores, _MASKED)
         probs = jax.nn.softmax(scores, axis=-1)
         o_lat = jnp.einsum(
@@ -827,12 +880,13 @@ def _attend_xla(q_lat, q_pe, c_kv, k_pe, cfg: MlaConfig, kv, layer, g,
 
 
 def _attend_kernels(q_lat, q_pe, c_kv, k_pe, cfg: MlaConfig, kv, layer, g,
-                    work, mesh):
+                    work, mesh, chosen=None):
     """One group under the kernels' discipline (module text): the cache
     is read, never written here; the chunk's rows come back as `staged`.
     Returns (o_lat [B, T, H, c], float32 from the decode walk and the
     model dtype from a prefill chunk's kernel, the caches as they came,
-    staged)."""
+    staged). `chosen` [B, T, MP * S] bool names each query's keys by
+    position (models/dots3.py's indexer; None: every key up to its own)."""
     k_cache, v_cache = kv
     pe_rows = _pad_last(k_pe, cfg.kv_rope_dim)  # the rope key as cached
     if q_lat.shape[1] == 1:
@@ -844,13 +898,14 @@ def _attend_kernels(q_lat, q_pe, c_kv, k_pe, cfg: MlaConfig, kv, layer, g,
             o_lat = _latent_decode(
                 qd, c_kv[:, 0], pe_rows[:, 0], k_cache, v_cache, layer,
                 g.page_tables, g.positions[:, 0], cfg, work, mesh,
+                None if chosen is None else chosen[:, 0],
             )[:, None]
     else:
         with jax.named_scope("flash"):
             o_lat = _latent_prefill_attention(
                 q_lat, q_pe, c_kv, pe_rows, k_cache, v_cache, layer,
                 g.page_tables, g.positions, g.valid, cfg, g.first_chunk,
-                mesh,
+                mesh, chosen,
             )
     return o_lat, kv, (c_kv[:, :, None, :], pe_rows[:, :, None, :])
 
@@ -929,11 +984,13 @@ def _gate(xf: jax.Array, lp: dict, cfg: MlaConfig, precision=None):
         # scores, weights use the uncorrected ones.
         scores = jax.nn.sigmoid(logits)
         choice = scores + lp["router_bias"][None, :]
-        choice = choice * _group_mask(
-            choice,
-            lambda gc: jnp.sum(lax.top_k(gc, min(2, e // cfg.n_group))[0],
-                               axis=-1),
-        )
+        if cfg.n_group > 1:  # one group: every expert stands (two top-k
+            # less to compile and to run)
+            choice = choice * _group_mask(
+                choice,
+                lambda gc: jnp.sum(
+                    lax.top_k(gc, min(2, e // cfg.n_group))[0], axis=-1),
+            )
         _, topi = lax.top_k(choice, k)
         topw = jnp.take_along_axis(scores, topi, axis=-1)
         if cfg.norm_topk_prob:
@@ -970,7 +1027,11 @@ def _routed_experts(
     that is a share of them (an expert-parallel deployment's chip,
     models/nemotron_h.py): an assignment to an expert held elsewhere sorts
     past the groups, where the grouped matmul computes nothing, and adds
-    nothing here."""
+    nothing here. A share's assignments are ordered by COUNTING (a running
+    count a group: exact and stable, for the few groups of a share): XLA's
+    stable sort of 16,640 assignments takes the TPU's compiler 15 s a
+    program, against 1.5 s for 4,352 (the compile for the described v5e,
+    PR 48; models/dots3.py)."""
     nt, h = xf.shape
     e, k = cfg.n_routed_experts, topi.shape[1]
     with jax.named_scope("route"):
@@ -979,10 +1040,32 @@ def _routed_experts(
             first, e = held
             flat_e = flat_e - first
             flat_e = jnp.where((flat_e >= 0) & (flat_e < e), flat_e, e)
-        order = jnp.argsort(flat_e, stable=True)
+            # an assignment's place: its group's start + its rank there
+            at = jnp.arange(nt * k, dtype=jnp.int32)
+            one = (flat_e[:, None] == jnp.arange(e + 1)[None]).astype(
+                jnp.float32)  # [N * k, groups and "elsewhere"]
+            # the running count in blocks of 128 assignments: inside a
+            # block one triangular product (exact: counts below 2**24),
+            # across blocks a short cumulative sum
+            pad = -(nt * k) % 128
+            blocks = jnp.pad(one, ((0, pad), (0, 0))).reshape(-1, 128, e + 1)
+            before = jnp.tril(jnp.ones((128, 128), jnp.float32), -1)
+            inside = jnp.einsum("ij,bjg->big", before, blocks,
+                                precision=lax.Precision.HIGHEST)
+            sums = jnp.sum(blocks, axis=1)  # [blocks, groups]
+            rank = (inside + (jnp.cumsum(sums, axis=0) - sums)[:, None]
+                    ).reshape(-1, e + 1)[:nt * k]
+            sizes = jnp.sum(sums, axis=0)
+            back = jnp.sum(
+                one * (rank + (jnp.cumsum(sizes) - sizes)[None]), axis=1
+            ).astype(jnp.int32)
+            order = jnp.zeros((nt * k,), jnp.int32).at[back].set(at)
+            group_sizes = sizes[:e].astype(jnp.int32)
+        else:
+            order = jnp.argsort(flat_e, stable=True)
+            # (an index past the groups is dropped: a scatter's default)
+            group_sizes = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
         expert_of_row = flat_e[order]
-        # (an index past the groups is dropped: jax's default for a scatter)
-        group_sizes = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
         xs = xf[order // k].astype(cfg.dtype)
     with jax.named_scope("experts"):
         ys = _grouped_ffn(
@@ -991,10 +1074,31 @@ def _routed_experts(
     with jax.named_scope("route"):
         if held is not None:  # rows past the groups hold whatever was there
             ys = jnp.where((expert_of_row < e)[:, None], ys, 0.0)
-        back = jnp.zeros((nt * k,), jnp.int32).at[order].set(
-            jnp.arange(nt * k, dtype=jnp.int32)
-        )
+        else:
+            back = jnp.zeros((nt * k,), jnp.int32).at[order].set(
+                jnp.arange(nt * k, dtype=jnp.int32)
+            )
         return jnp.sum(ys[back].reshape(nt, k, h) * topw[..., None], axis=1)
+
+
+def _dense_ffn(x: jax.Array, lp: dict, cfg: MlaConfig) -> jax.Array:
+    """A dense layer's SwiGLU MLP (`w_gate`, `w_up`, `w_down`)."""
+    gate = jax.nn.silu(_mm(x, lp, "w_gate", cfg.dtype).astype(jnp.float32))
+    up = _mm(x, lp, "w_up", cfg.dtype).astype(jnp.float32)
+    return _mm((gate * up).astype(cfg.dtype), lp, "w_down", cfg.dtype)
+
+
+def _shared_expert(xf: jax.Array, lp: dict, cfg: MlaConfig) -> jax.Array:
+    """The shared expert's SwiGLU (`ws_gate`, `ws_up`, `ws_down`) on [N, H]."""
+    shared_gate = jax.nn.silu(
+        _mm(xf, lp, "ws_gate", cfg.dtype).astype(jnp.float32)
+    )
+    return _mm(
+        (shared_gate
+         * _mm(xf, lp, "ws_up", cfg.dtype).astype(jnp.float32))
+        .astype(cfg.dtype),
+        lp, "ws_down", cfg.dtype,
+    )
 
 
 def _deepseek_moe_ffn(
@@ -1010,15 +1114,7 @@ def _deepseek_moe_ffn(
             topw, topi = _gate(xf, lp, cfg)
         routed = _routed_experts(xf, topw, topi, lp, cfg, mesh, stack)
         with jax.named_scope("shared"):
-            shared_gate = jax.nn.silu(
-                _mm(xf, lp, "ws_gate", cfg.dtype).astype(jnp.float32)
-            )
-            shared = _mm(
-                (shared_gate
-                 * _mm(xf, lp, "ws_up", cfg.dtype).astype(jnp.float32))
-                .astype(cfg.dtype),
-                lp, "ws_down", cfg.dtype,
-            )
+            shared = _shared_expert(xf, lp, cfg)
         return (routed.astype(cfg.dtype) + shared).reshape(x.shape)
 
 
@@ -1056,11 +1152,6 @@ def forward_groups(
             for g in groups
         ]
 
-    def dense_ffn(x, lp):
-        gate = jax.nn.silu(_mm(x, lp, "w_gate", cfg.dtype).astype(jnp.float32))
-        up = _mm(x, lp, "w_up", cfg.dtype).astype(jnp.float32)
-        return _mm((gate * up).astype(cfg.dtype), lp, "w_down", cfg.dtype)
-
     def layer_of(ffn):
         def layer(carry, xs):
             h, kc, vc = carry
@@ -1094,7 +1185,7 @@ def forward_groups(
     carry = (h, kv.k, kv.v)
     staged = []
     for group, ffn, lo, hi in (
-        ("dense_layers", lambda x, lp, li: dense_ffn(x, lp), 0, nd),
+        ("dense_layers", lambda x, lp, li: _dense_ffn(x, lp, cfg), 0, nd),
         ("moe_layers", moe_ffn, nd, cfg.num_layers),
     ):
         if hi > lo:

@@ -62,7 +62,9 @@ class ModelAdapter:
     #: time — init_params + quantize_params peaks at full-model dtype
     #: size, which for 8B+ configs exceeds a single chip's HBM
     init_params_quantized: Optional[Callable[[jax.Array], Any]] = None
-    #: layers that keep a recurrent state a sequence (Mamba-2): 0 for every
+    #: layers that keep a state of FIXED size a sequence in the slot pool (a
+    #: recurrent state: Mamba-2, lightning attention; or the last tokens of
+    #: a window layer as a ring, models/dots3.py): 0 for every
     #: family whose only per-sequence state is pages. Where it is not 0 the
     #: engine hands `init_kv` a `state_slots` count, passes each row's
     #: (read, write) slot entries beside its page table (`pt` is then the
@@ -71,12 +73,19 @@ class ModelAdapter:
     state_layers: int = 0
     #: bytes of one sequence's state, one generation (state_layers > 0)
     state_slot_bytes: int = 0
+    #: True where the slot holds KV WRITTEN BY POSITION (a window layer's
+    #: ring): what a rolled-back dispatch wrote is written again before it
+    #: is read, so the pool keeps ONE generation, a row reads and writes
+    #: the same entry and a commit flips nothing. False for a recurrent
+    #: state, which is not benign in place (docs/engine.md)
+    state_in_place: bool = False
     #: a model whose decode walk reads a chosen part of a row's pages:
     #: cache -> the device's running count, int32 [4], of (pages the lists
     #: given to the walks named, pages those rows held, pages its sparse
-    #: prompt chunks' tiles read, pages their queries named); the engine
-    #: reads it beside each dispatch's ids (`EngineMetrics.walk_pages_named`
-    #: / `walk_pages_live` / `chunk_pages_read` / `chunk_pages_named`)
+    #: prompt chunks' tiles read, pages their queries named), or [5] with
+    #: the held experts its rows chose; the engine reads it beside each
+    #: dispatch's ids (`EngineMetrics.walk_pages_named` / `walk_pages_live`
+    #: / `chunk_pages_read` / `chunk_pages_named` / `moe_experts_touched`)
     walk_pages: Optional[Callable] = None
     #: False for a model whose step programs are dear to load: the engine
     #: then keeps ONE prefill-carrying program a shape where it would keep
@@ -536,6 +545,52 @@ def _keye_vl_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
     )
 
 
+def _dots3_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
+    """dots3-note-prev's language model (models/dots3.py) through
+    `_hybrid_adapter`: pages for its full layers (latent, rope key and the
+    indexer's keys) and a slot a sequence for its window layers' rings, ONE
+    generation (`state_in_place`). What the hybrid adapter cannot say is
+    replaced here: the refusals' sentences."""
+    from dynamo_tpu.models import dots3
+
+    base = _hybrid_adapter(
+        name, cfg, dots3, "dots3-note-prev", dots3.dots3_logical_axes, mesh)
+
+    def init_kv(num_pages, page_size, kv_quantize=None, state_slots=0):
+        if kv_quantize:
+            raise ValueError(
+                "kv_quantize is not supported for dots3-note-prev: its "
+                "cache IS the attention input (a shared latent a token, no "
+                "per-head rows to scale), its indexer scores index keys "
+                "kept in bfloat16 beside the pages and its window layers "
+                "read a ring in the slot pool; run with kv_quantize=None")
+        return dots3.init_cache(cfg, num_pages, page_size, state_slots)
+
+    why = ("a sequence of this family is its pages (latent, rope key and "
+           "the indexer's keys), and the rings of its window layers in the "
+           "slot pool, and this would move or rewind the latent and the "
+           "rope key alone")
+    return replace(
+        base, init_kv=init_kv, state_in_place=dots3.STATE_IN_PLACE,
+        refuses=tuple((what, why) for what in (
+            "kv_tiers", "speculation", "page_transfer")))
+
+
+def _dots3_presets() -> dict:
+    from dynamo_tpu.models.dots3 import Dots3Config
+
+    return {
+        # the language model of dots3-note-prev as published: 46 layers,
+        # 256 experts, 152,064 ids (shape tests and a later multi-chip issue)
+        "dots3-note-prev": Dots3Config.dots3_note_prev,
+        # one chip of its deployment: layers 0-8, 8 of the 256 experts, an
+        # eighth of the vocabulary (chipbench/configs/
+        # dots3-note-prev-1chip.json)
+        "dots3-note-prev-9l-8e": Dots3Config.dots3_1chip,
+        "dots3-tiny": Dots3Config.tiny,
+    }
+
+
 def _keye_vl_presets() -> dict:
     from dynamo_tpu.models.keye_vl import KeyeVLConfig
 
@@ -629,6 +684,7 @@ _STATE_FAMILIES = (
     (_falcon_h1_presets, _falcon_h1_adapter),
     (_minicpm_sala_presets, _minicpm_sala_adapter),
     (_keye_vl_presets, _keye_vl_adapter),
+    (_dots3_presets, _dots3_adapter),
 )
 
 
@@ -639,7 +695,8 @@ def list_presets() -> list[str]:
     return sorted(_LLAMA_PRESETS) + sorted(_moe_presets()) + sorted(
         _mla_presets()
     ) + sorted(_nemotron_h_presets()) + sorted(_falcon_h1_presets()) + sorted(
-        _minicpm_sala_presets()) + sorted(_keye_vl_presets())
+        _minicpm_sala_presets()) + sorted(_keye_vl_presets()) + sorted(
+        _dots3_presets())
 
 
 def get_model(

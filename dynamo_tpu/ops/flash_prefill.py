@@ -544,22 +544,31 @@ def _latent_kernel(
     qp_ref,  # [1, H, BQ, R] VMEM: their rope part, scaled, zeros past it
     lcur_ref,  # [1, T, C] VMEM: this chunk's latent rows (staged)
     rcur_ref,  # [1, T, R] VMEM: this chunk's rope keys as cached
-    lat_hbm,  # [L, P, S, C] ANY: the latent pool
-    rope_hbm,  # [L, P, S, R] ANY: the rope-key pool
+    # then (positional; `masked` adds the two in brackets):
+    #   [mh_ref]  # [1, BQ, MPP * S] VMEM int32: query x cached key
+    #   [mo_ref]  # [1, BQ, T] VMEM int32: query x chunk key
+    #   lat_hbm,  # [L, P, S, C] ANY: the latent pool
+    #   rope_hbm,  # [L, P, S, R] ANY: the rope-key pool
     # output
-    o_ref,  # [1, H, BQ, C] in the queries' dtype
+    #   o_ref,  # [1, H, BQ, C] in the queries' dtype
     # scratch
-    lat_scr,  # [2, PB*S, C] VMEM: a slot is a block of pages
-    rope_scr,  # [2, PB*S, R]
-    m_scr,  # [H*BQ, 128] f32 running max (every lane the same)
-    l_scr,  # [H*BQ, 128] f32 running denominator
-    acc_scr,  # [H*BQ, C] f32
-    sem,  # [2, 2] DMA semaphores: [plane, slot]
-    *,
+    #   lat_scr,  # [2, PB*S, C] VMEM: a slot is a block of pages
+    #   rope_scr,  # [2, PB*S, R]
+    #   m_scr,  # [H*BQ, 128] f32 running max (every lane the same)
+    #   l_scr,  # [H*BQ, 128] f32 running denominator
+    #   acc_scr,  # [H*BQ, C] f32
+    #   sem,  # [2, 2] DMA semaphores: [plane, slot]
+    *refs,
     page_size: int,
     block_pages: int,
     block_cur: int,
+    masked: bool = False,
 ):
+    mh_ref = mo_ref = None
+    if masked:
+        mh_ref, mo_ref, *refs = refs
+    (lat_hbm, rope_hbm, o_ref, lat_scr, rope_scr, m_scr, l_scr, acc_scr,
+     sem) = refs
     b = pl.program_id(0)
     qi = pl.program_id(1)
     li = layer_ref[0]
@@ -604,9 +613,10 @@ def _latent_kernel(
         jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), bq
     )
 
-    def fold(lat, rope, mask, first=False):
+    def fold(lat, rope, mask, first=False, keep=None):
         """One turn of the online softmax over keys `lat` [K, C] (they are
-        the values too) and `rope` [K, R]; `first` starts the state."""
+        the values too) and `rope` [K, R]; `first` starts the state;
+        `keep` [BQ, K] bool masks further, a query token at a time."""
         sc = jax.lax.dot_general(
             ql, lat, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -615,6 +625,10 @@ def _latent_kernel(
             preferred_element_type=jnp.float32,
         )  # [H*BQ, K]
         sc = jnp.where(mask, sc, _MASKED)
+        if keep is not None:  # one set a query token, shared by its heads
+            sc = jnp.where(
+                keep[None], sc.reshape(hn, bq, -1), _MASKED
+            ).reshape(rows, -1)
         m_cur = jnp.max(sc, axis=1, keepdims=True)
         m_new = m_cur if first else jnp.maximum(m_scr[:, :1], m_cur)
         p = jnp.exp(sc - m_new)
@@ -648,6 +662,7 @@ def _latent_kernel(
             fold(
                 lat, rcur_ref[0, at, :],
                 (col <= row_rel) & (col < cur), first=j == 0,
+                keep=None if mo_ref is None else mo_ref[0, :, at] != 0,
             )
 
         if j == 0:
@@ -670,11 +685,16 @@ def _latent_kernel(
         key_pos = i * (pb * s) + jax.lax.broadcasted_iota(
             jnp.int32, (1, pb * s), 1
         )
-        fold(lat_scr[slot], rope_scr[slot], key_pos < hist)
+        keep = None
+        if mh_ref is not None:
+            at = pl.ds(pl.multiple_of(i * (pb * s), pb * s), pb * s)
+            keep = mh_ref[0, :, at] != 0
+        fold(lat_scr[slot], rope_scr[slot], key_pos < hist, keep=keep)
         return 0
 
     jax.lax.fori_loop(0, n_blk, body, 0)
-    inv = 1.0 / l_scr[:, :1]
+    # (a query none of whose keys is chosen has no sum: only padding rows)
+    inv = 1.0 / (jnp.maximum(l_scr[:, :1], 1e-30) if masked else l_scr[:, :1])
     o_ref[0] = (acc_scr[...] * inv).astype(o_ref.dtype).reshape(hn, bq, c)
 
 
@@ -690,6 +710,7 @@ def latent_prefill_attention(
     hist_lens: jax.Array,  # [B] int32: tokens already written to pages
     cur_lens: jax.Array,  # [B] int32: valid tokens in this chunk
     *,
+    chosen: jax.Array | None = None,  # [B, T, MP * S] bool, by position
     interpret: bool | None = None,
     mesh=None,
 ) -> jax.Array:
@@ -704,6 +725,12 @@ def latent_prefill_attention(
     scores, softmax, sums and the accumulator are float32 and live in
     VMEM a tile at a time. A history of 0 runs no turn.
 
+    `chosen` names the keys each query attends, by position (a learned
+    indexer's choice, ops/token_select.py; models/dots3.py): every history
+    page is still read and scored, and a key not chosen is masked, as
+    `ops/sparse_chunk.token_chunk_attention` does for GQA pools; one set a
+    query token, shared by the tile's heads.
+
     Returns o_lat [B, T, H, C] in the queries' dtype (the float32
     quotient rounded once, on the way out: what the caller's value
     up-projection takes); rows past cur_lens are unspecified, and what
@@ -711,6 +738,8 @@ def latent_prefill_attention(
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if chosen is not None and mesh is not None:
+        raise ValueError("a latent chunk under chosen keys runs on one chip")
     if mesh is not None and mesh.shape.get("tp", 1) > 1:
         from jax.sharding import PartitionSpec as P
 
@@ -738,7 +767,13 @@ def latent_prefill_attention(
             f"them; got pools {k_cache.shape} / {v_cache.shape}, rows "
             f"{lat_cur.shape} / {rope_cur.shape}, q_pe {q_pe.shape}"
         )
-    bq = min(LATENT_BLOCK_Q, t)
+    # a tile's [H x BQ, .] rows stay what 16 heads of LATENT_BLOCK_Q make;
+    # under `chosen` (64-128 heads of a 512-1,024-wide latent) a tile's
+    # accumulator stays 2**19 numbers: Mosaic unrolls a tile's dots into
+    # MXU passes, and a [2048, 1024] tile took the TPU's compiler 12 s a
+    # step program (PERF.md 6, PR 48)
+    bq = min(LATENT_BLOCK_Q, t, max(8, 16 * LATENT_BLOCK_Q // hn)) if (
+        chosen is None) else min(t, max(8, (1 << 19) // (hn * c)))
     tp = -(-t // bq) * bq
     pb = min(LATENT_BLOCK_PAGES, page_tables.shape[1])
     if tp != t:  # whole query blocks; `cur_lens` masks the tail
@@ -749,7 +784,8 @@ def latent_prefill_attention(
     # head-major queries: a cell's [H, BQ, .] block is its folded tile
     ql = q_lat.transpose(0, 2, 1, 3)
     qp = q_pe.transpose(0, 2, 1, 3)
-    block_cur = math.gcd(tp, LATENT_BLOCK_CUR)
+    # (under `chosen` the chunk over itself is ONE turn: a fold less)
+    block_cur = math.gcd(tp, LATENT_BLOCK_CUR) if chosen is None else tp
 
     def q_block(width):
         return pl.BlockSpec(
@@ -761,15 +797,40 @@ def latent_prefill_attention(
             (1, tp, width), lambda bi, qi, li, pt, hl, cl: (bi, 0, 0)
         )
 
+    masks, mask_specs = [], []
+    if chosen is not None:
+        mp = page_tables.shape[1]
+        mpp = -(-mp // pb) * pb
+        pos = jnp.arange(mp * s, dtype=jnp.int32)[None, None]
+        live = chosen & (
+            jnp.arange(t, dtype=jnp.int32)[None] < cur_lens[:, None]
+        )[..., None]
+        # the chunk's own keys: columns `hist` .. `hist + T` of the mask
+        own = jax.vmap(lambda m, h: jax.lax.dynamic_slice_in_dim(
+            jnp.pad(m, ((0, 0), (0, t))), h, t, axis=1))(live, hist_lens)
+        pad_t = ((0, 0), (0, tp - t))
+        masks = [
+            jnp.pad((live & (pos < hist_lens[:, None, None])).astype(
+                jnp.int32), pad_t + ((0, (mpp - mp) * s),)),
+            jnp.pad(own.astype(jnp.int32), pad_t + ((0, tp - t),)),
+        ]
+        mask_specs = [
+            pl.BlockSpec((1, bq, width),
+                         lambda bi, qi, li, pt, hl, cl: (bi, qi, 0))
+            for width in (mpp * s, tp)
+        ]
+
     out = pl.pallas_call(
         functools.partial(
-            _latent_kernel, page_size=s, block_pages=pb, block_cur=block_cur
+            _latent_kernel, page_size=s, block_pages=pb, block_cur=block_cur,
+            masked=chosen is not None,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(b, tp // bq),
             in_specs=[
                 q_block(c), q_block(r), chunk_block(c), chunk_block(r),
+                *mask_specs,
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
@@ -800,7 +861,7 @@ def latent_prefill_attention(
         page_tables.astype(jnp.int32),
         hist_lens.astype(jnp.int32),
         cur_lens.astype(jnp.int32),
-        ql, qp, lat_cur, rope_cur,
+        ql, qp, lat_cur, rope_cur, *masks,
         # a page as [S, C] rows: the same bytes
         k_cache.reshape(*k_cache.shape[:3], c),
         v_cache.reshape(*v_cache.shape[:3], r),

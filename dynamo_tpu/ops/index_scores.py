@@ -249,19 +249,25 @@ def paged_index_scores(
     hist: jax.Array,  # [B] int32: tokens of each row the pool holds
     ki_own: jax.Array | None = None,  # [B, T, Di]: this chunk's own keys
     *,
+    paired: bool = True,
     interpret: bool | None = None,
 ):
     """The index scores of each query over its row's CACHED tokens, by
     position: float32 [B, T, MP * S], 0 from `hist` on. With `ki_own`
     also the scores over the step's own keys, float32 [B, T, T] (query x
     own key, no causal mask: the selection masks by context), for the
-    caller to put in at the rows' `hist`."""
+    caller to put in at the rows' `hist`.
+
+    `paired` False reads a pool [L, P, S, Di] that holds ONE layer's key a
+    row (models/dots3.py: a 128-wide key fills the lanes by itself): the
+    same kernel, a query in the whole row and the layer's pages at `layer
+    * P`."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, t, nj, di = qi.shape
     pairs, n_p, s, lanes = ki_pool.shape
     mp = tables.shape[1]
-    if lanes != 2 * di or w.shape != (b, t, nj) or (
+    if lanes != (2 if paired else 1) * di or w.shape != (b, t, nj) or (
             ki_own is not None and ki_own.shape != (b, t, di)):
         raise ValueError(
             f"pool {ki_pool.shape}, qi {qi.shape}, w {w.shape}, own keys "
@@ -269,7 +275,8 @@ def paged_index_scores(
     pb = min(INDEX_BLOCK_PAGES, mp)
     mpp = _round_up(mp, pb)
     n = pb * s
-    bq = min(INDEX_BLOCK_Q, t)
+    # a tile's [J x BQ, keys] scores stay what 16 heads of INDEX_BLOCK_Q make
+    bq = min(INDEX_BLOCK_Q, t, max(8, 16 * INDEX_BLOCK_Q // nj))
     tp = _round_up(t, bq)
     nt = tp // bq
     columns = n if n % INDEX_COLUMNS else INDEX_COLUMNS
@@ -277,6 +284,8 @@ def paged_index_scores(
 
     def lanes_of(x):
         """[.., Di] -> [.., 2 Di]: in the layer's half of a pool row."""
+        if not paired:
+            return x
         z = jnp.zeros_like(x)
         return jnp.where(odd, jnp.concatenate([z, x], axis=-1),
                          jnp.concatenate([x, z], axis=-1))
@@ -319,7 +328,7 @@ def paged_index_scores(
     i32 = jnp.int32
     hist = hist.astype(i32)
     held = -(-hist // s)  # pages
-    null = (jnp.asarray(layer, i32) // 2) * n_p
+    null = (jnp.asarray(layer, i32) // (2 if paired else 1)) * n_p
     pages = null + jnp.pad(jnp.where(
         jnp.arange(mpp, dtype=i32)[None] < held[:, None],
         jnp.pad(tables.astype(i32), ((0, 0), (0, mpp - mp))), 0),

@@ -455,17 +455,20 @@ def paged_decode_attention(
     acc comes back [B, Hq, C]. Same work list, same block rule.
 
     `token_bits` walks the same pages and attends only the cached tokens
-    it names (a bit a (row, position); models/keye_vl.py, whose indexer
-    chooses them): resident in VMEM as one int32 a key column.
+    it names (a bit a (row, position); models/keye_vl.py over GQA rows and
+    models/dots3.py over a latent, whose indexers choose them): resident
+    in VMEM as one int32 a key column.
 
     `interpret` defaults to True off-TPU so tests run the same kernel on CPU.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     quantized = k_scale is not None
-    if token_bits is not None and (quantized or latent or (
+    if token_bits is not None and (quantized or (
             mesh is not None and mesh.shape.get("tp", 1) > 1)):
-        raise ValueError("token_bits: an unquantized GQA cache on one chip")
+        raise ValueError(
+            "token_bits: an unquantized cache (GQA rows or a latent) on one "
+            "chip; quantized pages and a tp mesh walk without bits")
     hkv, s = k_cache.shape[3], k_cache.shape[2]
     if work_list is None:
         work_list = decode_work_list(page_tables, history_lens)
@@ -527,6 +530,12 @@ def paged_decode_attention(
         b, hq, d, s, hkv, itemsize, quantized, vmem_budget,
         dv if latent else 0,
     ))
+    if token_bits is not None and (pb * s * hkv) % 128:
+        # a block's bits are read at a lane-aligned offset: whole lane
+        # tiles of key columns a block (7 pages of 64 -> 6)
+        step = 128 // math.gcd(128, s * hkv)
+        if pb >= step:
+            pb = pb // step * step
     hqp = _round_up(hq, 8)
     if hqp != hq:  # whole sublane tiles of query heads; the pad is masked
         q = jnp.pad(q, ((0, 0), (0, hqp - hq), (0, 0)))

@@ -23,7 +23,8 @@ record into a bounded deque. A record holds, as `record_step` writes it:
   recurrent-state plane (`state_resets`, `state_restores`,
   `prefix_refused_state`), a selecting walk (`walk_pages_named`,
   `walk_pages_live`) and its sparse prompt chunks (`chunk_pages_read`,
-  `chunk_pages_named`), speculation (`spec_drafted`,
+  `chunk_pages_named`; `moe_experts_touched` where it counts the held
+  experts its rows chose), speculation (`spec_drafted`,
   `spec_accepted`), `compiles` / `compile_ms`, `preempted`, `tokens`,
   and the dry clock's counters (`dry_ms`, `dry_slack_ms`, `dry_wait_ms`,
   `dry_<phase>_ms`, `dry_launches`, `launches`);
@@ -100,6 +101,8 @@ _DELTA_FIELDS = (
     # and whose sparse prompt chunks read a page once a tile of queries
     ("chunk_pages_read", "chunk_pages_read"),
     ("chunk_pages_named", "chunk_pages_named"),
+    # and which counts the held experts its rows touched (a share's chip)
+    ("moe_experts_touched", "moe_experts_touched"),
     # speculative decoding (ngram or draft model): drafted/accepted per
     # step — a record with tokens but no spec_drafted is a plain step
     ("spec_drafted", "spec_drafted"),

@@ -4,15 +4,18 @@
 `jit_mixed_fn` and `jit_multi_fn`, ms a dispatch, each operation under
 the DEEPEST scope one of the benchmark's readers names (`mlp/moe/*` and
 `attn/absorb` of chipbench/subscopes.py, `attn/ssm/*` of ssmscopes.py,
-`attn/select` of sparsescopes.py, `attn/index` of indexscopes.py; `attn`
-alone is then what none of them names), the
+`attn/select` of sparsescopes.py, `attn/index` of indexscopes.py,
+`attn/window` and `attn/gate` of dots3scopes.py; `attn` alone is then what
+none of them names), the
 operations under `attn/flash` by name, and
 `latent_flash_ms_per_mixed_step` (ISSUE 39's reading: the prefill chunk's
 attention, scope `attn/flash` inside `jit_mixed_fn`, ms a mixed step; for
 a latent model the kernel `latent_prefill_attention`, floor 1.25 ms at
 `docgen`'s mean history; for MiniCPM-SALA the kernel
 `sparse_chunk_attention` and the tile lists it is handed; for Keye-VL the
-kernel `token_chunk_attention` and the masks it is handed).
+kernel `token_chunk_attention` and the masks it is handed; for
+dots3-note-prev `latent_prefill_attention` under its masks in the full
+layers, beside `attn/window`, the sliding layers' ring attention).
 Reads files only (run it after the benchmark's process has gone;
 `JAX_PLATFORMS=cpu` keeps it off the chip).
 
@@ -44,10 +47,11 @@ def load_deepest(path: str) -> dict:
     """`hostspans.load`'s dict with each device operation under the
     longest scope any of the deep readers gives it (they sort one
     trace's operations alike, so their lists run in step)."""
-    from chipbench import indexscopes, sparsescopes, ssmscopes, subscopes
+    from chipbench import (dots3scopes, indexscopes, sparsescopes, ssmscopes,
+                           subscopes)
 
-    loads = [m.load_deep(path)
-             for m in (subscopes, ssmscopes, sparsescopes, indexscopes)]
+    loads = [m.load_deep(path) for m in (
+        subscopes, ssmscopes, sparsescopes, indexscopes, dots3scopes)]
     devices = {
         plane: {"modules": dev["modules"], "ops": [
             max(same, key=lambda op: len(op[3])) for same in zip(

@@ -892,6 +892,103 @@ def test_keye_vl_step_compiles_at_published_widths(topo, rows, t, b_pre):
         assert copy not in text, copy
 
 
+@pytest.mark.parametrize("rows,t,b_pre", [
+    pytest.param(32, 1, 0, id="decode-32-rows-fused-8"),
+    pytest.param(32, 512, 4, id="mixed-32-rows-beside-four-chunks"),
+    pytest.param(32, 32, 32, id="mixed-32-rows-beside-32-short-prompts"),
+])
+def test_dots3_step_compiles_at_published_widths(topo, rows, t, b_pre):
+    """Whole steps of `dots3-note-prev-9l-8e` as `dots3-longctx` serves it
+    (bf16, 9,000 pages of 64 in 3 full layers with their index keys, 36
+    ring slots of 1,088 rows in 6 window layers, --max-context 18432): the
+    index scores read out of the one-row pool in place (64 heads), the
+    sort-free selection, the decode rows' LATENT page walk under a bit a
+    token (128 heads: in pieces that fit the kernel's VMEM budget), a
+    prompt chunk's latent kernel under a mask bit a (query, key), the
+    window layers' ring written by position and read whole, the grouped
+    matmuls over the 8 held experts: every pool updated in place, the
+    program beside 6.2 GB of weights, 2.4 GB of pages and 0.5 GB of rings
+    inside the chip."""
+    adapter = get_model("dots3-note-prev-9l-8e", dtype="bfloat16",
+                        attention_impl="pallas")
+    chip = SingleDeviceSharding(topo.devices[0])
+    params = _on(chip, jax.eval_shape(
+        lambda: adapter.init_params(jax.random.key(0))))
+    kv = _on(chip, jax.eval_shape(
+        lambda: adapter.init_kv(9000, PAGE, state_slots=36)))
+    assert kv.ki.shape == (3, 9000, PAGE, 128)
+    assert kv.k.shape == (3, 9000, PAGE, 1, 512)
+    assert kv.ring.shape == (6, 37, 1088, 1024)
+    assert kv.ring_pe.shape == (6, 37, 1088, 128)
+    mp = 18432 // PAGE
+
+    def rows_of(b, tt):
+        return (
+            _sds((b, tt), jnp.int32, chip), _sds((b, tt), jnp.int32, chip),
+            _sds((b, tt), jnp.bool_, chip),
+            (_sds((b, mp), jnp.int32, chip), _sds((b, 2), jnp.int32, chip)),
+        )
+
+    def head(params, hidden):
+        logits = adapter.compute_logits(params, hidden[:, -1])
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    if b_pre:
+
+        def program(params, kv, prompt, decode):
+            h_p, h_d, kv = adapter.forward_hidden_mixed(
+                params, prompt, decode, kv)
+            return head(params, h_d), kv
+
+        args = (rows_of(b_pre, t), rows_of(rows, 1))
+    else:
+
+        def program(params, kv, tokens, positions, valid, pt):
+            def body(carry, _):
+                tokens, positions, kv = carry
+                hidden, kv = adapter.forward_hidden(
+                    params, tokens, positions, valid, kv, pt)
+                ids = head(params, hidden)
+                return (ids[:, None], positions + 1, kv), ids
+
+            (_, _, kv), ids = jax.lax.scan(
+                body, (tokens, positions, kv), None, length=8)
+            return ids, kv
+
+        args = rows_of(rows, t)
+
+    compiled = jax.jit(program, donate_argnums=(1,)).lower(
+        params, kv, *args).compile()
+    mem = compiled.memory_analysis()
+    pools = sum(np.prod(x.shape) * x.dtype.itemsize
+                for x in (kv.k, kv.v, kv.ki, kv.ring, kv.ring_pe))
+    print("dots3 compile", rows, t, b_pre, "temp", mem.temp_size_in_bytes,
+          "args", mem.argument_size_in_bytes, "alias",
+          mem.alias_size_in_bytes, "pools", pools)
+    assert mem.alias_size_in_bytes >= pools  # every pool in place
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    text = compiled.as_text()
+    # ONE body a kind of layer: each kernel once a full layer's body and
+    # once a window layer's (the ring walked as the latent pages it is)
+    print({k: _kernel_calls(text, k) for k in (
+        "latent_prefill_attention", "paged_decode_attention",
+        "paged_index_scores", "paged_index_scores_chunk")})
+    assert _kernel_calls(text, "latent_prefill_attention") == (
+        2 if b_pre else 0)
+    assert _kernel_calls(text, "paged_decode_attention") == 2
+    assert _kernel_calls(text, "paged_index_scores") == 1
+    assert _kernel_calls(text, "paged_index_scores_chunk") == (
+        1 if b_pre else 0)
+    # no copy of a pool: neither a layer of the rings nor the index keys
+    for copy in ("bf16[6,37,1088,1024]", "bf16[37,1088,1024]",
+                 "bf16[6,629,64,1,1024]", "bf16[32,1088,1024]",
+                 "bf16[3,9000,64,128]", "bf16[9000,64,128]",
+                 "bf16[3,9000,64,1,512]", "bf16[9000,64,1,512]",
+                 f"bf16[{rows},{mp * PAGE},128]"):
+        assert not re.search(
+            rf"= {re.escape(copy)}[^ ]* copy\(", text), copy
+
+
 @pytest.mark.parametrize("rows,vocab", [
     (64, 152_064),  # qwen2-longgen
     (32, 261_120),  # falconh1-longdoc
