@@ -57,12 +57,29 @@ ops/paged_attention.py `latent` + `token_bits`), a prompt chunk by tile
 under a mask bit a (query, key) (`attn/flash`, ops/flash_prefill.py
 `latent_prefill_attention` with `chosen`); the step's rows are staged and
 land once. A ring IS a few pages of a latent cache (1,088 rows = 17 pages
-of 64 a slot), so the S layers run the SAME two kernels under
-`attn/window`: the walk over the slot's ring pages under a bit a ring row
-(its position inside the query's window) and the chunk kernel under a
-mask a (query, ring row), the step's own rows in hand and written into the
-ring after the layer's attention. Without the kernels the rows are written
-first and the whole ring is attended in plain XLA.
+of 64 a slot), and under `attn/window` an S layer takes one of two FORMS
+by the shape of the group it is handed (`plain_piece`), the step's own
+rows in hand and written into the ring after the layer's attention:
+
+- ABSORBED (`window_attend`), as a long latent history needs and as the F
+  layers do: the SAME two kernels over the slot's ring pages in reach, the
+  decode walk under a bit a ring row (its position inside the query's
+  window) and the chunk kernel under a mask a (query, ring row). A DECODE
+  row (T = 1) and a SHORT piece (a 32-token tail, the ramp's short
+  prompts): few queries, so the latent-wide query and output cost little
+  and no key is up-projected;
+- PLAIN (`window_piece`), as latent models prefill: a prompt PIECE whose T
+  rows are not few beside the 576 ring rows in reach (4 T >= 576: a
+  512-token piece). A window reaches 512 keys behind a query, so the keys
+  are few (at most 1,088: 9 ring pages + the piece's 512 rows). They go
+  through `wkv_b` ONCE into heads, `K_h = (W_UK,h c | k_r)`, `V_h = W_UV,h
+  c`; the queries go in as projected; one banded flash pass by position
+  (ops/flash_prefill.py `window_prefill_attention`) at 768 FLOP a (query,
+  key, head) where the absorbed form costs 4,224 and needs a query and an
+  output of the latent's width, [512, 64, 1024] float32 each, around it.
+
+Without the kernels the rows are written first and the whole ring is
+attended absorbed in plain XLA (`ring_attention`: the tests' yardstick).
 """
 
 from __future__ import annotations
@@ -248,6 +265,15 @@ class Dots3Config:
         """The longest run of positions one dispatch may write (a chunk's
         T): what `ring_tokens` leaves beside the window (module text)."""
         return self.ring_tokens - (self.sliding_window - 1)
+
+    @property
+    def ring_reach(self) -> int:
+        """The ring rows a step's windows can reach: the whole pages that
+        hold the `sliding_window - 1` positions before the step's first
+        (`window_pages`: 9 pages = 576 of the 1,088 rows)."""
+        s = self.ring_page
+        return s * min(self.ring_tokens // s,
+                       -(-(self.sliding_window - 1) // s) + 1)
 
     @property
     def ring_width(self) -> int:
@@ -739,7 +765,7 @@ def window_pages(positions, valid, cfg: Dots3Config):
     pages of the ring (9 of 17 at the published sizes): the walk reads
     those and not the whole ring."""
     s, pages = cfg.ring_page, cfg.ring_tokens // cfg.ring_page
-    n = min(pages, -(-(cfg.sliding_window - 1) // s) + 1)
+    n = cfg.ring_reach // s
     first = jnp.where(valid[:, 0], positions[:, 0], 0)
     base = (first - (cfg.sliding_window - 1)) // s * s  # may be negative
     page = (base[:, None] // s + jnp.arange(n, dtype=jnp.int32)[None]) % pages
@@ -765,6 +791,79 @@ def window_keep(positions, valid, base, held: int, window: int,
     return jnp.concatenate([
         ring, mine, jnp.zeros((*positions.shape, columns - held - t), bool)
     ], axis=-1) & valid[..., None]
+
+
+def window_piece(qn, qp, ck, kp, rings, layer, g: StepGroup,
+                 cfg: Dots3Config, wkv_b):
+    """Under the kernels, a prompt piece's attention in a sliding layer in
+    the PLAIN form, and its rows' way into the ring after it. A window
+    reaches `sliding_window - 1` keys behind a query, so a piece's keys are
+    few: the ring pages in reach (`window_pages`, read by page: column i
+    holds position `base + i`, a key where that is not negative and before
+    the piece's first) and the piece's own rows (row j its position where
+    valid), at most 1,088 at the published sizes, padded to whole 128-key
+    blocks while a row is one latent wide. They go through `wkv_b` [c, H,
+    nope + v] ONCE into heads, `K_h = (W_UK,h c | k_r)` with the one rope
+    key a token under every head and `V_h = W_UV,h c` (float32 sums,
+    rounded once to the model dtype); the queries `qn` [B, T, H, nope] and
+    `qp` [B, T, H, r] go in as projected, scaled; one banded pass by
+    position (ops/flash_prefill.py `window_prefill_attention`; query j's
+    furthest key stands at most `ring_reach` columns before its own, none
+    before column j). Returns (o [B, T, H, v], rings): no query and no
+    output of the latent's width exists."""
+    from dynamo_tpu.ops.flash_prefill import window_prefill_attention
+
+    geo = cfg.swa_geo
+    b, t = g.positions.shape
+    if t > cfg.ring_run:
+        raise ValueError(
+            f"a chunk of {t} tokens would overwrite ring rows its own "
+            f"windows need: ring_tokens {cfg.ring_tokens} leaves a run of "
+            f"{cfg.ring_run}")
+    s, pages = cfg.ring_page, cfg.ring_tokens // cfg.ring_page
+    n, rr = geo.qk_nope_head_dim, geo.qk_rope_head_dim
+    dt, f32 = cfg.dtype, jnp.float32
+    slots = g.state_rows[:, 1]
+    base, page = window_pages(g.positions, g.valid, cfg)
+    held = page.shape[1] * s
+    first = jnp.where(g.valid[:, 0], g.positions[:, 0], 0)
+    at = ((layer * rings[0].shape[1] + slots) * pages)[:, None] + page
+    lat, rope = (ring.reshape(-1, s, ring.shape[-1])[at].reshape(b, held, -1)
+                 for ring in rings)
+    cached = base[:, None] + jnp.arange(held, dtype=jnp.int32)[None]
+    more = -(held + t) % 128
+    k_pos = jnp.concatenate([
+        jnp.where((cached >= 0) & (cached < first[:, None]), cached, -1),
+        jnp.where(g.valid, g.positions, -1),
+        jnp.full((b, more), -1, jnp.int32)], axis=1)
+    # a row that holds no key goes in as zeros: a ring row is whatever its
+    # last owner left, and as a value a zero weight does not silence a NaN
+    rows = jnp.where((k_pos >= 0)[..., None], jnp.pad(jnp.concatenate([
+        jnp.concatenate([lat, rope[..., :rr]], -1),
+        jnp.concatenate([ck.astype(dt), kp], -1)], axis=1),
+        ((0, 0), (0, more), (0, 0))), 0)  # [B, K, c + r]
+    # the WEIGHTS lay a head's key out, (W_UK,h | 0) over (0 | I): the
+    # product comes out as the kernel reads it, the rope key under every
+    # head; concatenating products cost two more passes over K and a
+    # transposing copy of K and of V (PERF.md 6, PR 50)
+    lay = jnp.pad(jnp.eye(rr, dtype=dt), ((0, 0), (n, 0)))  # [r, n + r]
+    w_k = jnp.concatenate([
+        jnp.pad(wkv_b[..., :n], ((0, 0), (0, 0), (0, rr))),
+        jnp.broadcast_to(lay[:, None], (rr, wkv_b.shape[1], n + rr))])
+    k = jnp.einsum("bkc,chd->bkhd", rows, w_k,
+                   preferred_element_type=f32).astype(dt)
+    v = jnp.einsum("bkc,chd->bkhd", rows[..., :-rr], wkv_b[..., n:],
+                   preferred_element_type=f32).astype(dt)
+    q = (jnp.concatenate([qn, qp], -1).astype(f32)
+         * geo.softmax_scale).astype(dt)
+    o = window_prefill_attention(
+        q, k, v, jnp.where(g.valid, g.positions, first[:, None]), k_pos,
+        window=cfg.sliding_window)
+    with jax.named_scope("kv_update"):
+        rings = ring_write(rings, layer, ck,
+                           mla._pad_last(kp, geo.kv_rope_dim), slots,
+                           g.positions, g.valid)
+    return o, rings
 
 
 def window_attend(ql, qp, ck, kp, rings, layer, g: StepGroup,
@@ -819,30 +918,60 @@ def window_attend(ql, qp, ck, kp, rings, layer, g: StepGroup,
     return o_lat, rings
 
 
+def plain_piece(t: int, cfg: Dots3Config) -> bool:
+    """Whether a group of T rows attends a sliding layer in the plain form
+    (`window_piece`) under the kernels. That form up-projects `ring_reach +
+    T` rows for T queries, where the absorbed one carries a latent-wide
+    query and output a query: it pays where T is not small beside the ring
+    rows in reach. On the chip a layer's block reads, plain against
+    absorbed, ms a piece of T tokens (PERF.md 6, PR 50): 1.68 / 2.53 at
+    512, 1.16 / 1.52 at 256, 0.99 / 1.10 at 128, 1.05 / 0.92 at 64, 0.92 /
+    0.82 at 32, and 13.3 / 4.5 for 32 prompts of 32 tokens from position
+    0: the two cross between 64 and 128 tokens."""
+    return cfg.kernels and 4 * t >= cfg.ring_reach
+
+
 def window_attention(x, lp, cfg: Dots3Config, rings, layer, groups):
-    """A sliding layer's attention block on the groups' rows
-    (`window_attend` a group). Returns (out, rings). Scopes: `qkv`,
-    `absorb`, `window` (and `kv_update` inside it), `gate`, `out`."""
+    """A sliding layer's attention block on the groups' rows: the
+    projections and `wo` on all rows at once, the attention a group in the
+    form its shape asks for (`plain_piece`). Under the kernels a group
+    whose T rows are not few beside the ring rows in reach (a 512-token
+    piece beside 576) attends PLAIN (`window_piece`); every other group, a
+    decode row (T = 1) and a short piece (32 tokens: a tail, the ramp's
+    short prompts), attends ABSORBED (`window_attend`), its rows alone
+    through `absorbed_query` and the value up-projection (32 of a mixed
+    step's 544). Returns (out, rings). Scopes: `qkv`, `absorb`, `window`
+    (and `kv_update` inside it), `gate`, `out`."""
     geo = cfg.swa_geo
     n = geo.qk_nope_head_dim
     q, c_kv, kv_a, _ = mla.latent_projections(x, lp, geo, cfg.rescale(geo))
-    q_lat, w_uv = mla.absorbed_query(q, lp, geo)
     gate = head_gate(x, lp, cfg)
-    o_lats = []
-    parts = (q_lat, q[..., n:], c_kv, kv_a[..., geo.kv_lora_rank:])
-    for g, ql, qp, ck, kp in zip(
+    outs = []
+    parts = (q, c_kv, kv_a[..., geo.kv_lora_rank:])
+    for g, qg, ck, kp in zip(
         groups, *(split_rows(a, groups) for a in parts)
     ):
         with jax.named_scope("qkv"):
-            qp = mla._interleaved_rope(qp, g.positions, geo)
+            qp = mla._interleaved_rope(qg[..., n:], g.positions, geo)
             kp = mla._interleaved_rope(kp, g.positions, geo).astype(cfg.dtype)
-        with jax.named_scope("window"):
-            o_lat, rings = window_attend(
-                ql, qp, ck, kp, rings, layer, g, cfg)
-        o_lats.append(o_lat.astype(w_uv.dtype))
+        if plain_piece(g.positions.shape[1], cfg):
+            wkv_b = mla._w(lp, "wkv_b", cfg.dtype).reshape(
+                geo.kv_lora_rank, geo.num_heads, n + geo.v_head_dim)
+            with jax.named_scope("window"):
+                o, rings = window_piece(
+                    qg[..., :n], qp, ck, kp, rings, layer, g, cfg, wkv_b)
+        else:
+            q_lat, w_uv = mla.absorbed_query(qg, lp, geo)
+            with jax.named_scope("window"):
+                o_lat, rings = window_attend(
+                    q_lat, qp, ck, kp, rings, layer, g, cfg)
+            with jax.named_scope("out"):
+                o = jnp.einsum(
+                    "...hc,chv->...hv", o_lat.astype(w_uv.dtype), w_uv,
+                    preferred_element_type=jnp.float32)
+        outs.append(o.astype(jnp.float32))
     with jax.named_scope("out"):
-        return (mla.latent_output(join_rows(o_lats), w_uv, lp, geo, gate),
-                rings)
+        return mla.heads_output(join_rows(outs), lp, geo, gate), rings
 
 
 # ---------------------------------------------------------------------------

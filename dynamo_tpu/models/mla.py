@@ -784,10 +784,16 @@ def latent_output(o_lat, w_uv, lp, cfg: MlaConfig, gate=None):
     dtype): the value up-projection, then `wo`; `gate` [.., heads]
     multiplies each head's output before `wo` (models/dots3.py). The
     caller names the scope."""
-    out = jnp.einsum(
+    return heads_output(jnp.einsum(
         "...hc,chv->...hv", o_lat, w_uv,
         preferred_element_type=jnp.float32,
-    )
+    ), lp, cfg, gate)
+
+
+def heads_output(out, lp, cfg: MlaConfig, gate=None):
+    """`wo` over the heads' outputs out [.., heads, v] (`latent_output`'s
+    second half: models/dots3.py has rows that come out of attention up-
+    projected already)."""
     if gate is not None:
         out = out * gate[..., None].astype(jnp.float32)
     out = out.reshape(*out.shape[:-2], -1).astype(cfg.dtype)

@@ -867,3 +867,114 @@ def latent_prefill_attention(
         v_cache.reshape(*v_cache.shape[:3], r),
     )
     return out[:, :, :t].transpose(0, 2, 1, 3)
+
+
+# ---------------------------------------------------------------------------
+# A band by position (models/dots3.py: a window layer's prompt piece)
+# ---------------------------------------------------------------------------
+
+
+def _window_kernel(
+    q_ref,  # [1, BQ, G x D]: G heads' queries, scaled
+    k_ref,  # [1, K, G x D]: those heads' keys
+    v_ref,  # [1, K, G x Dv]: their values
+    qpos_ref,  # [1, BQ, 1] int32: the queries' positions
+    kpos_ref,  # [1, 1, K] int32: the keys' positions; negative: no key
+    o_ref,  # [1, BQ, G x Dv]
+    *,
+    window: int,
+    width: int,
+    heads: int,
+):
+    bq = o_ref.shape[1]
+    d, dv = q_ref.shape[2] // heads, o_ref.shape[2] // heads
+    # (one tile takes every column: its first is 0, whatever BQ)
+    cols = pl.ds(pl.multiple_of(pl.program_id(2) * bq, 128)
+                 if k_ref.shape[1] > width else 0, width)
+    at = qpos_ref[0]  # [BQ, 1]
+    key = kpos_ref[0, :, cols]  # [1, W]
+    keep = (key >= 0) & (key <= at) & (key >= at - (window - 1))  # [BQ, W]
+    # the heads' chains are independent: the scheduler lays one head's
+    # softmax under another's MXU passes
+    for g in range(heads):
+        s = jax.lax.dot_general(
+            q_ref[0, :, g * d:(g + 1) * d], k_ref[0, cols, g * d:(g + 1) * d],
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        )  # [BQ, W]
+        s = jnp.where(keep, s, _MASKED)
+        p = jnp.where(
+            keep, jnp.exp(s - jnp.max(s, axis=1, keepdims=True)), 0.0)
+        v = v_ref[0, cols, g * dv:(g + 1) * dv]
+        o = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        o_ref[0, :, g * dv:(g + 1) * dv] = (
+            o / jnp.maximum(jnp.sum(p, axis=1, keepdims=True), 1e-30)
+        ).astype(o_ref.dtype)
+
+
+def window_prefill_attention(
+    q: jax.Array,  # [B, T, H, D] queries, SCALED
+    k: jax.Array,  # [B, K, H, D] keys
+    v: jax.Array,  # [B, K, H, Dv]
+    q_pos: jax.Array,  # [B, T] int32
+    k_pos: jax.Array,  # [B, K] int32; negative: the row holds no key
+    *,
+    window: int,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """A prompt piece's attention under a sliding window stated by
+    POSITION, its few keys in hand as heads (models/dots3.py
+    `window_piece`: the ring rows in reach, then the piece's own T rows,
+    then padding): a query at position t attends the keys whose position
+    lies in `[t - (window - 1), t]`. The caller lays the keys out so that
+    query j reaches no column before j and none after `K - T + j`; a grid
+    cell, (row of the batch, 4 heads, tile of 256 queries), then takes the
+    `K - T + 256` columns from its tile's first on as ONE tile (896 keys
+    at the published 513 in a ring page of 64): one softmax, no chain of
+    corrections, no mask array, the columns no query of the tile can reach
+    not read. Heads are picked by the block index out of the arrays as they
+    come, [.., H x D]: nothing is transposed. Operands reach the MXU in the
+    dtype they come in; scores and softmax are float32 in VMEM.
+
+    Returns [B, T, H, Dv] in the queries' dtype; a query with no key in its
+    band (padding) gets zeros.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, t, hn, d = q.shape
+    kk, dv = k.shape[1], v.shape[3]
+    bq = 256 if t % 256 == 0 else t
+    g = math.gcd(hn, 4)
+    width = kk - t + bq
+
+    def heads(rows, n):
+        return pl.BlockSpec((1, rows, g * n), (
+            (lambda bi, h, qi: (bi, qi, h)) if rows == bq
+            else (lambda bi, h, qi: (bi, 0, h))))
+
+    out = pl.pallas_call(
+        functools.partial(_window_kernel, window=window, width=width,
+                          heads=g),
+        grid=(b, hn // g, t // bq),
+        in_specs=[
+            heads(bq, d), heads(kk, d), heads(kk, dv),
+            pl.BlockSpec((1, bq, 1), lambda bi, h, qi: (bi, qi, 0)),
+            pl.BlockSpec((1, 1, kk), lambda bi, h, qi: (bi, 0, 0)),
+        ],
+        out_specs=heads(bq, dv),
+        out_shape=jax.ShapeDtypeStruct((b, t, hn * dv), q.dtype),
+        interpret=interpret,
+        name="window_prefill_attention",
+        # a cell's keys and values stay in VMEM, twice (the pipeline's two
+        # buffers): 7.1 MB at 1,152 keys of 4 x (256 + 128)
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024
+        ),
+    )(
+        q.reshape(b, t, hn * d), k.reshape(b, kk, hn * d),
+        v.reshape(b, kk, hn * dv),
+        q_pos.astype(jnp.int32)[..., None], k_pos.astype(jnp.int32)[:, None],
+    )
+    return out.reshape(b, t, hn, dv)

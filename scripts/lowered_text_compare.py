@@ -138,6 +138,17 @@ def cell_kernels():
         S((bk, hq, d), bf), S((Lk, Pk, s, hkv, d), bf),
         S((Lk, Pk, s, hkv, d), bf), S((), i32), S((bk, mpk), i32),
         S((bk,), i32), S((bk, mpk * s), jnp.bool_)))
+    # qwen2-longgen / phi3-chat-closed / falconh1-longdoc: the GQA chunk
+    # kernels (qwen2-7b: 28 / 4 heads of 128, a 512-token chunk)
+    hq, hkv = 28, 4
+    qkv = (S((1, 512, hq, d), bf), S((1, 512, hkv, d), bf),
+           S((1, 512, hkv, d), bf))
+    res["qwen2/flash_prefill"] = sha(tpu_text(
+        flash_prefill.flash_prefill_attention, *qkv, S((1,), i32)))
+    res["qwen2/paged_prefill"] = sha(tpu_text(
+        flash_prefill.paged_prefill_attention, *qkv,
+        S((28, 1700, s, hkv, d), bf), S((28, 1700, s, hkv, d), bf),
+        S((), i32), S((1, 128), i32), S((1,), i32), S((1,), i32)))
 
 
 try:

@@ -302,6 +302,145 @@ def test_the_windows_two_ends(impl, path, key, seen):
     assert (np.abs(moved - base).max() > 1e-3) == seen
 
 
+# -- (b') a prompt piece in the plain form == the ring attended absorbed --------
+
+
+@pytest.mark.parametrize("firsts,valid,filled", [
+    pytest.param((0,), 16, None, id="a-piece-that-starts-at-position-0"),
+    pytest.param((208,), 16, None, id="a-ring-that-wrapped-four-times"),
+    pytest.param((64,), 11, None, id="a-piece-shorter-than-its-bucket"),
+    pytest.param((32, 150), 16, None,
+                 id="two-pieces-of-different-positions-in-one-group"),
+    pytest.param((80,), 16, 7.0, id="a-slot-whose-last-owner-left-rows"),
+])
+def test_a_piece_in_the_plain_form_is_the_ring_attended_absorbed(
+        firsts, valid, filled):
+    """Under the kernels a window layer's prompt piece up-projects the keys
+    in reach once and attends them as projected (`window_piece`), where
+    the path without kernels writes the rows first and attends the whole
+    ring absorbed (`ring_attention`): the same sums in another order. Each
+    sequence is written into its slot's ring piece by piece up to the
+    judged one; that one's invalid tail holds NaN (it reaches no valid
+    row); a decode step after it reads the same ring through both paths,
+    and the two rings hold the same rows where either was written."""
+    b, t = len(firsts), 16
+    cfgs = {impl: dataclasses.replace(
+        dots3.Dots3Config.tiny(), attention_impl=impl)
+        for impl in ("xla", "pallas")}
+    geo = cfgs["xla"].swa_geo
+    hn, c, n = geo.num_heads, geo.kv_lora_rank, geo.qk_nope_head_dim
+    rr, vd = geo.qk_rope_head_dim, geo.v_head_dim
+    rng = np.random.default_rng(sum(firsts) + valid)
+    normal = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.normal(size=shape), jnp.float32)
+    total = max(firsts) + t + 1
+    wkv_b = normal(c, hn, n + vd) / np.sqrt(c)
+    qn, qp = normal(b, total, hn, n) * 0.5, normal(b, total, hn, rr) * 0.5
+    ck, kp = normal(b, total, c), normal(b, total, rr)
+    layer = jnp.int32(1)
+    slots = jnp.asarray([[2, 2], [1, 1]], jnp.int32)[:b]
+
+    def step(rings, impl, seqs, lo, width, live):
+        """Sequences `seqs` attend their `width` rows from `lo` on (the
+        first `live` valid, the rest NaN) and write them into their rings:
+        the plain form under the kernels, absorbed without them."""
+        cfg = cfgs[impl]
+        pos = jnp.asarray(lo)[:, None] + jnp.arange(width, dtype=jnp.int32)
+        g = StepGroup(
+            jnp.zeros_like(pos), pos,
+            jnp.broadcast_to(jnp.arange(width) < live, pos.shape),
+            jnp.zeros((len(seqs), 1), jnp.int32),
+            state_rows=slots[jnp.asarray(seqs)])
+        q, *cut = (
+            jnp.stack([a[i, f:f + width] for i, f in zip(seqs, lo)]
+                      ).at[:, live:].set(jnp.nan) for a in (qn, qp, ck, kp))
+        if impl == "pallas" and width > 1:
+            return dots3.window_piece(q, *cut, rings, layer, g, cfg, wkv_b)
+        o_lat, rings = dots3.window_attend(
+            jnp.einsum("bthn,chn->bthc", q, wkv_b[..., :n]), *cut, rings,
+            layer, g, cfg)
+        return jnp.einsum("bthc,chv->bthv", o_lat, wkv_b[..., n:]), rings
+
+    def serve(impl):
+        cache = dots3.init_cache(cfgs[impl], 4, PAGE, 2)
+        rings = (cache.ring, cache.ring_pe)
+        if filled is not None:
+            rings = tuple(r + filled for r in rings)
+        # every sequence up to its judged piece, a piece a call
+        for i, f in enumerate(firsts):
+            for lo in range(0, f, t):
+                _, rings = step(rings, impl, [i], [lo], t, min(t, f - lo))
+        seqs = list(range(b))
+        o, rings = step(rings, impl, seqs, list(firsts), t, valid)
+        o_d, rings = step(
+            rings, impl, seqs, [f + valid for f in firsts], 1, 1)
+        return (np.asarray(o)[:, :valid], np.asarray(o_d),
+                [np.asarray(r)[1, 1:, :, :w] for r, w in zip(rings, (c, rr))])
+
+    want, got = serve("xla"), serve("pallas")
+    np.testing.assert_allclose(got[0], want[0], atol=2e-5)
+    np.testing.assert_allclose(got[1], want[1], atol=2e-5)
+    assert np.isfinite(got[0]).all() and np.abs(want[0]).max() > 0.1
+    for mine, theirs in zip(got[2], want[2]):
+        np.testing.assert_array_equal(mine, theirs)
+
+
+@pytest.mark.parametrize("first", [
+    pytest.param(0, id="from-position-0"),
+    pytest.param(5, id="fewer-keys-than-a-window"),
+    pytest.param(70, id="past-a-wrap-of-the-ring"),
+    pytest.param(64, id="a-piece-that-starts-on-a-ring-page"),
+])
+def test_a_plain_piece_attends_exactly_its_window(first):
+    """With zero queries the softmax is uniform over the kept keys, and
+    with the latent of position k the (k mod 16)-th unit vector under a
+    `W_UV` that copies it the output NAMES them: a query at position p
+    attends the keys at `max(0, p - 8) .. p` (a window of 9), 9 of them
+    once it has them, its own among them and not the key 9 before it; a
+    ring row at or past the piece's first position (a stale row from a
+    wrap ago) is no key."""
+    cfg = dataclasses.replace(
+        dots3.Dots3Config.tiny(), attention_impl="pallas")
+    geo = cfg.swa_geo
+    hn, c, n = geo.num_heads, geo.kv_lora_rank, geo.qk_nope_head_dim
+    rr, vd, t = geo.qk_rope_head_dim, geo.v_head_dim, 16
+    assert cfg.sliding_window == 9 and 4 * t >= cfg.ring_reach
+    total = first + t
+    names = jnp.eye(c, dtype=jnp.float32)[jnp.arange(total) % vd][None]
+    wkv_b = jnp.zeros((c, hn, n + vd)).at[:vd, :, n:].set(
+        jnp.eye(vd)[:, None])
+    cache = dots3.init_cache(cfg, 4, PAGE, 2)
+    # what a wrap ago left: rows that would name every key at once
+    rings = (cache.ring + 1.0, cache.ring_pe + 1.0)
+    layer, slot = jnp.int32(1), jnp.asarray([[2, 2]], jnp.int32)
+
+    def group(lo, hi):
+        pos = jnp.arange(lo, hi, dtype=jnp.int32)[None]
+        return StepGroup(jnp.zeros_like(pos), pos, jnp.ones_like(pos, bool),
+                         jnp.zeros((1, 1), jnp.int32), state_rows=slot)
+
+    for lo in range(0, first, t):
+        hi = min(lo + t, first)
+        rings = dots3.ring_write(
+            rings, layer, names[:, lo:hi],
+            jnp.zeros((1, hi - lo, geo.kv_rope_dim)), slot[:, 1],
+            group(lo, hi).positions, jnp.ones((1, hi - lo), bool))
+    o, _ = dots3.window_piece(
+        jnp.zeros((1, t, hn, n)), jnp.zeros((1, t, hn, rr)),
+        names[:, first:], jnp.zeros((1, t, rr)), rings, layer,
+        group(first, total), cfg, wkv_b)
+    o = np.asarray(o)[0]  # [T, H, v]
+    for j in range(t):
+        pos = first + j
+        want = np.zeros(vd)
+        kept = range(max(0, pos - 8), pos + 1)
+        want[[k % vd for k in kept]] = 1.0 / len(kept)
+        assert len(kept) == min(9, pos + 1) and (pos - 9) % vd not in [
+            k % vd for k in kept]
+        for h in range(hn):
+            np.testing.assert_allclose(o[j, h], want, atol=1e-6)
+
+
 # -- (c) a rolled-back dispatch with ONE generation -----------------------------
 
 

@@ -229,3 +229,93 @@ def test_tp_shard_map(cpu_mesh_devices):
         )
     )
     np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
+
+
+# -- a band by position (models/dots3.py: a window layer's prompt piece) ------
+
+
+def _band_layout(first, t, held, shift=0):
+    """Positions as a window layer's piece lays its keys out: `held` ring
+    columns that hold `base + i` (a key where that is not negative and
+    before the piece's first position), then the piece's own `t` rows."""
+    base = first - held + shift
+    cached = base + np.arange(held)
+    q_pos = first + np.arange(t)
+    k_pos = np.concatenate(
+        [np.where((cached >= 0) & (cached < first), cached, -1), q_pos])
+    return q_pos[None].astype(np.int32), k_pos[None].astype(np.int32)
+
+
+@pytest.mark.parametrize("window,first,t,held,shift", [
+    pytest.param(9, 70, 40, 16, 5, id="window-9-one-tile"),
+    pytest.param(129, 1000, 512, 192, 64, id="window-129-two-query-tiles"),
+    pytest.param(513, 8192, 512, 576, 64, id="published-513-of-1088-keys"),
+    pytest.param(513, 0, 512, 576, 64, id="published-a-piece-from-position-0"),
+    pytest.param(513, 300, 32, 576, 20, id="published-a-short-piece-early"),
+])
+def test_a_band_query_attends_exactly_its_window(window, first, t, held,
+                                                 shift):
+    """With zero queries the softmax is uniform over the kept keys, and
+    with value row k the k-th unit vector the output NAMES them: a query
+    at position p attends the keys at `max(0, p - (window - 1)) .. p`,
+    `window` of them once it has them, its own among them, and not the
+    key `window` before it; a ring column at or past the piece's first
+    position (a stale row) is no key."""
+    from dynamo_tpu.ops.flash_prefill import window_prefill_attention
+
+    q_pos, k_pos = _band_layout(first, t, held, shift)
+    kk = k_pos.shape[1]
+    got = np.asarray(window_prefill_attention(
+        jnp.zeros((1, t, 1, 128), jnp.float32),
+        jnp.ones((1, kk, 1, 128), jnp.float32),
+        jnp.eye(kk, dtype=jnp.float32)[None, :, None, :],
+        jnp.asarray(q_pos), jnp.asarray(k_pos), window=window,
+        interpret=True))[0, :, 0]  # [T, K]
+    for j in (0, 1, t // 2, t - 1):
+        p = int(q_pos[0, j])
+        kept = np.flatnonzero(got[j] > 0)
+        want = list(range(max(0, p - (window - 1)), p + 1))
+        assert sorted(k_pos[0, kept]) == want, (j, p)
+        assert len(kept) == min(window, p + 1)
+        np.testing.assert_allclose(got[j, kept], 1.0 / len(kept), rtol=1e-5)
+        assert p in k_pos[0, kept] and (p - window) not in k_pos[0, kept]
+        # its own token is the piece's own row, not a ring column
+        assert held + j in kept
+
+
+@pytest.mark.parametrize("b,t,hn,d,dv,kk,window", [
+    pytest.param(2, 40, 3, 32, 16, 100, 9, id="three-heads-narrow-widths"),
+    pytest.param(1, 512, 8, 160, 128, 704, 129,
+                 id="two-query-tiles-two-head-groups"),
+    pytest.param(2, 16, 2, 256, 128, 48, 9, id="no-ring-row-yet"),
+])
+def test_a_band_matches_dense_attention_under_the_same_rule(
+        b, t, hn, d, dv, kk, window):
+    """Rows of a batch at different positions, widths that fill no lane
+    tile, queries and keys past the valid ones (a padding query comes out
+    as zeros): against dense float32 attention under the rule written
+    out."""
+    from dynamo_tpu.ops.flash_prefill import window_prefill_attention
+
+    rng = np.random.default_rng(kk)
+    q = rng.standard_normal((b, t, hn, d)).astype(np.float32) * 0.3
+    k = rng.standard_normal((b, kk, hn, d)).astype(np.float32)
+    v = rng.standard_normal((b, kk, hn, dv)).astype(np.float32)
+    first = (0 if kk == 48 else 50) + 7 * np.arange(b)
+    q_pos, k_pos = (np.concatenate(x) for x in zip(*(
+        _band_layout(int(f), t, kk - t, 3) for f in first)))
+    q_pos[:, -3:] = -1  # the piece's tail is padding: no key in its band
+    k_pos[:, -3:] = -1
+    k[:, -3:] = np.nan  # and may hold anything as a KEY;
+    v[:, -3:] = 0  # as a value zeros (a zero weight silences no NaN)
+    got = np.asarray(window_prefill_attention(
+        *(jnp.asarray(x) for x in (q, k, v, q_pos, k_pos)), window=window,
+        interpret=True))
+    s = np.einsum("bthd,bkhd->bhtk", q, k)
+    at, key = q_pos[:, :, None], k_pos[:, None, :]
+    keep = (key >= 0) & (key <= at) & (key >= at - (window - 1))
+    s = np.where(keep[:, None], s, -1e30)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("bhtk,bkhd->bthd", p / p.sum(-1, keepdims=True), v)
+    np.testing.assert_allclose(got[:, :-3], want[:, :-3], atol=2e-5)
+    assert (got[:, -3:] == 0).all()

@@ -16,6 +16,7 @@ has no option for it. A compile that passes is not a chip run —
 """
 
 import dataclasses
+import functools
 import os
 import re
 
@@ -905,8 +906,9 @@ def test_dots3_step_compiles_at_published_widths(topo, rows, t, b_pre):
     sort-free selection, the decode rows' LATENT page walk under a bit a
     token (128 heads: in pieces that fit the kernel's VMEM budget), a
     prompt chunk's latent kernel under a mask bit a (query, key), the
-    window layers' ring written by position and read whole, the grouped
-    matmuls over the 8 held experts: every pool updated in place, the
+    window layers' ring written by position, walked by a decode row (and
+    by a 32-token piece, absorbed) and read by page for a 512-token
+    piece's plain-form banded kernel, the grouped matmuls over the 8 held experts: every pool updated in place, the
     program beside 6.2 GB of weights, 2.4 GB of pages and 0.5 GB of rings
     inside the chip."""
     adapter = get_model("dots3-note-prev-9l-8e", dtype="bfloat16",
@@ -968,13 +970,20 @@ def test_dots3_step_compiles_at_published_widths(topo, rows, t, b_pre):
     assert mem.alias_size_in_bytes >= pools  # every pool in place
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
     text = compiled.as_text()
-    # ONE body a kind of layer: each kernel once a full layer's body and
-    # once a window layer's (the ring walked as the latent pages it is)
+    # ONE body a kind of layer: the decode walk once a full layer's body
+    # and once a window layer's (a decode row's ring walked as the latent
+    # pages it is); a prompt piece the absorbed chunk kernel in a full
+    # layer, and in a window layer too where it is short beside the ring
+    # rows in reach (32 tokens beside 576): a 512-token piece attends
+    # plain there, under the banded kernel
+    plain = bool(b_pre) and 4 * t >= 576
     print({k: _kernel_calls(text, k) for k in (
-        "latent_prefill_attention", "paged_decode_attention",
-        "paged_index_scores", "paged_index_scores_chunk")})
+        "latent_prefill_attention", "window_prefill_attention",
+        "paged_decode_attention", "paged_index_scores",
+        "paged_index_scores_chunk")})
     assert _kernel_calls(text, "latent_prefill_attention") == (
-        2 if b_pre else 0)
+        0 if not b_pre else 1 if plain else 2)
+    assert _kernel_calls(text, "window_prefill_attention") == int(plain)
     assert _kernel_calls(text, "paged_decode_attention") == 2
     assert _kernel_calls(text, "paged_index_scores") == 1
     assert _kernel_calls(text, "paged_index_scores_chunk") == (
@@ -987,6 +996,30 @@ def test_dots3_step_compiles_at_published_widths(topo, rows, t, b_pre):
                  f"bf16[{rows},{mp * PAGE},128]"):
         assert not re.search(
             rf"= {re.escape(copy)}[^ ]* copy\(", text), copy
+
+
+@pytest.mark.parametrize("b,t", [
+    pytest.param(4, 512, id="four-pieces-two-query-tiles-each"),
+    pytest.param(1, 256, id="one-tile-of-256"),
+    pytest.param(1, 192, id="one-tile-of-a-bucket-no-multiple-of-128"),
+])
+def test_window_band_kernel_compiles_at_published_widths(topo, b, t):
+    """The banded plain-form kernel of a dots3 window layer's prompt piece
+    (ops/flash_prefill.py `window_prefill_attention`) at the published 64
+    heads of 256 | 128 in bf16, 576 ring columns and the piece's rows in
+    whole 128-key blocks: a cell's keys and values inside VMEM, the
+    columns of a tile read at a lane-aligned offset."""
+    from dynamo_tpu.ops.flash_prefill import window_prefill_attention
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    kk = -(-(576 + t) // 128) * 128
+    bf = jnp.bfloat16
+    text = jax.jit(functools.partial(
+        window_prefill_attention, window=513, interpret=False)).lower(
+        _sds((b, t, 64, 256), bf, chip), _sds((b, kk, 64, 256), bf, chip),
+        _sds((b, kk, 64, 128), bf, chip), _sds((b, t), jnp.int32, chip),
+        _sds((b, kk), jnp.int32, chip)).compile().as_text()
+    assert _kernel_calls(text, "window_prefill_attention") == 1
 
 
 @pytest.mark.parametrize("rows,vocab", [
