@@ -103,7 +103,8 @@ def _wire_unpack(arr, d_true: int, pool_dtype):
 #: `ModelAdapter.walk_pages`' running count, in order, as EngineMetrics
 #: names its numbers
 _WALK_COUNTS = ("walk_pages_named", "walk_pages_live", "chunk_pages_read",
-                "chunk_pages_named", "moe_experts_touched")
+                "chunk_pages_named", "moe_experts_touched",
+                "moe_extra_passes")
 
 
 @dataclass
@@ -283,6 +284,11 @@ class EngineMetrics:
     #: number (models/dots3.py): the held experts some row of a step chose,
     #: an expert layer each: the matrices its grouped matmuls read
     moe_experts_touched: int = 0
+    #: a sixth (models/mla.py `_routed_experts`): passes over a share's
+    #: assignments beyond an expert layer's first, each of which reads the
+    #: held experts' matrices again; 0 while a step's count stays inside
+    #: `mla.share_rows`
+    moe_extra_passes: int = 0
     #: the dry clock (telemetry/flight.py `DryClock`; all 0 with
     #: `flight_recorder=False`): cumulative host ms during which the
     #: device had NOTHING queued while the engine had work, from the first
@@ -2350,7 +2356,7 @@ class JaxEngine:
         dispatch rolled back in between walked its pages too)."""
         if st.walk is None:
             return
-        now = np.asarray(st.walk).astype(np.int64)  # four numbers, or five
+        now = np.asarray(st.walk).astype(np.int64)  # four numbers to six
         seen = self._walk_seen[:now.size]
         for name, n in zip(_WALK_COUNTS, (now - seen) % (1 << 32)):
             setattr(self.metrics, name, getattr(self.metrics, name) + int(n))

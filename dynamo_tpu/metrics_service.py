@@ -122,6 +122,9 @@ _WORKER_FIELDS = (
     # a chip that holds a share of the experts: the held experts a step's
     # rows chose, an expert layer each (models/dots3.py; 0 for the others)
     ("moe_experts_touched", "counter"),
+    # and the passes over the share's assignments beyond a layer's first
+    # (models/mla.py `share_rows`: each reads the held matrices again)
+    ("moe_extra_passes", "counter"),
     # speculative decoding (spec_ngram / spec_draft_model): drafts
     # proposed vs accepted — their ratio times S is the extra tokens per
     # verify dispatch; the skip counters say WHY speculation sat out

@@ -368,8 +368,10 @@ class Dots3Cache(NamedTuple):
     index keys [F, P, S, Di], the page's third resident; `ring` and
     `ring_pe` the sliding layers' slot pool, latent [S layers, slots + 1,
     R, c'] and rope key [.., r'] (slot 0 the null slot), ONE generation;
-    `walked` models/keye_vl.py's count of what the full layers attended
-    and, fifth, the held experts the expert layers' rows touched."""
+    `walked` models/keye_vl.py's count of what the full layers attended,
+    fifth the held experts the expert layers' rows touched and sixth the
+    passes over a share's assignments beyond a layer's first
+    (`mla._routed_experts`)."""
 
     k: jax.Array
     v: jax.Array
@@ -423,7 +425,7 @@ def init_cache(cfg: Dots3Config, num_pages: int, page_size: int,
         ring_pe=jnp.zeros((cfg.state_layers, state_slots + 1,
                            cfg.ring_tokens, cfg.swa_geo.kv_rope_dim),
                           cfg.dtype),
-        walked=jnp.zeros((5,), jnp.int32),
+        walked=jnp.zeros((6,), jnp.int32),
     )
 
 
@@ -984,25 +986,29 @@ def moe_ffn(x, lp, cfg: Dots3Config, stack=None):
     models/nemotron_h.py and models/keye_vl.py compose theirs: the router's
     product at the highest precision (a flipped eighth expert adds or
     removes a whole expert where a chip holds a share), the share's
-    experts, the shared expert. Returns (out, int32: how many of the
-    experts HELD some row chose, whose matrices the grouped matmuls read).
-    Scopes (under the caller's `mlp`): `moe/route`, `moe/experts`,
-    `moe/shared`."""
+    experts, the shared expert. Returns (out, int32 [2]: how many of the
+    experts HELD some row chose, whose matrices the grouped matmuls read,
+    and how many passes over the share's assignments the layer took beyond
+    its first, `mla._routed_experts`). Names its scopes from the top
+    (`mlp/moe/route`, `mlp/moe/experts`, `mlp/moe/shared`): the caller
+    stands under none, for the sake of the share's loop."""
     geo = cfg.full_geo
     xf = x.reshape(-1, x.shape[-1])
     first, count = cfg.experts_held or (0, cfg.n_routed_experts)
-    with jax.named_scope("moe"):
-        with jax.named_scope("route"):
-            topw, topi = mla._gate(
-                xf, lp, geo, precision=lax.Precision.HIGHEST)
-            touched = jnp.sum(jnp.any(
-                topi[..., None] == first + jnp.arange(count), axis=(0, 1)
-            ).astype(jnp.int32))
-        routed = mla._routed_experts(
-            xf, topw, topi, lp, geo, None, stack, held=cfg.experts_held)
-        with jax.named_scope("shared"):
-            shared = mla._shared_expert(xf, lp, geo)
-        return (routed.astype(cfg.dtype) + shared).reshape(x.shape), touched
+    with jax.named_scope(mla.MOE_SCOPE + "route"):
+        topw, topi = mla._gate(
+            xf, lp, geo, precision=lax.Precision.HIGHEST)
+        touched = jnp.sum(jnp.any(
+            topi[..., None] == first + jnp.arange(count), axis=(0, 1)
+        ).astype(jnp.int32))
+    routed, extra = mla._routed_experts(
+        xf, topw, topi, lp, geo, None, stack, held=cfg.experts_held,
+        scope=mla.MOE_SCOPE)
+    with jax.named_scope(mla.MOE_SCOPE + "shared"):
+        shared = mla._shared_expert(xf, lp, geo)
+    with jax.named_scope("mlp"):
+        return ((routed.astype(cfg.dtype) + shared).reshape(x.shape),
+                jnp.stack([touched, extra]))
 
 
 def forward_groups(params: dict, cfg: Dots3Config, groups,
@@ -1039,20 +1045,21 @@ def forward_groups(params: dict, cfg: Dots3Config, groups,
         return {n: lax.dynamic_index_in_dim(w, li, 0, keepdims=False)
                 for n, w in params[stack].items() if n not in experts}
 
-    # an FFN returns (h, the held experts its rows touched)
+    # an FFN returns (h, `moe_ffn`'s two counts)
     def dense_mlp(h, li):
         with jax.named_scope("mlp"):
             lp = leaves("dense", li)
             return h + mla._dense_ffn(
-                rms_norm(h, lp["mlp_norm"], eps), lp, geo), jnp.int32(0)
+                rms_norm(h, lp["mlp_norm"], eps), lp, geo
+            ), jnp.zeros((2,), jnp.int32)
 
     def expert_mlp(h, li):
         with jax.named_scope("mlp"):
             lp = leaves("moe", li)
-            y, touched = moe_ffn(
-                rms_norm(h, lp["mlp_norm"], eps), lp, cfg,
-                (experts, li) if experts else None)
-            return h + y, touched
+            x = rms_norm(h, lp["mlp_norm"], eps)
+        y, counts = moe_ffn(x, lp, cfg, (experts, li) if experts else None)
+        with jax.named_scope("mlp"):
+            return h + y, counts
 
     def sliding_layer(j, carry, at):
         h, rings, touched = carry
@@ -1084,7 +1091,7 @@ def forward_groups(params: dict, cfg: Dots3Config, groups,
                 0, at["n_s"], lambda j, c: sliding_layer(j, c, at),
                 (h, rings, touched))
         return (h, kv, ki_pool, rings,
-                walked + jnp.concatenate([n, touched[None]])), staged
+                walked + jnp.concatenate([n, touched])), staged
 
     # where each period's layers lie in their stacks
     index = {"full": [], "dense": [], "ffn": [], "n_s": [], "swa": [],

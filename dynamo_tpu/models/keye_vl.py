@@ -177,11 +177,14 @@ class KeyeCache(NamedTuple):
     keys beside them (`index_pool`), a page's third resident (written with
     the page's K and V rows, freed and shared with the page). `walked`
     counts, on the device, what the decode steps ATTEND, a layer each,
-    summed since the cache was made and wrapping at 2**32: int32 [4], the
+    summed since the cache was made and wrapping at 2**32: int32 [6], the
     tokens the rows' selections name and the tokens the rows hold
     (`ModelAdapter.walk_pages`, in tokens here: `tokens_attended`), then
     the (query, key) pairs a prompt chunk's kernel multiplies and the
-    pairs its queries' selections name (`chunk_pairs`)."""
+    pairs its queries' selections name (`chunk_pairs`), a fifth left at 0
+    (models/dots3.py's held experts touched) and the passes over a
+    share's assignments beyond an expert layer's first
+    (`mla._routed_experts`)."""
 
     k: jax.Array
     v: jax.Array
@@ -278,7 +281,7 @@ def init_cache(cfg: KeyeVLConfig, num_pages: int, page_size: int
     return KeyeCache(
         k=jnp.zeros(rows, cfg.dtype), v=jnp.zeros(rows, cfg.dtype),
         ki=index_pool(cfg, num_pages, page_size),
-        walked=jnp.zeros((4,), jnp.int32),
+        walked=jnp.zeros((6,), jnp.int32),
     )
 
 
@@ -635,18 +638,22 @@ def attention(x, lp, cfg: KeyeVLConfig, kv, ki_pool, layer, groups, works):
 
 
 def moe_ffn(x, lp, cfg: KeyeVLConfig, stack=None):
-    """Scopes (under the caller's `mlp`): `moe/route`, `moe/experts`, as
-    models/mla.py's expert layer; no shared expert. The router's product
-    at the highest precision: a flipped eighth expert adds or removes a
-    whole expert where a chip holds a share."""
+    """(out, int32: the passes over a share's assignments beyond the
+    first, `mla._routed_experts`). Names its scopes from the top
+    (`mlp/moe/route`, `mlp/moe/experts`, as models/mla.py's expert layer;
+    no shared expert): the caller stands under none, for the sake of the
+    share's loop. The router's product at the highest precision: a flipped
+    eighth expert adds or removes a whole expert where a chip holds a
+    share."""
     xf = x.reshape(-1, x.shape[-1])
-    with jax.named_scope("moe"):
-        with jax.named_scope("route"):
-            topw, topi = mla_mod._gate(
-                xf, lp, cfg, precision=lax.Precision.HIGHEST)
-        routed = mla_mod._routed_experts(
-            xf, topw, topi, lp, cfg, None, stack, held=cfg.experts_held)
-        return routed.astype(cfg.dtype).reshape(x.shape)
+    with jax.named_scope(mla_mod.MOE_SCOPE + "route"):
+        topw, topi = mla_mod._gate(
+            xf, lp, cfg, precision=lax.Precision.HIGHEST)
+    routed, extra = mla_mod._routed_experts(
+        xf, topw, topi, lp, cfg, None, stack, held=cfg.experts_held,
+        scope=mla_mod.MOE_SCOPE)
+    with jax.named_scope("mlp"):
+        return routed.astype(cfg.dtype).reshape(x.shape), extra
 
 
 # ---------------------------------------------------------------------------
@@ -691,9 +698,13 @@ def forward_groups(params: dict, cfg: KeyeVLConfig, groups,
                 groups, works)
             h = h + a
         with jax.named_scope("mlp"):
-            h = h + moe_ffn(rms_norm(h, lp["mlp_norm"], eps), lp, cfg,
-                            (experts, li) if experts else None)
-        return (h, kv, walked + n), staged
+            x = rms_norm(h, lp["mlp_norm"], eps)
+        y, extra = moe_ffn(x, lp, cfg, (experts, li) if experts else None)
+        with jax.named_scope("mlp"):
+            h = h + y
+        # (no fifth count here: the held experts touched, models/dots3.py)
+        return (h, kv, walked + jnp.concatenate(
+            [n, jnp.stack([jnp.int32(0), extra])])), staged
 
     (h, kv, walked), staged = lax.scan(
         layer, (h, cache.pages, cache.walked),

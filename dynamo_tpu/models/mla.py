@@ -61,7 +61,12 @@ is one grouped matmul over the contiguous groups (ops/grouped_matmul.py:
 `jax.lax.ragged_dot`'s contract, a Pallas kernel on the TPU; operands
 in the model dtype, float32 accumulation), then un-sorted and
 summed with the gate's weights. Every assignment is computed: there is no
-capacity. The expert axis is sharded over the mesh's "ep" axis. The first
+capacity. A chip that holds a SHARE of a layer's experts (`held`:
+models/nemotron_h.py, models/keye_vl.py, models/dots3.py) moves only the
+assignments that fall on its share, a bounded number of rows a pass and as
+many passes as a step's count needs (`_routed_experts`, `share_rows`): the
+bound is no capacity either. The expert axis is sharded over the mesh's
+"ep" axis. The first
 `first_k_dense_replace` layers use a dense MLP (V2-Lite: layer 0) — the
 layer stack is two lax.scans (dense prefix, MoE suffix), keeping params
 scan-stacked without per-layer Python unrolling.
@@ -1015,6 +1020,39 @@ def _gate(xf: jax.Array, lp: dict, cfg: MlaConfig, precision=None):
     return topw, topi
 
 
+#: an expert layer's scopes from the top, for a caller that stands under
+#: none (`_routed_experts`' `scope`)
+MOE_SCOPE = "mlp/moe/"
+#: a pass over a share's assignments holds this many times what even
+#: routing sends the share (`share_rows`)
+_SHARE_ROOM = 2
+#: how a pass's rows are added into their tokens' rows: a product of [N, C]
+#: zeros and ones with the rows on the MXU, float32 at the highest
+#: precision, grows with N x C; a gather of every token's k places reads N
+#: x k rows. The product where a pass holds at most this many rows a slot
+#: of the top-k, the gather above it. scripts/expert_block_bench.py, PR 51,
+#: ms a layer: 384 rows of 5,120 under top 8 (a 512-token piece at 8 of
+#: 256 held) 0.041 against 0.149, 1,152 of 2,048 (16 of 128) 0.043 against
+#: 0.085; the product quadruples with a second piece where the gather
+#: doubles, so they cross at 175-270 rows a slot. A scatter-add took the
+#: TPU 2.5 us A ROW (0.97 ms at 384).
+_ONE_HOT_ROWS = 160
+
+
+def share_rows(nt: int, k: int, count: int, width: int) -> int:
+    """C, the rows one pass over a share's assignments holds: `_SHARE_ROOM`
+    times what `nt` tokens' top `k` of a router `width` wide send `count`
+    held experts under even routing, in whole row tiles of the grouped
+    matmul, and never more than there are assignments (a share that is
+    everything, a handful of rows: one pass of all of them, which is the
+    whole-batch path). A bound on a pass, not a capacity: what a step sends
+    the share beyond it takes another pass (`_routed_experts`)."""
+    from dynamo_tpu.ops.grouped_matmul import _TILE_ROWS as tile
+
+    mean = nt * k * count / width
+    return min(nt * k, -(-math.ceil(_SHARE_ROOM * mean) // tile) * tile)
+
+
 def _routed_experts(
     xf: jax.Array,  # [N, H]
     topw: jax.Array,  # [N, k] f32
@@ -1024,67 +1062,130 @@ def _routed_experts(
     mesh=None,
     stack=None,
     held=None,
-) -> jax.Array:
-    """[N, H] f32: every one of the N*k assignments computed, whatever
-    the routing. The assignments are sorted by expert (row j of the
-    sorted batch is token order[j] // k under expert expert_of_row[j]),
-    go through the grouped FFN, and come back in token order weighted by
-    the gate. `held` = (first, count) says which experts `lp` holds where
-    that is a share of them (an expert-parallel deployment's chip,
-    models/nemotron_h.py): an assignment to an expert held elsewhere sorts
-    past the groups, where the grouped matmul computes nothing, and adds
-    nothing here. A share's assignments are ordered by COUNTING (a running
-    count a group: exact and stable, for the few groups of a share): XLA's
-    stable sort of 16,640 assignments takes the TPU's compiler 15 s a
-    program, against 1.5 s for 4,352 (the compile for the described v5e,
-    PR 48; models/dots3.py)."""
+    scope: str = "",
+):
+    """([N, H] f32, passes beyond the first): every assignment to an
+    expert `lp` holds computed, whatever the routing. The assignments are
+    sorted by expert (row j of the sorted batch is token order[j] // k
+    under expert expert_of_row[j]), go through the grouped FFN, and come
+    back in token order weighted by the gate.
+
+    `held` = (first, count) says which experts `lp` holds where that is a
+    share of them (an expert-parallel deployment's chip,
+    models/nemotron_h.py). Only the share's own assignments move: they are
+    ordered by COUNTING (a running count a group: exact and stable, for
+    the few groups of a share; XLA's stable sort of 16,640 assignments
+    takes the TPU's compiler 15 s a program, against 1.5 s for 4,352: the
+    compile for the described v5e, PR 48; models/dots3.py), and the head of
+    that order is gathered, multiplied and added into its tokens' rows
+    `share_rows` at a time, in a loop of as many passes as the step's
+    count needs: one where routing is anywhere near even, more where it is
+    not, NONE dropped at any count; an assignment to an expert held
+    elsewhere is never materialised. The second result counts the passes
+    beyond the first (0 without `held`), which each read the held experts'
+    matrices again. A token's held assignments are summed in float32 in
+    the order of their experts, not of the gate's top-k.
+
+    `scope` is the path the three parts are named under (`route`,
+    `experts`) where the caller stands OUTSIDE it: an operation in a loop's
+    body is named `<the loop's own path>/while/body/<its scopes>`, and a
+    trace's readers take the first scope they know, so a share's caller
+    binds the loop under no scope and passes "mlp/moe/"."""
     nt, h = xf.shape
     e, k = cfg.n_routed_experts, topi.shape[1]
-    with jax.named_scope("route"):
-        flat_e = topi.reshape(nt * k).astype(jnp.int32)
-        if held is not None:
-            first, e = held
-            flat_e = flat_e - first
-            flat_e = jnp.where((flat_e >= 0) & (flat_e < e), flat_e, e)
-            # an assignment's place: its group's start + its rank there
-            at = jnp.arange(nt * k, dtype=jnp.int32)
-            one = (flat_e[:, None] == jnp.arange(e + 1)[None]).astype(
-                jnp.float32)  # [N * k, groups and "elsewhere"]
-            # the running count in blocks of 128 assignments: inside a
-            # block one triangular product (exact: counts below 2**24),
-            # across blocks a short cumulative sum
-            pad = -(nt * k) % 128
-            blocks = jnp.pad(one, ((0, pad), (0, 0))).reshape(-1, 128, e + 1)
-            before = jnp.tril(jnp.ones((128, 128), jnp.float32), -1)
-            inside = jnp.einsum("ij,bjg->big", before, blocks,
-                                precision=lax.Precision.HIGHEST)
-            sums = jnp.sum(blocks, axis=1)  # [blocks, groups]
-            rank = (inside + (jnp.cumsum(sums, axis=0) - sums)[:, None]
-                    ).reshape(-1, e + 1)[:nt * k]
-            sizes = jnp.sum(sums, axis=0)
-            back = jnp.sum(
-                one * (rank + (jnp.cumsum(sizes) - sizes)[None]), axis=1
-            ).astype(jnp.int32)
-            order = jnp.zeros((nt * k,), jnp.int32).at[back].set(at)
-            group_sizes = sizes[:e].astype(jnp.int32)
-        else:
-            order = jnp.argsort(flat_e, stable=True)
-            # (an index past the groups is dropped: a scatter's default)
-            group_sizes = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
+    flat_e = topi.reshape(nt * k).astype(jnp.int32)
+    if held is not None:
+        return _held_experts(xf, topw, flat_e, lp, cfg, mesh, stack, held,
+                             scope)
+    with jax.named_scope(scope + "route"):
+        order = jnp.argsort(flat_e, stable=True)
+        # (an index past the groups is dropped: a scatter's default)
+        group_sizes = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
         expert_of_row = flat_e[order]
         xs = xf[order // k].astype(cfg.dtype)
-    with jax.named_scope("experts"):
+    with jax.named_scope(scope + "experts"):
         ys = _grouped_ffn(
             xs, expert_of_row, group_sizes, lp, cfg, mesh, stack
         )
-    with jax.named_scope("route"):
-        if held is not None:  # rows past the groups hold whatever was there
-            ys = jnp.where((expert_of_row < e)[:, None], ys, 0.0)
-        else:
-            back = jnp.zeros((nt * k,), jnp.int32).at[order].set(
-                jnp.arange(nt * k, dtype=jnp.int32)
-            )
-        return jnp.sum(ys[back].reshape(nt, k, h) * topw[..., None], axis=1)
+    with jax.named_scope(scope + "route"):
+        back = jnp.zeros((nt * k,), jnp.int32).at[order].set(
+            jnp.arange(nt * k, dtype=jnp.int32)
+        )
+        return jnp.sum(
+            ys[back].reshape(nt, k, h) * topw[..., None], axis=1
+        ), jnp.int32(0)
+
+
+def _held_experts(xf, topw, flat_e, lp, cfg, mesh, stack, held, scope):
+    """`_routed_experts` on a chip that holds the experts `held`."""
+    nt, h = xf.shape
+    k = topw.shape[1]
+    first, e = held
+    c = share_rows(nt, k, e, cfg.n_routed_experts)
+    room = -(-nt * k // c) * c  # whole passes: a slice never runs off
+    with jax.named_scope(scope + "route"):
+        flat_e = flat_e - first
+        flat_e = jnp.where((flat_e >= 0) & (flat_e < e), flat_e, e)
+        one = (flat_e[:, None] == jnp.arange(e)[None]).astype(
+            jnp.float32)  # [N * k, groups]: no column for "elsewhere"
+        # the running count in blocks of 128 assignments: inside a
+        # block one triangular product (exact: counts below 2**24),
+        # across blocks a short cumulative sum
+        pad = -(nt * k) % 128
+        blocks = jnp.pad(one, ((0, pad), (0, 0))).reshape(-1, 128, e)
+        before = jnp.tril(jnp.ones((128, 128), jnp.float32), -1)
+        inside = jnp.einsum("ij,bjg->big", before, blocks,
+                            precision=lax.Precision.HIGHEST)
+        sums = jnp.sum(blocks, axis=1)  # [blocks, groups]
+        rank = (inside + (jnp.cumsum(sums, axis=0) - sums)[:, None]
+                ).reshape(-1, e)[:nt * k]
+        sizes = jnp.sum(sums, axis=0)
+        ends = jnp.cumsum(sizes)
+        # an assignment's place: its group's start + its rank there; one
+        # held elsewhere has none (a place past the end is dropped)
+        place = jnp.where(
+            flat_e < e, jnp.sum(one * (rank + (ends - sizes)[None]), axis=1),
+            room).astype(jnp.int32)
+        order = jnp.zeros((room,), jnp.int32).at[place].set(
+            jnp.arange(nt * k, dtype=jnp.int32), mode="drop")
+        sizes, ends = sizes.astype(jnp.int32), ends.astype(jnp.int32)
+        total = ends[-1]
+        gate = topw.reshape(nt * k)
+
+    def one_pass(p, acc):
+        lo = p * c
+        with jax.named_scope(scope + "route"):
+            mine = lax.dynamic_slice(order, (lo,), (c,))  # assignments
+            token = mine // k
+            # the groups as this slice of the order cuts them
+            group_sizes = (jnp.clip(ends, lo, lo + c)
+                           - jnp.clip(ends - sizes, lo, lo + c))
+            xs = xf[token].astype(cfg.dtype)
+        with jax.named_scope(scope + "experts"):
+            ys = _grouped_ffn(
+                xs, flat_e[mine], group_sizes, lp, cfg, mesh, stack)
+        with jax.named_scope(scope + "route"):
+            weighted = ys * gate[mine][:, None]
+            if c <= _ONE_HOT_ROWS * k:
+                # each token's rows summed by a product with 0 / 1 on the
+                # MXU, float32 throughout (rows past the step's count hold
+                # whatever was there: to no token)
+                live = lo + jnp.arange(c, dtype=jnp.int32) < total
+                sel = (token[None, :] == jnp.arange(nt)[:, None]) & live[None]
+                return acc + jnp.matmul(
+                    sel.astype(jnp.float32),
+                    jnp.where(live[:, None], weighted, 0.0),
+                    precision=lax.Precision.HIGHEST)
+            # each token's k places, where they lie in this pass
+            at = place.reshape(nt, k) - lo
+            here = (at >= 0) & (at < c)
+            return acc + jnp.sum(jnp.where(
+                here[..., None], weighted[jnp.where(here, at, 0)], 0.0),
+                axis=1)
+
+    passes = (total + c - 1) // c
+    out = lax.fori_loop(0, passes, one_pass, jnp.zeros((nt, h), jnp.float32))
+    return out, jnp.maximum(passes - 1, 0)
 
 
 def _dense_ffn(x: jax.Array, lp: dict, cfg: MlaConfig) -> jax.Array:
@@ -1118,7 +1219,7 @@ def _deepseek_moe_ffn(
     with jax.named_scope("moe"):
         with jax.named_scope("route"):
             topw, topi = _gate(xf, lp, cfg)
-        routed = _routed_experts(xf, topw, topi, lp, cfg, mesh, stack)
+        routed, _ = _routed_experts(xf, topw, topi, lp, cfg, mesh, stack)
         with jax.named_scope("shared"):
             shared = _shared_expert(xf, lp, cfg)
         return (routed.astype(cfg.dtype) + shared).reshape(x.shape)

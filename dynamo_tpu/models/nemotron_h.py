@@ -278,7 +278,11 @@ class HybridCache(NamedTuple):
     pages (models/llama.py KVPages' layout, one layer axis entry an
     attention layer), `conv` and `ssm` the state-space layers' slot pools
     (ops/ssm_state.py: [M layers, entries, ...]; entry = generation *
-    (slots + 1) + slot, slot 0 the null slot)."""
+    (slots + 1) + slot, slot 0 the null slot). `walked` is this family's
+    running count on the device (`ModelAdapter.walk_pages`), int32 [6]
+    laid out as models/dots3.py's: only the sixth is counted here, the
+    passes over a share's assignments beyond an expert layer's first
+    (`mla._routed_experts`); models/falcon_h1.py keeps none."""
 
     k: jax.Array
     v: jax.Array
@@ -286,6 +290,7 @@ class HybridCache(NamedTuple):
     v_scale: Optional[jax.Array] = None
     conv: Optional[jax.Array] = None
     ssm: Optional[jax.Array] = None
+    walked: Optional[jax.Array] = None
 
     @property
     def num_pages(self) -> int:
@@ -302,6 +307,11 @@ class HybridCache(NamedTuple):
     @property
     def pages(self) -> KVPages:
         return KVPages(k=self.k, v=self.v)
+
+
+def walk_count(cache: HybridCache) -> jax.Array:
+    """`ModelAdapter.walk_pages`: the cache's running count."""
+    return cache.walked
 
 
 def state_bytes_per_slot(cfg) -> int:
@@ -325,6 +335,8 @@ def init_cache(
         k=jnp.zeros(page, cfg.dtype), v=jnp.zeros(page, cfg.dtype),
         conv=jnp.zeros((nm, entries, *m.conv_state_shape), cfg.dtype),
         ssm=jnp.zeros((nm, entries, *m.ssm_state_shape), jnp.float32),
+        walked=(jnp.zeros((6,), jnp.int32)
+                if isinstance(cfg, NemotronHConfig) else None),
     )
 
 
@@ -650,23 +662,27 @@ def _relu2(x):
 
 
 def moe_ffn(x, lp, cfg: NemotronHConfig, mesh=None, stack=None):
-    """Scopes (under the caller's `mlp`): `moe/route`, `moe/experts`,
-    `moe/shared`, as models/mla.py's expert layer."""
+    """(out, int32: the passes over a share's assignments beyond the
+    first, `mla._routed_experts`). Names its scopes from the top
+    (`mlp/moe/route`, `mlp/moe/experts`, `mlp/moe/shared`, as
+    models/mla.py's expert layer): the caller stands under none, for the
+    sake of the share's loop."""
     xf = x.reshape(-1, x.shape[-1])
-    with jax.named_scope("moe"):
-        with jax.named_scope("route"):
-            topw, topi = mla_mod._gate(
-                xf, lp, cfg, precision=lax.Precision.HIGHEST
-            )
-        routed = mla_mod._routed_experts(
-            xf, topw, topi, lp, cfg, mesh, stack, held=cfg.experts_held
+    with jax.named_scope(mla_mod.MOE_SCOPE + "route"):
+        topw, topi = mla_mod._gate(
+            xf, lp, cfg, precision=lax.Precision.HIGHEST
         )
-        with jax.named_scope("shared"):
-            shared = _mm(
-                _relu2(_mm(xf, lp, "ws_up", cfg.dtype)).astype(cfg.dtype),
-                lp, "ws_down", cfg.dtype,
-            )
-        return (routed.astype(cfg.dtype) + shared).reshape(x.shape)
+    routed, extra = mla_mod._routed_experts(
+        xf, topw, topi, lp, cfg, mesh, stack, held=cfg.experts_held,
+        scope=mla_mod.MOE_SCOPE,
+    )
+    with jax.named_scope(mla_mod.MOE_SCOPE + "shared"):
+        shared = _mm(
+            _relu2(_mm(xf, lp, "ws_up", cfg.dtype)).astype(cfg.dtype),
+            lp, "ws_down", cfg.dtype,
+        )
+    with jax.named_scope("mlp"):
+        return (routed.astype(cfg.dtype) + shared).reshape(x.shape), extra
 
 
 # ---------------------------------------------------------------------------
@@ -747,7 +763,9 @@ def forward_groups(
     def moe_layer(h, lp, li):
         with jax.named_scope("mlp"):
             x = rms_norm(h, lp["norm"], eps)
-            return h + moe_ffn(x, lp, cfg, None, (experts, li))
+        y, extra = moe_ffn(x, lp, cfg, None, (experts, li))
+        with jax.named_scope("mlp"):
+            return h + y, extra
 
     def layer_params(kind: str, li):
         """Layer `li`'s own leaves of a kind's stack. The stacks stay
@@ -766,7 +784,7 @@ def forward_groups(
         index, among its kind, of the unit's first layer of that kind."""
 
         def body(carry, base):
-            h, kv, pools = carry
+            h, kv, pools, extra = carry
             seen = {"M": 0, "*": 0, "E": 0}
             staged = []
             for sym in pattern:
@@ -779,12 +797,13 @@ def forward_groups(
                     h, kv, st = attn_layer(h, kv, lp, li)
                     staged.append(st)
                 else:
-                    h = moe_layer(h, lp, li)
-            return (h, kv, pools), tuple(staged)
+                    h, n = moe_layer(h, lp, li)
+                    extra = extra + n
+            return (h, kv, pools, extra), tuple(staged)
 
         return body
 
-    carry = (h, cache.pages, (cache.conv, cache.ssm))
+    carry = (h, cache.pages, (cache.conv, cache.ssm), jnp.int32(0))
     staged_all = []
     done = {"M": 0, "*": 0, "E": 0}
     for pattern, reps in cfg.segments:
@@ -802,7 +821,7 @@ def forward_groups(
             staged_all.append(st)  # [reps][a layer of the unit][group]
         for s in done:
             done[s] += reps * per[s]
-    h, kv, (conv, ssm) = carry
+    h, kv, (conv, ssm), extra = carry
     if staged_all:
         # every attention layer's rows of the step, in layer order, in
         # one write a group
@@ -822,7 +841,8 @@ def forward_groups(
     with jax.named_scope("final_norm"):
         h = rms_norm(h, params["final_norm"], eps)
     return split_rows(h, groups), HybridCache(
-        k=kv.k, v=kv.v, conv=conv, ssm=ssm
+        k=kv.k, v=kv.v, conv=conv, ssm=ssm,
+        walked=cache.walked.at[5].add(extra),
     )
 
 

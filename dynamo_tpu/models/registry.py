@@ -82,10 +82,12 @@ class ModelAdapter:
     #: a model whose decode walk reads a chosen part of a row's pages:
     #: cache -> the device's running count, int32 [4], of (pages the lists
     #: given to the walks named, pages those rows held, pages its sparse
-    #: prompt chunks' tiles read, pages their queries named), or [5] with
-    #: the held experts its rows chose; the engine reads it beside each
+    #: prompt chunks' tiles read, pages their queries named), or [6] with
+    #: the held experts its rows chose and the passes over a share's
+    #: assignments beyond a layer's first; the engine reads it beside each
     #: dispatch's ids (`EngineMetrics.walk_pages_named` / `walk_pages_live`
-    #: / `chunk_pages_read` / `chunk_pages_named` / `moe_experts_touched`)
+    #: / `chunk_pages_read` / `chunk_pages_named` / `moe_experts_touched`
+    #: / `moe_extra_passes`)
     walk_pages: Optional[Callable] = None
     #: False for a model whose step programs are dear to load: the engine
     #: then keeps ONE prefill-carrying program a shape where it would keep

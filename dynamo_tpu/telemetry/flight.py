@@ -24,7 +24,8 @@ record into a bounded deque. A record holds, as `record_step` writes it:
   `prefix_refused_state`), a selecting walk (`walk_pages_named`,
   `walk_pages_live`) and its sparse prompt chunks (`chunk_pages_read`,
   `chunk_pages_named`; `moe_experts_touched` where it counts the held
-  experts its rows chose), speculation (`spec_drafted`,
+  experts its rows chose, `moe_extra_passes` where a share's assignments
+  took a layer more than one pass), speculation (`spec_drafted`,
   `spec_accepted`), `compiles` / `compile_ms`, `preempted`, `tokens`,
   and the dry clock's counters (`dry_ms`, `dry_slack_ms`, `dry_wait_ms`,
   `dry_<phase>_ms`, `dry_launches`, `launches`);
@@ -103,6 +104,8 @@ _DELTA_FIELDS = (
     ("chunk_pages_named", "chunk_pages_named"),
     # and which counts the held experts its rows touched (a share's chip)
     ("moe_experts_touched", "moe_experts_touched"),
+    # and the passes over the share's assignments beyond a layer's first
+    ("moe_extra_passes", "moe_extra_passes"),
     # speculative decoding (ngram or draft model): drafted/accepted per
     # step — a record with tokens but no spec_drafted is a plain step
     ("spec_drafted", "spec_drafted"),

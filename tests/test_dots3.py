@@ -554,7 +554,7 @@ def test_the_shares_of_the_experts_add_up_to_the_whole_layer(rows, shares):
         np.testing.assert_allclose(
             got, ref.moe_branch(x[0], mine, hf_of(cfg)), atol=2e-5)
         total = total + (got - shared)
-        touched += int(n)
+        touched += int(n[0])  # (touched, passes beyond the first)
     np.testing.assert_allclose(total + shared, want, atol=5e-5)
     # and so do the shares' counts of the experts their rows chose: every
     # expert some row chose is counted once, by the share that holds it

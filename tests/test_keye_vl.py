@@ -376,7 +376,7 @@ def test_the_shares_of_the_experts_add_up_to_the_whole_layer(rows, shares):
         held = kvm.init_params(jax.random.key(3), cfg)["layers"]
         for n in kvm.EXPERTS:
             np.testing.assert_array_equal(held[n][0], mine[n])
-        got = kvm.moe_ffn(x, mine, cfg)[0]
+        got = kvm.moe_ffn(x, mine, cfg)[0][0]  # (out, extra passes)
         np.testing.assert_allclose(
             got, ref.moe_branch(x[0], mine, hf_of(cfg)), atol=2e-5)
         total = total + got

@@ -250,7 +250,7 @@ def test_dropless_dispatch_matches_a_per_token_loop(routing, quantize):
         topi = jnp.stack([jnp.full((n,), 2), (jnp.arange(n) % 3 + 3) % 4], 1)
     elif routing == "two-experts-only":
         topi = jnp.broadcast_to(jnp.asarray([3, 0]), (n, 2))
-    got = mla._routed_experts(xf, topw, topi, lp, cfg)
+    got, _ = mla._routed_experts(xf, topw, topi, lp, cfg)
     want = per_token_experts(xf, np.asarray(topw), np.asarray(topi), lp)
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-4, rtol=1e-4)
     assert np.abs(want).max() > 1e-2  # there is something to drop

@@ -329,13 +329,13 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(tiny):
         share = dataclasses.replace(whole, experts_held=(first, 2))
         held = {**lp, "we_up": lp["we_up"][first : first + 2],
                 "we_down": lp["we_down"][first : first + 2]}
-        part = nh.moe_ffn(h[None], held, share)[0] - shared
+        part = nh.moe_ffn(h[None], held, share)[0][0] - shared
         assert float(jnp.abs(part).max()) > 1e-3  # every share adds its own
         total = total + part
     np.testing.assert_allclose(total, want, atol=1e-5)
     # and the layer that holds every expert is the reference's too
     np.testing.assert_allclose(
-        nh.moe_ffn(h[None], lp, whole)[0], want, atol=1e-5)
+        nh.moe_ffn(h[None], lp, whole)[0][0], want, atol=1e-5)
 
 
 # -- what refuses, refuses loudly -------------------------------------------
@@ -409,5 +409,6 @@ def test_memory_report_counts_the_state_pool_beside_the_pages():
     assert rep["state_pool_bytes"] == entries * nh.state_bytes_per_slot(cfg)
     assert rep["state_pool_bytes"] == eng.metrics.state_pool_bytes
     pages = 2 * cfg.count("*") * 256 * 4 * cfg.num_kv_heads * cfg.head_dim * 4
-    assert rep["kv_pool_bytes"] == eng.metrics.kv_pool_bytes == pages
+    # (and the device's running count, six int32: `HybridCache.walked`)
+    assert rep["kv_pool_bytes"] == eng.metrics.kv_pool_bytes == pages + 6 * 4
     assert eng.metrics.state_slots == eng.allocator.state_slots == 3
