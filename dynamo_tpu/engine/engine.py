@@ -864,7 +864,8 @@ class JaxEngine:
         whose only per-sequence state is pages)."""
         return tuple(
             x for x in (getattr(kv, name, None)
-                        for name in ("conv", "ssm", "ring", "ring_pe"))
+                        for name in ("conv", "ssm", "ring", "ring_pe",
+                                     "ring_v"))
             if x is not None
         )
 

@@ -150,9 +150,10 @@ class MlaConfig:
     first_k_dense_replace: int = 1
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = False
-    #: "greedy" (V2-Lite), "group_limited_greedy" (V2/V2-Chat), or
+    #: "greedy" (V2-Lite), "group_limited_greedy" (V2/V2-Chat),
     #: "noaux_tc" (V3/R1: sigmoid scores + aux-loss-free bias-corrected
-    #: group routing). Groups rank by max member (V2) / top-2 sum (V3) of
+    #: group routing) or "sigmoid" (the same with no correction bias).
+    #: Groups rank by max member (V2) / top-2 sum (V3) of
     #: the (bias-corrected, V3) scores; top-k selects within the winning
     #: groups; V3 weights come from the UNcorrected sigmoid scores
     topk_method: str = "greedy"
@@ -989,12 +990,16 @@ def _gate(xf: jax.Array, lp: dict, cfg: MlaConfig, precision=None):
         )  # [N, g]
         return jnp.repeat(gmask, e // g, axis=-1)  # [N, E]
 
-    if cfg.topk_method == "noaux_tc":
+    if cfg.topk_method in ("noaux_tc", "sigmoid"):
         # HF DeepseekV3TopkRouter: sigmoid scores; groups rank by the SUM
         # of their top-2 bias-corrected scores; selection uses corrected
-        # scores, weights use the uncorrected ones.
+        # scores, weights use the uncorrected ones. "sigmoid" is the rule
+        # with NO correction bias (models/cohere2_moe.py): the k highest
+        # scores themselves, ties to the lower index.
         scores = jax.nn.sigmoid(logits)
-        choice = scores + lp["router_bias"][None, :]
+        choice = scores
+        if cfg.topk_method == "noaux_tc":
+            choice = scores + lp["router_bias"][None, :]
         if cfg.n_group > 1:  # one group: every expert stands (two top-k
             # less to compile and to run)
             choice = choice * _group_mask(

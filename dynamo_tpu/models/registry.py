@@ -578,6 +578,52 @@ def _dots3_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
             "kv_tiers", "speculation", "page_transfer")))
 
 
+def _cohere2_moe_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
+    """Command A+'s language model (models/cohere2_moe.py) through
+    `_hybrid_adapter`: K and V pages for its full layers and a slot a
+    sequence for its window layers' rings of K and V, ONE generation
+    (`state_in_place`). What the hybrid adapter cannot say is replaced
+    here: the refusals' sentences."""
+    from dynamo_tpu.models import cohere2_moe as c2
+
+    base = _hybrid_adapter(
+        name, cfg, c2, "Command A+", c2.cohere2_moe_logical_axes, mesh)
+
+    def init_kv(num_pages, page_size, kv_quantize=None, state_slots=0):
+        if kv_quantize:
+            raise ValueError(
+                "kv_quantize is not supported for Command A+: three layers "
+                "of four keep their K and V in a ring in the slot pool, in "
+                "the model dtype, and narrowing the full layers' pages "
+                "alone has no tested path beside it; run with "
+                "kv_quantize=None")
+        return c2.init_cache(cfg, num_pages, page_size, state_slots)
+
+    why = ("a sequence of this family is its pages (the full layers' K and "
+           "V) and the rings of its window layers in the slot pool, and "
+           "this would move or rewind the pages alone")
+    return replace(
+        base, init_kv=init_kv, state_in_place=c2.STATE_IN_PLACE,
+        refuses=tuple((what, why) for what in (
+            "kv_tiers", "speculation", "page_transfer")))
+
+
+def _cohere2_moe_presets() -> dict:
+    from dynamo_tpu.models.cohere2_moe import Cohere2MoeConfig
+
+    return {
+        # the language model of command-a-plus-05-2026 as published: 32
+        # layers, 128 experts, 262,144 ids (436 GB in bf16: shape tests and
+        # a later multi-chip issue)
+        "command-a-plus": Cohere2MoeConfig.command_a_plus,
+        # one chip of its deployment: layers 0-3, 16 of the 128 experts, an
+        # eighth of the vocabulary (chipbench/configs/
+        # command-a-plus-1chip.json)
+        "command-a-plus-4l-16e": Cohere2MoeConfig.command_a_plus_1chip,
+        "command-a-plus-tiny": Cohere2MoeConfig.tiny,
+    }
+
+
 def _dots3_presets() -> dict:
     from dynamo_tpu.models.dots3 import Dots3Config
 
@@ -687,6 +733,7 @@ _STATE_FAMILIES = (
     (_minicpm_sala_presets, _minicpm_sala_adapter),
     (_keye_vl_presets, _keye_vl_adapter),
     (_dots3_presets, _dots3_adapter),
+    (_cohere2_moe_presets, _cohere2_moe_adapter),
 )
 
 
@@ -696,9 +743,7 @@ def list_presets() -> list[str]:
     dry-resolves each one's logical axes through the rule table."""
     return sorted(_LLAMA_PRESETS) + sorted(_moe_presets()) + sorted(
         _mla_presets()
-    ) + sorted(_nemotron_h_presets()) + sorted(_falcon_h1_presets()) + sorted(
-        _minicpm_sala_presets()) + sorted(_keye_vl_presets()) + sorted(
-        _dots3_presets())
+    ) + [name for presets, _ in _STATE_FAMILIES for name in sorted(presets())]
 
 
 def get_model(
@@ -842,15 +887,22 @@ def get_model(
         # implemented in the flash kernels (they scale by 1/sqrt(head_dim))
         # — serve it on the XLA path rather than fail ("auto" on TPU would
         # otherwise pick pallas and raise at trace). Explicit requests get
-        # a WARNING (see the MLA coercion above).
+        # a WARNING (see the MLA coercion above). This is the models/llama.py
+        # and models/moe.py presets' window alone (a mask over pages that
+        # keep growing: Gemma2/3, Mistral); a family whose window layers
+        # keep a ring in the slot pool (models/dots3.py,
+        # models/cohere2_moe.py) runs its windows under the kernels.
         log = (
             logger.warning
             if attention_impl in ("pallas", "hybrid")
             else logger.info
         )
         log(
-            "%s: sliding-window/softcap/rescaled attention has no flash "
-            "kernel -> serving with attention_impl=xla",
+            "%s: the sliding-window/softcap/rescaled attention of the "
+            "models/llama.py presets (Gemma2/3, Mistral: a mask over pages "
+            "that keep growing) has no flash kernel -> serving with "
+            "attention_impl=xla (the families with a ring a window layer, "
+            "dots3-note-prev and Command A+, keep their kernels)",
             name,
         )
         cfg = replace(cfg, attention_impl="xla")

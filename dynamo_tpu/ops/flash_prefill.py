@@ -978,3 +978,234 @@ def window_prefill_attention(
         q_pos.astype(jnp.int32)[..., None], k_pos.astype(jnp.int32)[:, None],
     )
     return out.reshape(b, t, hn, dv)
+
+
+# ---------------------------------------------------------------------------
+# A band by position over GQA rows (models/cohere2_moe.py: a window layer's
+# prompt piece over its slot's ring and itself)
+# ---------------------------------------------------------------------------
+
+#: chunk queries of one grid cell; the G query heads of a KV head fold into
+#: its rows (x G: 2,048 rows at 16 heads a KV head)
+RING_BLOCK_Q = 128
+#: ring rows one turn takes at most (a divisor of the ring's length)
+RING_BLOCK_K = 512
+
+
+def _ring_kernel(
+    live_ref,  # [B, R / BK] int32 (scalar prefetch): a ring tile holds a key
+    # in reach of the piece
+    q_ref,  # [1, BQ, G x D]: the queries of one KV head's G heads, SCALED
+    kcur_ref,  # [1, T, D]: that KV head's keys of the piece itself
+    vcur_ref,  # [1, T, D]
+    ring_k_ref,  # [1, 1, R, D]: the row's ring of that KV head
+    ring_v_ref,  # [1, 1, R, D]
+    qpos_ref,  # [1, BQ, 1] int32: the queries' positions
+    rpos_ref,  # [1, 1, R] int32: the position a ring row holds; < 0: none
+    cpos_ref,  # [1, 1, T] int32: the piece's positions; < 0: padding
+    o_ref,  # [1, BQ, G x D]
+    m_scr,  # [G x BQ, 128] f32 running max (every lane the same)
+    l_scr,  # [G x BQ, 128] f32 running denominator
+    acc_scr,  # [G x BQ, D] f32
+    *,
+    window: int,
+    block_k: int,
+):
+    b = pl.program_id(0)
+    qi = pl.program_id(2)
+    bq, t = q_ref.shape[1], kcur_ref.shape[1]
+    d = kcur_ref.shape[2]
+    g = q_ref.shape[2] // d
+    # the G heads' tiles one under the other: one dot a key tile for all
+    q = jnp.concatenate(
+        [q_ref[0, :, i * d:(i + 1) * d] for i in range(g)], axis=0)
+    at = jnp.concatenate([qpos_ref[0]] * g, axis=0)  # [G x BQ, 1]
+
+    def fold(k, v, key, first=False):
+        """One turn of the online softmax over the keys `k` [K, D] at the
+        positions `key` [1, K] (negative: none) and their values `v`."""
+        keep = (key >= 0) & (key <= at) & (key >= at - (window - 1))
+        sc = jnp.where(keep, jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32), _MASKED)  # [G x BQ, K]
+        m_cur = jnp.max(sc, axis=1, keepdims=True)
+        m_new = m_cur if first else jnp.maximum(m_scr[:, :1], m_cur)
+        p = jnp.where(keep, jnp.exp(sc - m_new), 0.0)
+        l_new = jnp.sum(p, axis=1, keepdims=True)
+        # (a row that holds no key is what its slot's last owner left, or a
+        # piece's padding: rows the model computed or the pool's zeros, so
+        # finite, and a zero weight silences them)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [G x BQ, D]
+        if not first:
+            corr = jnp.exp(m_scr[:, :1] - m_new)
+            l_new += corr * l_scr[:, :1]
+            pv += corr * acc_scr[...]
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        acc_scr[...] = pv
+
+    # -- the piece over itself: key tiles wholly after this cell's queries
+    # skipped (positions ascend along a piece) -------------------------------
+    for j in range(t // bq):
+        def turn(j=j):
+            rows = pl.ds(j * bq, bq)
+            fold(kcur_ref[0, rows, :], vcur_ref[0, rows, :],
+                 cpos_ref[0, :, rows], first=j == 0)
+
+        if j == 0:
+            turn()
+        else:
+            pl.when(j <= qi)(turn)
+
+    # -- the ring: rows as they lie, a tile none of whose rows the piece's
+    # first query reaches skipped --------------------------------------------
+    def body(i, _):
+        def turn():
+            rows = pl.ds(pl.multiple_of(i * block_k, block_k), block_k)
+            fold(ring_k_ref[0, 0, rows, :], ring_v_ref[0, 0, rows, :],
+                 rpos_ref[0, :, rows])
+
+        pl.when(live_ref[b, i] != 0)(turn)
+        return 0
+
+    jax.lax.fori_loop(0, ring_k_ref.shape[2] // block_k, body, 0)
+    out = acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
+    for i in range(g):
+        o_ref[0, :, i * d:(i + 1) * d] = out[i * bq:(i + 1) * bq].astype(
+            o_ref.dtype)
+
+
+def _gather_kernel(layer_ref, pages_ref, pool_ref, out_ref, sem):
+    """Every named page of layer `layer_ref[0]` into its place of the
+    output, HBM to HBM: all copies out before any wait (the targets are
+    disjoint)."""
+    def copy(i):
+        return pltpu.make_async_copy(
+            pool_ref.at[layer_ref[0], pages_ref[i]], out_ref.at[i], sem)
+
+    def start(i, _):
+        copy(i).start()
+        return 0
+
+    def drain(i, _):
+        copy(i).wait()
+        return 0
+
+    jax.lax.fori_loop(0, out_ref.shape[0], start, 0)
+    jax.lax.fori_loop(0, out_ref.shape[0], drain, 0)
+
+
+def gather_pages(pool: jax.Array, layer: jax.Array, pages: jax.Array,
+                 *, use_kernel: bool | None = None) -> jax.Array:
+    """The pages `pages` [n] of layer `layer` of a pool [L, P, S, Hkv, D],
+    one after the other: [n x S, Hkv, D], a copy. As a kernel of whole-page
+    DMAs (the TPU's default) the pool goes in as it lies: XLA's own gather,
+    in a loop over the rows of a batch and followed by a transpose, was
+    answered by the compiler with a transposed copy of the WHOLE pool
+    outside the loop (1 GB each for the rings of models/cohere2_moe.py:
+    the compile for the described v5e, PR 52)."""
+    if use_kernel is None:
+        use_kernel = jax.default_backend() == "tpu"
+    n, shape = pages.shape[0], pool.shape[2:]
+    if not use_kernel:
+        return jax.lax.dynamic_index_in_dim(
+            pool, layer, 0, keepdims=False)[pages].reshape(
+            n * shape[0], *shape[1:])
+    out = pl.pallas_call(
+        _gather_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA],
+        ),
+        out_shape=jax.ShapeDtypeStruct((n, *shape), pool.dtype),
+        name="gather_pages",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), pages.astype(jnp.int32),
+      pool)
+    return out.reshape(n * shape[0], *shape[1:])
+
+
+def ring_prefill_attention(
+    q: jax.Array,  # [B, T, Hq, D] queries, SCALED
+    k_cur: jax.Array,  # [B, T, Hkv, D] the piece's own keys
+    v_cur: jax.Array,  # [B, T, Hkv, D]
+    ring_k: jax.Array,  # [B, Hkv, R, D] each row's cached keys, by KV head
+    ring_v: jax.Array,  # [B, Hkv, R, D]
+    q_pos: jax.Array,  # [B, T] int32
+    ring_pos: jax.Array,  # [B, R] int32: the position a row holds; < 0: none
+    cur_pos: jax.Array,  # [B, T] int32, ascending; < 0: padding
+    *,
+    window: int,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """A prompt piece's attention under a sliding window stated by POSITION
+    over GQA rows: the keys are the piece's own rows and its sequence's
+    RING as the rows lie (a ring row holds the position `ring_pos` says, in
+    no order: the band is a mask by position, not a range of columns), the
+    ring handed in a KV head at a time. A query at position t attends the
+    keys whose position lies in `[t - (window - 1), t]`. A grid cell is (row
+    of the batch, KV head, tile of `RING_BLOCK_Q` queries): the G query
+    heads that share the KV head fold into the tile's rows, so a key tile
+    is read once for all of them; the band is walked as a CHAIN of key
+    tiles under a running max and sum (4,608 ring rows are 9 tiles of 512),
+    the piece's own tiles first, tiles after the cell's queries and ring
+    tiles the piece's first query cannot reach skipped. Operands reach the
+    MXU in the dtype they come in; scores and softmax are float32 in VMEM.
+
+    Returns [B, T, Hq, D] in the queries' dtype; a query with no key in its
+    band (padding) gets zeros.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, t, hq, d = q.shape
+    hkv = k_cur.shape[2]
+    g = hq // hkv
+    r = ring_k.shape[2]
+    bq = RING_BLOCK_Q if t % RING_BLOCK_Q == 0 else t
+    bk = next(n for n in (RING_BLOCK_K, 256, 128, 64, 32, 16, 8, 4, 2, 1)
+              if r % n == 0)
+    first = jnp.min(jnp.where(cur_pos >= 0, cur_pos, 1 << 30), axis=1)
+    live = jnp.any(
+        ((ring_pos >= 0) & (ring_pos >= first[:, None] - (window - 1))
+         ).reshape(b, r // bk, bk), axis=-1).astype(jnp.int32)
+    tile = pl.BlockSpec((1, bq, g * d), lambda bi, h, qi, lv: (bi, qi, h))
+    piece = pl.BlockSpec((1, t, d), lambda bi, h, qi, lv: (bi, 0, h))
+    ring = pl.BlockSpec((1, 1, r, d), lambda bi, h, qi, lv: (bi, h, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_ring_kernel, window=window, block_k=bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, hkv, t // bq),
+            in_specs=[
+                tile, piece, piece, ring, ring,
+                pl.BlockSpec((1, bq, 1), lambda bi, h, qi, lv: (bi, qi, 0)),
+                pl.BlockSpec((1, 1, r), lambda bi, h, qi, lv: (bi, 0, 0)),
+                pl.BlockSpec((1, 1, t), lambda bi, h, qi, lv: (bi, 0, 0)),
+            ],
+            out_specs=tile,
+            scratch_shapes=[
+                pltpu.VMEM((g * bq, 128), jnp.float32),
+                pltpu.VMEM((g * bq, 128), jnp.float32),
+                pltpu.VMEM((g * bq, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, t, hq * d), q.dtype),
+        interpret=interpret,
+        name="ring_prefill_attention",
+        # a row's ring of one KV head, keys and values, twice (the
+        # pipeline's two buffers): 4.7 MB at 4,608 rows of 128; a turn's
+        # scores and weights [2048, 512] float32 4 MB each
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=96 * 1024 * 1024
+        ),
+    )(
+        live, q.reshape(b, t, hq * d), k_cur.reshape(b, t, hkv * d),
+        v_cur.reshape(b, t, hkv * d), ring_k, ring_v,
+        q_pos.astype(jnp.int32)[..., None],
+        ring_pos.astype(jnp.int32)[:, None],
+        cur_pos.astype(jnp.int32)[:, None],
+    )
+    return out.reshape(b, t, hq, d)

@@ -56,7 +56,7 @@ def model_passes(preset, impl):
 
 
 for preset in ("mla-tiny", "mla-tiny-moe", "keye-vl2-tiny",
-               "nemotron-h-tiny"):
+               "nemotron-h-tiny", "dots3-tiny"):
     for impl in ("xla", "pallas"):
         try:
             model_passes(preset, impl)
