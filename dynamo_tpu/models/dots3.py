@@ -10,7 +10,8 @@ dots3_note): two kinds of latent attention in one stack, by `layer_types`.
   interleaved rope on q's rope part and on `k_r`; the rescale
   (`apply_mla_qkv_lora_rescale`) `a = sqrt(hidden / rank)` on the normed
   latents, not on the rope key. The cache holds `c_kv` and `k_r`; attention
-  runs in models/mla.py's absorbed form.
+  runs in models/mla.py's absorbed form or, for a prompt piece under the
+  kernels, in the plain one (below).
 - a FULL layer (F; 128 heads, c 512): DeepSeek-V3.2-Exp's lightning
   indexer over the latent cache: `qI = W_qI c_q` (`index_heads` x
   `index_head_dim`), `kI = LayerNorm(W_kI x)` (ONE key a token), `w = W_w x
@@ -51,23 +52,35 @@ position, reckoned from the row's last written one, is not negative.
 
 Under the kernels (`attention_impl` "pallas") the F layers' pools are read
 only inside the layer scan: index scores out of the pool in place
-(ops/index_scores.py, `paired=False`), the exact selection, a decode row's
-walk of its pages under a bit a cached token (`attn/paged`,
-ops/paged_attention.py `latent` + `token_bits`), a prompt chunk by tile
-under a mask bit a (query, key) (`attn/flash`, ops/flash_prefill.py
-`latent_prefill_attention` with `chosen`); the step's rows are staged and
-land once. A ring IS a few pages of a latent cache (1,088 rows = 17 pages
-of 64 a slot), and under `attn/window` an S layer takes one of two FORMS
-by the shape of the group it is handed (`plain_piece`), the step's own
-rows in hand and written into the ring after the layer's attention:
+(ops/index_scores.py, `paired=False`), the exact selection, and attention
+in one of two FORMS by the shape of the group (`plain_full`):
 
-- ABSORBED (`window_attend`), as a long latent history needs and as the F
-  layers do: the SAME two kernels over the slot's ring pages in reach, the
-  decode walk under a bit a ring row (its position inside the query's
-  window) and the chunk kernel under a mask a (query, ring row). A DECODE
-  row (T = 1) and a SHORT piece (a 32-token tail, the ramp's short
-  prompts): few queries, so the latent-wide query and output cost little
-  and no key is up-projected;
+- a DECODE row (T = 1) ABSORBED (`full_attend`): the walk of its pages
+  under a bit a cached token (`attn/paged`, ops/paged_attention.py `latent`
+  + `token_bits`), its rows alone through `absorbed_query` and the value
+  up-projection;
+- a prompt PIECE (T > 1: the 512 bucket, and the 32 bucket's tails and
+  short prompts alike) PLAIN (`full_piece`), as latent models prefill: the
+  queries as projected, and the history's latent rows sent through `wkv_b`
+  INSIDE the kernel a block of 512 at a time (`attn/flash`,
+  ops/flash_prefill.py `latent_plain_attention`: K and V of 128 heads over
+  8k-18k keys are ~1 GB a layer and piece and are never written out),
+  shared by the piece's T queries, under a mask bit a (query, key): 1,280
+  FLOP a (query, key, head) as the MXU takes them where the absorbed form
+  costs 2,304 and a query and an output of the latent's width around it.
+
+The step's rows are staged and land once. A ring IS a few pages of a
+latent cache (1,088 rows = 17 pages of 64 a slot), and under `attn/window`
+an S layer takes one of two forms too (`plain_piece`), the step's own rows
+in hand and written into the ring after the layer's attention:
+
+- ABSORBED (`window_attend`): the decode walk over the slot's ring pages in
+  reach under a bit a ring row (its position inside the query's window)
+  and the absorbed chunk kernel (ops/flash_prefill.py
+  `latent_prefill_attention` with `chosen`) under a mask a (query, ring
+  row). A DECODE row (T = 1) and a SHORT piece (a 32-token tail, the
+  ramp's short prompts): few queries, so the latent-wide query and output
+  cost little and no key is up-projected;
 - PLAIN (`window_piece`), as latent models prefill: a prompt PIECE whose T
   rows are not few beside the 576 ring rows in reach (4 T >= 576: a
   512-token piece). A window reaches 512 keys behind a query, so the keys
@@ -639,15 +652,28 @@ def index_rope(x, positions, cfg: Dots3Config):
         x[..., r:]], axis=-1)
 
 
+def piece_chosen(qi, ki_new, w, ki_pool, layer, g: StepGroup,
+                 cfg: Dots3Config):
+    """bool [B, T, N]: under the kernels, the keys each query of a prompt
+    piece attends in a full layer, by position: the index scores of the
+    cached keys out of the pool in place and of the piece's own, then the
+    exact selection."""
+    return keye.chosen_keys(
+        lambda *rows: keye.step_scores(*rows, ki_pool, layer, paired=False),
+        (qi, w, ki_new, g.page_tables, g.positions, g.valid),
+        g.page_tables.shape[1] * ki_pool.shape[2], g.positions, g.valid,
+        cfg.index_topk)
+
+
 def full_attend(ql, qp, ck, kp, qi, ki_new, w, kv, ki_pool, layer,
                 g: StepGroup, work, cfg: Dots3Config):
     """One group's attention in a full layer over the tokens its indexer
-    chooses. ql [B, T, H, c] float32 absorbed queries, qp [B, T, H, r], ck
-    [B, T, c], kp [B, T, r] post-rope; qi, ki_new, w the indexer's.
-    Returns (o_lat [B, T, H, c], (k, v) pools, ki_pool, what the kernels'
-    discipline staged: (latent, rope key as cached, index keys) or None,
-    int32 [4] what the step attended as models/keye_vl.py counts it, the
-    selection bool [B, T, N])."""
+    chooses, in the ABSORBED form. ql [B, T, H, c] float32 absorbed
+    queries, qp [B, T, H, r], ck [B, T, c], kp [B, T, r] post-rope; qi,
+    ki_new, w the indexer's. Returns (o_lat [B, T, H, c], (k, v) pools,
+    ki_pool, what the kernels' discipline staged: (latent, rope key as
+    cached, index keys) or None, int32 [4] what the step attended as
+    models/keye_vl.py counts it, the selection bool [B, T, N])."""
     geo = cfg.full_geo
     t = ql.shape[1]
     tables, positions, valid = g.page_tables, g.positions, g.valid
@@ -678,48 +704,130 @@ def full_attend(ql, qp, ck, kp, qi, ki_new, w, kv, ki_pool, layer,
         with jax.named_scope("select"):
             chosen = ts.select_tokens(sc, context, topk)[:, None]
     else:
-        chosen = keye.chosen_keys(
-            lambda *rows: keye.step_scores(
-                *rows, ki_pool, layer, paired=False),
-            (qi, w, ki_new, tables, positions, valid), n, positions, valid,
-            topk)
+        chosen = piece_chosen(qi, ki_new, w, ki_pool, layer, g, cfg)
     o_lat, kv, (c_st, pe_st) = mla._attend_kernels(
         ql, qp, ck, kp, geo, kv, layer, g, work, None, chosen=chosen)
     return o_lat, kv, ki_pool, (c_st, pe_st, ki_new), counted, chosen
 
 
+def full_piece(qn, qp, ck, kp, qi, ki_new, w, kv, ki_pool, layer,
+               g: StepGroup, cfg: Dots3Config, wkv_b):
+    """Under the kernels, a prompt piece's attention in a full layer in the
+    PLAIN form over the tokens its indexer chooses. A piece's history is
+    8k-18k latent rows: K and V of 128 heads over them would be ~1 GB a
+    layer and piece, so they are never written out; one kernel
+    (ops/flash_prefill.py `latent_plain_attention`) takes a block of cached
+    latent rows a turn and sends it through a group of heads' columns of
+    `wkv_b` [c, H, nope + v] in VMEM, `K_h = (W_UK,h c | k_r)`, `V_h =
+    W_UV,h c`, shared by the piece's T queries. The queries `qn` [B, T, H,
+    nope] and `qp` [B, T, H, r] post-rope go in as projected, scaled, the
+    rope part in the cached rope key's columns; ck [B, T, c] and kp [B, T,
+    r] post-rope are the piece's own rows, in hand and not cached yet.
+    Returns (o [B, T, H, v] float32, what `full_attend` stages for the
+    landing, int32 [4] what it counts): no query and no output of the
+    latent's width exists."""
+    from dynamo_tpu.ops.flash_prefill import latent_plain_attention
+
+    geo = cfg.full_geo
+    chosen = piece_chosen(qi, ki_new, w, ki_pool, layer, g, cfg)
+    pe_rows = mla._pad_last(kp, geo.kv_rope_dim)  # the rope key as cached
+    with jax.named_scope("flash"):
+        q = (jnp.concatenate(
+            [qn, mla._pad_last(qp, geo.kv_rope_dim)], -1
+        ).astype(jnp.float32) * geo.softmax_scale).astype(cfg.dtype)
+        o = latent_plain_attention(
+            q, wkv_b, ck, pe_rows, *kv, layer, g.page_tables,
+            jnp.where(g.valid[:, 0], g.positions[:, 0], 0),
+            jnp.sum(g.valid, axis=1), chosen)
+    counted = jnp.concatenate([
+        jnp.zeros((2,), jnp.int32),
+        keye.chunk_pairs(g.positions, g.valid, cfg.index_topk)])
+    return o, (ck[:, :, None, :], pe_rows[:, :, None, :], ki_new), counted
+
+
+def plain_full(t: int, cfg: Dots3Config) -> bool:
+    """Whether a group of T rows attends a full layer in the plain form
+    (`full_piece`) under the kernels: every prompt piece (T > 1), whose T
+    queries share a key's up-projection; a decode row (T = 1) has one query
+    a sequence and keeps the absorbed page walk under bits."""
+    return cfg.kernels and t > 1
+
+
 def full_attention(x, lp, cfg: Dots3Config, kv, ki_pool, layer, groups,
                    works):
-    """A full layer's attention block on the groups' rows. Returns (out
-    shaped like x, (k, v), ki_pool, per group what was staged, int32 [4]
-    counted). Scopes, under the caller's `attn`: `qkv`, `index`, `select`,
-    `absorb`, `paged`, `flash`, `kv_update`, `gate`, `out`."""
+    """A full layer's attention block on the groups' rows: the projections
+    and `wo` on all rows at once, the attention a group in the form its
+    shape asks for (`plain_full`). Under the kernels a prompt piece (T > 1)
+    attends PLAIN (`full_piece`); a decode row, and every group without the
+    kernels, attends ABSORBED (`full_attend`), those groups' rows alone
+    through `absorbed_query` and the value up-projection (32 of a mixed
+    step's 544); the head gate a group, `wo` on the heads side by side.
+    Returns (out shaped like x, (k, v), ki_pool, per group what was staged,
+    int32 [4] counted). Scopes, under the caller's `attn`: `qkv`, `index`,
+    `select`, `absorb`, `paged`, `flash`, `kv_update`, `gate`, `out`."""
     geo = cfg.full_geo
     n = geo.qk_nope_head_dim
     q, c_kv, kv_a, c_q = mla.latent_projections(
         x, lp, geo, cfg.rescale(geo))
-    q_lat, w_uv = mla.absorbed_query(q, lp, geo)
+    qs = split_rows(q, groups)
+    # the groups that attend absorbed, their rows alone through W_UK (every
+    # group: the rows as they are, not taken apart and joined again)
+    rest = [i for i, g in enumerate(groups)
+            if not plain_full(g.positions.shape[1], cfg)]
+    q_lats, w_uv = {}, None
+    if rest:
+        q_lat, w_uv = mla.absorbed_query(
+            q if len(rest) == len(groups) else join_rows(
+                [qs[i] for i in rest]), lp, geo)
+        q_lats = dict(zip(rest, split_rows(
+            q_lat, [groups[i] for i in rest])))
     qi, ki, w = index_projections(x, c_q, lp, cfg)
     gate = head_gate(x, lp, cfg)
-    o_lats, staged, counted = [], [], jnp.zeros((4,), jnp.int32)
-    parts = (q_lat, q[..., n:], c_kv, kv_a[..., geo.kv_lora_rank:], qi, ki,
-             w)
-    for g, work, ql, qp, ck, kp, qig, kig, wg in zip(
-        groups, works, *(split_rows(a, groups) for a in parts)
-    ):
+    gates = [None] * len(groups) if gate is None else split_rows(gate, groups)
+    outs, staged, counted = [], [], jnp.zeros((4,), jnp.int32)
+    parts = (q[..., n:], c_kv, kv_a[..., geo.kv_lora_rank:], qi, ki, w)
+    for i, (g, work, gt, qg, qp, ck, kp, qig, kig, wg) in enumerate(zip(
+        groups, works, gates, qs, *(split_rows(a, groups) for a in parts)
+    )):
         with jax.named_scope("qkv"):
             qp = mla._interleaved_rope(qp, g.positions, geo)
             kp = mla._interleaved_rope(kp, g.positions, geo).astype(cfg.dtype)
         with jax.named_scope("index"):
             qig = index_rope(qig, g.positions, cfg)
             kig = index_rope(kig, g.positions, cfg).astype(cfg.dtype)
-        o_lat, kv, ki_pool, st, cnt, _ = full_attend(
-            ql, qp, ck, kp, qig, kig, wg, kv, ki_pool, layer, g, work, cfg)
-        o_lats.append(o_lat.astype(w_uv.dtype))
+        if i in q_lats:
+            o_lat, kv, ki_pool, st, cnt, _ = full_attend(
+                q_lats[i], qp, ck, kp, qig, kig, wg, kv, ki_pool, layer, g,
+                work, cfg)
+        else:
+            wkv_b = mla._w(lp, "wkv_b", cfg.dtype).reshape(
+                geo.kv_lora_rank, geo.num_heads, n + geo.v_head_dim)
+            o, st, cnt = full_piece(
+                qg[..., :n], qp, ck, kp, qig, kig, wg, kv, ki_pool, layer, g,
+                cfg, wkv_b)
         staged.append(st)
         counted = counted + cnt
+        # a group's heads gated and laid side by side, [B, T, H x v] in the
+        # model dtype: `mla.heads_output`'s first half a group (after the
+        # count, where the parent's program has it: a decode program lowers
+        # to the same text). A plain piece's heads leave the kernel side by
+        # side and are gated so: joined as [544, 128, 128] they were laid
+        # out by head and back, 2 ms of a one-piece step (PERF.md 6, PR 53)
+        with jax.named_scope("out"):
+            if i in q_lats:  # the value up-projection
+                o = jnp.einsum(
+                    "...hc,chv->...hv", o_lat.astype(w_uv.dtype), w_uv,
+                    preferred_element_type=jnp.float32)
+                if gt is not None:
+                    o = o * gt[..., None].astype(jnp.float32)
+                o = o.reshape(*o.shape[:-2], -1)
+            else:
+                o = o.reshape(*o.shape[:-2], -1)
+                if gt is not None:
+                    o = o * jnp.repeat(gt, geo.v_head_dim, axis=-1)
+        outs.append(o.astype(cfg.dtype))
     with jax.named_scope("out"):
-        out = mla.latent_output(join_rows(o_lats), w_uv, lp, geo, gate)
+        out = _mm(join_rows(outs), lp, "wo", cfg.dtype)
     return out, kv, ki_pool, tuple(staged), counted
 
 

@@ -18,7 +18,11 @@ Three kernels: `flash_prefill_attention` (a first chunk: no history),
 `latent_prefill_attention` (the same for a latent cache, models/mla.py:
 one row a token that is key and value at once, a rope key beside it, bf16
 operands on the MXU, a block of pages a turn; a body of its own at the
-end of the file, since those needs conflict with the GQA body's).
+end of the file, since those needs conflict with the GQA body's). After
+them `latent_plain_attention` (a latent cache attended in the PLAIN form,
+keys and values up-projected a block at a time in VMEM), the two banded
+kernels by position (`window_prefill_attention`, `ring_prefill_attention`)
+and `gather_pages`.
 
 Parity note: the reference gets its prefill kernels from vLLM/TRT-LLM
 (engine-delegated, SURVEY.md §2.9); here the engine is first-class so the
@@ -725,11 +729,15 @@ def latent_prefill_attention(
     scores, softmax, sums and the accumulator are float32 and live in
     VMEM a tile at a time. A history of 0 runs no turn.
 
-    `chosen` names the keys each query attends, by position (a learned
-    indexer's choice, ops/token_select.py; models/dots3.py): every history
+    `chosen` names the keys each query attends, by position: every history
     page is still read and scored, and a key not chosen is masked, as
     `ops/sparse_chunk.token_chunk_attention` does for GQA pools; one set a
-    query token, shared by the tile's heads.
+    query token, shared by the tile's heads. Who still hands it one
+    (models/dots3.py): a window layer's SHORT piece (a 32-token tail, the
+    ramp's short prompts: `window_attend`, the window as a mask over the
+    slot's ring pages) and `full_attend` with T > 1, which only the
+    benchmark's reference and the tests call since a full layer's prompt
+    piece attends in the plain form (`latent_plain_attention` below).
 
     Returns o_lat [B, T, H, C] in the queries' dtype (the float32
     quotient rounded once, on the way out: what the caller's value
@@ -867,6 +875,331 @@ def latent_prefill_attention(
         v_cache.reshape(*v_cache.shape[:3], r),
     )
     return out[:, :, :t].transpose(0, 2, 1, 3)
+
+
+# ---------------------------------------------------------------------------
+# A latent cache attended in the PLAIN form (models/dots3.py: a full layer's
+# prompt piece), its keys and values up-projected a block at a time in VMEM
+# ---------------------------------------------------------------------------
+
+#: history pages one turn takes (1,024 keys at S = 64). A turn's fixed work
+#: (a head's running state read and written, the lane reductions, the
+#: weights' tiles loaded into the MXU for 1,024 latent rows and not 512)
+#: halves with its length: a layer's block at one 512-token piece over
+#: 8,192 tokens read 11.3 / 6.3 / 5.7 / 5.7 ms at 4 / 8 / 16 / 32 pages
+#: (PERF.md 6, PR 53); the last block's tail past the history is wasted
+PLAIN_BLOCK_PAGES = 16
+#: heads of one grid cell: a block of latent rows lands once for them all
+#: (4, 8 and 16 read the same)
+PLAIN_HEADS = 8
+#: queries of one softmax pass: a head's [T, keys] scores are taken 128
+#: rows at a time, the next rows' product issued before this pass's
+#: vector work, so a [128, 1024] float32 tile is live and not [512, 1024]
+#: (7.9 -> 6.3 ms a layer's block at blocks of 8 pages; 64 rows read 7.2).
+#: Two or four heads a turn of the head loop, their chains side by side,
+#: gained 0.4-1 % over one and cost 2.3 s of a step program's compile
+PLAIN_ROWS = 128
+
+
+def _plain_kernel(
+    # scalar prefetch
+    layer_ref,  # [1] int32
+    pt_ref,  # [B, MP] int32 page tables (SMEM)
+    hist_ref,  # [B] int32: tokens already in the cache (the piece's start)
+    cur_ref,  # [B] int32: valid tokens in THIS piece
+    # inputs
+    q_ref,  # [1, T, G x (n + R)] VMEM: G heads' queries as projected, scaled
+    w_ref,  # [C, G x (n + v)] VMEM: those heads' columns of `wkv_b`
+    lcur_ref,  # [1, T, C] VMEM: the piece's latent rows, zeros past `cur`
+    rcur_ref,  # [1, T, R] VMEM: its rope keys as cached, zeros past `cur`
+    mo_ref,  # [1, T, T] VMEM int8: query x the piece's own key
+    mh_hbm,  # [B, T, NB x K] ANY int8: query x cached key, by position
+    lat_hbm,  # [L, P, S, C] ANY: the latent pool
+    rope_hbm,  # [L, P, S, R] ANY: the rope-key pool
+    # output
+    o_ref,  # [1, T, G x v] float32
+    # scratch
+    lat_scr,  # [2, K, C] VMEM: a slot is a block of pages
+    rope_scr,  # [2, K, R]
+    mh_scr,  # [2, T, K] int8: the block's columns of the mask
+    bias_scr,  # [T, max(K, T)] f32: 0 where the turn's key is attended
+    k_scr,  # [max(K, T), n + R]: a head's keys, the rope key beside them
+    m_scr,  # [G, T, 128] f32 running max (every lane the same)
+    l_scr,  # [G, T, 128] f32 running denominator
+    acc_scr,  # [G, T, v] f32
+    sem,  # [3, 2] DMA semaphores: [plane, slot]
+    *,
+    page_size: int,
+    block_pages: int,
+    nope: int,
+    step: int,
+):
+    b = pl.program_id(1)
+    li = layer_ref[0]
+    t = lcur_ref.shape[1]
+    n, g, v = nope, acc_scr.shape[0], acc_scr.shape[2]
+    qd, wd = q_ref.shape[2] // g, w_ref.shape[1] // g
+    s, pb, mp = page_size, block_pages, pt_ref.shape[1]
+    kb = pb * s
+    hist = hist_ref[b]
+    cur = cur_ref[b]
+    n_blk = pl.cdiv(hist, kb)
+    mxu = lat_scr.dtype  # the pool's dtype is the model's: no cast to f32
+
+    def copies(slot, blk):
+        """The DMAs of history block `blk` into `slot`: one a page and
+        plane (`_latent_kernel`'s: pages past the row's history fetch
+        whatever the table names there, their keys masked) and the
+        block's columns of the mask."""
+        out = []
+        for p in range(pb):
+            page = pt_ref[b, jnp.minimum(blk * pb + p, mp - 1)]
+            for pi, (src, dst) in enumerate(
+                ((lat_hbm, lat_scr), (rope_hbm, rope_scr))
+            ):
+                out.append(pltpu.make_async_copy(
+                    src.at[li, page],
+                    dst.at[slot, pl.ds(p * s, s)],
+                    sem.at[pi, slot],
+                ))
+        out.append(pltpu.make_async_copy(
+            mh_hbm.at[b, :, pl.ds(pl.multiple_of(blk * kb, kb), kb)],
+            mh_scr.at[slot], sem.at[2, slot],
+        ))
+        return out
+
+    @pl.when(n_blk > 0)  # the first block lands under the piece's own turn
+    def _():
+        for cp in copies(0, 0):
+            cp.start()
+
+    def turn(lat, rope, keys: int, first=False):
+        """One turn of every head's online softmax over `keys` latent rows
+        `lat()` [keys, C] and rope keys `rope()` [keys, R], under the bias
+        in `bias_scr[:, :keys]`: a head's keys and values come out of ONE
+        product with its columns of `wkv_b` (float32 sums rounded once to
+        the pool's dtype), the one rope key a token beside every head's
+        keys; then, `step` queries at a time, `q_h K_h^T`, the softmax
+        turn, `p V_h`. A loop over the heads, so that a turn's body is
+        compiled once; `first` starts the state."""
+        k_scr[:keys, n:] = rope()
+
+        def head(h, _):
+            kv = jax.lax.dot_general(
+                lat(), w_ref[:, pl.ds(pl.multiple_of(h * wd, wd), wd)],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [keys, n + v]
+            k_scr[:keys, :n] = kv[:, :n].astype(mxu)
+            vh = kv[:, n:].astype(mxu)
+            at = pl.ds(pl.multiple_of(h * qd, qd), qd)
+
+            def scores(c):
+                rows = pl.ds(c * step, step)
+                return jax.lax.dot_general(
+                    q_ref[0, rows, at], k_scr[:keys],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) + bias_scr[rows, :keys]  # [step, keys]
+
+            ahead = scores(0)
+            for c in range(t // step):
+                rows = pl.ds(c * step, step)
+                sc = ahead
+                if c + 1 < t // step:
+                    ahead = scores(c + 1)
+                m_cur = jnp.max(sc, axis=1, keepdims=True)
+                m_new = m_cur if first else jnp.maximum(
+                    m_scr[h, rows, :1], m_cur)
+                p = jnp.exp(sc - m_new)
+                l_new = jnp.sum(p, axis=1, keepdims=True)
+                pv = jax.lax.dot_general(
+                    p.astype(mxu), vh, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [step, v]
+                if not first:
+                    corr = jnp.exp(m_scr[h, rows, :1] - m_new)
+                    l_new += corr * l_scr[h, rows, :1]
+                    pv += corr * acc_scr[h, rows]
+                m_scr[h, rows] = jnp.broadcast_to(
+                    m_new, (step, m_scr.shape[-1]))
+                l_scr[h, rows] = jnp.broadcast_to(
+                    l_new, (step, l_scr.shape[-1]))
+                acc_scr[h, rows] = pv
+            return 0
+
+        jax.lax.fori_loop(0, g, head, 0)
+
+    # -- the piece over itself: causal by position, padding masked ----------
+    row = jax.lax.broadcasted_iota(jnp.int32, (t, 1), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
+    bias_scr[:, :t] = jnp.where(
+        (mo_ref[0].astype(jnp.int32) != 0) & (col <= row) & (col < cur),
+        0.0, _MASKED)
+    turn(lambda: lcur_ref[0], lambda: rcur_ref[0], t, first=True)
+
+    # -- the history: every key lies before the piece, the last block's
+    # tail past `hist` masked ------------------------------------------------
+    def body(i, _):
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_blk)
+        def _():
+            for cp in copies(1 - slot, i + 1):
+                cp.start()
+
+        for cp in copies(slot, i):
+            cp.wait()
+        key_pos = i * kb + jax.lax.broadcasted_iota(jnp.int32, (1, kb), 1)
+        bias_scr[:, :kb] = jnp.where(
+            (mh_scr[slot].astype(jnp.int32) != 0) & (key_pos < hist),
+            0.0, _MASKED)
+        turn(lambda: lat_scr[slot], lambda: rope_scr[slot], kb)
+        return 0
+
+    jax.lax.fori_loop(0, n_blk, body, 0)
+    # (a query none of whose keys is chosen has no sum: only padding rows)
+    for h in range(g):
+        o_ref[0, :, h * v:(h + 1) * v] = acc_scr[h] / jnp.maximum(
+            l_scr[h, :, :1], 1e-30)
+
+
+def latent_plain_attention(
+    q: jax.Array,  # [B, T, H, n + R] queries as projected, SCALED: the nope
+    # part, then the rope part in the cached rope key's columns (zeros past)
+    wkv_b: jax.Array,  # [C, H, n + v]: W_UK | W_UV a head
+    lat_cur: jax.Array,  # [B, T, C] this piece's latent rows
+    rope_cur: jax.Array,  # [B, T, R] this piece's rope keys as cached
+    k_cache: jax.Array,  # [L, P, S, 1, C] the latent pool (history)
+    v_cache: jax.Array,  # [L, P, S, 1, R] the rope-key pool
+    layer: jax.Array,  # scalar int32
+    page_tables: jax.Array,  # [B, MP] int32
+    hist_lens: jax.Array,  # [B] int32: tokens already written to pages
+    cur_lens: jax.Array,  # [B] int32: valid tokens in this piece
+    chosen: jax.Array,  # [B, T, MP * S] bool, by position
+    *,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """A prompt piece of a latent-cache model over its paged history and
+    over itself in the PLAIN form, as latent models prefill: `softmax(q_h .
+    (W_UK,h c | k_r)) . W_UV,h c` a head, under the keys `chosen` names a
+    query (by position: a learned indexer's choice, models/dots3.py; one
+    set a query token, shared by its heads; every history page is still
+    read and scored, a key not chosen masked). The cache holds latent rows,
+    and K and V of 128 heads over 8k-18k keys are ~1 GB a layer and piece,
+    so the up-projection happens HERE: a grid cell is (a row's piece,
+    `PLAIN_HEADS` heads) with ALL the piece's queries, a turn takes one
+    block of `PLAIN_BLOCK_PAGES` history pages (double-buffered DMA from
+    the stacked pools with the block's columns of the int8 mask, `layer`
+    and the page tables prefetched; `_latent_kernel`'s page discipline)
+    and per head ONE product of the block with the head's columns of
+    `wkv_b` gives its keys and values in VMEM (float32 sums rounded once
+    to the pool's dtype), shared by the piece's T queries: 2 x (n + R + v)
+    FLOP a (query, key, head) and 2 x C x (n + v) / T for the up-projection
+    where the absorbed form (`latent_prefill_attention`) costs 2 x (2 C +
+    R) and needs a query and an output of the latent's width. The piece's
+    own rows, in hand and not cached yet, are the first turn. Operands
+    reach the MXU in the pool's dtype; scores, softmax, sums and the
+    accumulator are float32 in VMEM. No query and no output of the latent's
+    width exists.
+
+    Returns [B, T, H, v] float32; rows past cur_lens are unspecified, and
+    what rows past cur_lens hold on the way in reaches no other row.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, t, hn, qd = q.shape
+    c, nv = wkv_b.shape[0], wkv_b.shape[2]
+    s, r = k_cache.shape[2], v_cache.shape[4]
+    n = qd - r
+    v = nv - n
+    mp = page_tables.shape[1]
+    if k_cache.shape[3] != 1 or (
+        lat_cur.shape[-1], rope_cur.shape[-1], wkv_b.shape[1]
+    ) != (c, r, hn) or chosen.shape != (b, t, mp * s):
+        raise ValueError(
+            "a plain latent piece takes a one-row cache, rows as it caches "
+            f"them and a key mask by position; got pools {k_cache.shape} / "
+            f"{v_cache.shape}, rows {lat_cur.shape} / {rope_cur.shape}, q "
+            f"{q.shape}, wkv_b {wkv_b.shape}, chosen {chosen.shape}"
+        )
+    g = math.gcd(hn, PLAIN_HEADS)
+    pb = min(PLAIN_BLOCK_PAGES, mp)
+    kb = pb * s
+    wide = max(kb, t)
+    # rows past `cur` may hold anything: as keys and values a zero weight
+    # does not silence a NaN, so they go in as zeros
+    live = (jnp.arange(t, dtype=jnp.int32)[None]
+            < cur_lens[:, None])[..., None]
+    lat_cur = jnp.where(live, lat_cur, 0).astype(k_cache.dtype)
+    rope_cur = jnp.where(live, rope_cur, 0).astype(v_cache.dtype)
+    # the piece's own keys: columns `hist` .. `hist + T` of the mask
+    own = jax.vmap(lambda m, h: jax.lax.dynamic_slice_in_dim(
+        jnp.pad(m, ((0, 0), (0, t))), h, t, axis=1))(chosen, hist_lens)
+    mh = jnp.pad(chosen.astype(jnp.int8),
+                 ((0, 0), (0, 0), (0, -(mp * s) % kb)))
+
+    def heads(width):
+        return pl.BlockSpec(
+            (1, t, g * width), lambda hi, bi, li, pt, hl, cl: (bi, 0, hi))
+
+    def piece(width):
+        return pl.BlockSpec(
+            (1, t, width), lambda hi, bi, li, pt, hl, cl: (bi, 0, 0))
+
+    out = pl.pallas_call(
+        functools.partial(
+            _plain_kernel, page_size=s, block_pages=pb, nope=n,
+            step=math.gcd(t, PLAIN_ROWS)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            # (a group's columns of `wkv_b` fetched once for all the rows)
+            grid=(hn // g, b),
+            in_specs=[
+                heads(qd),
+                pl.BlockSpec((c, g * nv),
+                             lambda hi, bi, li, pt, hl, cl: (0, hi)),
+                piece(c), piece(r), piece(t),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=heads(v),
+            scratch_shapes=[
+                pltpu.VMEM((2, kb, c), k_cache.dtype),
+                pltpu.VMEM((2, kb, r), v_cache.dtype),
+                pltpu.VMEM((2, t, kb), jnp.int8),
+                pltpu.VMEM((t, wide), jnp.float32),
+                pltpu.VMEM((wide, qd), k_cache.dtype),
+                pltpu.VMEM((g, t, 128), jnp.float32),
+                pltpu.VMEM((g, t, 128), jnp.float32),
+                pltpu.VMEM((g, t, v), jnp.float32),
+                pltpu.SemaphoreType.DMA((3, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, t, hn * v), jnp.float32),
+        interpret=interpret,
+        name="latent_plain_attention",
+        # at 512 queries, 8 heads of 128 | 128 | 128 and blocks of 1,024
+        # keys of a 512-wide latent: the cell's queries, weights and output
+        # twice (the pipeline's two buffers) 12 MB, the heads' running
+        # state 6, a turn's blocks, masks, bias and keys 7, a head's
+        # float32 keys | values 1 and a pass's scores and weights 0.5 each
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024
+        ),
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        page_tables.astype(jnp.int32),
+        hist_lens.astype(jnp.int32),
+        cur_lens.astype(jnp.int32),
+        q.reshape(b, t, hn * qd), wkv_b.reshape(c, hn * nv), lat_cur,
+        rope_cur, own.astype(jnp.int8), mh,
+        # a page as [S, C] rows: the same bytes
+        k_cache.reshape(*k_cache.shape[:3], c),
+        v_cache.reshape(*v_cache.shape[:3], r),
+    )
+    return out.reshape(b, t, hn, v)
 
 
 # ---------------------------------------------------------------------------

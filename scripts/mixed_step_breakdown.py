@@ -14,12 +14,17 @@ a latent model the kernel `latent_prefill_attention`, floor 1.25 ms at
 `docgen`'s mean history; for MiniCPM-SALA the kernel
 `sparse_chunk_attention` and the tile lists it is handed; for Keye-VL the
 kernel `token_chunk_attention` and the masks it is handed; for
-dots3-note-prev `latent_prefill_attention` under its masks in the full
-layers, beside `attn/window`, the sliding layers' ring attention).
+dots3-note-prev `latent_plain_attention` and the masks it is handed in the
+full layers (`latent_prefill_attention` there before PR 53), beside
+`attn/window`, the sliding layers' ring attention).
 Reads files only (run it after the benchmark's process has gone;
 `JAX_PLATFORMS=cpu` keeps it off the chip).
 
     JAX_PLATFORMS=cpu python scripts/mixed_step_breakdown.py [TRACE.xplane.pb]
+        [--ops attn/out,attn]
+
+`--ops` lists the operations of further scopes inside `jit_mixed_fn` by
+name, as `attn/flash`'s are listed.
 """
 
 from __future__ import annotations
@@ -63,7 +68,12 @@ def load_deepest(path: str) -> dict:
 def main(argv=None) -> int:
     from chipbench import hostspans
 
-    argv = sys.argv[1:] if argv is None else argv
+    argv = list(sys.argv[1:] if argv is None else argv)
+    scopes = ["attn/flash"]
+    if "--ops" in argv:
+        at = argv.index("--ops")
+        scopes += argv[at + 1].split(",")
+        del argv[at:at + 2]
     path = argv[0] if argv else hostspans.newest_xplane()
     if path is None:
         print("mixed_step_breakdown: no trace under the run directory",
@@ -84,17 +94,19 @@ def main(argv=None) -> int:
         }))
     for dev in loaded["devices"].values():  # one chip
         mixed = [m for m in dev["modules"] if m[0] == "jit_mixed_fn"]
-        by_name: dict = {}
-        for name, start, end, scope in dev["ops"]:
-            if scope == "attn/flash" and any(
-                    s <= start and end <= e for _n, s, e in mixed):
-                key = name.split(".")[0]
-                by_name[key] = by_name.get(key, 0.0) + end - start
-        print(json.dumps({"attn/flash inside jit_mixed_fn, ms a dispatch "
-                          "by operation (whole length, not self time)": {
-            k: round(1e3 * v / (len(mixed) or 1), 4)
-            for k, v in sorted(by_name.items(), key=lambda kv: -kv[1])[:12]
-        }}))
+        for listed in scopes:
+            by_name: dict = {}
+            for name, start, end, scope in dev["ops"]:
+                if scope == listed and any(
+                        s <= start and end <= e for _n, s, e in mixed):
+                    key = name.split(".")[0]
+                    by_name[key] = by_name.get(key, 0.0) + end - start
+            print(json.dumps({f"{listed} inside jit_mixed_fn, ms a dispatch "
+                              "by operation (whole length, not self time)": {
+                k: round(1e3 * v / (len(mixed) or 1), 4)
+                for k, v in sorted(
+                    by_name.items(), key=lambda kv: -kv[1])[:12]
+            }}))
         break
     print(json.dumps(
         {"latent_flash_ms_per_mixed_step": flash_ms_per_mixed_step(loaded)}))

@@ -441,6 +441,146 @@ def test_a_plain_piece_attends_exactly_its_window(first):
             np.testing.assert_allclose(o[j, h], want, atol=1e-6)
 
 
+# -- (b'') a full layer's prompt piece in the plain form ------------------------
+
+
+def _full_block(rng, cfg, firsts, t, decode_rows=0):
+    """A full layer's block on drawn inputs: (lp, the pools as the kernels
+    keep them, groups [the pieces from `firsts`, then `decode_rows` decode
+    rows], their joined rows x, the keys given to each group's queries)."""
+    geo = cfg.full_geo
+    normal = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.normal(size=shape), jnp.float32)
+    lp = {name: (jnp.ones(shape) if name.endswith("norm") else
+                 normal(*shape) / np.sqrt(shape[0])).astype(cfg.dtype)
+          for name, shape in dots3._stack_shapes(cfg)["full"].items()}
+    pages, mp = 80, 16
+    kv = (normal(2, pages, PAGE, 1, geo.kv_lora_rank).astype(cfg.dtype),
+          mla._pad_last(normal(2, pages, PAGE, 1, geo.qk_rope_head_dim),
+                        128).astype(cfg.dtype))
+    ki_pool = jnp.zeros((2, pages, PAGE, cfg.index_head_dim), cfg.dtype)
+    groups, n = [], mp * PAGE
+    for lo, width in (
+            [(list(firsts), t)] + [([50 + i for i in range(decode_rows)], 1)]
+            * bool(decode_rows)):
+        pos = jnp.asarray(lo, jnp.int32)[:, None] + jnp.arange(
+            width, dtype=jnp.int32)
+        first = 1 + len(groups) * 40
+        tables = jnp.asarray(
+            first + np.arange(len(lo) * mp).reshape(len(lo), mp) % 39,
+            jnp.int32)
+        groups.append(StepGroup(
+            jnp.zeros_like(pos), pos, jnp.ones(pos.shape, bool), tables))
+    x = normal(sum(g.positions.size for g in groups), cfg.hidden_size)
+    if len(groups) == 1:
+        x = x.reshape(*groups[0].positions.shape, -1)
+    return lp, kv, ki_pool, groups, x.astype(cfg.dtype), n
+
+
+def _given(monkeypatch, rng, share):
+    """The indexer's choice replaced by one key in `share` of a query's
+    context (its own token always), whatever the path scores."""
+    def chosen_keys(score, rows, n, positions, valid, topk):
+        at = jnp.arange(n, dtype=jnp.int32)[None, None]
+        drawn = jnp.asarray(
+            rng.integers(0, share, size=(*positions.shape, n)) == 0)
+        own = at == positions[..., None]
+        return (drawn | own) & (at <= positions[..., None]) & valid[..., None]
+
+    monkeypatch.setattr(dots3.keye, "chosen_keys", chosen_keys)
+
+
+@pytest.mark.parametrize("firsts,valid,share,gate", [
+    pytest.param((0,), 16, 2, True, id="no-history"),
+    pytest.param((36,), 16, 2, True, id="a-history-that-is-no-whole-block"),
+    pytest.param((40, 8), 16, 2, True,
+                 id="two-pieces-of-different-histories-in-one-group"),
+    pytest.param((20,), 11, 2, True, id="a-padded-tail-that-holds-nan"),
+    pytest.param((44,), 16, 1, True, id="every-key-chosen"),
+    pytest.param((44,), 16, 3, True, id="one-key-in-three-chosen"),
+    pytest.param((36,), 16, 2, False, id="without-the-head-gate"),
+])
+def test_a_full_layers_piece_in_the_plain_form_is_attention_under_the_mask(
+        monkeypatch, firsts, valid, share, gate):
+    """Under the kernels a full layer's prompt piece attends in the plain
+    form (`full_piece`: ops/flash_prefill.py `latent_plain_attention`,
+    interpreted, a block of 2 pages of 4 a turn and 8 queries a softmax
+    pass, so that a history is several turns), where the path without
+    kernels writes the rows first and attends the gathered cache absorbed
+    under the same keys (`mla._attend_xla(.., keep=chosen)`), both in
+    float32 at `dots3-tiny`'s widths: the same sums in another order, the
+    head gate on both. The piece's invalid tail holds NaN under the
+    kernels and reaches no valid row."""
+    from dynamo_tpu.ops import flash_prefill
+
+    t = 16
+    monkeypatch.setattr(flash_prefill, "PLAIN_BLOCK_PAGES", 2)
+    monkeypatch.setattr(flash_prefill, "PLAIN_ROWS", 8)
+    cfgs = {impl: dataclasses.replace(
+        dots3.Dots3Config.tiny(), attention_impl=impl, headwise_gate=gate)
+        for impl in ("xla", "pallas")}
+    rng = np.random.default_rng(sum(firsts) + valid + share)
+    lp, kv, ki_pool, (g,), x, _ = _full_block(rng, cfgs["pallas"], firsts, t)
+    g = g._replace(valid=jnp.broadcast_to(
+        jnp.arange(t) < valid, (len(firsts), t)))
+    outs = {}
+    for impl, cfg in cfgs.items():
+        _given(monkeypatch, np.random.default_rng(7), share)
+        pools = kv if impl == "pallas" else (
+            kv[0], kv[1][..., :cfg.qk_rope_head_dim])
+        rows = x.at[:, valid:].set(jnp.nan if impl == "pallas" else 0.0)
+        out, _, _, staged, counted = dots3.full_attention(
+            rows, lp, cfg, pools, ki_pool, jnp.int32(1), [g], [None])
+        outs[impl] = np.asarray(out)[:, :valid], np.asarray(counted)
+    np.testing.assert_allclose(outs["pallas"][0], outs["xla"][0], atol=2e-5)
+    np.testing.assert_array_equal(outs["pallas"][1], outs["xla"][1])
+    assert np.isfinite(outs["pallas"][0]).all()
+    assert np.abs(outs["xla"][0]).max() > 0.1
+
+
+@pytest.mark.parametrize("decode_rows", [
+    pytest.param(0, id="a-group-of-pieces-alone"),
+    pytest.param(3, id="beside-a-group-of-decode-rows"),
+])
+def test_a_plain_full_piece_is_the_absorbed_one_and_stages_the_same_rows(
+        monkeypatch, decode_rows):
+    """`full_attention` under the kernels in bfloat16: a group of T > 1
+    attends plain by the rule (`plain_full`), and absorbed where the rule
+    is set aside (the parent's path: `full_attend`, `absorbed_query` and
+    the value up-projection on all rows). The outputs agree within
+    bfloat16, a decode group's rows to the letter's worth of the shared
+    `wo`, and what each group stages for the landing after the layer
+    loops, the latent rows, the rope keys as cached and the index keys,
+    is the same to the letter, as is the count."""
+    cfg = dataclasses.replace(
+        dots3.Dots3Config.tiny(), attention_impl="pallas",
+        dtype=jnp.bfloat16)
+    rng = np.random.default_rng(decode_rows)
+    lp, kv, ki_pool, groups, x, _ = _full_block(
+        rng, cfg, (40, 8), 16, decode_rows)
+    got = {}
+    for form in ("rule", "absorbed"):
+        _given(monkeypatch, np.random.default_rng(7), 2)
+        if form == "absorbed":
+            monkeypatch.setattr(dots3, "plain_full", lambda t, cfg: False)
+        out, _, _, staged, counted = dots3.full_attention(
+            x, lp, cfg, kv, ki_pool, jnp.int32(0), groups,
+            [None] * len(groups))
+        got[form] = (np.asarray(out, np.float32), staged, np.asarray(counted))
+    (mine, staged, counted), (theirs, staged_a, counted_a) = (
+        got["rule"], got["absorbed"])
+    assert np.linalg.norm(mine - theirs) < 2e-2 * np.linalg.norm(theirs)
+    assert np.linalg.norm(mine - theirs) > 0  # another order of sums
+    np.testing.assert_array_equal(counted, counted_a)
+    assert len(staged) == len(groups)
+    for st, st_a in zip(staged, staged_a):
+        assert len(st) == len(st_a) == 3
+        for a, b in zip(st, st_a):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(
+                np.asarray(a, np.float32), np.asarray(b, np.float32))
+
+
 # -- (c) a rolled-back dispatch with ONE generation -----------------------------
 
 

@@ -905,7 +905,8 @@ def test_dots3_step_compiles_at_published_widths(topo, rows, t, b_pre):
     index scores read out of the one-row pool in place (64 heads), the
     sort-free selection, the decode rows' LATENT page walk under a bit a
     token (128 heads: in pieces that fit the kernel's VMEM budget), a
-    prompt chunk's latent kernel under a mask bit a (query, key), the
+    prompt piece's plain-form kernel over the latent pages under a mask
+    bit a (query, key), the
     window layers' ring written by position, walked by a decode row (and
     by a 32-token piece, absorbed) and read by page for a 512-token
     piece's plain-form banded kernel, the grouped matmuls over the 8 held experts: every pool updated in place, the
@@ -972,17 +973,19 @@ def test_dots3_step_compiles_at_published_widths(topo, rows, t, b_pre):
     text = compiled.as_text()
     # ONE body a kind of layer: the decode walk once a full layer's body
     # and once a window layer's (a decode row's ring walked as the latent
-    # pages it is); a prompt piece the absorbed chunk kernel in a full
-    # layer, and in a window layer too where it is short beside the ring
-    # rows in reach (32 tokens beside 576): a 512-token piece attends
-    # plain there, under the banded kernel
+    # pages it is); a prompt piece attends a full layer PLAIN, its keys and
+    # values up-projected inside `latent_plain_attention`; in a window layer
+    # a 512-token piece attends plain too, under the banded kernel, and a
+    # piece short beside the ring rows in reach (32 tokens beside 576)
+    # runs the absorbed chunk kernel, which no full layer does any more
     plain = bool(b_pre) and 4 * t >= 576
     print({k: _kernel_calls(text, k) for k in (
-        "latent_prefill_attention", "window_prefill_attention",
-        "paged_decode_attention", "paged_index_scores",
-        "paged_index_scores_chunk")})
-    assert _kernel_calls(text, "latent_prefill_attention") == (
-        0 if not b_pre else 1 if plain else 2)
+        "latent_prefill_attention", "latent_plain_attention",
+        "window_prefill_attention", "paged_decode_attention",
+        "paged_index_scores", "paged_index_scores_chunk")})
+    assert _kernel_calls(text, "latent_plain_attention") == int(bool(b_pre))
+    assert _kernel_calls(text, "latent_prefill_attention") == int(
+        bool(b_pre) and not plain)
     assert _kernel_calls(text, "window_prefill_attention") == int(plain)
     assert _kernel_calls(text, "paged_decode_attention") == 2
     assert _kernel_calls(text, "paged_index_scores") == 1
@@ -1020,6 +1023,32 @@ def test_window_band_kernel_compiles_at_published_widths(topo, b, t):
         _sds((b, kk, 64, 128), bf, chip), _sds((b, t), jnp.int32, chip),
         _sds((b, kk), jnp.int32, chip)).compile().as_text()
     assert _kernel_calls(text, "window_prefill_attention") == 1
+
+
+@pytest.mark.parametrize("b,t", [
+    pytest.param(4, 512, id="four-pieces-of-512"),
+    pytest.param(1, 32, id="a-32-token-tail"),
+])
+def test_latent_plain_kernel_compiles_at_published_widths(topo, b, t):
+    """The plain-form kernel of a dots3 full layer's prompt piece
+    (ops/flash_prefill.py `latent_plain_attention`) at the published 128
+    heads of 128 | 64 (in 128 lanes) | 128 over a 512-wide latent in bf16,
+    pages of 64 in a table of 288: a head's columns of the weights and of
+    the queries read at a dynamic lane offset, the int8 mask's block by a
+    strided DMA, inside VMEM."""
+    from dynamo_tpu.ops.flash_prefill import latent_plain_attention
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    bf, i32 = jnp.bfloat16, jnp.int32
+    pool = lambda width: _sds((3, 9000, PAGE, 1, width), bf, chip)  # noqa: E731
+    text = jax.jit(functools.partial(
+        latent_plain_attention, interpret=False)).lower(
+        _sds((b, t, 128, 256), bf, chip), _sds((512, 128, 256), bf, chip),
+        _sds((b, t, 512), bf, chip), _sds((b, t, 128), bf, chip),
+        pool(512), pool(128), _sds((), i32, chip), _sds((b, 288), i32, chip),
+        _sds((b,), i32, chip), _sds((b,), i32, chip),
+        _sds((b, t, 288 * PAGE), jnp.bool_, chip)).compile().as_text()
+    assert _kernel_calls(text, "latent_plain_attention") == 1
 
 
 @pytest.mark.parametrize("rows,vocab", [
