@@ -15,4 +15,10 @@ see SURVEY.md at the repo root for the structural map of the reference):
 - Load/SLA autoscaling planner (`dynamo_tpu.planner`)
 """
 
+import time as _time
+
+#: `time.perf_counter()` when the package was imported: the process's
+#: start where the OS does not say it (`platform.process_age_s`)
+IMPORTED_PERF_S = _time.perf_counter()
+
 __version__ = "0.1.0"

@@ -55,7 +55,6 @@ from dynamo_tpu.models.registry import ModelAdapter, get_model
 from dynamo_tpu.parallel.logical import default_rules
 from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 from dynamo_tpu.parallel.shardings import batch_spec, shardings_for
-from dynamo_tpu import telemetry
 from dynamo_tpu.telemetry import phases
 from dynamo_tpu.telemetry.flight import PHASES as _LOOP_PHASES, DryClock
 from dynamo_tpu.tokens import TokenBlockSequence
@@ -217,6 +216,35 @@ class EngineMetrics:
     #: churning (the compile hazard the 3-axis mixed family introduced)
     compiles: int = 0
     compile_ms: float = 0.0
+    #: compile_ms by part (`_FirstCall`: jax's own events inside each
+    #: first call): tracing, lowering, and XLA's compile or the
+    #: persistent cache's read; what compile_ms holds beyond them is the
+    #: programs' first runs. cache_requests counts the compiles whose
+    #: result the persistent cache holds afterwards (answered from it, or
+    #: written to it; one too quick for jax to keep is in neither),
+    #: cache_hits those it answered: equal in a warm start, hits 0 in a
+    #: cold one, between them a cache that lost entries
+    compile_trace_ms: float = 0.0
+    compile_lower_ms: float = 0.0
+    compile_backend_ms: float = 0.0
+    compile_cache_requests: int = 0
+    compile_cache_hits: int = 0
+    #: the boot, set once by the constructor (ms on the host's clock):
+    #: boot_before_ms from the process's start, as the OS has it, to the
+    #: constructor's entry (the interpreter, the imports, the backend's
+    #: start where the caller made it, the tokenizer); boot_ms the whole
+    #: constructor (span `engine.boot`), of it boot_weights_ms the
+    #: parameters loaded or drawn, quantized and placed
+    #: (`engine.boot.weights`) and boot_pools_ms the KV pool, the state
+    #: slots and a draft model's (`engine.boot.pools`), each closed by
+    #: block_until_ready; boot_end_perf_s is `time.perf_counter()` at the
+    #: constructor's exit, for a caller that lays its own clock against
+    #: the boot
+    boot_before_ms: float = 0.0
+    boot_ms: float = 0.0
+    boot_weights_ms: float = 0.0
+    boot_pools_ms: float = 0.0
+    boot_end_perf_s: float = 0.0
     #: page-pool pressure: the high-watermark of active pages since boot
     #: and the scheduler's preemption-by-recompute count — preemptions
     #: climbing while the watermark pins at capacity is the "pool too
@@ -383,6 +411,10 @@ class _Phase:
         """Span args known only once the phase has run."""
         self._span.set_metadata(**args)
 
+    def elapsed_ms(self) -> float:
+        """Host ms since the phase was entered."""
+        return (time.perf_counter() - self._t0) * 1000.0
+
     def launched(self, out) -> Optional[int]:
         """Inside `engine.launch`, right after the program call returned
         `out` (one device array of its outputs): the dispatch's `seq` on
@@ -403,7 +435,7 @@ class _Phase:
                 # the span's exit on the host's monotonic clock: one span
                 # gives the offset between that clock and the profiler's
                 self._span.set_metadata(t_host_ns=time.perf_counter_ns())
-        dt_ms = (time.perf_counter() - self._t0) * 1000.0
+        dt_ms = self.elapsed_ms()
         self._span.__exit__(*exc)
         m = self._metrics
         if m is not None:
@@ -424,6 +456,120 @@ def _seq_arg(seq: Optional[int]) -> dict:
     """`engine.readback`'s `seq` arg: the dispatch it reads (no arg
     without a dry clock)."""
     return {} if seq is None else {"seq": seq}
+
+
+#: jax's own events (jax.monitoring, 0.9.0) that split a first call
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+#: fired where jax WRITES an entry: a compile the cache did not hold and
+#: now does (one too quick to be kept, under
+#: `jax_persistent_cache_min_compile_time_secs`, fires neither)
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+#: the parts whose sums the compile table's rollup by kind carries
+_FIRST_CALL_PARTS = ("trace_ms", "lower_ms", "backend_ms")
+
+#: `.open` is the calling thread's first call in progress, if any
+_first_calls = threading.local()
+_listening = False
+_listening_lock = threading.Lock()
+
+
+class _FirstCall:
+    """What jax says of itself during ONE first call of a step program
+    (`JaxEngine._cache_jit`), on the thread that makes the call: ms of
+    tracing, of lowering and of XLA's compile or the persistent cache's
+    read, and whether the cache answered or was written to. Trace and
+    lowering events NEST (an inner `jax.jit` traced for the first time
+    inside the program fires its own, and the outermost, fired last,
+    holds them all; a lowering rule that traces a helper fires a trace
+    event inside the lowering's), so each is taken as the events' maximum
+    up to the compile that follows them, not their sum."""
+
+    __slots__ = ("trace_ms", "lower_ms", "backend_ms", "hits", "misses",
+                 "_trace", "_lower")
+
+    def __init__(self):
+        self.trace_ms = self.lower_ms = self.backend_ms = 0.0
+        self.hits = self.misses = 0
+        self._trace = self._lower = 0.0
+
+    def __enter__(self) -> "_FirstCall":
+        _first_calls.open = self  # a first call never holds another
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        _first_calls.open = None
+        self._fold()
+        return False
+
+    def _fold(self) -> None:
+        self.trace_ms += self._trace
+        self.lower_ms += self._lower
+        self._trace = self._lower = 0.0
+
+    def duration(self, event: str, ms: float) -> None:
+        if event == _TRACE_EVENT:
+            self._trace = max(self._trace, ms)
+        elif event == _LOWER_EVENT:
+            self._lower = max(self._lower, ms)
+        elif event == _BACKEND_EVENT:
+            self._fold()
+            self.backend_ms += ms
+
+    @property
+    def cache(self) -> str:
+        """"hit": the persistent cache answered; "miss": XLA compiled
+        and the cache keeps the result, so a later start hits; "off": the
+        cache was asked for nothing, or the compile was too quick for jax
+        to keep (such a program never hits)."""
+        if self.misses:
+            return "miss"
+        return "hit" if self.hits else "off"
+
+    def parts(self, whole_ms: float) -> dict:
+        """The first call by part; `run_ms` is the remainder of
+        `whole_ms`: the program's first run, its inputs' transfer and the
+        dispatch."""
+        known = self.trace_ms + self.lower_ms + self.backend_ms
+        return {
+            "trace_ms": round(self.trace_ms, 3),
+            "lower_ms": round(self.lower_ms, 3),
+            "backend_ms": round(self.backend_ms, 3),
+            "run_ms": round(whole_ms - known, 3),
+            "cache": self.cache,
+        }
+
+
+def _on_jax_duration(event: str, duration_secs: float, **_kw) -> None:
+    call = getattr(_first_calls, "open", None)
+    if call is not None:
+        call.duration(event, duration_secs * 1000.0)
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    call = getattr(_first_calls, "open", None)
+    if call is None:
+        return
+    if event == _CACHE_HIT_EVENT:
+        call.hits += 1
+    elif event == _CACHE_MISS_EVENT:
+        call.misses += 1
+
+
+def _listen_to_jax() -> None:
+    """Register the process's ONE pair of jax.monitoring listeners (the
+    first engine built does): each returns at its first test unless the
+    calling thread is inside a first call."""
+    global _listening
+    with _listening_lock:
+        if not _listening:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+            jax.monitoring.register_event_listener(_on_jax_event)
+            _listening = True
 
 
 def _pages_only(method):
@@ -515,6 +661,26 @@ class JaxEngine:
         checkpoint_path: Optional[str] = None,
         on_tier_event=None,
     ):
+        from dynamo_tpu.platform import process_age_s
+
+        # the boot, timed from inside (docs/observability.md "Reading a
+        # slow start"): what the process spent before this line, the whole
+        # constructor, and inside it the weights and the pools
+        self.metrics = EngineMetrics(boot_before_ms=process_age_s() * 1e3)
+        with phase(self.metrics, "engine.boot", "boot_ms"):
+            self._boot(
+                config, params, mesh_config, on_kv_event, checkpoint_path,
+                on_tier_event,
+            )
+        #: on `time.perf_counter()`, the clock a caller stamps its own
+        #: start of serving with
+        self.metrics.boot_end_perf_s = time.perf_counter()
+
+    def _boot(
+        self, config, params, mesh_config, on_kv_event, checkpoint_path,
+        on_tier_event,
+    ) -> None:
+        """The constructor's body, under the `engine.boot` span."""
         from dynamo_tpu.platform import (
             enable_persistent_compile_cache,
             require_platform,
@@ -525,6 +691,7 @@ class JaxEngine:
         # into a CPU engine (jax's own fallback)
         require_platform()
         enable_persistent_compile_cache()
+        _listen_to_jax()
         self.config = config
         mc = mesh_config or MeshConfig(
             dp=config.dp, tp=config.tp, sp=config.sp, ep=config.ep
@@ -652,10 +819,8 @@ class JaxEngine:
                 state_slots=self._state_slots,
             )
         self.scheduler = Scheduler(config, self.allocator)
-        self.metrics = EngineMetrics(
-            kv_total_pages=config.num_pages - 1,
-            state_slots=self._state_slots,
-        )
+        self.metrics.kv_total_pages = config.num_pages - 1
+        self.metrics.state_slots = self._state_slots
         #: mid-decode deadline expiries, bumped by the runner (its abort
         #: path) — folded with the scheduler's pre-admission drops into
         #: metrics.deadline_expired
@@ -769,55 +934,35 @@ class JaxEngine:
         #: only, which is exactly what mixed steps collapse.
         self._last_emit: dict[str, tuple[float, int]] = {}
 
-        pre_quantized = False
-        if params is None:
-            checkpoint_path = checkpoint_path or self.adapter.default_checkpoint
-            if checkpoint_path is not None and self.adapter.load_params:
-                params = self.adapter.load_params(checkpoint_path)
-            elif (
-                config.quantize == "int8"
-                and self.adapter.init_params_quantized is not None
-            ):
-                # straight into int8 layout: init+quantize would peak at
-                # full-dtype model size (16GB for 8B — over v5e HBM)
-                logger.info(
-                    "initializing random int8 params for %s", config.model
-                )
-                params = self.adapter.init_params_quantized(jax.random.key(0))
-                pre_quantized = True
-            else:
-                logger.info("initializing random params for %s", config.model)
-                params = self.adapter.init_params(jax.random.key(0))
-        if config.quantize and not pre_quantized:
-            if config.quantize != "int8":
-                raise ValueError(
-                    f"unsupported quantize={config.quantize!r}; use int8"
-                )
-            if self.adapter.quantize_params is None:
-                raise ValueError(
-                    f"--quantize int8: the {config.model!r} adapter has no "
-                    "quantized layout (Llama-family models support it)"
-                )
-            params = self.adapter.quantize_params(params)
-        kv = self.adapter.init_kv(
-            config.num_pages, config.page_size,
-            kv_quantize=config.kv_quantize,
-            **({"state_slots": self._state_slots} if self._stateful else {}),
-        )
-        if self.mesh is not None:
-            specs = self.adapter.param_specs(quantized=bool(config.quantize))
-            params = self._put_global(params, shardings_for(self.mesh, specs))
-            kv = self._put_global(
-                kv,
-                shardings_for(
-                    self.mesh,
-                    self.adapter.kv_spec(kv_quantize=config.kv_quantize),
-                ),
+        with phase(self.metrics, "engine.boot.weights", "boot_weights_ms"):
+            params = self._boot_params(config, params, checkpoint_path)
+            if self.mesh is not None:
+                specs = self.adapter.param_specs(
+                    quantized=bool(config.quantize))
+                params = self._put_global(
+                    params, shardings_for(self.mesh, specs))
+            # the phase's time is its own, not the next one's
+            jax.block_until_ready(params)
+        with phase(self.metrics, "engine.boot.pools", "boot_pools_ms"):
+            kv = self.adapter.init_kv(
+                config.num_pages, config.page_size,
+                kv_quantize=config.kv_quantize,
+                **({"state_slots": self._state_slots}
+                   if self._stateful else {}),
             )
-        self.params = params
-        self.kv = kv
-        if self._spec_draft:
-            self._init_draft_model(config, impl)
+            if self.mesh is not None:
+                kv = self._put_global(
+                    kv,
+                    shardings_for(
+                        self.mesh,
+                        self.adapter.kv_spec(kv_quantize=config.kv_quantize),
+                    ),
+                )
+            self.params = params
+            self.kv = kv
+            if self._spec_draft:
+                self._init_draft_model(config, impl)
+            jax.block_until_ready((kv, self.draft_kv))
         # KV-pool byte gauges: actual device bytes (quantized pages +
         # scale planes) vs what the same pool costs at the model dtype —
         # the ~2x effective-capacity claim, measured not asserted.
@@ -857,6 +1002,42 @@ class JaxEngine:
             }
         else:
             self._batch_shardings = None
+
+    def _boot_params(self, config: EngineConfig, params, checkpoint_path):
+        """The model's parameter tree as the engine serves it: the
+        caller's, a checkpoint's, or random ones; quantized where the
+        configuration says so."""
+        pre_quantized = False
+        if params is None:
+            checkpoint_path = checkpoint_path or self.adapter.default_checkpoint
+            if checkpoint_path is not None and self.adapter.load_params:
+                params = self.adapter.load_params(checkpoint_path)
+            elif (
+                config.quantize == "int8"
+                and self.adapter.init_params_quantized is not None
+            ):
+                # straight into int8 layout: init+quantize would peak at
+                # full-dtype model size (16GB for 8B — over v5e HBM)
+                logger.info(
+                    "initializing random int8 params for %s", config.model
+                )
+                params = self.adapter.init_params_quantized(jax.random.key(0))
+                pre_quantized = True
+            else:
+                logger.info("initializing random params for %s", config.model)
+                params = self.adapter.init_params(jax.random.key(0))
+        if config.quantize and not pre_quantized:
+            if config.quantize != "int8":
+                raise ValueError(
+                    f"unsupported quantize={config.quantize!r}; use int8"
+                )
+            if self.adapter.quantize_params is None:
+                raise ValueError(
+                    f"--quantize int8: the {config.model!r} adapter has no "
+                    "quantized layout (Llama-family models support it)"
+                )
+            params = self.adapter.quantize_params(params)
+        return params
 
     @staticmethod
     def _state_pools(kv) -> tuple:
@@ -3083,23 +3264,29 @@ class JaxEngine:
         """Install a jitted program into the cache wrapped so its FIRST
         invocation — where jax traces and lowers it and XLA compiles —
         is counted, timed (dynamo_tpu_phase_compile_ms; wall time of
-        that plus the first run), spanned in the trace ring and listed
-        in self.programs for GET /v1/debug/programs. The wrapper
-        replaces itself with the bare jitted fn after that one call, so
-        the steady-state dispatch path pays nothing."""
+        that plus the first run), split into its parts by jax's own
+        events (`_FirstCall`) and listed in self.programs for GET
+        /v1/debug/programs. The wrapper replaces itself with the bare
+        jitted fn after that one call, so the steady-state dispatch path
+        pays nothing."""
 
         def first_call(*args, **kwargs):
-            ms0 = self.metrics.compile_ms
+            m = self.metrics
+            ms0 = m.compile_ms
             with phase(
-                self.metrics, "engine.compile", "compile_ms",
-                key=str(cache_key),
-            ), telemetry.span(
-                "engine.compile", service="engine",
-                attrs={"kind": kind, "key": str(cache_key)},
-            ):
-                out = jitted(*args, **kwargs)
-            dt_ms = self.metrics.compile_ms - ms0
-            self.metrics.compiles += 1
+                m, "engine.compile", "compile_ms", key=str(cache_key),
+            ) as span:
+                with _FirstCall() as call:
+                    out = jitted(*args, **kwargs)
+                parts = call.parts(span.elapsed_ms())
+                span.note(**parts)
+            dt_ms = m.compile_ms - ms0
+            m.compiles += 1
+            m.compile_trace_ms += call.trace_ms
+            m.compile_lower_ms += call.lower_ms
+            m.compile_backend_ms += call.backend_ms
+            m.compile_cache_requests += call.hits + call.misses
+            m.compile_cache_hits += call.hits
             self.compiles_by_kind[kind] = (
                 self.compiles_by_kind.get(kind, 0) + 1
             )
@@ -3109,6 +3296,7 @@ class JaxEngine:
                 "kind": kind,
                 "key": str(cache_key),
                 "compile_ms": round(dt_ms, 3),
+                **parts,
             }
             return out
 
@@ -4362,11 +4550,18 @@ class JaxEngine:
             k = kinds.setdefault(
                 p["kind"],
                 {"programs": 0, "compile_ms": 0.0,
+                 **dict.fromkeys(_FIRST_CALL_PARTS, 0.0), "cache_hits": 0,
                  "compiles": self.compiles_by_kind.get(p["kind"], 0)},
             )
             k["programs"] += 1
-            k["compile_ms"] = round(k["compile_ms"] + p["compile_ms"], 3)
-        return {"programs": programs, "kinds": kinds}
+            for part in ("compile_ms", *_FIRST_CALL_PARTS):
+                k[part] = round(k[part] + p[part], 3)
+            k["cache_hits"] += p["cache"] == "hit"
+        boot = {
+            part: round(getattr(self.metrics, f"boot_{part}"), 3)
+            for part in ("before_ms", "weights_ms", "pools_ms", "ms")
+        }
+        return {"programs": programs, "kinds": kinds, "boot": boot}
 
     def programs_wire(self) -> dict:
         """The compact per-kind rollup that rides the metrics frame."""

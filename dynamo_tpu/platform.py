@@ -10,7 +10,10 @@ the chip call `require_platform()` so that fallback is an error.
 from __future__ import annotations
 
 import os
+import time
 from pathlib import Path
+
+import dynamo_tpu
 
 #: compile cache used when JAX_COMPILATION_CACHE_DIR is unset: one fixed
 #: directory inside the checkout (the path is part of the cache key, so
@@ -46,6 +49,23 @@ def require_platform() -> str:
             f"no TPU attached (jax found {found!r}). {hint}"
         )
     return "tpu"
+
+
+def process_age_s() -> float:
+    """Seconds since this process started, as the OS has it: on Linux
+    `/proc/self/stat`'s starttime (clock ticks after boot) against
+    CLOCK_BOOTTIME. Where that cannot be read, or reads younger than the
+    package's own import, seconds since `dynamo_tpu` was imported."""
+    imported_s = time.perf_counter() - dynamo_tpu.IMPORTED_PERF_S
+    try:
+        with open("/proc/self/stat") as f:
+            # the fields after the command's name, which may hold spaces
+            fields = f.read().rsplit(")", 1)[1].split()
+        started_s = int(fields[19]) / os.sysconf("SC_CLK_TCK")
+        age_s = time.clock_gettime(time.CLOCK_BOOTTIME) - started_s
+    except (OSError, ValueError, IndexError, AttributeError):
+        return imported_s
+    return max(age_s, imported_s)
 
 
 def enable_persistent_compile_cache() -> str:

@@ -31,13 +31,17 @@ def engine():
 def test_programs_report_has_cost_model_attainment(engine):
     """GET /v1/debug/programs is the compile table (the name predates PR
     47, which took the cost model out): every program a REAL engine
-    loaded is listed once with its kind, key and first-call ms, the
-    kinds table counts them, and the wire is the kinds table."""
+    loaded is listed once with its kind, key, first-call ms and that
+    call's parts (tests/test_setup_timeline.py), the kinds table counts
+    them, the wire is the kinds table, and `boot` is the constructor by
+    phase."""
     rep = engine.programs_report()
-    assert set(rep) == {"programs", "kinds"}
+    assert set(rep) == {"programs", "kinds", "boot"}
+    assert set(rep["boot"]) == {"before_ms", "weights_ms", "pools_ms", "ms"}
     assert rep["programs"], "compiled programs must be recorded"
     for p in rep["programs"]:
-        assert set(p) == {"kind", "key", "compile_ms"}, p
+        assert set(p) == {"kind", "key", "compile_ms", "trace_ms",
+                          "lower_ms", "backend_ms", "run_ms", "cache"}, p
         assert p["compile_ms"] > 0
     keys = [p["key"] for p in rep["programs"]]
     assert sorted(keys) == sorted(str(k) for k in engine.programs)
@@ -47,7 +51,8 @@ def test_programs_report_has_cost_model_attainment(engine):
     assert "decode_multi" in kinds or "decode" in kinds
     for kind, k in kinds.items():
         mine = [p for p in rep["programs"] if p["kind"] == kind]
-        assert set(k) == {"programs", "compiles", "compile_ms"}, k
+        assert set(k) == {"programs", "compiles", "compile_ms", "trace_ms",
+                          "lower_ms", "backend_ms", "cache_hits"}, k
         assert k["programs"] == len(mine) >= 1
         assert k["compiles"] == engine.compiles_by_kind[kind] >= 1
         assert k["compile_ms"] == pytest.approx(
@@ -94,9 +99,13 @@ def test_debug_endpoints_over_frontend_http(engine):
                 assert engine.debug_name in doc["engines"]
                 kinds = doc["engines"][engine.debug_name]["kinds"]
                 assert kinds and all(
-                    set(k) == {"programs", "compiles", "compile_ms"}
+                    set(k) == {"programs", "compiles", "compile_ms",
+                               "trace_ms", "lower_ms", "backend_ms",
+                               "cache_hits"}
                     for k in kinds.values()
                 )
+                boot = doc["engines"][engine.debug_name]["boot"]
+                assert boot["ms"] >= boot["weights_ms"] > 0
                 async with s.get(f"{base}/v1/debug/flight?n=4") as r:
                     assert r.status == 200
                     doc = await r.json()

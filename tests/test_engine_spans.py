@@ -26,7 +26,8 @@ def make_engine(**overrides) -> JaxEngine:
 
 def engine_spans(trace_dir) -> list[dict]:
     """The `engine.*` events of a capture, in start order, of the host
-    thread that ran the loop — found by its events, not by a thread id."""
+    thread that ran the loop (or built the engine: a capture may begin
+    before the boot) — found by its events, not by a thread id."""
     from jax.profiler import ProfileData
 
     path = sorted(glob.glob(
@@ -43,7 +44,7 @@ def engine_spans(trace_dir) -> list[dict]:
                  **{k: v for k, v in e.stats}}
                 for e in line.events if e.name.startswith("engine.")
             ]
-            if any(e["name"] == "engine.step" for e in evs):
+            if any(e["name"] in ("engine.step", "engine.boot") for e in evs):
                 out += evs
     return sorted(out, key=lambda e: (e["start"], -e["end"]))
 

@@ -199,7 +199,9 @@ def test_fleet_snapshot_e2e():
                     pdoc = await r.json()
                 for iid, pw in pdoc["workers"].items():
                     assert pw["kinds"] and all(
-                        set(k) == {"programs", "compiles", "compile_ms"}
+                        set(k) == {"programs", "compiles", "compile_ms",
+                                   "trace_ms", "lower_ms", "backend_ms",
+                                   "cache_hits"}
                         for k in pw["kinds"].values()
                     ), (iid, pw)
                 role = snap["roles"]["decode"]
