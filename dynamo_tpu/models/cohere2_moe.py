@@ -107,13 +107,17 @@ STATE_IN_PLACE = True
 EXPERTS = ("we_gate", "we_up", "we_down")
 #: the router's draw, times 1 / sqrt(hidden): logits of standard deviation 2
 ROUTER_SPREAD = 2.0
-#: VMEM the ring's decode walk plans for, models/llama.py's own for its
-#: pages: at 32 rows x 128 heads it leaves ONE page a block, 512 key
-#: columns a turn. Under 48 MiB (the walk's bits raise the kernel's limit
-#: to 64) a block is 4 pages, and its [128, 2048] float32 temporaries took
-#: the TPU's compiler 165 s a step program where this takes 12 (the
-#: compile for the described v5e, PR 52)
-_WALK_VMEM_BUDGET = 12 << 20
+#: VMEM both decode walks plan for (ops/paged_attention.py `_block_pages`),
+#: the ring's and the full layer's: at 32 rows x 128 heads the kernel's
+#: estimate counts 10 MiB of whole-batch q, accumulator and m|l blocks, and
+#: models/llama.py's 12 left TWO pages a block where the rule wants 4 (1 MiB
+#: of K and V a turn: 13 MiB by the estimate; the compile for the described
+#: v5e fits it under a 7 MiB limit). One page a block ran at 52 % of the
+#: HBM floor, two at 67, four at 77-79 (PR 55, scripts/paged_decode_bench.py)
+#: since a block is folded in one-page sub-tiles: the [128, 2048] float32
+#: temporaries that took the TPU's compiler 165 s a step program (PR 52) are
+#: gone, a step program compiles in what one page a block took
+_WALK_VMEM_BUDGET = 16 << 20
 
 
 @dataclass(frozen=True)
@@ -780,7 +784,8 @@ def forward_groups(params: dict, cfg: Cohere2MoeConfig, groups,
                     *o.shape[:2], hq * d)
             o, kv, staged_f[i] = attention_block(
                 q, k, v, kv, p, g.page_tables, g.positions, g.valid,
-                full_geo, decode_work=works[i])
+                full_geo, decode_work=works[i],
+                decode_vmem_budget=_WALK_VMEM_BUDGET)
             return o
 
         h, n = block(h, p * (n_s + 1) + n_s, attend)

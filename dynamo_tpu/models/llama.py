@@ -1526,6 +1526,7 @@ def attention_block(
     decode_work=None,  # precomputed ops.paged_attention.decode_work_list
     rope_positions=None,  # [3,B,T] m-RoPE streams; None = positions
     sinks=None,  # [Hq] GPT-OSS per-head sink logits
+    decode_vmem_budget: int = _PALLAS_DECODE_VMEM_BUDGET,
 ):
     """rope → paged attention, in one of two write disciplines:
 
@@ -1545,6 +1546,9 @@ def attention_block(
     Returns (attn [B,T,Hq*head_dim], kv, staged) where staged is None
     (xla) or ([B,T,Hkv,Dpad], [B,T,Hkv,Dpad]).
     Handles the cache's lane padding (cfg.kv_head_dim) transparently.
+    `decode_vmem_budget`: what the decode walk may plan for and past which
+    a decode step takes the XLA gather; a family whose shapes are known to
+    fit the chip under another number says so (models/cohere2_moe.py).
     """
     b, t = q.shape[0], q.shape[1]
     rp = positions if rope_positions is None else rope_positions
@@ -1685,11 +1689,11 @@ def attention_block(
     kernel_vmem = decode_vmem_bytes(
         b, cfg.num_heads // tp, cfg.kv_head_dim, kv.k.shape[2],
         cfg.num_kv_heads // tp or 1, jnp.dtype(kv.k.dtype).itemsize,
-        quantized=kv.quantized, budget=_PALLAS_DECODE_VMEM_BUDGET,
+        quantized=kv.quantized, budget=decode_vmem_budget,
     )
     if t == 1 and (
         (cfg.attention_impl == "hybrid" and b > cfg.pallas_decode_max_batch)
-        or kernel_vmem > _PALLAS_DECODE_VMEM_BUDGET
+        or kernel_vmem > decode_vmem_budget
     ):
         # Two routes to the dense gather: (a) hybrid's large-batch policy
         # (the gather reads ~the same HBM bytes in a handful of fused XLA
@@ -1697,7 +1701,7 @@ def attention_block(
         # overflow — route instead of letting Mosaic fail allocation.
         if (
             cfg.attention_impl == "pallas"
-            and kernel_vmem > _PALLAS_DECODE_VMEM_BUDGET
+            and kernel_vmem > decode_vmem_budget
             and (key := (b, cfg.num_heads // tp, kv.k.shape[2]))
             not in _warned_vmem_reroute
         ):
@@ -1711,7 +1715,7 @@ def attention_block(
                 "decode kernel needs ~%.1f MiB VMEM (budget %.0f MiB) at "
                 "b=%d heads=%d S=%d — shrink batch, page size, or "
                 "heads-per-chip (tp) to keep the Pallas path",
-                kernel_vmem / 2**20, _PALLAS_DECODE_VMEM_BUDGET / 2**20,
+                kernel_vmem / 2**20, decode_vmem_budget / 2**20,
                 b, cfg.num_heads // tp, kv.k.shape[2],
             )
         with jax.named_scope("paged"):
@@ -1729,7 +1733,7 @@ def attention_block(
                 qd, kv.k, kv.v, layer, page_tables, hist,
                 scale_dim=cfg.head_dim, mesh=mesh, work_list=decode_work,
                 k_scale=kv.k_scale, v_scale=kv.v_scale,
-                vmem_budget=_PALLAS_DECODE_VMEM_BUDGET,
+                vmem_budget=decode_vmem_budget,
             )  # acc [B,Hq,Dpad] unnormalized, m/l [B,Hq]
             # Exact merge of the current (unwritten) token: self-attention
             # score s = q·k_cur/√d folded into the flash running state.

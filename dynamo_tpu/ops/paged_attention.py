@@ -12,9 +12,10 @@ One kernel invocation (grid=(1,)) walks every row of the batch back to
 back, so the DMA pipeline stays full across rows. A turn of the walk is a
 (row, BLOCK of consecutive page ordinals): up to `_block_pages` pages are
 DMA'd into one slot (only the pages that exist; a short last block masks
-its tail like a partly filled page), scored in one dot and folded in with
-one softmax update. A row's blocks are adjacent, so its running
-(m, l, acc) stays in registers and is written once.
+its tail like a partly filled page) and folded in a SUB-TILE of whole pages
+at a time (`_tile_pages`; for most shapes the whole block): scored in one
+dot and folded in with one softmax update. A row's blocks are adjacent, so
+its running (m, l, acc) stays in registers and is written once.
 
 Why blocks, measured on a v5e at qwen2-7b's decode shape (B 64, 28/4 heads
 of 128, S 64, bf16, histories 256-1400, 110 MB of K/V a layer; PR 25,
@@ -29,6 +30,25 @@ pages a block: 172 us as it is, 156 us DMA only, 89 us arithmetic only —
 bound by its copies, at 78 % of the 134 us the chip needs to read the bytes
 at 819 GB/s (phi3-mini's and llama3-8b's shapes: 86 and 89 %). 4 pages a
 block gave 197 us, 2 gave 267; a third slot gave nothing.
+
+Why sub-tiles, measured at Command A+'s shape (B 32, 128/8 heads of 128,
+S 64, bf16: a page is 256 KiB of K+V and a [128, 512] f32 score tile;
+histories 8,200-18,000, 1.75 GB a layer; PR 55, the same script, its
+`--dma-only` / `--arith-only`): at ONE page a block, which is what the
+budget left when the temporaries were reckoned a block wide, 4,095 us as
+it is, 2,620 DMA only, 2,742 arithmetic only, against a floor of 2,139:
+neither half is slow, they ADD. One 256 KiB copy in flight does not hide
+its own latency, and the score product, the softmax chain and the value
+product of the one tile hang on each other. 4 pages as ONE [128, 2048]
+tile cost the TPU's compiler 165 s a step program (PR 52). 4 pages folded
+a page at a time: 2,696 us as it is (79 % of the floor; 2 pages 3,189, 8
+pages 2,411), 2,327 DMA only, 2,082 arithmetic only: the body under the
+floor, because one sub-tile's products run beside another's chain. The
+same walk of a ring under a bit a row (65 pages a row): 1,274 -> 871 us.
+A sub-tile is as many whole pages as keep [Hq padded, columns] within
+`_MAX_TILE_SCORES` (65,536: 32 heads x a 2,048-column block, 128 heads x
+one 512-column page) and divide the block, so at 32 or fewer query heads
+the block is one sub-tile and the program is what it was.
 
 Cache layout is [L, P, S, Hkv, D] (models/llama.py KVPages): one (layer,
 page) slice is a contiguous [S, Hkv, D] block, so a single DMA per page
@@ -47,9 +67,12 @@ slices must be 128-aligned in the minor dimension.
 The block size follows from the shapes (`_block_pages`): as many pages as
 reach 1 MiB of K+V, at most 8, at most 2048 key columns, halved while
 `_footprint` would pass the caller's VMEM budget. `_footprint` is what
-`decode_vmem_bytes` reports: the whole-batch q, acc and m|l blocks (twice:
-the pipeline double-buffers them), two K and two V slots, the scale slots,
-and four [Hq, columns] 32-bit temporaries of a block.
+`decode_vmem_bytes` reports: the whole-batch q, acc and m|l blocks (counted
+twice, as a pipeline of more than one step would hold them; the compile
+for a described v5e fits 32 rows x 128 heads at 4 pages a block under a
+7 MiB limit, where this reads 13), two K and two V slots, the scale slots,
+and four [Hq, columns] 32-bit temporaries of a SUB-TILE. A walk that folds
+its block in several sub-tiles says so once a shape, at trace time.
 
 The kernel reads HISTORY ONLY (tokens already written to pages — the
 current token's KV is staged and written once per step by ops/kv_update).
@@ -66,6 +89,7 @@ so the kernel lives here.
 from __future__ import annotations
 
 import functools
+import logging
 import math
 
 import jax
@@ -81,12 +105,21 @@ _DEPTH = 2
 #: a slot only costs VMEM, and a row rarely has more pages to give
 _MAX_BLOCK_PAGES = 8
 _BLOCK_BYTES = 1 << 20
-#: key columns one block may spread over: the score tile [Hq, columns] and
-#: its softmax temporaries stay a few hundred KiB of f32
+#: key columns one block may spread over
 _MAX_BLOCK_COLUMNS = 2048
+#: scores folded at once, [Hq padded, columns] in f32: 32 heads over a whole
+#: block of 2048 columns, 128 heads over 512. A block with more is folded a
+#: sub-tile of whole pages at a time (`_tile_pages`)
+_MAX_TILE_SCORES = 1 << 16
 #: masked scores; finite so that a fully masked (padded) query row stays
 #: NaN-free
 _MASKED = -1e30
+#: scripts/paged_decode_bench.py's split of a turn, assigned before the
+#: trace and by nothing that serves: "copies" leaves a block's DMAs and
+#: drops its arithmetic, "body" the arithmetic on a resident slot
+_PROBE = None
+#: shapes whose walk in sub-tiles was already told
+_told_sub_tiles: set = set()
 
 
 def _round_up(x: int, m: int) -> int:
@@ -97,14 +130,16 @@ def _footprint(
     pb: int, b: int, hq: int, d: int, s: int, hkv: int, itemsize: int,
     quantized: bool, rope_dim: int = 0,
 ) -> int:
-    """VMEM bytes of one kernel call whose blocks hold `pb` pages: the
-    whole-batch q / acc / m+l blocks (double-buffered by the pipeline
-    although the grid has one step), `_DEPTH` K and V slots, the scale
-    slots of a quantized pool, and the block's live temporaries. A latent
-    cache (`rope_dim` > 0) has a `d`-wide page that is key and value at
-    once and a `rope_dim`-wide rope-key page beside it."""
+    """VMEM bytes one kernel call whose blocks hold `pb` pages is planned
+    to take: the whole-batch q / acc / m+l blocks, counted twice (what a
+    pipeline of several steps would hold; an upper bound at this grid of
+    one, module text), `_DEPTH` K and V slots, the scale slots of a
+    quantized pool, and the live temporaries of a sub-tile of the block. A
+    latent cache (`rope_dim` > 0) has a `d`-wide page that is key and value
+    at once and a `rope_dim`-wide rope-key page beside it."""
     hqp = _round_up(hq, 8)
     n = pb * s * hkv  # key columns of a block
+    tn = _tile_pages(pb, hq, s * hkv) * s * hkv  # and of a sub-tile of it
     sub = 32 // itemsize  # sublane tile of the cache dtype
     kv_width = d + rope_dim if rope_dim else 2 * d  # of one cached token
     whole_batch = 2 * b * _round_up(hqp, sub) * (d + rope_dim) * itemsize  # q
@@ -114,7 +149,7 @@ def _footprint(
         whole_batch += 2 * 2 * b * hqp * d * 4  # acc, and m|l in one block
     slots = _DEPTH * _round_up(n, sub) * kv_width * itemsize
     # limit, scores and p in 32 bits, p again for the MXU
-    temps = 4 * hqp * n * 4
+    temps = 4 * hqp * tn * 4
     if itemsize != 2:
         temps += n * kv_width * 4  # K and V of the slot cast for the MXU
     if quantized:
@@ -124,6 +159,17 @@ def _footprint(
         # its three-part operand and product, for K's and V's planes
         temps += 4 * 3 * pb * _round_up(hkv, 8) * s * hkv * 4
     return whole_batch + slots + temps
+
+
+def _tile_pages(pb: int, hq: int, rpp: int, lane_tiles: bool = False) -> int:
+    """Pages of a block of `pb` folded at once: the most that divide the
+    block and keep [Hq padded, columns] within `_MAX_TILE_SCORES`, never
+    under one page of `rpp` columns. `lane_tiles`: a sub-tile's columns are
+    whole lane tiles (its bits are read at that offset), else the block is
+    one tile."""
+    most = max(1, _MAX_TILE_SCORES // (_round_up(hq, 8) * rpp))
+    tile = max(t for t in range(1, pb + 1) if pb % t == 0 and t <= most)
+    return pb if lane_tiles and (tile * rpp) % 128 else tile
 
 
 def _block_pages(
@@ -180,6 +226,7 @@ def _decode_kernel(
     num_kv_heads: int,
     max_pages: int,  # MP — row stride of pt_ref
     block_pages: int,
+    tile_pages: int,  # pages of a block folded at once: they divide it
     quantized: bool,
     latent: bool,
     token_bits: bool = False,
@@ -203,6 +250,7 @@ def _decode_kernel(
     g = num_q_heads // hkv
     rpp = s * hkv  # cache rows (= key columns) of one page: r = slot*Hkv + h
     n = pb * rpp
+    tn = tile_pages * rpp  # key columns folded at once
     sub = _round_up(hkv, 8)  # scale rows a page takes in its slot
     # What the MXU is fed: a bf16 pool under bf16 queries goes in as it is
     # and narrow pools convert exactly; q/sqrt(d) and the softmax weights
@@ -231,13 +279,13 @@ def _decode_kernel(
         ks_scr[...] = jnp.zeros_like(ks_scr)
         vs_scr[...] = jnp.zeros_like(vs_scr)
 
-    # Column c of a block is cache row c: key position c // Hkv past the
-    # block's first, kv head c % Hkv. One dot scores every query head
+    # Column c of a sub-tile is cache row c of it: key position c // Hkv
+    # past its first, kv head c % Hkv. One dot scores every query head
     # against every column; `limit` keeps a head's own columns (position
     # where the heads match, else out of reach), so one compare against
     # the tokens left masks other heads and the tail together.
-    col = jax.lax.broadcasted_iota(jnp.int32, (hqp, n), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (hqp, n), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (hqp, tn), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (hqp, tn), 0)
     col_pos = jax.lax.div(col, hkv)
     col_head = col - col_pos * hkv
     own = (col_head * g <= row) & (row < (col_head + 1) * g)
@@ -305,7 +353,9 @@ def _decode_kernel(
         pages = -(-len_ref[b] // s)
         jax.lax.fori_loop(0, jnp.minimum(pb, pages - first), page_copies, 0)
 
-    @pl.when(n_rows > 0)
+    copies = _PROBE != "body"
+
+    @pl.when((n_rows > 0) if copies else False)
     def _():
         block_copies(rows_ref[0], 0, 0, lambda c: c.start())
 
@@ -323,7 +373,7 @@ def _decode_kernel(
             nslot = jax.lax.rem(it0 + kb + 1, _DEPTH)
             in_row = kb + 1 < n_blk
 
-            @pl.when(in_row | (ri + 1 < n_rows))
+            @pl.when((in_row | (ri + 1 < n_rows)) if copies else False)
             def _():
                 block_copies(
                     jnp.where(in_row, b, b_next),
@@ -331,42 +381,64 @@ def _decode_kernel(
                     nslot, lambda c: c.start(),
                 )
 
-            block_copies(b, kb, slot, lambda c: c.wait())
+            if copies:
+                block_copies(b, kb, slot, lambda c: c.wait())
+            if _PROBE == "copies":
+                return carry
 
-            k_blk = to_mxu(k_scr[slot])
-            scores = jax.lax.dot_general(
-                q[:, :d], k_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [HQP, N]
-            if latent:
-                # the page in k_scr is the latent: scored here, summed as
-                # the value below; v_scr holds the shared rope key
-                scores += jax.lax.dot_general(
-                    q[:, d:], to_mxu(v_scr[slot]), (((1,), (1,)), ((), ())),
+            # the block folded a sub-tile at a time, each its own score
+            # product, softmax update and value product: no temporary is
+            # wider than a sub-tile, and one's products run beside the
+            # chain of another
+            for t in range(pb // tile_pages):
+                cols = slice(None) if tn == n else slice(t * tn, (t + 1) * tn)
+                k_blk = to_mxu(k_scr[slot, cols])
+                scores = jax.lax.dot_general(
+                    q[:, :d], k_blk, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
-                )
-            if quantized:
-                scores = scores * column_scales(ks_scr[slot])
-            scores = jnp.where(limit < hist - kb * (pb * s), scores, _MASKED)
-            if token_bits:
-                # a row that attends CHOSEN tokens (ops/token_select.py):
-                # a block with none of them leaves sums of no meaning
-                # that the first chosen key's correction wipes
-                at = pl.multiple_of(kb * n, n)
-                keep = bits_ref[pl.ds(b, 1), pl.ds(at, n)]
-                scores = jnp.where(keep != 0, scores, _MASKED)
-            m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
-            p = jnp.exp(scores - m_new)
-            corr = jnp.exp(m - m_new)
-            l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
-            if quantized:
-                p = p * column_scales(vs_scr[slot])
-            pv = jax.lax.dot_general(
-                p.astype(mxu), k_blk if latent else to_mxu(v_scr[slot]),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [HQP, D]
-            return m_new, l_new, acc * corr + pv
+                )  # [HQP, TN]
+                if latent:
+                    # the page in k_scr is the latent: scored here, summed
+                    # as the value below; v_scr holds the shared rope key
+                    scores += jax.lax.dot_general(
+                        q[:, d:], to_mxu(v_scr[slot, cols]),
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+                if quantized:
+                    if t == 0:  # a block's scales expand at once
+                        k_scales = column_scales(ks_scr[slot])
+                    scores = scores * k_scales[:, cols]
+                # the sub-tile's first key position (`if t`: a block of
+                # one sub-tile traces the operations it always did)
+                first = kb * (pb * s)
+                if t:
+                    first = first + t * (tile_pages * s)
+                scores = jnp.where(limit < hist - first, scores, _MASKED)
+                if token_bits:
+                    # a row that attends CHOSEN tokens (ops/token_select.py):
+                    # a block with none of them leaves sums of no meaning
+                    # that the first chosen key's correction wipes
+                    at = kb * n + t * tn if t else kb * n
+                    at = pl.multiple_of(at, tn)
+                    keep = bits_ref[pl.ds(b, 1), pl.ds(at, tn)]
+                    scores = jnp.where(keep != 0, scores, _MASKED)
+                m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
+                p = jnp.exp(scores - m_new)
+                corr = jnp.exp(m - m_new)
+                l = l * corr + jnp.sum(p, axis=1, keepdims=True)
+                if quantized:
+                    if t == 0:
+                        v_scales = column_scales(vs_scr[slot])
+                    p = p * v_scales[:, cols]
+                pv = jax.lax.dot_general(
+                    p.astype(mxu),
+                    k_blk if latent else to_mxu(v_scr[slot, cols]),
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [HQP, D]
+                m, acc = m_new, acc * corr + pv
+            return m, l, acc
 
         # a row's running state stays in registers from block to block
         m, l, acc = jax.lax.fori_loop(
@@ -536,6 +608,16 @@ def paged_decode_attention(
         step = 128 // math.gcd(128, s * hkv)
         if pb >= step:
             pb = pb // step * step
+    tile = _tile_pages(pb, hq, s * hkv, token_bits is not None)
+    if tile < pb and (key := (b, hq, hkv, pb, tile)) not in _told_sub_tiles:
+        # static a program: said once a shape, at trace time
+        _told_sub_tiles.add(key)
+        logging.getLogger(__name__).info(
+            "paged_decode_attention: %d rows x %d / %d heads walk %d pages "
+            "a block in sub-tiles of %d key columns (~%.1f MiB VMEM)",
+            b, hq, hkv, pb, tile * s * hkv,
+            _footprint(pb, b, hq, d, s, hkv, itemsize, quantized,
+                       dv if latent else 0) / 2**20)
     hqp = _round_up(hq, 8)
     if hqp != hq:  # whole sublane tiles of query heads; the pad is masked
         q = jnp.pad(q, ((0, 0), (0, hqp - hq), (0, 0)))
@@ -602,6 +684,7 @@ def paged_decode_attention(
             num_kv_heads=hkv,
             max_pages=mp,
             block_pages=pb,
+            tile_pages=tile,
             quantized=quantized,
             latent=latent,
             token_bits=token_bits is not None,

@@ -55,8 +55,9 @@ def model_passes(preset, impl):
     res[f"{preset}/{impl}/mixed"] = sha(text)
 
 
-for preset in ("mla-tiny", "mla-tiny-moe", "keye-vl2-tiny",
-               "nemotron-h-tiny", "dots3-tiny"):
+for preset in ("tiny", "mla-tiny", "mla-tiny-moe", "keye-vl2-tiny",
+               "nemotron-h-tiny", "falcon-h1-tiny", "dots3-tiny",
+               "command-a-plus-tiny"):
     for impl in ("xla", "pallas"):
         try:
             model_passes(preset, impl)
@@ -138,6 +139,36 @@ def cell_kernels():
         S((bk, hq, d), bf), S((Lk, Pk, s, hkv, d), bf),
         S((Lk, Pk, s, hkv, d), bf), S((), i32), S((bk, mpk), i32),
         S((bk,), i32), S((bk, mpk * s), jnp.bool_)))
+    # the decode walk at every other cell's shape, under models/llama.py's
+    # budget: (rows, Hq, Hkv, pool dtype); the latent ones a chip's heads
+    budget = 12 << 20
+    for name, (rows, hq_, hkv_, dt) in {
+        "qwen2-longgen": (64, 28, 4, jnp.int8),
+        "phi3-chat-closed": (16, 32, 32, bf),
+        "nano3-chat-churn": (64, 32, 2, bf),
+        "falconh1-longdoc": (32, 20, 4, bf),
+        "keye-longctx/dense": (32, 32, 4, bf),
+        "command-a-plus": (32, 128, 8, bf),
+    }.items():
+        pool = S((4, 2000, s, hkv_, d), dt)
+        planes = [S((4, 2000, hkv_, 128), jnp.float32)] * 2 * (dt != bf)
+        res[f"walk/{name}"] = sha(tpu_text(
+            lambda q, k, v, layer, pt, hl, *sc, interpret: (
+                pa.paged_decode_attention(
+                    q, k, v, layer, pt, hl, interpret=interpret,
+                    vmem_budget=budget,
+                    **dict(zip(("k_scale", "v_scale"), sc)))),
+            S((rows, hq_, d), bf), pool, pool, S((), i32),
+            S((rows, 64), i32), S((rows,), i32), *planes))
+    for name, heads in {"dots3-longctx": 128, "dots3-longctx/64": 64}.items():
+        res[f"walk/{name}"] = sha(tpu_text(
+            lambda q, k, v, layer, pt, hl, bits, interpret: (
+                pa.paged_decode_attention(
+                    q, k, v, layer, pt, hl, interpret=interpret, latent=True,
+                    token_bits=bits, vmem_budget=48 << 20)),
+            S((bk, heads, c + r), bf), S((3, Pk, s, 1, c), bf),
+            S((3, Pk, s, 1, r), bf), S((), i32), S((bk, mpk), i32),
+            S((bk,), i32), S((bk, mpk * s), jnp.bool_)))
     # qwen2-longgen / phi3-chat-closed / falconh1-longdoc: the GQA chunk
     # kernels (qwen2-7b: 28 / 4 heads of 128, a 512-token chunk)
     hq, hkv = 28, 4

@@ -5,11 +5,15 @@ against a dense f32 reference.
 
     python scripts/paged_decode_bench.py [--shape NAME ...] [--impl FILE]
                      [--set NAME=VALUE ...] [--rows N] [--hist LO,HI]
+                     [--budget-mib N] [--dma-only | --arith-only]
 
 `--impl` times another file's `paged_decode_attention` (a copy of the
 parent commit's `ops/paged_attention.py`, an experiment) under the same
 inputs; `--set` assigns a module constant of it before tracing (block
-rule experiments). One JSON line per shape on stdout; refuses a backend
+rule experiments). `--dma-only` and `--arith-only` split a turn of the
+walk through the kernel's `_PROBE`: its DMAs issued and waited with no
+arithmetic, and its arithmetic on a resident slot with no DMA (times only,
+nothing to compare). One JSON line per shape on stdout; refuses a backend
 that is not a TPU unless `--rehearse` (tiny sizes, interpreted, never a
 number).
 """
@@ -47,10 +51,19 @@ SHAPES = {
     # 64 cached as 128 lanes), 16 heads, softmax scale 1/sqrt(128 + 64)
     "deepseek-v2-lite": dict(b=64, hq=16, hkv=1, d=512, rope=128,
                              scale_dim=192, hist=(2100, 5700), layers=8),
+    # command-a-plus-4l-16e as `cmdaplus-longctx` serves it, 128 / 8 heads:
+    # the full layer's pages, and a sliding layer's ring as pages (65 in
+    # reach of a decode row, the window's 4,095 keys named by a bit a row)
+    "command-a-plus-full": dict(b=32, hq=128, hkv=8, d=128,
+                                hist=(8200, 18000), layers=1),
+    "command-a-plus-ring": dict(b=32, hq=128, hkv=8, d=128,
+                                hist=(4160, 4160), layers=3, bits=4095),
 }
 REHEARSAL = dict(b=3, hq=8, hkv=2, d=128, hist=(1, 40), layers=2)
 REHEARSAL_LATENT = dict(b=3, hq=8, hkv=1, d=128, rope=128, scale_dim=24,
                         hist=(1, 40), layers=2)
+REHEARSAL_BITS = dict(b=3, hq=16, hkv=2, d=128, hist=(40, 40), layers=2,
+                      bits=29)
 PEAKS = json.loads((ROOT / "chipbench" / "peaks.json").read_text())
 
 
@@ -100,19 +113,26 @@ def make_case(shape: dict, seed: int, page: int):
         v = jax.random.normal(keys[1], (*pool[:4], rope), dtype)
     q = jax.random.normal(
         keys[4], (b, hq, d + rope), shape.get("qdtype", jnp.bfloat16))
+    bits = None
+    if shape.get("bits"):  # a run of that many cached tokens a row
+        at = np.arange(mp * page)[None]
+        first = rng.integers(0, hist - shape["bits"] + 1)[:, None]
+        bits = jnp.asarray((at >= first) & (at < first + shape["bits"]))
     return dict(
-        q=q, k=k, v=v, k_scale=scales[0], v_scale=scales[1],
+        q=q, k=k, v=v, k_scale=scales[0], v_scale=scales[1], bits=bits,
         pt=jnp.asarray(ids, jnp.int32), hist=jnp.asarray(hist, jnp.int32),
     )
 
 
-def walk(impl, scale_dim: int, interpret: bool, latent: bool = False):
+def walk(impl, scale_dim: int, interpret: bool, latent: bool = False,
+         budget: int | None = None):
     """All layers of the pool in one program, as a step program's layer
-    scan does: (acc, m, l) of every layer."""
+    scan does: (acc, m, l) of every layer. `budget`: the VMEM the caller
+    lets the block rule plan for."""
     import jax
     import jax.numpy as jnp
 
-    def fn(q, k, v, pt, hist, k_scale, v_scale):
+    def fn(q, k, v, pt, hist, k_scale, v_scale, bits):
         # the parent commit's work list also took the page size
         extra = (k.shape[2],) if "page_size" in inspect.signature(
             impl.decode_work_list).parameters else ()
@@ -123,6 +143,8 @@ def walk(impl, scale_dim: int, interpret: bool, latent: bool = False):
                 q, k, v, li, pt, hist, scale_dim=scale_dim,
                 work_list=work, k_scale=k_scale, v_scale=v_scale,
                 interpret=interpret, **({"latent": True} if latent else {}),
+                **({} if bits is None else {"token_bits": bits}),
+                **({"vmem_budget": budget} if budget else {}),
             )
 
         _, out = jax.lax.scan(
@@ -140,7 +162,7 @@ def reference(case: dict, layer: int, scale_dim: int):
     import jax.numpy as jnp
 
     @jax.jit
-    def ref(q, k, v, pt, hist, k_scale, v_scale):
+    def ref(q, k, v, pt, hist, k_scale, v_scale, bits):
         b, hq, _d = q.shape
         hkv, page = k.shape[3], k.shape[2]
 
@@ -161,6 +183,8 @@ def reference(case: dict, layer: int, scale_dim: int):
         ) / math.sqrt(scale_dim)
         mask = jnp.arange(kk.shape[1])[None, None, None, :] < hist[
             :, None, None, None]
+        if bits is not None:
+            mask &= bits[:, None, None, :]
         s = jnp.where(mask, s, -jnp.inf)
         m = jnp.max(s, axis=-1)
         p = jnp.exp(s - m[..., None])
@@ -170,7 +194,7 @@ def reference(case: dict, layer: int, scale_dim: int):
         return (out.reshape(b, hq, -1), m.reshape(b, hq), l.reshape(b, hq))
 
     return ref(case["q"], case["k"], case["v"], case["pt"], case["hist"],
-               case["k_scale"], case["v_scale"])
+               case["k_scale"], case["v_scale"], case["bits"])
 
 
 def kernel_seconds(trace_dir: str) -> tuple[float, int]:
@@ -188,7 +212,8 @@ def kernel_seconds(trace_dir: str) -> tuple[float, int]:
     return total, count
 
 
-def measure(impl, name: str, shape: dict, seed: int, rehearse: bool) -> dict:
+def measure(impl, name: str, shape: dict, seed: int, rehearse: bool,
+            budget: int | None = None) -> dict:
     import jax
     import numpy as np
 
@@ -196,16 +221,20 @@ def measure(impl, name: str, shape: dict, seed: int, rehearse: bool) -> dict:
     case = make_case(shape, seed, page)
     scale_dim = shape.get("scale_dim", shape["d"])
     rope = shape.get("rope", 0)
-    fn = walk(impl, scale_dim, interpret=rehearse, latent=bool(rope))
+    fn = walk(impl, scale_dim, interpret=rehearse, latent=bool(rope),
+              budget=budget)
     args = (case["q"], case["k"], case["v"], case["pt"], case["hist"],
-            case["k_scale"], case["v_scale"])
+            case["k_scale"], case["v_scale"], case["bits"])
     acc, m, l = (np.asarray(x[0]) for x in jax.block_until_ready(fn(*args)))
-    want, want_m, want_l = (
-        np.asarray(x) for x in reference(case, 0, scale_dim))
-    err = float(np.max(np.abs(acc / np.maximum(l, 1e-30)[..., None] - want)))
-    # the caller merges the current token by m and l themselves
-    err_m = float(np.max(np.abs(m - want_m)))
-    err_l = float(np.max(np.abs(l * np.exp(m - want_m) / want_l - 1.0)))
+    err = err_m = err_l = None  # half a turn computes nothing to compare
+    if getattr(impl, "_PROBE", None) is None:
+        want, want_m, want_l = (
+            np.asarray(x) for x in reference(case, 0, scale_dim))
+        err = float(np.max(np.abs(
+            acc / np.maximum(l, 1e-30)[..., None] - want)))
+        # the caller merges the current token by m and l themselves
+        err_m = float(np.max(np.abs(m - want_m)))
+        err_l = float(np.max(np.abs(l * np.exp(m - want_m) / want_l - 1.0)))
     itemsize = case["k"].dtype.itemsize
     live = int(np.asarray(case["hist"]).sum())
     kv_bytes = live * shape["hkv"] * itemsize * (
@@ -245,6 +274,14 @@ def main() -> int:
                     metavar="NAME=VALUE", help="module constant of the impl")
     ap.add_argument("--rows", type=int, help="another batch size")
     ap.add_argument("--hist", help="LO,HI: another range of histories")
+    ap.add_argument("--budget-mib", type=int, default=12,
+                    help="VMEM the block rule plans under, the models' own "
+                         "by default (models/llama.py); 0: none")
+    half = ap.add_mutually_exclusive_group()
+    half.add_argument("--dma-only", dest="probe", action="store_const",
+                      const="copies", help="a turn's DMAs, no arithmetic")
+    half.add_argument("--arith-only", dest="probe", action="store_const",
+                      const="body", help="its arithmetic on a resident slot")
     ap.add_argument("--label", default=None)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--rehearse", action="store_true")
@@ -261,8 +298,12 @@ def main() -> int:
         if not hasattr(impl, key):
             raise SystemExit(f"{ns.impl or 'paged_attention'} has no {key}")
         setattr(impl, key, int(value))
-    shapes = {"rehearsal": REHEARSAL,
-              "rehearsal-latent": REHEARSAL_LATENT} if ns.rehearse else {
+    if ns.probe:
+        if not hasattr(impl, "_PROBE"):
+            raise SystemExit(f"{ns.impl} has no _PROBE to split a turn by")
+        impl._PROBE = ns.probe
+    shapes = {"rehearsal": REHEARSAL, "rehearsal-latent": REHEARSAL_LATENT,
+              "rehearsal-bits": REHEARSAL_BITS} if ns.rehearse else {
         n: SHAPES[n] for n in (ns.shape or SHAPES)}
     failed = 0
     for name, shape in shapes.items():
@@ -271,11 +312,13 @@ def main() -> int:
         if ns.hist:
             shape = {**shape, "hist": tuple(map(int, ns.hist.split(",")))}
         try:
-            doc = measure(impl, name, shape, ns.seed, ns.rehearse)
+            doc = measure(impl, name, shape, ns.seed, ns.rehearse,
+                          ns.budget_mib << 20)
         except Exception as e:  # noqa: BLE001 — the other shapes still run
             doc = {"shape": name, "error": f"{type(e).__name__}: {e}"[:2000]}
             failed += 1
-        doc.update(impl=ns.label or ns.impl or "tree", set=ns.set)
+        doc.update(impl=ns.label or ns.impl or "tree", set=ns.set,
+                   probe=ns.probe, budget_mib=ns.budget_mib)
         print(json.dumps(doc), flush=True)
     return 1 if failed else 0
 

@@ -239,6 +239,124 @@ def test_kernel_at_block_boundaries(name, pages):
     _assert_matches_gather(shape, [max(h, 0) for h in hist], seed=3)
 
 
+#: shapes whose block is MORE than one sub-tile (`_tile_pages`): [Hq, a
+#: block's columns] passes `_MAX_TILE_SCORES`, so the block is folded a
+#: sub-tile of whole pages at a time. `scores`: that limit set for the test
+WIDE_SHAPES = {
+    # command-a-plus: 4 pages a block, a sub-tile one page of 512 columns
+    "gqa16_hkv8_128_heads": dict(
+        hq=128, hkv=8, d=128, s=64, dtype=jnp.bfloat16, pb=4, tp=1),
+    # half of it: 8 pages a block in two sub-tiles of four
+    "gqa16_hkv4_64_heads": dict(
+        hq=64, hkv=4, d=128, s=64, dtype=jnp.bfloat16, pb=8, tp=4),
+    # a tiny one forced onto sub-tiles of 128 columns: four pages of 32
+    "gqa8_hkv2_forced": dict(
+        hq=16, hkv=2, d=128, s=16, scores=16 * 128, pb=8, tp=4),
+    # and of 64 columns, which are no lane tile: under bits one sub-tile
+    "gqa8_hkv2_forced_narrow": dict(
+        hq=16, hkv=2, d=128, s=16, scores=16 * 64, pb=8, tp=2),
+}
+
+
+def _dense_reference(q, k, v, pt, hist, bits, scale_dim):
+    """Float64 attention of every row over its cached tokens (those `bits`
+    names, where given): (out [B, Hq, D], m [B, Hq], l [B, Hq]); a row with
+    nothing to attend is left NaN."""
+    q, k, v = (np.asarray(x, np.float64) for x in (q, k, v))
+    b, hq, d = q.shape
+    hkv = k.shape[2]
+    out = np.full((b, hq, d), np.nan)
+    m, l = np.full((b, hq), np.nan), np.full((b, hq), np.nan)
+    for r in range(b):
+        keep = np.arange(len(pt[r]) * k.shape[1]) < hist[r]
+        if bits is not None:
+            keep &= np.asarray(bits[r])[: len(keep)]
+        if not keep.any():
+            continue
+        kr = k[pt[r]].reshape(-1, hkv, d)[keep]  # [K, Hkv, D]
+        vr = v[pt[r]].reshape(-1, hkv, d)[keep]
+        for h in range(hq):
+            sc = kr[:, h // (hq // hkv)] @ q[r, h] / np.sqrt(scale_dim)
+            m[r, h] = sc.max()
+            w = np.exp(sc - m[r, h])
+            l[r, h] = w.sum()
+            out[r, h] = w @ vr[:, h // (hq // hkv)] / l[r, h]
+    return out, m, l
+
+
+def _wide_histories(kind, s, pb, tp):
+    """Histories (tokens a row) around a wide block's edges."""
+    blk, tile = pb * s, tp * s
+    return {
+        # the last block short by 1 .. pb-1 pages, each beside a whole one
+        "short-last-block": [
+            2 * blk - short * s for short in range(1, pb)] + [2 * blk],
+        # a last page part filled: one token, half a page, all but one
+        "part-filled-last-page": [
+            blk + tile + 1, 2 * blk + s // 2, 3 * blk - 1],
+        # a row of no history between two that have some
+        "zero-history-between": [blk + 3, 0, tile + s + 5],
+        # a row ends where a sub-tile ends, where a block does, and one
+        # token and one page past each
+        "tile-and-block-boundaries": [
+            tile, tile + 1, blk, blk + 1, blk + tile, 2 * blk + s],
+    }[kind]
+
+
+@pytest.mark.parametrize("bits", [False, True], ids=["plain", "token-bits"])
+@pytest.mark.parametrize("kind", [
+    "short-last-block", "part-filled-last-page", "zero-history-between",
+    "tile-and-block-boundaries",
+])
+@pytest.mark.parametrize("name", sorted(WIDE_SHAPES))
+def test_wide_blocks_fold_by_sub_tile(monkeypatch, name, kind, bits):
+    """A block wider than `_MAX_TILE_SCORES` is folded a sub-tile at a
+    time: against dense float64 attention over the same cached tokens (and,
+    under `token_bits`, the same chosen ones), m and l included."""
+    shape = WIDE_SHAPES[name]
+    if "scores" in shape:
+        monkeypatch.setattr(
+            paged_attention_ops, "_MAX_TILE_SCORES", shape["scores"])
+    hq, hkv, s = shape["hq"], shape["hkv"], shape["s"]
+    pb = _block_pages(shape, 8)
+    tp = paged_attention_ops._tile_pages(pb, hq, s * hkv, bits)
+    whole = bits and (shape["tp"] * s * hkv) % 128  # no lane tile: one tile
+    assert (pb, tp) == (shape["pb"], pb if whole else shape["tp"])
+    hist = _wide_histories(kind, s, shape["pb"], shape["tp"])
+    rng = np.random.default_rng(5)
+    q, kv, pt = _pool_case(rng, shape, hist, num_layers=1)
+    chosen = None
+    if bits:  # about half of a row's tokens, the row's last among them
+        chosen = rng.random((len(hist), pt.shape[1] * s)) < 0.5
+        for r, h in enumerate(hist):
+            chosen[r, max(h - 1, 0)] = True
+    acc, m, l = paged_decode_attention(
+        q, kv.k, kv.v, jnp.int32(0), pt, jnp.asarray(hist, jnp.int32),
+        interpret=True,
+        token_bits=None if chosen is None else jnp.asarray(chosen),
+    )
+    acc, m, l = np.asarray(acc), np.asarray(m), np.asarray(l)
+    want, want_m, want_l = _dense_reference(
+        q.astype(jnp.float32), kv.k[0].astype(jnp.float32),
+        kv.v[0].astype(jnp.float32), np.asarray(pt), hist, chosen,
+        shape["d"])
+    # bf16 operands into the MXU (the serving dtypes): two bf16 ulps of
+    # values of order one; f32 operands to rounding
+    tol = 2**-7 if q.dtype == jnp.bfloat16 else 2e-5
+    for r, h in enumerate(hist):
+        if h == 0:
+            assert not acc[r].any() and not l[r].any()
+            assert np.all(np.isneginf(m[r]))
+            continue
+        np.testing.assert_allclose(
+            acc[r] / l[r][:, None], want[r], rtol=tol, atol=tol,
+            err_msg=f"row {r} history {h}")
+        # the caller folds the row's own token in by m and l themselves
+        np.testing.assert_allclose(m[r], want_m[r], atol=8 * tol)
+        np.testing.assert_allclose(
+            l[r] * np.exp(m[r] - want_m[r]), want_l[r], rtol=8 * tol)
+
+
 def test_block_rule_follows_the_shapes_and_the_budget():
     """Pages a block: from page bytes and columns alone, halved under a
     budget before the call would overflow it; never under one page."""
@@ -255,9 +373,67 @@ def test_block_rule_follows_the_shapes_and_the_budget():
     assert tight < roomy
     assert rule(64, 28, 128, 64, 4, 2, False, roomy - 1) == 4
     assert rule(64, 28, 128, 64, 4, 2, False, 1) == 1
+    # command-a-plus, 32 rows x 128 / 8 heads: 256 KiB and 512 columns a
+    # page, 4 pages a block folded ONE page at a time (128 x 512 scores),
+    # so the budget pays for a sub-tile's temporaries, not a block's. The
+    # whole-batch blocks take 10 of models/llama.py's 12 MiB: 2 pages
+    # there (1 before the sub-tiles), 4 under the family's own 16
+    cmda = (32, 128, 128, 64, 8, 2, False)
+    tile = paged_attention_ops._tile_pages
+    assert rule(*cmda, None) == 4 and tile(4, 128, 512) == 1
+    assert rule(*cmda, 12 << 20) == 2 and rule(*cmda, 16 << 20) == 4
+    assert decode_vmem_bytes(*cmda[:6], budget=16 << 20) == 13 << 20
+    # a sub-tile is whole pages that divide the block; at 32 heads or
+    # fewer it is the whole 2048-column block
+    assert tile(8, 28, 256) == 8 and tile(4, 32, 512) == 4
+    assert tile(8, 64, 256) == 4 and tile(6, 64, 256) == 3
+    assert tile(1, 128, 2048) == 1
+    # under bits a sub-tile is whole lane tiles, else the block is one
+    assert tile(8, 1024, 64) == 1 and tile(8, 1024, 64, True) == 8
+    assert tile(8, 256, 64, True) == 4
 
 
-def _allocated_vmem_bytes(monkeypatch, b, hq, hkv, d, s, dtype, quantized):
+#: pages a block (no budget, models/llama.py's 12 MiB) at every preset of
+#: scripts/paged_decode_bench.py, as PR 25 to PR 54 had them
+BENCH_BLOCKS = {
+    "qwen2-7b": (8, 8), "qwen2-7b-kv8": (8, 8), "phi3-mini": (1, 1),
+    "llama3-8b": (4, 4), "deepseek-v2-lite": (8, 8),
+}
+
+
+def test_every_bench_preset_keeps_its_block_and_is_one_sub_tile():
+    """The sub-tile rule reads Hq: the shapes the accepted cells walk keep
+    the block they had and fold it in one piece (their programs lower to
+    the same text); Command A+'s two walks are the wide ones."""
+    import importlib.util
+    from pathlib import Path
+
+    path = Path(__file__).parent.parent / "scripts" / "paged_decode_bench.py"
+    spec = importlib.util.spec_from_file_location("paged_decode_bench", path)
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    wide = set()
+    for name, shape in bench.SHAPES.items():
+        args = (
+            shape["b"], shape["hq"], shape["d"], bench.PAGE, shape["hkv"],
+            1 if shape.get("kv") else 2, bool(shape.get("kv")),
+        )
+        rope = shape.get("rope", 0)
+        blocks = tuple(
+            paged_attention_ops._block_pages(*args, budget, rope)
+            for budget in (None, 12 << 20)
+        )
+        tiles = paged_attention_ops._tile_pages(
+            blocks[0], shape["hq"], bench.PAGE * shape["hkv"])
+        if tiles < blocks[0]:
+            wide.add(name)
+        else:
+            assert blocks == BENCH_BLOCKS[name], name
+    assert wide == {"command-a-plus-full", "command-a-plus-ring"}
+
+
+def _allocated_vmem_bytes(monkeypatch, b, hq, hkv, d, s, dtype, quantized,
+                          budget=12 << 20):
     """Bytes of the blocks and scratch one call hands Mosaic, each padded
     to its dtype's (sublane, 128) tile, read off the pallas_call itself."""
     seen = {}
@@ -277,7 +453,7 @@ def _allocated_vmem_bytes(monkeypatch, b, hq, hkv, d, s, dtype, quantized):
     jax.eval_shape(
         lambda q, k, v, pt, hist, **kw: paged_decode_attention(
             q, k, v, jnp.int32(0), pt, hist, interpret=True,
-            vmem_budget=12 << 20, **kw
+            vmem_budget=budget, **kw
         ),
         sds((b, hq, d), jnp.bfloat16), pool, pool,
         sds((b, mp), jnp.int32), sds((b,), jnp.int32), **scales,
@@ -325,6 +501,20 @@ def test_vmem_estimate_covers_what_the_call_allocates(monkeypatch, quantized):
             monkeypatch, b, hq, hkv, d, s, dtype, quantized
         )
         assert allocated <= estimate(b) <= budget, (b, allocated)
+
+
+@pytest.mark.parametrize("budget", [12 << 20, 16 << 20], ids=["12", "16"])
+def test_vmem_estimate_covers_the_wide_form(monkeypatch, budget):
+    """Command A+'s walk (32 rows x 128 / 8 heads, bf16): more than one
+    page a block under either budget, and the estimate, which counts a
+    sub-tile's temporaries, still covers what the call allocates."""
+    b, hq, hkv, d, s = 32, 128, 8, 128, 64
+    estimate = decode_vmem_bytes(b, hq, d, s, hkv, 2, budget=budget)
+    allocated = _allocated_vmem_bytes(
+        monkeypatch, b, hq, hkv, d, s, jnp.bfloat16, False, budget=budget)
+    assert paged_attention_ops._block_pages(
+        b, hq, d, s, hkv, 2, False, budget) > 1
+    assert allocated <= estimate <= budget, (allocated, estimate)
 
 
 @pytest.mark.parametrize("t", [1, 4, 8])

@@ -1107,7 +1107,7 @@ def test_engine_refuses_narrow_pages_with_too_few_kv_heads_per_shard():
     pytest.param(32, 32, 32, id="mixed-32-rows-beside-32-short-prompts"),
 ])
 def test_command_a_plus_step_compiles_at_published_widths(
-        topo, rows, t, b_pre):
+        topo, caplog, rows, t, b_pre):
     """Whole steps of `command-a-plus-4l-16e` as `cmdaplus-longctx` serves
     it (bf16, 9,000 pages of 64 in the full layer, 36 ring slots of 4,608
     rows of 8 KV heads of 128 in 3 window layers, --max-context 18432): a
@@ -1164,8 +1164,19 @@ def test_command_a_plus_step_compiles_at_published_widths(
 
         args = rows_of(rows, t)
 
-    compiled = jax.jit(program, donate_argnums=(1,)).lower(
-        params, kv, *args).compile()
+    from dynamo_tpu.ops import paged_attention as walk_ops
+
+    walk_ops._told_sub_tiles.clear()
+    with caplog.at_level("INFO", logger=walk_ops.__name__):
+        compiled = jax.jit(program, donate_argnums=(1,)).lower(
+            params, kv, *args).compile()
+    # both decode walks (one shape) take 4 pages a DMA block and fold them
+    # a page of 512 key columns at a time: said once, at trace time
+    told = [r.getMessage() for r in caplog.records
+            if "sub-tiles" in r.getMessage()]
+    assert len(told) == 1 and (
+        "32 rows x 128 / 8 heads walk 4 pages a block in sub-tiles of 512 "
+        "key columns" in told[0]), told
     mem = compiled.memory_analysis()
     pools = sum(np.prod(x.shape) * x.dtype.itemsize
                 for x in (kv.k, kv.v, kv.ring, kv.ring_v))
