@@ -344,9 +344,10 @@ def ring_pages(rings, page: int):
     """The slot pool as the pages of a GQA cache it is: [S layers, (slots
     + 1) x R / page, page, Hkv, D], the same bytes; slot s holds pages `s R
     / page` on, page 0 belongs to the null slot."""
-    n_l, n_s, r = rings[0].shape[:3]
-    return tuple(ring.reshape(n_l, n_s * (r // page), page, *ring.shape[3:])
-                 for ring in rings)
+    n_s, r = rings[0].shape[1:3]
+    return tuple(ring.reshape(ring.shape[0], n_s * (r // page), page,
+                              *ring.shape[3:])  # (K and V may differ in
+                 for ring in rings)  # layers: models/mimo_v2.py's lane parts)
 
 
 def ring_tables(slots, r: int, page: int):

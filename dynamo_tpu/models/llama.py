@@ -1427,7 +1427,8 @@ def paged_attention(
     else:
         probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgts,bskd->btkgd", probs, v_pages.astype(jnp.float32))
-    return out.reshape(b, t, hq * d).astype(q.dtype)
+    # (as wide as the values: models/mimo_v2.py's are narrower than its keys)
+    return out.reshape(b, t, hq * v_pages.shape[-1]).astype(q.dtype)
 
 
 def _chunk_only_attention(q, k, v, positions, valid, cfg, dpad, mesh=None,

@@ -578,34 +578,69 @@ def _dots3_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
             "kv_tiers", "speculation", "page_transfer")))
 
 
-def _cohere2_moe_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
-    """Command A+'s language model (models/cohere2_moe.py) through
-    `_hybrid_adapter`: K and V pages for its full layers and a slot a
-    sequence for its window layers' rings of K and V, ONE generation
-    (`state_in_place`). What the hybrid adapter cannot say is replaced
-    here: the refusals' sentences."""
-    from dynamo_tpu.models import cohere2_moe as c2
-
-    base = _hybrid_adapter(
-        name, cfg, c2, "Command A+", c2.cohere2_moe_logical_axes, mesh)
+def _gqa_ring_adapter(name: str, cfg, mod, family: str, axes, no_quantize: str,
+                      mesh=None) -> ModelAdapter:
+    """A family whose full layers keep K and V pages and whose window
+    layers keep a ring of K and V a sequence in the slot pool, ONE
+    generation (`state_in_place`), through `_hybrid_adapter`. What the
+    hybrid adapter cannot say is replaced here: why `kv_quantize` is
+    refused (`no_quantize`) and the refusals' sentence."""
+    base = _hybrid_adapter(name, cfg, mod, family, axes, mesh)
 
     def init_kv(num_pages, page_size, kv_quantize=None, state_slots=0):
         if kv_quantize:
             raise ValueError(
-                "kv_quantize is not supported for Command A+: three layers "
-                "of four keep their K and V in a ring in the slot pool, in "
-                "the model dtype, and narrowing the full layers' pages "
-                "alone has no tested path beside it; run with "
-                "kv_quantize=None")
-        return c2.init_cache(cfg, num_pages, page_size, state_slots)
+                f"kv_quantize is not supported for {family}: {no_quantize}; "
+                "run with kv_quantize=None")
+        return mod.init_cache(cfg, num_pages, page_size, state_slots)
 
     why = ("a sequence of this family is its pages (the full layers' K and "
            "V) and the rings of its window layers in the slot pool, and "
            "this would move or rewind the pages alone")
     return replace(
-        base, init_kv=init_kv, state_in_place=c2.STATE_IN_PLACE,
+        base, init_kv=init_kv, state_in_place=mod.STATE_IN_PLACE,
         refuses=tuple((what, why) for what in (
             "kv_tiers", "speculation", "page_transfer")))
+
+
+def _cohere2_moe_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
+    """Command A+'s language model (models/cohere2_moe.py)."""
+    from dynamo_tpu.models import cohere2_moe as c2
+
+    return _gqa_ring_adapter(
+        name, cfg, c2, "Command A+", c2.cohere2_moe_logical_axes,
+        "three layers of four keep their K and V in a ring in the slot "
+        "pool, in the model dtype, and narrowing the full layers' pages "
+        "alone has no tested path beside it", mesh)
+
+
+def _mimo_v2_adapter(name: str, cfg, mesh=None) -> ModelAdapter:
+    """MiMo-V2.5's language model (models/mimo_v2.py): pages and rings of
+    two KV geometries, both in lane parts."""
+    from dynamo_tpu.models import mimo_v2 as mm
+
+    return _gqa_ring_adapter(
+        name, cfg, mm, "MiMo-V2.5", mm.mimo_v2_logical_axes,
+        "five layers of six keep their K and V in a ring in the slot pool, "
+        "in the model dtype, and narrowing the full layers' pages alone "
+        "has no tested path beside it (nor has the walk of a cache in lane "
+        "parts one for scale planes)", mesh)
+
+
+def _mimo_v2_presets() -> dict:
+    from dynamo_tpu.models.mimo_v2 import MimoV2Config
+
+    return {
+        # the language model of MiMo-V2.5 as published: 48 layers, 256
+        # experts, 152,576 ids (618 GB in bf16: shape tests and a later
+        # multi-chip issue)
+        "mimo-v2.5": MimoV2Config.mimo_v2_5,
+        # one chip of its deployment: layers 0 and 6-11, 16 of the 256
+        # experts, an eighth of the vocabulary (chipbench/configs/
+        # mimo-v2.5-1chip.json)
+        "mimo-v2.5-7l-16e": MimoV2Config.mimo_v2_5_1chip,
+        "mimo-v2.5-tiny": MimoV2Config.tiny,
+    }
 
 
 def _cohere2_moe_presets() -> dict:
@@ -734,6 +769,7 @@ _STATE_FAMILIES = (
     (_keye_vl_presets, _keye_vl_adapter),
     (_dots3_presets, _dots3_adapter),
     (_cohere2_moe_presets, _cohere2_moe_adapter),
+    (_mimo_v2_presets, _mimo_v2_adapter),
 )
 
 

@@ -1330,29 +1330,46 @@ def _ring_kernel(
     # in reach of the piece
     q_ref,  # [1, BQ, G x D]: the queries of one KV head's G heads, SCALED
     kcur_ref,  # [1, T, D]: that KV head's keys of the piece itself
-    vcur_ref,  # [1, T, D]
+    vcur_ref,  # [1, T, Dv]
     ring_k_ref,  # [1, 1, R, D]: the row's ring of that KV head
-    ring_v_ref,  # [1, 1, R, D]
+    ring_v_ref,  # [1, 1, R, Dv]
     qpos_ref,  # [1, BQ, 1] int32: the queries' positions
     rpos_ref,  # [1, 1, R] int32: the position a ring row holds; < 0: none
     cpos_ref,  # [1, 1, T] int32: the piece's positions; < 0: padding
-    o_ref,  # [1, BQ, G x D]
-    m_scr,  # [G x BQ, 128] f32 running max (every lane the same)
-    l_scr,  # [G x BQ, 128] f32 running denominator
-    acc_scr,  # [G x BQ, D] f32
-    *,
+    *rest,  # [sink_ref [1, G, 128] f32: a head's sink logit, every lane],
+    # o_ref [1, BQ, G x Dv], then the scratch:
+    # m_scr [G x BQ, 128] f32 running max (every lane the same),
+    # l_scr [G x BQ, 128] f32 running denominator, acc_scr [G x BQ, Dv] f32
     window: int,
     block_k: int,
+    banded: bool = False,
 ):
+    sink_ref = rest[0] if len(rest) == 5 else None
+    o_ref, m_scr, l_scr, acc_scr = rest[-4:]
     b = pl.program_id(0)
     qi = pl.program_id(2)
     bq, t = q_ref.shape[1], kcur_ref.shape[1]
-    d = kcur_ref.shape[2]
+    d, dv = kcur_ref.shape[2], vcur_ref.shape[2]
     g = q_ref.shape[2] // d
     # the G heads' tiles one under the other: one dot a key tile for all
     q = jnp.concatenate(
         [q_ref[0, :, i * d:(i + 1) * d] for i in range(g)], axis=0)
     at = jnp.concatenate([qpos_ref[0]] * g, axis=0)  # [G x BQ, 1]
+    # the running softmax is STARTED here where a turn may not be the first
+    # to run: under a sink at (its logit, 1, 0), which is the sink exactly (a
+    # key of value 0 in every query's softmax); where own tiles BEFORE the
+    # band are skipped (`banded`) at (masked, 0, 0)
+    started = banded or sink_ref is not None
+    if started:
+        if sink_ref is None:
+            m_scr[...] = jnp.full(m_scr.shape, _MASKED, jnp.float32)
+            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        else:
+            m_scr[...] = jnp.concatenate([
+                jnp.broadcast_to(sink_ref[0, i:i + 1, :], (bq, 128))
+                for i in range(g)], axis=0)
+            l_scr[...] = jnp.ones(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
     def fold(k, v, key, first=False):
         """One turn of the online softmax over the keys `k` [K, D] at the
@@ -1370,7 +1387,7 @@ def _ring_kernel(
         # finite, and a zero weight silences them)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [G x BQ, D]
+            preferred_element_type=jnp.float32)  # [G x BQ, Dv]
         if not first:
             corr = jnp.exp(m_scr[:, :1] - m_new)
             l_new += corr * l_scr[:, :1]
@@ -1380,33 +1397,41 @@ def _ring_kernel(
         acc_scr[...] = pv
 
     # -- the piece over itself: key tiles wholly after this cell's queries
-    # skipped (positions ascend along a piece) -------------------------------
+    # skipped (positions ascend along a piece), and under `banded` the tiles
+    # wholly before the first query's window ----------------------------------
+    back = -(-(window - 1) // bq)  # own tiles behind a query tile in reach
     for j in range(t // bq):
         def turn(j=j):
             rows = pl.ds(j * bq, bq)
             fold(kcur_ref[0, rows, :], vcur_ref[0, rows, :],
-                 cpos_ref[0, :, rows], first=j == 0)
+                 cpos_ref[0, :, rows], first=j == 0 and not started)
 
-        if j == 0:
+        if banded:
+            pl.when((j <= qi) & (j >= qi - back))(turn)
+        elif j == 0:
             turn()
         else:
             pl.when(j <= qi)(turn)
 
     # -- the ring: rows as they lie, a tile none of whose rows the piece's
-    # first query reaches skipped --------------------------------------------
+    # first query reaches skipped; under `banded` every tile, for the query
+    # tiles that stand a window or more past the piece's first token ----------
     def body(i, _):
         def turn():
             rows = pl.ds(pl.multiple_of(i * block_k, block_k), block_k)
             fold(ring_k_ref[0, 0, rows, :], ring_v_ref[0, 0, rows, :],
                  rpos_ref[0, :, rows])
 
-        pl.when(live_ref[b, i] != 0)(turn)
+        reach = live_ref[b, i] != 0
+        if banded:  # a ring key lies before the piece's first position
+            reach &= qi * bq <= window - 2
+        pl.when(reach)(turn)
         return 0
 
     jax.lax.fori_loop(0, ring_k_ref.shape[2] // block_k, body, 0)
     out = acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
     for i in range(g):
-        o_ref[0, :, i * d:(i + 1) * d] = out[i * bq:(i + 1) * bq].astype(
+        o_ref[0, :, i * dv:(i + 1) * dv] = out[i * bq:(i + 1) * bq].astype(
             o_ref.dtype)
 
 
@@ -1464,14 +1489,15 @@ def gather_pages(pool: jax.Array, layer: jax.Array, pages: jax.Array,
 def ring_prefill_attention(
     q: jax.Array,  # [B, T, Hq, D] queries, SCALED
     k_cur: jax.Array,  # [B, T, Hkv, D] the piece's own keys
-    v_cur: jax.Array,  # [B, T, Hkv, D]
+    v_cur: jax.Array,  # [B, T, Hkv, Dv]
     ring_k: jax.Array,  # [B, Hkv, R, D] each row's cached keys, by KV head
-    ring_v: jax.Array,  # [B, Hkv, R, D]
+    ring_v: jax.Array,  # [B, Hkv, R, Dv]
     q_pos: jax.Array,  # [B, T] int32
     ring_pos: jax.Array,  # [B, R] int32: the position a row holds; < 0: none
     cur_pos: jax.Array,  # [B, T] int32, ascending; < 0: padding
     *,
     window: int,
+    sinks: jax.Array | None = None,  # [Hq] f32: a sink logit a query head
     interpret: bool | None = None,
 ) -> jax.Array:
     """A prompt piece's attention under a sliding window stated by POSITION
@@ -1488,44 +1514,70 @@ def ring_prefill_attention(
     tiles the piece's first query cannot reach skipped. Operands reach the
     MXU in the dtype they come in; scores and softmax are float32 in VMEM.
 
-    Returns [B, T, Hq, D] in the queries' dtype; a query with no key in its
+    The values may be narrower than the keys (`Dv` beside `D`:
+    models/mimo_v2.py), and `sinks` adds one learned logit a query head to
+    every query's softmax, a key of value 0: the running softmax starts at
+    (the logit, 1, 0) where it starts at the first tile's without. Where the
+    window is shorter than the piece (128 keys under a piece of 512) a query
+    tile reaches the own tiles from `ceil((window - 1) / RING_BLOCK_Q)`
+    behind it on, and the ring only where it stands less than a window past
+    the piece's first token: the other turns are skipped. A window as long
+    as the piece or longer, values as wide as the keys and no sink is the
+    code it was.
+
+    Returns [B, T, Hq, Dv] in the queries' dtype; a query with no key in its
     band (padding) gets zeros.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, t, hq, d = q.shape
-    hkv = k_cur.shape[2]
+    hkv, dv = k_cur.shape[2], v_cur.shape[3]
     g = hq // hkv
     r = ring_k.shape[2]
     bq = RING_BLOCK_Q if t % RING_BLOCK_Q == 0 else t
+    # some query tile of the piece stands a whole own tile past its window
+    banded = -(-(window - 1) // bq) < t // bq - 1
     bk = next(n for n in (RING_BLOCK_K, 256, 128, 64, 32, 16, 8, 4, 2, 1)
               if r % n == 0)
     first = jnp.min(jnp.where(cur_pos >= 0, cur_pos, 1 << 30), axis=1)
     live = jnp.any(
         ((ring_pos >= 0) & (ring_pos >= first[:, None] - (window - 1))
          ).reshape(b, r // bk, bk), axis=-1).astype(jnp.int32)
-    tile = pl.BlockSpec((1, bq, g * d), lambda bi, h, qi, lv: (bi, qi, h))
-    piece = pl.BlockSpec((1, t, d), lambda bi, h, qi, lv: (bi, 0, h))
-    ring = pl.BlockSpec((1, 1, r, d), lambda bi, h, qi, lv: (bi, h, 0, 0))
+    def tile(w):
+        return pl.BlockSpec((1, bq, g * w), lambda bi, h, qi, lv: (bi, qi, h))
+
+    def piece(w):
+        return pl.BlockSpec((1, t, w), lambda bi, h, qi, lv: (bi, 0, h))
+
+    def ring(w):
+        return pl.BlockSpec((1, 1, r, w), lambda bi, h, qi, lv: (bi, h, 0, 0))
+
+    sink_spec, sink_arg = [], []
+    if sinks is not None:
+        sink_spec = [pl.BlockSpec((1, g, 128), lambda bi, h, qi, lv: (h, 0, 0))]
+        sink_arg = [jnp.broadcast_to(
+            sinks.astype(jnp.float32).reshape(hkv, g, 1), (hkv, g, 128))]
     out = pl.pallas_call(
-        functools.partial(_ring_kernel, window=window, block_k=bk),
+        functools.partial(
+            _ring_kernel, window=window, block_k=bk, banded=banded),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, hkv, t // bq),
             in_specs=[
-                tile, piece, piece, ring, ring,
+                tile(d), piece(d), piece(dv), ring(d), ring(dv),
                 pl.BlockSpec((1, bq, 1), lambda bi, h, qi, lv: (bi, qi, 0)),
                 pl.BlockSpec((1, 1, r), lambda bi, h, qi, lv: (bi, 0, 0)),
                 pl.BlockSpec((1, 1, t), lambda bi, h, qi, lv: (bi, 0, 0)),
+                *sink_spec,
             ],
-            out_specs=tile,
+            out_specs=tile(dv),
             scratch_shapes=[
                 pltpu.VMEM((g * bq, 128), jnp.float32),
                 pltpu.VMEM((g * bq, 128), jnp.float32),
-                pltpu.VMEM((g * bq, d), jnp.float32),
+                pltpu.VMEM((g * bq, dv), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, t, hq * d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, t, hq * dv), q.dtype),
         interpret=interpret,
         name="ring_prefill_attention",
         # a row's ring of one KV head, keys and values, twice (the
@@ -1536,9 +1588,10 @@ def ring_prefill_attention(
         ),
     )(
         live, q.reshape(b, t, hq * d), k_cur.reshape(b, t, hkv * d),
-        v_cur.reshape(b, t, hkv * d), ring_k, ring_v,
+        v_cur.reshape(b, t, hkv * dv), ring_k, ring_v,
         q_pos.astype(jnp.int32)[..., None],
         ring_pos.astype(jnp.int32)[:, None],
         cur_pos.astype(jnp.int32)[:, None],
+        *sink_arg,
     )
-    return out.reshape(b, t, hq, d)
+    return out.reshape(b, t, hq, dv)

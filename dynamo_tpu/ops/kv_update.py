@@ -331,7 +331,7 @@ def paged_write(
         # XLA scatter fallback (CPU, meshes): token-granular, one 5D
         # advanced-index scatter per cache.
         ks = k_q.reshape(L, b * t, *k_q.shape[3:])
-        vs = v_q.reshape(L, b * t, *v_q.shape[3:])
+        vs = v_q.reshape(v_q.shape[0], b * t, *v_q.shape[3:])
         k_cache = k_cache.at[:, page_ids, slot_of].set(
             ks.astype(k_cache.dtype), mode="drop"
         )
@@ -350,9 +350,11 @@ def paged_write(
     run_pages = jnp.where(first_valid, run_pages, 0).reshape(-1)
     run_slots = jnp.where(first_valid, first_pos % s, 0).reshape(-1)
 
-    # K and V rows may differ in width (a latent cache: models/mla.py)
+    # K and V rows may differ in width (a latent cache: models/mla.py) and
+    # in how many layers they stand in (lane parts: models/mimo_v2.py)
     k_src = k_q.reshape(L, nr, run, *k_stage.shape[3:]).astype(k_cache.dtype)
-    v_src = v_q.reshape(L, nr, run, *v_stage.shape[3:]).astype(v_cache.dtype)
+    v_src = v_q.reshape(
+        v_q.shape[0], nr, run, *v_stage.shape[3:]).astype(v_cache.dtype)
     shapes = k_cache.shape, v_cache.shape
     if one_row:
         k_src, v_src, k_cache, v_cache = (

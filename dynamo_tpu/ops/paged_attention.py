@@ -64,6 +64,13 @@ and scores, m, l, exp and acc are f32.
 D is lane-padded to a 128 multiple (LlamaConfig.kv_head_dim): Mosaic DMA
 slices must be 128-aligned in the minor dimension.
 
+A head whose key is wider than its value and no multiple of 128 lanes
+(models/mimo_v2.py: 192 | 128) keeps the same layout in LANE PARTS (the
+wrapper's `parts`): two KV heads side by side, each 128-lane tile of the
+pair a pool entry of its own, so every pool stays [.., Hkv / 2, 128] and the
+bitcast above stays one; a page's parts are as many DMAs into the lanes of
+one slot, and the body is the same code over pair-heads.
+
 The block size follows from the shapes (`_block_pages`): as many pages as
 reach 1 MiB of K+V, at most 8, at most 2048 key columns, halved while
 `_footprint` would pass the caller's VMEM budget. `_footprint` is what
@@ -128,7 +135,7 @@ def _round_up(x: int, m: int) -> int:
 
 def _footprint(
     pb: int, b: int, hq: int, d: int, s: int, hkv: int, itemsize: int,
-    quantized: bool, rope_dim: int = 0,
+    quantized: bool, rope_dim: int = 0, dv: int | None = None,
 ) -> int:
     """VMEM bytes one kernel call whose blocks hold `pb` pages is planned
     to take: the whole-batch q / acc / m+l blocks, counted twice (what a
@@ -136,17 +143,19 @@ def _footprint(
     one, module text), `_DEPTH` K and V slots, the scale slots of a
     quantized pool, and the live temporaries of a sub-tile of the block. A
     latent cache (`rope_dim` > 0) has a `d`-wide page that is key and value
-    at once and a `rope_dim`-wide rope-key page beside it."""
+    at once and a `rope_dim`-wide rope-key page beside it; `dv` is the
+    width of a GQA cache's value where it is not the key's `d`."""
+    dv = d if dv is None else dv
     hqp = _round_up(hq, 8)
     n = pb * s * hkv  # key columns of a block
     tn = _tile_pages(pb, hq, s * hkv) * s * hkv  # and of a sub-tile of it
     sub = 32 // itemsize  # sublane tile of the cache dtype
-    kv_width = d + rope_dim if rope_dim else 2 * d  # of one cached token
+    kv_width = d + rope_dim if rope_dim else d + dv  # of one cached token
     whole_batch = 2 * b * _round_up(hqp, sub) * (d + rope_dim) * itemsize  # q
     if rope_dim:
         whole_batch += 2 * b * hqp * (d + 128) * 4  # acc, and m|l
     else:
-        whole_batch += 2 * 2 * b * hqp * d * 4  # acc, and m|l in one block
+        whole_batch += 2 * b * hqp * (dv + d) * 4  # acc, and m|l (as `d`)
     slots = _DEPTH * _round_up(n, sub) * kv_width * itemsize
     # limit, scores and p in 32 bits, p again for the MXU
     temps = 4 * hqp * tn * 4
@@ -175,12 +184,15 @@ def _tile_pages(pb: int, hq: int, rpp: int, lane_tiles: bool = False) -> int:
 def _block_pages(
     b: int, hq: int, d: int, s: int, hkv: int, itemsize: int,
     quantized: bool, budget: int | None, rope_dim: int = 0,
+    dv: int | None = None,
 ) -> int:
     """Pages per block, from the shapes alone: as many as reach
     `_BLOCK_BYTES` of K+V, at most `_MAX_BLOCK_PAGES`, no more key columns
     than `_MAX_BLOCK_COLUMNS`; then halved while the call would not fit
-    `budget` (a large batch's q/acc blocks leave less for the slots)."""
-    page_bytes = s * hkv * (d + rope_dim if rope_dim else 2 * d) * itemsize
+    `budget` (a large batch's q/acc blocks leave less for the slots). `dv`:
+    the value's width where it is not the key's (`_footprint`)."""
+    dv = d if dv is None else dv
+    page_bytes = s * hkv * (d + rope_dim if rope_dim else d + dv) * itemsize
     pb = max(1, min(
         _MAX_BLOCK_PAGES, _BLOCK_BYTES // page_bytes,
         _MAX_BLOCK_COLUMNS // (s * hkv),
@@ -188,7 +200,7 @@ def _block_pages(
     while (
         budget is not None and pb > 1
         and _footprint(
-            pb, b, hq, d, s, hkv, itemsize, quantized, rope_dim
+            pb, b, hq, d, s, hkv, itemsize, quantized, rope_dim, dv
         ) > budget
     ):
         pb //= 2
@@ -230,6 +242,7 @@ def _decode_kernel(
     quantized: bool,
     latent: bool,
     token_bits: bool = False,
+    parts: tuple = (1, 1),
 ):
     bits_ref = None
     if token_bits:
@@ -245,7 +258,9 @@ def _decode_kernel(
     li = layer_ref[0]
     n_rows = nrows_ref[0]
     bsz, hqp, _ = q_ref.shape
-    d = acc_ref.shape[2]  # a latent q carries its rope part past `d`
+    d = acc_ref.shape[2]  # the value's width: a latent's is its key's too
+    dk = k_scr.shape[2]  # what of q the keys are scored by (a latent q
+    # carries its rope part past it)
     hkv, s, pb = num_kv_heads, page_size, block_pages
     g = num_q_heads // hkv
     rpp = s * hkv  # cache rows (= key columns) of one page: r = slot*Hkv + h
@@ -329,10 +344,19 @@ def _decode_kernel(
                 axis=1,
             )
 
-    # one DMA plane per (cache/scale); a page and its scales land together
-    planes = [(k_ref, k_scr, rpp, rpp), (v_ref, v_scr, rpp, rpp)]
+    # one DMA plane per (cache/scale); a page and its scales land together.
+    # A cache in `parts` (the wrapper's text) is a plane a part: part t of
+    # layer li is entry `t x layers + li` and lands in the slot's lanes
+    # `t x 128` on
+    planes = [
+        (src, dst, rpp, rpp,
+         None if n_parts == 1 else (t * 128, t * (src.shape[0] // n_parts)))
+        for src, dst, n_parts in ((k_ref, k_scr, parts[0]),
+                                  (v_ref, v_scr, parts[1]))
+        for t in range(n_parts)]
     if quantized:
-        planes += [(ks_ref, ks_scr, sub, hkv), (vs_ref, vs_scr, sub, hkv)]
+        planes += [(ks_ref, ks_scr, sub, hkv, None),
+                   (vs_ref, vs_scr, sub, hkv, None)]
 
     def block_copies(b, kb, slot, act):
         """Start or wait the DMAs of block `kb` of row `b`: one per plane
@@ -341,13 +365,13 @@ def _decode_kernel(
 
         def page_copies(p, _):
             page = pt_ref[b * max_pages + first + p]
-            for pi, (src, dst, stride, rows_) in enumerate(planes):
+            for pi, (src, dst, stride, rows_, part) in enumerate(planes):
                 at = pl.multiple_of(p * stride, stride)
-                act(pltpu.make_async_copy(
-                    src.at[li, page],
-                    dst.at[slot, pl.ds(at, rows_)],
-                    sem.at[pi, slot],
-                ))
+                source, into = src.at[li, page], dst.at[slot, pl.ds(at, rows_)]
+                if part is not None:  # (its lanes of the slot, its layers)
+                    source = src.at[li + part[1] if part[1] else li, page]
+                    into = dst.at[slot, pl.ds(at, rows_), pl.ds(part[0], 128)]
+                act(pltpu.make_async_copy(source, into, sem.at[pi, slot]))
             return 0
 
         pages = -(-len_ref[b] // s)
@@ -394,14 +418,14 @@ def _decode_kernel(
                 cols = slice(None) if tn == n else slice(t * tn, (t + 1) * tn)
                 k_blk = to_mxu(k_scr[slot, cols])
                 scores = jax.lax.dot_general(
-                    q[:, :d], k_blk, (((1,), (1,)), ((), ())),
+                    q[:, :dk], k_blk, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )  # [HQP, TN]
                 if latent:
                     # the page in k_scr is the latent: scored here, summed
                     # as the value below; v_scr holds the shared rope key
                     scores += jax.lax.dot_general(
-                        q[:, d:], to_mxu(v_scr[slot, cols]),
+                        q[:, dk:], to_mxu(v_scr[slot, cols]),
                         (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32,
                     )
@@ -507,6 +531,7 @@ def paged_decode_attention(
     v_scale: jax.Array | None = None,
     vmem_budget: int | None = None,  # the caller's, as in decode_vmem_bytes
     token_bits: jax.Array | None = None,  # [B, MP * S] bool: keys attended
+    parts: tuple[int, int] = (1, 1),  # 128-lane parts of a K and a V row
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """History-only flash attention over the paged cache.
 
@@ -531,11 +556,32 @@ def paged_decode_attention(
     models/dots3.py over a latent, whose indexers choose them): resident
     in VMEM as one int32 a key column.
 
+    `parts` = (kp, vp) walks a cache whose key and value differ in width
+    and are no multiple of 128 lanes a head (models/mimo_v2.py: 192 | 128):
+    TWO KV heads' rows side by side are `kp` lane tiles of key and `vp` of
+    value, each tile a pool entry of its own: `k_cache` [kp x L, P, S, Hkv /
+    2, 128] holds part t of layer `layer` at `t x L + layer` (`v_cache`
+    likewise), so every pool is 128 wide, nothing is padded and the page
+    writer takes them as they are. A part lands in its lanes of the block's
+    slot, so the body sees ONE pair-head of `kp x 128` key and `vp x 128`
+    value columns: `q` is [B, Hq, kp x 128], a head's query in its own
+    head's half of the pair and zeros in the other, `acc` comes back [B, Hq,
+    vp x 128] with the head's output in its own half, and `Hkv / 2` pair-
+    heads take the place of the KV heads everywhere else (the bits' columns,
+    the block rule). The default is one part each: the code it was.
+
     `interpret` defaults to True off-TPU so tests run the same kernel on CPU.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     quantized = k_scale is not None
+    kp, vp = parts
+    if parts != (1, 1) and (quantized or latent or mesh is not None or any(
+            c.shape[4] != 128 or c.shape[0] % n
+            for c, n in ((k_cache, kp), (v_cache, vp)))):
+        raise ValueError(
+            "parts: unquantized GQA pools [parts x L, P, S, Hkv / 2, 128] on "
+            f"one chip; got {k_cache.shape}, {v_cache.shape} for {parts}")
     if token_bits is not None and (quantized or (
             mesh is not None and mesh.shape.get("tp", 1) > 1)):
         raise ValueError(
@@ -589,7 +635,7 @@ def paged_decode_attention(
         )
         return fn(*args)
     b, hq, dq = q.shape
-    d, dv = k_cache.shape[4], v_cache.shape[4]
+    d, dv = k_cache.shape[4] * kp, v_cache.shape[4] * vp
     if latent and (quantized or hkv != 1 or dq != d + dv):
         raise ValueError(
             "a latent walk takes an unquantized one-row cache and "
@@ -598,9 +644,9 @@ def paged_decode_attention(
     mp = page_tables.shape[1]
     n_rows, rows, pages = work_list
     itemsize = jnp.dtype(k_cache.dtype).itemsize
+    widths = (dv, None) if latent else (0, dv)  # (`rope_dim`, `dv`)
     pb = min(mp, _block_pages(
-        b, hq, d, s, hkv, itemsize, quantized, vmem_budget,
-        dv if latent else 0,
+        b, hq, d, s, hkv, itemsize, quantized, vmem_budget, *widths,
     ))
     if token_bits is not None and (pb * s * hkv) % 128:
         # a block's bits are read at a lane-aligned offset: whole lane
@@ -617,7 +663,7 @@ def paged_decode_attention(
             "a block in sub-tiles of %d key columns (~%.1f MiB VMEM)",
             b, hq, hkv, pb, tile * s * hkv,
             _footprint(pb, b, hq, d, s, hkv, itemsize, quantized,
-                       dv if latent else 0) / 2**20)
+                       *widths) / 2**20)
     hqp = _round_up(hq, 8)
     if hqp != hq:  # whole sublane tiles of query heads; the pad is masked
         q = jnp.pad(q, ((0, 0), (0, hqp - hq), (0, 0)))
@@ -662,15 +708,16 @@ def paged_decode_attention(
         in_specs.append(whole(b, cols * hkv))
         extra = dict(compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024))
-    scratch_shapes.append(
-        pltpu.SemaphoreType.DMA((4 if quantized else 2, _DEPTH))
+    scratch_shapes.append(  # a semaphore a DMA plane and slot
+        pltpu.SemaphoreType.DMA((4 if quantized else kp + vp, _DEPTH))
     )
+    wd = d if latent else dv  # the accumulator's width: the value's
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(1,),
         in_specs=in_specs,
-        out_specs=[whole(b, hqp, d), whole(b, hqp, 128)],
+        out_specs=[whole(b, hqp, wd), whole(b, hqp, 128)],
         scratch_shapes=scratch_shapes,
     )
     acc, ml = pl.pallas_call(
@@ -688,9 +735,10 @@ def paged_decode_attention(
             quantized=quantized,
             latent=latent,
             token_bits=token_bits is not None,
+            parts=parts,
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, hqp, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hqp, wd), jnp.float32),
             jax.ShapeDtypeStruct((b, hqp, 128), jnp.float32),
         ],
         grid_spec=grid_spec,

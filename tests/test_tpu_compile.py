@@ -693,8 +693,12 @@ def test_gqa_decode_walk_is_what_it_was_before_the_selecting_one():
 
 @pytest.mark.parametrize("rows,t,b_pre", [
     pytest.param(32, 1, 0, id="decode-32-rows-fused-8"),
-    pytest.param(32, 512, 1, id="mixed-32-rows-beside-a-chunk"),
-    pytest.param(32, 512, 4, id="mixed-32-rows-beside-four-pieces"),
+    # (PR 56: 90 and 82 test-seconds; a builder with the chip runs them on
+    # demand, `-m slow`, before a call that touches this family)
+    pytest.param(32, 512, 1, id="mixed-32-rows-beside-a-chunk",
+                 marks=pytest.mark.slow),
+    pytest.param(32, 512, 4, id="mixed-32-rows-beside-four-pieces",
+                 marks=pytest.mark.slow),
 ])
 def test_minicpm_sala_step_compiles_at_published_widths(
         topo, rows, t, b_pre):
@@ -895,8 +899,11 @@ def test_keye_vl_step_compiles_at_published_widths(topo, rows, t, b_pre):
 
 @pytest.mark.parametrize("rows,t,b_pre", [
     pytest.param(32, 1, 0, id="decode-32-rows-fused-8"),
-    pytest.param(32, 512, 4, id="mixed-32-rows-beside-four-chunks"),
-    pytest.param(32, 32, 32, id="mixed-32-rows-beside-32-short-prompts"),
+    # (PR 56: 100 and 112 test-seconds; on demand, `-m slow`)
+    pytest.param(32, 512, 4, id="mixed-32-rows-beside-four-chunks",
+                 marks=pytest.mark.slow),
+    pytest.param(32, 32, 32, id="mixed-32-rows-beside-32-short-prompts",
+                 marks=pytest.mark.slow),
 ])
 def test_dots3_step_compiles_at_published_widths(topo, rows, t, b_pre):
     """Whole steps of `dots3-note-prev-9l-8e` as `dots3-longctx` serves it
@@ -1206,3 +1213,188 @@ def test_command_a_plus_step_compiles_at_published_widths(
                  "bf16[3,37,4608,1024]", "bf16[37,4608,1024]"):
         assert not re.search(
             rf"= {re.escape(copy)}[^ ]* copy\(", text), copy
+
+
+@pytest.mark.parametrize("rows,t,b_pre", [
+    pytest.param(32, 1, 0, id="decode-32-rows-8-fused"),
+    pytest.param(32, 512, 1, id="mixed-32-rows-beside-a-chunk",
+                 marks=pytest.mark.slow),
+    pytest.param(32, 512, 4, id="mixed-32-rows-beside-four-chunks",
+                 marks=pytest.mark.slow),
+    pytest.param(32, 32, 32, id="mixed-32-rows-beside-32-short-prompts",
+                 marks=pytest.mark.slow),
+])
+def test_mimo_v2_step_compiles_at_published_widths(topo, rows, t, b_pre):
+    """Whole steps of `mimo-v2.5-7l-16e` as `mimo25-longctx` serves it
+    (bf16, 9,000 pages of 64 in 2 full layers of 4 KV heads, 36 ring slots
+    of 640 rows of 8 KV heads in 5 window layers, keys 192 beside values
+    128 in lane parts, --max-context 18432): a decode row's walk of the 3
+    ring pages in reach under a bit a ring row and of its pages in the full
+    layers, both over pools in parts, the sink merged outside; a prompt
+    piece's banded kernel under the sink over its ring and, with a window
+    no position reaches, over its pages; layer 0's dense MLP, the grouped
+    matmuls over the 16 held experts, the untied head: every pool updated
+    in place and NONE padded (the argument bytes are the pools' own), the
+    program beside 6.9 GB of weights, 2.9 GB of pages and 0.6 GB of rings
+    inside the chip. The decode case is tier-1's; the mixed ones run on
+    demand (`-m slow`)."""
+    adapter = get_model("mimo-v2.5-7l-16e", dtype="bfloat16",
+                        attention_impl="pallas")
+    chip = SingleDeviceSharding(topo.devices[0])
+    params = _on(chip, jax.eval_shape(
+        lambda: adapter.init_params(jax.random.key(0))))
+    kv = _on(chip, jax.eval_shape(
+        lambda: adapter.init_kv(9000, PAGE, state_slots=36)))
+    assert kv.k.shape == (3 * 2, 9000, PAGE, 2, 128)
+    assert kv.v.shape == (2 * 2, 9000, PAGE, 2, 128)
+    assert kv.ring.shape == (3 * 5, 37, 640, 4, 128)
+    assert kv.ring_v.shape == (2 * 5, 37, 640, 4, 128)
+    mp = 18432 // PAGE
+
+    def rows_of(b, tt):
+        return (
+            _sds((b, tt), jnp.int32, chip), _sds((b, tt), jnp.int32, chip),
+            _sds((b, tt), jnp.bool_, chip),
+            (_sds((b, mp), jnp.int32, chip), _sds((b, 2), jnp.int32, chip)),
+        )
+
+    def head(params, hidden):
+        logits = adapter.compute_logits(params, hidden[:, -1])
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    if b_pre:
+
+        def program(params, kv, prompt, decode):
+            h_p, h_d, kv = adapter.forward_hidden_mixed(
+                params, prompt, decode, kv)
+            return head(params, h_d), kv
+
+        args = (rows_of(b_pre, t), rows_of(rows, 1))
+    else:
+
+        def program(params, kv, tokens, positions, valid, pt):
+            def body(carry, _):
+                tokens, positions, kv = carry
+                hidden, kv = adapter.forward_hidden(
+                    params, tokens, positions, valid, kv, pt)
+                ids = head(params, hidden)
+                return (ids[:, None], positions + 1, kv), ids
+
+            (_, _, kv), ids = jax.lax.scan(
+                body, (tokens, positions, kv), None, length=8)
+            return ids, kv
+
+        args = rows_of(rows, t)
+
+    compiled = jax.jit(program, donate_argnums=(1,)).lower(
+        params, kv, *args).compile()
+    mem = compiled.memory_analysis()
+    pools = sum(np.prod(x.shape) * x.dtype.itemsize
+                for x in (kv.k, kv.v, kv.ring, kv.ring_v))
+    weights = sum(np.prod(x.shape) * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    print("mimo-v2.5 compile", rows, t, b_pre, "temp",
+          mem.temp_size_in_bytes, "args", mem.argument_size_in_bytes,
+          "alias", mem.alias_size_in_bytes, "pools", pools)
+    # a full layer's page is 64 x 2,560 B and a ring row 5,120 B
+    assert pools == 2 * 9000 * PAGE * 2560 + 5 * 37 * 640 * 5120
+    assert mem.alias_size_in_bytes >= pools  # every pool in place
+    # nothing padded: the arguments are the weights, the pools, the rows
+    assert mem.argument_size_in_bytes < weights + pools + (8 << 20)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    text = compiled.as_text()
+    calls = {k: _kernel_calls(text, k) for k in (
+        "ring_prefill_attention", "paged_prefill_attention",
+        "paged_decode_attention", "gather_pages", "paged_kv_write")}
+    print(calls)
+    # ONE body a kind of layer: the decode walk once in the window layers'
+    # body (the ring) and once in the full layers' (the pages); a piece the
+    # banded kernel in both, over K and V gathered by page a PART at a time
+    assert calls["paged_decode_attention"] == 2
+    assert calls["ring_prefill_attention"] == 2 * int(bool(b_pre))
+    assert calls["gather_pages"] == 2 * 5 * int(bool(b_pre))
+    assert calls["paged_prefill_attention"] == 0
+    # no copy of a pool, nor of a layer of one
+    for copy in ("bf16[15,37,640,4,128]", "bf16[10,37,640,4,128]",
+                 "bf16[37,640,4,128]", "bf16[15,370,64,4,128]",
+                 "bf16[10,370,64,4,128]", "bf16[370,64,4,128]",
+                 "bf16[6,9000,64,2,128]", "bf16[4,9000,64,2,128]",
+                 "bf16[9000,64,2,128]", "bf16[6,9000,128,128]",
+                 "bf16[15,370,256,128]"):
+        assert not re.search(
+            rf"= {re.escape(copy)}[^ ]* copy\(", text), copy
+
+
+def test_gqa_walk_and_ring_kernel_are_what_they_were_before_the_wide_key():
+    """PR 56 widens the decode walk by `parts` (a key wider than its value
+    in lane parts), `_footprint` / `_block_pages` by `dv`, the banded ring
+    kernel by a value width, a sink and the skipping of turns a short
+    window cannot reach, and the page writer by a V of other layers than K.
+    With the defaults (one part, `dv == dk`, no sink, a window longer than
+    the piece) the traced programs are what they were on PR 55's tree, read
+    here from the jaxprs at `cmdaplus-longctx`'s served widths: the same
+    operands, scratch and primitive counts, and NO semaphore, scratch or
+    operand more."""
+    from dynamo_tpu.ops import paged_attention as walk_ops
+    from dynamo_tpu.ops.flash_prefill import ring_prefill_attention
+
+    S = jax.ShapeDtypeStruct
+    b, hq, hkv, mp = 32, 128, 8, 65
+    ring = S((3, 2664, 64, hkv, 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v, pt, hist, bits: paged_decode_attention(
+            q, k, v, jnp.int32(1), pt, hist, scale_dim=128, token_bits=bits,
+            vmem_budget=16 << 20, interpret=False)
+    )(S((b, hq, 128), jnp.bfloat16), ring, ring, S((b, mp), jnp.int32),
+      S((b,), jnp.int32), S((b, mp * 64), jnp.bool_))
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    grid = call.params["grid_mapping"]
+    shapes = [str(v.aval) for v in call.invars]
+    assert shapes[5:] == [
+        "bfloat16[32,128,128]", "bfloat16[3,2664,512,128]",
+        "bfloat16[3,2664,512,128]", f"int32[32,{68 * 512}]"]
+    body = call.params["jaxpr"]
+    scratch = [str(v.aval) for v in
+               body.invars[-grid.num_scratch_operands:]]
+    assert scratch == ["Ref<vmem>{bfloat16[2,2048,128]}"] * 2 + [
+        "Ref<semaphore_mem>{dma_sem[2,2]}"]
+    seen = _primitives(body, {})
+    # four one-page sub-tiles a block: a score and a value product each
+    assert seen["dot_general"] == seen["dot:bfloat16/bfloat16"] == 8
+    assert (seen["dma_start"], seen["dma_wait"]) == (4, 2)
+    # the block rule reads the shapes as it did: `dv` None is `dv == d`
+    for args in ((32, 128, 128, 64, 8, 2, False, 16 << 20),
+                 (64, 28, 128, 64, 4, 1, True, None)):
+        assert walk_ops._block_pages(*args) == walk_ops._block_pages(
+            *args, 0, 128)
+        pb = walk_ops._block_pages(*args)
+        assert walk_ops._footprint(pb, *args[:7]) == walk_ops._footprint(
+            pb, *args[:7], 0, 128)
+    assert walk_ops._block_pages(32, 128, 128, 64, 8, 2, False, 16 << 20) == 4
+    # the wide walk of this PR: 6 pages of 2 pair-heads a block in the full
+    # layers, 3 of 4 in the ring, one sub-tile each
+    assert walk_ops._block_pages(
+        32, 64, 384, 64, 2, 2, False, 16 << 20, 0, 256) == 6
+    assert walk_ops._block_pages(
+        32, 64, 384, 64, 4, 2, False, 16 << 20, 0, 256) == 3
+    # the banded kernel over a 4,608-row ring under a window of 4,096: eight
+    # operands, three scratch buffers, nothing started before the first turn
+    r = 4608
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v, rk, rv, qp, rp, cp: ring_prefill_attention(
+            q, k, v, rk, rv, qp, rp, cp, window=4096, interpret=False)
+    )(S((1, 512, hq, 128), jnp.bfloat16), S((1, 512, hkv, 128), jnp.bfloat16),
+      S((1, 512, hkv, 128), jnp.bfloat16), S((1, hkv, r, 128), jnp.bfloat16),
+      S((1, hkv, r, 128), jnp.bfloat16), S((1, 512), jnp.int32),
+      S((1, r), jnp.int32), S((1, 512), jnp.int32))
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "ring_prefill_attention"
+    grid = call.params["grid_mapping"]
+    assert grid.grid == (1, 8, 4) and grid.num_index_operands == 1
+    assert len(call.invars) == 1 + 8 and grid.num_scratch_operands == 3
+    seen = _primitives(call.params["jaxpr"], {})
+    # four own tiles and the ring's turn: a score and a value product each
+    assert seen["dot_general"] == 2 * (4 + 1)
+    assert seen["cond"] == 3 + 1  # own tiles 1-3 and the ring's turn
